@@ -30,16 +30,19 @@ def build(force: bool = False, quiet: bool = True) -> str:
             and open(STAMP).read().strip() == _src_hash()):
         return SO
     include = sysconfig.get_paths()["include"]
+    # a clean checkout builds on first use, and a driver's workers may all
+    # get there at once: each builds into a file of its own
+    tmp = f"{SO}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-Wall",
-        f"-I{include}", SRC, "-o", SO + ".tmp",
+        f"-I{include}", SRC, "-o", tmp,
     ]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         if not quiet:
             sys.stderr.write(proc.stderr)
         raise RuntimeError(f"rt_native build failed:\n{proc.stderr[-2000:]}")
-    os.replace(SO + ".tmp", SO)
+    os.replace(tmp, SO)
     with open(STAMP, "w") as f:
         f.write(_src_hash())
     return SO

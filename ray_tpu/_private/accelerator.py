@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, Optional
+from typing import Dict, Sequence
 
 TPU_VERSION_ENV = "RT_TPU_VERSION"          # e.g. "v5p", "v5e"
 NUM_TPUS_ENV = "RT_NUM_TPUS"
@@ -75,11 +75,35 @@ def tpu_node_labels() -> Dict[str, str]:
     return labels
 
 
-def set_visible_chips(chip_indices, env: Optional[dict] = None) -> None:
-    """Pin a worker process to specific chips (reference:
-    ``TPU_VISIBLE_CHIPS`` handling, ``ray_constants.py:407``,
-    ``worker.py:430`` — the TPU analog of CUDA_VISIBLE_DEVICES)."""
+def worker_chip_env(chips: Sequence[int], host_chips: int,
+                    env: Dict[str, str]) -> Dict[str, str]:
+    """Make ``env`` (a worker's environment, mutated and returned) open
+    exactly the granted ``chips`` of a host that has ``host_chips``.
+
+    A worker granted chips sees those and no others. A worker granted
+    none is held to the CPU platform: a TPU chip belongs to one process
+    at a time, so a data worker, controller or proxy that touched JAX
+    first would take the chip from the worker that was granted it.
+
+    To open ONE chip of a host that has more, libtpu needs, beyond
+    TPU_VISIBLE_CHIPS, the chip described as a one-host slice of its own.
+    With that, workers on different chips of one host run side by side;
+    without it libtpu takes the whole-host lock and the second worker dies
+    at backend init. Found on a four-chip v5e host (CHANGES.md, PR 21),
+    where a PAIR of chips opens under no bounds tried (1,2,1 and 2,1,1,
+    rows and columns, either spelling of the variables: 1x2 is no v5e
+    topology), so a two-chip grant is left to libtpu, which refuses it. A
+    whole-host grant keeps the machine's own bounds.
+    """
     from ray_tpu._private.config import get_config
 
-    target = env if env is not None else os.environ
-    target[get_config().tpu_visible_chips_env] = ",".join(str(i) for i in chip_indices)
+    visible = get_config().tpu_visible_chips_env
+    if not chips:
+        env["JAX_PLATFORMS"] = "cpu"
+        env.pop(visible, None)
+        return env
+    env[visible] = ",".join(str(i) for i in chips)
+    if len(chips) == 1 and host_chips > 1:
+        env["TPU_CHIPS_PER_HOST_BOUNDS"] = "1,1,1"
+        env["TPU_HOST_BOUNDS"] = "1,1,1"
+    return env

@@ -766,18 +766,24 @@ class GcsServer:
             })
             try:
                 client = await self._pool.get(node.address)
-                # Bounded: a wedged raylet must fail over to another node,
-                # not pin this actor PENDING_CREATION forever (the raylet's
-                # own create path is bounded by process_startup_timeout_s).
-                cfg = get_config()
-                create_timeout = (cfg.process_startup_timeout_s
-                                  + (cfg.runtime_env_setup_timeout_s
-                                     if entry.spec.get("runtime_env") else 0)
-                                  + 30.0)
+                # Bounded by the node's life, not by a clock: the reply
+                # comes when the actor's __init__ has returned, and a model
+                # replica brings up its device, builds its weights and
+                # compiles its programs there, for minutes. (The raylet
+                # bounds what has a bound: the worker process's start.) A
+                # wedged raylet stops heartbeating, is marked dead, and
+                # only then does the creation fail over to another node.
                 restarts_before = entry.num_restarts
-                reply = await client.call("create_actor", {
-                    "actor_id": entry.actor_id, "spec": entry.spec},
-                    timeout=create_timeout)
+                call = asyncio.ensure_future(client.call("create_actor", {
+                    "actor_id": entry.actor_id, "spec": entry.spec}))
+                while True:
+                    done, _ = await asyncio.wait({call}, timeout=5.0)
+                    if done:
+                        break
+                    if not node.alive:
+                        call.cancel()
+                        raise TimeoutError("node died during actor creation")
+                reply = call.result()
                 if entry.state == ACTOR_DEAD:
                     # Killed during creation: reap the just-created worker.
                     if reply.get("ok"):

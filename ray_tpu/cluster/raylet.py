@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import ray_tpu
 from ray_tpu import _native
+from ray_tpu._private import accelerator
 from ray_tpu._private.config import get_config
 from ray_tpu._private.ids import ObjectID
 from ray_tpu.core import failure as F
@@ -51,12 +52,25 @@ from ray_tpu.util import metrics as M
 from ray_tpu.util.profiling import format_current_stacks
 
 
+_PACKAGE_ROOT = os.path.dirname(os.path.dirname(
+    os.path.abspath(ray_tpu.__file__)))
+
+
+async def _wait_gone(procs, timeout_s: float) -> None:
+    """Wait, up to ``timeout_s``, until every process has exited."""
+    deadline = time.monotonic() + timeout_s
+    while (any(proc.poll() is None for proc in procs)
+           and time.monotonic() < deadline):
+        await asyncio.sleep(0.02)
+
+
 class _WorkerEntry:
     def __init__(self, worker_id: str, proc: subprocess.Popen, key: Tuple,
                  loop: asyncio.AbstractEventLoop):
         self.worker_id = worker_id
         self.proc = proc
         self.key = key                      # (chip_tuple, runtime_env_hash)
+        self.chips: Tuple[int, ...] = ()    # TPU chips this process may open
         self.address: Optional[str] = None
         self.client: Optional[RpcClient] = None
         self.ready = loop.create_future()
@@ -464,11 +478,18 @@ class Raylet:
         self._stopped = True
         await cancel_and_wait(*self._tasks)
         self._tasks.clear()
-        for w in list(self._workers.values()):
+        procs = [w.proc for w in self._workers.values()]
+        for proc in procs:
             try:
-                w.proc.terminate()
+                proc.terminate()
             except ProcessLookupError:
                 pass
+        # a stopped node leaves no process behind: whoever starts next on
+        # this host must find its chips closed
+        await _wait_gone(procs, 3.0)
+        for proc in procs:
+            if proc.poll() is None:
+                proc.kill()
         if self._gcs is not None:
             await self._gcs.close()
         await self._pool.close_all()
@@ -898,10 +919,16 @@ class Raylet:
         # user prints must reach the log file (and the driver echo) promptly,
         # not sit in a block buffer until the worker exits
         env["PYTHONUNBUFFERED"] = "1"
+        # the worker is `python -m ray_tpu...`: it must find the package
+        # wherever the driver was started from
+        env["PYTHONPATH"] = os.pathsep.join(
+            [_PACKAGE_ROOT] + [d for d in env.get("PYTHONPATH", "").split(
+                os.pathsep) if d and d != _PACKAGE_ROOT])
         if runtime_env:
             env["RT_RUNTIME_ENV_JSON"] = json.dumps(runtime_env)
-        if chips:
-            env[get_config().tpu_visible_chips_env] = ",".join(map(str, chips))
+        # the chips this worker was granted and no others; none at all for a
+        # worker that was granted none
+        accelerator.worker_chip_env(chips, int(self.node.total.get(TPU)), env)
         if C.armed():
             # new workers join the tortured cluster armed from birth (live
             # workers got the plan via the chaos_arm RPC)
@@ -918,8 +945,31 @@ class Raylet:
             env=env, stdout=log_file, stderr=subprocess.STDOUT)
         log_file.close()
         entry = _WorkerEntry(worker_id, proc, key, self.loop)
+        entry.chips = tuple(chips)
         self._workers[worker_id] = entry
         return entry
+
+    async def _vacate_chips(self, chips: List[int]) -> None:
+        """Call before spawning a worker for ``chips``. A chip belongs to one
+        process at a time, and the pool's accounting frees a chip before the
+        process that opened it is gone: an idle pooled worker keeps its
+        backend up, and a killed actor's worker takes a moment to die. The
+        first kind is retired here; then both are waited for, because libtpu
+        refuses the newcomer a device that is still open. The wait is
+        bounded so that an entry this cannot classify (a fractional-chip
+        neighbour) costs time and not a deadlock."""
+        if not chips:
+            return
+        want = set(chips)
+        leaving = [e for e in self._workers.values()
+                   if want.intersection(e.chips) and e.proc.poll() is None
+                   and not e.busy and not e.is_actor_worker]
+        for e in leaving:
+            if e.idle_since is not None:
+                self._idle[e.key].remove(e)
+                e.idle_since = None
+                self._terminate_worker(e)
+        await _wait_gone([e.proc for e in leaving], 10.0)
 
     async def rpc_worker_ready(self, p):
         entry = self._workers.get(p["worker_id"])
@@ -992,6 +1042,7 @@ class Raylet:
                     self.loop.run_in_executor(
                         None, ensure_venv, runtime_env, cache_root),
                     get_config().runtime_env_setup_timeout_s)
+            await self._vacate_chips(chips)
             entry = self._spawn_worker(key, chips, runtime_env, python_exe)
             cfg = get_config()
             timeout = cfg.process_startup_timeout_s + (
@@ -2203,6 +2254,7 @@ class Raylet:
                     })
             if worker is None:
                 self._sched_stats["cold_spawns"] += 1
+                await self._vacate_chips(chips)
                 worker = self._spawn_worker((("actor", p["actor_id"]),),
                                             chips, spec.get("runtime_env"))
             worker.is_actor_worker = True

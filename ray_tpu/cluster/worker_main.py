@@ -191,8 +191,12 @@ class _GenStreamPusher:
         return sorted(pending.items())
 
 
+def _granted_chips() -> str:
+    return os.environ.get(get_config().tpu_visible_chips_env, "")
+
+
 class WorkerProcess:
-    def __init__(self):
+    def __init__(self, compiles=None):
         self.worker_id = os.environ["RT_WORKER_ID"]
         self.backend = ClusterBackend(
             gcs_address=os.environ["RT_GCS_ADDR"],
@@ -212,6 +216,11 @@ class WorkerProcess:
         from ray_tpu.core.failure import EmitLimiter
 
         self._failure_limiter = EmitLimiter(cap=256)
+        # rt-device log lines (see _log_device_use): the last poll's
+        # reading and the last one logged
+        self._device_polled: Optional[Dict[str, Any]] = None
+        self._device_logged: Optional[Dict[str, Any]] = None
+        self._compiles = compiles
 
     def start(self) -> None:
         from ray_tpu.core.worker import global_worker
@@ -254,9 +263,38 @@ class WorkerProcess:
             await asyncio.sleep(1.0)
             if self.backend._raylet._closed:
                 os._exit(0)
+            self._log_device_use()
             # buffered rpc.* chaos fires ship from the watch loop (the
             # rpc layer itself has no GCS handle)
             self.backend._drain_chaos_events()
+
+    def _log_device_use(self) -> None:
+        """Say in this worker's log which JAX backend it brought up, once,
+        and from then on what it has compiled and how much device memory it
+        has reached, each time that has moved and then stood still for a
+        second. A chip belongs to one process, so ``rt-device`` lines are
+        how an operator (and ``chip_smoke.py``) sees which worker took it; a
+        worker that never touches JAX logs none. Reads only what is already
+        up, and without the bridge's lock, which a backend coming up in
+        another thread holds for as long as that takes."""
+        bridge = sys.modules.get("jax._src.xla_bridge")
+        if getattr(bridge, "_default_backend", None) is None:
+            return
+        devices = bridge.local_devices()
+        if self._device_polled is None:
+            print(f"rt-device: backend-init pid={os.getpid()} "
+                  f"platform={devices[0].platform} "
+                  f"kind={devices[0].device_kind!r} count={len(devices)} "
+                  f"chips={_granted_chips() or '-'}", flush=True)
+        seen = {"peak_bytes": max((d.memory_stats() or {}).get(
+            "peak_bytes_in_use", 0) for d in devices)}
+        if self._compiles is not None:
+            seen.update(self._compiles.snapshot())
+        if seen == self._device_polled and seen != self._device_logged:
+            self._device_logged = seen
+            print("rt-device: use pid=%d %s" % (os.getpid(), " ".join(
+                f"{k}={v}" for k, v in seen.items())), flush=True)
+        self._device_polled = seen
 
     async def rpc_chaos_arm(self, p):
         """Live (re)arming from this worker's raylet when `rt chaos` ships
@@ -805,7 +843,16 @@ def main() -> None:
     from ray_tpu.parallel.xla_flags import apply_tpu_perf_flags
 
     apply_tpu_perf_flags()
-    wp = WorkerProcess()
+    compiles = None
+    if _granted_chips():
+        # this worker was granted chips, so it will run JAX: give it the
+        # run's one compile cache, and count what it compiles from its
+        # first program on (the import is one it was about to pay anyway)
+        from ray_tpu.util import compile_cache
+
+        compile_cache.configure()
+        compiles = compile_cache.CompileCounter()
+    wp = WorkerProcess(compiles)
     wp.start()
     threading.Event().wait()  # io loop thread does the work
 

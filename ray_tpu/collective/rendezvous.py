@@ -101,23 +101,14 @@ def bootstrap_jax_distributed(world_size: int, rank: int,
 
     _shutdown_previous_gang()
 
-    try:  # jax 0.4.x gates CPU cross-process collectives behind gloo opt-in
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:  # newer jax: on by default, option removed
-        pass
-    kwargs = dict(coordinator_address=address,
-                  num_processes=world_size,
-                  process_id=rank,
-                  local_device_ids=local_device_ids)
     try:
-        try:
-            # bound the rendezvous where jax supports it: a gang member
-            # that died pre-connect must fail THIS rank loudly in
-            # timeout_s, not hang the whole gang on a default 5-minute wait
-            jax.distributed.initialize(
-                initialization_timeout=max(1, int(timeout_s)), **kwargs)
-        except TypeError:  # older jax: no initialization_timeout kwarg
-            jax.distributed.initialize(**kwargs)
+        # bound the rendezvous: a gang member that died pre-connect must
+        # fail THIS rank loudly in timeout_s, not hang the whole gang on a
+        # default 5-minute wait
+        jax.distributed.initialize(
+            coordinator_address=address, num_processes=world_size,
+            process_id=rank, local_device_ids=local_device_ids,
+            initialization_timeout=max(1, int(timeout_s)))
     except Exception as e:  # noqa: BLE001
         # CPU-graceful: on a CPU-only host a failed process-group bootstrap
         # degrades to local (un-distributed) jax — the gang still runs, each
@@ -159,12 +150,10 @@ def _shutdown_previous_gang() -> None:
     carry a previous gang's coordinator client whose peers are gone — tear
     it down and drop cached backends so the new device topology can
     register (or so a degraded rank truly runs LOCAL jax). NCCL's
-    equivalent is destroy_process_group before re-init. getattr guard:
-    very old jax builds predate is_initialized — treat them as
-    never-initialized instead of dying before the bootstrap."""
+    equivalent is destroy_process_group before re-init."""
     import jax
 
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         try:
             jax.distributed.shutdown()
         except Exception:  # noqa: BLE001
@@ -172,12 +161,9 @@ def _shutdown_previous_gang() -> None:
             # WHY we're re-bootstrapping) — a failed goodbye to it must not
             # fail the new gang's hello.
             pass
-        try:
-            import jax.extend.backend as _jeb
+        import jax.extend.backend as _jeb
 
-            _jeb.clear_backends()
-        except Exception:  # pragma: no cover — best effort on older jax
-            pass
+        _jeb.clear_backends()
 
 
 def _rendezvous_strict() -> bool:

@@ -156,8 +156,12 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
                                            causal=True)
     elif cfg.attn_impl == "flash":
         from ray_tpu.ops.pallas.flash import flash_attention
+        from ray_tpu.parallel.context import flash_attention_on_mesh
 
-        attn = flash_attention(q, k, v, causal=True)
+        # a pipeline stage already runs per device inside its shard_map
+        attend = (flash_attention if cfg.pipeline_axis is not None
+                  else flash_attention_on_mesh)
+        attn = attend(q, k, v, causal=True)
     else:
         attn = mha(q, k, v, causal=True, segment_ids=segment_ids)
     return x + attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)

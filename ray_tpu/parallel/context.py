@@ -31,28 +31,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.pallas.flash import (
     NEG_INF,
+    flash_attention,
     flash_attention_with_lse,
     flash_vjp_chunk,
 )
 
 _CURRENT_MESH: contextvars.ContextVar[Optional[Mesh]] = contextvars.ContextVar(
     "ray_tpu_mesh", default=None)
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level, check_vma spelling
-    shard_map = jax.shard_map
-else:  # jax 0.4.x: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-if hasattr(lax, "axis_size"):  # jax >= 0.6
-    axis_size = lax.axis_size
-else:  # jax 0.4.x: psum of the literal 1 constant-folds to a concrete int
-    def axis_size(axis_name):
-        return lax.psum(1, axis_name)
-
 
 @contextlib.contextmanager
 def mesh_scope(mesh: Mesh):
@@ -83,7 +68,7 @@ def _merge(o1, lse1, o2, lse2):
 
 
 def _ring_perm(axis_name):
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     return [(i, (i + 1) % p) for i in range(p)]
 
 
@@ -102,7 +87,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = True,
 
 
 def _ring_fwd_loop(q, k, v, axis_name, causal, scale):
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_loc = q.shape[1]
     b, _, hq, d = q.shape
@@ -134,7 +119,7 @@ def _ring_fwd(q, k, v, axis_name, causal, scale):
 
 def _ring_bwd(axis_name, causal, scale, res, do):
     q, k, v, o, lse = res
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     s_loc = q.shape[1]
     perm = _ring_perm(axis_name)
@@ -177,7 +162,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = True,
     repeated up to hq first if P doesn't divide them (GQA). Differentiable
     through ``lax.all_to_all``.
     """
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     hq, hkv = q.shape[2], k.shape[2]
     if hq % p:
         raise ValueError(f"ulysses: q heads {hq} not divisible by sp={p}")
@@ -189,13 +174,34 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = True,
                                    tiled=True)
     qg, kg, vg = a2a(q), a2a(k), a2a(v)
     if use_flash:
-        from ray_tpu.ops.pallas.flash import flash_attention
         og = flash_attention(qg, kg, vg, causal=causal, scale=scale)
     else:
         from ray_tpu.ops.attention import mha
         og = mha(qg, kg, vg, causal=causal, scale=scale)
     return lax.all_to_all(og, axis_name, split_axis=1, concat_axis=2,
                           tiled=True)
+
+
+def flash_attention_on_mesh(q, k, v, *, causal: bool = True,
+                            scale: Optional[float] = None,
+                            mesh: Optional[Mesh] = None):
+    """The flash kernel under the ambient mesh. The TPU compiler does not
+    partition a Mosaic kernel ("cannot be automatically partitioned"), so
+    on more than one device the kernel runs per shard in the layout GSPMD
+    already gives q/k/v: batch over (dp, fsdp), heads over tp. KV heads
+    that tp does not divide are repeated up to the q heads first."""
+    mesh = mesh or current_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal=causal, scale=scale)
+    if k.shape[2] % mesh.shape["tp"]:
+        rep = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    spec = P(("dp", "fsdp"), None, "tp", None)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal, scale=scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def sequence_parallel_attention(q, k, v, *,
@@ -223,7 +229,7 @@ def sequence_parallel_attention(q, k, v, *,
             return ulysses_attention(qq, kk, vv, axis_name, causal, scale)
         raise ValueError(f"unknown sp impl {impl!r}")
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(qspec, qspec, qspec),
         out_specs=qspec,

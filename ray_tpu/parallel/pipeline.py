@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel.context import axis_size, shard_map
-
 
 def _microbatch(tree: Any, m: int):
     """Reshape every [B, ...] leaf to [m, B/m, ...]."""
@@ -64,7 +62,7 @@ def pipeline_spmd(stage_fn: Callable,
     replicated to all pp ranks (so downstream loss code is rank-agnostic).
     Differentiable.
     """
-    p = axis_size(axis_name)
+    p = lax.axis_size(axis_name)
     r = lax.axis_index(axis_name)
     m = num_microbatches
     if x.shape[0] % m:
@@ -153,7 +151,7 @@ def pipeline_1f1b(stage_fn: Callable,
 
     def body(w, head, xx, tt, mm, *rest):
         ss = rest[0] if rest else None
-        p = axis_size(axis_name)
+        p = lax.axis_size(axis_name)
         r = lax.axis_index(axis_name)
         if xx.shape[0] % m:
             raise ValueError(
@@ -258,7 +256,7 @@ def pipeline_1f1b(stage_fn: Callable,
     if segs is not None:
         args.append(segs)
         specs.append(dspec)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=tuple(specs),
         out_specs=(P(), pspec, hspec, xspec),
@@ -345,7 +343,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         in_specs = (pspec, xspec, jax.tree.map(lambda _: xspec, extras))
         args = (params, x, extras)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=xspec,

@@ -22,6 +22,9 @@ _controller_lock = threading.Lock()
 _controller = None
 
 
+_HEALTHY_TIMEOUT_S = 600.0  # serve.run's wait for the first replicas
+
+
 def _get_controller(create: bool = False):
     """The singleton controller actor (named, discovered via get_actor).
 
@@ -202,7 +205,10 @@ def run(app: Application, *, name: str = "default",
         ray_tpu.get(controller.ensure_proxy.remote(
             opts.host, opts.port, opts.num_proxies))
     if _blocking:
-        ray_tpu.get(controller.wait_healthy.remote(name), timeout=120)
+        # a model replica initialises its device, builds its weights and
+        # compiles its programs in __init__: minutes at a published width
+        ray_tpu.get(controller.wait_healthy.remote(name, _HEALTHY_TIMEOUT_S),
+                    timeout=_HEALTHY_TIMEOUT_S + 20)
     return DeploymentHandle(name, ingress)
 
 

@@ -330,12 +330,14 @@ def continuous_llm_app(preset: str = "debug", *, max_slots: int = 8,
 def static_llm_app(preset: str = "debug", *, max_batch: int = 8,
                    prompt_pad: int = 16, max_new: int = 16,
                    batch_wait_timeout_s: float = 0.02, name: str = "Static",
-                   max_ongoing_requests: int = 64, seed: int = 0):
+                   max_ongoing_requests: int = 64, seed: int = 0,
+                   ray_actor_options: Optional[Dict] = None):
     """The static ``@serve.batch`` control Application."""
     from ray_tpu import serve
 
     dep = serve.deployment(StaticLLM).options(
-        name=name, max_ongoing_requests=max_ongoing_requests)
+        name=name, max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=ray_actor_options)
     return dep.bind(preset, max_batch=max_batch, prompt_pad=prompt_pad,
                     max_new=max_new,
                     batch_wait_timeout_s=batch_wait_timeout_s, seed=seed)
@@ -353,7 +355,9 @@ def cb_vs_static_load(*, preset: str = "debug", slots: int = 8,
                       rps: float = 15.0, duration_s: float = 15.0,
                       num_proxies: int = 2, timeout_s: float = 240.0,
                       seed: int = 42,
-                      route_base: str = "cbvs") -> Dict[str, Dict[str, Any]]:
+                      route_base: str = "cbvs",
+                      ray_actor_options: Optional[Dict] = None
+                      ) -> Dict[str, Dict[str, Any]]:
     """THE continuous-vs-static comparison leg, shared by ``bench.py``
     (``decode_cb_*``), ``rt scale-envelope`` (``serve_under_load``) and
     ``scripts/chaos_smoke.sh``: open-loop Poisson arrivals round-robined
@@ -366,7 +370,9 @@ def cb_vs_static_load(*, preset: str = "debug", slots: int = 8,
     methodology; callers own their parameter sizing and assertions.
 
     Requires an initialized ray_tpu; deploys/tears down its own apps
-    (``<route_base>-cb`` / ``<route_base>-static``). Returns
+    (``<route_base>-cb`` / ``<route_base>-static``), one after the other,
+    each replica with ``ray_actor_options`` (``{"num_tpus": 1}`` puts it on
+    the chip; a replica granted none runs on the CPU). Returns
     {"continuous": poisson_result, "static": poisson_result}.
     """
     import itertools
@@ -379,12 +385,14 @@ def cb_vs_static_load(*, preset: str = "debug", slots: int = 8,
         ("continuous",
          continuous_llm_app(preset, max_slots=slots, max_len=max_len,
                             decode_stride=decode_stride, name="CB",
-                            max_ongoing_requests=4 * slots),
+                            max_ongoing_requests=4 * slots,
+                            ray_actor_options=ray_actor_options),
          f"/{route_base}-cb"),
         ("static",
          static_llm_app(preset, max_batch=slots, prompt_pad=prompt_len,
                         max_new=long_tokens, name="Static",
-                        max_ongoing_requests=4 * slots),
+                        max_ongoing_requests=4 * slots,
+                        ray_actor_options=ray_actor_options),
          f"/{route_base}-static"),
     ):
         name = f"{route_base}-{leg}"

@@ -21,25 +21,28 @@ can pass their own ``params`` count.
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
-# Per-chip peak (bf16 matmul). v5e: 197 TFLOP/s. "cpu" is a rough
-# placeholder so CPU smoke runs report a stable (if synthetic) MFU.
-PEAK_FLOPS = {"tpu": 197e12, "gpu": 312e12, "cpu": 1e11}
+# Peak dense bf16 FLOP/s of one chip, keyed by ``jax.Device.device_kind``.
+# v5e: 197 TFLOP/s (Google Cloud documentation, "TPU v5e"). A device that
+# is not in the table has no peak and so no MFU: that is an error, never a
+# default, and a CPU run reports no MFU at all.
+PEAK_FLOPS = {"TPU v5 lite": 197e12}
 
 
-def peak_flops_per_chip(platform: Optional[str] = None) -> float:
-    """Peak FLOP/s of one device; RT_PEAK_FLOPS overrides (e.g. for a
-    different TPU generation than the v5e default)."""
-    env = os.environ.get("RT_PEAK_FLOPS")
-    if env:
-        return float(env)
-    if platform is None:
+def peak_flops_per_chip(device_kind: Optional[str] = None) -> float:
+    """Peak FLOP/s of one device of ``device_kind`` (default: this
+    process's first device); raises on a device the table does not know."""
+    if device_kind is None:
         import jax
 
-        platform = jax.default_backend()
-    return PEAK_FLOPS.get(platform, 1e12)
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for device kind {device_kind!r}; "
+            f"add it to PEAK_FLOPS with its source") from None
 
 
 def _flops_params(cfg) -> int:

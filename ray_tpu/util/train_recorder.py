@@ -332,24 +332,23 @@ class TrainRecorder(RecorderCore):
             out = list(self._launches)
         return out[-limit:] if limit else out
 
-    def _peak_total(self) -> float:
-        """Aggregate peak FLOP/s across the devices this driver feeds."""
+    def _peak_total(self) -> Optional[float]:
+        """Aggregate peak FLOP/s across the devices this driver feeds;
+        None on the CPU, which has no peak on record — a CPU run reports
+        walls, gaps and counts but no MFU."""
         if self._peak_total_cached is None:
-            ndev = self.n_devices
-            peak = self.peak_flops
+            peak, ndev = self.peak_flops, self.n_devices
             if peak is None or ndev <= 0:
-                try:
-                    import jax
+                import jax
 
-                    from ray_tpu.util import flops as F
+                from ray_tpu.util import flops as F
 
-                    if peak is None:
-                        peak = F.peak_flops_per_chip(jax.default_backend())
-                    if ndev <= 0:
-                        ndev = jax.local_device_count()
-                except Exception:  # noqa: BLE001 — no jax here
-                    peak = peak if peak is not None else 1e12
-                    ndev = max(1, ndev)
+                if peak is None:
+                    if jax.default_backend() == "cpu":
+                        return None
+                    peak = F.peak_flops_per_chip()
+                if ndev <= 0:
+                    ndev = jax.local_device_count()
             self._peak_total_cached = float(peak) * max(1, ndev)
         return self._peak_total_cached
 
@@ -403,7 +402,7 @@ class TrainRecorder(RecorderCore):
             steps_tot += r["k"]
             device_s += r["phases"]["dispatch"] \
                 + r["phases"]["device_compute"]
-            if r["flops"] > 0 and r["wall_s"] > 0:
+            if peak_total and r["flops"] > 0 and r["wall_s"] > 0:
                 mfus.append(r["flops"] / (r["wall_s"] * peak_total))
         gaps.sort()
         phase_sum = sum(phase_totals.values())
@@ -438,7 +437,7 @@ class TrainRecorder(RecorderCore):
             out["marginal_mfu"] = round(mfus[-1], 6)
             out["marginal_mfu_mean"] = round(sum(mfus) / len(mfus), 6)
             out["marginal_mfu_recent"] = [round(m, 6) for m in mfus[-8:]]
-        if flops_tot > 0 and span > 0:
+        if peak_total and flops_tot > 0 and span > 0:
             raw_mfu = flops_tot / (device_s * peak_total) \
                 if device_s > 0 else 0.0
             achieved_mfu = flops_tot / (span * peak_total)

@@ -209,6 +209,139 @@ def test_cluster_tpu_task_gets_visible_chips(rt_cluster):
     assert set(chips) <= {0, 1, 2, 3}
 
 
+def _device_env_fn():
+    """A function (nested, so it travels to the worker by value) that
+    returns the worker's pid and what its environment says of devices."""
+
+    def device_env():
+        import os
+
+        keys = ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS",
+                "TPU_CHIPS_PER_HOST_BOUNDS", "TPU_HOST_BOUNDS",
+                "JAX_COMPILATION_CACHE_DIR")
+        return os.getpid(), {k: os.environ[k] for k in keys
+                             if k in os.environ}
+
+    return device_env
+
+
+def test_cluster_worker_without_chips_cannot_open_one(rt_cluster, monkeypatch):
+    """A chip belongs to one process at a time, so a worker that was granted
+    none is held to the CPU platform whatever the driver's environment says:
+    the first data worker, controller or proxy to touch JAX must not take the
+    device from the worker that was granted it."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1,2,3")
+
+    device_env = _device_env_fn()
+
+    @ray_tpu.remote(runtime_env={"env_vars": {"FRESH_WORKER": "1"}})
+    def plain():
+        import jax
+
+        return device_env()[1], jax.devices()[0].platform
+
+    env, platform = ray_tpu.get(plain.remote())
+    assert env["JAX_PLATFORMS"] == "cpu" and platform == "cpu"
+    assert "TPU_VISIBLE_CHIPS" not in env
+
+
+def test_cluster_tpu_worker_gets_its_chips_and_no_others(rt_cluster,
+                                                         monkeypatch):
+    """A subset of the host's chips comes with the bounds libtpu needs to
+    open it as a one-host slice of its own; the whole host keeps the
+    machine's bounds. Either way the worker shares the run's one compile
+    cache, at the fixed in-checkout path unless the environment names one."""
+    from ray_tpu.util import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    one = ray_tpu.remote(num_tpus=1)(_device_env_fn())
+    whole = ray_tpu.remote(num_tpus=4)(_device_env_fn())
+    _, env = ray_tpu.get(one.remote())
+    assert env["TPU_VISIBLE_CHIPS"] in {"0", "1", "2", "3"}
+    assert env["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,1,1"
+    assert env["TPU_HOST_BOUNDS"] == "1,1,1"
+    assert env["JAX_COMPILATION_CACHE_DIR"] == compile_cache.DEFAULT_DIR
+    _, env = ray_tpu.get(whole.remote())
+    assert env["TPU_VISIBLE_CHIPS"] == "0,1,2,3"
+    assert "TPU_CHIPS_PER_HOST_BOUNDS" not in env
+
+
+def test_cluster_chip_changes_hands_only_when_its_holder_is_gone(rt_cluster):
+    """The pool's accounting frees a chip as soon as its task returns, while
+    the task's worker idles in the pool with its backend up. Before another
+    process is given that chip the idle holder is retired, and waited for."""
+    import os
+
+    pid, _ = ray_tpu.get(
+        ray_tpu.remote(num_tpus=1)(_device_env_fn()).remote())
+
+    @ray_tpu.remote(num_tpus=4)
+    class Gang:
+        def holder_alive(self, pid):
+            try:
+                os.kill(pid, 0)
+            except ProcessLookupError:
+                return False
+            # a zombie is gone for this purpose: it holds no device
+            with open(f"/proc/{pid}/stat") as f:
+                return f.read().rsplit(")", 1)[1].split()[0] != "Z"
+
+    gang = Gang.remote()
+    assert ray_tpu.get(gang.holder_alive.remote(pid)) is False
+
+
+def test_worker_chip_env_unit():
+    from ray_tpu._private.accelerator import worker_chip_env
+
+    assert worker_chip_env([], 4, {"JAX_PLATFORMS": "tpu,cpu",
+                                   "TPU_VISIBLE_CHIPS": "0"}) == {
+        "JAX_PLATFORMS": "cpu"}
+    assert worker_chip_env([2], 4, {}) == {
+        "TPU_VISIBLE_CHIPS": "2", "TPU_CHIPS_PER_HOST_BOUNDS": "1,1,1",
+        "TPU_HOST_BOUNDS": "1,1,1"}
+    assert worker_chip_env([0], 1, {}) == {"TPU_VISIBLE_CHIPS": "0"}
+    assert worker_chip_env([0, 1, 2, 3], 4, {"X": "y"}) == {
+        "X": "y", "TPU_VISIBLE_CHIPS": "0,1,2,3"}
+
+
+def test_autodetect_counts_the_device_nodes_a_v5e_host_has(monkeypatch):
+    """A v5e host shows its chips as /dev/vfio/<n> (one chip: /dev/vfio/3
+    alone, as the chip tool's one-chip machine does); older generations as
+    /dev/accel<n>. RT_NUM_TPUS overrides."""
+    from ray_tpu._private import accelerator
+
+    nodes = {"/dev/accel*": [], "/dev/vfio/[0-9]*": ["/dev/vfio/3"]}
+    monkeypatch.delenv("RT_NUM_TPUS", raising=False)
+    monkeypatch.setattr(accelerator.glob, "glob", lambda pat: nodes[pat])
+    assert accelerator.autodetect_num_tpu_chips() == 1
+    nodes["/dev/vfio/[0-9]*"] = [f"/dev/vfio/{i}" for i in range(4)]
+    assert accelerator.autodetect_num_tpu_chips() == 4
+    nodes["/dev/vfio/[0-9]*"] = []
+    assert accelerator.autodetect_num_tpu_chips() == 0
+    monkeypatch.setenv("RT_NUM_TPUS", "2")
+    assert accelerator.autodetect_num_tpu_chips() == 2
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set it is left alone and no other
+    path is set; otherwise the cache goes to one fixed place in the checkout
+    (a path that moves never hits: it is part of the cache's key)."""
+    import os
+
+    from ray_tpu.util import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.configure() == str(tmp_path)
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.configure() == os.path.join(repo, ".jax_cache")
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == compile_cache.DEFAULT_DIR
+    for moving in ("/tmp", str(os.getpid())):
+        assert moving not in compile_cache.DEFAULT_DIR
+
+
 def test_cluster_worker_reuse(rt_cluster):
     @ray_tpu.remote
     def my_pid():

@@ -135,6 +135,25 @@ def test_sharded_step_matches_single_device():
     np.testing.assert_allclose(single, sharded, rtol=2e-2)
 
 
+def test_init_sharded_state_shards_the_optimizer_state_too():
+    """Adam's moments are zeros_like: they depend on no input, so no
+    sharding propagates to them, and left unpinned they all land on the
+    first device (seen on a four-chip v5e host: 4.6 GiB on chip 0, 0.5 on
+    the others). Every matrix of the state lies on every device."""
+    cfg = llama.PRESETS["debug"]
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), jax.devices()[:4])
+    params, opt_state = ts.init_sharded_state(
+        jax.random.key(0), cfg, mesh, ts.default_optimizer())
+    flat = jax.tree_util.tree_flatten_with_path((params, opt_state))[0]
+    matrices = [(path, x) for path, x in flat
+                if x.ndim >= 2 and "norm" not in jax.tree_util.keystr(path)]
+    assert len(matrices) == 3 * 9  # params, mu, nu
+    for path, x in matrices:
+        devices = {s.device for s in x.addressable_shards}
+        assert len(devices) == 4, jax.tree_util.keystr(path)
+        assert x.addressable_shards[0].data.size == x.size // 4
+
+
 def test_sharding_rules_cover_all_params():
     from jax.sharding import PartitionSpec as P
 

@@ -75,13 +75,21 @@ def test_mfu_formula():
     assert F.mfu(1e12, 0.0) == 0.0
 
 
-def test_peak_flops_env_override(monkeypatch):
+def test_peak_flops_unknown_device_raises(monkeypatch):
+    """Peaks are keyed by device_kind; a device that is not in the table is
+    an error, the CPU included, and no environment variable overrides it."""
     from ray_tpu.util import flops as F
 
+    assert F.peak_flops_per_chip("TPU v5 lite") == 197e12
     monkeypatch.setenv("RT_PEAK_FLOPS", "123.0")
-    assert F.peak_flops_per_chip("tpu") == 123.0
-    monkeypatch.delenv("RT_PEAK_FLOPS")
-    assert F.peak_flops_per_chip("tpu") == F.PEAK_FLOPS["tpu"]
+    assert F.peak_flops_per_chip("TPU v5 lite") == 197e12
+    for kind in ("TPU v9", "tpu", "cpu"):
+        with pytest.raises(ValueError, match="no peak"):
+            F.peak_flops_per_chip(kind)
+    with pytest.raises(ValueError, match="no peak"):
+        F.peak_flops_per_chip()  # this process's device: the CPU
+    with pytest.raises(ValueError, match="no peak"):
+        F.mfu(1e12, 1.0)
 
 
 # ---- record mechanics -------------------------------------------------------
@@ -105,7 +113,8 @@ def test_profiled_call_compile_execute_split():
     assert not second.first_call and second.compile_s == 0.0
     assert second.dispatch_s > 0 and second.execute_s > 0
     assert second.wall_s >= second.execute_s
-    assert second.tokens_per_s > 0 and second.mfu > 0
+    # the CPU has no peak on record: rates are reported, MFU is not
+    assert second.tokens_per_s > 0 and second.mfu == 0.0
     assert second.step == 1 and second.seq > first.seq
 
 
