@@ -641,8 +641,9 @@ function renderSched(el) {
 }
 
 // --- engine tab: ContinuousEngine flight-recorder snapshots ---
-const ENGINE_PHASES = ["admission", "kv_restore", "prefill", "decode_step",
-                       "token_delivery", "swap_barrier"];
+const ENGINE_PHASES = ["record", "admission", "kv_restore", "prefill",
+                       "decode_step", "token_delivery", "swap_barrier",
+                       "idle_wait"];
 function renderEngine(el) {
   const payload = data.engine || {};
   const engines = payload.engines || [];

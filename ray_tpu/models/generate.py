@@ -52,25 +52,29 @@ def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos):
             f"sequence to shard. 'flash' and 'xla' configs both decode via "
             f"the einsum path (same math; the pallas kernel is a "
             f"long-sequence training implementation).")
-    h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
-    positions = pos + jnp.arange(s)[None, :]  # [1, s] broadcasts over batch
-    positions = jnp.broadcast_to(positions, (b, s))
-    q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
-                   sin, cos, positions)
-    k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd),
-                   sin, cos, positions)
-    v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, pos, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, pos, 0, 0))
-    attn = mha(q, cache_k, cache_v, causal=True, q_offset=pos)
-    x = x + attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
+    # scopes are names only: they group the block's operations in a
+    # device trace (``attn``, ``mlp``) and change nothing that is computed
+    with jax.named_scope("attn"):
+        h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
+        positions = pos + jnp.arange(s)[None, :]  # [1, s] broadcasts over batch
+        positions = jnp.broadcast_to(positions, (b, s))
+        q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
+                       sin, cos, positions)
+        k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd),
+                       sin, cos, positions)
+        v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, pos, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, pos, 0, 0))
+        attn = mha(q, cache_k, cache_v, causal=True, q_offset=pos)
+        x = x + attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
 
-    if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
-        x = llama.ffn_half(cfg, x, layer)
-    else:  # MoE FFN: drop-free inference routing (shared ffn_half)
-        from ray_tpu.models import moe
+    with jax.named_scope("mlp"):
+        if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
+            x = llama.ffn_half(cfg, x, layer)
+        else:  # MoE FFN: drop-free inference routing (shared ffn_half)
+            from ray_tpu.models import moe
 
-        x, _ = moe.ffn_half(cfg, x, layer, drop_free=True)
+            x, _ = moe.ffn_half(cfg, x, layer, drop_free=True)
     return x, cache_k, cache_v
 
 
@@ -94,12 +98,13 @@ def _forward_with_cache(params: Params, tokens: jax.Array,
 
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
-    if last_only:
-        x = x[:, -1:, :]
-    x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cdt)
-    logits = (x @ head).astype(jnp.float32)
+    with jax.named_scope("head_sample"):
+        if last_only:
+            x = x[:, -1:, :]
+        x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(cdt)
+        logits = (x @ head).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

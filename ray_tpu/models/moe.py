@@ -106,44 +106,52 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
     C = max(1, int(cfg.capacity_factor * G * K / E))
     tokens = h.reshape(G, d)
 
-    logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                       # [G, E]
-    topk_probs, topk_idx = jax.lax.top_k(probs, K)                # [G, K]
-    # renormalize the selected gates (Mixtral convention)
-    topk_probs = topk_probs / (topk_probs.sum(-1, keepdims=True) + 1e-9)
+    # scopes are names only: they group the layer's operations in a
+    # device trace and change nothing that is computed
+    with jax.named_scope("moe_router"):
+        logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                       # [G, E]
+        topk_probs, topk_idx = jax.lax.top_k(probs, K)                # [G, K]
+        # renormalize the selected gates (Mixtral convention)
+        topk_probs = topk_probs / (topk_probs.sum(-1, keepdims=True) + 1e-9)
 
-    # capacity slots: position of each token within its expert's queue,
-    # counted over the flattened [K, G] selection order
-    sel_onehot = jax.nn.one_hot(topk_idx, E, dtype=jnp.int32)     # [G, K, E]
-    flat = sel_onehot.transpose(1, 0, 2).reshape(K * G, E)        # [K*G, E]
-    pos_flat = jnp.cumsum(flat, axis=0) - flat                    # slot idx
-    pos = pos_flat.reshape(K, G, E).transpose(1, 0, 2)            # [G, K, E]
-    slot = jnp.sum(pos * sel_onehot, axis=-1)                     # [G, K]
-    keep = slot < C                                               # overflow
+        # capacity slots: position of each token within its expert's queue,
+        # counted over the flattened [K, G] selection order
+        sel_onehot = jax.nn.one_hot(topk_idx, E, dtype=jnp.int32)     # [G, K, E]
+        flat = sel_onehot.transpose(1, 0, 2).reshape(K * G, E)        # [K*G, E]
+        pos_flat = jnp.cumsum(flat, axis=0) - flat                    # slot idx
+        pos = pos_flat.reshape(K, G, E).transpose(1, 0, 2)            # [G, K, E]
+        slot = jnp.sum(pos * sel_onehot, axis=-1)                     # [G, K]
+        keep = slot < C                                               # overflow
+        gates = topk_probs * keep                                      # [G, K]
 
-    gates = topk_probs * keep                                      # [G, K]
-    # dispatch/combine tensors [G, E, C]
-    slot_onehot = jax.nn.one_hot(slot, C, dtype=h.dtype)          # [G, K, C]
-    dispatch = jnp.einsum("gke,gkc->gec",
-                          sel_onehot.astype(h.dtype) * keep[..., None],
-                          slot_onehot)
-    combine = jnp.einsum("gke,gkc,gk->gec",
-                         sel_onehot.astype(h.dtype), slot_onehot,
-                         gates.astype(h.dtype))
+    with jax.named_scope("moe_dispatch"):
+        # dispatch/combine tensors [G, E, C]
+        slot_onehot = jax.nn.one_hot(slot, C, dtype=h.dtype)          # [G, K, C]
+        dispatch = jnp.einsum("gke,gkc->gec",
+                              sel_onehot.astype(h.dtype) * keep[..., None],
+                              slot_onehot)
+        combine = jnp.einsum("gke,gkc,gk->gec",
+                             sel_onehot.astype(h.dtype), slot_onehot,
+                             gates.astype(h.dtype))
+        expert_in = jnp.einsum("gd,gec->ecd", tokens, dispatch)       # [E, C, d]
 
-    expert_in = jnp.einsum("gd,gec->ecd", tokens, dispatch)       # [E, C, d]
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
-                                  layer["e_gate"].astype(h.dtype)))
-    up = jnp.einsum("ecd,edf->ecf", expert_in,
-                    layer["e_up"].astype(h.dtype))
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up,
-                            layer["e_down"].astype(h.dtype))
-    out = jnp.einsum("ecd,gec->gd", expert_out, combine)
+    with jax.named_scope("moe_experts"):
+        gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
+                                      layer["e_gate"].astype(h.dtype)))
+        up = jnp.einsum("ecd,edf->ecf", expert_in,
+                        layer["e_up"].astype(h.dtype))
+        expert_out = jnp.einsum("ecf,efd->ecd", gate * up,
+                                layer["e_down"].astype(h.dtype))
 
-    # Switch aux loss: balance token fraction vs router probability mass
-    frac = jnp.mean(sel_onehot[:, 0, :].astype(jnp.float32), axis=0)  # top-1
-    prob_mean = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac * prob_mean)
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum("ecd,gec->gd", expert_out, combine)
+
+    with jax.named_scope("moe_router"):
+        # Switch aux loss: balance token fraction vs router probability mass
+        frac = jnp.mean(sel_onehot[:, 0, :].astype(jnp.float32), axis=0)  # top-1
+        prob_mean = jnp.mean(probs, axis=0)
+        aux = E * jnp.sum(frac * prob_mean)
     return out.reshape(b, s, d), aux
 
 

@@ -49,6 +49,7 @@ from ray_tpu.models import generate as G
 from ray_tpu.models import llama
 from ray_tpu.util import engine_recorder as _rec
 from ray_tpu.util import prefix_hash as PH
+from ray_tpu.util.recorder_core import span as _span
 
 Params = Dict[str, Any]
 
@@ -334,8 +335,12 @@ class ContinuousBatcher:
         self._keys = np.zeros((max_slots, 2), np.uint32)
         # set by every submit_ex: admission telemetry the engine reads
         # (cached_tokens rides the request span; TTFT-collapse evidence;
-        # kv_restore_s/prefill_s feed the flight recorder's tick phases)
+        # admission_s/kv_restore_s/prefill_s are the spans the call was
+        # made of and feed the flight recorder's tick phases)
         self.last_admission: Dict[str, Any] = {}
+        # set by every step_many that launched: the wall of its three
+        # spans (decode_stage, decode_launch, decode_book), in seconds
+        self.last_step: Dict[str, float] = {}
 
     # -- admission --------------------------------------------------------
 
@@ -364,50 +369,56 @@ class ContinuousBatcher:
         depends only on tokens <= i and every op is row-independent), so
         warm output is token-exact vs a cold prefill (asserted in
         tests/test_zz_kv_cache.py)."""
-        if not self._free:
-            raise RuntimeError("no free slots")
-        s = len(prompt)
-        if s + max_new_tokens + 1 > self.max_len:
-            raise ValueError(f"prompt {s} + new {max_new_tokens} exceeds "
-                             f"max_len {self.max_len}")
-        if (temperature > 0 or top_k > 0) and not self.sampling:
-            raise ValueError(
-                "sampling request on a greedy engine: construct the "
-                "batcher/engine with sampling=True")
-        slot = self._free.pop()
-        prompt_arr = np.asarray(prompt, np.int32)
+        ph: Dict[str, float] = {}
+        with _span("admission", ph):
+            if not self._free:
+                raise RuntimeError("no free slots")
+            s = len(prompt)
+            if s + max_new_tokens + 1 > self.max_len:
+                raise ValueError(f"prompt {s} + new {max_new_tokens} "
+                                 f"exceeds max_len {self.max_len}")
+            if (temperature > 0 or top_k > 0) and not self.sampling:
+                raise ValueError(
+                    "sampling request on a greedy engine: construct the "
+                    "batcher/engine with sampling=True")
+            slot = self._free.pop()
+            prompt_arr = np.asarray(prompt, np.int32)
         cached = 0
-        t_kv0 = time.perf_counter()
-        hit = (self.prefix_cache.lookup(prompt_arr)
-               if self.prefix_cache is not None else None)
-        kv_restore_s = 0.0
+        pages = None
         try:
-            if hit is not None:
-                cached, pk, pv = hit
-                fn = _compiled_cached_prefill(
-                    self.cfg, cached, s - cached, self.max_slots,
-                    self.max_len, self.sampling)
-                args = (self.params, self._ck, self._cv,
-                        jnp.asarray(pk), jnp.asarray(pv),
-                        jnp.asarray(prompt_arr[cached:])[None, :], slot)
+            if self.prefix_cache is not None:
                 # warm admission's restore cost: the lookup + uploading
                 # the retained pages (the compiled call scatters them)
-                kv_restore_s = time.perf_counter() - t_kv0
-            else:
-                fn = _compiled_slot_prefill(self.cfg, s, self.max_slots,
-                                            self.max_len, self.sampling)
-                args = (self.params, self._ck, self._cv,
-                        jnp.asarray(prompt_arr)[None, :], slot)
-            t_pf0 = time.perf_counter()
-            if self.sampling:
-                key0 = jnp.asarray(
-                    np.asarray(jax.random.PRNGKey(int(seed)), np.uint32))
-                self._ck, self._cv, first, new_key = fn(
-                    *args, jnp.float32(temperature), jnp.int32(top_k),
-                    key0)
-            else:
-                self._ck, self._cv, first = fn(*args)
-            prefill_s = time.perf_counter() - t_pf0
+                with _span("kv_restore", ph):
+                    hit = self.prefix_cache.lookup(prompt_arr)
+                    if hit is not None:
+                        cached, pk, pv = hit
+                        pages = (jnp.asarray(pk), jnp.asarray(pv))
+            # staging, the compiled call AND the host read of the first
+            # token: the read is the fence, so the device's prefill time
+            # is inside this span and not in the bookkeeping after it
+            with _span("prefill", ph):
+                if pages is not None:
+                    fn = _compiled_cached_prefill(
+                        self.cfg, cached, s - cached, self.max_slots,
+                        self.max_len, self.sampling)
+                    args = (self.params, self._ck, self._cv, *pages,
+                            jnp.asarray(prompt_arr[cached:])[None, :], slot)
+                else:
+                    fn = _compiled_slot_prefill(
+                        self.cfg, s, self.max_slots, self.max_len,
+                        self.sampling)
+                    args = (self.params, self._ck, self._cv,
+                            jnp.asarray(prompt_arr)[None, :], slot)
+                if self.sampling:
+                    key0 = jnp.asarray(np.asarray(
+                        jax.random.PRNGKey(int(seed)), np.uint32))
+                    self._ck, self._cv, first, new_key = fn(
+                        *args, jnp.float32(temperature), jnp.int32(top_k),
+                        key0)
+                else:
+                    self._ck, self._cv, first = fn(*args)
+                first_tok = int(first[0])
         except BaseException:
             # a failed prefill must not leak the slot: callers (the
             # engine's admit loop) catch and continue, and a leaked slot
@@ -415,25 +426,27 @@ class ContinuousBatcher:
             # to zero capacity with no recovery path
             self._free.append(slot)
             raise
-        req = _Request(next(self._ids), slot, max_new_tokens, prompt_arr)
-        first_tok = int(first[0])
-        req.tokens.append(first_tok)
-        req.remaining -= 1
-        self._cur[slot] = first_tok
-        self._pos[slot] = s
-        if self.sampling:
-            self._temp[slot] = temperature
-            self._topk[slot] = top_k
-            self._keys[slot] = np.asarray(new_key)
+        with _span("admission", ph):
+            req = _Request(next(self._ids), slot, max_new_tokens, prompt_arr)
+            req.tokens.append(first_tok)
+            req.remaining -= 1
+            self._cur[slot] = first_tok
+            self._pos[slot] = s
+            if self.sampling:
+                self._temp[slot] = temperature
+                self._topk[slot] = top_k
+                self._keys[slot] = np.asarray(new_key)
+            done = req.remaining <= 0
+            if done:
+                self._capture(slot, req)
+                self._free.append(slot)
+            else:
+                self._active[slot] = req
         self.last_admission = {"cached_tokens": cached, "prompt_tokens": s,
-                               "slot": slot, "kv_restore_s": kv_restore_s,
-                               "prefill_s": prefill_s}
-        done = req.remaining <= 0
-        if done:
-            self._capture(slot, req)
-            self._free.append(slot)
-        else:
-            self._active[slot] = req
+                               "slot": slot,
+                               "admission_s": ph["admission"],
+                               "kv_restore_s": ph.get("kv_restore", 0.0),
+                               "prefill_s": ph["prefill"]}
         return req.req_id, first_tok, done
 
     def _capture(self, slot: int, req: _Request) -> None:
@@ -494,49 +507,57 @@ class ContinuousBatcher:
         """
         if not self._active:
             return []
-        slots = sorted(self._active)
-        n = len(slots)
-        # two buckets only — a lone row or the full engine: K-fusion
-        # already amortizes dispatch, so finer occupancy buckets buy
-        # little compute but each costs a warmup compile (~seconds);
-        # the lone-straggler case is the one worth its own program
-        bucket = 1 if n == 1 else self.max_slots
-        # pad with a repeat of the first active slot: the duplicate
-        # rows compute the SAME update from the same inputs, so the
-        # duplicate scatter writes identical values (deterministic)
-        idx = np.asarray(slots + [slots[0]] * (bucket - n), np.int32)
-        fn = _compiled_bucket_scan(self.cfg, bucket, self.max_slots,
-                                   self.max_len, k, self.sampling)
-        if self.sampling:
-            self._ck, self._cv, toks, new_keys = fn(
-                self.params, self._ck, self._cv,
-                jnp.asarray(self._cur[idx]), jnp.asarray(self._pos[idx]),
-                jnp.asarray(idx), jnp.asarray(self._temp[idx]),
-                jnp.asarray(self._topk[idx]), jnp.asarray(self._keys[idx]))
-            # duplicate padding rows carry the same key and compute the
-            # same split chain, so the repeated write is identical
-            self._keys[idx] = np.asarray(new_keys)
-        else:
-            self._ck, self._cv, toks = fn(
-                self.params, self._ck, self._cv,
-                jnp.asarray(self._cur[idx]), jnp.asarray(self._pos[idx]),
-                jnp.asarray(idx))
-        toks = np.asarray(toks)  # [k, bucket]
-        out = []
-        for j, slot in enumerate(slots):
-            req = self._active[slot]
-            take = min(k, req.remaining)
-            mine = [int(t) for t in toks[:take, j]]
-            req.tokens.extend(mine)
-            req.remaining -= take
-            self._cur[slot] = mine[-1]
-            self._pos[slot] += take
-            done = req.remaining <= 0
-            if done:
-                self._capture(slot, req)
-                del self._active[slot]
-                self._free.append(slot)
-            out.append((req.req_id, mine, done))
+        parts = self.last_step = {}
+        with _span("decode_stage", parts):
+            slots = sorted(self._active)
+            n = len(slots)
+            # two buckets only — a lone row or the full engine: K-fusion
+            # already amortizes dispatch, so finer occupancy buckets buy
+            # little compute but each costs a warmup compile (~seconds);
+            # the lone-straggler case is the one worth its own program
+            bucket = 1 if n == 1 else self.max_slots
+            # pad with a repeat of the first active slot: the duplicate
+            # rows compute the SAME update from the same inputs, so the
+            # duplicate scatter writes identical values (deterministic)
+            idx = np.asarray(slots + [slots[0]] * (bucket - n), np.int32)
+            fn = _compiled_bucket_scan(self.cfg, bucket, self.max_slots,
+                                       self.max_len, k, self.sampling)
+            args = (jnp.asarray(self._cur[idx]), jnp.asarray(self._pos[idx]),
+                    jnp.asarray(idx))
+            if self.sampling:
+                args += (jnp.asarray(self._temp[idx]),
+                         jnp.asarray(self._topk[idx]),
+                         jnp.asarray(self._keys[idx]))
+        # the compiled call through the host read of its tokens: the
+        # device is busy under this span and idle outside it
+        with _span("decode_launch", parts, k=k, bucket=bucket, active=n):
+            self._ck, self._cv, toks, *new_keys = fn(
+                self.params, self._ck, self._cv, *args)
+            toks = np.asarray(toks)  # [k, bucket]
+            if self.sampling:
+                # duplicate padding rows carry the same key and compute
+                # the same split chain, so the repeated write is identical
+                self._keys[idx] = np.asarray(new_keys[0])
+        with _span("decode_book", parts):
+            out = []
+            for j, slot in enumerate(slots):
+                req = self._active[slot]
+                take = min(k, req.remaining)
+                mine = [int(t) for t in toks[:take, j]]
+                req.tokens.extend(mine)
+                req.remaining -= take
+                self._cur[slot] = mine[-1]
+                self._pos[slot] += take
+                done = req.remaining <= 0
+                if done:
+                    self._capture(slot, req)
+                    del self._active[slot]
+                    self._free.append(slot)
+                out.append((req.req_id, mine, done))
+            # freeing the staged device inputs is host time between two
+            # launches too: done here, it is booked; left to the frame's
+            # teardown it would fall between the spans
+            del args, toks
         return out
 
     @property
@@ -613,7 +634,7 @@ _STREAM_END = None  # sentinel a token stream's queue yields when done
 class _EngineRequest:
     __slots__ = ("prompt", "max_new_tokens", "out", "on_token", "req_id",
                  "cancelled", "temperature", "top_k", "seed",
-                 "cached_tokens", "t_submit", "obs_ctx")
+                 "t_submit", "obs_ctx")
 
     def __init__(self, prompt: np.ndarray, max_new_tokens: int,
                  on_token: Optional[Callable[[Optional[int]], None]] = None,
@@ -625,7 +646,6 @@ class _EngineRequest:
         self.temperature = temperature
         self.top_k = top_k
         self.seed = seed
-        self.cached_tokens: Optional[int] = None  # set at admission
         self.t_submit = time.time()  # queue-wait starts here
         # ambient serve span context ({request_id, span_id}), when the
         # submitter rode a serve request — the flight recorder parents
@@ -733,10 +753,14 @@ class ContinuousEngine:
                                              max_slots=max_slots)
         # engine-thread-confined tick state (never touched off-thread):
         # end of the previous decode launch (the tick-gap anchor; reset
-        # to None when the engine goes idle) and the wall spent applying
-        # a weight swap since the last recorded tick
+        # to None when the engine goes idle), and the tick being
+        # gathered: seconds by phase since the last recorded tick, and
+        # when that one closed on both clocks. Every span of the loop
+        # adds to ``_ph``, so the ticks tile the thread's time.
         self._last_decode_end: Optional[float] = None
-        self._tick_swap_s = 0.0
+        self._ph: Dict[str, float] = {}
+        self._t_tick0 = time.perf_counter()
+        self._t_wall0 = time.time()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="rt-cb-engine")
         self._thread.start()
@@ -854,6 +878,12 @@ class ContinuousEngine:
             out["recorder"] = self._recorder.summary()
         return out
 
+    def note_pump_lag(self, lag_s: float) -> None:
+        """A ``submit_cb`` consumer that forwards bursts to another
+        thread reports here how long after the engine's callback the
+        burst was picked up there (the flight recorder's ``pump_lag``)."""
+        self._recorder.pump_lag(lag_s)
+
     def kv_stats(self) -> Optional[Dict[str, Any]]:
         """Prefix-cache counters WITHOUT touching the engine lock (the
         cache has its own): the per-tick metric publisher reads this —
@@ -942,7 +972,7 @@ class ContinuousEngine:
 
     # -- the engine thread ------------------------------------------------
 
-    def _admit_all(self) -> Dict[str, Any]:
+    def _admit_all(self) -> int:
         """Prefill pending requests into free slots. The jax prefill —
         which can hide a multi-second XLA compile for a new prompt
         length — runs OUTSIDE the lock, so submit/cancel/stats/
@@ -950,35 +980,38 @@ class ContinuousEngine:
         itself is engine-thread-owned and needs no lock); only the
         pending/live bookkeeping is locked.
 
-        Returns the tick's admission accounting for the flight recorder:
-        {kv_restore, prefill, admitted} — the caller attributes its own
-        wall minus these to the ``admission`` phase."""
-        adm = {"kv_restore": 0.0, "prefill": 0.0, "admitted": 0}
+        Returns how many requests it admitted. Its spans (``admission``
+        here, ``kv_restore`` and ``prefill`` inside ``submit_ex``,
+        ``record``) follow one another and add to the tick being
+        gathered."""
+        ph = self._ph
+        admitted = 0
         while True:
-            with self._work:
-                # honor shutdown BEFORE paying another prefill (each can
-                # hide a multi-second compile) — the stopped branch in
-                # _run ends the remaining streams
-                if self._stopped:
-                    return adm
-                if self._pending_swap is not None:
-                    # drain barrier: a queued weight swap holds admission
-                    # (a prefill under the old weights admitted now would
-                    # decode under the new ones after the swap)
-                    return adm
-                if not (self._pending and self._batcher._free):
-                    return adm
-                req = self._pending.popleft()
-                if req.cancelled:
-                    continue
-                self._admitting = req
+            with _span("admission", ph):
+                with self._work:
+                    # honor shutdown BEFORE paying another prefill (each
+                    # can hide a multi-second compile) — the stopped
+                    # branch in _run ends the remaining streams
+                    if self._stopped:
+                        return admitted
+                    if self._pending_swap is not None:
+                        # drain barrier: a queued weight swap holds
+                        # admission (a prefill under the old weights
+                        # admitted now would decode under the new ones
+                        # after the swap)
+                        return admitted
+                    if not (self._pending and self._batcher._free):
+                        return admitted
+                    req = self._pending.popleft()
+                    if req.cancelled:
+                        continue
+                    self._admitting = req
+                t_pop = time.time()  # the wait for a slot ends here
             try:
                 req_id, first_tok, done = self._batcher.submit_ex(
                     req.prompt, req.max_new_tokens,
                     temperature=req.temperature, top_k=req.top_k,
                     seed=req.seed)
-                la = self._batcher.last_admission
-                req.cached_tokens = la.get("cached_tokens", 0)
             except Exception:  # noqa: BLE001 — ONE request's prefill
                 # failing (bad shape, transient XLA error) must fail that
                 # request, not wedge the shared engine thread
@@ -986,69 +1019,75 @@ class ContinuousEngine:
                     self._admitting = None
                 req.emit_many([_STREAM_END])
                 continue
-            with self._work:
-                self._admitting = None
-                req.req_id = req_id
-                cancelled = req.cancelled
-                if cancelled:
-                    # cancelled mid-prefill: free the slot, end the stream
-                    if not done:
-                        self._batcher.cancel(req_id)
-                    req.emit_many([_STREAM_END])
-                else:
-                    self._admitted += 1
-                    req.emit_many([first_tok, _STREAM_END] if done
-                                  else [first_tok])
-                    self._tokens_out += 1
-                    if done:
-                        self._requests_completed += 1
+            la = self._batcher.last_admission
+            for phase in ("admission", "kv_restore", "prefill"):
+                ph[phase] = ph.get(phase, 0.0) + la[phase + "_s"]
+            with _span("admission", ph):
+                with self._work:
+                    self._admitting = None
+                    req.req_id = req_id
+                    cancelled = req.cancelled
+                    if cancelled:
+                        # cancelled mid-prefill: free the slot, end the
+                        # stream
+                        if not done:
+                            self._batcher.cancel(req_id)
+                        req.emit_many([_STREAM_END])
                     else:
-                        self._live[req_id] = req
+                        self._admitted += 1
+                        req.emit_many([first_tok, _STREAM_END] if done
+                                      else [first_tok])
+                        self._tokens_out += 1
+                        if done:
+                            self._requests_completed += 1
+                        else:
+                            self._live[req_id] = req
+            admitted += 1
             # lifecycle record, OUTSIDE the engine lock: admission just
             # produced the first token, so this stamp is the TTFT stamp
-            adm["kv_restore"] += la.get("kv_restore_s", 0.0)
-            adm["prefill"] += la.get("prefill_s", 0.0)
-            adm["admitted"] += 1
-            now = time.time()
-            self._recorder.request_admitted(
-                req_id, t_submit=req.t_submit, t_admit=now,
-                prompt_tokens=len(req.prompt),
-                cached_tokens=req.cached_tokens or 0,
-                prefill_s=la.get("prefill_s", 0.0),
-                kv_restore_s=la.get("kv_restore_s", 0.0),
-                slot=la.get("slot", -1), obs_ctx=req.obs_ctx)
-            if cancelled:
-                self._recorder.request_done(req_id, t=now,
-                                            state="cancelled")
-            elif done:
-                self._recorder.request_done(req_id, t=now, state="done")
+            with _span("record", ph):
+                now = time.time()
+                self._recorder.request_admitted(
+                    req_id, t_submit=req.t_submit, t_admit=now,
+                    t_admit_start=t_pop,
+                    prompt_tokens=len(req.prompt),
+                    cached_tokens=la["cached_tokens"],
+                    prefill_s=la["prefill_s"],
+                    kv_restore_s=la["kv_restore_s"],
+                    slot=la["slot"], obs_ctx=req.obs_ctx)
+                if cancelled:
+                    self._recorder.request_done(req_id, t=now,
+                                                state="cancelled")
+                elif done:
+                    self._recorder.request_done(req_id, t=now, state="done")
 
     def _maybe_swap_locked(self) -> None:
         """Apply a queued weight swap once the engine is fully drained
-        (no active slots, no prefill in flight). Caller holds _work."""
+        (no active slots, no prefill in flight). Caller holds _work and
+        has no span open: the apply is the ``swap_barrier`` span (drain
+        time shows up as the preceding ticks' shrinking active counts,
+        not here)."""
         if (self._pending_swap is None or self._live
                 or self._admitting is not None):
             return
-        t_swap0 = time.perf_counter()
-        params, waiters = self._pending_swap
-        self._pending_swap = None
-        self._batcher.params = params
-        if self._batcher.prefix_cache is not None:
-            # every retained page was computed under the OLD weights: a
-            # post-swap prefill restoring one would emit tokens belonging
-            # to neither model — invalidate the whole cache at the swap
-            self._batcher.prefix_cache.clear()
-        self._weight_swaps += 1
-        apply_s = time.perf_counter() - t_swap0
-        for st in waiters:
-            st["apply_s"] = apply_s
-            st["applied"] = True
-            st["event"].set()
-        # swap-barrier phase: the apply wall (drain time shows up as the
-        # preceding ticks' shrinking active counts, not here); consumed
-        # by the next record_tick (engine-thread-confined accumulator)
-        self._tick_swap_s += apply_s
-        self._recorder.record_swap(apply_s)
+        with _span("swap_barrier", self._ph):
+            t_swap0 = time.perf_counter()
+            params, waiters = self._pending_swap
+            self._pending_swap = None
+            self._batcher.params = params
+            if self._batcher.prefix_cache is not None:
+                # every retained page was computed under the OLD weights:
+                # a post-swap prefill restoring one would emit tokens
+                # belonging to neither model — invalidate the whole cache
+                # at the swap
+                self._batcher.prefix_cache.clear()
+            self._weight_swaps += 1
+            apply_s = time.perf_counter() - t_swap0
+            for st in waiters:
+                st["apply_s"] = apply_s
+                st["applied"] = True
+                st["event"].set()
+            self._recorder.record_swap(apply_s)
 
     def _fail_swap_locked(self, reason: str) -> None:
         """Unblock load_params waiters when the engine stops or dies
@@ -1061,37 +1100,54 @@ class ContinuousEngine:
             st["error"] = reason
             st["event"].set()
 
+    def _close_tick(self, tok_events=(), *, tick: Optional[int] = None,
+                    **fields: Any) -> None:
+        """Record the tick gathered since the last one closed and open
+        the next at this instant. The recorder's own calls and the
+        ``on_tick`` hook are the ``record`` span, the new tick's first."""
+        t_close, ph = time.perf_counter(), self._ph
+        wall_s, t_start = t_close - self._t_tick0, self._t_wall0
+        self._ph, self._t_tick0, self._t_wall0 = {}, t_close, time.time()
+        with _span("record", self._ph):
+            for rid, nburst, done in tok_events:
+                self._recorder.request_tokens(rid, nburst, self._t_wall0,
+                                              done)
+            self._recorder.record_tick(t_start=t_start, wall_s=wall_s,
+                                       phases=ph, **fields)
+            if tick is not None and self._on_tick is not None:
+                try:
+                    self._on_tick(tick, self.max_slots)
+                except Exception:  # noqa: BLE001 — telemetry only
+                    pass
+
     def _run(self) -> None:
+        """The loop, as spans that follow one another and never nest
+        (``recorder_core.span``): each adds its seconds to the tick being
+        gathered and is a ``bench:<phase>`` event on the profiler's
+        clock, so a device trace says what the host did in every gap."""
         rec = self._recorder
         while True:
-            t_tick0 = time.perf_counter()
-            t_wall0 = time.time()
-            with self._work:
-                # reap cancellations before admitting into their slots
-                doomed = [rid for rid, r in self._live.items()
-                          if r.cancelled]
+            with _span("admission", self._ph):
+                with self._work:
+                    # reap cancellations before admitting into their slots
+                    doomed = [rid for rid, r in self._live.items()
+                              if r.cancelled]
+                    for rid in doomed:
+                        self._live[rid].emit_many([_STREAM_END])
+                        del self._live[rid]
+                # slot free + KV capture OUTSIDE the lock: _capture syncs
+                # the device and copies the slot's pages to host — under
+                # _work that stall would block every submit/cancel (the
+                # batcher itself is engine-thread-confined, like
+                # step_many). Captures must land BEFORE the swap check: a
+                # swap clears the cache, and a doomed slot's pages are
+                # old-weight poison the moment it applies.
                 for rid in doomed:
-                    self._live[rid].emit_many([_STREAM_END])
-                    del self._live[rid]
-            # slot free + KV capture OUTSIDE the lock: _capture syncs
-            # the device and copies the slot's pages to host — under
-            # _work that stall would block every submit/cancel (the
-            # batcher itself is engine-thread-confined, like step_many).
-            # Captures must land BEFORE the swap check: a swap clears
-            # the cache, and a doomed slot's pages are old-weight poison
-            # the moment it applies.
-            for rid in doomed:
-                self._batcher.cancel(rid)
-                rec.request_done(rid, t=t_wall0, state="cancelled")
+                    self._batcher.cancel(rid)
+                    rec.request_done(rid, t=time.time(), state="cancelled")
             with self._work:
                 self._maybe_swap_locked()
-            t_adm0 = time.perf_counter()
-            adm = self._admit_all()
-            # admission phase = this tick's admission wall minus the
-            # batcher-attributed kv-restore/prefill shares (slot
-            # bookkeeping, cancel checks, first-token delivery)
-            adm_phase = max(0.0, (time.perf_counter() - t_adm0)
-                            - adm["kv_restore"] - adm["prefill"])
+            admitted = self._admit_all()
             with self._work:
                 if self._stopped:
                     self._fail_swap_locked("engine shut down mid-drain")
@@ -1104,27 +1160,27 @@ class ContinuousEngine:
                     return
                 if not self._live:
                     self._maybe_swap_locked()
-                    swap_s = self._tick_swap_s
-                    self._tick_swap_s = 0.0
-                    if adm["admitted"] or swap_s > 0.0:
+                    if admitted or self._ph.get("swap_barrier"):
                         # admission-only tick (every admitted request
                         # finished at its first token, or a swap landed)
-                        rec.record_tick(
-                            t_start=t_wall0,
-                            wall_s=time.perf_counter() - t_tick0,
-                            phases={"admission": adm_phase,
-                                    "kv_restore": adm["kv_restore"],
-                                    "prefill": adm["prefill"],
-                                    "swap_barrier": swap_s},
-                            active=0, pending=len(self._pending),
-                            bucket=0, k=0, tokens=adm["admitted"],
-                            admitted=adm["admitted"], gap_s=None)
+                        self._close_tick(
+                            active=0, pending=len(self._pending), bucket=0,
+                            k=0, tokens=admitted, admitted=admitted,
+                            gap_s=None)
                     # engine going idle: the next decode launch starts a
                     # fresh gap baseline (an idle engine is not starved)
                     self._last_decode_end = None
                     if self._pending or self._pending_swap is not None:
                         continue  # freshly unblocked work: no idle wait
-                    self._work.wait(timeout=0.5)
+                    with _span("idle_wait", self._ph):
+                        self._work.wait(timeout=0.5)
+                    if self._pending or self._pending_swap is not None:
+                        # woken for work: the parked stretch is a tick
+                        # of its own, so the next one starts with its
+                        # work and no launch's tick holds a wait
+                        self._close_tick(
+                            active=0, pending=len(self._pending), bucket=0,
+                            k=0, tokens=0, admitted=0, gap_s=None)
                     continue
             # decode OUTSIDE the lock: submit/cancel stay responsive
             # while the step runs (the jax call is the long pole).
@@ -1144,6 +1200,7 @@ class ContinuousEngine:
             gap_s = (t_dec0 - self._last_decode_end
                      if self._last_decode_end is not None else None)
             try:
+                # step_many is three spans of its own (last_step)
                 emitted = self._batcher.step_many(k)
             except Exception as e:  # noqa: BLE001 — a failed decode step
                 # poisons the shared cache state: end every stream NOW
@@ -1162,46 +1219,31 @@ class ContinuousEngine:
                 return
             t_dec1 = time.perf_counter()
             self._last_decode_end = t_dec1
-            tick_tokens = adm["admitted"]
+            self._ph["decode_step"] = t_dec1 - t_dec0
+            tick_tokens = admitted
             tok_events: List[Tuple[int, int, bool]] = []
-            with self._work:
-                self._steps += 1
-                for rid, toks, done in emitted:
-                    req = self._live.get(rid)
-                    if req is None:
-                        continue  # cancelled between step and dispatch
-                    burst = [int(t) for t in toks]
-                    self._tokens_out += len(burst)
-                    tick_tokens += len(burst)
-                    tok_events.append((rid, len(burst), done))
-                    if done:
-                        burst.append(_STREAM_END)
-                        del self._live[rid]
-                        self._requests_completed += 1
-                    req.emit_many(burst)
-                tick, cap = len(self._live), self.max_slots
-                pending_n = len(self._pending)
-            t_emit1 = time.perf_counter()
-            swap_s = self._tick_swap_s
-            self._tick_swap_s = 0.0
-            now = time.time()
-            for rid, nburst, done in tok_events:
-                rec.request_tokens(rid, nburst, now, done)
-            rec.record_tick(
-                t_start=t_wall0, wall_s=t_emit1 - t_tick0,
-                phases={"admission": adm_phase,
-                        "kv_restore": adm["kv_restore"],
-                        "prefill": adm["prefill"],
-                        "decode_step": t_dec1 - t_dec0,
-                        "token_delivery": t_emit1 - t_dec1,
-                        "swap_barrier": swap_s},
-                active=n_active, pending=pending_n, bucket=bucket, k=k,
-                tokens=tick_tokens, admitted=adm["admitted"], gap_s=gap_s)
-            if self._on_tick is not None:
-                try:
-                    self._on_tick(tick, cap)
-                except Exception:  # noqa: BLE001 — telemetry only
-                    pass
+            with _span("token_delivery", self._ph):
+                with self._work:
+                    self._steps += 1
+                    for rid, toks, done in emitted:
+                        req = self._live.get(rid)
+                        if req is None:
+                            continue  # cancelled between step and dispatch
+                        burst = [int(t) for t in toks]
+                        self._tokens_out += len(burst)
+                        tick_tokens += len(burst)
+                        tok_events.append((rid, len(burst), done))
+                        if done:
+                            burst.append(_STREAM_END)
+                            del self._live[rid]
+                            self._requests_completed += 1
+                        req.emit_many(burst)
+                    live_n = len(self._live)
+                    pending_n = len(self._pending)
+            self._close_tick(
+                tok_events, tick=live_n, active=n_active, pending=pending_n,
+                bucket=bucket, k=k, tokens=tick_tokens, admitted=admitted,
+                gap_s=gap_s, decode_parts=self._batcher.last_step)
 
 
 def _row_sample(logits, temp, top_k, sub):
@@ -1245,22 +1287,27 @@ def _compiled_slot_prefill(cfg, s: int, max_slots: int, max_len: int,
                "v": jnp.zeros((cfg.n_layers, 1, max_len, cfg.n_kv_heads,
                                cfg.head_dim), cfg.compute_dtype)}
         logits, row = G._forward_with_cache(params, prompt, cfg, row, 0)
-        first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
-                                  key)
-        ck = jax.lax.dynamic_update_slice(ck, row["k"], (0, slot, 0, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, row["v"], (0, slot, 0, 0, 0))
+        with jax.named_scope("head_sample"):
+            first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
+                                      key)
+        with jax.named_scope("kv_scatter"):
+            ck = jax.lax.dynamic_update_slice(ck, row["k"],
+                                              (0, slot, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, row["v"],
+                                              (0, slot, 0, 0, 0))
         return (ck, cv, first, key) if sample else (ck, cv, first)
 
+    # the program's name in a device trace (``jit_rt_prefill``)
     if sample:
         @jax.jit
-        def run(params, ck, cv, prompt, slot, temp, top_k, key):
+        def rt_prefill(params, ck, cv, prompt, slot, temp, top_k, key):
             return body(params, ck, cv, prompt, slot, temp, top_k, key)
     else:
         @jax.jit
-        def run(params, ck, cv, prompt, slot):
+        def rt_prefill(params, ck, cv, prompt, slot):
             return body(params, ck, cv, prompt, slot)
 
-    return run
+    return rt_prefill
 
 
 @functools.lru_cache(maxsize=256)
@@ -1281,23 +1328,28 @@ def _compiled_cached_prefill(cfg, c: int, sl: int, max_slots: int,
         row = {"k": zk.at[:, 0, :c].set(pk.astype(cfg.compute_dtype)),
                "v": zk.at[:, 0, :c].set(pv.astype(cfg.compute_dtype))}
         logits, row = G._forward_with_cache(params, suffix, cfg, row, c)
-        first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
-                                  key)
-        ck = jax.lax.dynamic_update_slice(ck, row["k"], (0, slot, 0, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, row["v"], (0, slot, 0, 0, 0))
+        with jax.named_scope("head_sample"):
+            first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
+                                      key)
+        with jax.named_scope("kv_scatter"):
+            ck = jax.lax.dynamic_update_slice(ck, row["k"],
+                                              (0, slot, 0, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cv, row["v"],
+                                              (0, slot, 0, 0, 0))
         return (ck, cv, first, key) if sample else (ck, cv, first)
 
     if sample:
         @jax.jit
-        def run(params, ck, cv, pk, pv, suffix, slot, temp, top_k, key):
+        def rt_cached_prefill(params, ck, cv, pk, pv, suffix, slot, temp,
+                              top_k, key):
             return body(params, ck, cv, pk, pv, suffix, slot, temp, top_k,
                         key)
     else:
         @jax.jit
-        def run(params, ck, cv, pk, pv, suffix, slot):
+        def rt_cached_prefill(params, ck, cv, pk, pv, suffix, slot):
             return body(params, ck, cv, pk, pv, suffix, slot)
 
-    return run
+    return rt_cached_prefill
 
 
 def _one_row_step(cfg, sample: bool = False):
@@ -1309,7 +1361,8 @@ def _one_row_step(cfg, sample: bool = False):
         cache = {"k": ck_row[:, None], "v": cv_row[:, None]}
         logits, cache = G._forward_with_cache(
             params, tok[None, None], cfg, cache, pos)
-        nxt = jnp.argmax(logits[0, -1, :]).astype(jnp.int32)
+        with jax.named_scope("head_sample"):
+            nxt = jnp.argmax(logits[0, -1, :]).astype(jnp.int32)
         return cache["k"][:, 0], cache["v"][:, 0], nxt
 
     def one_row_sampled(params, ck_row, cv_row, tok, pos, temp, top_k,
@@ -1317,8 +1370,9 @@ def _one_row_step(cfg, sample: bool = False):
         cache = {"k": ck_row[:, None], "v": cv_row[:, None]}
         logits, cache = G._forward_with_cache(
             params, tok[None, None], cfg, cache, pos)
-        key, sub = jax.random.split(key)
-        nxt = _row_sample(logits[0, -1, :], temp, top_k, sub)
+        with jax.named_scope("head_sample"):
+            key, sub = jax.random.split(key)
+            nxt = _row_sample(logits[0, -1, :], temp, top_k, sub)
         return cache["k"][:, 0], cache["v"][:, 0], nxt, key
 
     return one_row_sampled if sample else one_row
@@ -1339,9 +1393,10 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
 
     if sample:
         @jax.jit
-        def run(params, ck, cv, cur, pos, idx, temp, topk, keys):
-            ck_rows = ck.swapaxes(0, 1)[idx]  # [bucket, L, T, hkv, hd]
-            cv_rows = cv.swapaxes(0, 1)[idx]
+        def rt_decode(params, ck, cv, cur, pos, idx, temp, topk, keys):
+            with jax.named_scope("kv_gather"):
+                ck_rows = ck.swapaxes(0, 1)[idx]  # [bucket, L, T, hkv, hd]
+                cv_rows = cv.swapaxes(0, 1)[idx]
 
             def body(carry, _):
                 ck_r, cv_r, cur, pos, keys = carry
@@ -1352,14 +1407,16 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
 
             (ck_rows, cv_rows, _, _, keys), toks = jax.lax.scan(
                 body, (ck_rows, cv_rows, cur, pos, keys), None, length=k)
-            ck = ck.swapaxes(0, 1).at[idx].set(ck_rows).swapaxes(0, 1)
-            cv = cv.swapaxes(0, 1).at[idx].set(cv_rows).swapaxes(0, 1)
+            with jax.named_scope("kv_scatter"):
+                ck = ck.swapaxes(0, 1).at[idx].set(ck_rows).swapaxes(0, 1)
+                cv = cv.swapaxes(0, 1).at[idx].set(cv_rows).swapaxes(0, 1)
             return ck, cv, toks, keys  # [k, bucket], [bucket, 2]
     else:
         @jax.jit
-        def run(params, ck, cv, cur, pos, idx):
-            ck_rows = ck.swapaxes(0, 1)[idx]  # [bucket, L, T, hkv, hd]
-            cv_rows = cv.swapaxes(0, 1)[idx]
+        def rt_decode(params, ck, cv, cur, pos, idx):
+            with jax.named_scope("kv_gather"):
+                ck_rows = ck.swapaxes(0, 1)[idx]  # [bucket, L, T, hkv, hd]
+                cv_rows = cv.swapaxes(0, 1)[idx]
 
             def body(carry, _):
                 ck_r, cv_r, cur, pos = carry
@@ -1370,8 +1427,9 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
 
             (ck_rows, cv_rows, _, _), toks = jax.lax.scan(
                 body, (ck_rows, cv_rows, cur, pos), None, length=k)
-            ck = ck.swapaxes(0, 1).at[idx].set(ck_rows).swapaxes(0, 1)
-            cv = cv.swapaxes(0, 1).at[idx].set(cv_rows).swapaxes(0, 1)
+            with jax.named_scope("kv_scatter"):
+                ck = ck.swapaxes(0, 1).at[idx].set(ck_rows).swapaxes(0, 1)
+                cv = cv.swapaxes(0, 1).at[idx].set(cv_rows).swapaxes(0, 1)
             return ck, cv, toks  # [k, bucket]
 
-    return run
+    return rt_decode

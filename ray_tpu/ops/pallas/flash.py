@@ -128,6 +128,7 @@ def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, kv_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q_offset, q, k, v)
     return o, lse
 
@@ -240,6 +241,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, kv_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q_offset, q, k, v, do, lse, delta)[0]
 
     # dk/dv: grid walks k blocks outer, q blocks inner.
@@ -261,6 +263,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, kv_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q_offset, q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -910,6 +910,17 @@ def cmd_engine(args: argparse.Namespace) -> int:
                       f"(capacity est {summ.get('capacity_tok_s', 0):.1f})"
                       f"  decode-eff {summ.get('decode_efficiency', 0):.2f}"
                       f"  occupancy {summ.get('occupancy', 0):.2f}")
+            if "queue_p50_s" in summ:
+                front = (f"front-in p50 "
+                         f"{1e3 * summ['front_in_p50_s']:.1f}ms  "
+                         if "front_in_p50_s" in summ else "")
+                print(f"  {front}slot-wait p50 "
+                      f"{1e3 * summ['queue_p50_s']:.1f}ms p90 "
+                      f"{1e3 * summ.get('queue_p90_s', 0):.1f}ms  pump-lag "
+                      f"p99 {1e3 * summ.get('pump_lag_p99_s', 0):.2f}ms max "
+                      f"{1e3 * summ.get('pump_lag_max_s', 0):.1f}ms  "
+                      f"stall excess {summ.get('tick_excess_s', 0):.2f}s "
+                      f"(launch {summ.get('launch_excess_s', 0):.2f}s)")
             print(f"  recorder overhead "
                   f"{100 * summ.get('overhead_frac', 0):.3f}% of tick wall")
         elif args.engine_cmd == "ticks":

@@ -663,7 +663,8 @@ class DeploymentHandle:
             meta["model_id"] = self._model_id
         span_id = obs.new_span_id()
         if req_ctx is not None:
-            meta["request"] = {"request_id": req_ctx["request_id"],
+            # whatever else the ingress stamped (t_ingress) rides along
+            meta["request"] = {**req_ctx,
                                "app": req_ctx.get("app", self.app_name),
                                "route": req_ctx.get("route", "handle"),
                                "span_id": span_id}

@@ -183,58 +183,40 @@ class ContinuousLLM:
         top_k = int(body.get("top_k", 0))
         sample_seed = int(body.get("seed", 0))
         # the request context is ambient here (handle_request runs the
-        # callable under it); the admission span is emitted once the
-        # engine reports how many prompt tokens the prefix cache covered
+        # callable under it) and carries the front's stamps
         req_ctx = obs.current_request_context()
-        t_req = time.time()
         loop = asyncio.get_running_loop()
         aq: "asyncio.Queue" = asyncio.Queue()
+        engine = self.engine
 
-        def deliver(burst):
+        def deliver(burst, t_emit):
             # one loop wakeup per engine TICK (token burst), not per
             # token — and no executor thread parks per stream (the
             # default pool has ~cpu+4 threads; a dozen concurrent
-            # streams would starve it and serialize the whole replica)
+            # streams would starve it and serialize the whole replica).
+            # How long this loop took to pick the burst up is the stream
+            # pump's lag: the first boundary after the engine thread.
+            engine.note_pump_lag(time.perf_counter() - t_emit)
             for tok in burst:
                 aq.put_nowait(tok)
 
-        handle = self.engine.submit_cb(
+        handle = engine.submit_cb(
             prompt, n_new,
-            lambda burst: loop.call_soon_threadsafe(deliver, burst),
+            lambda burst: loop.call_soon_threadsafe(
+                deliver, burst, time.perf_counter()),
             temperature=temperature, top_k=top_k, seed=sample_seed,
             # the flight recorder parents the engine lifecycle span on
             # the serve request span — rt trace <rid> descends into
-            # queue_wait/kv_restore/prefill/decode
+            # queue_wait/kv_restore/prefill/decode, with the cached and
+            # prompt token counts beside them
             obs_ctx=req_ctx)
-        engine = self.engine
-        name = self._name
 
         async def stream():
-            first = True
             try:
                 while True:
                     tok = await aq.get()
                     if tok is None:
                         return
-                    if first:
-                        first = False
-                        if req_ctx is not None:
-                            # cached-token count on the request span: how
-                            # much of THIS prompt's prefill the kv cache
-                            # absorbed (rt trace <rid> shows it next to
-                            # the proxy's ttft phase)
-                            span = obs.new_span_id()
-                            obs.emit_span(
-                                f"serve:{req_ctx['request_id']}:kv:"
-                                f"{span[:8]}",
-                                f"kv:{name}",
-                                request_id=req_ctx["request_id"],
-                                span_id=span,
-                                parent_span_id=req_ctx.get("span_id"),
-                                t_start=t_req, t_end=time.time(),
-                                phases={"cached_tokens": float(
-                                    handle.cached_tokens or 0),
-                                    "prompt_tokens": float(len(prompt))})
                     yield tok
             finally:
                 # client gone mid-stream: free the slot for the next

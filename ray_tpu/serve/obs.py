@@ -39,7 +39,9 @@ from ray_tpu.util import metrics as M
 REQUEST_ID_HEADER = "x-rt-request-id"
 
 # request context: {"request_id", "app", "deployment", "route", "span_id"}
-_request_ctx: "contextvars.ContextVar[Optional[Dict[str, str]]]" = \
+# and, where a hop stamped them, "t_ingress" (HTTP proxy receipt) and
+# "t_replica" (replica entry): time.time() of hops on one host
+_request_ctx: "contextvars.ContextVar[Optional[Dict[str, Any]]]" = \
     contextvars.ContextVar("rt_serve_request_ctx", default=None)
 
 
@@ -62,7 +64,7 @@ def new_span_id() -> str:
     return uuid.uuid4().hex[:16]
 
 
-def current_request_context() -> Optional[Dict[str, str]]:
+def current_request_context() -> Optional[Dict[str, Any]]:
     """The ambient serve request context (None outside a request)."""
     return _request_ctx.get()
 
@@ -74,7 +76,7 @@ def get_serve_request_id() -> Optional[str]:
     return ctx.get("request_id") if ctx else None
 
 
-def activate_request(ctx: Optional[Dict[str, str]]):
+def activate_request(ctx: Optional[Dict[str, Any]]):
     """Make ``ctx`` ambient; returns a token for :func:`deactivate_request`.
 
     Also activates the matching tracing span context so task/actor calls

@@ -270,9 +270,11 @@ class ProxyActor:
         if handle is None:
             handle = DeploymentHandle(app_name, ingress)
             self._handles[key] = handle
+        # t_ingress: receipt on this host's clock, before routing; the
+        # engine recorder books proxy receipt -> engine submit against it
         req_ctx = {"request_id": request_id, "app": app_name,
                    "deployment": ingress, "route": route,
-                   "span_id": obs.new_span_id()}
+                   "span_id": obs.new_span_id(), "t_ingress": t_epoch}
         if (request.headers.get("Upgrade", "").lower() == "websocket"
                 and request.method == "GET"):
             # websockets are ingress traffic too: count the connection and

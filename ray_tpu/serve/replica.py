@@ -228,6 +228,7 @@ class ReplicaActor:
         """Returns ("ok", result, loaded_model_ids, kv_residency),
         ("stream", stream_id, loaded_model_ids, kv_residency) for
         generator results, or (REJECTED, ongoing_count)."""
+        t_replica = time.time()  # entry, before the wait for an executor
         # websocket inbound frames bypass admission control: the
         # connection's __ws_connect__ stream already holds a slot, and
         # rejecting its own frames would wedge every connection on a
@@ -257,11 +258,11 @@ class ReplicaActor:
                 # THIS request's trace: make the context ambient before the
                 # contextvars copy below snapshots it
                 req_token = obs.activate_request({
-                    "request_id": req["request_id"],
+                    **req,
                     "app": req.get("app", self._app),
                     "deployment": self._deployment,
                     "route": req.get("route", ""),
-                    "span_id": replica_span})
+                    "span_id": replica_span, "t_replica": t_replica})
             token = _current_model_id.set((meta or {}).get("model_id", ""))
             t_epoch, t0 = time.time(), time.perf_counter()
             exec_mark = [t0]  # executor thread stamps user-code start

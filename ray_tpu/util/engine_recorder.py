@@ -9,17 +9,30 @@ stamps bounded, lock-light records on every tick and every request, and a
 separate drain thread ships the derived telemetry everywhere the other
 planes already live.
 
-What one TICK record holds — a partition of the tick's wall into the
-phases the loop actually runs (``models/serving.py`` stamps them):
+What one TICK record holds — a partition of the engine thread's wall,
+from the end of one tick's token delivery to the end of the next, into the
+phases the loop actually runs. ``models/serving.py`` stamps each with
+``recorder_core.span``, so the same extents are ``bench:<phase>`` events on
+the profiler's clock while a device trace runs:
 
-  admission       slot bookkeeping around admitting pending requests
-                  (queue pop, cancel checks, emit of the first token)
+  admission       reap of cancellations, swap check, queue pop, slot
+                  bookkeeping, emit of the first token
   kv_restore      prefix-cache lookup + retained-page upload for warm
                   admissions (the TTFT-collapse path)
-  prefill         the compiled prefill call for the uncached suffix
-  decode_step     the fused ``step_many(k)`` launch across active slots
+  prefill         staging the prompt, the compiled prefill call AND the
+                  host read of its first token (the fence: the device is
+                  busy under this phase, not under ``admission``)
+  decode_step     the fused ``step_many(k)`` launch across active slots;
+                  ``decode_parts`` beside it splits the same wall into
+                  decode_stage (index build + host->device uploads),
+                  decode_launch (compiled call through the token read:
+                  the device is busy) and decode_book (per-slot
+                  bookkeeping after the read, KV capture included)
   token_delivery  handing each tick's token bursts to their consumers
   swap_barrier    applying a drain-barrier weight swap, when one landed
+  record          the recorder's own calls and the ``on_tick`` hook
+  idle_wait       parked on the condition variable: no live request (a
+                  parked stretch is a tick of its own, closed on waking)
 
 plus active-slot count, the bucket the decode launch compiled for
 (lone-row vs full-engine), the k-step fusion stride, and the decode
@@ -28,9 +41,15 @@ active — the single number that spikes when a long-prompt prefill (or
 anything else) starves decode, and the diagnostic baseline the
 prefill/decode disaggregation arc is judged against.
 
-What one REQUEST record holds: queue-wait, cached-vs-computed prefill
+What one REQUEST record holds: ``queue_s`` (submit -> popped for
+admission: waited for a slot), ``queue_wait_s`` (submit -> first token; the
+name is older than the split and is TTFT), cached-vs-computed prefill
 tokens (from the batcher's ``last_admission``), decode ticks, TTFT, TPOT,
-and the terminal state (done / cancelled). Requests submitted under an
+the terminal state (done / cancelled) and, for a request that came through
+the HTTP proxy, ``front_in_s`` (proxy receipt -> engine submit) and
+``replica_in_s`` (replica entry -> engine submit). The stream pump reports
+per burst how long the replica's event loop took to pick it up
+(``pump_lag``). Requests submitted under an
 ambient serve request context JOIN the request span tree: the drain emits
 an ``engine:<name>`` span parented on the serve span, so ``rt trace
 <request-id>`` descends from proxy→replica into engine phases.
@@ -63,7 +82,7 @@ from __future__ import annotations
 import os
 import time
 from collections import OrderedDict, deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from ray_tpu.util.recorder_core import (RecorderCore, RecorderRegistry,
                                         pct as _pct)
@@ -77,8 +96,10 @@ _KV_PREFIX = "@engine/"
 
 #: canonical tick-phase vocabulary, in tick-loop order (the timeline
 #: tick lane and ``rt engine ticks`` render phases in this order)
-TICK_PHASES = ("admission", "kv_restore", "prefill", "decode_step",
-               "token_delivery", "swap_barrier")
+TICK_PHASES = ("record", "admission", "kv_restore", "prefill", "decode_step",
+               "token_delivery", "swap_barrier", "idle_wait")
+#: the three spans ``step_many`` splits ``decode_step``'s wall into
+DECODE_PARTS = ("decode_stage", "decode_launch", "decode_book")
 
 _REGISTRY = RecorderRegistry()
 
@@ -125,6 +146,11 @@ class EngineRecorder(RecorderCore):
         self._done: "deque[Dict[str, Any]]" = deque(maxlen=cap)  # rt: guarded-by(_lock)
         self._window: "deque[Dict[str, Any]]" = \
             deque(maxlen=_SLO_WINDOW)  # rt: guarded-by(_lock)
+        # (wall time, lag) per delivered burst: as many as the tick ring's
+        # launches can hold at full occupancy
+        self._pump: "deque[Tuple[float, float]]" = \
+            deque(maxlen=cap * self.max_slots)  # rt: guarded-by(_lock)
+        self._overhead_tick_s = 0.0  # rt: guarded-by(_lock)
         self._tick_seq = 0  # rt: guarded-by(_lock)
         self._req_seq = 0  # rt: guarded-by(_lock)
         self._swaps = 0  # rt: guarded-by(_lock)
@@ -141,13 +167,24 @@ class EngineRecorder(RecorderCore):
 
     # -- tick path (engine thread) ---------------------------------------
 
+    def _charge_locked(self, t0: float) -> None:
+        """Book a recorder call's own wall: to the lifetime total and to
+        the tick being gathered. Caller holds ``_lock``."""
+        dt = time.perf_counter() - t0
+        self._overhead_s += dt
+        self._overhead_tick_s += dt
+
     def record_tick(self, *, t_start: float, wall_s: float,
                     phases: Dict[str, float], active: int, pending: int,
                     bucket: int, k: int, tokens: int, admitted: int,
-                    gap_s: Optional[float]) -> None:
+                    gap_s: Optional[float],
+                    decode_parts: Optional[Dict[str, float]] = None
+                    ) -> None:
         """One engine tick: phase partition + the decode tick-gap. The
         ONLY thing this does is append to a bounded deque — no metrics,
-        no I/O (drained off-thread)."""
+        no I/O (drained off-thread). ``decode_parts`` is ``decode_step``'s
+        wall again, split three ways; it stays out of ``phases`` so that
+        they still sum to the tick."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
@@ -158,27 +195,42 @@ class EngineRecorder(RecorderCore):
                "k": k, "tokens": tokens, "admitted": admitted}
         if gap_s is not None:
             rec["gap_s"] = gap_s
+        if decode_parts:
+            rec["decode_parts"] = {p: decode_parts.get(p, 0.0)
+                                   for p in DECODE_PARTS}
         with self._lock:
             self._tick_seq += 1
             rec["seq"] = self._tick_seq
+            # recorder calls since the last tick; this call's own wall
+            # is the next tick's first entry
+            rec["overhead_s"] = self._overhead_tick_s
+            self._overhead_tick_s = 0.0
             self._ticks.append(rec)
             self._wall_total_s += wall_s
-            self._overhead_s += time.perf_counter() - t0
+            self._charge_locked(t0)
         self._ensure_drainer()
 
     def request_admitted(self, rid: int, *, t_submit: float, t_admit: float,
                          prompt_tokens: int, cached_tokens: int,
                          prefill_s: float, kv_restore_s: float,
                          slot: int = -1,
-                         obs_ctx: Optional[Dict[str, str]] = None) -> None:
-        """Lifecycle start: admission produced the first token, so this
-        stamp IS the TTFT stamp (queue_wait = admission - submit)."""
+                         t_admit_start: Optional[float] = None,
+                         obs_ctx: Optional[Dict[str, Any]] = None) -> None:
+        """Lifecycle start: admission produced the first token, so
+        ``t_admit`` IS the TTFT stamp (queue_wait = admission - submit, a
+        name kept for its readers). ``t_admit_start`` is when the engine
+        popped the request for admission: ``queue_s`` is the wait for a
+        slot alone. A context stamped by the HTTP proxy (``t_ingress``)
+        and the replica (``t_replica``), all on one host's ``time.time()``,
+        gives the front's share per request."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
         rec = {"rid": rid, "t_submit": t_submit, "t_admit": t_admit,
                "t_first": t_admit, "queue_wait_s": max(0.0,
                                                        t_admit - t_submit),
+               "queue_s": max(0.0, (t_admit if t_admit_start is None
+                                    else t_admit_start) - t_submit),
                "prompt_tokens": int(prompt_tokens),
                "cached_tokens": int(cached_tokens),
                "computed_tokens": int(prompt_tokens) - int(cached_tokens),
@@ -188,12 +240,16 @@ class EngineRecorder(RecorderCore):
         if obs_ctx:
             rec["request_id"] = obs_ctx.get("request_id")
             rec["parent_span_id"] = obs_ctx.get("span_id")
+            for key, stamp in (("front_in_s", "t_ingress"),
+                               ("replica_in_s", "t_replica")):
+                if stamp in obs_ctx:
+                    rec[key] = max(0.0, t_submit - obs_ctx[stamp])
         with self._lock:
             self._requests_total += 1
             self._active[rid] = rec
             while len(self._active) > self._done.maxlen:
                 self._active.popitem(last=False)  # runaway-leak backstop
-            self._overhead_s += time.perf_counter() - t0
+            self._charge_locked(t0)
 
     def request_tokens(self, rid: int, n: int, t: float,
                        done: bool = False) -> None:
@@ -207,7 +263,7 @@ class EngineRecorder(RecorderCore):
                 rec["tokens"] += n
                 rec["decode_ticks"] += 1
                 rec["t_last"] = t
-            self._overhead_s += time.perf_counter() - t0
+            self._charge_locked(t0)
         if done:
             self.request_done(rid, t=t, state="done")
 
@@ -221,7 +277,7 @@ class EngineRecorder(RecorderCore):
         with self._lock:
             rec = self._active.pop(rid, None)
             if rec is None:
-                self._overhead_s += time.perf_counter() - t0
+                self._charge_locked(t0)
                 return
             rec["state"] = state
             rec["t_done"] = t
@@ -233,13 +289,21 @@ class EngineRecorder(RecorderCore):
             rec["seq"] = self._req_seq
             self._done.append(rec)
             if state == "done":
-                self._window.append({"t": t, "ttft_s": rec["ttft_s"],
-                                     "tpot_s": rec["tpot_s"],
-                                     "tokens": n,
-                                     "decode_ticks": rec["decode_ticks"]})
+                self._window.append(_window_entry(rec))
             else:
                 self._cancelled_total += 1
-            self._overhead_s += time.perf_counter() - t0
+            self._charge_locked(t0)
+
+    def pump_lag(self, lag_s: float) -> None:
+        """One burst crossed from the engine thread to its consumer's
+        event loop ``lag_s`` after ``emit_many`` handed it over (called
+        on that loop: the first boundary after the engine thread)."""
+        if not self.enabled:
+            return
+        t0 = time.perf_counter()
+        with self._lock:
+            self._pump.append((time.time(), max(0.0, lag_s)))
+            self._charge_locked(t0)
 
     def record_swap(self, apply_s: float, drained_reqs: int = 0) -> None:
         if not self.enabled:
@@ -280,13 +344,14 @@ class EngineRecorder(RecorderCore):
         with self._lock:
             ticks = list(self._ticks)
             window = list(self._window)
+            pump = list(self._pump)
             active = len(self._active)
             base = {"requests_total": self._requests_total,
                     "cancelled_total": self._cancelled_total,
                     "swaps": self._swaps, "ticks_total": self._tick_seq}
             if self._last_swap is not None:
                 base["last_swap"] = dict(self._last_swap)
-        out = self._aggregate(ticks, window)
+        out = self._aggregate(ticks, window, [lag for _, lag in pump])
         out.update(base)
         out["name"] = self.name
         out["active"] = active
@@ -301,17 +366,19 @@ class EngineRecorder(RecorderCore):
         the bench legs carve steady/burst/recovery windows with this."""
         with self._lock:
             ticks = [t for t in self._ticks if t0 <= t["t"] < t1]
-            window = [{"t": r["t_done"], "ttft_s": r["ttft_s"],
-                       "tpot_s": r["tpot_s"], "tokens": r["tokens"],
-                       "decode_ticks": r["decode_ticks"]}
-                      for r in self._done
+            window = [_window_entry(r) for r in self._done
                       if r["state"] == "done" and t0 <= r["t_done"] < t1]
-        return self._aggregate(ticks, window)
+            pump = list(self._pump)  # filtered off the lock: the ring is long
+        return self._aggregate(ticks, window,
+                               [lag for t, lag in pump if t0 <= t < t1])
 
     def _aggregate(self, ticks: List[Dict[str, Any]],
-                   window: List[Dict[str, Any]]) -> Dict[str, Any]:
+                   window: List[Dict[str, Any]],
+                   lags: List[float]) -> Dict[str, Any]:
         phase_totals = {p: 0.0 for p in TICK_PHASES}
+        part_totals = {p: 0.0 for p in DECODE_PARTS}
         wall = 0.0
+        overhead = 0.0
         gaps: List[float] = []
         cap_tokens = 0
         tokens_emitted = 0
@@ -319,8 +386,11 @@ class EngineRecorder(RecorderCore):
         decode_wall = 0.0
         for t in ticks:
             wall += t["wall_s"]
+            overhead += t.get("overhead_s", 0.0)
             for p, v in t["phases"].items():
                 phase_totals[p] = phase_totals.get(p, 0.0) + v
+            for p, v in t.get("decode_parts", {}).items():
+                part_totals[p] += v
             if "gap_s" in t:
                 gaps.append(t["gap_s"])
             d = t["phases"].get("decode_step", 0.0)
@@ -355,7 +425,29 @@ class EngineRecorder(RecorderCore):
             if decode_wall > 0 else 0.0,
             "capacity_tok_s": round(cap_tokens / decode_wall, 1)
             if decode_wall > 0 else 0.0,
+            "decode_parts_s": {p: round(v, 6)
+                               for p, v in part_totals.items() if v > 0.0},
+            # what stalls cost, by the engine thread's own clock: a tick
+            # (and, inside it, a launch) counts for what it took beyond
+            # twice the median of its (bucket, k) peers
+            "tick_excess_s": round(_excess(
+                ticks, lambda t: t["wall_s"]), 6),
+            "launch_excess_s": round(_excess(
+                ticks, lambda t: t.get("decode_parts", {}).get(
+                    "decode_launch", 0.0)), 6),
+            "overhead_frac": round(overhead / wall, 6) if wall > 0 else 0.0,
+            "pump_bursts": len(lags),
         }
+        if lags:
+            lags = sorted(lags)
+            out["pump_lag_p50_s"] = round(_pct(lags, 0.50), 6)
+            out["pump_lag_p99_s"] = round(_pct(lags, 0.99), 6)
+            out["pump_lag_max_s"] = round(lags[-1], 6)
+        for key, qs in (("queue", (50, 90)), ("front_in", (50, 90)),
+                        ("replica_in", (50,))):
+            vals = sorted(w[key + "_s"] for w in window if key + "_s" in w)
+            for q in qs if vals else ():
+                out[f"{key}_p{q}_s"] = round(_pct(vals, q / 100), 6)
         n = len(window)
         out["window_completed"] = n
         if n:
@@ -407,12 +499,16 @@ class EngineRecorder(RecorderCore):
                "admitted": t["admitted"]}
         if "gap_s" in t:
             out["gap_ms"] = round(t["gap_s"] * 1e3, 3)
+        if "decode_parts" in t:
+            out["decode_parts_ms"] = {p: round(v * 1e3, 3)
+                                      for p, v in t["decode_parts"].items()}
         return out
 
     @staticmethod
     def _compact_req(r: Dict[str, Any]) -> Dict[str, Any]:
         out = {"rid": r["rid"], "state": r["state"],
                "queue_wait_ms": round(r["queue_wait_s"] * 1e3, 3),
+               "queue_ms": round(r.get("queue_s", 0.0) * 1e3, 3),
                "prompt_tokens": r["prompt_tokens"],
                "cached_tokens": r["cached_tokens"],
                "computed_tokens": r["computed_tokens"],
@@ -492,8 +588,12 @@ class EngineRecorder(RecorderCore):
         for r in new_reqs:
             try:
                 span = obs.new_span_id()
+                # the two counts ride ``phases`` the way the replica's
+                # per-request kv span carried them (`rt trace <rid>`)
                 phases = {"queue_wait": r["queue_wait_s"],
-                          "prefill": r["prefill_s"]}
+                          "prefill": r["prefill_s"],
+                          "cached_tokens": float(r["cached_tokens"]),
+                          "prompt_tokens": float(r["prompt_tokens"])}
                 if r["kv_restore_s"] > 0:
                     phases["kv_restore"] = r["kv_restore_s"]
                 if "t_done" in r:
@@ -547,6 +647,32 @@ class EngineRecorder(RecorderCore):
         return events, advance
 
 
+def _window_entry(r: Dict[str, Any]) -> Dict[str, Any]:
+    """What the aggregates read of one finished request."""
+    out = {"t": r["t_done"], "ttft_s": r["ttft_s"], "tpot_s": r["tpot_s"],
+           "tokens": r["tokens"], "decode_ticks": r["decode_ticks"]}
+    for key in ("queue_s", "front_in_s", "replica_in_s"):
+        if key in r:
+            out[key] = r[key]
+    return out
+
+
+def _excess(ticks: List[Dict[str, Any]], wall) -> float:
+    """Sum over the ticks that launched a decode of what ``wall(tick)``
+    took beyond twice the median of the ticks of the same ``(bucket, k)``
+    (the same program at the same stride): 0 while the engine ticks
+    evenly, a stall of ``d`` seconds shows as ``d`` less one median."""
+    groups: Dict[Tuple[int, int], List[float]] = {}
+    for t in ticks:
+        if t["phases"].get("decode_step", 0.0) > 0.0:
+            groups.setdefault((t["bucket"], t["k"]), []).append(wall(t))
+    total = 0.0
+    for walls in groups.values():
+        limit = 2.0 * _pct(sorted(walls), 0.50)
+        total += sum(w - limit for w in walls if w > limit)
+    return total
+
+
 _metric_cache: Optional[Dict[str, Any]] = None
 _GAP_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
                 2.5, 5.0)
@@ -564,7 +690,8 @@ def _metric_handles(M) -> Dict[str, Any]:
             "phase": M.get_or_create(
                 M.Histogram, "rt_engine_tick_phase_seconds",
                 "Per-tick engine phase wall (admission / kv_restore / "
-                "prefill / decode_step / token_delivery / swap_barrier)",
+                "prefill / decode_step / token_delivery / swap_barrier / "
+                "record / idle_wait)",
                 boundaries=_GAP_BUCKETS, tag_keys=("engine", "phase")),
             "gap": M.get_or_create(
                 M.Histogram, "rt_engine_tick_gap_seconds",

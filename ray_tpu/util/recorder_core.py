@@ -22,6 +22,11 @@ copying it:
                     ``_drain_spans``)
   cluster_backend   the "initialized runtime or None" probe every
                     drain pass makes
+  span              one named extent on two clocks at once: the host's
+                    ``perf_counter`` (summed into the caller's dict, what
+                    a recorder keeps) and the profiler's (a
+                    ``TraceAnnotation``, what a device trace is read
+                    against; free while no trace runs)
   pct               nearest-rank percentile over a pre-sorted list
 
 The hot-path discipline (the PR 15 ``@memkv/`` lesson, measured: a
@@ -35,6 +40,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -47,6 +53,49 @@ def pct(sorted_vals: List[float], q: float) -> float:
         return 0.0
     idx = min(len(sorted_vals) - 1, int(q * (len(sorted_vals) - 1) + 0.5))
     return sorted_vals[idx]
+
+
+#: What a span is called on the profiler's clock. ``benchmark/lib/trace.py``
+#: (``ANNOTATION_PREFIX``) gives each idle gap of the device to the host
+#: event under this prefix that overlaps it most, on any thread; that
+#: reduction is the benchmark's, so the prefix changes there and here
+#: together or not at all.
+TRACE_PREFIX = "bench:"
+
+
+class span:
+    """``with span("prefill", phases):`` times the block and adds the
+    seconds to ``phases["prefill"]``; for the same extent it holds a
+    ``jax.profiler.TraceAnnotation`` named ``bench:prefill``, so a device
+    trace taken meanwhile shows what the host was doing in each gap.
+    Spans of one thread must follow one another and never nest: the
+    reduction takes the largest overlap, so an enclosing span would own
+    every gap. ``jax`` is used only where the process has imported it
+    already; ``stats`` (small ints) become the event's stats in a trace
+    and leave its name clean."""
+
+    __slots__ = ("_name", "_into", "_ann", "_t0")
+
+    def __init__(self, name: str, into: Optional[Dict[str, float]] = None,
+                 **stats: int):
+        self._name = name
+        self._into = into
+        jax = sys.modules.get("jax")
+        self._ann = (jax.profiler.TraceAnnotation(TRACE_PREFIX + name, **stats)
+                     if jax is not None else None)
+
+    def __enter__(self) -> "span":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._into is not None:
+            self._into[self._name] = self._into.get(self._name, 0.0) + dt
 
 
 def cluster_backend() -> Optional[Any]:

@@ -19,9 +19,9 @@ worker-acquire (spawn vs warm), arg-fetch, execute, result-store line up
 under the task's main lane.
 
 Engine flight-recorder records (``util/engine_recorder.py``) export as
-``engine:<name>:*`` lanes: the tick-phase lane (admission / kv_restore /
-prefill / decode_step / token_delivery / swap_barrier partition per
-tick, with decode tick-gap stalls as their own spans) and per-slot
+``engine:<name>:*`` lanes: the tick-phase lane (record / admission /
+kv_restore / prefill / decode_step / token_delivery / swap_barrier /
+idle_wait partition per tick, with decode tick-gap stalls as their own spans) and per-slot
 request lanes (queued + decode span per lifecycle) — a prefill burst
 starving decode is visible as a widening gap between decode launches.
 
@@ -411,7 +411,7 @@ def _phase_lanes(ev: Dict[str, Any]) -> List[Dict[str, Any]]:
     """One traced task's phase breakdown -> consecutive Perfetto sub-spans
     on a ``<task>:phases`` track, anchored at the task's enqueue time.
     ``driver_get`` trails the reply, so it lays out after the partition."""
-    from ray_tpu.util.tracing import sorted_phases
+    from ray_tpu.util.tracing import sorted_phases, timed_phases
 
     times = ev.get("times", {})
     start = times.get("PENDING") or times.get("RUNNING")
@@ -422,7 +422,7 @@ def _phase_lanes(ev: Dict[str, Any]) -> List[Dict[str, Any]]:
     out: List[Dict[str, Any]] = []
     # PENDING is stamped at raylet enqueue — the submit phase precedes it
     t = (start - max(0.0, ev["phases"].get("submit", 0.0))) * 1e6
-    for name, secs in sorted_phases(ev["phases"]):
+    for name, secs in sorted_phases(timed_phases(ev["phases"])):
         dur = max(0.0, secs) * 1e6
         args = {"seconds": secs}
         if name == "worker_acquire" and ev.get("worker_source"):

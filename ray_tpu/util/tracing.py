@@ -182,10 +182,21 @@ SERVE_PHASE_ORDER = ("proxy_route", "handle", "route", "call",
 # lifecycle inside ContinuousEngine, in causal order (queue-wait until a
 # slot frees, KV restore of the cached prefix, prefill of the suffix,
 # then the decode ticks until the last token). Tick records additionally
-# use decode_step/token_delivery/swap_barrier.
-ENGINE_PHASE_ORDER = ("queue_wait", "kv_restore", "prefill",
+# use record (a tick's first span: the recorder's calls for the tick
+# before it), decode_step/token_delivery/swap_barrier and idle_wait.
+ENGINE_PHASE_ORDER = ("record", "queue_wait", "kv_restore", "prefill",
                       "decode_step", "decode", "token_delivery",
-                      "swap_barrier")
+                      "swap_barrier", "idle_wait")
+
+# Counts that ride a span's ``phases`` (the only payload a serve span
+# has): how many prompt tokens the engine's request span saw and how many
+# of them the prefix cache covered. Not seconds: left out of durations.
+COUNT_PHASES = frozenset({"cached_tokens", "prompt_tokens"})
+
+
+def timed_phases(phases: Dict[str, float]) -> Dict[str, float]:
+    """``phases`` without the counts: what may be summed as seconds."""
+    return {k: v for k, v in phases.items() if k not in COUNT_PHASES}
 
 
 def sorted_phases(phases: Dict[str, float]) -> List[Any]:
@@ -218,7 +229,7 @@ def span_tree(spans: List[Dict[str, Any]]) -> List[Any]:
 
 
 def _span_duration(span: Dict[str, Any]) -> float:
-    phases = span.get("phases") or {}
+    phases = timed_phases(span.get("phases") or {})
     if phases:
         return sum(v for k, v in phases.items() if k != "driver_get")
     times = span.get("times") or {}
@@ -242,7 +253,7 @@ def critical_path(spans: List[Dict[str, Any]]) -> List[Any]:
     node = max(roots, key=lambda n: _span_duration(n[0]))
     while node is not None:
         span, children = node
-        phases = span.get("phases") or {}
+        phases = timed_phases(span.get("phases") or {})
         if phases:
             name, dur = max(phases.items(), key=lambda kv: kv[1])
         else:
@@ -269,6 +280,9 @@ def format_trace(spans: List[Dict[str, Any]]) -> str:
             f"[{span.get('state', '?')}]  {dur * 1e3:.1f} ms  "
             f"task_id={span.get('task_id', '')[:16]}")
         phases = span.get("phases") or {}
+        for pname in sorted(COUNT_PHASES.intersection(phases)):
+            lines.append(f"{pad}     {pname:<15}{phases[pname]:>10.0f}")
+        phases = timed_phases(phases)
         if phases:
             total = sum(phases.values()) or 1.0
             for pname, secs in sorted_phases(phases):

@@ -219,6 +219,28 @@ def _hang_watchdog(request):
     faulthandler.cancel_dump_traceback_later()
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _serve_port_per_xdist_worker():
+    """Most serve tests start the HTTP proxy on ``HTTPOptions``' default
+    port. xdist runs files side by side, so two such files that happen to
+    overlap fight over the one port (seen: 16 tests refused with EADDRINUSE
+    after a new test file shifted the schedule). Each worker gets a default
+    port of its own; tests ask ``serve.http_port()`` and name none."""
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+    if not worker.startswith("gw"):
+        yield
+        return
+    from ray_tpu.serve import config
+
+    init = config.HTTPOptions.__init__
+    shared, mine = init.__defaults__, \
+        config.DEFAULT_HTTP_PORT + 1 + int(worker[2:])
+    init.__defaults__ = tuple(mine if d == config.DEFAULT_HTTP_PORT else d
+                              for d in shared)
+    yield
+    init.__defaults__ = shared
+
+
 @pytest.fixture
 def rt_local():
     """A fresh in-process runtime per test."""

@@ -104,13 +104,19 @@ def test_request_record_joins_serve_span_tree(engine_run):
     from ray_tpu.serve import obs
 
     eng, _, _, _ = engine_run
-    n = eng._recorder._drain_spans()
-    assert n >= 1
-    with obs._span_lock:
-        spans = [dict(e) for e in obs._span_buf]
-    mine = [e for e in spans
-            if e["trace"]["trace_id"] == "req-obs-2"]
-    assert mine, [e.get("task_id") for e in spans]
+    # the drain thread (every 2 s) may have been here first, or be in the
+    # middle of its pass: either way the request gets exactly one span
+    eng._recorder._drain_spans()
+    deadline = time.time() + 20
+    while True:
+        with obs._span_lock:
+            spans = [dict(e) for e in obs._span_buf]
+        mine = [e for e in spans
+                if e["trace"]["trace_id"] == "req-obs-2"]
+        if mine or time.time() > deadline:
+            break
+        time.sleep(0.05)
+    assert len(mine) == 1, [e.get("task_id") for e in spans]
     ev = mine[-1]
     assert ev["task_id"].startswith("serve:req-obs-2:engine:")
     assert ev["trace"]["parent_span_id"] == "parentspan01"
@@ -350,3 +356,340 @@ def test_api_engine_and_cli_json(rt_cluster):
         assert rc == 0 and "recorder overhead" in out.getvalue()
     finally:
         rec.close()
+
+
+# ---------------------------------------------------------------------------
+# the tick loop and the request path as spans (``recorder_core.span``): one
+# vocabulary on the recorder's clock and on the profiler's
+# ---------------------------------------------------------------------------
+
+VOCABULARY = set(ER.TICK_PHASES) - {"decode_step"} | set(ER.DECODE_PARTS)
+
+
+def _tiny_engine(**kw):
+    cfg = llama.PRESETS["debug"]
+    params = llama.init_params(jax.random.key(0), cfg)
+    args = dict(max_slots=4, max_len=160, decode_stride=4, warmup=True,
+                kv_cache_bytes=0, kv_label="spans")
+    args.update(kw)
+    eng = serving.ContinuousEngine(params, cfg, **args)
+    prompt = (np.arange(24) % cfg.vocab_size).astype(np.int32)
+    eng._batcher.warmup(prompt_lens=(len(prompt),))
+    return eng, prompt
+
+
+def _drain(queues):
+    return [list(iter(q.get, None)) for q in queues]
+
+
+def test_new_phases_partition_the_tick():
+    """Ticks tile the engine thread's time: ``record`` and ``idle_wait``
+    are phases, the phases sum to the wall within 2%, and ``decode_step``
+    keeps its extent: the sum of its three parts."""
+    eng, prompt = _tiny_engine()
+    try:
+        t0 = time.time()
+        _drain([eng.submit_stream(prompt, 60) for _ in range(6)])
+        time.sleep(0.7)  # the engine parks: idle_wait, carried forward
+        _drain([eng.submit_stream(prompt, 8)])
+        time.sleep(0.1)
+        w = eng._recorder.window_summary(t0, time.time())
+        assert 0.98 <= w["phase_sum_ratio"] <= 1.0, w
+        assert w["phase_s"]["record"] > 0 and w["phase_s"]["idle_wait"] > 0.5
+        assert set(w["phase_s"]) <= set(ER.TICK_PHASES)
+        assert set(w["decode_parts_s"]) == set(ER.DECODE_PARTS)
+        assert 0 < w["overhead_frac"] < 0.02
+        decoded = [t for t in eng._recorder.ticks() if "decode_parts" in t]
+        assert decoded
+        for t in decoded:
+            assert sum(t["decode_parts"].values()) \
+                <= t["phases"]["decode_step"], t
+        # what lies between the three spans is a few lines of interpreter
+        assert sum(w["decode_parts_s"].values()) \
+            >= 0.93 * w["phase_s"]["decode_step"], w
+        # consecutive ticks abut: one's start is the last one's end
+        ticks = eng._recorder.ticks()
+        for a, b in zip(ticks, ticks[1:]):
+            assert abs(a["t"] + a["wall_s"] - b["t"]) < 5e-3, (a, b)
+    finally:
+        eng.shutdown()
+
+
+def test_prefill_phase_contains_the_first_token_read(monkeypatch):
+    """The wait for the device is the read of the first token, not the
+    call: a prefill whose read is slow must show in ``prefill``, and
+    ``admission`` must stay host bookkeeping."""
+    eng, prompt = _tiny_engine()
+
+    class SlowFirst:
+        def __init__(self, first):
+            self.first = first
+
+        def __getitem__(self, i):
+            time.sleep(0.15)  # the device finishes only now
+            return self.first[i]
+
+    real = serving._compiled_slot_prefill
+
+    def slow_prefill(*a, **k):
+        fn = real(*a, **k)
+
+        def run(*args):
+            ck, cv, first = fn(*args)
+            return ck, cv, SlowFirst(first)
+        return run
+
+    monkeypatch.setattr(serving, "_compiled_slot_prefill", slow_prefill)
+    try:
+        t0 = time.time()
+        _drain([eng.submit_stream(prompt, 4)])
+        time.sleep(0.1)
+        w = eng._recorder.window_summary(t0, time.time())
+        assert w["phase_s"]["prefill"] >= 0.15, w["phase_s"]
+        assert w["phase_s"]["admission"] < 0.05, w["phase_s"]
+        r = eng._recorder.requests()[-1]
+        assert r["prefill_s"] >= 0.15
+        # popped at once, first token only after the slow prefill
+        assert r["queue_s"] < 0.05 < 0.15 <= r["ttft_s"]
+    finally:
+        eng.shutdown()
+
+
+def test_request_queue_and_front_stamps():
+    """``queue_s`` is the wait for a slot (submit -> popped), never more
+    than TTFT; the front's figures exist only for a request whose context
+    a proxy and a replica stamped."""
+    eng, prompt = _tiny_engine(max_slots=2)
+    try:
+        t0 = time.time()
+        stamped = {"request_id": "via-proxy", "span_id": "s1",
+                   "t_ingress": t0 - 0.030, "t_replica": t0 - 0.010}
+        qs = [eng.submit_stream(prompt, 40, obs_ctx=stamped)]
+        qs += [eng.submit_stream(prompt, 40,
+                                 obs_ctx={"request_id": "direct",
+                                          "span_id": "s2"})]
+        qs += [eng.submit_stream(prompt, 40) for _ in range(3)]
+        _drain(qs)
+        time.sleep(0.1)
+        reqs = eng._recorder.requests()
+        assert len(reqs) == 5
+        for r in reqs:
+            assert 0.0 <= r["queue_s"] <= r["ttft_s"] == r["queue_wait_s"], r
+        # two slots, five requests: the later ones waited for a slot
+        assert max(r["queue_s"] for r in reqs) > 0.005
+        by_id = {r.get("request_id"): r for r in reqs}
+        assert 0.030 <= by_id["via-proxy"]["front_in_s"] < 0.5
+        assert 0.010 <= by_id["via-proxy"]["replica_in_s"] \
+            <= by_id["via-proxy"]["front_in_s"]
+        assert "front_in_s" not in by_id["direct"]
+        assert "replica_in_s" not in by_id["direct"]
+        w = eng._recorder.window_summary(t0 - 1, time.time())
+        assert w["front_in_p50_s"] == pytest.approx(
+            by_id["via-proxy"]["front_in_s"], abs=1e-5)
+        assert w["queue_p50_s"] <= w["queue_p90_s"] <= w["ttft_p99_s"]
+    finally:
+        eng.shutdown()
+
+
+def test_tick_excess_is_zero_on_even_ticks():
+    rec = ER.EngineRecorder("even", max_slots=4, enabled=True)
+    try:
+        for i in range(30):
+            rec.record_tick(
+                t_start=100.0 + i, wall_s=0.010,
+                phases={"decode_step": 0.008, "token_delivery": 0.002},
+                decode_parts={"decode_stage": 0.001, "decode_launch": 0.006,
+                              "decode_book": 0.001},
+                active=4, pending=0, bucket=4, k=4 if i % 3 else 1,
+                tokens=4, admitted=0, gap_s=0.002)
+        w = rec.window_summary(0.0, 1000.0)
+        assert w["tick_excess_s"] == 0.0 and w["launch_excess_s"] == 0.0
+        # a parked stretch is a tick without a launch: not a stall
+        rec.record_tick(t_start=200.0, wall_s=30.0,
+                        phases={"idle_wait": 30.0}, active=0, pending=1,
+                        bucket=0, k=0, tokens=0, admitted=0, gap_s=None)
+        assert rec.window_summary(0.0, 1000.0)["tick_excess_s"] == 0.0
+    finally:
+        rec.close()
+
+
+def test_tick_excess_sees_a_tick_made_to_sleep():
+    """One tick of a live engine sleeps 0.2 s on the host (in ``on_tick``,
+    so outside the launch): the engine thread's excess holds the delay
+    less one median tick, and none of it is the launch's."""
+    calls = []
+
+    def on_tick(active, slots):
+        calls.append(active)
+        if len(calls) == 6:
+            time.sleep(0.2)
+
+    eng, prompt = _tiny_engine(on_tick=on_tick)
+    try:
+        t0 = time.time()
+        _drain([eng.submit_stream(prompt, 100) for _ in range(4)])
+        time.sleep(0.1)
+        w = eng._recorder.window_summary(t0, time.time())
+        assert len(calls) > 12
+        assert 0.15 <= w["tick_excess_s"] <= 0.26, w
+        assert w["launch_excess_s"] < 0.03, w
+        assert w["tick_gap_max_s"] >= 0.2
+    finally:
+        eng.shutdown()
+
+
+def test_pump_lag_sees_a_blocked_event_loop():
+    """The stream pump's first boundary: a burst the engine hands over
+    while the replica's event loop is blocked waits for it, and the
+    recorder says for how long."""
+    import asyncio
+
+    from ray_tpu.serve.llm import ContinuousLLM
+
+    llm = ContinuousLLM("debug", max_slots=2, max_len=320, decode_stride=2,
+                        name="pump", kv_cache_bytes=0)
+    try:
+        async def main():
+            gen = await llm({"tokens": list(range(1, 17)),
+                             "max_new_tokens": 200})
+            toks = [await gen.__anext__()]
+            time.sleep(0.2)  # the loop is blocked; the engine ticks on
+            toks += [t async for t in gen]
+            return toks
+
+        t0 = time.time()
+        toks = asyncio.run(main())
+        assert len(toks) == 200
+        time.sleep(0.1)
+        w = llm.engine._recorder.window_summary(t0, time.time())
+        assert w["pump_bursts"] >= 50
+        assert 0.1 <= w["pump_lag_max_s"] <= 0.5, w
+        assert w["pump_lag_p50_s"] <= w["pump_lag_p99_s"] \
+            <= w["pump_lag_max_s"]
+        assert llm.engine.stats()["recorder"]["pump_bursts"] >= 50
+    finally:
+        llm.engine.shutdown()
+
+
+def test_engine_spans_on_the_profilers_clock(tmp_path):
+    """A ``jax.profiler`` trace of a live engine: the engine thread's line
+    holds ``bench:`` events of the vocabulary only, no two overlap, and
+    from one decode launch to the next they cover the loop."""
+    eng, prompt = _tiny_engine()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    try:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            _drain([eng.submit_stream(prompt, 120) for _ in range(6)])
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        eng.shutdown()
+    from ray_tpu.util.recorder_core import TRACE_PREFIX
+
+    paths = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert paths, list(tmp_path.rglob("*"))
+    data = jax.profiler.ProfileData.from_file(str(paths[-1]))
+    lines = [[(e.start_ns, e.start_ns + e.duration_ns, e.name)
+              for e in line.events if e.name.startswith(TRACE_PREFIX)]
+             for plane in data.planes if plane.name.startswith("/host:")
+             for line in plane.lines]
+    engine = [sorted(evs) for evs in lines
+              if any(n == TRACE_PREFIX + "decode_launch" for _, _, n in evs)]
+    assert len(engine) == 1, [len(evs) for evs in lines]
+    evs = engine[0]
+    assert {n[len(TRACE_PREFIX):] for _, _, n in evs} <= VOCABULARY
+    assert {"admission", "prefill", "decode_stage", "decode_launch",
+            "decode_book", "token_delivery",
+            "record"} <= {n[len(TRACE_PREFIX):] for _, _, n in evs}
+    for (_, end, a), (start, _, b) in zip(evs, evs[1:]):
+        assert end <= start, (a, b, end - start)
+    launches = [e for e in evs if e[2] == TRACE_PREFIX + "decode_launch"]
+    assert len(launches) > 20
+    period = covered = gap = gap_covered = 0
+    for (a0, a1, _), (b0, _, _) in zip(launches, launches[1:]):
+        inside = [(max(s, a1), min(e, b0)) for s, e, _ in evs
+                  if e > a1 and s < b0]
+        period += b0 - a0
+        gap += b0 - a1
+        gap_covered += sum(e - s for s, e in inside)
+    covered = gap_covered + period - gap
+    # launch to launch the spans cover the loop; in the gaps alone, on
+    # this CPU, ~10 us of interpreter between spans weigh against a gap
+    # of ~1 ms (on the chip a gap is several ms)
+    assert covered / period >= 0.95, covered / period
+    assert gap_covered / gap >= 0.80, gap_covered / gap
+
+
+def test_engine_span_carries_the_token_counts():
+    """The replica's per-request ``kv:`` span is gone; the engine's
+    lifecycle span carries its two counts, and they are not seconds."""
+    from ray_tpu.serve import obs
+    from ray_tpu.util import tracing
+
+    rec = ER.EngineRecorder("counts", max_slots=2, enabled=True)
+    try:
+        rec.request_admitted(1, t_submit=10.0, t_admit=10.2,
+                             prompt_tokens=48, cached_tokens=32,
+                             prefill_s=0.1, kv_restore_s=0.05,
+                             obs_ctx={"request_id": "req-counts",
+                                      "span_id": "p1"})
+        rec.request_tokens(1, 7, 10.5, done=True)
+        assert rec._drain_spans() == 1
+        with obs._span_lock:
+            ev = [dict(e) for e in obs._span_buf
+                  if e["trace"]["trace_id"] == "req-counts"][-1]
+        assert ev["phases"]["cached_tokens"] == 32.0
+        assert ev["phases"]["prompt_tokens"] == 48.0
+        timed = tracing.timed_phases(ev["phases"])
+        assert set(timed) == {"queue_wait", "prefill", "kv_restore",
+                              "decode"}
+        assert tracing._span_duration(ev) == pytest.approx(
+            sum(timed.values()))
+        assert tracing.critical_path([ev])[0][1] in timed
+        text = tracing.format_trace([ev])
+        assert "cached_tokens" in text and "32" in text
+    finally:
+        rec.close()
+
+
+def test_front_stamps_reach_the_engine_through_the_http_proxy(rt_cluster):
+    """Proxy receipt and replica entry ride the request context through
+    handle and replica into the engine's request record: the front's
+    figure is there for a request that came by HTTP and absent for a
+    direct handle call."""
+    import requests
+
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import continuous_llm_app
+
+    try:
+        serve.run(continuous_llm_app("debug", max_slots=2, max_len=96,
+                                     decode_stride=2, name="Front",
+                                     kv_cache_bytes=0),
+                  name="front", route_prefix="/front",
+                  http_options=serve.HTTPOptions(port=0))
+        h = serve.get_deployment_handle("Front", "front")
+        body = {"tokens": list(range(1, 13)), "max_new_tokens": 6}
+
+        assert len(list(h.remote(body).result())) == 6
+        direct = h.engine_stats.remote().result()["recorder"]
+        assert direct["window_completed"] == 1
+        assert "queue_p50_s" in direct
+        # no proxy saw it; it did enter a replica
+        assert "front_in_p50_s" not in direct
+        assert 0.0 < direct["replica_in_p50_s"] < 5.0
+
+        r = requests.post(f"http://127.0.0.1:{serve.http_port()}/front/",
+                          json=body, timeout=60)
+        assert r.status_code == 200 and len(r.text.split()) == 6
+        via = h.engine_stats.remote().result()["recorder"]
+        assert via["window_completed"] == 2
+        # proxy -> handle -> replica -> executor -> engine, on one host
+        assert 0.0 < via["replica_in_p50_s"] <= via["front_in_p50_s"] < 5.0
+        assert via["queue_p50_s"] <= via["ttft_p99_s"]
+        assert via["pump_bursts"] >= 2
+    finally:
+        serve.shutdown()
+        serve._forget_controller_for_tests()
