@@ -2,12 +2,24 @@
 
 TPU-first decode (no reference counterpart — Ray ships no model code; this
 is the standard JAX recipe): the cache is a STATIC [L, B, max_len, kv_heads,
-head_dim] buffer written with ``dynamic_update_slice``, prefill runs the
-whole prompt as one batched forward (MXU-friendly), and the decode loop is
-a single ``lax.scan`` over steps — one compiled program regardless of how
-many tokens are generated. Causality over the not-yet-written cache tail
-falls out of ``mha(q_offset=pos)``'s mask. GQA works unchanged (the cache
-holds kv heads).
+head_dim] buffer, prefill runs the whole prompt as one batched forward
+(MXU-friendly), and the decode loop is a single ``lax.scan`` over steps —
+one compiled program regardless of how many tokens are generated.
+Causality over the not-yet-written cache tail falls out of
+``mha(q_offset=pos)``'s mask. GQA works unchanged (the cache holds kv
+heads).
+
+Two forwards share one block arithmetic (``_qkv``, ``_after_attention``):
+
+- ``_forward_with_cache``: [B, S] tokens at ONE position for all rows,
+  the cache as the layer scan's ``xs``/``ys``, each layer writing its S
+  new positions with ``dynamic_update_slice``. Prefill, ``generate``,
+  speculation.
+- ``decode_step_in_place``: one token per row at PER-ROW positions, the
+  whole cache in the layer scan's carry, written only at the new
+  positions (one indexed update a layer) and read where it lies. The
+  serving engine's decode step, whose cache is donated: nothing the size
+  of the cache is copied.
 
 Works for both model families: llama densely, MoE via its block functions
 (each family exposes ``cache_block``-compatible attention weights).
@@ -37,14 +49,13 @@ def init_cache(cfg: llama.LlamaConfig, batch: int, max_len: int) -> Dict:
             "v": jnp.zeros(shape, cfg.compute_dtype)}
 
 
-def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos):
-    """One decoder block over [B, S, d] at absolute position ``pos``,
-    reading/writing the layer's [B, max_len, hkv, hd] cache slices.
-    Returns (hidden, new_cache_k, new_cache_v)."""
-    b, s, d = x.shape
+def _qkv(cfg, x, layer, sin, cos, positions):
+    """The attention half up to the cache: pre-norm, the three projections
+    and rope at ``positions`` [B, S]. Returns q [B, S, hq, hd] and k, v
+    [B, S, hkv, hd]."""
+    b, s, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.compute_dtype
-
     if cfg.attn_impl in ("ring", "ulysses"):
         raise NotImplementedError(
             f"decode with attn_impl={cfg.attn_impl!r} (sequence-parallel "
@@ -52,30 +63,53 @@ def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos):
             f"sequence to shard. 'flash' and 'xla' configs both decode via "
             f"the einsum path (same math; the pallas kernel is a "
             f"long-sequence training implementation).")
+    h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
+    q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
+                   sin, cos, positions)
+    k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd),
+                   sin, cos, positions)
+    v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
+    return q, k, v
+
+
+def _after_attention(cfg, x, attn, layer):
+    """The rest of the block: output projection and residual (still the
+    ``attn`` scope's), then the feed-forward half."""
+    b, s, _ = x.shape
+    with jax.named_scope("attn"):
+        x = x + attn.reshape(b, s, -1) @ layer["wo"].astype(cfg.compute_dtype)
+    with jax.named_scope("mlp"):
+        if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
+            return llama.ffn_half(cfg, x, layer)
+        # MoE FFN: drop-free inference routing (shared ffn_half)
+        from ray_tpu.models import moe
+
+        return moe.ffn_half(cfg, x, layer, drop_free=True)[0]
+
+
+def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos):
+    """One decoder block over [B, S, d] at absolute position ``pos``,
+    reading/writing the layer's [B, max_len, hkv, hd] cache slices.
+    Returns (hidden, new_cache_k, new_cache_v)."""
+    b, s, _ = x.shape
     # scopes are names only: they group the block's operations in a
     # device trace (``attn``, ``mlp``) and change nothing that is computed
     with jax.named_scope("attn"):
-        h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
-        positions = pos + jnp.arange(s)[None, :]  # [1, s] broadcasts over batch
-        positions = jnp.broadcast_to(positions, (b, s))
-        q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
-                       sin, cos, positions)
-        k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd),
-                       sin, cos, positions)
-        v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
+        positions = jnp.broadcast_to(pos + jnp.arange(s)[None, :], (b, s))
+        q, k, v = _qkv(cfg, x, layer, sin, cos, positions)
         cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, pos, 0, 0))
         cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, pos, 0, 0))
         attn = mha(q, cache_k, cache_v, causal=True, q_offset=pos)
-        x = x + attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
+    return _after_attention(cfg, x, attn, layer), cache_k, cache_v
 
-    with jax.named_scope("mlp"):
-        if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
-            x = llama.ffn_half(cfg, x, layer)
-        else:  # MoE FFN: drop-free inference routing (shared ffn_half)
-            from ray_tpu.models import moe
 
-            x, _ = moe.ffn_half(cfg, x, layer, drop_free=True)
-    return x, cache_k, cache_v
+def _head(params: Params, cfg, x):
+    """Final norm and the vocabulary projection, float32 logits."""
+    cdt = cfg.compute_dtype
+    x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
+    head = (params["embed"].T if cfg.tie_embeddings
+            else params["lm_head"]).astype(cdt)
+    return (x @ head).astype(jnp.float32)
 
 
 def _forward_with_cache(params: Params, tokens: jax.Array,
@@ -99,13 +133,54 @@ def _forward_with_cache(params: Params, tokens: jax.Array,
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
     with jax.named_scope("head_sample"):
-        if last_only:
-            x = x[:, -1:, :]
-        x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"]).astype(cdt)
-        logits = (x @ head).astype(jnp.float32)
+        logits = _head(params, cfg, x[:, -1:, :] if last_only else x)
     return logits, {"k": new_k, "v": new_v}
+
+
+def decode_step_in_place(params: Params, tok: jax.Array, cfg,
+                         ck: jax.Array, cv: jax.Array, slot0,
+                         pos: jax.Array) -> Tuple[jax.Array, jax.Array,
+                                                  jax.Array]:
+    """One decode step for the ``B`` cache rows ``slot0 .. slot0 + B`` of
+    a slot cache ``ck``/``cv`` [L, slots, max_len, hkv, hd]: ``tok`` [B]
+    is each row's token AT its own position ``pos`` [B]. Returns (logits
+    [B, V] float32, ck, cv).
+
+    The block arithmetic is ``_block_with_cache``'s; what differs is the
+    cache's way through the step. It rides the layer scan's carry, each
+    layer writes its rows' new K and V ([B, hkv, hd]) at ``(layer, row,
+    pos)`` with one indexed update and attention reads the layer's rows
+    out of the same buffer — with the cache donated by the caller the
+    update is in place and nothing cache-sized is stacked, transposed or
+    gathered. A position past ``max_len`` (a row that finished earlier in
+    a fused launch, whose tokens nobody reads) writes nothing."""
+    cdt = cfg.compute_dtype
+    b = tok.shape[0]
+    _, _, max_len, hkv, hd = ck.shape
+    x = params["embed"].astype(cdt)[tok][:, None, :]
+    sin, cos = rope_angles(max_len, cfg.head_dim, cfg.rope_theta, cdt)
+    rows = slot0 + jnp.arange(b)
+
+    def layer_rows(cache, l):  # [B, max_len, hkv, hd], where they lie
+        return jax.lax.dynamic_slice(cache, (l, slot0, 0, 0, 0),
+                                     (1, b, max_len, hkv, hd))[0]
+
+    def body(carry, sl):
+        x, ck, cv = carry
+        layer, l = sl
+        with jax.named_scope("attn"):
+            q, k, v = _qkv(cfg, x, layer, sin, cos, pos[:, None])
+            ck = ck.at[l, rows, pos].set(k[:, 0], mode="drop")
+            cv = cv.at[l, rows, pos].set(v[:, 0], mode="drop")
+            attn = mha(q, layer_rows(ck, l), layer_rows(cv, l), causal=True,
+                       q_offset=pos)
+        return (_after_attention(cfg, x, attn, layer), ck, cv), None
+
+    (x, ck, cv), _ = jax.lax.scan(
+        body, (x, ck, cv), (params["layers"], jnp.arange(cfg.n_layers)))
+    with jax.named_scope("head_sample"):
+        logits = _head(params, cfg, x)[:, 0, :]
+    return logits, ck, cv
 
 
 def generate(params: Params, prompt: jax.Array, cfg,

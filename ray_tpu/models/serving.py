@@ -6,18 +6,23 @@ paged dynamic memory:
 
 - ONE static KV cache [L, max_slots, max_len, hkv, hd]; a request
   occupies a SLOT for its lifetime. No paging, no dynamic shapes — the
-  compiled programs never change as requests come and go.
-- Admission is a per-request prefill that scatters the prompt's KV into
-  the free slot (`dynamic_update_slice` on the slot axis) and returns
-  the first generated token.
+  compiled programs never change as requests come and go. The cache is
+  one buffer: every engine program takes it DONATED and returns it
+  aliased, and the batcher rebinds it from each result, so the device
+  holds it once and no program copies it.
+- Admission is a per-request prefill that writes the prompt's KV into
+  the free slot's row (`dynamic_update_slice` on the slot axis, in
+  place) and returns the first generated token.
 - Every engine tick is ONE compiled launch decoding the ACTIVE slots
-  together: the per-slot absolute position rides a vector, handled by
-  ``vmap``-ing the single-row cached forward (per-row rope positions,
-  per-row cache writes become scatters, causal masking by each row's own
-  position). Occupied rows are gathered into a {1, max_slots} bucket
-  (a lone straggler pays one row, not the whole engine) and a
-  ``lax.scan`` fuses K decode
-  steps per launch (dispatch overhead amortized K-fold — the decode-side
+  together, in place: one batched forward over the bucket's rows with
+  per-row positions (rope, causal mask), each layer writing its rows'
+  new K/V at ``(layer, slot, pos)`` with one indexed update and reading
+  the layer's rows where they lie (``generate.decode_step_in_place``).
+  Two buckets: the full engine, whose rows are the slots in slot order
+  (a free slot computes a row nobody reads, into a row the next prefill
+  overwrites whole), and a lone straggler, which pays one row addressed
+  by a dynamic slice. A ``lax.scan`` fuses K decode steps per launch
+  (dispatch overhead amortized K-fold — the decode-side
   ``make_multi_step``). Stale KV in freed slots is never observed: the
   next admission prefills the slot from position 0.
 - Greedy decoding — each request's output is EXACTLY
@@ -31,6 +36,7 @@ a steady-state one (the decode step is length-independent).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import itertools
 import os
@@ -48,6 +54,7 @@ import numpy as np
 from ray_tpu.models import generate as G
 from ray_tpu.models import llama
 from ray_tpu.util import engine_recorder as _rec
+from ray_tpu.util import hlo_copies
 from ray_tpu.util import prefix_hash as PH
 from ray_tpu.util.recorder_core import span as _span
 
@@ -304,6 +311,12 @@ class _Request:
         self.prompt = prompt
 
 
+class SlotCacheLost(RuntimeError):
+    """A compiled call failed after it had consumed the donated slot
+    cache. The batcher has rebuilt a zeroed cache and dropped every
+    active request (their KV went with the buffer); it admits again."""
+
+
 class ContinuousBatcher:
     """Slot-based continuous batching engine around one model."""
 
@@ -311,14 +324,17 @@ class ContinuousBatcher:
                  max_slots: int = 8, max_len: int = 512,
                  prefix_cache: Optional[PrefixKVCache] = None,
                  sampling: bool = False):
-        self.params = params
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_len = max_len
-        shape = (cfg.n_layers, max_slots, max_len, cfg.n_kv_heads,
-                 cfg.head_dim)
-        self._ck = jnp.zeros(shape, cfg.compute_dtype)
-        self._cv = jnp.zeros(shape, cfg.compute_dtype)
+        #: per decode program this batcher has asked for, what its
+        #: compiled form does to the cache (``hlo_copies.cache_traffic``);
+        #: the engine's recorder shows it
+        self.program_stats: List[Dict[str, Any]] = []
+        self.params = params
+        # ONE buffer each, donated to every compiled call and rebound
+        # from its result (``_donating``)
+        self._ck, self._cv = self._zero_cache()
         self._free: List[int] = list(range(max_slots))
         self._active: Dict[int, _Request] = {}  # slot -> request
         self._cur = np.zeros(max_slots, np.int32)   # token AT pos, per slot
@@ -341,6 +357,51 @@ class ContinuousBatcher:
         # set by every step_many that launched: the wall of its three
         # spans (decode_stage, decode_launch, decode_book), in seconds
         self.last_step: Dict[str, float] = {}
+
+    @property
+    def params(self) -> Params:
+        return self._params
+
+    @params.setter
+    def params(self, params: Params) -> None:
+        # an executable takes the arguments it was compiled for: the
+        # weights' shapes, types and placement are part of a decode
+        # program's key, so that it follows them as ``jit`` would
+        leaves, tree = jax.tree.flatten(params)
+        self._weights = (tree, tuple(jax.ShapeDtypeStruct(
+            jnp.shape(x), jnp.result_type(x),
+            sharding=getattr(x, "sharding", None)) for x in leaves))
+        self._params = params
+
+    def _zero_cache(self) -> Tuple[jax.Array, jax.Array]:
+        cache = G.init_cache(self.cfg, self.max_slots, self.max_len)
+        return cache["k"], cache["v"]
+
+    @contextlib.contextmanager
+    def _donating(self):
+        """Round a compiled call that takes ``_ck``/``_cv`` donated, from
+        the call through the host read that fences it; the block rebinds
+        both from the call's result. A failure before the call consumed
+        them (a compile error, a bad argument) leaves the cache as it
+        was and is the caller's to handle. One after (the buffers are
+        deleted, or the results poisoned) has lost every slot's KV: the
+        batcher starts over on a zeroed cache with no active request
+        and raises :class:`SlotCacheLost`, so that it never holds a
+        deleted buffer."""
+        ck, cv = self._ck, self._cv
+        try:
+            yield
+        except BaseException as e:
+            if not (ck.is_deleted() or cv.is_deleted()):
+                raise
+            self._ck, self._cv = self._zero_cache()
+            self._active.clear()
+            self._free = list(range(self.max_slots))
+            if not isinstance(e, Exception):
+                raise  # an interrupt stays one
+            raise SlotCacheLost(
+                f"slot cache lost in a failed launch: "
+                f"{type(e).__name__}: {e}"[:300]) from e
 
     # -- admission --------------------------------------------------------
 
@@ -402,23 +463,23 @@ class ContinuousBatcher:
                     fn = _compiled_cached_prefill(
                         self.cfg, cached, s - cached, self.max_slots,
                         self.max_len, self.sampling)
-                    args = (self.params, self._ck, self._cv, *pages,
-                            jnp.asarray(prompt_arr[cached:])[None, :], slot)
+                    args = (*pages, jnp.asarray(prompt_arr[cached:])[None, :],
+                            slot)
                 else:
                     fn = _compiled_slot_prefill(
                         self.cfg, s, self.max_slots, self.max_len,
                         self.sampling)
-                    args = (self.params, self._ck, self._cv,
-                            jnp.asarray(prompt_arr)[None, :], slot)
+                    args = (jnp.asarray(prompt_arr)[None, :], slot)
                 if self.sampling:
-                    key0 = jnp.asarray(np.asarray(
-                        jax.random.PRNGKey(int(seed)), np.uint32))
-                    self._ck, self._cv, first, new_key = fn(
-                        *args, jnp.float32(temperature), jnp.int32(top_k),
-                        key0)
-                else:
-                    self._ck, self._cv, first = fn(*args)
-                first_tok = int(first[0])
+                    args += (jnp.float32(temperature), jnp.int32(top_k),
+                             jnp.asarray(np.asarray(
+                                 jax.random.PRNGKey(int(seed)), np.uint32)))
+                with self._donating():
+                    self._ck, self._cv, first, *new_key = fn(
+                        self.params, self._ck, self._cv, *args)
+                    first_tok = int(first[0])
+        except SlotCacheLost:
+            raise  # every slot is free again
         except BaseException:
             # a failed prefill must not leak the slot: callers (the
             # engine's admit loop) catch and continue, and a leaked slot
@@ -435,7 +496,7 @@ class ContinuousBatcher:
             if self.sampling:
                 self._temp[slot] = temperature
                 self._topk[slot] = top_k
-                self._keys[slot] = np.asarray(new_key)
+                self._keys[slot] = np.asarray(new_key[0])
             done = req.remaining <= 0
             if done:
                 self._capture(slot, req)
@@ -494,16 +555,21 @@ class ContinuousBatcher:
         measured per-launch overhead is ~ms — the make_multi_step story,
         applied to decode):
 
-        - Bucketed active-slot stepping: occupied slots are gathered,
-          stepped, scattered back — a lone straggler on an 8-slot engine
-          pays one row, not eight (buckets: {1, max_slots}).
+        - Bucketed active-slot stepping: a lone straggler on an 8-slot
+          engine pays one row, not eight (buckets: {1, max_slots}). The
+          full bucket's rows are the slots themselves, in slot order: a
+          free slot's row computes from whatever ``_cur``/``_pos`` still
+          hold, writes into its own free row, and nobody reads its token.
         - K-step fusion: a ``lax.scan`` decodes ``k`` tokens per launch,
           so dispatch overhead is paid once per K tokens instead of per
           token. A request finishing mid-tick just has its surplus
           tokens discarded (its rows compute independently; the freed
           slot's stale KV is overwritten by the next prefill).
 
-        Two programs (lone-row, full-engine) compile per distinct ``k``.
+        The program steps the slot cache in place (it is donated and
+        rebound here). Two programs (lone-row, full-engine) compile per
+        distinct ``k``. Raises :class:`SlotCacheLost` when a launch
+        failed after it had consumed the cache.
         """
         if not self._active:
             return []
@@ -516,34 +582,23 @@ class ContinuousBatcher:
             # little compute but each costs a warmup compile (~seconds);
             # the lone-straggler case is the one worth its own program
             bucket = 1 if n == 1 else self.max_slots
-            # pad with a repeat of the first active slot: the duplicate
-            # rows compute the SAME update from the same inputs, so the
-            # duplicate scatter writes identical values (deterministic)
-            idx = np.asarray(slots + [slots[0]] * (bucket - n), np.int32)
-            fn = _compiled_bucket_scan(self.cfg, bucket, self.max_slots,
-                                       self.max_len, k, self.sampling)
-            args = (jnp.asarray(self._cur[idx]), jnp.asarray(self._pos[idx]),
-                    jnp.asarray(idx))
-            if self.sampling:
-                args += (jnp.asarray(self._temp[idx]),
-                         jnp.asarray(self._topk[idx]),
-                         jnp.asarray(self._keys[idx]))
+            fn = self._program(bucket, k)
+            rows, args = self._stage(bucket, slots[0] if n == 1 else 0)
         # the compiled call through the host read of its tokens: the
         # device is busy under this span and idle outside it
-        with _span("decode_launch", parts, k=k, bucket=bucket, active=n):
+        with _span("decode_launch", parts, k=k, bucket=bucket, active=n), \
+                self._donating():
             self._ck, self._cv, toks, *new_keys = fn(
                 self.params, self._ck, self._cv, *args)
             toks = np.asarray(toks)  # [k, bucket]
             if self.sampling:
-                # duplicate padding rows carry the same key and compute
-                # the same split chain, so the repeated write is identical
-                self._keys[idx] = np.asarray(new_keys[0])
+                self._keys[rows] = np.asarray(new_keys[0])
         with _span("decode_book", parts):
             out = []
-            for j, slot in enumerate(slots):
+            for slot in slots:
                 req = self._active[slot]
                 take = min(k, req.remaining)
-                mine = [int(t) for t in toks[:take, j]]
+                mine = [int(t) for t in toks[:take, slot - rows.start]]
                 req.tokens.extend(mine)
                 req.remaining -= take
                 self._cur[slot] = mine[-1]
@@ -559,6 +614,28 @@ class ContinuousBatcher:
             # teardown it would fall between the spans
             del args, toks
         return out
+
+    def _stage(self, bucket: int, slot0: int) -> Tuple[slice, Tuple]:
+        """The rows ``slot0 .. slot0 + bucket`` and a decode program's
+        arguments for them, after the weights and the cache."""
+        rows = slice(slot0, slot0 + bucket)
+        args = (jnp.asarray(self._cur[rows]), jnp.asarray(self._pos[rows]),
+                jnp.int32(slot0))
+        if self.sampling:
+            args += (jnp.asarray(self._temp[rows]),
+                     jnp.asarray(self._topk[rows]),
+                     jnp.asarray(self._keys[rows]))
+        return rows, args
+
+    def _program(self, bucket: int, k: int):
+        """The decode executable for ``(bucket, k)``, compiled on first
+        use (``warmup`` asks for all of them before traffic)."""
+        fn, stats = _decode_executable(
+            self.cfg, bucket, self.max_slots, self.max_len, k, self.sampling,
+            self._weights)
+        if stats not in self.program_stats:
+            self.program_stats.append(stats)
+        return fn
 
     @property
     def num_active(self) -> int:
@@ -577,31 +654,31 @@ class ContinuousBatcher:
         a mid-flight XLA compile that stalls every active stream —
         under Poisson load the stall backlog saturates the slots and
         never recovers. Keep this bucket set in lockstep with
-        step_many's choice."""
-        cur = jnp.asarray(self._cur)
-        pos = jnp.asarray(self._pos)
+        step_many's choice. The programs run for real, on the donated
+        cache: a decode step rewrites, for each row, the K/V its next
+        real step writes again, and a prefill goes to a free slot, whose
+        row the next admission overwrites whole."""
         for k in sorted(set(strides)):
             for bucket in sorted({1, self.max_slots}):
-                fn = _compiled_bucket_scan(self.cfg, bucket, self.max_slots,
-                                           self.max_len, int(k),
-                                           self.sampling)
-                idx = jnp.zeros(bucket, jnp.int32)
-                args = (self.params, self._ck, self._cv,
-                        cur[:bucket], pos[:bucket], idx)
-                if self.sampling:
-                    args += (jnp.asarray(self._temp[:bucket]),
-                             jnp.asarray(self._topk[:bucket]),
-                             jnp.asarray(self._keys[:bucket]))
-                np.asarray(fn(*args)[2])
+                fn = self._program(bucket, int(k))
+                with self._donating():
+                    self._ck, self._cv, toks, *_ = fn(
+                        self.params, self._ck, self._cv,
+                        *self._stage(bucket, 0)[1])
+                    np.asarray(toks)
         for s in prompt_lens:
+            if not self._free:
+                raise RuntimeError("no free slot to warm a prefill in")
             fn = _compiled_slot_prefill(self.cfg, int(s), self.max_slots,
                                         self.max_len, self.sampling)
-            args = (self.params, self._ck, self._cv,
-                    jnp.zeros((1, int(s)), jnp.int32), 0)
+            args = (jnp.zeros((1, int(s)), jnp.int32), self._free[-1])
             if self.sampling:
                 args += (jnp.float32(0.0), jnp.int32(0),
                          jnp.asarray(self._keys[0]))
-            np.asarray(fn(*args)[2])
+            with self._donating():
+                self._ck, self._cv, first, *_ = fn(
+                    self.params, self._ck, self._cv, *args)
+                np.asarray(first)
 
     def cancel(self, req_id: int) -> bool:
         """Free a request's slot mid-flight (client disconnect). The slot's
@@ -751,6 +828,8 @@ class ContinuousEngine:
         # spans/KV snapshots (NO GCS or metrics I/O on the tick path)
         self._recorder = _rec.EngineRecorder(kv_label or "engine",
                                              max_slots=max_slots)
+        # the batcher's own list: a program compiled later shows too
+        self._recorder.decode_programs = self._batcher.program_stats
         # engine-thread-confined tick state (never touched off-thread):
         # end of the previous decode launch (the tick-gap anchor; reset
         # to None when the engine goes idle), and the tick being
@@ -1012,12 +1091,21 @@ class ContinuousEngine:
                     req.prompt, req.max_new_tokens,
                     temperature=req.temperature, top_k=req.top_k,
                     seed=req.seed)
-            except Exception:  # noqa: BLE001 — ONE request's prefill
+            except Exception as e:  # noqa: BLE001 — ONE request's prefill
                 # failing (bad shape, transient XLA error) must fail that
-                # request, not wedge the shared engine thread
+                # request, not wedge the shared engine thread. One that
+                # took the donated cache with it fails the active
+                # requests too: the batcher dropped them with their KV
                 with self._work:
                     self._admitting = None
+                    lost: Dict[int, _EngineRequest] = {}
+                    if isinstance(e, SlotCacheLost):
+                        lost, self._live = self._live, {}
                 req.emit_many([_STREAM_END])
+                for rid, live in lost.items():
+                    live.emit_many([_STREAM_END])
+                    self._recorder.request_done(rid, t=time.time(),
+                                                state="cancelled")
                 continue
             la = self._batcher.last_admission
             for phase in ("admission", "kv_restore", "prefill"):
@@ -1273,41 +1361,34 @@ def _first_token(logits_last, sample: bool, temp=None, top_k=None,
     return _row_sample(logits_last[0], temp, top_k, sub)[None], key
 
 
+def _write_row(ck, cv, row: Dict[str, jax.Array], slot):
+    """A prefilled row into its slot of the (donated) cache, in place:
+    one row's bytes move, not the cache's."""
+    with jax.named_scope("kv_scatter"):
+        return (jax.lax.dynamic_update_slice(ck, row["k"], (0, slot, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(cv, row["v"], (0, slot, 0, 0, 0)))
+
+
 @functools.lru_cache(maxsize=64)
 def _compiled_slot_prefill(cfg, s: int, max_slots: int, max_len: int,
                            sample: bool = False):
-    """Prefill ONE prompt into ONE slot of the shared cache; returns the
-    updated cache and the first token (greedy, or sampled off the
-    request's key when the engine runs the sampling programs)."""
+    """Prefill ONE prompt into ONE slot of the shared cache, which the
+    program takes donated; returns the cache and the first token (greedy,
+    or sampled off the request's key when the engine runs the sampling
+    programs, which also return the key)."""
 
-    def body(params, ck, cv, prompt, slot, temp=None, top_k=None,
-             key=None):
-        row = {"k": jnp.zeros((cfg.n_layers, 1, max_len, cfg.n_kv_heads,
-                               cfg.head_dim), cfg.compute_dtype),
-               "v": jnp.zeros((cfg.n_layers, 1, max_len, cfg.n_kv_heads,
-                               cfg.head_dim), cfg.compute_dtype)}
-        logits, row = G._forward_with_cache(params, prompt, cfg, row, 0)
+    # the program's name in a device trace (``jit_rt_prefill``)
+    def rt_prefill(params, ck, cv, prompt, slot, temp=None, top_k=None,
+                   key=None):
+        logits, row = G._forward_with_cache(params, prompt, cfg,
+                                            G.init_cache(cfg, 1, max_len), 0)
         with jax.named_scope("head_sample"):
             first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
                                       key)
-        with jax.named_scope("kv_scatter"):
-            ck = jax.lax.dynamic_update_slice(ck, row["k"],
-                                              (0, slot, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, row["v"],
-                                              (0, slot, 0, 0, 0))
+        ck, cv = _write_row(ck, cv, row, slot)
         return (ck, cv, first, key) if sample else (ck, cv, first)
 
-    # the program's name in a device trace (``jit_rt_prefill``)
-    if sample:
-        @jax.jit
-        def rt_prefill(params, ck, cv, prompt, slot, temp, top_k, key):
-            return body(params, ck, cv, prompt, slot, temp, top_k, key)
-    else:
-        @jax.jit
-        def rt_prefill(params, ck, cv, prompt, slot):
-            return body(params, ck, cv, prompt, slot)
-
-    return rt_prefill
+    return jax.jit(rt_prefill, donate_argnums=(1, 2))
 
 
 @functools.lru_cache(maxsize=256)
@@ -1319,117 +1400,85 @@ def _compiled_cached_prefill(cfg, c: int, sl: int, max_slots: int,
     collapse on shared-prefix traffic. Token-exact vs the cold path: the
     restored K/V are the same per-position values a full prefill would
     recompute (each position's K/V depends only on tokens <= it, and
-    attention always masks over the same full-length row cache)."""
+    attention always masks over the same full-length row cache). The
+    cache is donated, as in the cold prefill."""
 
-    def body(params, ck, cv, pk, pv, suffix, slot, temp=None, top_k=None,
-             key=None):
-        zk = jnp.zeros((cfg.n_layers, 1, max_len, cfg.n_kv_heads,
-                        cfg.head_dim), cfg.compute_dtype)
-        row = {"k": zk.at[:, 0, :c].set(pk.astype(cfg.compute_dtype)),
-               "v": zk.at[:, 0, :c].set(pv.astype(cfg.compute_dtype))}
+    def rt_cached_prefill(params, ck, cv, pk, pv, suffix, slot, temp=None,
+                          top_k=None, key=None):
+        row = G.init_cache(cfg, 1, max_len)
+        row = {"k": row["k"].at[:, 0, :c].set(pk.astype(cfg.compute_dtype)),
+               "v": row["v"].at[:, 0, :c].set(pv.astype(cfg.compute_dtype))}
         logits, row = G._forward_with_cache(params, suffix, cfg, row, c)
         with jax.named_scope("head_sample"):
             first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
                                       key)
-        with jax.named_scope("kv_scatter"):
-            ck = jax.lax.dynamic_update_slice(ck, row["k"],
-                                              (0, slot, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, row["v"],
-                                              (0, slot, 0, 0, 0))
+        ck, cv = _write_row(ck, cv, row, slot)
         return (ck, cv, first, key) if sample else (ck, cv, first)
 
-    if sample:
-        @jax.jit
-        def rt_cached_prefill(params, ck, cv, pk, pv, suffix, slot, temp,
-                              top_k, key):
-            return body(params, ck, cv, pk, pv, suffix, slot, temp, top_k,
-                        key)
-    else:
-        @jax.jit
-        def rt_cached_prefill(params, ck, cv, pk, pv, suffix, slot):
-            return body(params, ck, cv, pk, pv, suffix, slot)
-
-    return rt_cached_prefill
-
-
-def _one_row_step(cfg, sample: bool = False):
-    """The single-row cached decode body shared by the full-engine and
-    bucketed step programs: per-row rope, per-row cache scatter, per-row
-    causal masking — plus per-row sampling state when enabled."""
-
-    def one_row(params, ck_row, cv_row, tok, pos):
-        cache = {"k": ck_row[:, None], "v": cv_row[:, None]}
-        logits, cache = G._forward_with_cache(
-            params, tok[None, None], cfg, cache, pos)
-        with jax.named_scope("head_sample"):
-            nxt = jnp.argmax(logits[0, -1, :]).astype(jnp.int32)
-        return cache["k"][:, 0], cache["v"][:, 0], nxt
-
-    def one_row_sampled(params, ck_row, cv_row, tok, pos, temp, top_k,
-                        key):
-        cache = {"k": ck_row[:, None], "v": cv_row[:, None]}
-        logits, cache = G._forward_with_cache(
-            params, tok[None, None], cfg, cache, pos)
-        with jax.named_scope("head_sample"):
-            key, sub = jax.random.split(key)
-            nxt = _row_sample(logits[0, -1, :], temp, top_k, sub)
-        return cache["k"][:, 0], cache["v"][:, 0], nxt, key
-
-    return one_row_sampled if sample else one_row
+    return jax.jit(rt_cached_prefill, donate_argnums=(1, 2))
 
 
 @functools.lru_cache(maxsize=128)
 def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
                           k: int, sample: bool = False):
-    """``k`` fused decode steps for ``bucket`` ACTIVE slots out of
-    ``max_slots``: gather the occupied rows, ``lax.scan`` the vmapped
-    single-row forward ``k`` times, scatter the updated KV back, return
-    the [k, bucket] token block. One launch per K tokens per occupancy
-    bucket — the decode-side make_multi_step. The sampling variant
-    additionally carries each row's PRNG key through the scan (one split
-    per token, so a request's draw chain is independent of batch
-    composition and tick stride — seeded determinism)."""
-    one_row = _one_row_step(cfg, sample)
+    """``k`` fused decode steps, in place on the donated slot cache, for
+    the ``bucket`` rows from slot ``slot0`` on: a ``lax.scan`` of
+    ``generate.decode_step_in_place`` with the cache in its carry, which
+    returns the cache and the [k, bucket] token block. One launch per K
+    tokens per occupancy bucket — the decode-side make_multi_step. The
+    full bucket's rows are all the slots (``slot0`` is not looked at);
+    the lone row is addressed by a dynamic slice at ``slot0``. The
+    sampling variant additionally carries each row's PRNG key through
+    the scan (one split per token, so a request's draw chain is
+    independent of batch composition and tick stride — seeded
+    determinism) and returns the keys [bucket, 2]."""
 
+    # the program's name in a device trace (``jit_rt_decode``)
+    def rt_decode(params, ck, cv, cur, pos, slot0, temp=None, topk=None,
+                  keys=None):
+        first = slot0 if bucket < max_slots else 0
+
+        def body(carry, _):
+            ck, cv, cur, pos, keys = carry
+            logits, ck, cv = G.decode_step_in_place(params, cur, cfg, ck, cv,
+                                                    first, pos)
+            with jax.named_scope("head_sample"):
+                if sample:
+                    keys, subs = jnp.moveaxis(
+                        jax.vmap(jax.random.split)(keys), 1, 0)
+                    nxt = jax.vmap(_row_sample)(logits, temp, topk, subs)
+                else:
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (ck, cv, nxt, pos + 1, keys), nxt
+
+        (ck, cv, _, _, keys), toks = jax.lax.scan(
+            body, (ck, cv, cur, pos, keys), None, length=k)
+        return (ck, cv, toks, keys) if sample else (ck, cv, toks)
+
+    return jax.jit(rt_decode, donate_argnums=(1, 2))
+
+
+@functools.lru_cache(maxsize=128)
+def _decode_executable(cfg, bucket: int, max_slots: int, max_len: int,
+                       k: int, sample: bool, weights: Tuple):
+    """``_compiled_bucket_scan``'s program compiled ahead of time for
+    ``weights`` (the tree and the leaves' shapes, types and shardings),
+    and what the compiled form does to the cache (``bucket``, ``k`` and
+    ``hlo_copies.cache_traffic``): read off the very executable that
+    runs, on whatever backend this is. One compile per key for the
+    process, as with ``jit``'s own cache."""
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    cache = jax.ShapeDtypeStruct(
+        (cfg.n_layers, max_slots, max_len, cfg.n_kv_heads, cfg.head_dim),
+        cfg.compute_dtype)
+    args = (i32((bucket,)), i32((bucket,)), i32(()))
     if sample:
-        @jax.jit
-        def rt_decode(params, ck, cv, cur, pos, idx, temp, topk, keys):
-            with jax.named_scope("kv_gather"):
-                ck_rows = ck.swapaxes(0, 1)[idx]  # [bucket, L, T, hkv, hd]
-                cv_rows = cv.swapaxes(0, 1)[idx]
+        args += (jax.ShapeDtypeStruct((bucket,), jnp.float32), i32((bucket,)),
+                 jax.ShapeDtypeStruct((bucket, 2), jnp.uint32))
+    tree, leaves = weights
+    fn = _compiled_bucket_scan(cfg, bucket, max_slots, max_len, k,
+                               sample).lower(
+        jax.tree.unflatten(tree, leaves), cache, cache, *args).compile()
+    return fn, dict(bucket=bucket, k=k, **hlo_copies.cache_traffic(
+        fn, cache, rows=bucket, steps=k))
 
-            def body(carry, _):
-                ck_r, cv_r, cur, pos, keys = carry
-                ck_r, cv_r, nxt, keys = jax.vmap(
-                    one_row, in_axes=(None, 0, 0, 0, 0, 0, 0, 0))(
-                    params, ck_r, cv_r, cur, pos, temp, topk, keys)
-                return (ck_r, cv_r, nxt, pos + 1, keys), nxt
-
-            (ck_rows, cv_rows, _, _, keys), toks = jax.lax.scan(
-                body, (ck_rows, cv_rows, cur, pos, keys), None, length=k)
-            with jax.named_scope("kv_scatter"):
-                ck = ck.swapaxes(0, 1).at[idx].set(ck_rows).swapaxes(0, 1)
-                cv = cv.swapaxes(0, 1).at[idx].set(cv_rows).swapaxes(0, 1)
-            return ck, cv, toks, keys  # [k, bucket], [bucket, 2]
-    else:
-        @jax.jit
-        def rt_decode(params, ck, cv, cur, pos, idx):
-            with jax.named_scope("kv_gather"):
-                ck_rows = ck.swapaxes(0, 1)[idx]  # [bucket, L, T, hkv, hd]
-                cv_rows = cv.swapaxes(0, 1)[idx]
-
-            def body(carry, _):
-                ck_r, cv_r, cur, pos = carry
-                ck_r, cv_r, nxt = jax.vmap(
-                    one_row, in_axes=(None, 0, 0, 0, 0))(
-                    params, ck_r, cv_r, cur, pos)
-                return (ck_r, cv_r, nxt, pos + 1), nxt
-
-            (ck_rows, cv_rows, _, _), toks = jax.lax.scan(
-                body, (ck_rows, cv_rows, cur, pos), None, length=k)
-            with jax.named_scope("kv_scatter"):
-                ck = ck.swapaxes(0, 1).at[idx].set(ck_rows).swapaxes(0, 1)
-                cv = cv.swapaxes(0, 1).at[idx].set(cv_rows).swapaxes(0, 1)
-            return ck, cv, toks  # [k, bucket]
-
-    return rt_decode

@@ -26,7 +26,8 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     Supports GQA: k/v may have fewer heads than q as long as
     ``q_heads % kv_heads == 0``. ``q_offset`` is the absolute position of
-    q[0] relative to k (for decode with a KV cache). Softmax in fp32.
+    q[0] relative to k (for decode with a KV cache): one value for the
+    batch, or one per row ([batch]). Softmax in fp32.
     """
     b, sq, hq, d = q.shape
     _, sk, hkv, _ = k.shape
@@ -45,10 +46,10 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     mask = None
     if causal:
-        qpos = jnp.arange(sq)[:, None] + q_offset
+        # [sq, 1], or [batch, sq, 1] under per-row offsets
+        qpos = jnp.arange(sq)[:, None] + jnp.asarray(q_offset)[..., None, None]
         kpos = jnp.arange(sk)[None, :]
-        mask = qpos >= kpos  # [sq, sk]
-        mask = mask[None, None, :, :]
+        mask = (qpos >= kpos).reshape(-1, 1, sq, sk)
     if segment_ids is not None:
         # [b, 1, sq, sk]; cross-segment attention is masked (packed sequences).
         seg_mask = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]

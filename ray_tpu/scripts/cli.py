@@ -921,6 +921,14 @@ def cmd_engine(args: argparse.Namespace) -> int:
                       f"{1e3 * summ.get('pump_lag_max_s', 0):.1f}ms  "
                       f"stall excess {summ.get('tick_excess_s', 0):.2f}s "
                       f"(launch {summ.get('launch_excess_s', 0):.2f}s)")
+            for prog in summ.get("decode_programs") or ():
+                print(f"  decode program bucket={prog['bucket']} "
+                      f"k={prog['k']}: cache_donated "
+                      f"{prog['cache_donated']}  "
+                      f"cache_copy_bytes_per_step "
+                      f"{prog['cache_copy_bytes_per_step']} "
+                      f"({prog['cache_copy_bytes_per_step'] / max(1, prog['cache_bytes']):.2f}"
+                      f" of the cache)")
             print(f"  recorder overhead "
                   f"{100 * summ.get('overhead_frac', 0):.3f}% of tick wall")
         elif args.engine_cmd == "ticks":

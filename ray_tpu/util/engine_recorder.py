@@ -150,6 +150,11 @@ class EngineRecorder(RecorderCore):
         # launches can hold at full occupancy
         self._pump: "deque[Tuple[float, float]]" = \
             deque(maxlen=cap * self.max_slots)  # rt: guarded-by(_lock)
+        #: what each compiled decode program does to the slot cache, as the
+        #: engine's batcher found it in the compiled program (``bucket``,
+        #: ``k``, ``cache_donated``, ``cache_copy_bytes_per_step``,
+        #: ``cache_bytes``); the engine points this at the batcher's list
+        self.decode_programs: List[Dict[str, Any]] = []
         self._overhead_tick_s = 0.0  # rt: guarded-by(_lock)
         self._tick_seq = 0  # rt: guarded-by(_lock)
         self._req_seq = 0  # rt: guarded-by(_lock)
@@ -437,6 +442,7 @@ class EngineRecorder(RecorderCore):
                     "decode_launch", 0.0)), 6),
             "overhead_frac": round(overhead / wall, 6) if wall > 0 else 0.0,
             "pump_bursts": len(lags),
+            "decode_programs": [dict(p) for p in self.decode_programs],
         }
         if lags:
             lags = sorted(lags)

@@ -1,0 +1,211 @@
+"""What a compiled program does to a large buffer it was meant to step in
+place, read off its optimized HLO.
+
+The serving engine's decode program takes the slot cache donated and is
+written so that nothing cache-sized is copied; whether the compiler agreed
+is in the compiled module, on any backend, with nothing run:
+``cache_traffic`` counts the bytes of every cache-shaped result that is a
+fresh buffer (a ``copy``, a slice that was materialised, a transposed or
+re-stacked piece), and of every cache-shaped update written back in place,
+each weighted by the trip counts of the loops it sits in. A refactor that
+brings a copy back shows here on a CPU run; what a copy costs in time only
+a chip run says.
+"""
+
+from __future__ import annotations
+
+import re
+from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+import numpy as np
+
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_ARRAY = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
+_CALLEE = re.compile(r"\b(body|calls|to_apply|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_CONDITION = re.compile(r"\bcondition=%?([\w.\-]+)")
+_CONSTANT = re.compile(r"\bconstant\((\d+)\)")
+# results that are no new buffer: views, plumbing, and the loops and calls
+# whose bodies are walked themselves
+_NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+              "conditional", "call", "constant", "after-all",
+              "optimization-barrier"}
+# updated where they lie (the compiler adds an explicit ``copy`` where it
+# cannot): what moves is the update, which operand that is
+_IN_PLACE = {"dynamic-update-slice": 1, "scatter": 2}
+
+
+def _split_result(rest: str) -> Tuple[str, str, str]:
+    """``<shape> <opcode>(<operands>)<attributes>`` -> (shape, opcode,
+    what follows the opcode's opening parenthesis)."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += ch == "("
+            depth -= ch == ")"
+            if depth == 0:
+                break
+        shape, rest = rest[:i + 1], rest[i + 1:].lstrip()
+    else:
+        shape, _, rest = rest.partition(" ")
+    opcode, _, tail = rest.partition("(")
+    return shape, opcode.strip(), tail
+
+
+def _operands(tail: str) -> List[str]:
+    """Names of the operands in ``a, bf16[2]{0} %b), attr=...``."""
+    depth, end = 1, len(tail)
+    for i, ch in enumerate(tail):
+        depth += ch in "([{"
+        depth -= ch in ")]}"
+        if depth == 0:
+            end = i
+            break
+    names, depth, cur = [], 0, ""
+    for ch in tail[:end] + ",":
+        depth += ch in "([{"
+        depth -= ch in ")]}"
+        if ch == "," and depth == 0:
+            if cur.strip():
+                names.append(cur.split()[-1].lstrip("%"))
+            cur = ""
+        else:
+            cur += ch
+    return names
+
+
+class _Module:
+    """Computations of an HLO module's text: name -> its instructions as
+    (name, result shape text, opcode, operand names, whole line)."""
+
+    def __init__(self, text: str):
+        self.computations: Dict[str, List[Tuple[str, str, str, List[str],
+                                                str]]] = {}
+        self.entry: Optional[str] = None
+        current = None
+        for line in text.splitlines():
+            head = _COMPUTATION.match(line)
+            if head:
+                current = self.computations.setdefault(head.group(2), [])
+                if head.group(1):
+                    self.entry = head.group(2)
+                continue
+            if line.startswith("}"):
+                current = None
+                continue
+            inst = _INSTRUCTION.match(line) if current is not None else None
+            if inst:
+                shape, opcode, tail = _split_result(inst.group(2))
+                current.append((inst.group(1), shape, opcode,
+                                _operands(tail), line))
+
+    def trips(self, line: str) -> int:
+        """How often a ``while`` runs its body: the count the compiler
+        wrote down, or the bound its condition compares a counter from
+        zero against (what a ``lax.scan`` lowers to); 1 where neither is
+        there to read."""
+        known = _TRIPS.search(line)
+        if known:
+            return int(known.group(1))
+        cond = _CONDITION.search(line)
+        insts = self.computations.get(cond.group(1), []) if cond else []
+        root = insts[-1] if insts else None
+        if root and root[2] == "compare" and "direction=LT" in root[4]:
+            for name, _, opcode, _, text in insts:
+                bound = _CONSTANT.search(text)
+                if opcode == "constant" and name in root[3] and bound:
+                    return int(bound.group(1))
+        return 1
+
+    def walk(self) -> Iterator[Tuple[int, Tuple, List[Tuple]]]:
+        """Every instruction that runs, outside fused computations, with
+        how often it runs in one execution of the module and the
+        instructions of its computation."""
+        todo, seen = [(self.entry, 1)], set()
+        while todo:
+            name, times = todo.pop()
+            if (name, times) in seen or name not in self.computations:
+                continue
+            seen.add((name, times))
+            for inst in self.computations[name]:
+                yield times, inst, self.computations[name]
+                opcode, line = inst[2], inst[4]
+                if opcode == "fusion":
+                    continue
+                trips = self.trips(line) if opcode == "while" else 1
+                for kind, callee in _CALLEE.findall(line):
+                    todo.append((callee, times * trips if kind == "body"
+                                 else times))
+                for group in _BRANCHES.findall(line):
+                    todo += [(c.strip().lstrip("%"), times)
+                             for c in group.split(",")]
+
+
+def _arrays(shape: str) -> List[Tuple[str, Tuple[int, ...]]]:
+    return [(dt, tuple(int(d) for d in dims.split(",") if d))
+            for dt, dims in _ARRAY.findall(shape)]
+
+
+def cache_traffic(compiled: Any, cache: Any, *, rows: int,
+                  steps: int) -> Dict[str, Any]:
+    """For a compiled decode program over a slot cache shaped like
+    ``cache`` [L, slots, max_len, hkv, hd] (K and V each), stepping ``rows``
+    rows ``steps`` times a launch:
+
+    - ``cache_donated``: the program's input-output aliases cover K and V;
+    - ``cache_copy_bytes_per_step``: bytes, per decode step, of results and
+      in-place updates that are cache-shaped (the cache's type, ``max_len``
+      and ``head_dim`` among the dimensions) and at least one layer's
+      ``rows`` large, times the trip counts of the loops round them;
+    - ``cache_bytes``: K and V together, to read the other against.
+    """
+    _, _, max_len, hkv, hd = cache.shape
+    itemsize = np.dtype(cache.dtype).itemsize
+    dtype = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}[
+        str(cache.dtype)]
+    floor = rows * max_len * hkv * hd * itemsize
+
+    def counted(shape: str) -> int:
+        total = 0
+        for dt, dims in _arrays(shape):
+            nbytes = int(np.prod(dims, dtype=np.int64)) * itemsize
+            if (dt == dtype and nbytes >= floor and max_len in dims
+                    and hd in dims):
+                total += nbytes
+        return total
+
+    module = _Module(compiled.as_text())
+
+    def moved(inst, within) -> int:
+        """Bytes one execution of ``inst`` moves: its result if that is
+        a new buffer, the update if it writes in place. ``within``: the
+        instructions among which its operands are defined."""
+        _, shape, opcode, operands, _ = inst
+        if opcode in _NO_BUFFER:
+            return 0
+        if opcode in _IN_PLACE:
+            update = operands[_IN_PLACE[opcode]]
+            return sum(counted(i[1]) for i in within if i[0] == update)
+        return counted(shape)
+
+    total = 0
+    for times, inst, peers in module.walk():
+        if inst[2] == "fusion":
+            callee = dict(_CALLEE.findall(inst[4])).get("calls")
+            fused = module.computations.get(callee, [])
+            writers = [i for i in fused if i[2] in _IN_PLACE
+                       and _arrays(i[1]) and _arrays(i[1])[0] in
+                       _arrays(inst[1])]
+            if writers:  # the fusion's result is its operand, updated
+                total += times * sum(moved(i, fused) for i in writers)
+                continue
+        total += times * moved(inst, peers)
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * int(np.prod(cache.shape, dtype=np.int64)) * itemsize
+    return {"cache_donated": bool(
+                mem is not None and mem.alias_size_in_bytes >= cache_bytes),
+            "cache_copy_bytes_per_step": int(total // steps),
+            "cache_bytes": cache_bytes}

@@ -29,6 +29,7 @@ from ray_tpu.parallel import train_step as ts
 from ray_tpu.parallel.context import mesh_scope
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.parallel.plan import compile_plan
+from ray_tpu.util import hlo_copies
 
 CFG_1B = llama.PRESETS["1b"]
 
@@ -96,34 +97,87 @@ def test_flash_with_traced_offset_compiles_for_v5e(topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _engine_args(topo, slots, max_len):
+def _engine_args(topo, slots, max_len, cfg=CFG_1B):
     one = SingleDeviceSharding(topo.devices[0])
     params = _on(one, jax.eval_shape(
-        lambda: llama.init_params(jax.random.key(0), CFG_1B)))
+        lambda: llama.init_params(jax.random.key(0), cfg)))
     cache = jax.ShapeDtypeStruct(
-        (CFG_1B.n_layers, slots, max_len, CFG_1B.n_kv_heads,
-         CFG_1B.head_dim), CFG_1B.compute_dtype, sharding=one)
+        (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.head_dim),
+        cfg.compute_dtype, sharding=one)
     return params, cache, lambda *shape: jax.ShapeDtypeStruct(
         shape, jnp.int32, sharding=one)
 
 
+def _cache_bytes(cache):
+    return 2 * cache.size * cache.dtype.itemsize  # K and V
+
+
 def test_engine_prefill_compiles_for_v5e_at_1b(topo):
+    """The prefill takes the slot cache donated: one row is written, the
+    cache is neither copied nor held twice."""
     params, cache, i32 = _engine_args(topo, 8, 2048)
     compiled = serving._compiled_slot_prefill(CFG_1B, 1024, 8, 2048).lower(
         params, cache, cache, i32(1, 1024), i32()).compile()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes) < 16 * 2 ** 30
+            + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes) < 16 * 2 ** 30
+    assert mem.alias_size_in_bytes >= _cache_bytes(cache)
 
 
-def test_engine_decode_compiles_for_v5e_at_1b(topo):
-    """Eight slots, eight fused decode steps: the engine's widest launch."""
-    params, cache, i32 = _engine_args(topo, 8, 2048)
-    compiled = serving._compiled_bucket_scan(CFG_1B, 8, 8, 2048, 8).lower(
-        params, cache, cache, i32(8), i32(8), i32(8)).compile()
+# the widths the benchmark's serve cells run (Mistral-7B: 8 KV heads of 128),
+# depth cut for the test's time, and the "1b" preset (4 KV heads of 64)
+CFG_7B_WIDE = llama.LlamaConfig(
+    vocab_size=32768, d_model=4096, n_layers=4, n_heads=32, n_kv_heads=8,
+    d_ff=14336, max_seq_len=2048, rope_theta=1e6, tie_embeddings=False,
+    param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("widths,slots,bucket", [
+    ("7b", 16, 16), ("7b", 16, 1), ("1b", 8, 8), ("1b", 8, 1)])
+def test_engine_decode_steps_the_cache_in_place_on_v5e(topo, widths, slots,
+                                                       bucket):
+    """Eight fused decode steps over the full bucket (the engine's widest
+    launch) and over a lone row: the TPU compiler's copies are the ones that
+    cost, so this is where the mechanism is guarded. Weights in bf16, as
+    they are served (the casts of float32 weights are temporaries of their
+    own). At both widths the cache is aliased from input to output and the
+    program holds neither a copy of the gathered cache nor a per-layer
+    stack-back. At the served widths, whose (8, 128) rows are the
+    compiler's own tile, it also holds no other: temporaries are smaller
+    than one cache, and per step exactly the layer's rows are staged for
+    the attention product (on-chip, by the chip run of PR 24). At the
+    "1b" widths the compiler re-tiles the cache round the launch (a copy
+    in and out a launch, more than one cache of temporaries): known, not
+    held to the bound."""
+    cfg = (CFG_7B_WIDE if widths == "7b"
+           else dataclasses.replace(CFG_1B, param_dtype=jnp.bfloat16))
+    params, cache, i32 = _engine_args(topo, slots, 2048, cfg)
+    compiled = serving._compiled_bucket_scan(
+        cfg, bucket, slots, 2048, 8).lower(
+        params, cache, cache, i32(bucket), i32(bucket), i32()).compile()
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes) < 16 * 2 ** 30
+            + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes) < 16 * 2 ** 30
+    assert mem.alias_size_in_bytes >= _cache_bytes(cache)
+    traffic = hlo_copies.cache_traffic(compiled, cache, rows=bucket, steps=8)
+    assert traffic["cache_donated"]
+    if widths == "7b":
+        assert mem.temp_size_in_bytes < _cache_bytes(cache)
+        assert traffic["cache_copy_bytes_per_step"] \
+            <= traffic["cache_bytes"] * bucket // slots, traffic
+    # by name: no copy of the cache as the gather laid it out ([rows, L,
+    # ...]), and no dynamic-update-slice whose update is a layer's rows
+    # (the stack-back of a cache that is the layer scan's xs and ys)
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+    for line in compiled.as_text().splitlines():
+        assert f"bf16[{bucket},{cfg.n_layers},2048,{hkv},{hd}]" not in line, \
+            line
+        if " dynamic-update-slice(" in line:
+            assert f"bf16[{bucket},2048,{hkv},{hd}]" not in line \
+                and f"bf16[{bucket},1,2048,{hkv},{hd}]" not in line \
+                and f"bf16[1,{bucket},2048,{hkv},{hd}]" not in line, line
 
 
 def test_sharded_flash_step_compiles_for_four_chips(topo):
