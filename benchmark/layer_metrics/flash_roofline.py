@@ -11,5 +11,5 @@ def read(run):
     t = run.get("trace")
     if not t:
         return None
-    share = trace.flash_roofline(t, run["device"]["kind"])
+    share = trace.kernel_roofline(t, "flash", run["device"]["kind"])
     return 100.0 * share["share"] if share else None

@@ -1,8 +1,9 @@
-"""Share of the device's busy time spent in the Pallas calls
-(``tpu_custom_call`` events of the trace; the flash kernels are the only
-ones in the step)."""
+"""Share of the device's busy time spent in the flash kernels' calls (the
+``tpu_custom_call`` events of the trace that ``benchmark/kernels/flash.py``
+knows; they are the only Pallas calls in the step)."""
 
 
 def read(run):
     t = run.get("trace")
-    return 100.0 * t["kernel_s"] / t["busy_s"] if t and t["busy_s"] else None
+    flash = t["kernels"].get("flash") if t else None
+    return 100.0 * flash["seconds"] / t["busy_s"] if flash and t["busy_s"] else None

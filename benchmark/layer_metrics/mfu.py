@@ -15,6 +15,6 @@ def read(run):
         return None
     cell = run["cell"]
     flops = arithmetic.train_flops_per_token(
-        cell["config"]["config"], cell["n_layers"], tr["seq"])
+        cell["family"], cell["config"]["config"], cell["n_layers"], tr["seq"])
     peak = arithmetic.peaks(run["device"]["kind"])["flops"] * run["device"]["count"]
     return 100.0 * flops * tr["tokens"] / tr["span_s"] / peak

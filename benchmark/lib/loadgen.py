@@ -92,20 +92,37 @@ def _requests(traffic: Dict[str, Any], dues: Sequence[float], measured: bool,
     return out
 
 
+def _arrivals(spec: Optional[Dict[str, Any]], low: float, high: float, n: int,
+              rng: np.random.Generator) -> np.ndarray:
+    """``n`` sorted arrival times in [low, high), their number fixed whatever
+    the seed. No ``spec``: the sorted values of as many uniform draws (the
+    order statistics of Poisson arrivals given their number). ``{"dist":
+    "gamma", "cv": c}``: ``n + 1`` gaps drawn from a gamma distribution of
+    that coefficient of variation (shape 1/c^2; 1 is Poisson again, above 1
+    arrivals come in bursts), their running sums rescaled so that the last
+    gap ends at ``high``."""
+    if spec is None:
+        return np.sort(rng.uniform(low, high, n))
+    if spec.get("dist") != "gamma":
+        raise ValueError(f"no arrival distribution {spec.get('dist')!r}")
+    gaps = rng.gamma(1.0 / float(spec["cv"]) ** 2, size=n + 1)
+    return low + (high - low) * np.cumsum(gaps[:n]) / gaps.sum()
+
+
 def open_schedule(traffic: Dict[str, Any], seconds: float, seed: int,
                   vocab: int, max_len: int) -> List[Request]:
     """Exactly ``rate * lead_s`` lead-in requests before the window and
-    ``rate * seconds`` inside it, each set at the sorted values of as many
-    uniform draws (the order statistics of Poisson arrivals given their
-    number)."""
+    ``rate * seconds`` inside it, each set arriving as ``traffic["arrivals"]``
+    says (``_arrivals``; absent: Poisson)."""
     rng = np.random.default_rng([seed, 0x10AD])
     rate, lead = float(traffic["rate_rps"]), float(traffic.get("lead_s", 0))
     n_lead, n_win = int(round(rate * lead)), int(round(rate * seconds))
+    how = traffic.get("arrivals")
     # the window's lengths are drawn apart from the lead-in's, so that the
     # measured multiset is the same whatever the seed
-    before = _requests(traffic, np.sort(rng.uniform(-lead, 0.0, n_lead)),
+    before = _requests(traffic, _arrivals(how, -lead, 0.0, n_lead, rng),
                        False, vocab, max_len, rng)
-    return before + _requests(traffic, np.sort(rng.uniform(0.0, seconds, n_win)),
+    return before + _requests(traffic, _arrivals(how, 0.0, seconds, n_win, rng),
                               True, vocab, max_len, rng, first_index=n_lead)
 
 

@@ -89,6 +89,10 @@ def info_lines(cell: Cell, run: Dict[str, Any]) -> List[str]:
         t = run["train"]
         out.append(f"train: {t['launches']} launches x K={t['steps_per_launch']}"
                    f" in {t['span_s']:.2f}s on mesh {t['mesh'] or 'one device'}")
+    if run.get("trace"):
+        by_scope = sorted(run["trace"]["by_scope"].items(), key=lambda kv: -kv[1])
+        out.append("by_scope: device seconds a chip by program/scope: "
+                   + ", ".join(f"{k} {s:.4f}" for k, s in by_scope))
     return out
 
 

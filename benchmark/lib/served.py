@@ -1,20 +1,50 @@
 """The replica the serve cells run: ``serve.llm.ContinuousLLM`` itself, given
 a published configuration instead of a preset's name, with the few methods
-the benchmark needs beside the chip.
-
-``ContinuousLLM.__init__`` takes a preset *name* and looks it up in
-``llama.PRESETS``; so the subclass builds the ``LlamaConfig`` from the
-configuration file, registers it under the configuration's name inside the
-replica and calls ``super().__init__``. Requests go the normal way: HTTP
-proxy -> handle -> replica -> ``ContinuousEngine`` -> stream back.
+the benchmark needs beside the chip. The configuration's family
+(``benchmark/families/``) gives the program's config, the function that makes
+the weights and the reference; nothing here knows an architecture. Requests
+go the normal way: HTTP proxy -> handle -> replica -> ``ContinuousEngine`` ->
+stream back.
 """
 
 from __future__ import annotations
 
+import contextlib
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from ray_tpu.serve.llm import ContinuousLLM
+
+from benchmark.lib import spec
+
+
+@contextlib.contextmanager
+def _front_door(name: str, cfg: Any, init_params: Callable[[Any, Any], Any],
+                facts: Dict[str, float]) -> Iterator[None]:
+    """``ContinuousLLM.__init__`` takes a preset's *name*, looks it up in
+    ``llama.PRESETS`` and calls ``llama.init_params(key, cfg)``: the only way
+    in for a config object and for weights of another module's making is
+    through those two names, for the length of the call. The weights are
+    the benchmark's to make: on the device, in one jitted call from the
+    seed, in the type they are served in. (A program change takes this
+    function's place: PERF.md, Open questions.)"""
+    import jax
+
+    from ray_tpu.models import llama
+
+    def jitted(rng, cfg):
+        llama.init_params = eager  # the family's function may build on it
+        t = time.perf_counter()
+        params = jax.block_until_ready(
+            jax.jit(init_params, static_argnums=1)(rng, cfg))
+        facts["weights_s"] = time.perf_counter() - t
+        return params
+
+    eager, llama.PRESETS[name], llama.init_params = llama.init_params, cfg, jitted
+    try:
+        yield
+    finally:
+        llama.init_params = eager
 
 
 class BenchLLM(ContinuousLLM):
@@ -28,33 +58,15 @@ class BenchLLM(ContinuousLLM):
 
         jax.devices()
         self._facts = {"backend_init_s": time.perf_counter() - t0}
-
-        from ray_tpu.models import llama
-
-        from benchmark.lib import model
-
         self._cfg_file = cfg_file
+        self._family = spec.load_family(cfg_file["family"])
         self._trace_dir = trace_dir
         name = cfg_file["name"]
-        llama.PRESETS[name] = model.program_config(
-            cfg_file, n_layers, max_seq_len=engine_args["max_len"])
-        # the weights are the benchmark's to make: on the device, in one
-        # jitted call from the seed, in the type they are served in
-        eager_init = llama.init_params
-
-        def init_params(rng, cfg):
-            t = time.perf_counter()
-            params = jax.block_until_ready(
-                jax.jit(eager_init, static_argnums=1)(rng, cfg))
-            self._facts["weights_s"] = time.perf_counter() - t
-            return params
-
-        llama.init_params = init_params
-        try:
+        cfg = self._family.program_config(cfg_file, n_layers,
+                                          max_seq_len=engine_args["max_len"])
+        with _front_door(name, cfg, self._family.init_params, self._facts):
             t = time.perf_counter()
             super().__init__(name, name=name, **engine_args)
-        finally:
-            llama.init_params = eager_init
         self._facts["engine_init_s"] = (time.perf_counter() - t
                                         - self._facts["weights_s"])
 
@@ -108,9 +120,7 @@ class BenchLLM(ContinuousLLM):
         import jax.numpy as jnp
         import numpy as np
 
-        from benchmark.lib import reference
-
-        hf, out, size = self._cfg_file["config"], [], self.engine.max_len
+        out, size = [], self.engine.max_len
         for s in samples:
             prompt, toks = s["prompt"], s["tokens"]
             first, n = len(prompt) - 1, len(toks)
@@ -118,8 +128,9 @@ class BenchLLM(ContinuousLLM):
             ctx[0, :first + n] = prompt + toks[:-1]
             following[first:first + n] = toks
             m = {k: np.asarray(v)[first:first + n] for k, v in
-                 reference.token_margins(self.params, jnp.asarray(ctx),
-                                         jnp.asarray(following), hf).items()}
+                 self._family.token_margins(
+                     self.params, jnp.asarray(ctx), jnp.asarray(following),
+                     self._cfg_file, rows=(first, first + n)).items()}
             out.append({"tokens": n, "finite": bool(m["finite"].all()),
                         "worst_margin": float(m["margin"].max()),
                         "not_argmax": int((m["margin"] > 0).sum()),

@@ -3,17 +3,23 @@
 A cell of ``workloads`` names a configuration and a traffic mix; the harness
 finds ``benchmark/configs/<config>.json``, ``benchmark/traffic/<traffic>.json``
 and, for every per-layer metric, ``benchmark/layer_metrics/<metric>.py`` by
-that name alone. Adding a cell, a configuration, a mix or a per-layer metric
-is adding files and entries; nothing here knows any of them.
+that name alone. The configuration names its family, found as
+``benchmark/families/<family>.py``: the one place that knows an architecture
+(the program's config and weights, the plain reference, the arithmetic). A
+device trace is costed by every file of ``benchmark/kernels/``. Adding a cell,
+a configuration, a family, a kernel, a mix or a per-layer metric is adding
+files and entries; nothing here knows any of them.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
 import re
 import tempfile
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -129,19 +135,55 @@ def metrics_of(bench: Dict[str, Any], kind: str, cell: str
     return mine
 
 
-def load_reader(name: str, root: str = ROOT) -> Callable[[Dict[str, Any]], Any]:
-    """``benchmark/layer_metrics/<name>.py``'s ``read(run)``."""
-    _check_name("per_layer metric", name)
-    path = os.path.join(root, BENCH_DIR, "layer_metrics", name + ".py")
+def _load_module(kind: str, name: str, root: str, needs: List[str]) -> ModuleType:
+    """``benchmark/<kind>/<name>.py``, loaded under a name of its own, with
+    every callable of ``needs``."""
+    _check_name(kind, name)
+    path = os.path.join(root, BENCH_DIR, kind, name + ".py")
     if not os.path.exists(path):
-        raise SpecError(f"per-layer metric {name!r} has no reader at {path}")
+        raise SpecError(f"{kind} {name!r} has no file at {path}")
     spec = importlib.util.spec_from_file_location(
-        "benchmark_layer_metric_" + re.sub(r"\W", "_", name), path)
+        f"benchmark_{kind}_" + re.sub(r"\W", "_", name), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    if not callable(getattr(module, "read", None)):
-        raise SpecError(f"{path} defines no read(run)")
-    return module.read
+    for attr in needs:
+        if not callable(getattr(module, attr, None)):
+            raise SpecError(f"{path} defines no {attr}()")
+    return module
+
+
+def load_reader(name: str, root: str = ROOT) -> Callable[[Dict[str, Any]], Any]:
+    """``benchmark/layer_metrics/<name>.py``'s ``read(run)``."""
+    return _load_module("layer_metrics", name, root, ["read"]).read
+
+
+FAMILY_API = ["program_config", "init_params", "logits", "token_margins", "loss",
+              "matmul_params", "attention_flops_per_token",
+              "cache_bytes_per_position"]
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str, root: str = ROOT) -> ModuleType:
+    """``benchmark/families/<name>.py``: what a configuration file's
+    ``family`` names. Importing one imports neither JAX nor the program
+    (the parent reads its arithmetic); its functions do. One module object
+    per file, because its reference block keys a compiled program. A family
+    that builds on another loads it from ``root_of(__file__)``."""
+    return _load_module("families", name, root, FAMILY_API)
+
+
+def root_of(path: str) -> str:
+    """The checkout that a file of ``benchmark/<kind>/`` lies in."""
+    return os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(path))))
+
+
+def load_kernels(root: str = ROOT) -> Dict[str, ModuleType]:
+    """Every ``benchmark/kernels/<kernel>.py`` by the file's name, each with
+    a ``match(event_name)``: the directory is the list."""
+    folder = os.path.join(root, BENCH_DIR, "kernels")
+    names = sorted(f[:-3] for f in os.listdir(folder)
+                   if f.endswith(".py") and not f.startswith("_"))
+    return {n: _load_module("kernels", n, root, ["match"]) for n in names}
 
 
 class Cell:
@@ -178,6 +220,10 @@ class Cell:
         self.per_layer = metrics_of(self.bench, "per_layer", workload)
         self.readers = {m["name"]: load_reader(m["name"], root)
                         for m in self.per_layer}
+
+    @property
+    def family(self) -> ModuleType:
+        return load_family(self.config["family"], self.root)
 
     @property
     def phase(self) -> str:

@@ -102,15 +102,16 @@ def train_loop(config: Dict[str, Any]) -> None:
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
     from ray_tpu.train.driver import StepDriver
 
-    from benchmark.lib import model, reference, trace
+    from benchmark.lib import model, spec, trace
 
     traffic, cfg_file = config["traffic"], config["cfg_file"]
     seconds = float(config["seconds"])
     k = train.get_fast_path().steps_per_launch
     batch, seq = traffic["batch"], traffic["seq"]
-    cfg = model.program_config(cfg_file, config["n_layers"], max_seq_len=seq,
-                               attn_impl=traffic["attn_impl"],
-                               loss_chunk=traffic["loss_chunk"])
+    family = spec.load_family(cfg_file["family"])
+    cfg = family.program_config(cfg_file, config["n_layers"], max_seq_len=seq,
+                                attn_impl=traffic["attn_impl"],
+                                loss_chunk=traffic["loss_chunk"])
     optimizer = ts.default_optimizer(lr=traffic["lr"], warmup_steps=10,
                                      total_steps=10_000)
     if len(devices) > 1:
@@ -144,10 +145,9 @@ def train_loop(config: Dict[str, Any]) -> None:
     # the reference, before the state is donated: the loss of the first
     # step is the loss of the initial parameters on the first batch
     t = time.perf_counter()
-    ref = reference.loss(
+    ref = family.loss(
         params, jax.device_put(jnp.asarray(batch0),
-                               NamedSharding(mesh, PartitionSpec())),
-        cfg_file["config"], cfg_file["assumed"].get("capacity_factor"))
+                               NamedSharding(mesh, PartitionSpec())), cfg_file)
     ref = {name: float(v) for name, v in ref.items()}
     setup["reference_check_s"] = time.perf_counter() - t
 

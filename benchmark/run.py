@@ -77,7 +77,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         shutil.rmtree(trace_dir, ignore_errors=True)  # reduced in the worker
     run["seconds"] = args.seconds
     run["cell"] = {"config": cell.config, "traffic": cell.traffic,
-                   "n_layers": cell.n_layers(), "chips": cell.chips}
+                   "n_layers": cell.n_layers(), "chips": cell.chips,
+                   "family": cell.family}
     if "jax" in sys.modules:
         raise RuntimeError("the parent process imported jax")
     for line in results.info_lines(cell, run):

@@ -2,10 +2,15 @@
 the seed, requests are timed from their due instant, and how late the
 generator ran is reported."""
 
+import hashlib
+import json
 import os
 import sys
 import threading
 import time
+
+import numpy as np
+import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import benchmark_testlib as lib  # noqa: E402
@@ -45,6 +50,45 @@ def test_every_seed_offers_the_same_load():
     assert lengths(a) == lengths(b)  # the multiset; the order differs
     assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
     assert a[20].prompt[:8] != b[20].prompt[:8]  # unshared tokens
+
+
+def test_without_arrivals_the_schedule_is_the_parents_to_the_bit():
+    """``chat-steady`` at 51 s, seed 1, as the parent commit (PR 24) drew
+    it: a hash over every due time (as hex), prompt, answer length and
+    whether it is measured."""
+    with open(os.path.join(lib.REPO, "benchmark/traffic/chat-steady.json")) as f:
+        chat = json.load(f)
+    assert "arrivals" not in chat
+    sched = loadgen.open_schedule(chat, 51, 1, 32768, 2048)
+    assert len(sched) == 118 and sched[0].due == -7.838710365928552
+    digest = hashlib.sha256(json.dumps(
+        [[float(r.due).hex(), r.prompt, r.n_new, r.measured] for r in sched]
+    ).encode()).hexdigest()
+    assert digest == ("4c98c72cd56960dbfc3ce2d2dc36be23"
+                      "04feb2d7eefb39d6dc4cdd5aff862293")
+
+
+def test_gamma_arrivals_come_in_bursts_and_offer_the_same_load():
+    bursty = {**CHAT, "rate_rps": 200, "arrivals": {"dist": "gamma", "cv": 3}}
+    lengths = []
+    for seed in (1, 2, 4000000007):
+        sched = loadgen.open_schedule(bursty, 50, seed, 32768, 4096)  # no clipping
+        win = [r for r in sched if r.measured]
+        assert len(win) == 10_000 and len(sched) - len(win) == 400  # exact
+        assert all(0 <= r.due < 50 for r in win)
+        assert all(-2 <= r.due < 0 for r in sched if not r.measured)
+        assert [r.due for r in win] == sorted(r.due for r in win)
+        gaps = np.diff([r.due for r in win])
+        assert abs(gaps.std() / gaps.mean() - 3.0) < 0.3
+        lengths.append((sorted(len(r.prompt) for r in win),
+                        sorted(r.n_new for r in win)))
+    assert lengths[0] == lengths[1] == lengths[2]  # the multisets, whatever the seed
+    steady = loadgen.open_schedule({**bursty, "arrivals": {"dist": "gamma", "cv": 1}},
+                                   50, 1, 32768, 2048)
+    gaps = np.diff([r.due for r in steady if r.measured])
+    assert abs(gaps.std() / gaps.mean() - 1.0) < 0.1  # cv 1 is Poisson again
+    with pytest.raises(ValueError, match="no arrival distribution"):
+        loadgen.open_schedule({**CHAT, "arrivals": {"dist": "weibull"}}, 5, 1, 100, 2048)
 
 
 def test_closed_plan_gives_each_client_its_own_requests():

@@ -1,6 +1,7 @@
-"""The benchmark's float32 reference against the program's own models, at a
-small size on the CPU: ``llama.forward``, ``moe.forward``, both losses, and
-prefill-then-decode through the engine's cache."""
+"""Each family's float32 reference against the program's own models, at a
+small size on the CPU: ``llama.forward``, ``moe.forward``, the losses, and
+prefill-then-decode through the engine's cache. The third family exists in
+a copy only (``benchmark_testlib``), as a later PR's would."""
 
 import dataclasses
 import os
@@ -15,7 +16,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import benchmark_testlib as lib  # noqa: E402
 
 sys.path.insert(0, lib.REPO)
-from benchmark.lib import model, reference  # noqa: E402
+from benchmark.lib import spec  # noqa: E402
 from ray_tpu.models import llama, moe, serving  # noqa: E402
 
 # With float32 compute the program and the reference do the same mathematics
@@ -28,8 +29,17 @@ F32_TOL = 3e-4
 BF16_SCALE_SHARE = 1 / 32
 
 
-def _cfg(name, **over):
-    cfg = model.program_config(lib.CONFIGS[name], 2, max_seq_len=96)
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return lib.make_copy(str(tmp_path_factory.mktemp("bench-ref")))
+
+
+def _family(name, root=spec.ROOT):
+    return spec.load_family(lib.CONFIGS[name]["family"], root)
+
+
+def _cfg(name, root=spec.ROOT, **over):
+    cfg = _family(name, root).program_config(lib.CONFIGS[name], 2, max_seq_len=96)
     return dataclasses.replace(cfg, param_dtype=jnp.float32, **over)
 
 
@@ -38,34 +48,37 @@ def _tokens(seed, shape, vocab=256):
                        jnp.int32)
 
 
-@pytest.mark.parametrize("name,fam", [("tiny-dense", llama), ("tiny-moe", moe)])
-def test_forward_matches_reference_in_float32(name, fam):
-    cfg = _cfg(name, compute_dtype=jnp.float32, remat=False)
-    params = fam.init_params(jax.random.key(0), cfg)
+FAMILIES = [("tiny-dense", llama), ("tiny-moe", moe), ("tiny-third", llama)]
+
+
+@pytest.mark.parametrize("name,fam", FAMILIES)
+def test_forward_matches_reference_in_float32(root, name, fam):
+    cfg = _cfg(name, root, compute_dtype=jnp.float32, remat=False)
+    params = _family(name, root).init_params(jax.random.key(0), cfg)
     tokens = _tokens(1, (2, 48))
     if fam is moe:
         # the reference's plain forward routes without dropping, as Mixtral
         # does; give the program the capacity that never drops
         cfg = dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
     got = np.asarray(fam.forward(params, tokens, cfg))
-    want = np.asarray(reference.logits(params, tokens, lib.CONFIGS[name]["config"]))
+    want = np.asarray(_family(name, root).logits(params, tokens, lib.CONFIGS[name]))
     assert np.abs(got - want).max() < F32_TOL * np.abs(want).max()
 
 
-@pytest.mark.parametrize("name,fam", [("tiny-dense", llama), ("tiny-moe", moe)])
-def test_loss_matches_reference(name, fam):
+@pytest.mark.parametrize("name,fam", FAMILIES)
+def test_loss_matches_reference(root, name, fam):
     """The training loss: chunked cross entropy, and with experts the
     capacity drops over the whole batch and the weighted balancing loss."""
-    cfg = _cfg(name, compute_dtype=jnp.float32, loss_chunk=16)
-    params = fam.init_params(jax.random.key(2), cfg)
+    cfg = _cfg(name, root, compute_dtype=jnp.float32, loss_chunk=16)
+    family = _family(name, root)
+    params = family.init_params(jax.random.key(2), cfg)
     tokens = _tokens(3, (4, 65))
     got = float(fam.lm_loss(params, {"tokens": tokens}, cfg))
-    want = reference.loss(params, tokens, lib.CONFIGS[name]["config"],
-                          lib.CONFIGS[name]["assumed"].get("capacity_factor"))
+    want = family.loss(params, tokens, lib.CONFIGS[name])
     assert abs(got - float(want["loss"])) < F32_TOL * got
     if fam is moe:
         assert float(want["aux"]) > 0.9  # ~1 when routing is balanced
-        dropless = reference.loss(params, tokens, lib.CONFIGS[name]["config"], None)
+        dropless = family.loss(params, tokens, {**lib.CONFIGS[name], "assumed": {}})
         assert float(dropless["ce"]) != float(want["ce"])  # something dropped
 
 
@@ -79,7 +92,6 @@ def test_prefill_then_decode_through_the_cache():
     prompts = [np.asarray(_tokens(5 + i, (n,))) for i, n in enumerate((8, 16, 32))]
     ids = [batcher.submit(p, 24) for p in prompts]
     out = batcher.run_to_completion()
-    hf = lib.CONFIGS["tiny-dense"]["config"]
     for rid, prompt in zip(ids, prompts):
         toks = out[rid]
         assert len(toks) == 24
@@ -88,8 +100,9 @@ def test_prefill_then_decode_through_the_cache():
         ctx[0, :first + 24] = np.concatenate([prompt, toks[:-1]])
         following = np.zeros(96, np.int32)
         following[first:first + 24] = toks
-        m = reference.token_margins(params, jnp.asarray(ctx),
-                                    jnp.asarray(following), hf)
+        m = _family("tiny-dense").token_margins(
+            params, jnp.asarray(ctx), jnp.asarray(following),
+            lib.CONFIGS["tiny-dense"], rows=(first, first + 24))
         rows = slice(first, first + 24)
         assert bool(np.asarray(m["finite"])[rows].all())
         assert (np.asarray(m["margin"])[rows].max()
@@ -100,6 +113,6 @@ def test_a_wrong_token_fails_the_margin():
     cfg = _cfg("tiny-dense")
     params = llama.init_params(jax.random.key(4), cfg)
     ctx = _tokens(9, (1, 32))
-    logits = np.asarray(reference.logits(params, ctx, lib.CONFIGS["tiny-dense"]["config"]))[0]
+    logits = np.asarray(_family("tiny-dense").logits(params, ctx, lib.CONFIGS["tiny-dense"]))[0]
     worst = logits.max(-1) - logits.min(-1)  # the least likely token
     assert worst.min() > np.abs(logits).max() * BF16_SCALE_SHARE

@@ -63,18 +63,63 @@ def test_configuration_keeps_its_sources_widths(name):
 
 
 def test_arithmetic_at_the_published_widths():
+    dense, moe = spec.load_family("dense"), spec.load_family("moe")
     mistral = PUBLISHED["mistral-7b-v0.3"]
-    assert arithmetic.layer_matmul_params(mistral) == 218_103_808
-    assert arithmetic.total_params(mistral, 32) == 7_248_023_552  # "7.25B"
+    assert dense.matmul_params(mistral, 1) == 218_103_808
+    assert arithmetic.total_params(dense, mistral, 32) == 7_248_023_552  # "7.25B"
     mixtral = PUBLISHED["mixtral-8x7b-v0.1"]
-    assert arithmetic.total_params(mixtral, 32) == 46_702_792_704  # "46.7B"
-    assert arithmetic.layer_matmul_params(mixtral) == 394_297_344   # 2 of 8
+    assert arithmetic.total_params(moe, mixtral, 32) == 46_702_792_704  # "46.7B"
+    assert moe.matmul_params(mixtral, 1) == 394_297_344   # 2 of 8
     # 6 per matmul parameter and 6*s*heads*head_dim of causal attention
-    assert arithmetic.train_flops_per_token(mistral, 6, 4096) == pytest.approx(
+    assert arithmetic.train_flops_per_token(dense, mistral, 6, 4096) == pytest.approx(
         6 * (6 * 218_103_808 + 4096 * 32768) + 6 * 6 * 4096 * 4096)
-    assert arithmetic.kv_bytes_per_position(mistral, 16) == 65536
+    assert dense.cache_bytes_per_position(mistral, 16) == 65536
     with pytest.raises(KeyError):
         arithmetic.peaks("cpu")
+
+
+# what the parent commit's arithmetic.py gave (PR 24), at the cells' depths:
+# train_flops_per_token at s4096, weight_bytes, kv_bytes_per_position,
+# total_params
+@pytest.mark.parametrize("name,depth,flops,weights,cache,params", [
+    ("mistral-7b-v0.3", 15, 21944598528.0, 6811803648, 61440, 3540119552),
+    ("mistral-7b-v0.3", 6, 9261023232.0, 2885787648, 24576, 1577111552),
+    ("mixtral-8x7b-v0.1", 3, 8185774080.0, 8969773056, 12288, 4615958528),
+])
+def test_the_moved_arithmetic_gives_what_it_gave(name, depth, flops, weights,
+                                                 cache, params):
+    cfg = spec.Cell(next(w["name"] for w in BENCH["workloads"]
+                         if w["config"] == name)).config
+    family, hf = spec.load_family(cfg["family"]), cfg["config"]
+    assert depth in cfg["reduced"]["num_hidden_layers"].values()
+    assert arithmetic.train_flops_per_token(family, hf, depth, 4096) == flops
+    assert arithmetic.weight_bytes(family, hf, depth) == weights
+    assert family.cache_bytes_per_position(hf, depth) == cache
+    assert arithmetic.total_params(family, hf, depth) == params
+
+
+def test_the_library_knows_no_architecture():
+    """Nothing under ``benchmark/lib``, nor the two entry points, tells one
+    family from another: a family's name, its configuration keys and the
+    program's model modules appear in ``benchmark/families/`` alone. The one
+    exception is ``served.py``'s way through ``ContinuousLLM``'s constructor,
+    which only a program change can remove."""
+    files = [os.path.join("benchmark", f) for f in ("run.py", "sweep.py")]
+    files += [os.path.join("benchmark", "lib", f)
+              for f in sorted(os.listdir(os.path.join(lib.REPO, "benchmark", "lib")))
+              if f.endswith(".py")]
+    banned = re.compile(r"num_local_experts|num_experts_per_tok|capacity_factor"
+                        r"|intermediate_size|models import moe|models\.moe"
+                        r"|[\"']dense[\"']|[\"']moe[\"']")
+    for rel in files:
+        with open(os.path.join(lib.REPO, rel)) as f:
+            text = f.read()
+        assert not banned.search(text), (rel, banned.search(text).group(0))
+        if not rel.endswith("served.py"):
+            assert "llama" not in text, rel
+    assert sorted(os.listdir(os.path.join(lib.REPO, "benchmark", "families"))) \
+        >= ["dense.py", "moe.py"]
+    assert "flash" in spec.load_kernels()
 
 
 def test_contract_shapes():
@@ -131,14 +176,18 @@ def test_a_bad_cell_name_or_a_missing_file_is_refused(tmp_path):
         spec.Cell("absent", root)
     with pytest.raises(spec.SpecError, match="no such file"):
         spec.Cell("tiny-filler-0", root)  # its traffic file was never written
-    with pytest.raises(spec.SpecError, match="no reader"):
+    with pytest.raises(spec.SpecError, match="has no file"):
         spec.load_reader("absent_metric", root)
+    with pytest.raises(spec.SpecError, match="has no file"):
+        spec.load_family("absent-family", root)
 
 
-def test_adding_a_cell_a_configuration_a_mix_and_a_metric_is_adding_files(tmp_path):
-    """``make_copy`` adds two configurations, four mixes and eight cells by
-    writing new files and appending entries. Here a per-layer metric joins
-    them the same way, and no file that was there is touched."""
+def test_adding_an_architecture_a_cell_a_mix_and_a_metric_is_adding_files(tmp_path):
+    """``make_copy`` adds three configurations, a family, a kernel, five
+    mixes and eleven cells by writing new files and appending entries. Here
+    a per-layer metric joins them the same way. No file that was there is
+    touched, and BENCHMARK.json only gained: every list of the original is
+    the beginning of the copy's."""
     before = {}
     for d, _, files in os.walk(os.path.join(lib.REPO, "benchmark")):
         for f in files:
@@ -160,6 +209,29 @@ def test_adding_a_cell_a_configuration_a_mix_and_a_metric_is_adding_files(tmp_pa
     for rel, data in before.items():
         with open(os.path.join(root, rel), "rb") as fh:
             assert fh.read() == data, rel
+    added = {os.path.relpath(os.path.join(d, f), root)
+             for d, _, files in os.walk(os.path.join(root, "benchmark"))
+             for f in files if not f.endswith(".pyc")} - set(before)
+    assert {"benchmark/families/tiny-third.py", "benchmark/kernels/tiny-matmul.py",
+            "benchmark/traffic/tiny-bursty.json",
+            "benchmark/configs/tiny-third.json"} <= added
+
+    with open(os.path.join(lib.REPO, "BENCHMARK.json")) as f:
+        old = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        new = json.load(f)
+    assert set(new) == set(old)
+    for key in ("command", "paths", "run_seconds"):
+        assert new[key] == old[key]
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        assert len(new[key]) >= len(old[key])
+        for was, now in zip(old[key], new[key]):  # the original is a prefix
+            assert set(now) == set(was)
+            for field, value in was.items():
+                if field == "workloads":
+                    assert now[field][:len(value)] == value
+                else:
+                    assert now[field] == value, (key, was["name"], field)
 
     cell = spec.Cell("tiny-train", root)
     assert cell.config["name"] == "tiny-dense" and cell.n_layers() == 2
@@ -174,6 +246,23 @@ def test_adding_a_cell_a_configuration_a_mix_and_a_metric_is_adding_files(tmp_pa
     assert "tokens_per_launch" not in spec.Cell("tiny-moe-x4", root).readers
     assert spec.Cell("tiny-moe-x4", root).chips == 4
     assert results.end_to_end_value("train_tok_s_chip", cell, run) == 512.0
+
+    # the third family is the file of that name in the copy, and its
+    # arithmetic is its own: the dense family cannot read its keys
+    third = spec.Cell("tiny-third-train", root)
+    assert third.family.__file__ == os.path.join(
+        root, "benchmark/families/tiny-third.py")
+    assert spec.Cell("tiny-moe-decode", root).family.__file__.startswith(root)
+    hf = third.config["config"]
+    assert third.family.matmul_params(hf, 2) == spec.load_family("dense").matmul_params(
+        lib.TINY, 2)
+    with pytest.raises(KeyError):
+        spec.load_family("dense").matmul_params(hf, 2)
+    tpu = {**run, "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+           "cell": {"config": third.config, "n_layers": 2, "family": third.family}}
+    mfu = spec.load_reader("mfu", root)(tpu)
+    assert mfu == pytest.approx(100.0 * 512.0 / 197e12 * arithmetic.train_flops_per_token(
+        third.family, hf, 2, 64))
 
 
 def test_percentile_metrics_are_read_by_name():
