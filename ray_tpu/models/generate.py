@@ -21,8 +21,9 @@ Two forwards share one block arithmetic (``_qkv``, ``_after_attention``):
   serving engine's decode step, whose cache is donated: nothing the size
   of the cache is copied.
 
-Works for both model families: llama densely, MoE via its block functions
-(each family exposes ``cache_block``-compatible attention weights).
+Works for both model families: llama densely, MoE through
+``moe.served_ffn_half`` (drop-free routing whose work follows the routed
+tokens), whose per-layer routing counters the ``_stats`` forms return.
 """
 
 from __future__ import annotations
@@ -64,33 +65,58 @@ def _qkv(cfg, x, layer, sin, cos, positions):
             f"the einsum path (same math; the pallas kernel is a "
             f"long-sequence training implementation).")
     h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
-    q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
+    q = apply_rope(llama.project_qk(cfg, h, layer, "q").reshape(b, s, hq, hd),
                    sin, cos, positions)
-    k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd),
+    k = apply_rope(llama.project_qk(cfg, h, layer, "k").reshape(b, s, hkv, hd),
                    sin, cos, positions)
     v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
     return q, k, v
 
 
-def _after_attention(cfg, x, attn, layer):
+def _split_experts(layers: Params) -> Tuple[Params, Optional[Params]]:
+    """(what a layer scan slices a layer at a time, what it must leave
+    whole): a sparse model's stacked expert matrices are read where they
+    lie (``moe.served_ffn_half``); a dense model has none."""
+    if "router" not in layers:
+        return layers, None
+    from ray_tpu.models import moe
+
+    return ({k: v for k, v in layers.items() if k not in moe.EXPERT_WEIGHTS},
+            {k: layers[k] for k in moe.EXPERT_WEIGHTS})
+
+
+def _after_attention(cfg, x, attn, layer, experts=None, index=None):
     """The rest of the block: output projection and residual (still the
-    ``attn`` scope's), then the feed-forward half."""
+    ``attn`` scope's), then the feed-forward half. Returns (hidden,
+    stats): a sparse layer's routing counters (``moe.SERVED_STATS``), a
+    dense layer's None. ``experts`` and ``index``: ``_split_experts``'s
+    second part and which layer this is."""
     b, s, _ = x.shape
     with jax.named_scope("attn"):
         x = x + attn.reshape(b, s, -1) @ layer["wo"].astype(cfg.compute_dtype)
-    with jax.named_scope("mlp"):
-        if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
-            return llama.ffn_half(cfg, x, layer)
-        # MoE FFN: drop-free inference routing (shared ffn_half)
-        from ray_tpu.models import moe
+    if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
+        with jax.named_scope("mlp"):
+            return llama.ffn_half(cfg, x, layer), None
+    # MoE FFN: drop-free inference routing under its own four scopes
+    from ray_tpu.models import moe
 
-        return moe.ffn_half(cfg, x, layer, drop_free=True)[0]
+    return moe.served_ffn_half(cfg, x, layer, experts, index)
 
 
-def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos):
+def _fold_stats(stats):
+    """The layers' stacked routing counters as one launch-sized vector."""
+    if stats is None:
+        return None
+    from ray_tpu.models import moe
+
+    return moe.fold_served_stats(stats)
+
+
+def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos,
+                      experts=None, index=None):
     """One decoder block over [B, S, d] at absolute position ``pos``,
     reading/writing the layer's [B, max_len, hkv, hd] cache slices.
-    Returns (hidden, new_cache_k, new_cache_v)."""
+    Returns (hidden, new_cache_k, new_cache_v, stats)."""
     b, s, _ = x.shape
     # scopes are names only: they group the block's operations in a
     # device trace (``attn``, ``mlp``) and change nothing that is computed
@@ -100,7 +126,8 @@ def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos):
         cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, pos, 0, 0))
         cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, pos, 0, 0))
         attn = mha(q, cache_k, cache_v, causal=True, q_offset=pos)
-    return _after_attention(cfg, x, attn, layer), cache_k, cache_v
+    x, stats = _after_attention(cfg, x, attn, layer, experts, index)
+    return x, cache_k, cache_v, stats
 
 
 def _head(params: Params, cfg, x):
@@ -115,36 +142,61 @@ def _head(params: Params, cfg, x):
 def _forward_with_cache(params: Params, tokens: jax.Array,
                         cfg, cache: Dict, pos,
                         last_only: bool = True) -> Tuple[jax.Array, Dict]:
+    """``_forward_with_cache_stats`` without the routing counters."""
+    return _forward_with_cache_stats(params, tokens, cfg, cache, pos,
+                                     last_only)[:2]
+
+
+def _forward_with_cache_stats(params: Params, tokens: jax.Array,
+                              cfg, cache: Dict, pos,
+                              last_only: bool = True
+                              ) -> Tuple[jax.Array, Dict, Optional[jax.Array]]:
     """tokens [B, S] at absolute position ``pos`` -> (logits, updated
-    cache). ``last_only`` projects ONLY the final position to the vocab —
-    generation never needs the full [B, S, V] prefill logits, which at 32k
-    vocab would dominate HBM (the same blowup llama's loss_chunk avoids)."""
+    cache, the sparse layers' routing counters or None). ``last_only``
+    projects ONLY the final position to the vocab — generation never needs
+    the full [B, S, V] prefill logits, which at 32k vocab would dominate
+    HBM (the same blowup llama's loss_chunk avoids)."""
     cdt = cfg.compute_dtype
     x = params["embed"].astype(cdt)[tokens]
     max_len = cache["k"].shape[2]
     sin, cos = rope_angles(max_len, cfg.head_dim, cfg.rope_theta, cdt)
 
+    layers, experts = _split_experts(params["layers"])
+
     def body(carry, sl):
         x = carry
-        layer, ck, cv = sl
-        x, ck, cv = _block_with_cache(cfg, x, layer, ck, cv, sin, cos, pos)
-        return x, (ck, cv)
+        layer, ck, cv, *index = sl
+        x, ck, cv, stats = _block_with_cache(cfg, x, layer, ck, cv, sin, cos,
+                                             pos, experts, *index)
+        return x, (ck, cv, stats)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    xs = (layers, cache["k"], cache["v"])
+    if experts is not None:  # a sparse layer has to know which it is
+        xs += (jnp.arange(cfg.n_layers),)
+    x, (new_k, new_v, stats) = jax.lax.scan(body, x, xs)
     with jax.named_scope("head_sample"):
         logits = _head(params, cfg, x[:, -1:, :] if last_only else x)
-    return logits, {"k": new_k, "v": new_v}
+    return logits, {"k": new_k, "v": new_v}, _fold_stats(stats)
 
 
 def decode_step_in_place(params: Params, tok: jax.Array, cfg,
                          ck: jax.Array, cv: jax.Array, slot0,
                          pos: jax.Array) -> Tuple[jax.Array, jax.Array,
                                                   jax.Array]:
+    """``decode_step_in_place_stats`` without the routing counters."""
+    return decode_step_in_place_stats(params, tok, cfg, ck, cv, slot0,
+                                      pos)[:3]
+
+
+def decode_step_in_place_stats(params: Params, tok: jax.Array, cfg,
+                               ck: jax.Array, cv: jax.Array, slot0,
+                               pos: jax.Array
+                               ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                          Optional[jax.Array]]:
     """One decode step for the ``B`` cache rows ``slot0 .. slot0 + B`` of
     a slot cache ``ck``/``cv`` [L, slots, max_len, hkv, hd]: ``tok`` [B]
     is each row's token AT its own position ``pos`` [B]. Returns (logits
-    [B, V] float32, ck, cv).
+    [B, V] float32, ck, cv, the sparse layers' routing counters or None).
 
     The block arithmetic is ``_block_with_cache``'s; what differs is the
     cache's way through the step. It rides the layer scan's carry, each
@@ -174,13 +226,15 @@ def decode_step_in_place(params: Params, tok: jax.Array, cfg,
             cv = cv.at[l, rows, pos].set(v[:, 0], mode="drop")
             attn = mha(q, layer_rows(ck, l), layer_rows(cv, l), causal=True,
                        q_offset=pos)
-        return (_after_attention(cfg, x, attn, layer), ck, cv), None
+        x, stats = _after_attention(cfg, x, attn, layer, experts, l)
+        return (x, ck, cv), stats
 
-    (x, ck, cv), _ = jax.lax.scan(
-        body, (x, ck, cv), (params["layers"], jnp.arange(cfg.n_layers)))
+    layers, experts = _split_experts(params["layers"])
+    (x, ck, cv), stats = jax.lax.scan(
+        body, (x, ck, cv), (layers, jnp.arange(cfg.n_layers)))
     with jax.named_scope("head_sample"):
         logits = _head(params, cfg, x)[:, 0, :]
-    return logits, ck, cv
+    return logits, ck, cv, _fold_stats(stats)
 
 
 def generate(params: Params, prompt: jax.Array, cfg,

@@ -65,16 +65,25 @@ class LlamaConfig:
     pipeline_axis: Optional[str] = None
     pipeline_microbatches: int = 4
     pipeline_schedule: str = "gpipe"
+    # QK-norm as OLMoE has it: a learned RMSNorm over the whole q and the
+    # whole k projection, before the split into heads and before RoPE
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    def qk_norm_params(self) -> int:
+        """One layer's ``q_norm`` and ``k_norm`` weights (0 without them)."""
+        return ((self.n_heads + self.n_kv_heads) * self.head_dim
+                if self.qk_norm else 0)
+
     def num_params(self) -> int:
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
         hd = self.head_dim
         per_layer = (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                     + self.n_heads * hd * d + 3 * d * f + 2 * d)
+                     + self.n_heads * hd * d + 3 * d * f + 2 * d
+                     + self.qk_norm_params())
         head = 0 if self.tie_embeddings else d * v
         return v * d + l * per_layer + d + head
 
@@ -124,9 +133,25 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
         },
         "final_norm": jnp.ones((d,), cfg.param_dtype),
     }
+    if cfg.qk_norm:
+        params["layers"]["q_norm"] = jnp.ones((L, hq * hd), cfg.param_dtype)
+        params["layers"]["k_norm"] = jnp.ones((L, hkv * hd), cfg.param_dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = norm_init(jax.random.fold_in(rng, 99), (d, cfg.vocab_size), d)
     return params
+
+
+def project_qk(cfg: LlamaConfig, h: jax.Array, layer: Params,
+               which: str) -> jax.Array:
+    """The ``which`` ("q" or "k") projection of the normed hidden ``h``
+    [B, S, d], still [B, S, heads * hd]: with ``qk_norm`` it goes through
+    its RMSNorm whole, before the split into heads and before RoPE. Shared
+    by the training blocks and the cached ones (``generate._qkv``)."""
+    cdt = cfg.compute_dtype
+    y = h @ layer["w" + which].astype(cdt)
+    if cfg.qk_norm:
+        y = rmsnorm(y, layer[which + "_norm"].astype(cdt), cfg.norm_eps)
+    return y
 
 
 def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
@@ -139,8 +164,8 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     cdt = cfg.compute_dtype
 
     h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
-    q = (h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd)
-    k = (h @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd)
+    q = project_qk(cfg, h, layer, "q").reshape(b, s, hq, hd)
+    k = project_qk(cfg, h, layer, "k").reshape(b, s, hkv, hd)
     v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)

@@ -39,6 +39,9 @@ class MoEConfig(llama.LlamaConfig):
     top_k: int = 2
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    # top-k gates divided by their sum (Mixtral's convention); off, they
+    # are the softmax's own values (OLMoE's ``norm_topk_prob: false``)
+    norm_topk_prob: bool = True
 
     def num_params(self) -> int:
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
@@ -46,7 +49,7 @@ class MoEConfig(llama.LlamaConfig):
         attn = (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
                 + self.n_heads * hd * d)
         moe = self.n_experts * 3 * d * f + d * self.n_experts  # experts+router
-        per_layer = attn + moe + 2 * d
+        per_layer = attn + moe + 2 * d + self.qk_norm_params()
         head = 0 if self.tie_embeddings else d * v
         return v * d + l * per_layer + d + head
 
@@ -59,7 +62,8 @@ class MoEConfig(llama.LlamaConfig):
                 + self.n_heads * hd * d)
         moe = self.top_k * 3 * d * f + d * self.n_experts
         head = 0 if self.tie_embeddings else d * v
-        return v * d + l * (attn + moe + 2 * d) + d + head
+        return (v * d + l * (attn + moe + 2 * d + self.qk_norm_params())
+                + d + head)
 
 
 PRESETS: Dict[str, MoEConfig] = {
@@ -112,8 +116,9 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
         logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)                       # [G, E]
         topk_probs, topk_idx = jax.lax.top_k(probs, K)                # [G, K]
-        # renormalize the selected gates (Mixtral convention)
-        topk_probs = topk_probs / (topk_probs.sum(-1, keepdims=True) + 1e-9)
+        if cfg.norm_topk_prob:
+            # renormalize the selected gates (Mixtral convention)
+            topk_probs = topk_probs / (topk_probs.sum(-1, keepdims=True) + 1e-9)
 
         # capacity slots: position of each token within its expert's queue,
         # counted over the flattened [K, G] selection order
@@ -155,17 +160,109 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
     return out.reshape(b, s, d), aux
 
 
-def ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params,
-             drop_free: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """Pre-norm MoE FFN + residual; returns (hidden, aux_loss).
-    ``drop_free``: capacity covers every selection (inference routing —
-    capacity drops are a training-time load-balancing construct)."""
-    c = (dataclasses.replace(cfg, capacity_factor=float(cfg.n_experts))
-         if drop_free else cfg)
+def ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params
+             ) -> Tuple[jax.Array, jax.Array]:
+    """Pre-norm MoE FFN + residual; returns (hidden, aux_loss)."""
     h = llama.rmsnorm(x, layer["mlp_norm"].astype(cfg.compute_dtype),
                       cfg.norm_eps)
-    ffn, aux = _moe_ffn(c, h, layer)
+    ffn, aux = _moe_ffn(cfg, h, layer)
     return x + ffn, aux
+
+
+# What one served MoE layer says of its routing, an int32 vector in this
+# order. Over layers, steps and launches the first four add up and the
+# last is a maximum (``fold_served_stats``, the engine's recorder).
+SERVED_STATS = ("moe_assignments",      # (token, expert) pairs routed: G*K
+                "moe_rows_computed",    # rows the grouped products ran
+                "moe_experts_touched",  # experts with at least one row
+                "moe_expert_slots",     # experts there were: E
+                "moe_max_expert_rows")  # the busiest expert's rows
+
+
+def fold_served_stats(stats: jax.Array) -> jax.Array:
+    """[n, 5] of ``SERVED_STATS`` (a scan's layers or steps) -> [5]."""
+    return jnp.concatenate([stats[:, :4].sum(0), stats[:, 4:].max(0)])
+
+
+#: a layer's weights that the served block reads where they lie, stacked
+#: over the layers, and that a layer scan must therefore not slice
+EXPERT_WEIGHTS = ("e_gate", "e_up", "e_down")
+
+
+def served_ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params,
+                    experts: Params, index) -> Tuple[jax.Array, jax.Array]:
+    """The served block's pre-norm MoE FFN + residual: [B, S, d] ->
+    ([B, S, d], ``SERVED_STATS``). Inference routing drops nothing
+    (capacity is a training-time load-balancing construct), and an
+    expert may be chosen by every token at once, so there is no capacity
+    buffer: the G*K (token, expert) assignments are sorted by expert, the
+    tokens' rows gathered in that order with every expert's group padded
+    to whole tiles, each tile multiplied by its expert's matrices
+    (``ops/pallas/grouped_matmul``) and every token sums its K rows,
+    weighted by its gates. Rows computed are at most G*K + E * tile;
+    memory and arithmetic are linear in G*K; no tensor is [G, E, .] but the
+    router's probabilities. The four scopes stand for themselves in a
+    device trace (not under ``mlp``).
+
+    ``experts`` are ``EXPERT_WEIGHTS`` stacked over the layers ([L, E, ..])
+    with ``index`` this layer's: inside a layer scan they are read where
+    they lie and no layer's worth of them is sliced out."""
+    from ray_tpu.ops.pallas import grouped_matmul as gmm
+
+    b, s, d = x.shape
+    E, K = cfg.n_experts, cfg.top_k
+    G, cdt = b * s, cfg.compute_dtype
+    A = G * K
+    tile = gmm.tile_rows(A, E)
+    tiles = gmm.n_tiles(G, K, E, tile)
+
+    with jax.named_scope("moe_router"):
+        h = llama.rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
+        tokens = h.reshape(G, d)
+        logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                       # [G, E]
+        gates, chosen = jax.lax.top_k(probs, K)                       # [G, K]
+        if cfg.norm_topk_prob:
+            gates = gates / (gates.sum(-1, keepdims=True) + 1e-9)
+
+    with jax.named_scope("moe_dispatch"):
+        # assignment a = g * K + k; ``order`` lists them expert by expert,
+        # a token's before a later token's (the sort is stable)
+        expert = chosen.reshape(A)
+        order = jnp.argsort(expert, stable=True)
+        by_expert = expert[order]
+        starts = jnp.searchsorted(by_expert, jnp.arange(E + 1))
+        sizes = jnp.diff(starts)                     # rows an expert
+        group_tiles = -(-sizes // tile)              # tiles that hold them
+        ends = jnp.cumsum(group_tiles)               # its tiles end here
+        used = ends[-1:]
+        # where a sorted assignment's row lies once every group is padded
+        # to whole tiles: its group's first tile, then its rank in the group
+        first = (ends - group_tiles)[by_expert] * tile
+        dest = first + jnp.arange(A) - starts[by_expert]
+        source = jnp.full((tiles * tile,), G, jnp.int32).at[dest].set(
+            order // K, indices_are_sorted=True, unique_indices=True)
+        rows = jnp.take(tokens, source, axis=0, mode="fill", fill_value=0)
+        # a tile past the last one in use names that one's expert
+        tile_expert = jnp.searchsorted(
+            ends, jnp.minimum(jnp.arange(tiles), used - 1), side="right")
+
+    with jax.named_scope("moe_experts"):
+        args = (tile_expert, used, jnp.asarray(index, jnp.int32).reshape(1))
+        act = gmm.grouped_matmul(rows, *args, experts["e_gate"],
+                                 experts["e_up"])
+        out_rows = gmm.grouped_matmul(act, *args, experts["e_down"])
+
+    with jax.named_scope("moe_combine"):
+        back = jnp.zeros((A,), jnp.int32).at[order].set(
+            dest, unique_indices=True)               # an assignment's row
+        mine = out_rows[back].reshape(G, K, d)
+        out = x + jnp.einsum("gk,gkd->gd", gates.astype(cdt),
+                             mine).reshape(b, s, d)
+        stats = jnp.stack([jnp.int32(A), used[0] * tile,
+                           (sizes > 0).sum(dtype=jnp.int32), jnp.int32(E),
+                           sizes.max()])
+    return out, stats
 
 
 def _moe_block(cfg: MoEConfig, x: jax.Array, layer: Params,

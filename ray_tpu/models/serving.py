@@ -357,6 +357,10 @@ class ContinuousBatcher:
         # set by every step_many that launched: the wall of its three
         # spans (decode_stage, decode_launch, decode_book), in seconds
         self.last_step: Dict[str, float] = {}
+        # a sparse model's routing counters (``moe.SERVED_STATS``) of the
+        # launches since ``take_moe_stats``, by kind of launch; a dense
+        # model's stay empty
+        self._moe_stats: Dict[str, np.ndarray] = {}
 
     @property
     def params(self) -> Params:
@@ -371,6 +375,9 @@ class ContinuousBatcher:
         self._weights = (tree, tuple(jax.ShapeDtypeStruct(
             jnp.shape(x), jnp.result_type(x),
             sharding=getattr(x, "sharding", None)) for x in leaves))
+        # a sparse model's programs return routing counters behind their
+        # tokens (``_with_stats``); ``generate`` tells by the same key
+        self._sparse = "router" in params["layers"]
         self._params = params
 
     def _zero_cache(self) -> Tuple[jax.Array, jax.Array]:
@@ -402,6 +409,18 @@ class ContinuousBatcher:
             raise SlotCacheLost(
                 f"slot cache lost in a failed launch: "
                 f"{type(e).__name__}: {e}"[:300]) from e
+
+    def _note_moe_stats(self, kind: str, stats: np.ndarray) -> None:
+        had = self._moe_stats.get(kind)
+        self._moe_stats[kind] = stats if had is None else np.concatenate(
+            [had[:4] + stats[:4], np.maximum(had[4:], stats[4:])])
+
+    def take_moe_stats(self) -> Dict[str, List[int]]:
+        """The routing counters gathered since the last call, as
+        {"prefill" | "decode": ``moe.SERVED_STATS`` values}: the engine
+        takes them once a tick."""
+        out, self._moe_stats = self._moe_stats, {}
+        return {kind: [int(v) for v in stats] for kind, stats in out.items()}
 
     # -- admission --------------------------------------------------------
 
@@ -477,6 +496,9 @@ class ContinuousBatcher:
                 with self._donating():
                     self._ck, self._cv, first, *new_key = fn(
                         self.params, self._ck, self._cv, *args)
+                    if self._sparse:  # the counters ride behind the token
+                        first = np.asarray(first)
+                        self._note_moe_stats("prefill", first[1:])
                     first_tok = int(first[0])
         except SlotCacheLost:
             raise  # every slot is free again
@@ -591,6 +613,9 @@ class ContinuousBatcher:
             self._ck, self._cv, toks, *new_keys = fn(
                 self.params, self._ck, self._cv, *args)
             toks = np.asarray(toks)  # [k, bucket]
+            if self._sparse:  # the counters ride behind the tokens
+                self._note_moe_stats("decode", toks[k * bucket:])
+                toks = toks[:k * bucket].reshape(k, bucket)
             if self.sampling:
                 self._keys[rows] = np.asarray(new_keys[0])
         with _span("decode_book", parts):
@@ -1201,7 +1226,9 @@ class ContinuousEngine:
                 self._recorder.request_tokens(rid, nburst, self._t_wall0,
                                               done)
             self._recorder.record_tick(t_start=t_start, wall_s=wall_s,
-                                       phases=ph, **fields)
+                                       phases=ph,
+                                       moe=self._batcher.take_moe_stats(),
+                                       **fields)
             if tick is not None and self._on_tick is not None:
                 try:
                     self._on_tick(tick, self.max_slots)
@@ -1361,6 +1388,16 @@ def _first_token(logits_last, sample: bool, temp=None, top_k=None,
     return _row_sample(logits_last[0], temp, top_k, sub)[None], key
 
 
+def _with_stats(toks, stats):
+    """A program's tokens and, from a sparse model, its routing counters
+    (``moe.SERVED_STATS``) behind them in ONE int32 vector: the host reads
+    both in the read that fences the launch. A dense model's tokens go
+    out as they are."""
+    if stats is None:
+        return toks
+    return jnp.concatenate([toks.reshape(-1), stats])
+
+
 def _write_row(ck, cv, row: Dict[str, jax.Array], slot):
     """A prefilled row into its slot of the (donated) cache, in place:
     one row's bytes move, not the cache's."""
@@ -1380,12 +1417,13 @@ def _compiled_slot_prefill(cfg, s: int, max_slots: int, max_len: int,
     # the program's name in a device trace (``jit_rt_prefill``)
     def rt_prefill(params, ck, cv, prompt, slot, temp=None, top_k=None,
                    key=None):
-        logits, row = G._forward_with_cache(params, prompt, cfg,
-                                            G.init_cache(cfg, 1, max_len), 0)
+        logits, row, stats = G._forward_with_cache_stats(
+            params, prompt, cfg, G.init_cache(cfg, 1, max_len), 0)
         with jax.named_scope("head_sample"):
             first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
                                       key)
         ck, cv = _write_row(ck, cv, row, slot)
+        first = _with_stats(first, stats)
         return (ck, cv, first, key) if sample else (ck, cv, first)
 
     return jax.jit(rt_prefill, donate_argnums=(1, 2))
@@ -1408,11 +1446,13 @@ def _compiled_cached_prefill(cfg, c: int, sl: int, max_slots: int,
         row = G.init_cache(cfg, 1, max_len)
         row = {"k": row["k"].at[:, 0, :c].set(pk.astype(cfg.compute_dtype)),
                "v": row["v"].at[:, 0, :c].set(pv.astype(cfg.compute_dtype))}
-        logits, row = G._forward_with_cache(params, suffix, cfg, row, c)
+        logits, row, stats = G._forward_with_cache_stats(params, suffix, cfg,
+                                                         row, c)
         with jax.named_scope("head_sample"):
             first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
                                       key)
         ck, cv = _write_row(ck, cv, row, slot)
+        first = _with_stats(first, stats)
         return (ck, cv, first, key) if sample else (ck, cv, first)
 
     return jax.jit(rt_cached_prefill, donate_argnums=(1, 2))
@@ -1424,7 +1464,8 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
     """``k`` fused decode steps, in place on the donated slot cache, for
     the ``bucket`` rows from slot ``slot0`` on: a ``lax.scan`` of
     ``generate.decode_step_in_place`` with the cache in its carry, which
-    returns the cache and the [k, bucket] token block. One launch per K
+    returns the cache and the [k, bucket] token block (from a sparse
+    model with its routing counters behind it: ``_with_stats``). One launch per K
     tokens per occupancy bucket — the decode-side make_multi_step. The
     full bucket's rows are all the slots (``slot0`` is not looked at);
     the lone row is addressed by a dynamic slice at ``slot0``. The
@@ -1440,8 +1481,8 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
 
         def body(carry, _):
             ck, cv, cur, pos, keys = carry
-            logits, ck, cv = G.decode_step_in_place(params, cur, cfg, ck, cv,
-                                                    first, pos)
+            logits, ck, cv, stats = G.decode_step_in_place_stats(
+                params, cur, cfg, ck, cv, first, pos)
             with jax.named_scope("head_sample"):
                 if sample:
                     keys, subs = jnp.moveaxis(
@@ -1449,10 +1490,11 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
                     nxt = jax.vmap(_row_sample)(logits, temp, topk, subs)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (ck, cv, nxt, pos + 1, keys), nxt
+            return (ck, cv, nxt, pos + 1, keys), (nxt, stats)
 
-        (ck, cv, _, _, keys), toks = jax.lax.scan(
+        (ck, cv, _, _, keys), (toks, stats) = jax.lax.scan(
             body, (ck, cv, cur, pos, keys), None, length=k)
+        toks = _with_stats(toks, G._fold_stats(stats))
         return (ck, cv, toks, keys) if sample else (ck, cv, toks)
 
     return jax.jit(rt_decode, donate_argnums=(1, 2))

@@ -929,6 +929,12 @@ def cmd_engine(args: argparse.Namespace) -> int:
                       f"{prog['cache_copy_bytes_per_step']} "
                       f"({prog['cache_copy_bytes_per_step'] / max(1, prog['cache_bytes']):.2f}"
                       f" of the cache)")
+            if summ.get("moe_assignments"):
+                print(f"  moe: {summ['moe_assignments']} assignments, "
+                      f"{summ['moe_rows_computed']} rows computed, experts "
+                      f"touched {summ['moe_experts_touched']} of "
+                      f"{summ['moe_expert_slots']}, busiest expert "
+                      f"{summ['moe_max_expert_rows']} rows in one layer")
             print(f"  recorder overhead "
                   f"{100 * summ.get('overhead_frac', 0):.3f}% of tick wall")
         elif args.engine_cmd == "ticks":

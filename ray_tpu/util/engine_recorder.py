@@ -100,6 +100,11 @@ TICK_PHASES = ("record", "admission", "kv_restore", "prefill", "decode_step",
                "token_delivery", "swap_barrier", "idle_wait")
 #: the three spans ``step_many`` splits ``decode_step``'s wall into
 DECODE_PARTS = ("decode_stage", "decode_launch", "decode_book")
+# a sparse model's routing counters, in the order the programs return them
+# (``models/moe.py:SERVED_STATS``): over launches the first four add up and
+# the last is a maximum
+MOE_COUNTERS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
+                "moe_expert_slots", "moe_max_expert_rows")
 
 _REGISTRY = RecorderRegistry()
 
@@ -183,13 +188,16 @@ class EngineRecorder(RecorderCore):
                     phases: Dict[str, float], active: int, pending: int,
                     bucket: int, k: int, tokens: int, admitted: int,
                     gap_s: Optional[float],
-                    decode_parts: Optional[Dict[str, float]] = None
+                    decode_parts: Optional[Dict[str, float]] = None,
+                    moe: Optional[Dict[str, List[int]]] = None
                     ) -> None:
         """One engine tick: phase partition + the decode tick-gap. The
         ONLY thing this does is append to a bounded deque — no metrics,
         no I/O (drained off-thread). ``decode_parts`` is ``decode_step``'s
         wall again, split three ways; it stays out of ``phases`` so that
-        they still sum to the tick."""
+        they still sum to the tick. ``moe`` is what a sparse model's
+        launches of this tick said of their routing (``MOE_COUNTERS``
+        values under "prefill" and "decode"); a dense model's is empty."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
@@ -203,6 +211,8 @@ class EngineRecorder(RecorderCore):
         if decode_parts:
             rec["decode_parts"] = {p: decode_parts.get(p, 0.0)
                                    for p in DECODE_PARTS}
+        if moe:
+            rec["moe"] = moe
         with self._lock:
             self._tick_seq += 1
             rec["seq"] = self._tick_seq
@@ -444,6 +454,7 @@ class EngineRecorder(RecorderCore):
             "pump_bursts": len(lags),
             "decode_programs": [dict(p) for p in self.decode_programs],
         }
+        out.update(_moe_totals(ticks))
         if lags:
             lags = sorted(lags)
             out["pump_lag_p50_s"] = round(_pct(lags, 0.50), 6)
@@ -660,6 +671,24 @@ def _window_entry(r: Dict[str, Any]) -> Dict[str, Any]:
     for key in ("queue_s", "front_in_s", "replica_in_s"):
         if key in r:
             out[key] = r[key]
+    return out
+
+
+def _moe_totals(ticks: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """``MOE_COUNTERS`` over the ticks' launches, and under ``moe_decode``
+    over the decode launches alone (a prefill's rows an expert are another
+    population than a decode step's). Nothing for a dense model."""
+    def fold(kinds) -> Dict[str, int]:
+        rows = [t["moe"][k] for t in ticks if "moe" in t
+                for k in kinds if k in t["moe"]]
+        if not rows:
+            return {}
+        return {name: (max if name == MOE_COUNTERS[-1] else sum)(
+            r[i] for r in rows) for i, name in enumerate(MOE_COUNTERS)}
+
+    out: Dict[str, Any] = fold(("prefill", "decode"))
+    if out:
+        out["moe_decode"] = fold(("decode",))
     return out
 
 

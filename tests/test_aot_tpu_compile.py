@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import llama, serving
+from ray_tpu.models import llama, moe, serving
 from ray_tpu.ops.pallas import flash
 from ray_tpu.parallel import train_step as ts
 from ray_tpu.parallel.context import mesh_scope
@@ -97,10 +97,9 @@ def test_flash_with_traced_offset_compiles_for_v5e(topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _engine_args(topo, slots, max_len, cfg=CFG_1B):
+def _engine_args(topo, slots, max_len, cfg=CFG_1B, init=llama.init_params):
     one = SingleDeviceSharding(topo.devices[0])
-    params = _on(one, jax.eval_shape(
-        lambda: llama.init_params(jax.random.key(0), cfg)))
+    params = _on(one, jax.eval_shape(lambda: init(jax.random.key(0), cfg)))
     cache = jax.ShapeDtypeStruct(
         (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.head_dim),
         cfg.compute_dtype, sharding=one)
@@ -178,6 +177,54 @@ def test_engine_decode_steps_the_cache_in_place_on_v5e(topo, widths, slots,
             assert f"bf16[{bucket},2048,{hkv},{hd}]" not in line \
                 and f"bf16[{bucket},1,2048,{hkv},{hd}]" not in line \
                 and f"bf16[1,{bucket},2048,{hkv},{hd}]" not in line, line
+
+
+# OLMoE-1B-7B at its published widths and the depth its cell serves
+# (benchmark/configs/olmoe-1b-7b-0125.json): 64 experts of width 1024, top-8
+# un-renormalised, QK-norm, 16 KV heads
+CFG_OLMOE = moe.MoEConfig(
+    vocab_size=50304, d_model=2048, n_layers=13, n_heads=16, n_kv_heads=16,
+    d_ff=1024, max_seq_len=2048, rope_theta=1e4, tie_embeddings=False,
+    param_dtype=jnp.bfloat16, n_experts=64, top_k=8, norm_topk_prob=False,
+    qk_norm=True)
+
+
+@pytest.mark.parametrize("program", ["prefill-1536", "decode-16x8",
+                                     "decode-1x8"])
+def test_olmoe_engine_programs_compile_for_v5e(topo, program):
+    """The served expert path at OLMoE's widths: the chat grid's longest
+    prefill (1536 tokens, 12,288 assignments over 64 experts) and the
+    in-place decode programs fit the chip. The one-hot form needed 2.4 GB
+    each for ``dispatch`` and ``combine`` [G, E, C] and 3.2 GB of expert
+    rows a layer; sorted by expert and multiplied in tiles, a prefill's
+    temporaries stay under 1 GiB. The cache is still donated and crosses HBM once a step."""
+    params, cache, i32 = _engine_args(topo, 16, 2048, CFG_OLMOE,
+                                      moe.init_params)
+    if program == "prefill-1536":
+        compiled = serving._compiled_slot_prefill(
+            CFG_OLMOE, 1536, 16, 2048).lower(
+            params, cache, cache, i32(1, 1536), i32()).compile()
+    else:
+        bucket = 16 if program == "decode-16x8" else 1
+        compiled = serving._compiled_bucket_scan(
+            CFG_OLMOE, bucket, 16, 2048, 8).lower(
+            params, cache, cache, i32(bucket), i32(bucket), i32()).compile()
+        traffic = hlo_copies.cache_traffic(compiled, cache, rows=bucket,
+                                           steps=8)
+        assert traffic["cache_donated"]
+        assert traffic["cache_copy_bytes_per_step"] \
+            <= 1.01 * traffic["cache_bytes"] * bucket // 16, traffic
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes) < 15.75 * 2 ** 30
+    assert mem.alias_size_in_bytes >= _cache_bytes(cache)
+    assert mem.temp_size_in_bytes < 2 ** 30
+    # the experts' products are the two grouped-matmul kernels, which read
+    # the stacked weights where they lie: no layer's experts are sliced out
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "bf16[64,2048,1024]" not in text and "bf16[64,1024,2048]" not in text
 
 
 def test_sharded_flash_step_compiles_for_four_chips(topo):

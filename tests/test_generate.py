@@ -47,9 +47,16 @@ def test_gqa_decode(fp32_cfg):
         seq = np.concatenate([seq, np.asarray(toks[:, t])[:, None]], axis=1)
 
 
-def test_moe_decode_matches_dropfree_forward():
+@pytest.mark.parametrize("routing", [
+    dict(n_experts=4, top_k=2, norm_topk_prob=True),    # Mixtral's shape
+    dict(n_experts=8, top_k=3, norm_topk_prob=False),   # OLMoE's
+], ids=["e4-k2-renormalised", "e8-k3-as-they-are"])
+def test_moe_decode_matches_dropfree_forward(routing):
+    """The served expert path (assignments sorted by expert, grouped
+    products) decodes what the capacity dispatch computes with room for
+    every selection."""
     base = dataclasses.replace(moe.PRESETS["moe-debug"],
-                               compute_dtype=jnp.float32)
+                               compute_dtype=jnp.float32, **routing)
     cfg_ref = dataclasses.replace(base,
                                   capacity_factor=float(base.n_experts))
     params = moe.init_params(jax.random.key(0), base)
