@@ -1162,6 +1162,11 @@ def cmd_train(args: argparse.Namespace) -> int:
                 print(f"  gap attribution (MFU cost): {parts}")
         print(f"  recorder overhead "
               f"{100 * summ.get('overhead_frac', 0):.3f}% of launch wall")
+        for fp in summ.get("flash_plans") or ():
+            print(f"  flash {fp['kind']} s{fp['seq_q']}x{fp['seq_k']} "
+                  f"d{fp['head_dim']}: tile {fp['block_q']}x"
+                  f"{fp['block_k']}, {fp['live_steps']} of "
+                  f"{fp['grid_steps']} grid steps live")
         for r in (s.get("launches") or [])[-args.limit:]:
             when = time.strftime("%H:%M:%S",
                                  time.localtime(r.get("t", 0)))

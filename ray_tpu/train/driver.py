@@ -37,6 +37,7 @@ The K knob comes from ``FastPathConfig.steps_per_launch``
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -99,6 +100,13 @@ class StepDriver:
             self.recorder: Optional[Any] = TrainRecorder()
         except Exception:  # noqa: BLE001 — observability must not block
             self.recorder = None
+        # the flash kernels' tilings, noted as a launch traces them: the
+        # recorder's own list, so a program compiled later shows too
+        from ray_tpu.ops.pallas import flash
+
+        self._noting_flash_plans = functools.partial(
+            flash.noting_plans,
+            self.recorder.flash_plans if self.recorder is not None else [])
         self._fpt_cache: Dict[int, float] = {}
         self._single = ts.make_train_step(cfg, optimizer, loss_fn, mesh,
                                           plan=plan)
@@ -266,8 +274,9 @@ class StepDriver:
                 self.host_s += time.perf_counter() - t0
                 n_exec = self.compile_count() if rec is not None else 0
                 t1 = time.perf_counter()
-                params, opt_state, metrics = self._multi(
-                    params, opt_state, placed)
+                with self._noting_flash_plans():
+                    params, opt_state, metrics = self._multi(
+                        params, opt_state, placed)
                 dispatch_s = time.perf_counter() - t1
                 t_disp_end = time.time() if rec is not None else 0.0
                 self.step_s += dispatch_s
@@ -338,7 +347,9 @@ class StepDriver:
         placed = self._place(batch, stacked=False)
         self.host_s += time.perf_counter() - t0
         t1 = time.perf_counter()
-        params, opt_state, metrics = self._single(params, opt_state, placed)
+        with self._noting_flash_plans():
+            params, opt_state, metrics = self._single(params, opt_state,
+                                                      placed)
         self.step_s += time.perf_counter() - t1
         self.launches += 1
         self.steps += 1

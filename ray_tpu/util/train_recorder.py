@@ -142,6 +142,11 @@ class TrainRecorder(RecorderCore):
         self._dry_resets = 0  # rt: guarded-by(_lock)
         self._compiles = 0  # rt: guarded-by(_lock)
         self._peak_total_cached: Optional[float] = None
+        # one entry per distinct tiling the step's flash kernels were traced
+        # with (``ops/pallas/flash.noting_plans``): the driver notes into
+        # this list, static per compiled shape like the engine recorder's
+        # ``decode_programs``; an ``xla`` step leaves it empty
+        self.flash_plans: List[Dict[str, Any]] = []
         # done-hook plumbing: the step path enqueues, one watcher thread
         # blocks on output buffers FIFO (launch order), so finalize order
         # is monotone and _prev_done_t never runs backwards
@@ -378,7 +383,9 @@ class TrainRecorder(RecorderCore):
         return self._aggregate(recs)
 
     def _aggregate(self, recs: List[Dict[str, Any]]) -> Dict[str, Any]:
-        out: Dict[str, Any] = {"window_launches": len(recs)}
+        out: Dict[str, Any] = {
+            "window_launches": len(recs),
+            "flash_plans": [dict(p) for p in self.flash_plans]}
         if not recs:
             return out
         phase_totals = {p: 0.0 for p in LAUNCH_PHASES}

@@ -70,8 +70,11 @@ def _on(sharding, tree):
     (8, 2048, 16, 16, 64),    # the 410m widths
     (4, 2048, 32, 4, 64),     # "1b": grouped-query, 32 heads over 4
     (2, 2048, 32, 32, 128),   # head_dim 128
+    (1, 4096, 32, 8, 128),    # the benchmark's train cells, a chip
 ])
 def test_flash_fwd_bwd_compiles_for_v5e(topo, b, s, h, hkv, d):
+    from benchmark.kernels.flash import call_kind
+
     one = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one)
     kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16, sharding=one)
@@ -82,8 +85,26 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, b, s, h, hkv, d):
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
-    # forward, dq, dk/dv
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    # forward, dq, dk/dv: three calls, each with the results the benchmark's
+    # matcher tells them apart by and counts their work from
+    calls = [call_kind(line.strip().lstrip("%"))
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert sorted(calls) == [(kind, b * h, s, d, 2)
+                             for kind in ("dkv", "dq", "fwd")], calls
+
+
+def test_flash_plan_at_the_train_cells_shape():
+    """What ``plan`` chooses where the benchmark trains (bh 32, s 4096,
+    d 128, bf16, causal): tiles of at least 512 a side, at most 0.65 of the
+    grid doing work (36 of 64 at 512², 10 of 16 at 1024²; 1.0 at the 128²
+    of before, masked blocks and all), inside the VMEM limit the call sets,
+    which the compile above was held to."""
+    for kind in flash.KINDS:
+        p = flash.plan(4096, 4096, 128, 2, True, kind)
+        assert min(p.block_q, p.block_k) >= 512, p
+        assert p.live_steps / p.grid_steps <= 0.65, p
+        assert p.vmem_bytes <= p.vmem_limit_bytes <= 64 << 20, p
 
 
 def test_flash_with_traced_offset_compiles_for_v5e(topo):

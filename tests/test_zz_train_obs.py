@@ -281,7 +281,10 @@ def test_window_summary_carves_launches():
         w2 = rec.window_summary(1500.0, 2500.0)
         assert w2["window_launches"] == 1 and w2["tokens"] == 32
         assert w2["data_wait_frac"] == pytest.approx(0.2 / 0.3, abs=1e-3)
-        assert rec.window_summary(0.0, 999.0) == {"window_launches": 0}
+        # an empty window still says how the step was tiled (static per
+        # compiled shape; this synthetic recorder traced no flash kernel)
+        assert rec.window_summary(0.0, 999.0) == {"window_launches": 0,
+                                                  "flash_plans": []}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
     finally:
