@@ -195,8 +195,8 @@ def _train_loop(config: Dict[str, Any]) -> None:
     on_tpu = devices[0].platform == "tpu"
     k = train.get_fast_path().steps_per_launch
     batch, seq = config["batch"], config["seq"]
-    # bf16 parameters (and so bf16 adamw moments) as bench.py sets them for
-    # this width, the flash kernel, the chunked loss
+    # bf16 parameters (and so bf16 adamw moments) as the benchmark's train
+    # cells set them, the flash kernel, the chunked loss
     cfg = dataclasses.replace(
         llama.PRESETS[config["preset"]], param_dtype=jnp.bfloat16,
         attn_impl="flash", loss_chunk=config["loss_chunk"],

@@ -1294,21 +1294,6 @@ def main(argv=None) -> int:
         "ray_tpu.scripts.microbenchmark",
         fromlist=["main"]).main(a))
 
-    p_scale = sub.add_parser(
-        "scale-envelope",
-        help="one-host scalability envelope (reference: "
-             "release/benchmarks/README.md)")
-    p_scale.add_argument("--actors", type=int, default=1000)
-    p_scale.add_argument("--queued", type=int, default=10_000)
-    p_scale.add_argument("--pgs", type=int, default=100)
-    p_scale.add_argument("--actor-budget-s", type=float, default=120.0)
-    p_scale.add_argument("--out", type=str, default="")
-    p_scale.set_defaults(fn=lambda a: __import__(
-        "ray_tpu.scripts.scale_envelope", fromlist=["main"]).main(
-        ["--actors", str(a.actors), "--queued", str(a.queued),
-         "--pgs", str(a.pgs), "--actor-budget-s", str(a.actor_budget_s)]
-        + (["--out", a.out] if a.out else [])))
-
     p_serve = sub.add_parser("serve", help="deploy/inspect serve apps")
     serve_sub = p_serve.add_subparsers(dest="serve_cmd", required=True)
     ps_deploy = serve_sub.add_parser("deploy")

@@ -156,7 +156,7 @@ print("doctor healthy after train leg")
 echo "== overload leg: probe under a deep flood (fair dispatch) =="
 # Flood one scheduling class, then submit a 1-task probe in ANOTHER class:
 # round-robin dispatch must answer it in < 1 s instead of making it wait
-# out the whole backlog (the SCALE_r05 255 s FIFO pathology).
+# out the whole backlog (what one FIFO queue made it do).
 FLOOD="${RT_SMOKE_FLOOD:-5000}"
 T0=$(python -c 'import time; print(time.time())')
 python - "$FLOOD" <<'EOF'
@@ -373,7 +373,7 @@ from ray_tpu.serve.llm import cb_vs_static_load
 
 rps, secs, p99_bound_ms = float(sys.argv[1]), float(sys.argv[2]), float(sys.argv[3])
 # LONG sizes the static control PAST saturation at the offered load
-# (the BENCH_r06-verified operating point): it must decode max_new=256
+# (an operating point found on a CPU, never on a chip): it must decode max_new=256
 # for every flush while continuous admission's actual token demand
 # stays far under engine capacity
 ray_tpu.init(address="auto")

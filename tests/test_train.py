@@ -1,5 +1,5 @@
 """Train-layer tests: gang orchestration, reporting, checkpointing, restart,
-and the MNIST-MLP-style data-parallel config (BASELINE.md config 2) with
+and the MNIST-MLP-style data-parallel config with
 host-collective gradient sync across real worker processes.
 """
 

@@ -261,8 +261,8 @@ def test_gcs_kv_degraded_wal_run_merges_on_reopen(tmp_path, monkeypatch):
 def test_large_object_transfer_under_small_store(monkeypatch):
     """A 512MB object crosses nodes with a 128MB store cap: the source
     spills it, chunks serve from the spill file, the destination restores
-    under its own cap — bounded memory end to end (reference envelope:
-    the 1 GiB broadcast in BASELINE.md, scaled to CI time)."""
+    under its own cap — bounded memory end to end (a 1 GiB broadcast,
+    scaled to CI time)."""
     monkeypatch.setenv("RT_OBJECT_STORE_MEMORY_BYTES", str(128 * 1024 * 1024))
     config_mod.reset_config_for_tests()
     if ray_tpu.is_initialized():

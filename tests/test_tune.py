@@ -3,6 +3,7 @@ and the Train-on-Tune integration (reference test model:
 ``python/ray/tune/tests/test_tune_*.py``)."""
 
 import os
+import time
 
 import numpy as np
 import pytest
@@ -129,12 +130,26 @@ def test_tune_error_reported(rt_cluster, tmp_path):
 
 
 def test_pbt_mutates_from_checkpoint(rt_cluster, tmp_path):
+    # A step takes no time, so a weak trial whose actor is up first can run
+    # all eight before the strong trial's actor has started, and PBT then has
+    # nobody to clone from (seen whenever the suite's load delayed the second
+    # actor). The weak trial waits for the strong one's first step: a wait on
+    # the condition the test is about, not on a clock.
+    strong_stepped = str(tmp_path / "strong_stepped")
+
     class PBTTrainable(tune.Trainable):
         def setup(self, config):
             self.lr = config["lr"]
             self.level = 0
 
         def step(self):
+            if self.lr >= 0.1:
+                open(strong_stepped, "w").close()
+            else:
+                deadline = time.time() + 120
+                while not os.path.exists(strong_stepped) \
+                        and time.time() < deadline:
+                    time.sleep(0.01)
             self.level += self.lr
             return {"level": self.level, "lr": self.lr}
 

@@ -1,7 +1,7 @@
 """Overload-robust control plane: per-class round-robin dispatch, warm
 worker pools, bounded-queue backpressure, and deadline budgets — the
 scheduler rework the observability arc's queue-wait histograms exist to
-prove (ROADMAP item 1; SCALE_r05's 255 s probe-behind-a-flood pathology).
+prove (a probe behind a flood used to wait out the whole FIFO backlog).
 
 Reference analogs: ``raylet/local_task_manager.h`` (per-SchedulingClass
 dispatch queues), ``raylet/worker_pool.h`` (prestart + idle reuse), and
@@ -97,8 +97,8 @@ def test_overload_options_validation():
 
 def test_probe_under_5k_flood():
     """THE acceptance number: a 1-task probe in its own scheduling class
-    completes in < 1 s while >= 5k bulk tasks are queued (SCALE_r05
-    measured 255 s for this under FIFO). The flood is not drained — the
+    completes in < 1 s while >= 5k bulk tasks are queued (under FIFO it
+    waited out the backlog). The flood is not drained — the
     point is the probe's latency while the backlog is deep."""
     ray_tpu.init(num_cpus=2)
 
