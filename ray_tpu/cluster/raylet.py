@@ -490,6 +490,10 @@ class Raylet:
         for proc in procs:
             if proc.poll() is None:
                 proc.kill()
+        # a killed worker is gone only once the kernel has closed what it
+        # held: one that held four chips outlasted its SIGKILL by seconds,
+        # and the next process on the host found /dev/vfio busy
+        await _wait_gone(procs, 30.0)
         if self._gcs is not None:
             await self._gcs.close()
         await self._pool.close_all()

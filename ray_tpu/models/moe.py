@@ -4,10 +4,14 @@ TPU-first design (no reference counterpart — Ray ships no model code; the
 recipe is the public GShard/Switch einsum formulation): the router performs
 STATIC top-k capacity dispatch, so every tensor shape is fixed at trace
 time and XLA tiles the expert FFNs onto the MXU as one batched einsum.
-Experts shard over the mesh's ``ep`` axis (each device group holds
-n_experts/ep experts); GSPMD inserts the all-to-alls implied by the
-dispatch/combine einsums over ICI. Attention blocks, RoPE, norms and the
-chunked loss are shared with :mod:`ray_tpu.models.llama`.
+Where ``n_experts`` splits evenly over the mesh's ``ep`` x ``fsdp`` a
+device owns whole experts (``sharding_rules``): the dispatch einsum sums
+each device's tokens into the owner's ``[E, C, d]`` rows (a reduce-scatter
+over ICI), the combine einsum gathers them back, and nothing ``[E, C, f]``
+crosses devices. Where it does not, experts go over ``ep`` alone and fsdp
+splits the model dim, which costs an all-reduce of every ``[E, C, f]``
+product. Attention blocks, RoPE, norms and the chunked loss are shared with
+:mod:`ray_tpu.models.llama`.
 
 Routing (per token): softmax router logits -> top-k experts -> each chosen
 token takes a slot in its expert's capacity buffer
@@ -322,19 +326,38 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array],
 
 
 def sharding_rules(pipeline: bool = False) -> ShardingRules:
-    """Llama rules + expert tensors: experts over ``ep``, expert matrices'
-    ff dim over ``tp`` (fsdp shards the model dim like the dense path)."""
+    """Llama rules + expert tensors, the expert matrices' ff dim over
+    ``tp``. Where ``n_experts`` splits evenly over ``ep`` x ``fsdp`` the
+    expert dimension takes both axes and the model dim stays whole: a chip
+    owns whole experts, its share of every ``[E, C, .]`` buffer is local,
+    and what crosses chips is the tokens' ``[E, C, d]`` rows on their way
+    to their experts and back. Where it does not (6 experts on ``fsdp``
+    4), experts go over ``ep`` and fsdp shards the model dim like the
+    dense path: the contraction over d is then split, and GSPMD all-reduces
+    the partial ``[E, C, f]`` products. Which one a mesh gets is resolved
+    per leaf from its shape and the mesh (``ShardingRules``)."""
     if pipeline:
         raise NotImplementedError(
             "pipeline parallelism for the MoE family is not implemented")
+    whole = ("ep", "fsdp")  # over the expert dimension: whole experts a chip
     return ShardingRules([
         (r"embed$", P("tp", "fsdp")),
         (r"lm_head$", P("fsdp", "tp")),
         (r"layers/w[qkv]$", P(None, "fsdp", "tp")),
         (r"layers/wo$", P(None, "tp", "fsdp")),
         (r"layers/router$", P(None, "fsdp", None)),
-        (r"layers/e_(gate|up)$", P(None, "ep", "fsdp", "tp")),
-        (r"layers/e_down$", P(None, "ep", "tp", "fsdp")),
+        (r"layers/e_(gate|up)$", [P(None, whole, None, "tp"),
+                                  P(None, "ep", "fsdp", "tp")]),
+        (r"layers/e_down$", [P(None, whole, "tp", None),
+                             P(None, "ep", "tp", "fsdp")]),
         (r"layers/.*norm", P(None)),
         (r"norm", P()),
     ])
+
+
+def expert_placement(e_gate_spec: P) -> str:
+    """What a plan resolved for ``layers/e_gate``: ``"expert"`` where fsdp
+    splits the expert dimension (chips own whole experts), ``"model_dim"``
+    where it splits d."""
+    axes = e_gate_spec[1]
+    return "expert" if axes is not None and "fsdp" in axes else "model_dim"

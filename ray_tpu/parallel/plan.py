@@ -126,6 +126,20 @@ class Plan:
             self._state_shardings[key] = out
         return out
 
+    def expert_placement(self) -> Optional[str]:
+        """How the plan places a sparse family's expert matrices on this
+        mesh (``moe.expert_placement`` of what the rules resolve for
+        ``layers/e_gate``); None for a family without experts."""
+        from ray_tpu.parallel.train_step import model_family
+
+        placement = getattr(model_family(self.cfg), "expert_placement", None)
+        if placement is None:
+            return None
+        c = self.cfg
+        return placement(self._rules().spec_for(
+            "layers/e_gate", (c.n_layers, c.n_experts, c.d_model, c.d_ff),
+            self.mesh))
+
     # ---- batch placement ----------------------------------------------------
     def batch_sharding(self, ndim: int, shard_seq: bool,
                        stacked: bool) -> NamedSharding:

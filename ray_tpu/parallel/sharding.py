@@ -9,8 +9,9 @@ the all-gathers/reduce-scatters that DDP/FSDP would do by hand.
 
 from __future__ import annotations
 
+import math
 import re
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -28,17 +29,42 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
-class ShardingRules:
-    """Ordered (regex, PartitionSpec) rules; first match wins."""
+def _divides(spec: P, shape: Sequence[int], mesh: Mesh) -> bool:
+    """Whether every dimension of ``shape`` that ``spec`` shards splits
+    evenly over the mesh axes it names."""
+    for dim, axes in zip(shape, spec):
+        names = () if axes is None else (
+            axes if isinstance(axes, tuple) else (axes,))
+        if dim % math.prod(mesh.shape.get(name, 1) for name in names):
+            return False
+    return True
 
-    def __init__(self, rules: Sequence[Tuple[str, P]], default: P = P()):
-        self._rules = [(re.compile(pat), spec) for pat, spec in rules]
+
+class ShardingRules:
+    """Ordered (regex, spec) rules; first match wins. A rule's spec is a
+    PartitionSpec or a list of them in order of preference: a leaf takes
+    the first that splits its shape evenly over the mesh it is placed on
+    (the last where none does), so a placement follows what the rules can
+    see of the model and the mesh, the sizes, and takes no option."""
+
+    def __init__(self, rules: Sequence[Tuple[str, Union[P, Sequence[P]]]],
+                 default: P = P()):
+        self._rules = [(re.compile(pat),
+                        (spec,) if isinstance(spec, P) else tuple(spec))
+                       for pat, spec in rules]
         self._default = default
 
-    def spec_for(self, path_string: str) -> P:
-        for pat, spec in self._rules:
+    def spec_for(self, path_string: str, shape: Optional[Sequence[int]] = None,
+                 mesh: Optional[Mesh] = None) -> P:
+        """The spec of the leaf at ``path_string``. With the leaf's ``shape``
+        and the ``mesh``, a rule's alternatives are resolved against them;
+        without, a rule answers with its first."""
+        for pat, specs in self._rules:
             if pat.search(path_string):
-                return spec
+                if shape is None or mesh is None:
+                    return specs[0]
+                return next((s for s in specs if _divides(s, shape, mesh)),
+                            specs[-1])
         return self._default
 
     def tree_specs(self, tree: Any):
@@ -48,8 +74,8 @@ class ShardingRules:
 
     def tree_shardings(self, tree: Any, mesh: Mesh):
         return jax.tree_util.tree_map_with_path(
-            lambda path, leaf: NamedSharding(mesh, self.spec_for(_path_str(path))),
-            tree)
+            lambda path, leaf: NamedSharding(mesh, self.spec_for(
+                _path_str(path), getattr(leaf, "shape", None), mesh)), tree)
 
 
 def named_sharding(mesh: Mesh, *axes) -> NamedSharding:

@@ -1,9 +1,14 @@
 """Sharded train-step construction: init, step, and mesh auto-layout.
 
 The jit-compiled training step that every trainer in the Train layer runs.
-Parameters/optimizer state are sharded by the model's rules; GSPMD propagates
-those shardings through ``optimizer.init`` and the step function, inserting
-all-gathers (fsdp), reduce-scatters (grads), and all-reduces (tp) on ICI.
+Parameters and optimizer state are placed by the model's rules, each leaf
+resolved against its shape and the mesh (a sparse model's experts whole on
+a chip where they split evenly over ``ep`` x ``fsdp``); the step pins those
+shardings and GSPMD derives every collective from them: all-gathers of the
+fsdp-sharded weights, reduce-scatters of their gradients, all-reduces over
+tp, and the experts' ``[E, C, d]`` rows to their owners and back, on ICI.
+What a compiled step ended up with is in its driver's recorder
+(``TrainRecorder.collectives``).
 Gradient synchronization never touches the object plane — the property the
 reference maintains with NCCL outside Ray (SURVEY.md §3.4), achieved here by
 construction.

@@ -1167,6 +1167,14 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"d{fp['head_dim']}: tile {fp['block_q']}x"
                   f"{fp['block_k']}, {fp['live_steps']} of "
                   f"{fp['grid_steps']} grid steps live")
+        if summ.get("expert_placement"):
+            print(f"  experts placed by {summ['expert_placement']}")
+        coll = summ.get("collectives") or {}
+        if coll:
+            parts = "  ".join(
+                f"{kind} {c['count']} ({c['runs']} runs, "
+                f"{c['bytes'] / 1e9:.2f} GB)" for kind, c in coll.items())
+            print(f"  collectives a launch: {parts}")
         for r in (s.get("launches") or [])[-args.limit:]:
             when = time.strftime("%H:%M:%S",
                                  time.localtime(r.get("t", 0)))

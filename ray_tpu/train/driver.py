@@ -37,6 +37,7 @@ The K knob comes from ``FastPathConfig.steps_per_launch``
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
@@ -44,6 +45,14 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 from ray_tpu.util import metrics as M
 
 _LAUNCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0)
+
+
+def _abstract(tree: Any) -> Any:
+    """``tree``'s arrays as shapes, types and shardings."""
+    import jax
+
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=getattr(x, "sharding", None)), tree)
 
 
 def _instruments():
@@ -107,6 +116,8 @@ class StepDriver:
         self._noting_flash_plans = functools.partial(
             flash.noting_plans,
             self.recorder.flash_plans if self.recorder is not None else [])
+        if self.recorder is not None and plan is not None:
+            self.recorder.expert_placement = plan.expert_placement()
         self._fpt_cache: Dict[int, float] = {}
         self._single = ts.make_train_step(cfg, optimizer, loss_fn, mesh,
                                           plan=plan)
@@ -202,6 +213,24 @@ class StepDriver:
             self._fpt_cache[seq] = fpt
         return tokens * fpt
 
+    def _read_collectives(self, abstract: Tuple[Any, Any, Any]
+                          ) -> Dict[str, Dict[str, int]]:
+        """The fused program's collectives by kind, read off the executable
+        the launch just compiled: lowering the same shapes and placements
+        again is answered from ``jit``'s own caches, with no second
+        compile, and the walk of its text happens once, on the host, while
+        the device runs the launch."""
+        from ray_tpu.parallel.context import mesh_scope
+        from ray_tpu.util import hlo_copies
+
+        try:
+            with (mesh_scope(self._mesh) if self._mesh is not None
+                  else contextlib.nullcontext()):
+                return hlo_copies.collective_inventory(
+                    self._multi._jit.lower(*abstract).compile())
+        except Exception:  # noqa: BLE001 — observability must not block
+            return {}
+
     # ---- the loop -----------------------------------------------------------
     def run(self, params: Any, opt_state: Any, batches: Iterable[Any],
             on_launch: Optional[Callable[[Dict[str, Any]], None]] = None,
@@ -273,6 +302,10 @@ class StepDriver:
                 h2d_s = time.perf_counter() - t_h2d
                 self.host_s += time.perf_counter() - t0
                 n_exec = self.compile_count() if rec is not None else 0
+                # the launch that compiles the program: its arguments by
+                # shape and placement, taken before they are donated
+                unread = (_abstract((params, opt_state, placed))
+                          if rec is not None and n_exec == 0 else None)
                 t1 = time.perf_counter()
                 with self._noting_flash_plans():
                     params, opt_state, metrics = self._multi(
@@ -291,6 +324,8 @@ class StepDriver:
                     # tracing+compiling — book it as compile, not dispatch
                     # (step-profiler convention, so the two can't drift)
                     compiled = self.compile_count() > n_exec
+                    if unread is not None:
+                        rec.collectives = self._read_collectives(unread)
                     seq = rec.record_launch(
                         t_start=rec_t0, data_wait_s=rec_data_s,
                         h2d_s=h2d_s,

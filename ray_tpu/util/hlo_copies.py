@@ -1,5 +1,5 @@
-"""What a compiled program does to a large buffer it was meant to step in
-place, read off its optimized HLO.
+"""What a compiled program does, read off its optimized HLO: to a large
+buffer it was meant to step in place, and across chips.
 
 The serving engine's decode program takes the slot cache donated and is
 written so that nothing cache-sized is copied; whether the compiler agreed
@@ -10,6 +10,10 @@ re-stacked piece), and of every cache-shaped update written back in place,
 each weighted by the trip counts of the loops it sits in. A refactor that
 brings a copy back shows here on a CPU run; what a copy costs in time only
 a chip run says.
+
+A sharded train step's collectives are GSPMD's choice, made from where the
+parameters were placed: ``collectives`` lists them with their results and
+the product each completes, ``collective_inventory`` sums them by kind.
 """
 
 from __future__ import annotations
@@ -120,10 +124,11 @@ class _Module:
                     return int(bound.group(1))
         return 1
 
-    def walk(self) -> Iterator[Tuple[int, Tuple, List[Tuple]]]:
-        """Every instruction that runs, outside fused computations, with
-        how often it runs in one execution of the module and the
-        instructions of its computation."""
+    def walk(self, fusions: bool = False
+             ) -> Iterator[Tuple[int, Tuple, List[Tuple]]]:
+        """Every instruction that runs, outside fused computations (inside
+        them too with ``fusions``), with how often it runs in one execution
+        of the module and the instructions of its computation."""
         todo, seen = [(self.entry, 1)], set()
         while todo:
             name, times = todo.pop()
@@ -133,7 +138,7 @@ class _Module:
             for inst in self.computations[name]:
                 yield times, inst, self.computations[name]
                 opcode, line = inst[2], inst[4]
-                if opcode == "fusion":
+                if opcode == "fusion" and not fusions:
                     continue
                 trips = self.trips(line) if opcode == "while" else 1
                 for kind, callee in _CALLEE.findall(line):
@@ -209,3 +214,60 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int,
                 mem is not None and mem.alias_size_in_bytes >= cache_bytes),
             "cache_copy_bytes_per_step": int(total // steps),
             "cache_bytes": cache_bytes}
+
+
+#: the opcodes that move data between chips; an asynchronous pair counts
+#: once, at its ``-done``, whose result is the collective's own
+COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
+                    "all-to-all", "collective-permute")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_ITEMSIZE = re.compile(r"[a-z]+(\d+)")
+
+
+def _nbytes(dtype: str, dims: Tuple[int, ...]) -> int:
+    bits = _ITEMSIZE.fullmatch(dtype)
+    return int(np.prod(dims, dtype=np.int64)) * (
+        int(bits.group(1)) if bits else 8) // 8
+
+
+def collectives(compiled: Any) -> List[Dict[str, Any]]:
+    """Every collective of a compiled program, the TPU compiler's fused
+    ones included: its ``kind``, its ``name``, its result's ``arrays``
+    [(dtype, dims)] and their ``bytes``, how often one execution of the
+    program ``runs`` it (the trip counts of the loops round it), and the
+    ``op_name`` of the source operation it completes. An all-reduce that
+    is only sliced (how a reduce-scatter is spelt where the compiler fuses
+    the two) is a ``reduce-scatter`` with the slice's result."""
+    out = []
+    for times, (name, shape, opcode, _, line), peers in _Module(
+            compiled.as_text()).walk(fusions=True):
+        kind = opcode[:-len("-done")] if opcode.endswith("-done") else opcode
+        if kind not in COLLECTIVE_KINDS:
+            continue
+        if kind == "all-reduce":
+            users = [p for p in peers if name in p[3]]
+            if users and all(p[2] == "dynamic-slice" and p[3][0] == name
+                             for p in users):
+                kind, shape = "reduce-scatter", users[0][1]
+        arrays = _arrays(shape)
+        op_name = _OP_NAME.search(line)
+        out.append({"kind": kind, "name": name, "arrays": arrays,
+                    "bytes": sum(_nbytes(dt, dims) for dt, dims in arrays),
+                    "runs": times,
+                    "op_name": op_name.group(1) if op_name else ""})
+    return out
+
+
+def collective_inventory(compiled: Any) -> Dict[str, Dict[str, int]]:
+    """``{kind: {count, runs, bytes}}`` of a compiled program: collectives
+    of that kind in the program, their executions in one execution of the
+    program, and the bytes of their results over those executions (what a
+    chip ends up holding, not what crosses a link: a ring moves (n-1)/n of
+    an all-gather's result and twice that of an all-reduce's)."""
+    kinds: Dict[str, Dict[str, int]] = {}
+    for c in collectives(compiled):
+        k = kinds.setdefault(c["kind"], {"count": 0, "runs": 0, "bytes": 0})
+        k["count"] += 1
+        k["runs"] += c["runs"]
+        k["bytes"] += c["runs"] * c["bytes"]
+    return kinds

@@ -147,6 +147,14 @@ class TrainRecorder(RecorderCore):
         # this list, static per compiled shape like the engine recorder's
         # ``decode_programs``; an ``xla`` step leaves it empty
         self.flash_plans: List[Dict[str, Any]] = []
+        # what the driver's plan and compiled step say of themselves, static
+        # like the list above: how the plan placed a sparse model's expert
+        # matrices (``moe.expert_placement``: "expert" or "model_dim"; None
+        # for a dense model or no mesh), and the fused program's collectives
+        # ``{kind: {count, runs, bytes}}`` read off the executable that runs
+        # (``util/hlo_copies.collective_inventory``; None until it compiled)
+        self.expert_placement: Optional[str] = None
+        self.collectives: Optional[Dict[str, Dict[str, int]]] = None
         # done-hook plumbing: the step path enqueues, one watcher thread
         # blocks on output buffers FIFO (launch order), so finalize order
         # is monotone and _prev_done_t never runs backwards
@@ -385,7 +393,10 @@ class TrainRecorder(RecorderCore):
     def _aggregate(self, recs: List[Dict[str, Any]]) -> Dict[str, Any]:
         out: Dict[str, Any] = {
             "window_launches": len(recs),
-            "flash_plans": [dict(p) for p in self.flash_plans]}
+            "flash_plans": [dict(p) for p in self.flash_plans],
+            "expert_placement": self.expert_placement,
+            "collectives": {k: dict(v) for k, v in
+                            (self.collectives or {}).items()}}
         if not recs:
             return out
         phase_totals = {p: 0.0 for p in LAUNCH_PHASES}

@@ -12,6 +12,7 @@ The file's name sorts first so that tier-1 reaches it inside its time limit.
 """
 
 import dataclasses
+import math
 import os
 import subprocess
 import sys
@@ -64,6 +65,11 @@ def compiled_kernel(monkeypatch):
 def _on(sharding, tree):
     return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=sharding), tree)
+
+
+def _as_sharded(tree, shardings):
+    return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), tree, shardings)
 
 
 @pytest.mark.parametrize("b,s,h,hkv,d", [
@@ -261,19 +267,64 @@ def test_sharded_flash_step_compiles_for_four_chips(topo):
     p_sh, o_sh = plan.state_shardings(opt)
     p_abs = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
     o_abs = jax.eval_shape(opt.init, p_abs)
-    as_sharded = lambda tree, sh: jax.tree.map(
-        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        tree, sh)
     k = 2
     batch = {"tokens": jax.ShapeDtypeStruct(
         (k, 2, 2049), jnp.int32, sharding=plan.batch_sharding(3, False, True))}
     multi = ts.make_multi_step(cfg, opt, k, mesh=mesh, plan=plan)
     with mesh_scope(mesh):
-        compiled = multi._jit.lower(as_sharded(p_abs, p_sh),
-                                    as_sharded(o_abs, o_sh), batch).compile()
+        compiled = multi._jit.lower(_as_sharded(p_abs, p_sh),
+                                    _as_sharded(o_abs, o_sh), batch).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text and "all-reduce" in text
+
+
+# Mixtral-8x7B at its published widths (benchmark/configs/
+# mixtral-8x7b-v0.1.json) as its four-chip cell trains it, one layer deep
+CFG_MIXTRAL = moe.MoEConfig(
+    vocab_size=32000, d_model=4096, n_layers=1, n_heads=32, n_kv_heads=8,
+    d_ff=14336, max_seq_len=4096, rope_theta=1e6, tie_embeddings=False,
+    param_dtype=jnp.bfloat16, attn_impl="flash", loss_chunk=256,
+    n_experts=8, top_k=2, capacity_factor=1.25, router_aux_coef=0.02)
+
+
+def test_mixtral_step_keeps_its_experts_rows_on_their_chip(topo, capsys):
+    """b4 x s4096, K=2 over ``fsdp 4``: eight experts split four ways, so a
+    chip owns two whole experts and contracts the whole model dim. No
+    collective of the compiled step is then an ``[E, C, f]`` buffer (under
+    fsdp on the model dim there were five, 1.17 GB each: every chip's
+    partial products summed onto every chip) and none completes a product
+    of ``moe_experts``; what crosses chips in the layer is ``[E, C, d]``."""
+    cfg, k, batch, seq = CFG_MIXTRAL, 2, 4, 4096
+    mesh, _ = ts.auto_mesh(4, topo.devices, tp=1)
+    opt = ts.default_optimizer(total_steps=100)
+    plan = compile_plan(cfg, mesh)
+    assert plan.expert_placement() == "expert"
+    p_sh, o_sh = plan.state_shardings(opt)
+    assert "fsdp" in p_sh["layers"]["e_gate"].spec[1]
+    assert p_sh["layers"]["e_gate"].spec[2] is None
+    p_abs = jax.eval_shape(lambda: moe.init_params(jax.random.key(0), cfg))
+    o_abs = jax.eval_shape(opt.init, p_abs)
+    tokens = {"tokens": jax.ShapeDtypeStruct(
+        (k, batch, seq + 1), jnp.int32,
+        sharding=plan.batch_sharding(3, False, True))}
+    multi = ts.make_multi_step(cfg, opt, k, mesh=mesh, plan=plan)
+    with mesh_scope(mesh):
+        compiled = multi._jit.lower(_as_sharded(p_abs, p_sh),
+                                    _as_sharded(o_abs, o_sh), tokens).compile()
+    E = cfg.n_experts
+    C = int(cfg.capacity_factor * batch * seq * cfg.top_k / E)
+    found = hlo_copies.collectives(compiled)
+    assert found
+    for c in found:
+        assert "moe_experts" not in c["op_name"], c
+        for _, dims in c["arrays"]:
+            assert math.prod(dims) != E * C * cfg.d_ff, c
+    with capsys.disabled():
+        print("\nMixtral, 1 layer, fsdp 4, collectives a launch of 2 steps:")
+        for kind, n in hlo_copies.collective_inventory(compiled).items():
+            print(f"  {kind}: {n['count']} ({n['runs']} runs), "
+                  f"{n['bytes'] / 1e9:.2f} GB of results")
 
 
 def test_libtpu_accepts_the_perf_flags():

@@ -130,6 +130,162 @@ def test_moe_sharded_train_step_ep_axis(cfg):
     assert losses[2] != losses[1]
 
 
+@pytest.mark.parametrize("n_experts,n,axes,placement", [
+    (4, 4, dict(tp=1), "expert"),             # fsdp 4
+    (4, 4, dict(tp=1, ep=2), "expert"),       # ep 2 x fsdp 2
+    (4, 8, dict(tp=2, ep=2), "expert"),       # tp 2 x ep 2, fsdp 2 is left
+    (6, 4, dict(tp=1), "model_dim"),          # fsdp 4 does not split 6 experts
+], ids=["fsdp4", "ep2-fsdp2", "tp2-ep2", "e6-fsdp4"])
+def test_sharded_step_agrees_with_one_device(cfg, n_experts, n, axes,
+                                             placement):
+    """Where the experts split evenly over ep x fsdp a chip owns whole
+    experts, where they do not fsdp splits the model dim as before; either
+    way the sharded step computes what one device computes (float32, so
+    that the order of a sum is the only difference), and the driver's
+    recorder says which placement the plan resolved and what the compiled
+    step moves across chips."""
+    from ray_tpu.parallel import train_step as ts
+    from ray_tpu.parallel.plan import compile_plan
+    from ray_tpu.train.driver import StepDriver
+
+    if len(jax.devices()) < n:
+        pytest.skip("needs the 8-device CPU mesh")
+    c = dataclasses.replace(cfg, n_experts=n_experts,
+                            compute_dtype=jnp.float32)
+    mesh, _ = ts.auto_mesh(n, jax.devices()[:n], **axes)
+    optimizer = ts.default_optimizer(total_steps=10)
+    params = moe.init_params(jax.random.key(0), c)
+    tokens = jax.random.randint(jax.random.key(1), (8, 33), 0, c.vocab_size)
+    loss_and_grads = jax.value_and_grad(
+        lambda p, b: moe.lm_loss(p, b, c))
+    want_loss, want = jax.jit(loss_and_grads)(params, {"tokens": tokens})
+
+    plan = compile_plan(c, mesh)
+    assert plan.expert_placement() == placement
+    p_sh, _ = plan.state_shardings(optimizer)
+    e_axes = p_sh["layers"]["e_gate"].spec[1]
+    assert ("fsdp" in e_axes) == (placement == "expert"), e_axes
+    assert "ep" in e_axes
+    got_loss, got = jax.jit(
+        loss_and_grads,
+        in_shardings=(p_sh, plan.batch_sharding(2, False, False)),
+        out_shardings=(plan.replicated(), p_sh))(params, {"tokens": tokens})
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-5)
+    for path, g in jax.tree_util.tree_leaves_with_path(got):
+        w = want
+        for key in path:
+            w = w[key.key]
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
+                                   atol=2e-6, err_msg=str(path))
+
+    # the fused driver: two steps a launch from sharded state; its loss is
+    # the one above and its recorder carries the placement and the program
+    driver = StepDriver(c, optimizer, mesh=mesh, steps_per_launch=2)
+    try:
+        state = ts.init_sharded_state(jax.random.key(0), c, mesh, optimizer)
+        batch = {"tokens": np.asarray(tokens)}
+        _, _, metrics = driver.run(*state, [batch, batch])
+        np.testing.assert_allclose(float(metrics["loss"][0]),
+                                   float(want_loss), rtol=1e-5)
+        summ = driver.recorder.summary()
+        assert summ["expert_placement"] == placement
+        kinds = summ["collectives"]
+        assert kinds and all(k["count"] > 0 and k["bytes"] > 0
+                             and k["runs"] >= k["count"]
+                             for k in kinds.values()), kinds
+        assert driver.compile_count() == 1  # reading it compiled nothing
+    finally:
+        driver.recorder.close()
+
+
+@pytest.mark.parametrize("n_experts,axes,e_gate,e_down", [
+    (8, dict(fsdp=4), (None, ("ep", "fsdp"), None, "tp"),
+     (None, ("ep", "fsdp"), "tp", None)),
+    (8, dict(ep=2, fsdp=2), (None, ("ep", "fsdp"), None, "tp"),
+     (None, ("ep", "fsdp"), "tp", None)),
+    (64, dict(fsdp=2, tp=2), (None, ("ep", "fsdp"), None, "tp"),
+     (None, ("ep", "fsdp"), "tp", None)),
+    (6, dict(fsdp=4), (None, "ep", "fsdp", "tp"), (None, "ep", "tp", "fsdp")),
+    (6, dict(ep=2, fsdp=2), (None, "ep", "fsdp", "tp"),
+     (None, "ep", "tp", "fsdp")),
+    (8, None, (None, ("ep", "fsdp"), None, "tp"),
+     (None, ("ep", "fsdp"), "tp", None)),
+], ids=["e8-fsdp4", "e8-ep2-fsdp2", "e64-fsdp2-tp2", "e6-fsdp4",
+        "e6-ep2-fsdp2", "no-mesh"])
+def test_rules_place_experts_by_what_divides(cfg, n_experts, axes, e_gate,
+                                             e_down):
+    """The placement is read off the sizes: the experts' dimension takes
+    ep x fsdp where ``n_experts`` splits evenly over them, else today's
+    (experts over ep, the model dim over fsdp); adam's moments follow their
+    parameter by path and shape; asked without a mesh a rule answers with
+    its first choice. No option selects it."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel import train_step as ts
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    c = dataclasses.replace(cfg, n_experts=n_experts)
+    rules = moe.sharding_rules()
+    shapes = jax.eval_shape(lambda: moe.init_params(jax.random.key(0), c))
+    if axes is None:
+        assert rules.spec_for("layers/e_gate") == P(*e_gate)
+        assert rules.tree_specs(shapes)["layers"]["e_down"] == P(*e_down)
+        return
+    mesh = make_mesh(MeshConfig(**axes), jax.devices()[:4])
+    state = jax.eval_shape(ts.default_optimizer(total_steps=10).init, shapes)
+    placed = rules.tree_shardings({"params": shapes, "opt": state}, mesh)
+    found = {}
+    for path, sh in jax.tree_util.tree_leaves_with_path(placed):
+        name = str(getattr(path[-1], "key", ""))
+        if name.startswith("e_"):
+            found.setdefault(name, set()).add(sh.spec)
+    # the parameter and both of its moments, one placement each
+    assert found == {"e_gate": {P(*e_gate)}, "e_up": {P(*e_gate)},
+                     "e_down": {P(*e_down)}}, found
+    assert moe.expert_placement(P(*e_gate)) == (
+        "expert" if n_experts != 6 else "model_dim")
+
+
+def test_checkpoint_of_the_old_placement_restores_into_the_new(cfg, tmp_path):
+    """State saved with the experts' model dim over fsdp (the placement
+    before experts were placed by expert) restores into the plan's
+    placement, value for value: the restore reshards by the target's
+    shardings, whatever the file was written under."""
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel import train_step as ts
+    from ray_tpu.parallel.plan import compile_plan
+    from ray_tpu.parallel.sharding import ShardingRules
+    from ray_tpu.train.checkpoint import Checkpoint
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four CPU devices")
+    mesh, _ = ts.auto_mesh(4, jax.devices()[:4], tp=1)
+    optimizer = ts.default_optimizer(total_steps=10)
+    old = ShardingRules([
+        (r"layers/e_(gate|up)$", P(None, "ep", "fsdp", "tp")),
+        (r"layers/e_down$", P(None, "ep", "tp", "fsdp"))])
+    params, opt_state = ts.init_sharded_state(jax.random.key(0), cfg, mesh,
+                                              optimizer, rules=old)
+    assert params["layers"]["e_gate"].sharding.spec == P(None, "ep", "fsdp",
+                                                         "tp")
+    ckpt = Checkpoint.from_directory(str(tmp_path / "ck"))
+    ckpt.save_pytree({"params": params, "opt_state": opt_state}, "state")
+
+    p_sh, o_sh = compile_plan(cfg, mesh).state_shardings(optimizer)
+    target = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        {"params": params, "opt_state": opt_state},
+        {"params": p_sh, "opt_state": o_sh})
+    back = ckpt.load_pytree("state", target)
+    assert "fsdp" in back["params"]["layers"]["e_gate"].sharding.spec[1]
+    for a, b, s in zip(jax.tree.leaves((params, opt_state)),
+                       jax.tree.leaves((back["params"], back["opt_state"])),
+                       jax.tree.leaves((p_sh, o_sh))):
+        assert b.sharding == s
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_moe_param_counts(cfg):
     params = moe.init_params(jax.random.key(0), cfg)
     actual = sum(int(np.prod(x.shape))

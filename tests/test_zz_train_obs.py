@@ -285,10 +285,12 @@ def test_window_summary_carves_launches():
         w2 = rec.window_summary(1500.0, 2500.0)
         assert w2["window_launches"] == 1 and w2["tokens"] == 32
         assert w2["data_wait_frac"] == pytest.approx(0.2 / 0.3, abs=1e-3)
-        # an empty window still says how the step was tiled (static per
-        # compiled shape; this synthetic recorder traced no flash kernel)
-        assert rec.window_summary(0.0, 999.0) == {"window_launches": 0,
-                                                  "flash_plans": []}
+        # an empty window still says how the step was tiled, placed and
+        # what it moves across chips (static per compiled shape; this
+        # synthetic recorder traced no flash kernel and compiled no step)
+        assert rec.window_summary(0.0, 999.0) == {
+            "window_launches": 0, "flash_plans": [],
+            "expert_placement": None, "collectives": {}}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
     finally:
@@ -461,6 +463,9 @@ def test_api_train_and_cli_json(rt_cluster):
                                dispatch_s=0.1, k=4, tokens=256,
                                batch_shape=(4, 2, 17), flops=5e7)
         rec.finalize_launch(s1, time.time())
+        rec.expert_placement = "expert"
+        rec.collectives = {"all-gather": {"count": 2, "runs": 6,
+                                          "bytes": 3_000_000_000}}
         counts = rec.drain_now()
         assert counts["kv"] == 1, counts  # the @train/ snapshot landed
         assert counts["events"] >= 1, counts  # the timeline lane shipped
@@ -496,6 +501,8 @@ def test_api_train_and_cli_json(rt_cluster):
         assert rc == 0
         assert "MFU waterfall" in text and "recorder overhead" in text
         assert "launch gap" in text
+        assert "experts placed by expert" in text
+        assert "all-gather 2 (6 runs, 3.00 GB)" in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
@@ -509,3 +516,75 @@ def test_api_train_and_cli_json(rt_cluster):
             "launches_total"] == 1
     finally:
         rec.close()
+
+
+_STEP_HLO = """\
+HloModule jit_steps, is_scheduled=true
+
+%add (x: bf16[], y: bf16[]) -> bf16[] {
+  %x = bf16[] parameter(0)
+  %y = bf16[] parameter(1)
+  ROOT %add.0 = bf16[] add(%x, %y)
+}
+
+%all-reduce-scatter.1 (input: bf16[8,64,32]) -> bf16[2,64,32] {
+  %input = bf16[8,64,32]{2,1,0} parameter(0)
+  %all-reduce.7 = bf16[8,64,32]{2,1,0} all-reduce(%input), replica_groups={{0,1,2,3}}, to_apply=%add
+  %offset = s32[] constant(0)
+  ROOT %dynamic-slice.3 = bf16[2,64,32]{2,1,0} dynamic-slice(%all-reduce.7, %offset, %offset, %offset), dynamic_slice_sizes={2,64,32}
+}
+
+%layer (p: (s32[], bf16[8,64,32])) -> (s32[], bf16[8,64,32]) {
+  %p = (s32[], bf16[8,64,32]{2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %rows = bf16[8,64,32]{2,1,0} get-tuple-element(%p), index=1
+  %fusion.1 = bf16[2,64,32]{2,1,0} fusion(%rows), kind=kCustom, calls=%all-reduce-scatter.1
+  %all-gather.4 = bf16[8,64,32]{2,1,0} all-gather(%fusion.1), dimensions={0}, metadata={op_name="jit(steps)/while/body/moe_combine/ecd,gec->gd/dot_general"}
+  %all-reduce.9 = bf16[8,64,128]{2,1,0} all-reduce(%all-gather.4), replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(steps)/while/body/moe_experts/ecd,edf->ecf/dot_general"}
+  ROOT %next = (s32[], bf16[8,64,32]{2,1,0}) tuple(%i, %all-gather.4)
+}
+
+%more (p.1: (s32[], bf16[8,64,32])) -> pred[] {
+  %p.1 = (s32[], bf16[8,64,32]{2,1,0}) parameter(0)
+  %i.1 = s32[] get-tuple-element(%p.1), index=0
+  %three = s32[] constant(3)
+  ROOT %lt = pred[] compare(%i.1, %three), direction=LT
+}
+
+ENTRY %main (a: bf16[8,64,32]) -> bf16[8,64,32] {
+  %a = bf16[8,64,32]{2,1,0} parameter(0)
+  %zero = s32[] constant(0)
+  %init = (s32[], bf16[8,64,32]{2,1,0}) tuple(%zero, %a)
+  %while.1 = (s32[], bf16[8,64,32]{2,1,0}) while(%init), condition=%more, body=%layer
+  %out = bf16[8,64,32]{2,1,0} get-tuple-element(%while.1), index=1
+  %permute-start = (bf16[8,64,32]{2,1,0}, bf16[8,64,32]{2,1,0}) collective-permute-start(%out), source_target_pairs={{0,1},{1,0}}
+  ROOT %permute-done = bf16[8,64,32]{2,1,0} collective-permute-done(%permute-start)
+}
+"""
+
+
+def test_collective_inventory_of_a_step_program():
+    """What the recorder's ``collectives`` is read with: every collective
+    that runs, the fused ones too, weighted by the loops round it; an
+    all-reduce that is only sliced is the reduce-scatter it stands for,
+    with the slice's bytes; an asynchronous pair counts once; each carries
+    the product it completes, so a test can ask for the experts'."""
+    import types
+
+    from ray_tpu.util import hlo_copies
+
+    program = types.SimpleNamespace(as_text=lambda: _STEP_HLO)
+    found = {c["name"]: c for c in hlo_copies.collectives(program)}
+    assert sorted(found) == ["all-gather.4", "all-reduce.7", "all-reduce.9",
+                             "permute-done"]
+    assert found["all-reduce.7"]["kind"] == "reduce-scatter"
+    assert found["all-reduce.7"]["arrays"] == [("bf16", (2, 64, 32))]
+    assert found["all-reduce.9"]["runs"] == 3
+    assert "moe_experts" in found["all-reduce.9"]["op_name"]
+    assert found["permute-done"]["kind"] == "collective-permute"
+    rows = 8 * 64 * 32 * 2
+    assert hlo_copies.collective_inventory(program) == {
+        "reduce-scatter": {"count": 1, "runs": 3, "bytes": 3 * rows // 4},
+        "all-gather": {"count": 1, "runs": 3, "bytes": 3 * rows},
+        "all-reduce": {"count": 1, "runs": 3, "bytes": 3 * rows * 4},
+        "collective-permute": {"count": 1, "runs": 1, "bytes": rows}}
