@@ -24,6 +24,13 @@ Two forwards share one block arithmetic (``_qkv``, ``_after_attention``):
 Works for both model families: llama densely, MoE through
 ``moe.served_ffn_half`` (drop-free routing whose work follows the routed
 tokens), whose per-layer routing counters the ``_stats`` forms return.
+
+A model with recurrent layers (``models/hybrid.py``) keeps, beside the keys
+and values of its attention layers, a state and a convolution tail for each
+recurrent layer: the cache is a tree of buffers by layer kind
+(``init_cache``), and ``_forward_with_cache_stats`` and ``decode_step_on_slots``
+hand such a model to that module, which builds its attention layers from
+the pieces here.
 """
 
 from __future__ import annotations
@@ -44,16 +51,43 @@ Params = Dict[str, Any]
 
 
 def init_cache(cfg: llama.LlamaConfig, batch: int, max_len: int) -> Dict:
-    """Zeroed KV cache [L, B, max_len, kv_heads, head_dim] (compute dtype)."""
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.compute_dtype),
-            "v": jnp.zeros(shape, cfg.compute_dtype)}
+    """The zeroed cache of ``batch`` rows, a tree of buffers by layer kind:
+    ``k`` and ``v`` [L_attention, B, max_len, kv_heads, head_dim] (compute
+    dtype) for the layers that attend, and for a model with recurrent
+    layers their ``ssm`` state and ``conv`` tail as well
+    (``hybrid.init_state``): ``cache_names``'s buffers."""
+    shape = (cfg.n_attention_layers, batch, max_len, cfg.n_kv_heads,
+             cfg.head_dim)
+    cache = {"k": jnp.zeros(shape, cfg.compute_dtype),
+             "v": jnp.zeros(shape, cfg.compute_dtype)}
+    if cfg.n_recurrent_layers:
+        from ray_tpu.models import hybrid
+
+        cache.update(hybrid.init_state(cfg, batch))
+    return cache
+
+
+def cache_names(cfg) -> Tuple[str, ...]:
+    """``init_cache``'s buffers in the order every engine program takes and
+    returns them (a dict that went through ``jax.tree`` comes back sorted:
+    nothing may go by a dict's own order)."""
+    return ("k", "v") + (("ssm", "conv") if cfg.n_recurrent_layers else ())
+
+
+def embed(params: Params, cfg, tokens: jax.Array) -> jax.Array:
+    """The tokens' embeddings in the compute dtype, times
+    ``embedding_multiplier`` where the config has one."""
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
 
 
 def _qkv(cfg, x, layer, sin, cos, positions):
     """The attention half up to the cache: pre-norm, the three projections
-    and rope at ``positions`` [B, S]. Returns q [B, S, hq, hd] and k, v
-    [B, S, hkv, hd]."""
+    and rope at ``positions`` [B, S] (none where the config rotates
+    nothing: ``sin`` and ``cos`` are then not looked at). Returns q
+    [B, S, hq, hd] and k, v [B, S, hkv, hd]."""
     b, s, _ = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.compute_dtype
@@ -65,12 +99,21 @@ def _qkv(cfg, x, layer, sin, cos, positions):
             f"the einsum path (same math; the pallas kernel is a "
             f"long-sequence training implementation).")
     h = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
-    q = apply_rope(llama.project_qk(cfg, h, layer, "q").reshape(b, s, hq, hd),
-                   sin, cos, positions)
-    k = apply_rope(llama.project_qk(cfg, h, layer, "k").reshape(b, s, hkv, hd),
-                   sin, cos, positions)
+    def rotated(y):
+        return apply_rope(y, sin, cos, positions) if cfg.use_rope else y
+
+    q = rotated(llama.project_qk(cfg, h, layer, "q").reshape(b, s, hq, hd))
+    k = rotated(llama.project_qk(cfg, h, layer, "k").reshape(b, s, hkv, hd))
     v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
     return q, k, v
+
+
+def _rope_table(cfg, max_len: int):
+    """(sin, cos) up to ``max_len``, or (None, None) without rotation."""
+    if not cfg.use_rope:
+        return None, None
+    return rope_angles(max_len, cfg.head_dim, cfg.rope_theta,
+                       cfg.compute_dtype)
 
 
 def _split_experts(layers: Params) -> Tuple[Params, Optional[Params]]:
@@ -93,7 +136,8 @@ def _after_attention(cfg, x, attn, layer, experts=None, index=None):
     second part and which layer this is."""
     b, s, _ = x.shape
     with jax.named_scope("attn"):
-        x = x + attn.reshape(b, s, -1) @ layer["wo"].astype(cfg.compute_dtype)
+        x = x + llama.on_residual(
+            cfg, attn.reshape(b, s, -1) @ layer["wo"].astype(cfg.compute_dtype))
     if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
         with jax.named_scope("mlp"):
             return llama.ffn_half(cfg, x, layer), None
@@ -125,7 +169,8 @@ def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos,
         q, k, v = _qkv(cfg, x, layer, sin, cos, positions)
         cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, pos, 0, 0))
         cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, pos, 0, 0))
-        attn = mha(q, cache_k, cache_v, causal=True, q_offset=pos)
+        attn = mha(q, cache_k, cache_v, causal=True, q_offset=pos,
+                   scale=cfg.attn_scale)
     x, stats = _after_attention(cfg, x, attn, layer, experts, index)
     return x, cache_k, cache_v, stats
 
@@ -136,7 +181,10 @@ def _head(params: Params, cfg, x):
     x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"]).astype(cdt)
-    return (x @ head).astype(jnp.float32)
+    logits = (x @ head).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _forward_with_cache(params: Params, tokens: jax.Array,
@@ -156,10 +204,14 @@ def _forward_with_cache_stats(params: Params, tokens: jax.Array,
     projects ONLY the final position to the vocab — generation never needs
     the full [B, S, V] prefill logits, which at 32k vocab would dominate
     HBM (the same blowup llama's loss_chunk avoids)."""
-    cdt = cfg.compute_dtype
-    x = params["embed"].astype(cdt)[tokens]
+    if cfg.n_recurrent_layers:
+        from ray_tpu.models import hybrid
+
+        return (*hybrid.forward_with_cache(params, tokens, cfg, cache, pos,
+                                           last_only), None)
+    x = embed(params, cfg, tokens)
     max_len = cache["k"].shape[2]
-    sin, cos = rope_angles(max_len, cfg.head_dim, cfg.rope_theta, cdt)
+    sin, cos = _rope_table(cfg, max_len)
 
     layers, experts = _split_experts(params["layers"])
 
@@ -206,27 +258,16 @@ def decode_step_in_place_stats(params: Params, tok: jax.Array, cfg,
     update is in place and nothing cache-sized is stacked, transposed or
     gathered. A position past ``max_len`` (a row that finished earlier in
     a fused launch, whose tokens nobody reads) writes nothing."""
-    cdt = cfg.compute_dtype
-    b = tok.shape[0]
-    _, _, max_len, hkv, hd = ck.shape
-    x = params["embed"].astype(cdt)[tok][:, None, :]
-    sin, cos = rope_angles(max_len, cfg.head_dim, cfg.rope_theta, cdt)
-    rows = slot0 + jnp.arange(b)
-
-    def layer_rows(cache, l):  # [B, max_len, hkv, hd], where they lie
-        return jax.lax.dynamic_slice(cache, (l, slot0, 0, 0, 0),
-                                     (1, b, max_len, hkv, hd))[0]
+    max_len = ck.shape[2]
+    x = embed(params, cfg, tok)[:, None, :]
+    sin, cos = _rope_table(cfg, max_len)
+    rows = slot0 + jnp.arange(tok.shape[0])
 
     def body(carry, sl):
         x, ck, cv = carry
         layer, l = sl
-        with jax.named_scope("attn"):
-            q, k, v = _qkv(cfg, x, layer, sin, cos, pos[:, None])
-            ck = ck.at[l, rows, pos].set(k[:, 0], mode="drop")
-            cv = cv.at[l, rows, pos].set(v[:, 0], mode="drop")
-            attn = mha(q, layer_rows(ck, l), layer_rows(cv, l), causal=True,
-                       q_offset=pos)
-        x, stats = _after_attention(cfg, x, attn, layer, experts, l)
+        x, ck, cv, stats = attend_in_place(cfg, x, layer, ck, cv, l, slot0,
+                                           rows, pos, sin, cos, experts)
         return (x, ck, cv), stats
 
     layers, experts = _split_experts(params["layers"])
@@ -235,6 +276,47 @@ def decode_step_in_place_stats(params: Params, tok: jax.Array, cfg,
     with jax.named_scope("head_sample"):
         logits = _head(params, cfg, x)[:, 0, :]
     return logits, ck, cv, _fold_stats(stats)
+
+
+def attend_in_place(cfg, x, layer, ck, cv, l, slot0, rows, pos, sin, cos,
+                    experts=None):
+    """One attention block of a decode step, in place on the slot cache:
+    ``x`` [B, 1, d] are the cache's rows ``rows`` = ``slot0 .. slot0 + B``,
+    each at its own position ``pos`` [B]; layer ``l`` of ``ck``/``cv``
+    takes their new K and V with one indexed update and is read where it
+    lies. Returns (hidden, ck, cv, stats)."""
+    b = x.shape[0]
+    _, _, max_len, hkv, hd = ck.shape
+
+    def layer_rows(cache):  # [B, max_len, hkv, hd], where they lie
+        return jax.lax.dynamic_slice(cache, (l, slot0, 0, 0, 0),
+                                     (1, b, max_len, hkv, hd))[0]
+
+    with jax.named_scope("attn"):
+        q, k, v = _qkv(cfg, x, layer, sin, cos, pos[:, None])
+        ck = ck.at[l, rows, pos].set(k[:, 0], mode="drop")
+        cv = cv.at[l, rows, pos].set(v[:, 0], mode="drop")
+        attn = mha(q, layer_rows(ck), layer_rows(cv), causal=True,
+                   q_offset=pos, scale=cfg.attn_scale)
+    x, stats = _after_attention(cfg, x, attn, layer, experts, l)
+    return x, ck, cv, stats
+
+
+def decode_step_on_slots(params: Params, tok: jax.Array, cfg, cache: Dict,
+                         slot0, pos: jax.Array
+                         ) -> Tuple[jax.Array, Dict, Optional[jax.Array]]:
+    """``decode_step_in_place_stats`` on the slot cache as the tree of
+    buffers ``init_cache`` makes: (logits, the tree, routing counters or
+    None). A model with recurrent layers steps their state in place as
+    well (``hybrid.decode_step_in_place``)."""
+    if cfg.n_recurrent_layers:
+        from ray_tpu.models import hybrid
+
+        return (*hybrid.decode_step_in_place(params, tok, cfg, cache, slot0,
+                                             pos), None)
+    logits, ck, cv, stats = decode_step_in_place_stats(
+        params, tok, cfg, cache["k"], cache["v"], slot0, pos)
+    return logits, {"k": ck, "v": cv}, stats
 
 
 def generate(params: Params, prompt: jax.Array, cfg,
@@ -346,6 +428,12 @@ def generate_speculative(params: Params, draft_params: Params,
     target never agrees with still "works" but pays k draft launches per
     emitted token.
     """
+    if cfg.n_recurrent_layers or draft_cfg.n_recurrent_layers:
+        raise NotImplementedError(
+            "speculative decoding on a model with recurrent layers: a "
+            "rejected draft is undone by writing over its keys and values, "
+            "and a recurrent state that has taken the rejected tokens in "
+            "cannot be taken back (it needs a snapshot per round)")
     b, s = prompt.shape
     total = max_len or (s + max_new_tokens + speculate_k + 1)
     if total < s + max_new_tokens + speculate_k + 1:

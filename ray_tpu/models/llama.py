@@ -68,10 +68,31 @@ class LlamaConfig:
     # QK-norm as OLMoE has it: a learned RMSNorm over the whole q and the
     # whole k projection, before the split into heads and before RoPE
     qk_norm: bool = False
+    # Granite's knobs, each neutral by default (a neutral one adds no
+    # operation to a program): queries and keys rotated or not ("nope"), the
+    # softmax scale where it is not 1/sqrt(head_dim), and the scalars on the
+    # embedding, on every residual branch and under the logits. The cached
+    # (served) blocks and ``ffn_half`` read them; the training blocks
+    # refuse a config that sets one (``forward_hidden``).
+    use_rope: bool = True
+    attn_scale: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def n_attention_layers(self) -> int:
+        """Layers that keep keys and values (``models/hybrid.py`` has
+        layers that keep a recurrent state instead)."""
+        return self.n_layers
+
+    @property
+    def n_recurrent_layers(self) -> int:
+        return 0
 
     def qk_norm_params(self) -> int:
         """One layer's ``q_norm`` and ``k_norm`` weights (0 without them)."""
@@ -198,7 +219,15 @@ def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
     h = rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
     gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
     up = h @ layer["w_up"].astype(cdt)
-    return x + (gate * up) @ layer["w_down"].astype(cdt)
+    return x + on_residual(cfg, (gate * up) @ layer["w_down"].astype(cdt))
+
+
+def on_residual(cfg: LlamaConfig, branch: jax.Array) -> jax.Array:
+    """A block's branch as it joins the residual stream: times
+    ``residual_multiplier`` where the config has one."""
+    if cfg.residual_multiplier == 1.0:
+        return branch
+    return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
 
 
 def _block(cfg: LlamaConfig, x: jax.Array, layer: Params,
@@ -264,6 +293,14 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     """tokens [batch, seq] -> (final-norm hidden [batch, seq, d], head [d, V]),
     both in compute dtype — callers project to logits (possibly chunked)."""
     cdt = cfg.compute_dtype
+    if (not cfg.use_rope or cfg.attn_scale is not None
+            or cfg.n_recurrent_layers
+            or (cfg.embedding_multiplier, cfg.residual_multiplier,
+                cfg.logits_scaling) != (1.0, 1.0, 1.0)):
+        raise NotImplementedError(
+            "the training blocks compute rotated attention at 1/sqrt(head_dim) "
+            "with no multipliers and no recurrent layers; this config is "
+            "served only (models/generate.py, models/hybrid.py)")
     x = params["embed"].astype(cdt)[tokens]
     sin, cos = rope_angles(tokens.shape[1], cfg.head_dim, cfg.rope_theta, cdt)
 

@@ -7,12 +7,19 @@ paged dynamic memory:
 - ONE static KV cache [L, max_slots, max_len, hkv, hd]; a request
   occupies a SLOT for its lifetime. No paging, no dynamic shapes — the
   compiled programs never change as requests come and go. The cache is
-  one buffer: every engine program takes it DONATED and returns it
-  aliased, and the batcher rebinds it from each result, so the device
-  holds it once and no program copies it.
+  one tree of buffers by layer kind (``generate.init_cache``): ``k`` and
+  ``v`` for the layers that attend and, for a model with recurrent layers
+  (``models/hybrid.py``), their state ``ssm`` [L, max_slots, h, p, n] and
+  convolution tail ``conv`` beside them. Every engine program takes the
+  tree's buffers DONATED and returns them aliased, and the batcher rebinds
+  the whole tree from each result, so the device holds it once and no
+  program copies it.
 - Admission is a per-request prefill that writes the prompt's KV into
   the free slot's row (`dynamic_update_slice` on the slot axis, in
-  place) and returns the first generated token.
+  place) and returns the first generated token. A recurrent layer's row
+  takes the state and the tail AFTER the prompt's last token, so a slot
+  that is taken again starts from its own prefill and never from what
+  the last request left.
 - Every engine tick is ONE compiled launch decoding the ACTIVE slots
   together, in place: one batched forward over the bucket's rows with
   per-row positions (rope, causal mask), each layer writing its rows'
@@ -53,6 +60,7 @@ import numpy as np
 
 from ray_tpu.models import generate as G
 from ray_tpu.models import llama
+from ray_tpu.ops import ssm
 from ray_tpu.util import engine_recorder as _rec
 from ray_tpu.util import hlo_copies
 from ray_tpu.util import prefix_hash as PH
@@ -314,11 +322,25 @@ class _Request:
 class SlotCacheLost(RuntimeError):
     """A compiled call failed after it had consumed the donated slot
     cache. The batcher has rebuilt a zeroed cache and dropped every
-    active request (their KV went with the buffer); it admits again."""
+    active request (their rows went with the buffers); it admits again."""
+
+
+def _refuse_prefix_cache(cfg) -> None:
+    if cfg.n_recurrent_layers:
+        raise ValueError(
+            f"prefix cache (kv_cache_bytes > 0) on a model with "
+            f"{cfg.n_recurrent_layers} recurrent layers: a retained prefix "
+            f"is pages of K and V, and restoring them restores nothing of a "
+            f"recurrent layer's state after that prefix (it would need a "
+            f"snapshot of the state per retained prefix). Serve it with "
+            f"kv_cache_bytes=0")
 
 
 class ContinuousBatcher:
-    """Slot-based continuous batching engine around one model."""
+    """Slot-based continuous batching engine around one model: its slot
+    cache is the tree of buffers ``generate.init_cache`` makes for the
+    model's layer kinds (keys and values, and a recurrent layer's state
+    and tail), one row of each a slot."""
 
     def __init__(self, params: Params, cfg: llama.LlamaConfig, *,
                  max_slots: int = 8, max_len: int = 512,
@@ -332,9 +354,11 @@ class ContinuousBatcher:
         #: the engine's recorder shows it
         self.program_stats: List[Dict[str, Any]] = []
         self.params = params
-        # ONE buffer each, donated to every compiled call and rebound
-        # from its result (``_donating``)
-        self._ck, self._cv = self._zero_cache()
+        if prefix_cache is not None:
+            _refuse_prefix_cache(cfg)
+        # ONE tree of buffers, each donated to every compiled call and the
+        # whole tree rebound from its result (``_donating``, ``_launch``)
+        self._cache = self._zero_cache()
         self._free: List[int] = list(range(max_slots))
         self._active: Dict[int, _Request] = {}  # slot -> request
         self._cur = np.zeros(max_slots, np.int32)   # token AT pos, per slot
@@ -361,6 +385,14 @@ class ContinuousBatcher:
         # launches since ``take_moe_stats``, by kind of launch; a dense
         # model's stay empty
         self._moe_stats: Dict[str, np.ndarray] = {}
+        # chunks the recurrent layers' scans ran over in the prefills since
+        # ``take_scan_chunks`` (layers x chunks of the prompt); 0 without
+        # recurrent layers
+        self._scan_chunks = 0
+
+    # the attention layers' buffers by name (tests and ``_capture``)
+    _ck = property(lambda self: self._cache["k"])
+    _cv = property(lambda self: self._cache["v"])
 
     @property
     def params(self) -> Params:
@@ -380,28 +412,54 @@ class ContinuousBatcher:
         self._sparse = "router" in params["layers"]
         self._params = params
 
-    def _zero_cache(self) -> Tuple[jax.Array, jax.Array]:
-        cache = G.init_cache(self.cfg, self.max_slots, self.max_len)
-        return cache["k"], cache["v"]
+    def check_params(self, params: Params) -> None:
+        """Raise where ``params`` is not a tree of the engine's model: a
+        swap takes weights of the same tree and shapes (the slot cache, the
+        programs and the config were made for them)."""
+        tree, leaves = self._weights
+        new_leaves, new_tree = jax.tree.flatten(params)
+        if new_tree != tree:
+            raise ValueError(
+                f"load_params: the weights are another model's tree "
+                f"({new_tree.num_leaves} leaves; this engine serves "
+                f"{type(self.cfg).__name__} with {tree.num_leaves}: "
+                f"{new_tree} != {tree})"[:600])
+        for path, had, new in zip(jax.tree.leaves_with_path(params), leaves,
+                                  new_leaves):
+            if tuple(jnp.shape(new)) != tuple(had.shape):
+                raise ValueError(
+                    f"load_params: {jax.tree_util.keystr(path[0])} is "
+                    f"{tuple(jnp.shape(new))}, this engine's is "
+                    f"{tuple(had.shape)}")
+
+    def _zero_cache(self) -> Dict[str, jax.Array]:
+        return G.init_cache(self.cfg, self.max_slots, self.max_len)
+
+    def _launch(self, fn, *args):
+        """``fn(params, *the tree's buffers, *args)``: rebinds the tree
+        from the front of the result and returns the rest."""
+        names = G.cache_names(self.cfg)
+        out = fn(self.params, *(self._cache[name] for name in names), *args)
+        self._cache = dict(zip(names, out))
+        return out[len(names):]
 
     @contextlib.contextmanager
     def _donating(self):
-        """Round a compiled call that takes ``_ck``/``_cv`` donated, from
-        the call through the host read that fences it; the block rebinds
-        both from the call's result. A failure before the call consumed
-        them (a compile error, a bad argument) leaves the cache as it
-        was and is the caller's to handle. One after (the buffers are
-        deleted, or the results poisoned) has lost every slot's KV: the
-        batcher starts over on a zeroed cache with no active request
-        and raises :class:`SlotCacheLost`, so that it never holds a
-        deleted buffer."""
-        ck, cv = self._ck, self._cv
+        """Round a compiled call that takes the slot tree donated
+        (``_launch``), from the call through the host read that fences it.
+        A failure before the call consumed the buffers (a compile error, a
+        bad argument) leaves the cache as it was and is the caller's to
+        handle. One after (a buffer is deleted, or the results poisoned)
+        has lost every slot's rows: the batcher starts over on a tree
+        zeroed in every buffer with no active request and raises
+        :class:`SlotCacheLost`, so that it never holds a deleted buffer."""
+        held = list(self._cache.values())
         try:
             yield
         except BaseException as e:
-            if not (ck.is_deleted() or cv.is_deleted()):
+            if not any(buf.is_deleted() for buf in held):
                 raise
-            self._ck, self._cv = self._zero_cache()
+            self._cache = self._zero_cache()
             self._active.clear()
             self._free = list(range(self.max_slots))
             if not isinstance(e, Exception):
@@ -421,6 +479,13 @@ class ContinuousBatcher:
         takes them once a tick."""
         out, self._moe_stats = self._moe_stats, {}
         return {kind: [int(v) for v in stats] for kind, stats in out.items()}
+
+    def take_scan_chunks(self) -> int:
+        """Chunks the recurrent layers' prefill scans ran over since the
+        last call (0 for a model without them): the engine takes it once
+        a tick."""
+        out, self._scan_chunks = self._scan_chunks, 0
+        return out
 
     # -- admission --------------------------------------------------------
 
@@ -494,12 +559,12 @@ class ContinuousBatcher:
                              jnp.asarray(np.asarray(
                                  jax.random.PRNGKey(int(seed)), np.uint32)))
                 with self._donating():
-                    self._ck, self._cv, first, *new_key = fn(
-                        self.params, self._ck, self._cv, *args)
+                    first, *new_key = self._launch(fn, *args)
                     if self._sparse:  # the counters ride behind the token
                         first = np.asarray(first)
                         self._note_moe_stats("prefill", first[1:])
                     first_tok = int(first[0])
+                self._scan_chunks += scan_chunks(self.cfg, s - cached)
         except SlotCacheLost:
             raise  # every slot is free again
         except BaseException:
@@ -610,8 +675,7 @@ class ContinuousBatcher:
         # device is busy under this span and idle outside it
         with _span("decode_launch", parts, k=k, bucket=bucket, active=n), \
                 self._donating():
-            self._ck, self._cv, toks, *new_keys = fn(
-                self.params, self._ck, self._cv, *args)
+            toks, *new_keys = self._launch(fn, *args)
             toks = np.asarray(toks)  # [k, bucket]
             if self._sparse:  # the counters ride behind the tokens
                 self._note_moe_stats("decode", toks[k * bucket:])
@@ -681,16 +745,14 @@ class ContinuousBatcher:
         never recovers. Keep this bucket set in lockstep with
         step_many's choice. The programs run for real, on the donated
         cache: a decode step rewrites, for each row, the K/V its next
-        real step writes again, and a prefill goes to a free slot, whose
-        row the next admission overwrites whole."""
+        real step writes again (and steps a recurrent state that nobody
+        reads), and a prefill goes to a free slot, whose row the next
+        admission overwrites whole."""
         for k in sorted(set(strides)):
             for bucket in sorted({1, self.max_slots}):
                 fn = self._program(bucket, int(k))
                 with self._donating():
-                    self._ck, self._cv, toks, *_ = fn(
-                        self.params, self._ck, self._cv,
-                        *self._stage(bucket, 0)[1])
-                    np.asarray(toks)
+                    np.asarray(self._launch(fn, *self._stage(bucket, 0)[1])[0])
         for s in prompt_lens:
             if not self._free:
                 raise RuntimeError("no free slot to warm a prefill in")
@@ -701,9 +763,7 @@ class ContinuousBatcher:
                 args += (jnp.float32(0.0), jnp.int32(0),
                          jnp.asarray(self._keys[0]))
             with self._donating():
-                self._ck, self._cv, first, *_ = fn(
-                    self.params, self._ck, self._cv, *args)
-                np.asarray(first)
+                np.asarray(self._launch(fn, *args)[0])
 
     def cancel(self, req_id: int) -> bool:
         """Free a request's slot mid-flight (client disconnect). The slot's
@@ -855,6 +915,12 @@ class ContinuousEngine:
                                              max_slots=max_slots)
         # the batcher's own list: a program compiled later shows too
         self._recorder.decode_programs = self._batcher.program_stats
+        if cfg.n_recurrent_layers:
+            self._recorder.state_layout = {
+                "layers": {"attention": cfg.n_attention_layers,
+                           "recurrent": cfg.n_recurrent_layers},
+                "state_bytes_per_row": cfg.state_bytes_per_row(),
+                "kv_bytes_per_position": cfg.kv_bytes_per_position()}
         # engine-thread-confined tick state (never touched off-thread):
         # end of the previous decode launch (the tick-gap anchor; reset
         # to None when the engine goes idle), and the tick being
@@ -1029,6 +1095,7 @@ class ContinuousEngine:
         # every subsequent decode tick re-transfer the full model
         # host-to-device when jit commits its arguments
         params = jax.tree_util.tree_map(jnp.asarray, params)
+        self._batcher.check_params(params)
         with self._work:
             if self._stopped:
                 raise RuntimeError("engine is shut down")
@@ -1228,7 +1295,8 @@ class ContinuousEngine:
             self._recorder.record_tick(t_start=t_start, wall_s=wall_s,
                                        phases=ph,
                                        moe=self._batcher.take_moe_stats(),
-                                       **fields)
+                                       scan_chunks=self._batcher
+                                       .take_scan_chunks(), **fields)
             if tick is not None and self._on_tick is not None:
                 try:
                     self._on_tick(tick, self.max_slots)
@@ -1398,12 +1466,41 @@ def _with_stats(toks, stats):
     return jnp.concatenate([toks.reshape(-1), stats])
 
 
-def _write_row(ck, cv, row: Dict[str, jax.Array], slot):
-    """A prefilled row into its slot of the (donated) cache, in place:
-    one row's bytes move, not the cache's."""
+def scan_chunks(cfg, tokens: int) -> int:
+    """Chunks a prefill of ``tokens`` runs its recurrent layers' scans
+    over, all layers together; 0 for a model without such layers."""
+    if not cfg.n_recurrent_layers:
+        return 0
+    return cfg.n_recurrent_layers * ssm.n_chunks(tokens, cfg.mamba_chunk_size)
+
+
+def _write_row(cache: Dict[str, jax.Array], row: Dict[str, jax.Array], slot
+               ) -> Dict[str, jax.Array]:
+    """A prefilled row into its slot of the (donated) slot tree, in place,
+    buffer by buffer (its K and V; a recurrent layer's final state and
+    tail): one row's bytes move, not the cache's."""
     with jax.named_scope("kv_scatter"):
-        return (jax.lax.dynamic_update_slice(ck, row["k"], (0, slot, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(cv, row["v"], (0, slot, 0, 0, 0)))
+        return {name: jax.lax.dynamic_update_slice(
+                    buf, row[name], (0, slot) + (0,) * (buf.ndim - 2))
+                for name, buf in cache.items()}
+
+
+def _on_slot_tree(cfg, program):
+    """``program(params, cache, *args) -> (cache, *results)`` on the slot
+    tree, as the jitted function the batcher calls: ``(params, *the tree's
+    buffers, *args) -> (*the tree's buffers, *results)``, the buffers in
+    ``generate.cache_names``'s order and every one of them donated. It
+    keeps ``program``'s name, which is the program's in a device trace."""
+    names = G.cache_names(cfg)
+    n = len(names)
+
+    @functools.wraps(program)
+    def flat(params, *args):
+        cache, *results = program(params, dict(zip(names, args[:n])),
+                                  *args[n:])
+        return (*(cache[name] for name in names), *results)
+
+    return jax.jit(flat, donate_argnums=tuple(range(1, 1 + n)))
 
 
 @functools.lru_cache(maxsize=64)
@@ -1415,18 +1512,18 @@ def _compiled_slot_prefill(cfg, s: int, max_slots: int, max_len: int,
     programs, which also return the key)."""
 
     # the program's name in a device trace (``jit_rt_prefill``)
-    def rt_prefill(params, ck, cv, prompt, slot, temp=None, top_k=None,
+    def rt_prefill(params, cache, prompt, slot, temp=None, top_k=None,
                    key=None):
         logits, row, stats = G._forward_with_cache_stats(
             params, prompt, cfg, G.init_cache(cfg, 1, max_len), 0)
         with jax.named_scope("head_sample"):
             first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
                                       key)
-        ck, cv = _write_row(ck, cv, row, slot)
+        cache = _write_row(cache, row, slot)
         first = _with_stats(first, stats)
-        return (ck, cv, first, key) if sample else (ck, cv, first)
+        return (cache, first, key) if sample else (cache, first)
 
-    return jax.jit(rt_prefill, donate_argnums=(1, 2))
+    return _on_slot_tree(cfg, rt_prefill)
 
 
 @functools.lru_cache(maxsize=256)
@@ -1439,9 +1536,11 @@ def _compiled_cached_prefill(cfg, c: int, sl: int, max_slots: int,
     restored K/V are the same per-position values a full prefill would
     recompute (each position's K/V depends only on tokens <= it, and
     attention always masks over the same full-length row cache). The
-    cache is donated, as in the cold prefill."""
+    cache is donated, as in the cold prefill. Pages of K and V say nothing
+    of a recurrent layer's state: a model with such layers is refused."""
+    _refuse_prefix_cache(cfg)
 
-    def rt_cached_prefill(params, ck, cv, pk, pv, suffix, slot, temp=None,
+    def rt_cached_prefill(params, cache, pk, pv, suffix, slot, temp=None,
                           top_k=None, key=None):
         row = G.init_cache(cfg, 1, max_len)
         row = {"k": row["k"].at[:, 0, :c].set(pk.astype(cfg.compute_dtype)),
@@ -1451,20 +1550,20 @@ def _compiled_cached_prefill(cfg, c: int, sl: int, max_slots: int,
         with jax.named_scope("head_sample"):
             first, key = _first_token(logits[:, -1, :], sample, temp, top_k,
                                       key)
-        ck, cv = _write_row(ck, cv, row, slot)
+        cache = _write_row(cache, row, slot)
         first = _with_stats(first, stats)
-        return (ck, cv, first, key) if sample else (ck, cv, first)
+        return (cache, first, key) if sample else (cache, first)
 
-    return jax.jit(rt_cached_prefill, donate_argnums=(1, 2))
+    return _on_slot_tree(cfg, rt_cached_prefill)
 
 
 @functools.lru_cache(maxsize=128)
 def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
                           k: int, sample: bool = False):
-    """``k`` fused decode steps, in place on the donated slot cache, for
+    """``k`` fused decode steps, in place on the donated slot tree, for
     the ``bucket`` rows from slot ``slot0`` on: a ``lax.scan`` of
-    ``generate.decode_step_in_place`` with the cache in its carry, which
-    returns the cache and the [k, bucket] token block (from a sparse
+    ``generate.decode_step_on_slots`` with the tree in its carry, which
+    returns the tree and the [k, bucket] token block (from a sparse
     model with its routing counters behind it: ``_with_stats``). One launch per K
     tokens per occupancy bucket — the decode-side make_multi_step. The
     full bucket's rows are all the slots (``slot0`` is not looked at);
@@ -1475,14 +1574,14 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
     determinism) and returns the keys [bucket, 2]."""
 
     # the program's name in a device trace (``jit_rt_decode``)
-    def rt_decode(params, ck, cv, cur, pos, slot0, temp=None, topk=None,
+    def rt_decode(params, cache, cur, pos, slot0, temp=None, topk=None,
                   keys=None):
         first = slot0 if bucket < max_slots else 0
 
         def body(carry, _):
-            ck, cv, cur, pos, keys = carry
-            logits, ck, cv, stats = G.decode_step_in_place_stats(
-                params, cur, cfg, ck, cv, first, pos)
+            cache, cur, pos, keys = carry
+            logits, cache, stats = G.decode_step_on_slots(
+                params, cur, cfg, cache, first, pos)
             with jax.named_scope("head_sample"):
                 if sample:
                     keys, subs = jnp.moveaxis(
@@ -1490,14 +1589,14 @@ def _compiled_bucket_scan(cfg, bucket: int, max_slots: int, max_len: int,
                     nxt = jax.vmap(_row_sample)(logits, temp, topk, subs)
                 else:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (ck, cv, nxt, pos + 1, keys), (nxt, stats)
+            return (cache, nxt, pos + 1, keys), (nxt, stats)
 
-        (ck, cv, _, _, keys), (toks, stats) = jax.lax.scan(
-            body, (ck, cv, cur, pos, keys), None, length=k)
+        (cache, _, _, keys), (toks, stats) = jax.lax.scan(
+            body, (cache, cur, pos, keys), None, length=k)
         toks = _with_stats(toks, G._fold_stats(stats))
-        return (ck, cv, toks, keys) if sample else (ck, cv, toks)
+        return (cache, toks, keys) if sample else (cache, toks)
 
-    return jax.jit(rt_decode, donate_argnums=(1, 2))
+    return _on_slot_tree(cfg, rt_decode)
 
 
 @functools.lru_cache(maxsize=128)
@@ -1505,14 +1604,12 @@ def _decode_executable(cfg, bucket: int, max_slots: int, max_len: int,
                        k: int, sample: bool, weights: Tuple):
     """``_compiled_bucket_scan``'s program compiled ahead of time for
     ``weights`` (the tree and the leaves' shapes, types and shardings),
-    and what the compiled form does to the cache (``bucket``, ``k`` and
-    ``hlo_copies.cache_traffic``): read off the very executable that
+    and what the compiled form does to the slot tree (``bucket``, ``k``
+    and ``hlo_copies.cache_traffic``): read off the very executable that
     runs, on whatever backend this is. One compile per key for the
     process, as with ``jit``'s own cache."""
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
-    cache = jax.ShapeDtypeStruct(
-        (cfg.n_layers, max_slots, max_len, cfg.n_kv_heads, cfg.head_dim),
-        cfg.compute_dtype)
+    cache = jax.eval_shape(lambda: G.init_cache(cfg, max_slots, max_len))
     args = (i32((bucket,)), i32((bucket,)), i32(()))
     if sample:
         args += (jax.ShapeDtypeStruct((bucket,), jnp.float32), i32((bucket,)),
@@ -1520,7 +1617,8 @@ def _decode_executable(cfg, bucket: int, max_slots: int, max_len: int,
     tree, leaves = weights
     fn = _compiled_bucket_scan(cfg, bucket, max_slots, max_len, k,
                                sample).lower(
-        jax.tree.unflatten(tree, leaves), cache, cache, *args).compile()
+        jax.tree.unflatten(tree, leaves),
+        *(cache[name] for name in G.cache_names(cfg)), *args).compile()
     return fn, dict(bucket=bucket, k=k, **hlo_copies.cache_traffic(
         fn, cache, rows=bucket, steps=k))
 

@@ -929,6 +929,22 @@ def cmd_engine(args: argparse.Namespace) -> int:
                       f"{prog['cache_copy_bytes_per_step']} "
                       f"({prog['cache_copy_bytes_per_step'] / max(1, prog['cache_bytes']):.2f}"
                       f" of the cache)")
+                if "state_bytes" in prog:  # a model with recurrent layers
+                    print(f"    state_donated {prog['state_donated']}  "
+                          f"state_copy_bytes_per_step "
+                          f"{prog['state_copy_bytes_per_step']} "
+                          f"({prog['state_copy_bytes_per_step'] / max(1, prog['state_bytes']):.2f}"
+                          f" of the recurrent state; 2.00 x the launch's "
+                          f"share of the rows is in place)")
+            layout = summ.get("state_layout")
+            if layout:
+                print(f"  state layout: {layout['layers']['recurrent']} "
+                      f"recurrent + {layout['layers']['attention']} "
+                      f"attention layers, "
+                      f"{layout['state_bytes_per_row']} state bytes a row, "
+                      f"{layout['kv_bytes_per_position']} K/V bytes a "
+                      f"position; prefill scan chunks "
+                      f"{summ.get('ssm_scan_chunks', 0)}")
             if summ.get("moe_assignments"):
                 print(f"  moe: {summ['moe_assignments']} assignments, "
                       f"{summ['moe_rows_computed']} rows computed, experts "
@@ -1515,7 +1531,12 @@ def main(argv=None) -> int:
              "request lifecycles, SLO/goodput rollup (@engine/ KV "
              "snapshots, util/engine_recorder.py)")
     eng_sub = p_eng.add_subparsers(dest="engine_cmd", required=True)
-    for name, what in (("stats", "per-engine SLO/goodput/phase rollup"),
+    for name, what in (("stats", "per-engine SLO/goodput/phase rollup; per "
+                        "compiled decode program what it does to the slot "
+                        "cache (cache_donated, cache_copy_bytes_per_step) "
+                        "and, for a hybrid model (models/hybrid.py), its "
+                        "recurrent state (state_layout, state_donated, "
+                        "state_copy_bytes_per_step, ssm_scan_chunks)"),
                        ("ticks", "tail the per-tick phase records"),
                        ("requests", "tail the request lifecycle records")):
         pe = eng_sub.add_parser(name, help=what)

@@ -158,8 +158,14 @@ class EngineRecorder(RecorderCore):
         #: what each compiled decode program does to the slot cache, as the
         #: engine's batcher found it in the compiled program (``bucket``,
         #: ``k``, ``cache_donated``, ``cache_copy_bytes_per_step``,
-        #: ``cache_bytes``); the engine points this at the batcher's list
+        #: ``cache_bytes``, and with recurrent layers ``state_donated``,
+        #: ``state_copy_bytes_per_step``, ``state_bytes``); the engine
+        #: points this at the batcher's list
         self.decode_programs: List[Dict[str, Any]] = []
+        #: static, of a model with recurrent layers: its layers by kind and
+        #: what a slot holds for them (``layers``, ``state_bytes_per_row``,
+        #: ``kv_bytes_per_position``); None for a model without
+        self.state_layout: Optional[Dict[str, Any]] = None
         self._overhead_tick_s = 0.0  # rt: guarded-by(_lock)
         self._tick_seq = 0  # rt: guarded-by(_lock)
         self._req_seq = 0  # rt: guarded-by(_lock)
@@ -189,15 +195,17 @@ class EngineRecorder(RecorderCore):
                     bucket: int, k: int, tokens: int, admitted: int,
                     gap_s: Optional[float],
                     decode_parts: Optional[Dict[str, float]] = None,
-                    moe: Optional[Dict[str, List[int]]] = None
-                    ) -> None:
+                    moe: Optional[Dict[str, List[int]]] = None,
+                    scan_chunks: int = 0) -> None:
         """One engine tick: phase partition + the decode tick-gap. The
         ONLY thing this does is append to a bounded deque — no metrics,
         no I/O (drained off-thread). ``decode_parts`` is ``decode_step``'s
         wall again, split three ways; it stays out of ``phases`` so that
         they still sum to the tick. ``moe`` is what a sparse model's
         launches of this tick said of their routing (``MOE_COUNTERS``
-        values under "prefill" and "decode"); a dense model's is empty."""
+        values under "prefill" and "decode"); a dense model's is empty.
+        ``scan_chunks``: chunks the recurrent layers' scans ran over in
+        this tick's prefills (0 without such layers)."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
@@ -213,6 +221,8 @@ class EngineRecorder(RecorderCore):
                                    for p in DECODE_PARTS}
         if moe:
             rec["moe"] = moe
+        if scan_chunks:
+            rec["scan_chunks"] = scan_chunks
         with self._lock:
             self._tick_seq += 1
             rec["seq"] = self._tick_seq
@@ -455,6 +465,10 @@ class EngineRecorder(RecorderCore):
             "decode_programs": [dict(p) for p in self.decode_programs],
         }
         out.update(_moe_totals(ticks))
+        if self.state_layout is not None:
+            out["state_layout"] = dict(self.state_layout)
+            out["ssm_scan_chunks"] = sum(t.get("scan_chunks", 0)
+                                         for t in ticks)
         if lags:
             lags = sorted(lags)
             out["pump_lag_p50_s"] = round(_pct(lags, 0.50), 6)

@@ -7,7 +7,8 @@ is in the compiled module, on any backend, with nothing run:
 ``cache_traffic`` counts the bytes of every cache-shaped result that is a
 fresh buffer (a ``copy``, a slice that was materialised, a transposed or
 re-stacked piece), and of every cache-shaped update written back in place,
-each weighted by the trip counts of the loops it sits in. A refactor that
+each weighted by the trip counts of the loops it sits in; for a recurrent
+layer's state, which every step must read and write, the reads as well. A refactor that
 brings a copy back shows here on a CPU run; what a copy costs in time only
 a chip run says.
 
@@ -154,23 +155,56 @@ def _arrays(shape: str) -> List[Tuple[str, Tuple[int, ...]]]:
             for dt, dims in _ARRAY.findall(shape)]
 
 
+_HLO_TYPE = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
+# ``ops/pallas/ssm_update.py``'s call: its result is the whole stacked state,
+# aliased to its operand; what it moves is in its name
+_STATE_KERNEL = re.compile(r"ssm_update_r(\d+)_h(\d+)_p(\d+)_n(\d+)")
+
+
+def _fused_writers(module: _Module, inst: Tuple) -> Tuple[List[Tuple],
+                                                           List[Tuple]]:
+    """For a ``fusion``: (the in-place updates in its computation whose
+    result is the fusion's own, i.e. the fusion's result is its operand,
+    updated; the computation's instructions)."""
+    callee = dict(_CALLEE.findall(inst[4])).get("calls")
+    fused = module.computations.get(callee, [])
+    return [i for i in fused if i[2] in _IN_PLACE and _arrays(i[1])
+            and _arrays(i[1])[0] in _arrays(inst[1])], fused
+
+
 def cache_traffic(compiled: Any, cache: Any, *, rows: int,
                   steps: int) -> Dict[str, Any]:
-    """For a compiled decode program over a slot cache shaped like
-    ``cache`` [L, slots, max_len, hkv, hd] (K and V each), stepping ``rows``
-    rows ``steps`` times a launch:
+    """For a compiled decode program over a slot cache, stepping ``rows``
+    rows ``steps`` times a launch. ``cache`` is the slot tree
+    (``generate.init_cache``'s buffers by name, arrays or their shapes:
+    ``k`` and ``v`` [L, slots, max_len, hkv, hd], and with recurrent layers
+    ``ssm`` [L, slots, h, p, n] and ``conv``), or one array shaped like K
+    and like V.
 
-    - ``cache_donated``: the program's input-output aliases cover K and V;
+    - ``cache_donated``: the program's input-output aliases cover the
+      whole tree;
     - ``cache_copy_bytes_per_step``: bytes, per decode step, of results and
-      in-place updates that are cache-shaped (the cache's type, ``max_len``
+      in-place updates that are K/V-shaped (the cache's type, ``max_len``
       and ``head_dim`` among the dimensions) and at least one layer's
       ``rows`` large, times the trip counts of the loops round them;
     - ``cache_bytes``: K and V together, to read the other against.
-    """
-    _, _, max_len, hkv, hd = cache.shape
-    itemsize = np.dtype(cache.dtype).itemsize
-    dtype = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}[
-        str(cache.dtype)]
+
+    With recurrent layers also ``state_donated`` (the same aliases),
+    ``state_bytes`` (the ``ssm`` buffer) and ``state_copy_bytes_per_step``:
+    bytes, per decode step, that are state-shaped (the state's type, its
+    last two dimensions, at least one layer's ``rows`` large) and are read
+    by a slice (alone or inside a fusion), written by an in-place update,
+    or a new buffer. A step that reads each row's state once and writes it
+    once where it lies moves 2.0 x ``rows / slots`` of ``state_bytes``."""
+    tree = cache if isinstance(cache, dict) else {"k": cache, "v": cache}
+    module = _Module(compiled.as_text())
+    mem = compiled.memory_analysis()
+    tree_bytes = sum(int(np.prod(buf.shape, dtype=np.int64))
+                     * np.dtype(buf.dtype).itemsize for buf in tree.values())
+    donated = bool(mem is not None and mem.alias_size_in_bytes >= tree_bytes)
+    _, _, max_len, hkv, hd = tree["k"].shape
+    itemsize = np.dtype(tree["k"].dtype).itemsize
+    dtype = _HLO_TYPE[str(tree["k"].dtype)]
     floor = rows * max_len * hkv * hd * itemsize
 
     def counted(shape: str) -> int:
@@ -181,8 +215,6 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int,
                     and hd in dims):
                 total += nbytes
         return total
-
-    module = _Module(compiled.as_text())
 
     def moved(inst, within) -> int:
         """Bytes one execution of ``inst`` moves: its result if that is
@@ -199,21 +231,66 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int,
     total = 0
     for times, inst, peers in module.walk():
         if inst[2] == "fusion":
-            callee = dict(_CALLEE.findall(inst[4])).get("calls")
-            fused = module.computations.get(callee, [])
-            writers = [i for i in fused if i[2] in _IN_PLACE
-                       and _arrays(i[1]) and _arrays(i[1])[0] in
-                       _arrays(inst[1])]
+            writers, fused = _fused_writers(module, inst)
             if writers:  # the fusion's result is its operand, updated
                 total += times * sum(moved(i, fused) for i in writers)
                 continue
         total += times * moved(inst, peers)
-    mem = compiled.memory_analysis()
-    cache_bytes = 2 * int(np.prod(cache.shape, dtype=np.int64)) * itemsize
-    return {"cache_donated": bool(
-                mem is not None and mem.alias_size_in_bytes >= cache_bytes),
-            "cache_copy_bytes_per_step": int(total // steps),
-            "cache_bytes": cache_bytes}
+    out = {"cache_donated": donated,
+           "cache_copy_bytes_per_step": int(total // steps),
+           "cache_bytes": 2 * int(np.prod(tree["k"].shape, dtype=np.int64))
+           * itemsize}
+    if "ssm" in tree:
+        out.update(state_donated=donated, **_state_traffic(
+            module, tree["ssm"], rows, steps))
+    return out
+
+
+def _state_traffic(module: _Module, state: Any, rows: int, steps: int
+                   ) -> Dict[str, int]:
+    """``cache_traffic``'s two state counters for the ``ssm`` buffer."""
+    itemsize = np.dtype(state.dtype).itemsize
+    dtype = _HLO_TYPE[str(state.dtype)]
+    last_two = tuple(state.shape[-2:])
+    floor = rows * int(np.prod(state.shape[2:], dtype=np.int64)) * itemsize
+
+    def counted(shape: str) -> int:
+        return sum(nbytes for dt, dims in _arrays(shape)
+                   for nbytes in [int(np.prod(dims, dtype=np.int64)) * itemsize]
+                   if dt == dtype and nbytes >= floor
+                   and dims[-2:] == last_two)
+
+    def touched(inst, within) -> Optional[int]:
+        """Bytes a slice reads or an in-place update writes; None for an
+        instruction that is neither."""
+        _, shape, opcode, operands, _ = inst
+        if opcode in ("dynamic-slice", "slice"):
+            return counted(shape)
+        if opcode in _IN_PLACE:
+            update = operands[_IN_PLACE[opcode]]
+            return sum(counted(i[1]) for i in within if i[0] == update)
+        return None
+
+    total = 0
+    for times, inst, peers in module.walk():
+        kernel = _STATE_KERNEL.match(inst[0]) if inst[2] == "custom-call" \
+            else None
+        if kernel:  # steps the rows its name says where they lie: read, written
+            total += times * 2 * itemsize * int(np.prod(
+                [int(v) for v in kernel.groups()], dtype=np.int64))
+            continue
+        own = touched(inst, peers)
+        if own is not None:
+            total += times * own
+        elif inst[2] == "fusion":
+            writers, fused = _fused_writers(module, inst)
+            total += times * sum(touched(i, fused) or 0 for i in fused)
+            if not writers:  # its result is a buffer of its own
+                total += times * counted(inst[1])
+        elif inst[2] not in _NO_BUFFER:
+            total += times * counted(inst[1])
+    return {"state_copy_bytes_per_step": int(total // steps),
+            "state_bytes": int(np.prod(state.shape, dtype=np.int64)) * itemsize}
 
 
 #: the opcodes that move data between chips; an asynchronous pair counts

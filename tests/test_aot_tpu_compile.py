@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import llama, moe, serving
+from ray_tpu.models import generate, hybrid, llama, moe, serving
 from ray_tpu.ops.pallas import flash
 from ray_tpu.parallel import train_step as ts
 from ray_tpu.parallel.context import mesh_scope
@@ -252,6 +252,96 @@ def test_olmoe_engine_programs_compile_for_v5e(topo, program):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "bf16[64,2048,1024]" not in text and "bf16[64,1024,2048]" not in text
+
+
+# granite-4.0-h-micro at its published widths (benchmark/configs/
+# granite-4.0-h-micro.json), two of its four periods of ten layers for the
+# test's time (the period loop then runs twice), 64 slots as its cell has
+CFG_GRANITE = hybrid.HybridConfig(
+    vocab_size=100352, d_model=2048, n_layers=20, n_heads=32, n_kv_heads=8,
+    d_ff=8192, max_seq_len=2048, norm_eps=1e-5, tie_embeddings=True,
+    param_dtype=jnp.bfloat16, use_rope=False, attn_scale=0.015625,
+    embedding_multiplier=12.0, residual_multiplier=0.22, logits_scaling=8.0,
+    layer_types=(("mamba",) * 5 + ("attention",) + ("mamba",) * 4) * 2)
+
+
+def _hybrid_args(topo, slots):
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: hybrid.init_params(jax.random.key(0), CFG_GRANITE)))
+    tree = _on(one, jax.eval_shape(
+        lambda: generate.init_cache(CFG_GRANITE, slots, 2048)))
+    buffers = [tree[name] for name in generate.cache_names(CFG_GRANITE)]
+    return params, tree, buffers, lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=one)
+
+
+def _tree_bytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in tree.values())
+
+
+def _no_weight_stack_is_copied(compiled):
+    """No program transposes a kind's stacked weights before its layer loop:
+    the chip keeps an array whose last size is no multiple of 128 lanes with
+    another size minor, and ``in_proj`` stored [in, 8512] was copied whole
+    by every prefill and every decode launch (3.84 ms each on the chip:
+    PERF.md, PR 31); stored [out, in] it is read where it lies."""
+    for line in compiled.as_text().splitlines():
+        if " copy(" in line and "%params__layers__" in line:
+            # an attention layer's k and v projections ([2, 2048, 512], 4 MB
+            # the pair) are copied; nothing a layer's size or more is
+            sizes = line.split(" = ")[1].split("[")[1].split("]")[0]
+            assert math.prod(map(int, sizes.split(","))) < 2 ** 23, line[:300]
+
+
+@pytest.mark.parametrize("bucket", [64, 1])
+def test_hybrid_decode_steps_the_whole_slot_tree_in_place_on_v5e(topo, bucket):
+    """The hybrid's decode program: every buffer of the slot tree (K, V, the
+    float32 recurrent state, the convolution tail) is aliased from input to
+    output, and the state moves exactly 2.0 x its rows' bytes a step, once
+    read and once written where it lies: the update is the Pallas call
+    ``ssm_update_r<rows>_...`` on the stacked state (written in XLA it was
+    two fusions that each read the rows' state, 3.0 x: PERF.md, PR 31), and
+    nothing state-shaped is sliced out or stacked back. K and V at head 64
+    are re-tiled round the attention product, as at the "1b" widths above:
+    known, held to 2.5 x the cache for the full bucket and not to 1.0."""
+    params, tree, buffers, i32 = _hybrid_args(topo, 64)
+    compiled = serving._compiled_bucket_scan(
+        CFG_GRANITE, bucket, 64, 2048, 8).lower(
+        params, *buffers, i32(bucket), i32(bucket), i32()).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(tree)
+    traffic = hlo_copies.cache_traffic(compiled, tree, rows=bucket, steps=8)
+    assert traffic["cache_donated"] and traffic["state_donated"]
+    rows_state = traffic["state_bytes"] * bucket // 64
+    assert traffic["state_bytes"] == 18 * 64 * 64 * 64 * 128 * 4
+    assert traffic["state_copy_bytes_per_step"] == 2 * rows_state, traffic
+    # the lone row's launch copies K and V in once for its 8 steps (0.28 x
+    # the cache a step), the "1b" widths' re-tiling again
+    assert traffic["cache_copy_bytes_per_step"] \
+        <= (2.5 if bucket == 64 else 0.3) * traffic["cache_bytes"], traffic
+    text = compiled.as_text()
+    assert f"ssm_update_r{bucket}_h64_p64_n128" in text
+    _no_weight_stack_is_copied(compiled)
+    for line in text.splitlines():  # no layer's rows of the state on their own
+        if " copy(" in line or " dynamic-update-slice(" in line:
+            assert f"f32[{bucket},64,64,128]" not in line \
+                and f"f32[1,{bucket},64,64,128]" not in line, line
+
+
+@pytest.mark.parametrize("length", [128, 384])
+def test_hybrid_prefill_compiles_for_v5e(topo, length):
+    """The cell's two prompt lengths (half a chunk of the scan; a chunk and
+    a half): the whole tree donated, one row's bytes written, and the
+    chunked scan's temporaries far from the chip's memory."""
+    params, tree, buffers, i32 = _hybrid_args(topo, 64)
+    compiled = serving._compiled_slot_prefill(
+        CFG_GRANITE, length, 64, 2048).lower(
+        params, *buffers, i32(1, length), i32()).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(tree)
+    assert mem.temp_size_in_bytes < 2 ** 30
+    _no_weight_stack_is_copied(compiled)
 
 
 def test_sharded_flash_step_compiles_for_four_chips(topo):

@@ -131,7 +131,7 @@ def test_prefill_then_decode_on_the_slot_cache(family, cfg, params):
     for row, p in enumerate(prompts):
         logits, one = G._forward_with_cache(params, jnp.asarray(p)[None], cfg,
                                             G.init_cache(cfg, 1, 96), 0)
-        ck, cv = serving._write_row(ck, cv, one, row)
+        ck, cv = serving._write_row({"k": ck, "v": cv}, one, row).values()
         assert float(jnp.abs(logits[0, -1] - ref[row][len(p) - 1]).max()) \
             < TOL * scale
     step = jax.jit(lambda tok, ck, cv, pos: G.decode_step_in_place(
