@@ -17,7 +17,13 @@ Two forwards share one block arithmetic (``_qkv``, ``_after_attention``):
   speculation.
 - ``decode_step_in_place``: one token per row at PER-ROW positions, the
   whole cache in the layer scan's carry, written only at the new
-  positions (one indexed update a layer) and read where it lies. The
+  positions (one indexed update a layer) and read where it lies, and of
+  each row only the positions below the step's ``kv_read_bound``: the
+  furthest row's position rounded up to a ``KV_CHUNK``, reckoned from
+  ``pos`` once a step, so a launch whose rows live at 700 of 2048
+  allocated positions reads 768 of each. The bound picks one of
+  ``max_len / KV_CHUNK`` branches inside the one program (no program per
+  length); a position left unread is one the causal mask zeroes. The
   serving engine's decode step, whose cache is donated: nothing the size
   of the cache is copied.
 
@@ -40,6 +46,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu.models import llama
 from ray_tpu.ops.attention import mha
@@ -231,6 +238,30 @@ def _forward_with_cache_stats(params: Params, tokens: jax.Array,
     return logits, {"k": new_k, "v": new_v}, _fold_stats(stats)
 
 
+#: positions a bounded read advances by (``kv_read_bound``): a multiple of
+#: the chip's 128 lanes, since positions are the lane dimension wherever the
+#: chip keeps K and V position-minor (head 64)
+KV_CHUNK = 256
+
+
+def kv_read_bound(pos, max_len: int, xp=jnp):
+    """How many positions of each row a decode step's attention reads: the
+    furthest row's ``pos + 1``, rounded up to whole ``KV_CHUNK``s, at most
+    ``max_len`` (a row past its end holds nothing up: it writes nothing and
+    nobody reads its token). ``pos`` [B] are the step's rows' positions, a
+    free slot's among them (the batcher keeps those at 0). ``xp`` is
+    ``jnp`` in the program and ``numpy`` in the recorder, which counts what
+    the program read from the positions it staged: one rule for both."""
+    furthest = xp.minimum(xp.max(pos), max_len - 1) + 1
+    return xp.minimum(-(-furthest // KV_CHUNK) * KV_CHUNK, max_len)
+
+
+def kv_read_bounds(max_len: int) -> Tuple[int, ...]:
+    """Every value ``kv_read_bound`` takes at ``max_len``, ascending."""
+    return tuple(min(n, max_len)
+                 for n in range(KV_CHUNK, max_len + KV_CHUNK, KV_CHUNK))
+
+
 def decode_step_in_place(params: Params, tok: jax.Array, cfg,
                          ck: jax.Array, cv: jax.Array, slot0,
                          pos: jax.Array) -> Tuple[jax.Array, jax.Array,
@@ -254,20 +285,23 @@ def decode_step_in_place_stats(params: Params, tok: jax.Array, cfg,
     cache's way through the step. It rides the layer scan's carry, each
     layer writes its rows' new K and V ([B, hkv, hd]) at ``(layer, row,
     pos)`` with one indexed update and attention reads the layer's rows
-    out of the same buffer — with the cache donated by the caller the
-    update is in place and nothing cache-sized is stacked, transposed or
-    gathered. A position past ``max_len`` (a row that finished earlier in
-    a fused launch, whose tokens nobody reads) writes nothing."""
+    out of the same buffer, below the step's ``kv_read_bound`` only — with
+    the cache donated by the caller the update is in place and nothing
+    cache-sized is stacked, transposed or gathered. A position past
+    ``max_len`` (a row that finished earlier in a fused launch, whose
+    tokens nobody reads) writes nothing."""
     max_len = ck.shape[2]
     x = embed(params, cfg, tok)[:, None, :]
     sin, cos = _rope_table(cfg, max_len)
     rows = slot0 + jnp.arange(tok.shape[0])
+    bound = kv_read_bound(pos, max_len)  # once a step, for every layer
 
     def body(carry, sl):
         x, ck, cv = carry
         layer, l = sl
         x, ck, cv, stats = attend_in_place(cfg, x, layer, ck, cv, l, slot0,
-                                           rows, pos, sin, cos, experts)
+                                           rows, pos, sin, cos, bound,
+                                           experts)
         return (x, ck, cv), stats
 
     layers, experts = _split_experts(params["layers"])
@@ -279,27 +313,53 @@ def decode_step_in_place_stats(params: Params, tok: jax.Array, cfg,
 
 
 def attend_in_place(cfg, x, layer, ck, cv, l, slot0, rows, pos, sin, cos,
-                    experts=None):
+                    bound, experts=None):
     """One attention block of a decode step, in place on the slot cache:
     ``x`` [B, 1, d] are the cache's rows ``rows`` = ``slot0 .. slot0 + B``,
     each at its own position ``pos`` [B]; layer ``l`` of ``ck``/``cv``
     takes their new K and V with one indexed update and is read where it
-    lies. Returns (hidden, ck, cv, stats)."""
+    lies, below ``bound`` (the step's ``kv_read_bound``: every position a
+    row attends to lies below it): one branch for each value the bound
+    takes, each the slice-and-``mha`` of the whole row cut at its own static
+    length. A position a branch leaves unread is one the causal mask zeroes:
+    its score adds exactly 0 to the float32 softmax. Returns (hidden, ck,
+    cv, stats)."""
     b = x.shape[0]
     _, _, max_len, hkv, hd = ck.shape
-
-    def layer_rows(cache):  # [B, max_len, hkv, hd], where they lie
-        return jax.lax.dynamic_slice(cache, (l, slot0, 0, 0, 0),
-                                     (1, b, max_len, hkv, hd))[0]
+    lengths = kv_read_bounds(max_len)
+    # with branches to choose between, what they read and give is held to
+    # the layout it arrives in: left free, the chip's compiler re-lays the
+    # whole cache round the conditional (a cache-sized copy a branch, and
+    # for a lone row one a layer at 16 x its size: PERF.md, PR 32)
+    held = _as_it_lies if len(lengths) > 1 else lambda x: x
 
     with jax.named_scope("attn"):
         q, k, v = _qkv(cfg, x, layer, sin, cos, pos[:, None])
-        ck = ck.at[l, rows, pos].set(k[:, 0], mode="drop")
-        cv = cv.at[l, rows, pos].set(v[:, 0], mode="drop")
-        attn = mha(q, layer_rows(ck), layer_rows(cv), causal=True,
-                   q_offset=pos, scale=cfg.attn_scale)
+        ck = held(ck.at[l, rows, pos].set(k[:, 0], mode="drop"))
+        cv = held(cv.at[l, rows, pos].set(v[:, 0], mode="drop"))
+
+        def reading(n):
+            def layer_rows(cache):  # [B, n, hkv, hd], where they lie
+                return held(jax.lax.dynamic_slice(
+                    cache, (l, slot0, 0, 0, 0), (1, b, n, hkv, hd))[0])
+
+            return lambda: mha(q, layer_rows(ck), layer_rows(cv),
+                               causal=True, q_offset=pos,
+                               scale=cfg.attn_scale)
+
+        attn = jax.lax.switch((bound - 1) // KV_CHUNK,
+                              [reading(n) for n in lengths])
+    # (the output projection too: after a conditional the lone row's
+    # program transposed the whole ``wo`` stack, every launch)
+    layer = {**layer, "wo": held(layer["wo"])}
     x, stats = _after_attention(cfg, x, attn, layer, experts, l)
     return x, ck, cv, stats
+
+
+def _as_it_lies(x: jax.Array) -> jax.Array:
+    """``x`` held to the row-major layout a program's arguments have."""
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
 
 
 def decode_step_on_slots(params: Params, tok: jax.Array, cfg, cache: Dict,

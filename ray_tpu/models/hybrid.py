@@ -344,7 +344,8 @@ def decode_step_in_place(params: Params, tok: jax.Array, cfg: HybridConfig,
     [B, V] float32, the tree).
 
     The whole tree rides the layer loops' carry. An attention layer is
-    ``generate.attend_in_place``. A mamba layer steps its rows' state where
+    ``generate.attend_in_place``, reading below the step's
+    ``generate.kv_read_bound``. A mamba layer steps its rows' state where
     it lies (``ops/pallas/ssm_update.py``: read once, written once) and its
     tail at ``(layer, slot0)``: with the tree donated by the caller nothing
     state-sized is copied, and rows outside the launch keep theirs bit for
@@ -353,6 +354,8 @@ def decode_step_in_place(params: Params, tok: jax.Array, cfg: HybridConfig,
     layers = params["layers"]
     sin, cos = G._rope_table(cfg, cache["k"].shape[2])
     rows = slot0 + jnp.arange(b)
+    # once a step, for the four attention layers
+    bound = G.kv_read_bound(pos, cache["k"].shape[2])
 
     def rows_of(buf, i):  # [B, ...] of layer i, where they lie
         return jax.lax.dynamic_slice(
@@ -378,7 +381,7 @@ def decode_step_in_place(params: Params, tok: jax.Array, cfg: HybridConfig,
     def attention(x, bufs, i):
         x, ck, cv, _ = G.attend_in_place(
             cfg, x, _layer_of(layers["attention"], i), *bufs, i, slot0, rows,
-            pos, sin, cos)
+            pos, sin, cos, bound)
         return x, (ck, cv)
 
     x, cache = _walk(cfg, G.embed(params, cfg, tok)[:, None, :], cache,

@@ -24,13 +24,18 @@ paged dynamic memory:
   together, in place: one batched forward over the bucket's rows with
   per-row positions (rope, causal mask), each layer writing its rows'
   new K/V at ``(layer, slot, pos)`` with one indexed update and reading
-  the layer's rows where they lie (``generate.decode_step_in_place``).
+  the layer's rows where they lie (``generate.decode_step_in_place``),
+  each row below the step's ``generate.kv_read_bound`` only: the
+  positions the launch's furthest row has reached, rounded up to a
+  chunk, not the ``max_len`` a slot was allocated. The batcher counts
+  what that read (``take_kv_positions``: rows x bound against the
+  active rows' live positions) from the positions it staged.
   Two buckets: the full engine, whose rows are the slots in slot order
-  (a free slot computes a row nobody reads, into a row the next prefill
-  overwrites whole), and a lone straggler, which pays one row addressed
-  by a dynamic slice. A ``lax.scan`` fuses K decode steps per launch
-  (dispatch overhead amortized K-fold — the decode-side
-  ``make_multi_step``). Stale KV in freed slots is never observed: the
+  (a free slot computes a row nobody reads at position 0, where it holds
+  no bound up, into a row the next prefill overwrites whole), and a lone
+  straggler, which pays one row addressed by a dynamic slice. A
+  ``lax.scan`` fuses K decode steps per launch (dispatch overhead
+  amortized K-fold — the decode-side ``make_multi_step``). Stale KV in freed slots is never observed: the
   next admission prefills the slot from position 0.
 - Greedy decoding — each request's output is EXACTLY
   ``generate.generate(...)`` on its own prompt, regardless of what else
@@ -389,6 +394,9 @@ class ContinuousBatcher:
         # ``take_scan_chunks`` (layers x chunks of the prompt); 0 without
         # recurrent layers
         self._scan_chunks = 0
+        # positions the decode launches since ``take_kv_positions`` had
+        # attention read, and positions their active rows had live
+        self._kv_positions = [0, 0]
 
     # the attention layers' buffers by name (tests and ``_capture``)
     _ck = property(lambda self: self._cache["k"])
@@ -462,6 +470,7 @@ class ContinuousBatcher:
             self._cache = self._zero_cache()
             self._active.clear()
             self._free = list(range(self.max_slots))
+            self._pos[:] = 0
             if not isinstance(e, Exception):
                 raise  # an interrupt stays one
             raise SlotCacheLost(
@@ -486,6 +495,22 @@ class ContinuousBatcher:
         a tick."""
         out, self._scan_chunks = self._scan_chunks, 0
         return out
+
+    def take_kv_positions(self) -> Tuple[int, int]:
+        """(read, live) over the decode launches since the last call: rows
+        x ``generate.kv_read_bound`` of each fused step, reckoned from the
+        positions the launch was staged with (what the program computes
+        from them; no device read), and the active rows' ``pos + 1`` over
+        the steps whose tokens they took. (0, 0) where none launched."""
+        out, self._kv_positions = self._kv_positions, [0, 0]
+        return out[0], out[1]
+
+    def _release(self, slot: int) -> None:
+        """Free ``slot``: its position goes back to 0, so that the row of a
+        request that has left holds no launch's ``kv_read_bound`` up
+        (nothing reads a free row; its next admission prefills from 0)."""
+        self._pos[slot] = 0
+        self._free.append(slot)
 
     # -- admission --------------------------------------------------------
 
@@ -587,7 +612,7 @@ class ContinuousBatcher:
             done = req.remaining <= 0
             if done:
                 self._capture(slot, req)
-                self._free.append(slot)
+                self._release(slot)
             else:
                 self._active[slot] = req
         self.last_admission = {"cached_tokens": cached, "prompt_tokens": s,
@@ -645,8 +670,9 @@ class ContinuousBatcher:
         - Bucketed active-slot stepping: a lone straggler on an 8-slot
           engine pays one row, not eight (buckets: {1, max_slots}). The
           full bucket's rows are the slots themselves, in slot order: a
-          free slot's row computes from whatever ``_cur``/``_pos`` still
-          hold, writes into its own free row, and nobody reads its token.
+          free slot's row computes from whatever ``_cur`` still holds at
+          position 0 (``_release``), writes into its own free row, and
+          nobody reads its token.
         - K-step fusion: a ``lax.scan`` decodes ``k`` tokens per launch,
           so dispatch overhead is paid once per K tokens instead of per
           token. A request finishing mid-tick just has its surplus
@@ -683,6 +709,10 @@ class ContinuousBatcher:
             if self.sampling:
                 self._keys[rows] = np.asarray(new_keys[0])
         with _span("decode_book", parts):
+            # from the positions the launch was staged with, still held
+            self._kv_positions[0] += bucket * sum(
+                int(G.kv_read_bound(self._pos[rows] + j, self.max_len, np))
+                for j in range(k))
             out = []
             for slot in slots:
                 req = self._active[slot]
@@ -691,12 +721,15 @@ class ContinuousBatcher:
                 req.tokens.extend(mine)
                 req.remaining -= take
                 self._cur[slot] = mine[-1]
+                # positions 0 .. pos + j, for each of the steps it took
+                self._kv_positions[1] += (take * (int(self._pos[slot]) + 1)
+                                          + take * (take - 1) // 2)
                 self._pos[slot] += take
                 done = req.remaining <= 0
                 if done:
                     self._capture(slot, req)
                     del self._active[slot]
-                    self._free.append(slot)
+                    self._release(slot)
                 out.append((req.req_id, mine, done))
             # freeing the staged device inputs is host time between two
             # launches too: done here, it is booked; left to the frame's
@@ -774,7 +807,7 @@ class ContinuousBatcher:
             if req.req_id == req_id:
                 self._capture(slot, req)
                 del self._active[slot]
-                self._free.append(slot)
+                self._release(slot)
                 return True
         return False
 
@@ -1296,7 +1329,9 @@ class ContinuousEngine:
                                        phases=ph,
                                        moe=self._batcher.take_moe_stats(),
                                        scan_chunks=self._batcher
-                                       .take_scan_chunks(), **fields)
+                                       .take_scan_chunks(),
+                                       kv_positions=self._batcher
+                                       .take_kv_positions(), **fields)
             if tick is not None and self._on_tick is not None:
                 try:
                     self._on_tick(tick, self.max_slots)
@@ -1620,5 +1655,5 @@ def _decode_executable(cfg, bucket: int, max_slots: int, max_len: int,
         jax.tree.unflatten(tree, leaves),
         *(cache[name] for name in G.cache_names(cfg)), *args).compile()
     return fn, dict(bucket=bucket, k=k, **hlo_copies.cache_traffic(
-        fn, cache, rows=bucket, steps=k))
+        fn, cache, rows=bucket, steps=k, bounds=G.kv_read_bounds(max_len)))
 

@@ -945,6 +945,11 @@ def cmd_engine(args: argparse.Namespace) -> int:
                       f"{layout['kv_bytes_per_position']} K/V bytes a "
                       f"position; prefill scan chunks "
                       f"{summ.get('ssm_scan_chunks', 0)}")
+            if summ.get("kv_positions_read"):
+                print(f"  decode attention read "
+                      f"{summ['kv_positions_read']} positions for "
+                      f"{summ['kv_positions_live']} live "
+                      f"(kv_read_ratio {summ['kv_read_ratio']:.2f})")
             if summ.get("moe_assignments"):
                 print(f"  moe: {summ['moe_assignments']} assignments, "
                       f"{summ['moe_rows_computed']} rows computed, experts "
@@ -1536,7 +1541,9 @@ def main(argv=None) -> int:
                         "cache (cache_donated, cache_copy_bytes_per_step) "
                         "and, for a hybrid model (models/hybrid.py), its "
                         "recurrent state (state_layout, state_donated, "
-                        "state_copy_bytes_per_step, ssm_scan_chunks)"),
+                        "state_copy_bytes_per_step, ssm_scan_chunks); "
+                        "positions decode attention read over positions "
+                        "live (kv_read_ratio)"),
                        ("ticks", "tail the per-tick phase records"),
                        ("requests", "tail the request lifecycle records")):
         pe = eng_sub.add_parser(name, help=what)

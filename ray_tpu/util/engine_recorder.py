@@ -196,7 +196,8 @@ class EngineRecorder(RecorderCore):
                     gap_s: Optional[float],
                     decode_parts: Optional[Dict[str, float]] = None,
                     moe: Optional[Dict[str, List[int]]] = None,
-                    scan_chunks: int = 0) -> None:
+                    scan_chunks: int = 0,
+                    kv_positions: Tuple[int, int] = (0, 0)) -> None:
         """One engine tick: phase partition + the decode tick-gap. The
         ONLY thing this does is append to a bounded deque — no metrics,
         no I/O (drained off-thread). ``decode_parts`` is ``decode_step``'s
@@ -205,7 +206,10 @@ class EngineRecorder(RecorderCore):
         launches of this tick said of their routing (``MOE_COUNTERS``
         values under "prefill" and "decode"); a dense model's is empty.
         ``scan_chunks``: chunks the recurrent layers' scans ran over in
-        this tick's prefills (0 without such layers)."""
+        this tick's prefills (0 without such layers). ``kv_positions``:
+        (positions the tick's decode launch had attention read, positions
+        its active rows had live): ``ContinuousBatcher.take_kv_positions``;
+        (0, 0) for a tick that launched no decode."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
@@ -223,6 +227,8 @@ class EngineRecorder(RecorderCore):
             rec["moe"] = moe
         if scan_chunks:
             rec["scan_chunks"] = scan_chunks
+        if kv_positions[0]:
+            rec["kv_positions_read"], rec["kv_positions_live"] = kv_positions
         with self._lock:
             self._tick_seq += 1
             rec["seq"] = self._tick_seq
@@ -465,6 +471,7 @@ class EngineRecorder(RecorderCore):
             "decode_programs": [dict(p) for p in self.decode_programs],
         }
         out.update(_moe_totals(ticks))
+        out.update(_kv_read_totals(ticks))
         if self.state_layout is not None:
             out["state_layout"] = dict(self.state_layout)
             out["ssm_scan_chunks"] = sum(t.get("scan_chunks", 0)
@@ -704,6 +711,19 @@ def _moe_totals(ticks: List[Dict[str, Any]]) -> Dict[str, Any]:
     if out:
         out["moe_decode"] = fold(("decode",))
     return out
+
+
+def _kv_read_totals(ticks: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """Over the ticks' decode launches: positions attention read (rows x
+    the step's bound), positions the active rows had live, and the first
+    over the second (1.0 would be a read of the live positions alone).
+    Nothing where no tick carries the counter."""
+    read = sum(t.get("kv_positions_read", 0) for t in ticks)
+    live = sum(t.get("kv_positions_live", 0) for t in ticks)
+    if not read:
+        return {}
+    return {"kv_positions_read": read, "kv_positions_live": live,
+            "kv_read_ratio": round(read / live, 4) if live else 0.0}
 
 
 def _excess(ticks: List[Dict[str, Any]], wall) -> float:

@@ -19,8 +19,9 @@ the product each completes, ``collective_inventory`` sums them by kind.
 
 from __future__ import annotations
 
+import functools
 import re
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -149,6 +150,37 @@ class _Module:
                     todo += [(c.strip().lstrip("%"), times)
                              for c in group.split(",")]
 
+    def fold(self, value, pick=max, fusions: bool = False) -> int:
+        """``value(instruction, its computation's instructions)`` summed
+        over one execution of the module: a loop's body times its trips,
+        and of a conditional's branches, which are alternatives, the one
+        ``pick`` takes (``max``: an upper limit whatever the predicate)."""
+        memo: Dict[str, int] = {}
+
+        def total(name: str) -> int:
+            if name not in memo:
+                memo[name] = 0
+                insts = self.computations.get(name, [])
+                memo[name] = sum(value(i, insts) + called(i) for i in insts)
+            return memo[name]
+
+        def called(inst) -> int:
+            opcode, line = inst[2], inst[4]
+            if opcode == "fusion" and not fusions:
+                return 0
+            trips = self.trips(line) if opcode == "while" else 1
+            out, branches = 0, []
+            for kind, callee in _CALLEE.findall(line):
+                if kind.endswith("_computation"):  # a two-way conditional's
+                    branches.append(callee)
+                else:
+                    out += total(callee) * (trips if kind == "body" else 1)
+            for group in _BRANCHES.findall(line):
+                branches += [c.strip().lstrip("%") for c in group.split(",")]
+            return out + (pick(map(total, branches)) if branches else 0)
+
+        return total(self.entry)
+
 
 def _arrays(shape: str) -> List[Tuple[str, Tuple[int, ...]]]:
     return [(dt, tuple(int(d) for d in dims.split(",") if d))
@@ -172,22 +204,31 @@ def _fused_writers(module: _Module, inst: Tuple) -> Tuple[List[Tuple],
             and _arrays(i[1])[0] in _arrays(inst[1])], fused
 
 
-def cache_traffic(compiled: Any, cache: Any, *, rows: int,
-                  steps: int) -> Dict[str, Any]:
+def cache_traffic(compiled: Any, cache: Any, *, rows: int, steps: int,
+                  bounds: Sequence[int] = ()) -> Dict[str, Any]:
     """For a compiled decode program over a slot cache, stepping ``rows``
     rows ``steps`` times a launch. ``cache`` is the slot tree
     (``generate.init_cache``'s buffers by name, arrays or their shapes:
     ``k`` and ``v`` [L, slots, max_len, hkv, hd], and with recurrent layers
     ``ssm`` [L, slots, h, p, n] and ``conv``), or one array shaped like K
-    and like V.
+    and like V. ``bounds``: the lengths below ``max_len`` a step's read may
+    stop at (``generate.kv_read_bounds``), each a branch of a conditional.
 
     - ``cache_donated``: the program's input-output aliases cover the
       whole tree;
     - ``cache_copy_bytes_per_step``: bytes, per decode step, of results and
-      in-place updates that are K/V-shaped (the cache's type, ``max_len``
-      and ``head_dim`` among the dimensions) and at least one layer's
-      ``rows`` large, times the trip counts of the loops round them;
-    - ``cache_bytes``: K and V together, to read the other against.
+      in-place updates that are K/V-shaped (the cache's type, ``head_dim``
+      and one of the lengths, ``max_len`` or a bound, among the dimensions)
+      and at least one layer's ``rows`` large at that length, times the
+      trip counts of the loops round them, and with the read at its full
+      length (of a conditional's branches the costliest counts): an upper
+      limit whatever the rows' positions;
+    - ``cache_read_bytes_per_step``: bytes, per step, that slices so shaped
+      read out of the cache, alone or inside a fusion, at the full length
+      again; ``cache_read_bytes_per_step_least``: the same with every
+      conditional taking its cheapest branch, what a step reads while its
+      rows are short (equal to the other where the read has no bound);
+    - ``cache_bytes``: K and V together, to read the others against.
 
     With recurrent layers also ``state_donated`` (the same aliases),
     ``state_bytes`` (the ``ssm`` buffer) and ``state_copy_bytes_per_step``:
@@ -205,14 +246,16 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int,
     _, _, max_len, hkv, hd = tree["k"].shape
     itemsize = np.dtype(tree["k"].dtype).itemsize
     dtype = _HLO_TYPE[str(tree["k"].dtype)]
-    floor = rows * max_len * hkv * hd * itemsize
+    lengths = sorted({max_len, *bounds})
 
+    @functools.lru_cache(maxsize=None)  # three passes ask the same shapes
     def counted(shape: str) -> int:
         total = 0
         for dt, dims in _arrays(shape):
             nbytes = int(np.prod(dims, dtype=np.int64)) * itemsize
-            if (dt == dtype and nbytes >= floor and max_len in dims
-                    and hd in dims):
+            if dt == dtype and hd in dims and any(
+                    n in dims and nbytes >= rows * n * hkv * hd * itemsize
+                    for n in lengths):
                 total += nbytes
         return total
 
@@ -226,18 +269,21 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int,
         if opcode in _IN_PLACE:
             update = operands[_IN_PLACE[opcode]]
             return sum(counted(i[1]) for i in within if i[0] == update)
-        return counted(shape)
-
-    total = 0
-    for times, inst, peers in module.walk():
-        if inst[2] == "fusion":
+        if opcode == "fusion":
             writers, fused = _fused_writers(module, inst)
             if writers:  # the fusion's result is its operand, updated
-                total += times * sum(moved(i, fused) for i in writers)
-                continue
-        total += times * moved(inst, peers)
+                return sum(moved(i, fused) for i in writers)
+        return counted(shape)
+
+    def sliced(inst, within) -> int:
+        return counted(inst[1]) if inst[2] in ("dynamic-slice", "slice") else 0
+
     out = {"cache_donated": donated,
-           "cache_copy_bytes_per_step": int(total // steps),
+           "cache_copy_bytes_per_step": module.fold(moved) // steps,
+           "cache_read_bytes_per_step": module.fold(
+               sliced, fusions=True) // steps,
+           "cache_read_bytes_per_step_least": module.fold(
+               sliced, min, fusions=True) // steps,
            "cache_bytes": 2 * int(np.prod(tree["k"].shape, dtype=np.int64))
            * itemsize}
     if "ssm" in tree:

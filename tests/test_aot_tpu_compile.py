@@ -138,6 +138,13 @@ def _cache_bytes(cache):
     return 2 * cache.size * cache.dtype.itemsize  # K and V
 
 
+def _traffic(compiled, cache, bucket):
+    """What eight fused steps of ``bucket`` rows do to a cache of 2048
+    positions, the branches of the bounded read known to the counter."""
+    return hlo_copies.cache_traffic(compiled, cache, rows=bucket, steps=8,
+                                    bounds=generate.kv_read_bounds(2048))
+
+
 def test_engine_prefill_compiles_for_v5e_at_1b(topo):
     """The prefill takes the slot cache donated: one row is written, the
     cache is neither copied nor held twice."""
@@ -187,7 +194,7 @@ def test_engine_decode_steps_the_cache_in_place_on_v5e(topo, widths, slots,
             + mem.output_size_in_bytes
             - mem.alias_size_in_bytes) < 16 * 2 ** 30
     assert mem.alias_size_in_bytes >= _cache_bytes(cache)
-    traffic = hlo_copies.cache_traffic(compiled, cache, rows=bucket, steps=8)
+    traffic = _traffic(compiled, cache, bucket)
     assert traffic["cache_donated"]
     if widths == "7b":
         assert mem.temp_size_in_bytes < _cache_bytes(cache)
@@ -236,8 +243,7 @@ def test_olmoe_engine_programs_compile_for_v5e(topo, program):
         compiled = serving._compiled_bucket_scan(
             CFG_OLMOE, bucket, 16, 2048, 8).lower(
             params, cache, cache, i32(bucket), i32(bucket), i32()).compile()
-        traffic = hlo_copies.cache_traffic(compiled, cache, rows=bucket,
-                                           steps=8)
+        traffic = _traffic(compiled, cache, bucket)
         assert traffic["cache_donated"]
         assert traffic["cache_copy_bytes_per_step"] \
             <= 1.01 * traffic["cache_bytes"] * bucket // 16, traffic
@@ -311,7 +317,7 @@ def test_hybrid_decode_steps_the_whole_slot_tree_in_place_on_v5e(topo, bucket):
         params, *buffers, i32(bucket), i32(bucket), i32()).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _tree_bytes(tree)
-    traffic = hlo_copies.cache_traffic(compiled, tree, rows=bucket, steps=8)
+    traffic = _traffic(compiled, tree, bucket)
     assert traffic["cache_donated"] and traffic["state_donated"]
     rows_state = traffic["state_bytes"] * bucket // 64
     assert traffic["state_bytes"] == 18 * 64 * 64 * 64 * 128 * 4
@@ -342,6 +348,64 @@ def test_hybrid_prefill_compiles_for_v5e(topo, length):
     assert mem.alias_size_in_bytes >= _tree_bytes(tree)
     assert mem.temp_size_in_bytes < 2 ** 30
     _no_weight_stack_is_copied(compiled)
+
+
+@pytest.mark.parametrize("family,bucket", [
+    ("dense", 16), ("dense", 1), ("olmoe", 16), ("olmoe", 1),
+    ("hybrid", 64), ("hybrid", 1)])
+def test_decode_reads_below_the_bound_on_v5e(topo, family, bucket):
+    """The bounded read as the chip's compiler leaves it, for the three
+    families' decode programs: one conditional a layer whose branches slice
+    256, 512 ... 2048 positions of the launch's rows out of the carried
+    cache. At the smallest bound a step reads an eighth of what it read
+    when every allocated position was read, at the largest exactly that
+    (the rows' share of the cache, once); the cache is still donated and
+    nothing larger than one layer's rows is materialised (at head 64 the
+    rows are re-tiled round the products, as before: 1.25 x at the full
+    bound where the whole-row program had 2.25); the state moves 2.0 x its
+    rows; no weight stack is copied that the whole-row program did not copy
+    (the lone row's ``wo`` would be, were it not held: PERF.md, PR 32)."""
+    if family == "hybrid":
+        cfg, slots = CFG_GRANITE, 64
+        params, cache, buffers, i32 = _hybrid_args(topo, slots)
+    else:
+        cfg, slots = (CFG_7B_WIDE, 16) if family == "dense" else (CFG_OLMOE, 16)
+        params, cache, i32 = _engine_args(
+            topo, slots, 2048, cfg,
+            llama.init_params if family == "dense" else moe.init_params)
+        buffers = [cache, cache]
+    compiled = serving._compiled_bucket_scan(
+        cfg, bucket, slots, 2048, 8).lower(
+        params, *buffers, i32(bucket), i32(bucket), i32()).compile()
+    traffic = _traffic(compiled, cache, bucket)
+    share = traffic["cache_bytes"] * bucket // slots
+    assert traffic["cache_donated"]
+    assert traffic["cache_read_bytes_per_step"] == share, traffic
+    assert traffic["cache_read_bytes_per_step_least"] \
+        == share * generate.KV_CHUNK // 2048, traffic
+    text = compiled.as_text()
+    assert text.count(" conditional(") == 1  # one attention block, in loops
+    shape = cache["k"].shape if family == "hybrid" else cache.shape
+    whole = ",".join(map(str, shape))
+    for line in text.splitlines():  # no copy of the cache round a branch
+        if " copy(" in line and f"bf16[{whole}]" in line:
+            # (at head 64 both buffers are converted on a launch's entry
+            # and back on its exit, as before: outside every loop)
+            assert family == "hybrid" and "while/body" not in line, line[:300]
+    if family == "dense":
+        assert traffic["cache_copy_bytes_per_step"] <= share, traffic
+    elif family == "olmoe":  # MHA: the slices fuse into their products
+        assert traffic["cache_copy_bytes_per_step"] == 0, traffic
+    else:
+        assert traffic["cache_copy_bytes_per_step"] <= (
+            1.3 if bucket == 64 else 0.3) * traffic["cache_bytes"], traffic
+        assert traffic["state_copy_bytes_per_step"] \
+            == 2 * traffic["state_bytes"] * bucket // slots, traffic
+        _no_weight_stack_is_copied(compiled)
+    if bucket == 1:  # the lone row's program copies no weight stack at all
+        assert not [line for line in text.splitlines() if " copy(" in line
+                    and "%params__layers__" in line
+                    and "router" not in line], "a weight stack is copied"
 
 
 def test_sharded_flash_step_compiles_for_four_chips(topo):

@@ -307,7 +307,10 @@ CACHE = jax.ShapeDtypeStruct(
 # by this function in a checkout of that commit: with ``qk_norm`` off and
 # ``norm_topk_prob`` on, the defaults, nothing these programs compute or
 # the order they compute it in has changed. A later PR that changes one of
-# them on purpose takes its digest anew.
+# them on purpose takes its digest anew (PR 32: the in-place decode step
+# reckons ``generate.kv_read_bound`` from ``pos`` before its layer loop; at
+# this ``max_len`` the bound takes one value and the step lowers as before,
+# ``tests/test_hybrid_serving.py``).
 PROGRAMS = {
     "dense_forward": ("1279e2a80f1d1bb7", lambda: (
         lambda p, t: llama.forward(p, t, DENSE),
@@ -321,7 +324,7 @@ PROGRAMS = {
     "mixtral_lm_loss": ("84ac7b8aae592ed7", lambda: (
         lambda p, t: moe.lm_loss(p, {"tokens": t}, SPARSE),
         _shapes(moe.init_params, SPARSE), TOKENS)),
-    "dense_decode_in_place": ("e769efe2b3bd61a8", lambda: (
+    "dense_decode_in_place": ("083088822a7edc17", lambda: (
         lambda p, t, ck, cv, pos: G.decode_step_in_place(
             p, t, DENSE, ck, cv, 0, pos),
         _shapes(llama.init_params, DENSE), ROWS, CACHE, CACHE, ROWS)),

@@ -81,6 +81,43 @@ def test_decode_program_steps_the_cache_in_place(sampling, bucket, k):
         <= stats["cache_bytes"] * bucket // SLOTS, stats
 
 
+@pytest.mark.parametrize("bucket", [1, SLOTS])
+def test_the_counter_sees_a_bounded_read(bucket, monkeypatch):
+    """With the read bounded (chunk 16 of ``max_len`` 64: four branches) the
+    counter still has something to hold to the limit above: it knows the
+    branches' shapes, takes the costliest branch for the step's upper
+    limit, and says what the slices read at the full bound (the rows'
+    share of the cache, as before) and at the least (a chunk of each row).
+    On this backend a branch materialises its slice and the slice
+    transposed (it is held to the cache's own layout, for the chip's
+    compiler: ``generate.attend_in_place``): twice the rows, no more."""
+    monkeypatch.setattr(G, "KV_CHUNK", 16)
+    serving._compiled_bucket_scan.cache_clear()
+    serving._decode_executable.cache_clear()
+    cfg = dataclasses.replace(llama.PRESETS["debug"],
+                              compute_dtype=jnp.float32)
+    b = ContinuousBatcher(llama.init_params(jax.random.key(0), cfg), cfg,
+                          max_slots=SLOTS, max_len=MAX_LEN)
+    try:
+        b._program(bucket, 8)
+    finally:
+        serving._compiled_bucket_scan.cache_clear()
+        serving._decode_executable.cache_clear()
+    (stats,) = b.program_stats
+    share = stats["cache_bytes"] * bucket // SLOTS
+    assert stats["cache_donated"] is True
+    assert stats["cache_read_bytes_per_step"] == share
+    assert stats["cache_read_bytes_per_step_least"] == share * 16 // MAX_LEN
+    assert 0 < stats["cache_copy_bytes_per_step"] <= 2 * share, stats
+    # the whole-row program, for the same rows: one length, one number
+    monkeypatch.setattr(G, "KV_CHUNK", MAX_LEN)
+    whole = ContinuousBatcher(b.params, cfg, max_slots=SLOTS, max_len=MAX_LEN)
+    whole._program(bucket, 8)
+    (stats,) = whole.program_stats
+    assert stats["cache_read_bytes_per_step"] \
+        == stats["cache_read_bytes_per_step_least"] == share
+
+
 def test_the_counter_sees_a_copy_that_comes_back():
     """The reader itself: the same step with the cache as the layer
     scan's ``xs``/``ys`` (sliced out and stacked back every layer, as the
