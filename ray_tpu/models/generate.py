@@ -31,11 +31,13 @@ Works for both model families: llama densely, MoE through
 ``moe.served_ffn_half`` (drop-free routing whose work follows the routed
 tokens), whose per-layer routing counters the ``_stats`` forms return.
 
-A model with recurrent layers (``models/hybrid.py``) keeps, beside the keys
-and values of its attention layers, a state and a convolution tail for each
-recurrent layer: the cache is a tree of buffers by layer kind
-(``init_cache``), and ``_forward_with_cache_stats`` and ``decode_step_on_slots``
-hand such a model to that module, which builds its attention layers from
+A model with recurrent layers (``models/hybrid.py``, ``models/sambay.py``)
+keeps, beside the keys and values of its attention layers, a state and a
+convolution tail for each recurrent layer, and one with window layers a ring
+of the last ``sliding_window`` keys and values for each of those: the cache
+is a tree of buffers by layer kind (``init_cache``), and
+``_forward_with_cache_stats`` and ``decode_step_on_slots`` hand such a model
+to its module (``hybrid.model_of``), which builds its attention layers from
 the pieces here.
 """
 
@@ -60,17 +62,29 @@ Params = Dict[str, Any]
 def init_cache(cfg: llama.LlamaConfig, batch: int, max_len: int) -> Dict:
     """The zeroed cache of ``batch`` rows, a tree of buffers by layer kind:
     ``k`` and ``v`` [L_attention, B, max_len, kv_heads, head_dim] (compute
-    dtype) for the layers that attend, and for a model with recurrent
-    layers their ``ssm`` state and ``conv`` tail as well
-    (``hybrid.init_state``): ``cache_names``'s buffers."""
-    shape = (cfg.n_attention_layers, batch, max_len, cfg.n_kv_heads,
-             cfg.head_dim)
+    dtype; a row's three sizes are the config's ``kv_shape(max_len)``) for
+    the layers that keep every position, ``wk`` and ``wv`` [L_window, B,
+    ``kv_shape(sliding_window)``] for window layers' rings, and for a model with
+    recurrent layers their ``ssm`` state and ``conv`` tail as well (its
+    module's ``init_state``): ``cache_names``'s buffers."""
+    shape = (cfg.n_attention_layers, batch, *cfg.kv_shape(max_len))
     cache = {"k": jnp.zeros(shape, cfg.compute_dtype),
              "v": jnp.zeros(shape, cfg.compute_dtype)}
+    if cfg.n_window_layers:
+        if max_len < cfg.sliding_window:
+            raise ValueError(
+                f"max_len {max_len} under the sliding window "
+                f"{cfg.sliding_window}: a window layer's ring is the window "
+                f"long whatever max_len is, and a cache shorter than it is "
+                f"no deployment of this model")
+        ring = (cfg.n_window_layers, batch,
+                *cfg.kv_shape(cfg.sliding_window))
+        cache.update(wk=jnp.zeros(ring, cfg.compute_dtype),
+                     wv=jnp.zeros(ring, cfg.compute_dtype))
     if cfg.n_recurrent_layers:
         from ray_tpu.models import hybrid
 
-        cache.update(hybrid.init_state(cfg, batch))
+        cache.update(hybrid.model_of(cfg).init_state(cfg, batch))
     return cache
 
 
@@ -78,7 +92,8 @@ def cache_names(cfg) -> Tuple[str, ...]:
     """``init_cache``'s buffers in the order every engine program takes and
     returns them (a dict that went through ``jax.tree`` comes back sorted:
     nothing may go by a dict's own order)."""
-    return ("k", "v") + (("ssm", "conv") if cfg.n_recurrent_layers else ())
+    return (("k", "v") + (("wk", "wv") if cfg.n_window_layers else ())
+            + (("ssm", "conv") if cfg.n_recurrent_layers else ()))
 
 
 def embed(params: Params, cfg, tokens: jax.Array) -> jax.Array:
@@ -185,10 +200,14 @@ def _block_with_cache(cfg, x, layer, cache_k, cache_v, sin, cos, pos,
 def _head(params: Params, cfg, x):
     """Final norm and the vocabulary projection, float32 logits."""
     cdt = cfg.compute_dtype
-    x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
+    wide = x.dtype != cdt  # a residual stream kept in float32 (sambay.py)
+    x = llama.pre_norm(cfg, x, params, "final_norm")
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"]).astype(cdt)
-    logits = (x @ head).astype(jnp.float32)
+    if wide:  # the logits as they were summed, not rounded on the way
+        logits = jnp.matmul(x, head, preferred_element_type=jnp.float32)
+    else:
+        logits = (x @ head).astype(jnp.float32)
     if cfg.logits_scaling != 1.0:
         logits = logits / cfg.logits_scaling
     return logits
@@ -214,8 +233,8 @@ def _forward_with_cache_stats(params: Params, tokens: jax.Array,
     if cfg.n_recurrent_layers:
         from ray_tpu.models import hybrid
 
-        return (*hybrid.forward_with_cache(params, tokens, cfg, cache, pos,
-                                           last_only), None)
+        return (*hybrid.model_of(cfg).forward_with_cache(
+            params, tokens, cfg, cache, pos, last_only), None)
     x = embed(params, cfg, tokens)
     max_len = cache["k"].shape[2]
     sin, cos = _rope_table(cfg, max_len)
@@ -324,36 +343,56 @@ def attend_in_place(cfg, x, layer, ck, cv, l, slot0, rows, pos, sin, cos,
     length. A position a branch leaves unread is one the causal mask zeroes:
     its score adds exactly 0 to the float32 softmax. Returns (hidden, ck,
     cv, stats)."""
-    b = x.shape[0]
-    _, _, max_len, hkv, hd = ck.shape
-    lengths = kv_read_bounds(max_len)
     # with branches to choose between, what they read and give is held to
     # the layout it arrives in: left free, the chip's compiler re-lays the
     # whole cache round the conditional (a cache-sized copy a branch, and
     # for a lone row one a layer at 16 x its size: PERF.md, PR 32)
-    held = _as_it_lies if len(lengths) > 1 else lambda x: x
+    held = held_as_it_lies(ck.shape[2])
 
     with jax.named_scope("attn"):
         q, k, v = _qkv(cfg, x, layer, sin, cos, pos[:, None])
         ck = held(ck.at[l, rows, pos].set(k[:, 0], mode="drop"))
         cv = held(cv.at[l, rows, pos].set(v[:, 0], mode="drop"))
-
-        def reading(n):
-            def layer_rows(cache):  # [B, n, hkv, hd], where they lie
-                return held(jax.lax.dynamic_slice(
-                    cache, (l, slot0, 0, 0, 0), (1, b, n, hkv, hd))[0])
-
-            return lambda: mha(q, layer_rows(ck), layer_rows(cv),
-                               causal=True, q_offset=pos,
-                               scale=cfg.attn_scale)
-
-        attn = jax.lax.switch((bound - 1) // KV_CHUNK,
-                              [reading(n) for n in lengths])
+        attn = read_below(q, ck, cv, l, slot0, pos, bound,
+                          scale=cfg.attn_scale)
     # (the output projection too: after a conditional the lone row's
     # program transposed the whole ``wo`` stack, every launch)
     layer = {**layer, "wo": held(layer["wo"])}
     x, stats = _after_attention(cfg, x, attn, layer, experts, l)
     return x, ck, cv, stats
+
+
+def read_below(q, ck, cv, l, slot0, pos, bound, axis: int = 2, **how):
+    """``mha`` of ``q`` [B, 1, hq, hd], the rows ``slot0 .. slot0 + B`` at
+    their positions ``pos`` [B], over layer ``l`` of ``ck``/``cv`` read
+    where it lies, below ``bound`` (``attend_in_place`` says how). Writes
+    nothing: a layer that attends to another's keys and values
+    (``models/sambay.py``) is this alone. ``axis``: where positions lie in
+    ``ck`` (the config's ``kv_length_axis``); ``how``: ``mha``'s other
+    arguments."""
+    b = q.shape[0]
+    max_len = ck.shape[axis]
+    lengths = kv_read_bounds(max_len)
+    held = held_as_it_lies(max_len)
+
+    def reading(n):
+        size = (1, b) + ck.shape[2:axis] + (n,) + ck.shape[axis + 1:]
+
+        def layer_rows(cache):  # [B, n, hkv, hd], where they lie
+            return held(jax.lax.dynamic_slice(
+                cache, (l, slot0, 0, 0, 0), size)[0])
+
+        return lambda: mha(q, layer_rows(ck), layer_rows(cv), causal=True,
+                           q_offset=pos, **how)
+
+    return jax.lax.switch((bound - 1) // KV_CHUNK,
+                          [reading(n) for n in lengths])
+
+
+def held_as_it_lies(max_len: int):
+    """``_as_it_lies`` where a read of ``max_len`` positions has branches
+    to choose between, the identity where it has one."""
+    return _as_it_lies if len(kv_read_bounds(max_len)) > 1 else lambda x: x
 
 
 def _as_it_lies(x: jax.Array) -> jax.Array:
@@ -372,8 +411,8 @@ def decode_step_on_slots(params: Params, tok: jax.Array, cfg, cache: Dict,
     if cfg.n_recurrent_layers:
         from ray_tpu.models import hybrid
 
-        return (*hybrid.decode_step_in_place(params, tok, cfg, cache, slot0,
-                                             pos), None)
+        return (*hybrid.model_of(cfg).decode_step_in_place(
+            params, tok, cfg, cache, slot0, pos), None)
     logits, ck, cv, stats = decode_step_in_place_stats(
         params, tok, cfg, cache["k"], cache["v"], slot0, pos)
     return logits, {"k": ck, "v": cv}, stats

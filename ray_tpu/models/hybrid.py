@@ -27,7 +27,8 @@ The SwiGLU half is ``llama.ffn_half`` and the head ``generate._head``.
 
 Parameters are stacked BY LAYER KIND (``params["layers"]["mamba"]``,
 ``["attention"]``), and the forward walks ``layer_types`` period by period
-(``_walk``): a ``lax.scan`` over the repeats of the order's shortest
+(``_walk``, which ``models/sambay.py`` shares for an order of several
+segments): a ``lax.scan`` over the repeats of the order's shortest
 repeating pattern (Granite's ten layers: five mamba, one attention, four
 mamba), inside it each run of one kind a ``lax.scan`` over that kind's stack
 from where the kind's last run ended. The outer loop is what keeps the
@@ -47,7 +48,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Iterator, Tuple
+from typing import Any, ClassVar, Dict, List, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,13 +75,13 @@ class HybridConfig(llama.LlamaConfig):
     mamba_chunk_size: int = 256
     state_dtype: Any = jnp.float32
 
+    #: the cache tree's buffers a layer of each kind reads and writes
+    #: (``_walk`` carries them through the kind's run)
+    BUFFERS: ClassVar[Mapping[str, Tuple[str, ...]]] = {
+        "mamba": ("ssm", "conv"), "attention": ("k", "v")}
+
     def __post_init__(self):
-        if len(self.layer_types) != self.n_layers or set(
-                self.layer_types) - set(KINDS):
-            raise ValueError(
-                f"layer_types names {len(self.layer_types)} layers of kinds "
-                f"{sorted(set(self.layer_types))}; n_layers is "
-                f"{self.n_layers} and the kinds are {KINDS}")
+        check_layer_types(self, KINDS)
 
     @property
     def n_attention_layers(self) -> int:
@@ -106,19 +107,11 @@ class HybridConfig(llama.LlamaConfig):
                     if n % p == 0
                     and self.layer_types[:p] * (n // p) == self.layer_types)
 
-    def runs(self) -> Iterator[Tuple[str, int, int]]:
-        """``pattern()`` as runs of one kind: (kind, how many layers of the
-        kind the pattern has before the run, layers in the run)."""
+    def segments(self) -> List[Tuple[Tuple[str, ...], int]]:
+        """The order as ``_walk`` takes it, [(pattern, repeats)]: here the
+        one pattern the order repeats whole."""
         pattern = self.pattern()
-        done = dict.fromkeys(KINDS, 0)
-        i = 0
-        while i < len(pattern):
-            kind, j = pattern[i], i
-            while j < len(pattern) and pattern[j] == kind:
-                j += 1
-            yield kind, done[kind], j - i
-            done[kind] += j - i
-            i = j
+        return [(pattern, self.n_layers // len(pattern))]
 
     def state_bytes_per_row(self) -> int:
         """A row's recurrent state and convolution tails, all layers."""
@@ -143,6 +136,24 @@ class HybridConfig(llama.LlamaConfig):
         head = 0 if self.tie_embeddings else d * v
         return (v * d + d + head + self.n_attention_layers * (attention + mlp)
                 + self.n_recurrent_layers * (mamba + mlp))
+
+
+def check_layer_types(cfg, kinds: Tuple[str, ...]) -> None:
+    if len(cfg.layer_types) != cfg.n_layers or set(
+            cfg.layer_types) - set(kinds):
+        raise ValueError(
+            f"layer_types names {len(cfg.layer_types)} layers of kinds "
+            f"{sorted(set(cfg.layer_types))}; n_layers is "
+            f"{cfg.n_layers} and the kinds are {kinds}")
+
+
+def model_of(cfg):
+    """The module that computes ``cfg``'s layers (``init_state``,
+    ``forward_with_cache``, ``decode_step_in_place``): this one, or the one
+    a config of another module names."""
+    import importlib
+
+    return importlib.import_module(type(cfg).__module__)
 
 
 PRESETS: Dict[str, HybridConfig] = {
@@ -273,34 +284,58 @@ def _mamba_block(cfg: HybridConfig, x, layer: Params, state, tail):
     return _mamba_out(cfg, x, y, xs, z, layer), state, tail
 
 
-_BUFFERS = {"mamba": ("ssm", "conv"), "attention": ("k", "v")}
+def _runs(pattern: Tuple[str, ...]) -> List[Tuple[str, int, int]]:
+    """``pattern`` as runs of one kind: (kind, how many layers of the kind
+    the pattern has before the run, layers in the run)."""
+    out: List[Tuple[str, int, int]] = []
+    done: Dict[str, int] = {}
+    i = 0
+    while i < len(pattern):
+        kind, j = pattern[i], i
+        while j < len(pattern) and pattern[j] == kind:
+            j += 1
+        out.append((kind, done.get(kind, 0), j - i))
+        done[kind] = done.get(kind, 0) + j - i
+        i = j
+    return out
 
 
-def _walk(cfg: HybridConfig, x, cache: Dict, blocks: Dict) -> Tuple[Any, Dict]:
-    """``x`` through every layer in the model's order, the cache tree in
-    the loops' carry. ``blocks[kind](x, bufs, i) -> (x, bufs)`` runs layer
-    ``i`` of that kind's stack on the kind's two buffers (``_BUFFERS``)."""
-    pattern = cfg.pattern()
+def _walk(cfg, x, cache: Dict, blocks: Dict,
+          segments: Optional[List[Tuple[Tuple[str, ...], int]]] = None,
+          start: Optional[Dict[str, int]] = None) -> Tuple[Any, Dict]:
+    """``x`` through the layers of ``segments`` (``cfg.segments()``, the
+    whole model in its order, left None), the cache tree in the loops'
+    carry. A segment is a pattern of kinds and how often it repeats: a
+    ``lax.scan`` over the repeats, inside it each run of one kind a
+    ``lax.scan`` over that kind's stack from where the kind's last run
+    ended. ``blocks[kind](x, bufs, i) -> (x, bufs)`` runs layer ``i`` of the
+    kind's stack on the kind's buffers (``cfg.BUFFERS``). ``start``: layers
+    of each kind that lie before the first segment (none, left None)."""
+    done = dict(start or {})
+    cache = dict(cache)
+    for pattern, repeats in (cfg.segments() if segments is None
+                             else segments):
+        def one_period(carry, rep, pattern=pattern, done=dict(done)):
+            x, cache = carry
+            for kind, before, n in _runs(pattern):
+                names = cfg.BUFFERS[kind]
 
-    def one_period(carry, rep):
-        x, cache = carry
-        for kind, before, n in cfg.runs():
-            names = _BUFFERS[kind]
+                def layer(c, i, block=blocks[kind]):
+                    x, bufs = block(c[0], c[1:], i)
+                    return (x, *bufs), None
 
-            def layer(c, i, block=blocks[kind]):
-                x, bufs = block(c[0], c[1:], i)
-                return (x, *bufs), None
+                first = rep * pattern.count(kind) + (before
+                                                     + done.get(kind, 0))
+                (x, *bufs), _ = jax.lax.scan(
+                    layer, (x, *(cache[name] for name in names)),
+                    first + jnp.arange(n))
+                cache = {**cache, **dict(zip(names, bufs))}
+            return (x, cache), None
 
-            first = rep * pattern.count(kind) + before
-            (x, *bufs), _ = jax.lax.scan(
-                layer, (x, *(cache[name] for name in names)),
-                first + jnp.arange(n))
-            cache = {**cache, **dict(zip(names, bufs))}
-        return (x, cache), None
-
-    (x, cache), _ = jax.lax.scan(
-        one_period, (x, dict(cache)),
-        jnp.arange(cfg.n_layers // len(pattern)))
+        (x, cache), _ = jax.lax.scan(one_period, (x, cache),
+                                     jnp.arange(repeats))
+        for kind in set(pattern):
+            done[kind] = done.get(kind, 0) + repeats * pattern.count(kind)
     return x, cache
 
 

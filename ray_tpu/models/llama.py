@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import mha
-from ray_tpu.ops.norms import rmsnorm
+from ray_tpu.ops.norms import layernorm, rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_angles
 from ray_tpu.parallel.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
@@ -93,6 +93,21 @@ class LlamaConfig:
     @property
     def n_recurrent_layers(self) -> int:
         return 0
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers that keep the last ``sliding_window`` keys and values in
+        a ring (``models/sambay.py`` has them)."""
+        return 0
+
+    #: where positions lie in ``generate.init_cache``'s ``k`` and ``v``
+    #: [L, rows, ...]: before the heads (``models/sambay.py`` keeps a head's
+    #: positions together, axis 3)
+    kv_length_axis = 2
+
+    def kv_shape(self, length: int) -> Tuple[int, int, int]:
+        """What a cache row keeps of ``length`` positions' keys (values)."""
+        return length, self.n_kv_heads, self.head_dim
 
     def qk_norm_params(self) -> int:
         """One layer's ``q_norm`` and ``k_norm`` weights (0 without them)."""
@@ -213,10 +228,34 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     return x + attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
 
 
+def pre_norm(cfg: LlamaConfig, x: jax.Array, layer: Params, name: str
+             ) -> jax.Array:
+    """A block's norm before a branch: an RMSNorm with the weight
+    ``layer[name]``, or, where the layer also has a bias ``name + "_b"``
+    (``models/sambay.py``), a LayerNorm with both, in the compute dtype
+    whatever ``x`` comes in."""
+    cdt = cfg.compute_dtype
+    if name + "_b" in layer:  # (of a residual stream kept in float32 too)
+        return layernorm(x, layer[name].astype(x.dtype),
+                         layer[name + "_b"].astype(x.dtype),
+                         cfg.norm_eps).astype(cdt)
+    return rmsnorm(x, layer[name].astype(cdt), cfg.norm_eps)
+
+
 def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
     """Pre-norm SwiGLU MLP + residual — shared by train and decode paths."""
     cdt = cfg.compute_dtype
-    h = rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
+    h = pre_norm(cfg, x, layer, "mlp_norm")
+    if x.dtype != cdt:
+        # a residual stream kept wider than the compute dtype
+        # (``models/sambay.py``): every product is summed to the stream's
+        # type and only what goes INTO a product is rounded
+        def mm(a, w):
+            return jnp.matmul(a.astype(cdt), layer[w].astype(cdt),
+                              preferred_element_type=x.dtype)
+
+        return x + on_residual(
+            cfg, mm(jax.nn.silu(mm(h, "w_gate")) * mm(h, "w_up"), "w_down"))
     gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
     up = h @ layer["w_up"].astype(cdt)
     return x + on_residual(cfg, (gate * up) @ layer["w_down"].astype(cdt))
@@ -300,7 +339,8 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         raise NotImplementedError(
             "the training blocks compute rotated attention at 1/sqrt(head_dim) "
             "with no multipliers and no recurrent layers; this config is "
-            "served only (models/generate.py, models/hybrid.py)")
+            "served only (models/generate.py, models/hybrid.py, "
+            "models/sambay.py)")
     x = params["embed"].astype(cdt)[tokens]
     sin, cos = rope_angles(tokens.shape[1], cfg.head_dim, cfg.rope_theta, cdt)
 

@@ -10,7 +10,9 @@ paged dynamic memory:
   one tree of buffers by layer kind (``generate.init_cache``): ``k`` and
   ``v`` for the layers that attend and, for a model with recurrent layers
   (``models/hybrid.py``), their state ``ssm`` [L, max_slots, h, p, n] and
-  convolution tail ``conv`` beside them. Every engine program takes the
+  convolution tail ``conv`` beside them, and for one with window layers
+  (``models/sambay.py``) their rings ``wk``/``wv`` of the last
+  ``sliding_window`` positions. Every engine program takes the
   tree's buffers DONATED and returns them aliased, and the batcher rebinds
   the whole tree from each result, so the device holds it once and no
   program copies it.
@@ -336,9 +338,9 @@ def _refuse_prefix_cache(cfg) -> None:
             f"prefix cache (kv_cache_bytes > 0) on a model with "
             f"{cfg.n_recurrent_layers} recurrent layers: a retained prefix "
             f"is pages of K and V, and restoring them restores nothing of a "
-            f"recurrent layer's state after that prefix (it would need a "
-            f"snapshot of the state per retained prefix). Serve it with "
-            f"kv_cache_bytes=0")
+            f"recurrent layer's state after that prefix, nor of a window "
+            f"layer's ring (it would need a snapshot of both per retained "
+            f"prefix). Serve it with kv_cache_bytes=0")
 
 
 class ContinuousBatcher:
@@ -395,8 +397,15 @@ class ContinuousBatcher:
         # recurrent layers
         self._scan_chunks = 0
         # positions the decode launches since ``take_kv_positions`` had
-        # attention read, and positions their active rows had live
+        # attention read, and positions their active rows had live; the
+        # same of the window layers' rings (a model with such layers)
         self._kv_positions = [0, 0]
+        self._window_positions = [0, 0]
+        # layer-tokens the prefills since ``take_prefill_layer_tokens``
+        # computed, and what every layer over every token would have been
+        # (a model whose prefill leaves layers out for all but the last
+        # token: ``sambay.forward_with_cache``)
+        self._prefill_layer_tokens = [0, 0]
 
     # the attention layers' buffers by name (tests and ``_capture``)
     _ck = property(lambda self: self._cache["k"])
@@ -505,6 +514,20 @@ class ContinuousBatcher:
         out, self._kv_positions = self._kv_positions, [0, 0]
         return out[0], out[1]
 
+    def take_window_positions(self) -> Tuple[int, int]:
+        """``take_kv_positions`` for the window layers' rings: a step
+        reads every row's ring whole (``sliding_window`` positions), and an
+        active row has ``min(pos + 1, sliding_window)`` of them live. (0, 0)
+        for a model without window layers."""
+        out, self._window_positions = self._window_positions, [0, 0]
+        return out[0], out[1]
+
+    def take_prefill_layer_tokens(self) -> Tuple[int, int]:
+        """(computed, whole) layer-tokens of the prefills since the last
+        call; (0, 0) where every prefill runs every layer over every token."""
+        out, self._prefill_layer_tokens = self._prefill_layer_tokens, [0, 0]
+        return out[0], out[1]
+
     def _release(self, slot: int) -> None:
         """Free ``slot``: its position goes back to 0, so that the row of a
         request that has left holds no launch's ``kv_read_bound`` up
@@ -590,6 +613,9 @@ class ContinuousBatcher:
                         self._note_moe_stats("prefill", first[1:])
                     first_tok = int(first[0])
                 self._scan_chunks += scan_chunks(self.cfg, s - cached)
+                if hasattr(self.cfg, "prefill_layer_tokens"):
+                    for i, n in enumerate(self.cfg.prefill_layer_tokens(s)):
+                        self._prefill_layer_tokens[i] += n
         except SlotCacheLost:
             raise  # every slot is free again
         except BaseException:
@@ -713,10 +739,17 @@ class ContinuousBatcher:
             self._kv_positions[0] += bucket * sum(
                 int(G.kv_read_bound(self._pos[rows] + j, self.max_len, np))
                 for j in range(k))
+            window = (self.cfg.sliding_window if self.cfg.n_window_layers
+                      else 0)
+            self._window_positions[0] += bucket * k * window
             out = []
             for slot in slots:
                 req = self._active[slot]
                 take = min(k, req.remaining)
+                if window:
+                    self._window_positions[1] += sum(
+                        min(int(self._pos[slot]) + j + 1, window)
+                        for j in range(take))
                 mine = [int(t) for t in toks[:take, slot - rows.start]]
                 req.tokens.extend(mine)
                 req.remaining -= take
@@ -954,6 +987,13 @@ class ContinuousEngine:
                            "recurrent": cfg.n_recurrent_layers},
                 "state_bytes_per_row": cfg.state_bytes_per_row(),
                 "kv_bytes_per_position": cfg.kv_bytes_per_position()}
+            if cfg.n_window_layers:  # the layers by the model's own kinds
+                self._recorder.state_layout.update(
+                    kinds={kind: cfg.layer_types.count(kind)
+                           for kind in dict.fromkeys(cfg.layer_types)},
+                    sliding_window=cfg.sliding_window,
+                    window_bytes_per_row=cfg.window_bytes_per_row(),
+                    kv_readers=cfg.kv_readers)
         # engine-thread-confined tick state (never touched off-thread):
         # end of the previous decode launch (the tick-gap anchor; reset
         # to None when the engine goes idle), and the tick being
@@ -1331,7 +1371,12 @@ class ContinuousEngine:
                                        scan_chunks=self._batcher
                                        .take_scan_chunks(),
                                        kv_positions=self._batcher
-                                       .take_kv_positions(), **fields)
+                                       .take_kv_positions(),
+                                       window_positions=self._batcher
+                                       .take_window_positions(),
+                                       prefill_layer_tokens=self._batcher
+                                       .take_prefill_layer_tokens(),
+                                       **fields)
             if tick is not None and self._on_tick is not None:
                 try:
                     self._on_tick(tick, self.max_slots)
@@ -1655,5 +1700,6 @@ def _decode_executable(cfg, bucket: int, max_slots: int, max_len: int,
         jax.tree.unflatten(tree, leaves),
         *(cache[name] for name in G.cache_names(cfg)), *args).compile()
     return fn, dict(bucket=bucket, k=k, **hlo_copies.cache_traffic(
-        fn, cache, rows=bucket, steps=k, bounds=G.kv_read_bounds(max_len)))
+        fn, cache, rows=bucket, steps=k, bounds=G.kv_read_bounds(max_len),
+        length_axis=cfg.kv_length_axis))
 

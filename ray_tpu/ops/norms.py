@@ -16,3 +16,15 @@ def rmsnorm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     xf = x.astype(jnp.float32)
     rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (xf * rms).astype(dtype) * weight
+
+
+def layernorm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+              eps: float = 1e-5) -> jax.Array:
+    """LayerNorm with weight and bias: mean and variance in float32 as
+    ``rmsnorm``'s mean square is, the scale and the shift applied in the
+    input dtype."""
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * inv).astype(dtype) * weight + bias

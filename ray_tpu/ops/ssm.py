@@ -1,4 +1,5 @@
-"""Mamba-2's state-space recurrence, twice: over a whole sequence in chunks
+"""Two state-space recurrences, Mamba-2's (below) and Mamba-1's (S6, at the
+end of this file), each twice: over a whole sequence in chunks
 (what a prefill runs) and for one token (what a decode step runs).
 
 Per head, with a state ``h`` [P, N] (channel, state), a scalar decay
@@ -135,3 +136,58 @@ def causal_conv(xs: jax.Array, tail: jax.Array, w: jax.Array, bias: jax.Array
     window = jnp.concatenate([tail.astype(xs.dtype), xs], axis=1)
     out = sum(window[:, j:j + s] * w[j] for j in range(k)) + bias
     return out, window[:, s:]
+
+
+# ---- S6, the selective recurrence of Mamba-1 ----------------------------------
+#
+# Per channel ``c`` of ``d_inner`` and state element ``n``, with ``A`` [n, c]
+# negative, a step ``dt`` [c] positive, an input ``x`` [c] and the
+# projections ``B``, ``C`` [n] that ALL channels share::
+#
+#     h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+#     y_t[c]    = sum_n h_t[n, c] C_t[n]
+#
+# Every element of the state has a decay of its own, so nothing here is a
+# matrix product (Mamba-2's one decay a head is what makes ``ssd_scan``'s
+# chunks products): the scan is the recurrence itself over time, elementwise
+# on a state kept ``[n, c]``, channels minor: 5120 channels are 40 x 128
+# lanes and 16 states two sublane tiles, where the published ``[c, n]`` would
+# leave 112 of 128 lanes empty. State, decays and ``dt`` float32, as above.
+
+
+def s6_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
+            C: jax.Array, h0: Optional[jax.Array] = None, *, unroll: int = 8
+            ) -> Tuple[jax.Array, jax.Array]:
+    """The S6 recurrence over a sequence: ``x`` [b, s, c]; ``dt`` [b, s, c]
+    float32, positive; ``A`` [n, c] float32, negative; ``B``, ``C``
+    [b, s, n]; ``h0`` [b, n, c] float32 or None for zeros. Returns (``y``
+    [b, s, c] in ``x``'s type, the state after token ``s - 1`` [b, n, c]
+    float32). A ``lax.scan`` over time, ``unroll`` steps a trip."""
+    b, s, c = x.shape
+    n = A.shape[0]
+    xd = x.astype(F32) * dt
+    state0 = jnp.zeros((b, n, c), F32) if h0 is None else h0.astype(F32)
+
+    def step(state, t):
+        dt_t, xd_t, b_t, c_t = t                  # [b, c] [b, c] [b, n] [b, n]
+        state = (state * jnp.exp(dt_t[:, None, :] * A)
+                 + xd_t[:, None, :] * b_t.astype(F32)[:, :, None])
+        return state, (state * c_t.astype(F32)[:, :, None]).sum(1)
+
+    last, y = jax.lax.scan(
+        step, state0, tuple(a.swapaxes(0, 1) for a in (dt, xd, B, C)),
+        unroll=min(unroll, s))
+    return y.swapaxes(0, 1).astype(x.dtype), last
+
+
+def s6_update(state: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
+              B: jax.Array, C: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The S6 recurrence for one token a row: ``state`` [b, n, c] float32,
+    ``x`` [b, c], ``dt`` [b, c] float32 and positive, ``A`` [n, c], ``B``,
+    ``C`` [b, n]. Returns (``y`` [b, c] in ``x``'s type, the new state).
+    The recurrence as it is written, and what ``ops/pallas/s6_update.py``
+    (the engine's decode step) is tested against."""
+    new = (state.astype(F32) * jnp.exp(dt[:, None, :] * A)
+           + (x.astype(F32) * dt)[:, None, :] * B.astype(F32)[:, :, None])
+    y = (new * C.astype(F32)[:, :, None]).sum(1)
+    return y.astype(x.dtype), new

@@ -945,6 +945,22 @@ def cmd_engine(args: argparse.Namespace) -> int:
                       f"{layout['kv_bytes_per_position']} K/V bytes a "
                       f"position; prefill scan chunks "
                       f"{summ.get('ssm_scan_chunks', 0)}")
+            if layout and layout.get("kinds"):
+                print(f"  layers by kind: "
+                      + ", ".join(f"{n} {kind}" for kind, n in
+                                  layout["kinds"].items())
+                      + f"; rings of {layout['sliding_window']} positions, "
+                      f"{layout['window_bytes_per_row']} bytes a row; "
+                      f"{layout['kv_readers']} layers read the shared K/V")
+            if summ.get("window_positions_read"):
+                print(f"  window rings read "
+                      f"{summ['window_positions_read']} positions for "
+                      f"{summ.get('window_positions_live', 0)} live")
+            if summ.get("prefill_layer_tokens_whole"):
+                print(f"  prefills computed "
+                      f"{summ.get('prefill_layer_tokens', 0)} layer-tokens "
+                      f"of {summ['prefill_layer_tokens_whole']} "
+                      f"(every layer over every token)")
             if summ.get("kv_positions_read"):
                 print(f"  decode attention read "
                       f"{summ['kv_positions_read']} positions for "

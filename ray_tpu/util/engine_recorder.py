@@ -164,7 +164,10 @@ class EngineRecorder(RecorderCore):
         self.decode_programs: List[Dict[str, Any]] = []
         #: static, of a model with recurrent layers: its layers by kind and
         #: what a slot holds for them (``layers``, ``state_bytes_per_row``,
-        #: ``kv_bytes_per_position``); None for a model without
+        #: ``kv_bytes_per_position``; with window layers also ``kinds``,
+        #: ``sliding_window``, ``window_bytes_per_row`` and ``kv_readers``,
+        #: the layers that read the shared keys and values); None for a
+        #: model without
         self.state_layout: Optional[Dict[str, Any]] = None
         self._overhead_tick_s = 0.0  # rt: guarded-by(_lock)
         self._tick_seq = 0  # rt: guarded-by(_lock)
@@ -197,7 +200,9 @@ class EngineRecorder(RecorderCore):
                     decode_parts: Optional[Dict[str, float]] = None,
                     moe: Optional[Dict[str, List[int]]] = None,
                     scan_chunks: int = 0,
-                    kv_positions: Tuple[int, int] = (0, 0)) -> None:
+                    kv_positions: Tuple[int, int] = (0, 0),
+                    window_positions: Tuple[int, int] = (0, 0),
+                    prefill_layer_tokens: Tuple[int, int] = (0, 0)) -> None:
         """One engine tick: phase partition + the decode tick-gap. The
         ONLY thing this does is append to a bounded deque — no metrics,
         no I/O (drained off-thread). ``decode_parts`` is ``decode_step``'s
@@ -209,7 +214,12 @@ class EngineRecorder(RecorderCore):
         this tick's prefills (0 without such layers). ``kv_positions``:
         (positions the tick's decode launch had attention read, positions
         its active rows had live): ``ContinuousBatcher.take_kv_positions``;
-        (0, 0) for a tick that launched no decode."""
+        (0, 0) for a tick that launched no decode. ``window_positions``:
+        the same of the window layers' rings
+        (``take_window_positions``). ``prefill_layer_tokens``: (computed,
+        whole) layer-tokens of the tick's prefills
+        (``take_prefill_layer_tokens``). Both (0, 0) for a model without
+        window layers, or whose prefill runs every layer over every token."""
         if not self.enabled:
             return
         t0 = time.perf_counter()
@@ -229,6 +239,12 @@ class EngineRecorder(RecorderCore):
             rec["scan_chunks"] = scan_chunks
         if kv_positions[0]:
             rec["kv_positions_read"], rec["kv_positions_live"] = kv_positions
+        if window_positions[0]:
+            (rec["window_positions_read"],
+             rec["window_positions_live"]) = window_positions
+        if prefill_layer_tokens[1]:
+            (rec["prefill_layer_tokens"],
+             rec["prefill_layer_tokens_whole"]) = prefill_layer_tokens
         with self._lock:
             self._tick_seq += 1
             rec["seq"] = self._tick_seq
@@ -472,6 +488,11 @@ class EngineRecorder(RecorderCore):
         }
         out.update(_moe_totals(ticks))
         out.update(_kv_read_totals(ticks))
+        for key in ("window_positions_read", "window_positions_live",
+                    "prefill_layer_tokens", "prefill_layer_tokens_whole"):
+            total = sum(t.get(key, 0) for t in ticks)
+            if total:  # a model that records none of them shows none
+                out[key] = total
         if self.state_layout is not None:
             out["state_layout"] = dict(self.state_layout)
             out["ssm_scan_chunks"] = sum(t.get("scan_chunks", 0)

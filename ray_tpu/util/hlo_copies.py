@@ -188,9 +188,16 @@ def _arrays(shape: str) -> List[Tuple[str, Tuple[int, ...]]]:
 
 
 _HLO_TYPE = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
-# ``ops/pallas/ssm_update.py``'s call: its result is the whole stacked state,
-# aliased to its operand; what it moves is in its name
-_STATE_KERNEL = re.compile(r"ssm_update_r(\d+)_h(\d+)_p(\d+)_n(\d+)")
+# ``ops/pallas/ssm_update.py``'s call, or ``s6_update.py``'s: its result is
+# the whole stacked state, aliased to its operand; what it moves is in its
+# name, the rows and a row's sizes
+_STATE_KERNEL = re.compile(r"(?:ssm_update_r(\d+)_h(\d+)_p(\d+)_n(\d+)"
+                           r"|s6_update_r(\d+)_n(\d+)_c(\d+))")
+
+
+# ``ops/pallas/kv_write.py``'s call: its results are whole K/V buffers, each
+# aliased to its operand; what it moves of each is rows x a tile, in its name
+_KV_KERNEL = re.compile(r"kv_write_r(\d+)_h(\d+)_t(\d+)_d(\d+)")
 
 
 def _fused_writers(module: _Module, inst: Tuple) -> Tuple[List[Tuple],
@@ -205,14 +212,18 @@ def _fused_writers(module: _Module, inst: Tuple) -> Tuple[List[Tuple],
 
 
 def cache_traffic(compiled: Any, cache: Any, *, rows: int, steps: int,
-                  bounds: Sequence[int] = ()) -> Dict[str, Any]:
+                  bounds: Sequence[int] = (), length_axis: int = 2
+                  ) -> Dict[str, Any]:
     """For a compiled decode program over a slot cache, stepping ``rows``
     rows ``steps`` times a launch. ``cache`` is the slot tree
     (``generate.init_cache``'s buffers by name, arrays or their shapes:
-    ``k`` and ``v`` [L, slots, max_len, hkv, hd], and with recurrent layers
-    ``ssm`` [L, slots, h, p, n] and ``conv``), or one array shaped like K
+    ``k`` and ``v`` [L, slots, max_len, hkv, hd], with window layers their
+    rings ``wk`` and ``wv`` [L, slots, window, hkv, hd], and with recurrent
+    layers ``ssm`` [L, slots, ...] and ``conv``), or one array shaped like K
     and like V. ``bounds``: the lengths below ``max_len`` a step's read may
     stop at (``generate.kv_read_bounds``), each a branch of a conditional.
+    ``length_axis``: where positions lie in ``k`` (the config's
+    ``kv_length_axis``: 2 as above, 3 for [L, slots, hkv, max_len, hd]).
 
     - ``cache_donated``: the program's input-output aliases cover the
       whole tree;
@@ -228,7 +239,8 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int, steps: int,
       again; ``cache_read_bytes_per_step_least``: the same with every
       conditional taking its cheapest branch, what a step reads while its
       rows are short (equal to the other where the read has no bound);
-    - ``cache_bytes``: K and V together, to read the others against.
+    - ``cache_bytes``: K and V together, and the rings, to read the others
+      against; with rings also ``window_bytes``, theirs alone.
 
     With recurrent layers also ``state_donated`` (the same aliases),
     ``state_bytes`` (the ``ssm`` buffer) and ``state_copy_bytes_per_step``:
@@ -243,10 +255,14 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int, steps: int,
     tree_bytes = sum(int(np.prod(buf.shape, dtype=np.int64))
                      * np.dtype(buf.dtype).itemsize for buf in tree.values())
     donated = bool(mem is not None and mem.alias_size_in_bytes >= tree_bytes)
-    _, _, max_len, hkv, hd = tree["k"].shape
+    row = list(tree["k"].shape[2:])
+    max_len = row.pop(length_axis - 2)
+    hkv, hd = row
     itemsize = np.dtype(tree["k"].dtype).itemsize
     dtype = _HLO_TYPE[str(tree["k"].dtype)]
-    lengths = sorted({max_len, *bounds})
+    rings = [tree[name] for name in ("wk", "wv") if name in tree]
+    lengths = sorted({max_len, *bounds,
+                      *(r.shape[length_axis] for r in rings)})
 
     @functools.lru_cache(maxsize=None)  # three passes ask the same shapes
     def counted(shape: str) -> int:
@@ -263,12 +279,16 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int, steps: int,
         """Bytes one execution of ``inst`` moves: its result if that is
         a new buffer, the update if it writes in place. ``within``: the
         instructions among which its operands are defined."""
-        _, shape, opcode, operands, _ = inst
+        name, shape, opcode, operands, _ = inst
         if opcode in _NO_BUFFER:
             return 0
         if opcode in _IN_PLACE:
             update = operands[_IN_PLACE[opcode]]
             return sum(counted(i[1]) for i in within if i[0] == update)
+        kernel = _KV_KERNEL.match(name) if opcode == "custom-call" else None
+        if kernel:  # a tile a row and buffer, read and written back
+            return 2 * len(_arrays(shape)) * itemsize * int(np.prod(
+                [int(v) for v in kernel.groups()], dtype=np.int64))
         if opcode == "fusion":
             writers, fused = _fused_writers(module, inst)
             if writers:  # the fusion's result is its operand, updated
@@ -284,8 +304,12 @@ def cache_traffic(compiled: Any, cache: Any, *, rows: int, steps: int,
                sliced, fusions=True) // steps,
            "cache_read_bytes_per_step_least": module.fold(
                sliced, min, fusions=True) // steps,
-           "cache_bytes": 2 * int(np.prod(tree["k"].shape, dtype=np.int64))
-           * itemsize}
+           "cache_bytes": sum(int(np.prod(tree[name].shape, dtype=np.int64))
+                              for name in ("k", "v", "wk", "wv")
+                              if name in tree) * itemsize}
+    if rings:
+        out["window_bytes"] = sum(int(np.prod(r.shape, dtype=np.int64))
+                                  for r in rings) * itemsize
     if "ssm" in tree:
         out.update(state_donated=donated, **_state_traffic(
             module, tree["ssm"], rows, steps))
@@ -323,7 +347,7 @@ def _state_traffic(module: _Module, state: Any, rows: int, steps: int
             else None
         if kernel:  # steps the rows its name says where they lie: read, written
             total += times * 2 * itemsize * int(np.prod(
-                [int(v) for v in kernel.groups()], dtype=np.int64))
+                [int(v) for v in kernel.groups() if v], dtype=np.int64))
             continue
         own = touched(inst, peers)
         if own is not None:

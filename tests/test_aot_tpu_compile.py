@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import generate, hybrid, llama, moe, serving
+from ray_tpu.models import generate, hybrid, llama, moe, sambay, serving
 from ray_tpu.ops.pallas import flash
 from ray_tpu.parallel import train_step as ts
 from ray_tpu.parallel.context import mesh_scope
@@ -348,6 +348,99 @@ def test_hybrid_prefill_compiles_for_v5e(topo, length):
     assert mem.alias_size_in_bytes >= _tree_bytes(tree)
     assert mem.temp_size_in_bytes < 2 ** 30
     _no_weight_stack_is_copied(compiled)
+
+
+# Phi-4-mini-flash-reasoning at its published widths and depth, 64 slots as
+# its cell has (benchmark/configs/phi-4-mini-flash-reasoning.json)
+CFG_PHI4FLASH = sambay.SambaYConfig(
+    vocab_size=200064, d_model=2560, n_layers=32, n_heads=40, n_kv_heads=20,
+    d_ff=10240, max_seq_len=2048, norm_eps=1e-5, tie_embeddings=True,
+    param_dtype=jnp.bfloat16, use_rope=False, attn_scale=0.125,
+    layer_types=sambay.layer_types_for(32))
+
+
+def _sambay_args(topo, slots=64):
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _on(one, jax.eval_shape(
+        lambda: sambay.init_params(jax.random.key(0), CFG_PHI4FLASH)))
+    tree = _on(one, jax.eval_shape(
+        lambda: generate.init_cache(CFG_PHI4FLASH, slots, 2048)))
+    buffers = [tree[name] for name in generate.cache_names(CFG_PHI4FLASH)]
+    return params, tree, buffers, lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.int32, sharding=one)
+
+
+def _largest_copy(compiled, but=()):
+    """Elements of the largest array any ``copy`` of the program makes,
+    arrays of the shapes ``but`` left out."""
+    return max([math.prod(dims) for line in compiled.as_text().splitlines()
+                if " copy(" in line
+                for _, dims in hlo_copies._arrays(
+                    line.split(" = ", 1)[1].split(" copy(")[0])
+                if dims not in but] or [0])
+
+
+@pytest.mark.parametrize("bucket", [64, 1])
+def test_sambay_decode_steps_three_cache_shapes_in_place_on_v5e(topo, bucket):
+    """The cell's decode program, all 32 layers: every buffer of the slot
+    tree (the shared K/V, the rings, the float32 state, the tails) is aliased
+    from input to output; the state moves exactly 2.0 x its rows' bytes a step
+    (``s6_update_r<rows>_...`` on the stacked state); of keys and values
+    nothing is written but a 16-position tile a row, buffer and layer that
+    writes (``kv_write_r<rows>_...``: 8 window layers and the full one), and
+    nothing cache-sized or weight-stack-sized is copied at all; the read is
+    the rings whole and the shared buffer below the bound, once for each of
+    its eight readers; and the program has 9 MB of temporaries of its own
+    beside 9.9 GB of weights and slot tree (12.2 GB at 128 slots: both fit)."""
+    params, tree, buffers, i32 = _sambay_args(topo)
+    compiled = serving._compiled_bucket_scan(
+        CFG_PHI4FLASH, bucket, 64, 2048, 8).lower(
+        params, *buffers, i32(bucket), i32(bucket), i32()).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(tree)
+    assert mem.temp_size_in_bytes < 2 ** 27
+    traffic = hlo_copies.cache_traffic(
+        compiled, tree, rows=bucket, steps=8,
+        bounds=generate.kv_read_bounds(2048), length_axis=3)
+    assert traffic["cache_donated"] and traffic["state_donated"]
+    assert traffic["state_bytes"] == 9 * 64 * 16 * 5120 * 4
+    rows_state = traffic["state_bytes"] * bucket // 64
+    if bucket == 64:
+        assert traffic["state_copy_bytes_per_step"] == 2 * rows_state, traffic
+    else:  # a lone row's ``A`` [16, 5120] is as large as its state and counts
+        assert traffic["state_copy_bytes_per_step"] <= 4.2 * rows_state, traffic
+    tile = 10 * 16 * 128 * 2
+    assert traffic["cache_copy_bytes_per_step"] == 9 * 2 * 2 * bucket * tile
+    rings, shared = 8 * 512 * 5120, 5120  # K and V: a row; a row and position
+    # (the counter goes by slices: where the launch is every row of the one
+    # layer there is, the full layer's own read is of the buffer as it
+    # stands, and seven of the eight readers are counted)
+    readers = 8 if bucket == 1 else 7
+    assert traffic["cache_read_bytes_per_step"] \
+        == bucket * (rings + readers * 2048 * shared), traffic
+    assert traffic["cache_read_bytes_per_step_least"] \
+        <= bucket * (rings + readers * 256 * shared), traffic
+    assert traffic["window_bytes"] == 64 * rings
+    text = compiled.as_text()
+    assert f"s6_update_r{bucket}_n16_c5120" in text
+    assert f"kv_write_r{bucket}_h10_t16_d128" in text
+    # (the lone row's launch re-lays the convolution tails, 35 MB, on its
+    # way in and out; no weight stack, nothing of K, V or the state)
+    assert _largest_copy(compiled, (tree["conv"].shape,)) < 2 ** 23
+
+
+@pytest.mark.parametrize("length", [128, 384])
+def test_sambay_prefill_compiles_for_v5e(topo, length):
+    """The cell's two prompt lengths: the whole tree donated, one row's bytes
+    written, temporaries far from the chip's memory, no weight stack copied."""
+    params, tree, buffers, i32 = _sambay_args(topo)
+    compiled = serving._compiled_slot_prefill(
+        CFG_PHI4FLASH, length, 64, 2048).lower(
+        params, *buffers, i32(1, length), i32()).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _tree_bytes(tree)
+    assert mem.temp_size_in_bytes < 2 ** 29
+    assert _largest_copy(compiled) < 2 ** 23
 
 
 @pytest.mark.parametrize("family,bucket", [

@@ -561,6 +561,17 @@ def _engine_programs():
         yield (f"{name} cached", serving._compiled_cached_prefill(
             cfg, 16, 8, 4, 64).lower(params, cache, cache, pages, pages,
                                      i32(1, 8), i32()))
+    # and a Granite-like config's, on its slot tree
+    cfg = hybrid.PRESETS["hybrid-debug"]
+    params = jax.eval_shape(lambda: hybrid.init_params(jax.random.key(0), cfg))
+    tree = jax.eval_shape(lambda: G.init_cache(cfg, 4, 64))
+    buffers = [tree[name] for name in G.cache_names(cfg)]
+    for bucket in (4, 1):
+        yield (f"granite-like decode {bucket}", serving._compiled_bucket_scan(
+            cfg, bucket, 4, 64, 8, False).lower(
+            params, *buffers, i32(bucket), i32(bucket), i32()))
+    yield ("granite-like prefill", serving._compiled_slot_prefill(
+        cfg, 24, 4, 64).lower(params, *buffers, i32(1, 24), i32()))
 
 
 # the digests of these programs as commit 8320701 (the parent of PR 31)
@@ -584,6 +595,13 @@ PARENT_PROGRAMS = {
     "olmoe-like prefill": "8aa92e4a669947bf",
     "olmoe-like prefill-sampling": "ce4ab2112c4d181c",
     "olmoe-like cached": "b822f7010eba7b82",
+    # as commit bc8f66c (the parent of PR 34) lowered them: with ``_walk``
+    # taking an order in segments, the cache tree's shapes asked of the
+    # config, and the norms, the bounded read and the dispatch behind
+    # helpers, a Granite program computes what it computed, in that order
+    "granite-like decode 4": "7c18f399f995a11a",
+    "granite-like decode 1": "91b9e1163771b27f",
+    "granite-like prefill": "ef68303b068763ff",
 }
 
 
