@@ -69,14 +69,10 @@ class Cluster:
     def shutdown(self) -> None:
         import ray_tpu
 
+        from ray_tpu.util import lifecycle
+
         if ray_tpu.is_initialized():
+            # tears down the backend; the handle still owns the components
             ray_tpu.shutdown()
-        else:
-            self._handle.shutdown()
-            return
-        # shutdown() above tears down the backend; the handle still owns the
-        # control-plane components if no driver was attached.
-        try:
-            self._handle.shutdown()
-        except Exception:
-            pass
+        self._handle.shutdown()
+        lifecycle.close_shutdown(self._handle.session_name)

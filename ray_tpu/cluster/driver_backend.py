@@ -13,6 +13,7 @@ a true multi-host deployment.
 
 from __future__ import annotations
 
+import asyncio
 import os
 import time
 import uuid
@@ -25,6 +26,7 @@ from ray_tpu.cluster.gcs import GcsServer
 from ray_tpu.cluster.raylet import Raylet
 from ray_tpu.cluster.rpc import EventLoopThread, RpcServer
 from ray_tpu.core import resources as res
+from ray_tpu.util import lifecycle
 
 
 class ClusterHandle:
@@ -32,6 +34,7 @@ class ClusterHandle:
 
     def __init__(self, session_name: Optional[str] = None):
         self.session_name = session_name or f"session_{uuid.uuid4().hex[:12]}"
+        lifecycle.set_session(self.session_name)
         self.io = EventLoopThread(name="rt-cluster-io")
         self.gcs: Optional[GcsServer] = None
         self.gcs_address: Optional[str] = None
@@ -118,23 +121,46 @@ class ClusterHandle:
         self.raylets.remove(raylet)
 
     def shutdown(self) -> None:
+        """Stop every node, then the head, then the loop; returns when the
+        lifecycle record's rows say no process of the session is left. Each
+        raylet's ``stop`` runs its own schedule to the end (ask, SIGTERM,
+        SIGKILL, each with its wait): there is no clock over it. A step
+        that raises, or a wait that runs out, is said in the ``rt-shutdown``
+        line (``lifecycle.note_abandoned``) and the rest still runs."""
+        async def _step(what: str, coro, timeout: Optional[float] = None):
+            try:
+                await asyncio.wait_for(coro, timeout)
+            except asyncio.TimeoutError:
+                lifecycle.note_abandoned(f"{what} not done in {timeout:g} s")
+            except Exception as e:  # noqa: BLE001 — said, and the rest runs
+                lifecycle.note_abandoned(f"{what} raised {e!r}")
+
         async def _go():
             for raylet in self.raylets:
-                try:
-                    await raylet.stop()
-                except Exception:
-                    pass
-            try:
+                await _step(f"raylet {raylet.node_id[:8]} stop", raylet.stop())
+            # whoever no node's stop saw gone (a node whose stop raised, a
+            # row of a node removed earlier): SIGKILL, and wait it out
+            with lifecycle.span("last_sweep", parent="shutdown"):
+                left = lifecycle.not_gone(session=self.session_name)
+                for row in left:
+                    row.kill()
+                left = await lifecycle.wait_gone(left, Raylet._KILL_WAIT_S)
+            if left:
+                lifecycle.note_abandoned(
+                    "the last sweep waited "
+                    f"{Raylet._KILL_WAIT_S:.0f} s after SIGKILL for "
+                    + "; ".join(r.describe() for r in left))
+            cap = get_config().graceful_shutdown_timeout_s
+            with lifecycle.span("gcs_stop", parent="shutdown"):
                 if self.gcs is not None:
-                    await self.gcs.stop()
-                await self._gcs_rpc_server.stop()
-            except Exception:
-                pass
+                    await _step("gcs stop", self.gcs.stop(), cap)
+                    await _step("gcs rpc server stop",
+                                self._gcs_rpc_server.stop(), cap)
 
         try:
-            self.io.run(_go(), timeout=get_config().graceful_shutdown_timeout_s)
-        except Exception:
-            pass
+            self.io.run(_go())
+        except Exception as e:  # noqa: BLE001 — the loop itself is gone
+            lifecycle.note_abandoned(f"cluster stop raised {e!r}")
         # Session owner: remove the shared shm dir once, after all nodes stop.
         if self.raylets:
             try:
@@ -142,7 +168,8 @@ class ClusterHandle:
             except Exception:
                 pass
         self.raylets.clear()
-        self.io.stop()
+        with lifecycle.span("io_stop", parent="shutdown"):
+            self.io.stop()
 
 
 def start_or_connect(address: Optional[str], job_id: JobID, *,
@@ -167,16 +194,19 @@ def start_or_connect(address: Optional[str], job_id: JobID, *,
                                 namespace=namespace, client_mode=True)
     if address is None:
         cluster = ClusterHandle()
-        cluster.start_gcs()
-        raylet = cluster.add_node(num_cpus=num_cpus, num_tpus=num_tpus,
-                                  resources=resources)
-        backend = ClusterBackend(
-            gcs_address=cluster.gcs_address,
-            raylet_address=raylet.server.address,
-            node_id=raylet.node_id,
-            session_name=cluster.session_name,
-            job_id=job_id, role="driver", namespace=namespace)
-        backend.connect()
+        with lifecycle.span("gcs_start", parent="init"):
+            cluster.start_gcs()
+        with lifecycle.span("raylet_start", parent="init"):
+            raylet = cluster.add_node(num_cpus=num_cpus, num_tpus=num_tpus,
+                                      resources=resources)
+        with lifecycle.span("driver_connect", parent="init"):
+            backend = ClusterBackend(
+                gcs_address=cluster.gcs_address,
+                raylet_address=raylet.server.address,
+                node_id=raylet.node_id,
+                session_name=cluster.session_name,
+                job_id=job_id, role="driver", namespace=namespace)
+            backend.connect()
         backend._cluster_shutdown_hook = cluster.shutdown
         backend._cluster = cluster
         return backend

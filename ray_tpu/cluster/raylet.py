@@ -48,6 +48,7 @@ from ray_tpu.cluster.rpc import (
 from ray_tpu.exceptions import WorkerCrashedError
 from ray_tpu.scheduler.policy import strategy_allows_local
 from ray_tpu.util import chaos as C
+from ray_tpu.util import lifecycle
 from ray_tpu.util import metrics as M
 from ray_tpu.util.profiling import format_current_stacks
 
@@ -56,19 +57,15 @@ _PACKAGE_ROOT = os.path.dirname(os.path.dirname(
     os.path.abspath(ray_tpu.__file__)))
 
 
-async def _wait_gone(procs, timeout_s: float) -> None:
-    """Wait, up to ``timeout_s``, until every process has exited."""
-    deadline = time.monotonic() + timeout_s
-    while (any(proc.poll() is None for proc in procs)
-           and time.monotonic() < deadline):
-        await asyncio.sleep(0.02)
-
-
 class _WorkerEntry:
     def __init__(self, worker_id: str, proc: subprocess.Popen, key: Tuple,
-                 loop: asyncio.AbstractEventLoop):
+                 loop: asyncio.AbstractEventLoop,
+                 row: Optional[lifecycle.ProcRow] = None):
         self.worker_id = worker_id
         self.proc = proc
+        # the process's line in the lifecycle record: every poll and signal
+        # goes through it, so that the row says when and how it ended
+        self.row = row or lifecycle.ProcRow(proc, worker_id)
         self.key = key                      # (chip_tuple, runtime_env_hash)
         self.chips: Tuple[int, ...] = ()    # TPU chips this process may open
         self.address: Optional[str] = None
@@ -474,34 +471,82 @@ class Raylet:
                 asyncio.ensure_future(self._memory_monitor_loop()))
         return self.server.address
 
+    # how long each rung of the ladder waits for its processes to be seen
+    # gone: asked (the worker's own ``exit``), SIGTERM, SIGKILL. A killed
+    # worker is gone only once the kernel has closed what it held: one that
+    # held four chips outlasted its SIGKILL by seconds, and the next process
+    # on the host found /dev/vfio busy (PR 30)
+    _EXIT_ASK_S = 3.0
+    _TERM_GRACE_S = 3.0
+    _KILL_WAIT_S = 30.0
+
     async def stop(self, destroy_store: bool = False) -> None:
+        """Stop the node and leave no process behind: whoever starts next
+        on this host must find its chips closed. The processes are the
+        lifecycle record's rows for this node, every ``Popen`` not yet seen
+        gone, whatever ``_workers`` still knows of them."""
         self._stopped = True
-        await cancel_and_wait(*self._tasks)
-        self._tasks.clear()
-        procs = [w.proc for w in self._workers.values()]
-        for proc in procs:
-            try:
-                proc.terminate()
-            except ProcessLookupError:
-                pass
-        # a stopped node leaves no process behind: whoever starts next on
-        # this host must find its chips closed
-        await _wait_gone(procs, 3.0)
-        for proc in procs:
-            if proc.poll() is None:
-                proc.kill()
-        # a killed worker is gone only once the kernel has closed what it
-        # held: one that held four chips outlasted its SIGKILL by seconds,
-        # and the next process on the host found /dev/vfio busy
-        await _wait_gone(procs, 30.0)
-        if self._gcs is not None:
-            await self._gcs.close()
-        await self._pool.close_all()
-        await self.server.stop()
+        with lifecycle.span("raylet_stop", parent="shutdown",
+                            node_id=self.node_id):
+            with lifecycle.span("cancel_tasks", parent="raylet_stop"):
+                await cancel_and_wait(*self._tasks)
+                self._tasks.clear()
+            left = await self._end_processes(
+                lifecycle.not_gone(node_id=self.node_id))
+            if left:
+                lifecycle.note_abandoned(
+                    f"raylet {self.node_id[:8]} waited "
+                    f"{self._KILL_WAIT_S:.0f} s after SIGKILL for "
+                    + "; ".join(r.describe() for r in left))
+            with lifecycle.span("rpc_close", parent="raylet_stop"):
+                if self._gcs is not None:
+                    await self._gcs.close()
+                await self._pool.close_all()
+                await self.server.stop()
         # The shm session dir is SHARED by all nodes of the session (same
         # host); only the session owner destroys it (ClusterHandle.shutdown).
         if destroy_store:
             self.store.destroy()
+
+    async def _end_processes(self, rows: List[lifecycle.ProcRow]
+                             ) -> List[lifecycle.ProcRow]:
+        """Ask, then SIGTERM, then SIGKILL, each rung for whoever the one
+        before left alive; returns the rows still not seen gone at the end.
+        A chip worker sat out every SIGTERM for the whole grace (D15) and
+        leaves at once when asked: its ``exit`` is ``os._exit``."""
+        by_id = {e.worker_id: e for e in self._workers.values()}
+        with lifecycle.span("exit_ask", parent="raylet_stop"):
+            asked = [r for r in rows
+                     if getattr(by_id.get(r.worker_id), "client", None)]
+            heard = await asyncio.gather(*(self._ask_exit(by_id[r.worker_id])
+                                           for r in asked))
+            await lifecycle.wait_gone(
+                [r for r, ok in zip(asked, heard) if ok], self._EXIT_ASK_S)
+        with lifecycle.span("term_grace", parent="raylet_stop"):
+            for row in rows:
+                row.terminate()
+            left = await lifecycle.wait_gone(rows, self._TERM_GRACE_S)
+        with lifecycle.span("kill_wait", parent="raylet_stop"):
+            for row in left:
+                row.kill()
+            return await lifecycle.wait_gone(left, self._KILL_WAIT_S)
+
+    async def _ask_exit(self, entry: _WorkerEntry) -> bool:
+        """The worker's own ``exit`` RPC (``os._exit(0)`` a moment after the
+        reply); whether it answered. One that cannot within half a second
+        (a loop that another thread starves: a train worker's took 7 s at
+        its run's end, my chip run, PR 37) is the next rung's: SIGTERM
+        needs no answer."""
+        if not entry.row.alive:
+            return False
+        entry.row.ask_exit_stamp()
+        try:
+            # the whole call under the clock, not its reply alone: the write
+            # waits too when the peer does not read
+            await asyncio.wait_for(entry.client.call("exit", {}), 0.5)
+            return True
+        except Exception:  # noqa: BLE001 — deaf or gone: SIGTERM is next
+            return False
 
     async def _heartbeat_loop(self) -> None:
         cfg = get_config()
@@ -736,7 +781,7 @@ class Raylet:
         """rt_worker_rss_bytes per live worker; dead workers' samples are
         removed so the page doesn't accumulate stale series."""
         by_pid = {e.proc.pid: e.worker_id
-                  for e in self._workers.values() if e.proc.poll() is None}
+                  for e in self._workers.values() if e.row.poll() is None}
         live: set = set()
         for pid, rss in _native.process_memory(list(by_pid)):
             wid = by_pid.get(pid)
@@ -825,7 +870,7 @@ class Raylet:
             C.disarm()
         self._chaos_seen_rev = rev
         for entry in list(self._workers.values()):
-            if entry.client is None or entry.proc.poll() is not None:
+            if entry.client is None or entry.row.poll() is not None:
                 continue
             try:
                 await entry.client.call(
@@ -911,7 +956,13 @@ class Raylet:
     # ---- worker pool --------------------------------------------------------
     def _spawn_worker(self, key: Tuple, chips: List[int],
                       runtime_env: Optional[Dict] = None,
-                      python_exe: Optional[str] = None) -> _WorkerEntry:
+                      python_exe: Optional[str] = None, *,
+                      kind: str = "task", cause: Optional[str] = None
+                      ) -> _WorkerEntry:
+        """``Popen`` one worker. The one place a process is made, so the one
+        place that opens its row in the lifecycle record (``kind``: task,
+        actor or warm; ``cause``: the task or actor that asked) and counts
+        the spawn."""
         worker_id = os.urandom(8).hex()
         env = dict(os.environ)
         env["RT_WORKER_ID"] = worker_id
@@ -948,10 +999,23 @@ class Raylet:
              "ray_tpu.cluster.worker_main"],
             env=env, stdout=log_file, stderr=subprocess.STDOUT)
         log_file.close()
-        entry = _WorkerEntry(worker_id, proc, key, self.loop)
+        row = lifecycle.add_row(lifecycle.ProcRow(
+            proc, worker_id, chips=chips, kind=kind, cause=cause,
+            node_id=self.node_id, session=self.session_name))
+        self._sched_stats["prestarted" if kind == "warm"
+                          else "cold_spawns"] += 1
+        entry = _WorkerEntry(worker_id, proc, key, self.loop, row)
         entry.chips = tuple(chips)
         self._workers[worker_id] = entry
         return entry
+
+    def _forget(self, entry: _WorkerEntry) -> None:
+        """Take a worker out of ``_workers``. Only ``poll()`` having
+        collected the child allows it: the reap loop is what closes a row,
+        and a process that the books have dropped while it lives is one
+        that ``stop`` used to miss."""
+        if entry.row.poll() is not None:
+            self._workers.pop(entry.worker_id, None)
 
     async def _vacate_chips(self, chips: List[int]) -> None:
         """Call before spawning a worker for ``chips``. A chip belongs to one
@@ -966,20 +1030,22 @@ class Raylet:
             return
         want = set(chips)
         leaving = [e for e in self._workers.values()
-                   if want.intersection(e.chips) and e.proc.poll() is None
+                   if want.intersection(e.chips) and e.row.poll() is None
                    and not e.busy and not e.is_actor_worker]
         for e in leaving:
             if e.idle_since is not None:
                 self._idle[e.key].remove(e)
                 e.idle_since = None
                 self._terminate_worker(e)
-        await _wait_gone([e.proc for e in leaving], 10.0)
+        await lifecycle.wait_gone([e.row for e in leaving], 10.0)
 
     async def rpc_worker_ready(self, p):
         entry = self._workers.get(p["worker_id"])
         if entry is None:
             return {"ok": False}
         entry.address = p["address"]
+        entry.row.t_main = p.get("t_main")
+        entry.row.t_ready = time.time()
         entry.client = await self._pool.get(p["address"])
         if not entry.ready.done():
             entry.ready.set_result(True)
@@ -1000,7 +1066,8 @@ class Raylet:
             pass
 
     async def _get_worker(self, key: Tuple, chips: List[int],
-                          runtime_env: Optional[Dict] = None
+                          runtime_env: Optional[Dict] = None,
+                          cause: Optional[str] = None
                           ) -> Tuple[_WorkerEntry, str]:
         """Returns ``(worker, source)`` with source "warm" (pool hit) or
         "spawn" (fresh process) — the phase tracer's worker_acquire tag.
@@ -1018,10 +1085,10 @@ class Raylet:
             idle = self._idle.get(key)
             while idle:
                 entry = idle.pop()
-                if entry.proc.poll() is None:
+                if entry.row.poll() is None:
                     entry.idle_since = None
                     return entry, "warm"
-                self._workers.pop(entry.worker_id, None)
+                self._forget(entry)
             if self._spawn_slots > 0:
                 break
             await asyncio.sleep(0.05)
@@ -1047,15 +1114,15 @@ class Raylet:
                         None, ensure_venv, runtime_env, cache_root),
                     get_config().runtime_env_setup_timeout_s)
             await self._vacate_chips(chips)
-            entry = self._spawn_worker(key, chips, runtime_env, python_exe)
+            entry = self._spawn_worker(key, chips, runtime_env, python_exe,
+                                       kind="task", cause=cause)
             cfg = get_config()
             timeout = cfg.process_startup_timeout_s + (
                 cfg.runtime_env_setup_timeout_s if runtime_env else 0)
             try:
                 await asyncio.wait_for(entry.ready, timeout)
             except asyncio.TimeoutError:
-                entry.proc.kill()
-                self._workers.pop(entry.worker_id, None)
+                entry.row.kill()  # the reap loop collects it
                 raise
             return entry, "spawn"
         finally:
@@ -1064,7 +1131,7 @@ class Raylet:
     def _release_worker(self, entry: _WorkerEntry) -> None:
         entry.busy = False
         entry.current_task = None
-        if entry.proc.poll() is None and not entry.is_actor_worker:
+        if entry.row.poll() is None and not entry.is_actor_worker:
             entry.idle_since = time.monotonic()
             self._idle.setdefault(entry.key, []).append(entry)
 
@@ -1108,11 +1175,10 @@ class Raylet:
                 if now - entry.idle_since <= cfg.idle_worker_ttl_s:
                     break  # oldest within TTL -> all newer ones are too
                 self._idle.get(entry.key, []).remove(entry)
-                self._workers.pop(entry.worker_id, None)
-                try:
-                    entry.proc.terminate()
-                except Exception:  # noqa: BLE001 — already gone
-                    pass
+                entry.idle_since = None
+                # stays in the books until poll() has collected it, and is
+                # killed if it sits the request and the SIGTERM out
+                self._terminate_worker(entry)
 
             # warm-pool prestart (reference: worker_pool.h PrestartWorkers):
             # keep the configured floor of plain workers idle so the next
@@ -1121,7 +1187,7 @@ class Raylet:
             if cfg.worker_prestart_floor > 0 and not self._stopped:
                 warm_idle = sum(
                     1 for e in self._idle.get(_WARM_KEY, ())
-                    if e.proc.poll() is None)
+                    if e.row.poll() is None)
                 # floor capped by the idle soft limit: a floor above it
                 # would fight the surplus reaper above in a perpetual
                 # boot/retire churn loop on an otherwise idle node
@@ -1132,8 +1198,8 @@ class Raylet:
                     spawn_task(self._prestart_worker())
 
             for entry in list(self._workers.values()):
-                if entry.proc.poll() is not None:
-                    self._workers.pop(entry.worker_id, None)
+                if entry.row.poll() is not None:
+                    self._forget(entry)
                     if entry.is_actor_worker and entry.actor_id:
                         getattr(entry, "_pool", self.node).release(
                             ResourceSet(entry_spec_resources(entry)), entry.assignment)
@@ -1148,10 +1214,10 @@ class Raylet:
                             cause = F.cause_dict(
                                 F.WORKER_CRASH,
                                 f"worker exited with code "
-                                f"{entry.proc.returncode}",
+                                f"{entry.row.exit}",
                                 node_id=self.node_id,
                                 worker_id=entry.worker_id,
-                                exit_code=entry.proc.returncode)
+                                exit_code=entry.row.exit)
                         # degraded-aware: a dead actor's report must not
                         # kill the reap loop while the GCS is down — it
                         # defers and replays on resync (the restart budget
@@ -1178,15 +1244,13 @@ class Raylet:
                 return
             self._spawn_slots -= 1
             try:
-                entry = self._spawn_worker(_WARM_KEY, [], None)
+                entry = self._spawn_worker(_WARM_KEY, [], None, kind="warm")
                 try:
                     await asyncio.wait_for(
                         entry.ready, get_config().process_startup_timeout_s)
                 except asyncio.TimeoutError:
-                    entry.proc.kill()
-                    self._workers.pop(entry.worker_id, None)
+                    entry.row.kill()  # the reap loop collects it
                     return
-                self._sched_stats["prestarted"] += 1
                 self._release_worker(entry)
                 self._dispatch_event.set()
             finally:
@@ -1263,24 +1327,21 @@ class Raylet:
 
     def _terminate_worker(self, entry: _WorkerEntry,
                           grace_s: float = 5.0) -> None:
-        """SIGTERM now, SIGKILL if still alive after the grace period. The
+        """Ask the worker to exit, SIGTERM it if it has not gone a second
+        later, SIGKILL if it is still alive after the grace period. The
         entry STAYS in ``_workers`` so the reap loop's ``poll()`` collects
         the child (popping immediately would leak a zombie — nothing would
-        ever wait() it)."""
-        try:
-            entry.proc.terminate()
-        except ProcessLookupError:
-            return
+        ever wait() it). If the node stops first, ``stop`` takes the ladder
+        over from the row."""
+        async def _ladder():
+            if entry.client is not None and await self._ask_exit(entry) \
+                    and not await lifecycle.wait_gone([entry.row], 1.0):
+                return
+            entry.row.terminate()
+            if await lifecycle.wait_gone([entry.row], grace_s):
+                entry.row.kill()
 
-        async def _escalate():
-            await asyncio.sleep(grace_s)
-            if entry.proc.poll() is None:
-                try:
-                    entry.proc.kill()
-                except ProcessLookupError:
-                    pass
-
-        spawn_task(_escalate())
+        spawn_task(_ladder())
 
     # injectable for tests (fake pressure without allocating gigabytes);
     # instance-level plain callable, so no descriptor binding applies
@@ -1318,7 +1379,7 @@ class Raylet:
                 victim.oom_killed = True
                 victim_rss = _native.process_rss(victim.proc.pid)
                 try:
-                    victim.proc.kill()
+                    victim.row.kill()
                 except ProcessLookupError:
                     pass
                 self._record_oom_kill(victim, victim_rss,
@@ -1364,7 +1425,7 @@ class Raylet:
     def _pick_oom_victim(self) -> Optional[_WorkerEntry]:
         idle_workers, task_workers, actor_workers = [], [], []
         for e in self._workers.values():
-            if e.proc.poll() is not None or e.oom_killed:
+            if e.row.poll() is not None or e.oom_killed:
                 continue
             if e.is_actor_worker:
                 actor_workers.append(e)
@@ -2071,7 +2132,8 @@ class Raylet:
                                    "pool": pool}
         worker = None
         try:
-            worker, source = await self._get_worker(key, chips, renv)
+            worker, source = await self._get_worker(key, chips, renv,
+                                                    cause=task_id)
             # warm-pool accounting: a pool hit skipped an interpreter boot
             if source == "warm":
                 self._sched_stats["warm_hits"] += 1
@@ -2081,8 +2143,6 @@ class Raylet:
                             1.0, {"node_id": self.node_id, "kind": "task"})
                     except Exception:  # noqa: BLE001 — telemetry only
                         pass
-            else:
-                self._sched_stats["cold_spawns"] += 1
             f = C.maybe_fire("raylet.kill_worker",
                              target=payload.get("fn_name"))
             if f is not None:
@@ -2094,7 +2154,7 @@ class Raylet:
                                   name=payload.get("fn_name"),
                                   worker_id=worker.worker_id)
                 try:
-                    worker.proc.kill()
+                    worker.row.kill()
                 except ProcessLookupError:
                     pass
             worker.busy = True
@@ -2230,7 +2290,7 @@ class Raylet:
                 idle = self._idle.get(_WARM_KEY)
                 while idle:
                     cand = idle.pop()
-                    if cand.proc.poll() is None:
+                    if cand.row.poll() is None:
                         worker = cand
                         worker.idle_since = None
                         worker.key = (("actor", p["actor_id"]),)
@@ -2244,7 +2304,7 @@ class Raylet:
                             except Exception:  # noqa: BLE001
                                 pass
                         break
-                    self._workers.pop(cand.worker_id, None)
+                    self._forget(cand)
                 if worker is not None:
                     # placement receipt: adoption is a placement decision —
                     # the warm pool won over a cold spawn on this node
@@ -2257,10 +2317,11 @@ class Raylet:
                         "candidates": [self._local_features()],
                     })
             if worker is None:
-                self._sched_stats["cold_spawns"] += 1
                 await self._vacate_chips(chips)
                 worker = self._spawn_worker((("actor", p["actor_id"]),),
-                                            chips, spec.get("runtime_env"))
+                                            chips, spec.get("runtime_env"),
+                                            kind="actor",
+                                            cause=p["actor_id"])
             worker.is_actor_worker = True
             worker.job_id = spec.get("job_id")
             worker.actor_id = p["actor_id"]
@@ -2274,6 +2335,7 @@ class Raylet:
                 + (cfg.runtime_env_setup_timeout_s
                    if spec.get("runtime_env") else 0))
             reply = await worker.client.call("create_actor", p)
+            self._note_actor_init(worker.row, spec, reply)
             if not reply.get("ok"):
                 # Unmark before releasing so _reap_loop doesn't release the
                 # same resources a second time (double-release would corrupt
@@ -2311,6 +2373,19 @@ class Raylet:
             # the create reply finalizes the actor (emitting both would
             # double rt_failures_total for one failure)
             return {"ok": False, "error": repr(e), "cause": cause}
+
+    @staticmethod
+    def _note_actor_init(row: lifecycle.ProcRow, spec: Dict, reply: Dict
+                         ) -> None:
+        """What the ``create_actor`` reply carries for the record: the
+        worker's clock round the user's ``__init__``, and the spans its
+        process made on the way (an engine's ``engine_init`` and below)."""
+        row.cause = row.cause or spec.get("actor_id")  # a warm one adopted
+        row.label = spec.get("name") or spec.get("class_name")
+        row.t_asked = spec.get("t_asked")
+        row.t_actor_init0 = reply.get("t_actor_init0")
+        row.t_actor_init1 = reply.get("t_actor_init1")
+        lifecycle.merge(reply.get("spans") or (), worker_id=row.worker_id)
 
     async def rpc_kill_actor(self, p):
         for entry in list(self._workers.values()):
@@ -2802,7 +2877,7 @@ class Raylet:
                 "pinned": pinned})
         objects.sort(key=lambda d: -d["size"])
         by_pid = {e.proc.pid: e for e in self._workers.values()
-                  if e.proc.poll() is None}
+                  if e.row.poll() is None}
         workers = [{
             "worker_id": by_pid[pid].worker_id, "pid": pid, "rss": rss,
             "busy": by_pid[pid].busy,
@@ -2858,7 +2933,7 @@ class Raylet:
             return info
 
         live = [e for e in self._workers.values()
-                if e.proc.poll() is None]
+                if e.row.poll() is None]
         out.extend(await asyncio.gather(*(one(e) for e in live)))
         return {"node_id": self.node_id, "processes": out}
 

@@ -48,6 +48,7 @@ from ray_tpu.cluster import stream as rt_stream
 from ray_tpu.cluster.object_store import PlasmaStore
 from ray_tpu.runtime_env import prepare_runtime_env
 from ray_tpu.util import chaos as _chaos
+from ray_tpu.util import lifecycle
 from ray_tpu.util import metrics as M
 from ray_tpu.util import tracing
 from ray_tpu.util.placement_group import (
@@ -1492,6 +1493,9 @@ class ClusterBackend(RuntimeBackend):
             "method_meta": method_meta,
             "owner": self.address,
             "runtime_env": self._prepare_env(options),
+            # the asker's clock: where the actor's row in the lifecycle
+            # record starts (``replica_start`` in a serve cell)
+            "t_asked": time.time(),
         }
         reply = self.io.run(self._gcs.call("register_actor", {"spec": spec}))
         if reply.get("error"):
@@ -1737,10 +1741,11 @@ class ClusterBackend(RuntimeBackend):
         if hook is not None:
             try:
                 hook()
+            except Exception as e:  # noqa: BLE001 — said in rt-shutdown
+                lifecycle.note_abandoned(f"cluster shutdown raised {e!r}")
+        with lifecycle.span("backend_disconnect", parent="shutdown"):
+            try:
+                self.io.run(self.server.stop(), timeout=2)
             except Exception:
                 pass
-        try:
-            self.io.run(self.server.stop(), timeout=2)
-        except Exception:
-            pass
-        self.io.stop()
+            self.io.stop()

@@ -19,6 +19,7 @@ import inspect
 import os
 import sys
 import threading
+import time
 import traceback
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
@@ -32,6 +33,7 @@ from ray_tpu.cluster.worker_core import ClusterBackend
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.exceptions import TaskError
 from ray_tpu.util import chaos as C
+from ray_tpu.util import lifecycle
 
 
 class _GenStreamPump:
@@ -196,8 +198,9 @@ def _granted_chips() -> str:
 
 
 class WorkerProcess:
-    def __init__(self, compiles=None):
+    def __init__(self, compiles=None, t_main: Optional[float] = None):
         self.worker_id = os.environ["RT_WORKER_ID"]
+        lifecycle.set_session(os.environ["RT_SESSION_NAME"])
         self.backend = ClusterBackend(
             gcs_address=os.environ["RT_GCS_ADDR"],
             raylet_address=os.environ["RT_RAYLET_ADDR"],
@@ -221,6 +224,7 @@ class WorkerProcess:
         self._device_polled: Optional[Dict[str, Any]] = None
         self._device_logged: Optional[Dict[str, Any]] = None
         self._compiles = compiles
+        self._t_main = t_main  # this process's clock on entering main()
 
     def start(self) -> None:
         from ray_tpu.core.worker import global_worker
@@ -237,7 +241,8 @@ class WorkerProcess:
         global_worker().connect(self.backend, self.backend.job_id, "worker")
         self.backend.io.run(self.backend._raylet.call("worker_ready", {
             "worker_id": self.worker_id,
-            "address": self.backend.server.address}))
+            "address": self.backend.server.address,
+            "t_main": self._t_main}))
         # Exit when the raylet goes away.
         self.backend.io.spawn(self._watch_raylet())
 
@@ -645,15 +650,21 @@ class WorkerProcess:
             args, kwargs = self._resolve_args(spec["args"], spec["kwargs"])
             return cls(*args, **kwargs)
 
+        # the reply carries this process's half of the actor's row in the
+        # lifecycle record: its clock round the user's __init__, and the
+        # spans made on the way (an engine's engine_init and below)
+        stamps = {"t_actor_init0": time.time()}
         try:
             self._actor_instance = await loop.run_in_executor(
                 self._actor_threads, build)
-            return {"ok": True, "address": self.backend.server.address}
+            reply = {"ok": True, "address": self.backend.server.address}
         # rt: lint-allow(except-discipline) error transport: __init__
         # failure crosses the wire as the create-actor reply
         except BaseException as e:  # noqa: BLE001
             traceback.print_exc()
-            return {"ok": False, "error": f"__init__ failed: {e!r}"}
+            reply = {"ok": False, "error": f"__init__ failed: {e!r}"}
+        stamps["t_actor_init1"] = time.time()
+        return {**reply, **stamps, "spans": lifecycle.spans()}
 
     async def _actor_consumer(self, q: asyncio.Queue) -> None:
         while True:
@@ -831,6 +842,7 @@ class WorkerProcess:
 
 
 def main() -> None:
+    t_main = time.time()
     # Debuggability: `kill -USR1 <worker_pid>` dumps all thread stacks to the
     # worker's log (stderr) — the only way to see inside a wedged worker.
     import faulthandler
@@ -852,7 +864,7 @@ def main() -> None:
 
         compile_cache.configure()
         compiles = compile_cache.CompileCounter()
-    wp = WorkerProcess(compiles)
+    wp = WorkerProcess(compiles, t_main)
     wp.start()
     threading.Event().wait()  # io loop thread does the work
 

@@ -17,6 +17,7 @@ from ray_tpu._private.ids import ActorID, JobID, ObjectID, TaskID
 from ray_tpu._private.serialization import SerializationContext
 from ray_tpu.core.backend import RuntimeBackend
 from ray_tpu.core.object_ref import ObjectRef
+from ray_tpu.util import lifecycle
 
 
 class _TaskContext(threading.local):
@@ -188,31 +189,46 @@ def init(address: Optional[str] = None, *,
         if ignore_reinit_error:
             return RuntimeInfo(w)
         raise RuntimeError("ray_tpu.init() called twice; pass ignore_reinit_error=True")
-    if _system_config:
-        import ray_tpu._private.config as cfgmod
+    with lifecycle.span("init"):
+        if _system_config:
+            import ray_tpu._private.config as cfgmod
 
-        cfg = cfgmod.get_config()
-        for k, v in _system_config.items():
-            setattr(cfg, k, v)
-    job_id = JobID.from_random()
-    if local_mode or address == "local":
-        from ray_tpu.core.local_backend import LocalBackend
+            cfg = cfgmod.get_config()
+            for k, v in _system_config.items():
+                setattr(cfg, k, v)
+        job_id = JobID.from_random()
+        if local_mode or address == "local":
+            from ray_tpu.core.local_backend import LocalBackend
 
-        backend = LocalBackend(job_id, num_cpus=num_cpus, num_tpus=num_tpus,
-                               resources_override=resources, namespace=namespace)
-        w.connect(backend, job_id, "local")
+            backend = LocalBackend(job_id, num_cpus=num_cpus,
+                                   num_tpus=num_tpus,
+                                   resources_override=resources,
+                                   namespace=namespace)
+            w.connect(backend, job_id, "local")
+            return RuntimeInfo(w)
+        from ray_tpu.cluster.driver_backend import start_or_connect
+
+        backend = start_or_connect(address, job_id, num_cpus=num_cpus,
+                                   num_tpus=num_tpus, resources=resources,
+                                   namespace=namespace)
+        w.connect(backend, job_id, "driver")
         return RuntimeInfo(w)
-    from ray_tpu.cluster.driver_backend import start_or_connect
-
-    backend = start_or_connect(address, job_id, num_cpus=num_cpus,
-                               num_tpus=num_tpus, resources=resources,
-                               namespace=namespace)
-    w.connect(backend, job_id, "driver")
-    return RuntimeInfo(w)
 
 
 def shutdown() -> None:
-    _global_worker.disconnect()
+    """Disconnect, and where this process started the cluster, stop it:
+    returns when the lifecycle record's rows say no process it spawned is
+    left, and says one ``rt-shutdown`` line on stderr if any had to be
+    killed or was left (``ray_tpu.util.lifecycle.last_shutdown()``)."""
+    w = _global_worker
+    if not w.connected:
+        w.disconnect()
+        return
+    owns_cluster = getattr(w.backend, "_cluster_shutdown_hook", None) is not None
+    with lifecycle.span("shutdown"):
+        w.disconnect()
+    if owns_cluster:
+        lifecycle.close_shutdown()
 
 
 def is_initialized() -> bool:

@@ -70,6 +70,7 @@ from ray_tpu.models import llama
 from ray_tpu.ops import ssm
 from ray_tpu.util import engine_recorder as _rec
 from ray_tpu.util import hlo_copies
+from ray_tpu.util import lifecycle
 from ray_tpu.util import prefix_hash as PH
 from ray_tpu.util.recorder_core import span as _span
 
@@ -365,7 +366,8 @@ class ContinuousBatcher:
             _refuse_prefix_cache(cfg)
         # ONE tree of buffers, each donated to every compiled call and the
         # whole tree rebound from its result (``_donating``, ``_launch``)
-        self._cache = self._zero_cache()
+        with lifecycle.span("cache_alloc", parent="engine_init"):
+            self._cache = jax.block_until_ready(self._zero_cache())
         self._free: List[int] = list(range(max_slots))
         self._active: Dict[int, _Request] = {}  # slot -> request
         self._cur = np.zeros(max_slots, np.int32)   # token AT pos, per slot
@@ -944,18 +946,23 @@ class ContinuousEngine:
             kv_cache_bytes = int(os.environ.get("RT_KV_CACHE_BYTES", "0"))
         cache = (PrefixKVCache(max_bytes=kv_cache_bytes, label=kv_label)
                  if kv_cache_bytes > 0 else None)
-        self._batcher = ContinuousBatcher(params, cfg, max_slots=max_slots,
-                                          max_len=max_len,
-                                          prefix_cache=cache,
-                                          sampling=sampling)
         self.decode_stride = max(1, int(decode_stride))
-        if warmup:
-            # pay every decode-program compile HERE (replica init — the
-            # controller's readiness probe covers it) instead of at the
-            # first request of each occupancy level
-            self._batcher.warmup(
-                strides=(1, self.decode_stride) if self.decode_stride > 1
-                else (1,))
+        # the engine's share of a replica's set-up on the lifecycle record:
+        # the slot tree's allocation and one span per decode program
+        with lifecycle.span("engine_init", max_slots=max_slots,
+                            max_len=max_len):
+            self._batcher = ContinuousBatcher(params, cfg,
+                                              max_slots=max_slots,
+                                              max_len=max_len,
+                                              prefix_cache=cache,
+                                              sampling=sampling)
+            if warmup:
+                # pay every decode-program compile HERE (replica init — the
+                # controller's readiness probe covers it) instead of at the
+                # first request of each occupancy level
+                self._batcher.warmup(
+                    strides=(1, self.decode_stride) if self.decode_stride > 1
+                    else (1,))
         self.max_slots = max_slots
         self.max_len = max_len
         self._on_tick = on_tick
@@ -1695,11 +1702,23 @@ def _decode_executable(cfg, bucket: int, max_slots: int, max_len: int,
         args += (jax.ShapeDtypeStruct((bucket,), jnp.float32), i32((bucket,)),
                  jax.ShapeDtypeStruct((bucket, 2), jnp.uint32))
     tree, leaves = weights
-    fn = _compiled_bucket_scan(cfg, bucket, max_slots, max_len, k,
-                               sample).lower(
-        jax.tree.unflatten(tree, leaves),
-        *(cache[name] for name in G.cache_names(cfg)), *args).compile()
-    return fn, dict(bucket=bucket, k=k, **hlo_copies.cache_traffic(
-        fn, cache, rows=bucket, steps=k, bounds=G.kv_read_bounds(max_len),
-        length_axis=cfg.kv_length_axis))
+    # the program's own seconds, by what they went on; they join its
+    # entry in ``decode_programs`` and its span in the lifecycle record
+    took: Dict[str, float] = {}
+    with lifecycle.span("decode_program", parent="engine_init",
+                        bucket=bucket, k=k) as whole:
+        with _span("lower_s", took):
+            lowered = _compiled_bucket_scan(cfg, bucket, max_slots, max_len,
+                                            k, sample).lower(
+                jax.tree.unflatten(tree, leaves),
+                *(cache[name] for name in G.cache_names(cfg)), *args)
+        with _span("compile_s", took):
+            fn = lowered.compile()
+        with _span("traffic_s", took):
+            traffic = hlo_copies.cache_traffic(
+                fn, cache, rows=bucket, steps=k,
+                bounds=G.kv_read_bounds(max_len),
+                length_axis=cfg.kv_length_axis)
+    whole.entry.update(took)
+    return fn, dict(bucket=bucket, k=k, **traffic, **took)
 

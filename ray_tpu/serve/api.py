@@ -17,6 +17,7 @@ from ray_tpu.serve.config import (AutoscalingConfig, DeploymentConfig,
                                   HTTPOptions)
 from ray_tpu.serve.controller import CONTROLLER_NAME, ServeController
 from ray_tpu.serve.handle import DeploymentHandle, _HandleMarker
+from ray_tpu.util import lifecycle
 
 _controller_lock = threading.Lock()
 _controller = None
@@ -195,21 +196,49 @@ def run(app: Application, *, name: str = "default",
     if not isinstance(app, Application):
         raise TypeError("serve.run() takes an Application "
                         "(deployment.bind(...))")
-    controller = _get_controller(create=True)
-    deployments: List[Dict] = []
-    ingress = _collect_graph(app, name, deployments)
-    ray_tpu.get(controller.deploy_application.remote(
-        name, route_prefix or "/", ingress, deployments))
-    if route_prefix is not None:
-        opts = http_options or HTTPOptions()
-        ray_tpu.get(controller.ensure_proxy.remote(
-            opts.host, opts.port, opts.num_proxies))
+    with lifecycle.span("serve_run", app=name) as whole:
+        with lifecycle.span("controller_start", parent="serve_run"):
+            controller = _get_controller(create=True)
+            deployments: List[Dict] = []
+            ingress = _collect_graph(app, name, deployments)
+            ray_tpu.get(controller.deploy_application.remote(
+                name, route_prefix or "/", ingress, deployments))
+        if route_prefix is not None:
+            with lifecycle.span("proxy_start", parent="serve_run"):
+                opts = http_options or HTTPOptions()
+                ray_tpu.get(controller.ensure_proxy.remote(
+                    opts.host, opts.port, opts.num_proxies))
+        if _blocking:
+            # a model replica initialises its device, builds its weights and
+            # compiles its programs in __init__: minutes at a published width
+            ray_tpu.get(
+                controller.wait_healthy.remote(name, _HEALTHY_TIMEOUT_S),
+                timeout=_HEALTHY_TIMEOUT_S + 20)
     if _blocking:
-        # a model replica initialises its device, builds its weights and
-        # compiles its programs in __init__: minutes at a published width
-        ray_tpu.get(controller.wait_healthy.remote(name, _HEALTHY_TIMEOUT_S),
-                    timeout=_HEALTHY_TIMEOUT_S + 20)
+        _note_replicas(name, whole.entry)
     return DeploymentHandle(name, ingress)
+
+
+def _note_replicas(app: str, serve_run: Dict[str, Any]) -> None:
+    """``serve_run``'s two children that nobody in this process timed, from
+    the replicas' rows in the lifecycle record (the raylet of a default
+    ``init()`` lives here; a remote node's rows do not, and the spans are
+    then absent): ``replica_start``, the controller asking for the first
+    replica until the last one's ``__init__`` returned, and
+    ``healthy_wait``, from there until ``serve.run`` returned: the
+    controller's reconcile taking the replica in, ``wait_healthy``'s poll,
+    the reply."""
+    rows = [r for r in lifecycle.processes()
+            if (r["label"] or "").startswith(f"RT_SERVE:{app}#")
+            and r["t_actor_init1"] is not None
+            and r["t_asked"] is not None and r["t_asked"] >= serve_run["t0"]]
+    if not rows:
+        return
+    ready = min(max(r["t_actor_init1"] for r in rows), serve_run["t1"])
+    lifecycle.record("replica_start", min(r["t_asked"] for r in rows), ready,
+                     parent="serve_run", pid=rows[-1]["pid"])
+    lifecycle.record("healthy_wait", ready, serve_run["t1"],
+                     parent="serve_run")
 
 
 def start(http_options: Optional[HTTPOptions] = None) -> None:
@@ -278,9 +307,14 @@ def shutdown() -> None:
         controller = _get_controller()
     except RuntimeError:
         return
-    try:
-        ray_tpu.get(controller.shutdown.remote(), timeout=30)
-        ray_tpu.kill(controller)
-    except Exception:  # noqa: BLE001 — already gone
-        pass
-    _forget_controller()
+    with lifecycle.span("serve_shutdown"):
+        try:
+            # the controller's reply is its own spans of the teardown:
+            # proxies_stop and replicas_stop
+            lifecycle.merge(
+                ray_tpu.get(controller.shutdown.remote(), timeout=30) or ())
+            with lifecycle.span("controller_stop", parent="serve_shutdown"):
+                ray_tpu.kill(controller)
+        except Exception:  # noqa: BLE001 — already gone
+            pass
+        _forget_controller()

@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import ray_tpu
 from ray_tpu.serve.config import AutoscalingConfig, DeploymentConfig
 from ray_tpu.serve.replica import ReplicaActor
+from ray_tpu.util import lifecycle
 
 CONTROLLER_NAME = "RT_SERVE_CONTROLLER"
 RECONCILE_PERIOD_S = 0.25
@@ -843,7 +844,10 @@ class ServeController:
         except Exception:  # noqa: BLE001
             pass
 
-    def shutdown(self) -> None:
+    def shutdown(self) -> List[Dict[str, Any]]:
+        """Tear everything down; the reply is this process's spans of it
+        (``replicas_stop``, ``proxies_stop``) for the caller's lifecycle
+        record."""
         self._shutdown = True
         try:
             # drop the status snapshot: doctor must not grade a dead
@@ -863,25 +867,30 @@ class ServeController:
         # proxy stop RPCs on purpose (see ensure_proxy)
         with self._proxy_boot_lock:
             self._deregister_proxies()
-            with self._lock:
-                for key in list(self._deployments):
-                    self._stop_deployment(self._deployments.pop(key))
-                self._apps.clear()
-                proxies, self._proxies = list(self._proxies), []
-                self._proxy = None
-                gproxy, self._grpc_proxy = self._grpc_proxy, None
-            for _, proxy, _ in proxies:
-                try:
-                    # rt: lint-allow(lock-discipline) shutdown stop RPC:
-                    # the boot lock is held on purpose (header comment)
-                    ray_tpu.get(proxy.stop.remote())
-                    ray_tpu.kill(proxy)
-                except Exception:  # noqa: BLE001
-                    pass
-            if gproxy is not None:
-                try:
-                    # rt: lint-allow(lock-discipline) same as above
-                    ray_tpu.get(gproxy.shutdown.remote())
-                    ray_tpu.kill(gproxy)
-                except Exception:  # noqa: BLE001
-                    pass
+            with lifecycle.span("replicas_stop",
+                                parent="serve_shutdown") as replicas:
+                with self._lock:
+                    for key in list(self._deployments):
+                        self._stop_deployment(self._deployments.pop(key))
+                    self._apps.clear()
+                    proxies, self._proxies = list(self._proxies), []
+                    self._proxy = None
+                    gproxy, self._grpc_proxy = self._grpc_proxy, None
+            with lifecycle.span("proxies_stop",
+                                parent="serve_shutdown") as stopped:
+                for _, proxy, _ in proxies:
+                    try:
+                        # rt: lint-allow(lock-discipline) shutdown stop RPC:
+                        # the boot lock is held on purpose (header comment)
+                        ray_tpu.get(proxy.stop.remote())
+                        ray_tpu.kill(proxy)
+                    except Exception:  # noqa: BLE001
+                        pass
+                if gproxy is not None:
+                    try:
+                        # rt: lint-allow(lock-discipline) same as above
+                        ray_tpu.get(gproxy.shutdown.remote())
+                        ray_tpu.kill(gproxy)
+                    except Exception:  # noqa: BLE001
+                        pass
+        return [replicas.entry, stopped.entry]

@@ -21,6 +21,7 @@ import ray_tpu
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import lifecycle
 
 DEFAULT_STORAGE = "/tmp/ray_tpu_results"
 
@@ -81,6 +82,7 @@ class JaxTrainer:
 
     # -- the fit loop ---------------------------------------------------------
     def fit(self) -> Result:
+        self._t_fit = time.time()
         name = self.run_config.name or f"JaxTrainer_{uuid.uuid4().hex[:8]}"
         storage = self.run_config.storage_path or DEFAULT_STORAGE
         run_dir = os.path.join(storage, name)
@@ -109,7 +111,8 @@ class JaxTrainer:
                        if hasattr(ds, "reset_streaming_split") else ds)
                 for name, ds in self.datasets.items()}
             group = WorkerGroup(self.scaling, name)
-            group.start()
+            with lifecycle.span("gang_placed", parent="trainer_start"):
+                group.start()
             try:
                 if self._dist_bootstrap and self.scaling.num_workers > 1:
                     group.run(self._dist_bootstrap,
@@ -187,6 +190,7 @@ class JaxTrainer:
                        if r["type"] == "report"]
             if reports:
                 rank0_report = reports[0][1]
+                self._note_first_launch(rank0_report.get("first_launch"))
                 metrics = dict(rank0_report["metrics"])
                 ckpt = rank0_report.get("checkpoint")
                 if ckpt is not None:
@@ -198,6 +202,20 @@ class JaxTrainer:
             active = [w for w, r in zip(active, round_results)
                       if r["type"] == "report"]
         return None
+
+
+    def _note_first_launch(self, first: Optional[Dict[str, float]]) -> None:
+        """``trainer_start`` and its child ``first_launch`` on the lifecycle
+        record, when rank 0's reply says its first launch has returned:
+        ``fit``'s entry until then, and from the gang's placing until then
+        (the worker's loop up to and with its first launch)."""
+        placed = lifecycle.last("gang_placed")
+        if first is None or placed is None:
+            return
+        lifecycle.record("first_launch", placed["t1"], first["t_done"],
+                         parent="trainer_start",
+                         compile_s=first["compile_s"])
+        lifecycle.record("trainer_start", self._t_fit, first["t_done"])
 
 
 class TorchTrainer(JaxTrainer):

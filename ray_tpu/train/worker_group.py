@@ -9,6 +9,7 @@ ScalingConfig (a slice group for multi-host TPU gangs).
 
 from __future__ import annotations
 
+import sys
 import threading
 import traceback
 from typing import Any, Callable, Dict, List, Optional
@@ -35,6 +36,7 @@ class TrainWorker:
         self.experiment_name = experiment_name
         self.session: Optional[TrainSession] = None
         self._thread: Optional[threading.Thread] = None
+        self._first_launch_told = False
 
     def ping(self) -> int:
         return self.rank
@@ -83,7 +85,25 @@ class TrainWorker:
             return {"type": "error", "message": repr(err),
                     "traceback": "".join(traceback.format_exception(
                         type(err), err, err.__traceback__))}
+        first = None if self._first_launch_told else self._first_launch()
+        if first is not None:
+            self._first_launch_told = True
+            item = {**item, "first_launch": first}
         return item
+
+    @staticmethod
+    def _first_launch() -> Optional[Dict[str, float]]:
+        """When the ``StepDriver``'s first launch returned, on this
+        process's clock, and what of it was the compile: the train
+        recorder's first record, told once, on the reply that was going
+        anyway (the trainer's ``first_launch`` span)."""
+        recorders = sys.modules.get("ray_tpu.util.train_recorder")
+        for rec in (recorders.live_recorders() if recorders else ()):
+            for launch in rec.launches()[:1]:
+                if launch["seq"] == 1:
+                    return {"t_done": launch["t_dispatch_end"],
+                            "compile_s": launch["phases"]["compile"]}
+        return None
 
     def shutdown(self) -> None:
         session_mod.clear_session()
