@@ -29,8 +29,8 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import mha
 from ray_tpu.ops.norms import layernorm, rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_angles
-from ray_tpu.parallel.sharding import ShardingRules
-from jax.sharding import PartitionSpec as P
+from ray_tpu.parallel.sharding import ShardingRules, axes_size
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 Params = Dict[str, Any]
 
@@ -374,11 +374,17 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg: LlamaConfig) -> ja
     HBM holds one [B, chunk, V] fp32 slice instead of [B, S, V] plus its
     cotangent — the logits, not the activations, are what cap batch size at
     32k vocab. Extra cost: the head matmul is recomputed in backward (~3% of
-    step FLOPs at 410M scale).
+    step FLOPs at 410M scale). Under a mesh that shards the head's model
+    dim the loop closes over a head gathered once before it
+    (``head_for_loss_loop``): no chunk, forward or recomputed, moves the
+    head, and its gradient crosses the chips once after the loop.
     """
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x, head = forward_hidden(params, inputs, cfg, batch.get("segment_ids"))
+    head = head_for_loss_loop(
+        head, sharding_rules(cfg.pipeline_axis is not None), cfg,
+        targets.shape[1])
     return chunked_ce(x, head, targets, batch.get("loss_mask"),
                       cfg.loss_chunk)
 
@@ -442,12 +448,66 @@ def lm_loss_and_grads_1f1b(params: Params, batch: Dict[str, jax.Array],
     return loss, grads
 
 
+def _loss_chunks(S: int, chunk: int) -> int:
+    """How many chunks ``chunked_ce``'s loop makes of S positions; 0 where
+    it runs no loop."""
+    return S // chunk if chunk and S % chunk == 0 and S > chunk else 0
+
+
+def head_for_loss_loop(head: jax.Array, rules: ShardingRules, cfg: Any,
+                       S: int) -> jax.Array:
+    """``head`` [d, V], in the compute dtype, as ``chunked_ce``'s loop
+    should close over it: whole along d on every chip, V left on the axes
+    the family's ``rules`` give it.
+
+    The rules store the head with d over ``fsdp``. Left so, the product
+    ``xc @ head`` inside the loop's fully rematted body makes GSPMD gather
+    the head in every chunk, forward and recomputed, and reduce-scatter its
+    gradient chunk by chunk: at ``[4096, 32000]`` over ``fsdp 4`` with 16
+    chunks, 48 collectives of 262 MB a step where 2 do. Constrained here,
+    before the loop, the gather happens once; the constraint's transpose is
+    the same constraint, so the head's cotangent accumulates whole per chip
+    in the backward loop's carry and is summed over the chips once after it.
+
+    What tells is what the step can observe: the ambient mesh and the rule
+    that places the head. With no mesh, no loop, every axis on d of size 1
+    or a head the rules replicate (the pipelined ones, whose 1f1b loss runs
+    inside a ``shard_map`` where a constraint on mesh axes is an error),
+    the head comes back as it came and the program is what it was.
+
+    Cost: ``d * V / tp`` elements of the compute dtype a chip for the
+    gathered head and as much for its cotangent's carry (262 MB each at
+    4096 x 32000 bf16). A head too large for that (a 256k vocabulary)
+    wants a loop over vocabulary shards with the log-sum-exp reduced
+    instead, which nothing here builds.
+    """
+    from ray_tpu.parallel.context import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or not _loss_chunks(S, cfg.loss_chunk):
+        return head
+    # a tied head is the embedding [V, d] read the other way round; a spec
+    # names no axis for the dimensions it leaves out
+    leaf, shape = (("embed", head.shape[::-1]) if cfg.tie_embeddings
+                   else ("lm_head", head.shape))
+    axes = (tuple(rules.spec_for(leaf, shape, mesh)) + (None, None))[:2]
+    d_axes, v_axes = axes[::-1] if cfg.tie_embeddings else axes
+    if axes_size(d_axes, mesh) == 1:
+        return head
+    return jax.lax.with_sharding_constraint(
+        head, NamedSharding(mesh, P(None, v_axes)))
+
+
 def chunked_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
                mask: Optional[jax.Array], chunk: int) -> jax.Array:
-    """Cross entropy from final hiddens; shared by every model family."""
+    """Cross entropy from final hiddens; shared by every model family.
+
+    Knows no mesh: the loop's body multiplies by ``head`` as it is handed
+    in, in every chunk and again in the rematted backward, so a caller
+    under a mesh passes it through ``head_for_loss_loop`` first."""
     S = targets.shape[1]
-    if chunk and S % chunk == 0 and S > chunk:
-        n_chunks = S // chunk
+    n_chunks = _loss_chunks(S, chunk)
+    if n_chunks:
         xs = x.reshape(x.shape[0], n_chunks, chunk, -1).swapaxes(0, 1)
         ts = targets.reshape(targets.shape[0], n_chunks, chunk).swapaxes(0, 1)
         ms = (jnp.ones_like(ts, jnp.float32) if mask is None

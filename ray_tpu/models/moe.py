@@ -315,11 +315,14 @@ def forward(params: Params, tokens: jax.Array, cfg: MoEConfig,
 
 def lm_loss(params: Params, batch: Dict[str, jax.Array],
             cfg: MoEConfig) -> jax.Array:
-    """Next-token CE + router aux loss (llama's chunked CE reused)."""
+    """Next-token CE + router aux loss (llama's chunked CE reused, its
+    loop's head gathered once before it under a mesh)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x, head, aux = forward_hidden(params, inputs, cfg,
                                   batch.get("segment_ids"))
+    head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
+                                    targets.shape[1])
     ce = llama.chunked_ce(x, head, targets, batch.get("loss_mask"),
                           cfg.loss_chunk)
     return ce + cfg.router_aux_coef * aux

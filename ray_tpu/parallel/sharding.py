@@ -29,15 +29,19 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
+def axes_size(axes: Union[None, str, Tuple[str, ...]], mesh: Mesh) -> int:
+    """Over how many devices one dimension's entry of a spec (None, an axis
+    name or a tuple of them) splits that dimension on ``mesh``."""
+    names = () if axes is None else (
+        axes if isinstance(axes, tuple) else (axes,))
+    return math.prod(mesh.shape.get(name, 1) for name in names)
+
+
 def _divides(spec: P, shape: Sequence[int], mesh: Mesh) -> bool:
     """Whether every dimension of ``shape`` that ``spec`` shards splits
     evenly over the mesh axes it names."""
-    for dim, axes in zip(shape, spec):
-        names = () if axes is None else (
-            axes if isinstance(axes, tuple) else (axes,))
-        if dim % math.prod(mesh.shape.get(name, 1) for name in names):
-            return False
-    return True
+    return not any(dim % axes_size(axes, mesh)
+                   for dim, axes in zip(shape, spec))
 
 
 class ShardingRules:

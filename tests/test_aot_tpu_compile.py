@@ -14,6 +14,7 @@ The file's name sorts first so that tier-1 reaches it inside its time limit.
 import dataclasses
 import math
 import os
+import re
 import subprocess
 import sys
 
@@ -501,27 +502,43 @@ def test_decode_reads_below_the_bound_on_v5e(topo, family, bucket):
                     and "router" not in line], "a weight stack is copied"
 
 
-def test_sharded_flash_step_compiles_for_four_chips(topo):
-    """fsdp x tp over four described chips, "1b" widths, depth cut to two
-    layers for the test's time. The TPU compiler does not partition a Mosaic
-    kernel; ``flash_attention_on_mesh`` runs it per shard, and the fused-K
-    step must hold both the kernel and the collectives."""
-    cfg = dataclasses.replace(CFG_1B, param_dtype=jnp.bfloat16,
-                              attn_impl="flash", loss_chunk=256, n_layers=2)
-    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), topo.devices)
+def _compile_fused_step(fam, cfg, mesh, k, batch, seq):
+    """The fused-K train step of ``cfg`` compiled for ``mesh``'s described
+    chips: (plan, parameter shardings, compiled). Module fixtures are set up
+    before the function-scoped ``compiled_kernel``, so the Mosaic kernel is
+    asked for here too."""
     opt = ts.default_optimizer(total_steps=100)
     plan = compile_plan(cfg, mesh)
     p_sh, o_sh = plan.state_shardings(opt)
-    p_abs = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
+    p_abs = jax.eval_shape(lambda: fam.init_params(jax.random.key(0), cfg))
     o_abs = jax.eval_shape(opt.init, p_abs)
-    k = 2
-    batch = {"tokens": jax.ShapeDtypeStruct(
-        (k, 2, 2049), jnp.int32, sharding=plan.batch_sharding(3, False, True))}
+    tokens = {"tokens": jax.ShapeDtypeStruct(
+        (k, batch, seq + 1), jnp.int32,
+        sharding=plan.batch_sharding(3, False, True))}
     multi = ts.make_multi_step(cfg, opt, k, mesh=mesh, plan=plan)
-    with mesh_scope(mesh):
-        compiled = multi._jit.lower(_as_sharded(p_abs, p_sh),
-                                    _as_sharded(o_abs, o_sh), batch).compile()
-    text = compiled.as_text()
+    with pytest.MonkeyPatch.context() as mp, mesh_scope(mesh):
+        mp.setattr(flash, "_needs_interpret", lambda: False)
+        return plan, p_sh, multi._jit.lower(
+            _as_sharded(p_abs, p_sh), _as_sharded(o_abs, o_sh),
+            tokens).compile()
+
+
+@pytest.fixture(scope="module")
+def flash_step(topo):
+    """The fused-K step over fsdp x tp on four described chips, "1b" widths,
+    depth cut to two layers for the test's time: (cfg, K, compiled)."""
+    cfg = dataclasses.replace(CFG_1B, param_dtype=jnp.bfloat16,
+                              attn_impl="flash", loss_chunk=256, n_layers=2)
+    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), topo.devices)
+    k = 2
+    return cfg, k, _compile_fused_step(llama, cfg, mesh, k, 2, 2048)[2]
+
+
+def test_sharded_flash_step_compiles_for_four_chips(flash_step):
+    """The TPU compiler does not partition a Mosaic kernel;
+    ``flash_attention_on_mesh`` runs it per shard, and the fused-K step
+    must hold both the kernel and the collectives."""
+    text = flash_step[2].as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text and "all-reduce" in text
 
@@ -535,30 +552,30 @@ CFG_MIXTRAL = moe.MoEConfig(
     n_experts=8, top_k=2, capacity_factor=1.25, router_aux_coef=0.02)
 
 
-def test_mixtral_step_keeps_its_experts_rows_on_their_chip(topo, capsys):
-    """b4 x s4096, K=2 over ``fsdp 4``: eight experts split four ways, so a
-    chip owns two whole experts and contracts the whole model dim. No
-    collective of the compiled step is then an ``[E, C, f]`` buffer (under
-    fsdp on the model dim there were five, 1.17 GB each: every chip's
-    partial products summed onto every chip) and none completes a product
-    of ``moe_experts``; what crosses chips in the layer is ``[E, C, d]``."""
-    cfg, k, batch, seq = CFG_MIXTRAL, 2, 4, 4096
+@pytest.fixture(scope="module")
+def mixtral_step(topo):
+    """``CFG_MIXTRAL``, b4 x s4096, K=2 over ``fsdp 4``, as the four-chip
+    cell runs it: (K, batch, seq, compiled)."""
+    k, batch, seq = 2, 4, 4096
     mesh, _ = ts.auto_mesh(4, topo.devices, tp=1)
-    opt = ts.default_optimizer(total_steps=100)
-    plan = compile_plan(cfg, mesh)
+    plan, p_sh, compiled = _compile_fused_step(moe, CFG_MIXTRAL, mesh, k,
+                                               batch, seq)
     assert plan.expert_placement() == "expert"
-    p_sh, o_sh = plan.state_shardings(opt)
     assert "fsdp" in p_sh["layers"]["e_gate"].spec[1]
     assert p_sh["layers"]["e_gate"].spec[2] is None
-    p_abs = jax.eval_shape(lambda: moe.init_params(jax.random.key(0), cfg))
-    o_abs = jax.eval_shape(opt.init, p_abs)
-    tokens = {"tokens": jax.ShapeDtypeStruct(
-        (k, batch, seq + 1), jnp.int32,
-        sharding=plan.batch_sharding(3, False, True))}
-    multi = ts.make_multi_step(cfg, opt, k, mesh=mesh, plan=plan)
-    with mesh_scope(mesh):
-        compiled = multi._jit.lower(_as_sharded(p_abs, p_sh),
-                                    _as_sharded(o_abs, o_sh), tokens).compile()
+    return k, batch, seq, compiled
+
+
+def test_mixtral_step_keeps_its_experts_rows_on_their_chip(mixtral_step,
+                                                           capsys):
+    """Eight experts split four ways, so a chip owns two whole experts and
+    contracts the whole model dim. No collective of the compiled step is
+    then an ``[E, C, f]`` buffer (under fsdp on the model dim there were
+    five, 1.17 GB each: every chip's partial products summed onto every
+    chip) and none completes a product of ``moe_experts``; what crosses
+    chips in the layer is ``[E, C, d]``."""
+    cfg = CFG_MIXTRAL
+    _, batch, seq, compiled = mixtral_step
     E = cfg.n_experts
     C = int(cfg.capacity_factor * batch * seq * cfg.top_k / E)
     found = hlo_copies.collectives(compiled)
@@ -572,6 +589,59 @@ def test_mixtral_step_keeps_its_experts_rows_on_their_chip(topo, capsys):
         for kind, n in hlo_copies.collective_inventory(compiled).items():
             print(f"  {kind}: {n['count']} ({n['runs']} runs), "
                   f"{n['bytes'] / 1e9:.2f} GB of results")
+
+
+def _head_collectives(compiled, dims):
+    """The collectives of a compiled step whose result holds the loss's
+    head at ``dims``, one per channel: where the TPU compiler makes a
+    collective asynchronous, its start, continuation and done fusions each
+    spell the instruction out under the one ``channel_id``, and
+    ``hlo_copies.collectives`` lists all three."""
+    text = compiled.as_text()
+    by_channel = {}
+    for c in hlo_copies.collectives(compiled):
+        if any(d == dims for _, d in c["arrays"]):
+            channel = re.search(
+                rf"%{re.escape(c['name'])} = .*?channel_id=(\d+)", text)
+            by_channel.setdefault(channel.group(1) if channel else c["name"],
+                                  c)
+    return list(by_channel.values())
+
+
+@pytest.mark.parametrize("step", ["mixtral-fsdp4", "1b-fsdp2-tp2"])
+def test_the_loss_gathers_its_head_once_a_step(step, request, capsys):
+    """``chunked_ce``'s loop closes over a head whole along d
+    (``llama.head_for_loss_loop``): the head is gathered once a step before
+    the loop and its gradient summed over the chips once after it, V left
+    on ``tp``. At most 3 collectives a step hold the head and none runs per
+    chunk. Fails on the parent of the change that brought it: there the
+    Mixtral step gathers ``[4096, 32000]`` 16 times forward and 16 times in
+    the rematted backward a step (64 runs a launch of 2, and 32 more of
+    the gradient's ``[1024, 32000]`` reduce-scatter); the ``fsdp 2 x tp 2``
+    one gathers ``[2048, 16000]`` 8 + 8 times a step (32 a launch)."""
+    if step == "mixtral-fsdp4":
+        k, _, seq, compiled = request.getfixturevalue("mixtral_step")
+        cfg, tp = CFG_MIXTRAL, 1
+    else:
+        cfg, k, compiled = request.getfixturevalue("flash_step")
+        seq, tp = 2048, 2
+    chunks = seq // cfg.loss_chunk
+    found = _head_collectives(compiled, (cfg.d_model, cfg.vocab_size // tp))
+    with capsys.disabled():
+        print(f"\n{step}: collectives that hold the head, a launch of {k}:")
+        for c in found:
+            print(f"  {c['kind']} {c['arrays']} x{c['runs']} {c['op_name']}")
+    assert found and {c["kind"] for c in found} >= {"all-gather"}, found
+    assert sum(c["runs"] for c in found) <= 3 * k, found
+    for c in found:
+        assert c["runs"] % (chunks * k), c
+    # nor does anything else as wide as the vocabulary cross chips per chunk
+    # (the parent's gradient, reduce-scattered as [d / fsdp, V], and the
+    # chunk's [b, 256, V] logits' cotangent, gathered for it)
+    for c in hlo_copies.collectives(compiled):
+        for _, dims in c["arrays"]:
+            if len(dims) > 1 and dims[-1] == cfg.vocab_size // tp:
+                assert c["runs"] % (chunks * k), c
 
 
 def test_libtpu_accepts_the_perf_flags():
