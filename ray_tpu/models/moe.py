@@ -1,17 +1,22 @@
 """Mixtral-style sparse-MoE transformer — the second flagship model family.
 
 TPU-first design (no reference counterpart — Ray ships no model code; the
-recipe is the public GShard/Switch einsum formulation): the router performs
+recipe is the public GShard/Switch capacity formulation): the router performs
 STATIC top-k capacity dispatch, so every tensor shape is fixed at trace
-time and XLA tiles the expert FFNs onto the MXU as one batched einsum.
-Where ``n_experts`` splits evenly over the mesh's ``ep`` x ``fsdp`` a
-device owns whole experts (``sharding_rules``): the dispatch einsum sums
-each device's tokens into the owner's ``[E, C, d]`` rows (a reduce-scatter
-over ICI), the combine einsum gathers them back, and nothing ``[E, C, f]``
-crosses devices. Where it does not, experts go over ``ep`` alone and fsdp
-splits the model dim, which costs an all-reduce of every ``[E, C, f]``
-product. Attention blocks, RoPE, norms and the chunked loss are shared with
-:mod:`ray_tpu.models.llama`.
+time and XLA tiles the expert FFNs onto the MXU as one batched einsum over
+the ``[E, C, d]`` capacity buffers. The routed rows move into and out of
+those buffers by index (``_dispatch``, ``_combine``: a slot reads its
+token's row, a token sums its K slots' rows, and each move's backward is the
+other move); no tensor is ``[G, E, C]``. Where ``n_experts`` splits evenly
+over the mesh's ``ep`` x ``fsdp`` a device owns whole experts
+(``sharding_rules``): the tokens ``[G, d]`` are all-gathered to the
+experts' owners, each of which reads the rows of its own slots; back, each
+owner reads for every token what its own experts hold and the partial
+``[G, d]`` outputs are reduce-scattered over the tokens' axes; nothing
+``[E, C, .]`` crosses devices. Where it does not, experts go over ``ep``
+alone and fsdp splits the model dim, which costs an all-reduce of every
+``[E, C, f]`` product. Attention blocks, RoPE, norms and the chunked loss
+are shared with :mod:`ray_tpu.models.llama`.
 
 Routing (per token): softmax router logits -> top-k experts -> each chosen
 token takes a slot in its expert's capacity buffer
@@ -24,6 +29,7 @@ Load-balancing aux loss: ``n_experts * sum_e(fraction_e * prob_e)``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -104,15 +110,161 @@ def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     return base
 
 
+def _fill_take(rows: jax.Array, index: jax.Array) -> jax.Array:
+    """``rows[index]`` along the first dimension; an index past the end
+    reads a row of zeros."""
+    return jnp.take(rows, index, axis=0, mode="fill", fill_value=0)
+
+
+def _row_placement(cfg: MoEConfig, G: int):
+    """Where the routed rows live under the ambient mesh: ``(mesh, token
+    axes, expert axes)``, the mesh axes of more than one device that split
+    the tokens' ``G`` and the experts' ``E``, the latter as
+    ``sharding_rules`` resolve ``layers/e_gate`` on this mesh. None with no
+    mesh, on one device, or where an axis does not split its size evenly:
+    the rows then move by plain ``take``s."""
+    from ray_tpu.parallel.context import current_mesh
+    from ray_tpu.parallel.sharding import BATCH_AXES, axes_size
+
+    mesh = current_mesh()
+    if mesh is None:
+        return None
+    e_axes = sharding_rules().spec_for(
+        "layers/e_gate",
+        (cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff), mesh)[1]
+    e_axes = e_axes if isinstance(e_axes, tuple) else (e_axes,)
+    over, among = (tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+                   for axes in (BATCH_AXES, e_axes))
+    if (not over + among or G % axes_size(over, mesh)
+            or cfg.n_experts % axes_size(among, mesh)):
+        return None
+    return mesh, over, among
+
+
+def _rows_to_slots(place, x: jax.Array, token: jax.Array) -> jax.Array:
+    """x [G, d], token [E, C] -> [E, C, d]: every capacity slot reads its
+    token's row, an empty one (``token == G``) zeros. Under a mesh the
+    tokens are gathered to the experts' owners (one all-gather of
+    ``[G, d]``) and each chip reads the rows of its own experts' slots."""
+    if place is None:
+        return _fill_take(x, token)
+    mesh, over, among = place
+
+    def local(x, token):
+        if over:
+            x = jax.lax.all_gather(x, over, axis=0, tiled=True)
+        return _fill_take(x, token)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(over or None), P(among or None)),
+        out_specs=P(among or None), check_vma=False)(x, token)
+
+
+def _rows_to_tokens(place, y: jax.Array, w: jax.Array, dest: jax.Array
+                    ) -> jax.Array:
+    """y [E, C, d], w and dest [G, K] -> [G, d]: every token sums the rows
+    of its K slots weighted by ``w``; a dropped assignment (``dest >=
+    E * C``) reads zeros. Under a mesh a chip reads what its own experts
+    hold (another chip's rows are zeros) for the tokens of the chips it
+    shares the experts out with, and the partial ``[G, d]`` is
+    reduce-scattered back over the tokens' axes."""
+    E, C, d = y.shape
+
+    def weighted(rows, w, at):
+        # read as [K, G, d]: with K next to d the chip lays it on the
+        # sublanes, two to a tile, and the read takes three times as long
+        return jnp.einsum("kg,kgd->gd", w.T, _fill_take(rows, at.T))
+
+    if place is None:
+        return weighted(y.reshape(E * C, d), w, dest)
+    mesh, over, among = place
+    # the tokens' axes that also split the experts; the others (dp) hold
+    # every expert among themselves and keep their tokens to themselves
+    shared = tuple(a for a in over if a in among)
+    # (gathered below as one contiguous block of G: the tokens' innermost)
+    assert over[len(over) - len(shared):] == shared, (over, among)
+    summed = tuple(a for a in among if a not in shared)
+
+    def local(y, w, dest):
+        if shared:
+            w, dest = (jax.lax.all_gather(v, shared, axis=0, tiled=True)
+                       for v in (w, dest))
+        mine = y.shape[0] * C
+        at = dest - (jax.lax.axis_index(among) * mine if among else 0)
+        at = jnp.where((at >= 0) & (at < mine), at, mine)
+        part = weighted(y.reshape(mine, d), w, at)
+        if summed:
+            part = jax.lax.psum(part, summed)
+        if shared:
+            part = jax.lax.psum_scatter(part, shared, scatter_dimension=0,
+                                        tiled=True)
+        return part
+
+    tok = P(over or None)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(among or None), tok, tok),
+        out_specs=tok, check_vma=False)(y, w, dest)
+
+
+# Dispatch and combine are one partial permutation read from its two ends,
+# so each one's backward is the other. Left to autodiff, a gather's
+# transpose is a scatter-add of d-wide rows, which the TPU runs row by row.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(place, x, src, dest):
+    """Tokens' rows into the capacity slots. ``src`` [E, C] is each slot's
+    assignment ``g * K + k`` (``G * K``: empty), ``dest`` [G, K] each
+    assignment's slot ``e * C + c`` (``>= E * C``: dropped)."""
+    return _rows_to_slots(place, x, src // dest.shape[1])
+
+
+def _dispatch_fwd(place, x, src, dest):
+    return _dispatch(place, x, src, dest), dest
+
+
+def _dispatch_bwd(place, dest, ct):
+    kept = (dest < ct.shape[0] * ct.shape[1]).astype(ct.dtype)
+    return _rows_to_tokens(place, ct, kept, dest), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(place, y, w, src, dest):
+    """Slots' rows back to their tokens, weighted by the gates ``w``
+    [G, K]; ``src`` and ``dest`` as in ``_dispatch``."""
+    return _rows_to_tokens(place, y, w, dest)
+
+
+def _combine_fwd(place, y, w, src, dest):
+    return _combine(place, y, w, src, dest), (y, w, src, dest)
+
+
+def _combine_bwd(place, res, ct):
+    y, w, src, dest = res
+    rows = _rows_to_slots(place, ct, src // w.shape[1])           # [E, C, d]
+    slot_w = _fill_take(w.reshape(-1), src)                       # [E, C]
+    # a gate's cotangent is its slot's <row, cotangent>, summed in float32
+    dots = jnp.sum(rows.astype(jnp.float32) * y.astype(jnp.float32), axis=-1)
+    d_w = _fill_take(dots.reshape(-1), dest).astype(w.dtype)
+    return rows * slot_w[..., None], d_w, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
              ) -> Tuple[jax.Array, jax.Array]:
     """[B, S, d] -> ([B, S, d], aux_loss). Static-shape top-k capacity
-    dispatch (GShard einsum formulation)."""
+    dispatch: every shape is fixed at trace time, and the routed rows move
+    into and out of the ``[E, C, d]`` capacity buffers by index."""
     b, s, d = h.shape
     E, K = cfg.n_experts, cfg.top_k
     G = b * s
     C = max(1, int(cfg.capacity_factor * G * K / E))
     tokens = h.reshape(G, d)
+    place = _row_placement(cfg, G)
 
     # scopes are names only: they group the layer's operations in a
     # device trace and change nothing that is computed
@@ -135,15 +287,12 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
         gates = topk_probs * keep                                      # [G, K]
 
     with jax.named_scope("moe_dispatch"):
-        # dispatch/combine tensors [G, E, C]
-        slot_onehot = jax.nn.one_hot(slot, C, dtype=h.dtype)          # [G, K, C]
-        dispatch = jnp.einsum("gke,gkc->gec",
-                              sel_onehot.astype(h.dtype) * keep[..., None],
-                              slot_onehot)
-        combine = jnp.einsum("gke,gkc,gk->gec",
-                             sel_onehot.astype(h.dtype), slot_onehot,
-                             gates.astype(h.dtype))
-        expert_in = jnp.einsum("gd,gec->ecd", tokens, dispatch)       # [E, C, d]
+        # the routing from its two ends: an assignment's slot, a slot's
+        # assignment (no two kept assignments share a slot)
+        dest = jnp.where(keep, topk_idx * C + slot, E * C)            # [G, K]
+        src = jnp.full((E * C,), G * K, jnp.int32).at[dest.reshape(-1)].set(
+            jnp.arange(G * K, dtype=jnp.int32), mode="drop").reshape(E, C)
+        expert_in = _dispatch(place, tokens, src, dest)               # [E, C, d]
 
     with jax.named_scope("moe_experts"):
         gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
@@ -154,7 +303,7 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
                                 layer["e_down"].astype(h.dtype))
 
     with jax.named_scope("moe_combine"):
-        out = jnp.einsum("ecd,gec->gd", expert_out, combine)
+        out = _combine(place, expert_out, gates.astype(h.dtype), src, dest)
 
     with jax.named_scope("moe_router"):
         # Switch aux loss: balance token fraction vs router probability mass
@@ -333,8 +482,10 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
     ``tp``. Where ``n_experts`` splits evenly over ``ep`` x ``fsdp`` the
     expert dimension takes both axes and the model dim stays whole: a chip
     owns whole experts, its share of every ``[E, C, .]`` buffer is local,
-    and what crosses chips is the tokens' ``[E, C, d]`` rows on their way
-    to their experts and back. Where it does not (6 experts on ``fsdp``
+    and what crosses chips is ``[G, d]``: the tokens gathered to the
+    experts' owners, the owners' partial outputs reduce-scattered back
+    (``_rows_to_slots``, ``_rows_to_tokens``, which read this placement off
+    the ambient mesh). Where it does not (6 experts on ``fsdp``
     4), experts go over ``ep`` and fsdp shards the model dim like the
     dense path: the contraction over d is then split, and GSPMD all-reduces
     the partial ``[E, C, f]`` products. Which one a mesh gets is resolved

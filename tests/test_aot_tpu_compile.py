@@ -591,6 +591,56 @@ def test_mixtral_step_keeps_its_experts_rows_on_their_chip(mixtral_step,
                   f"{n['bytes'] / 1e9:.2f} GB of results")
 
 
+def test_mixtral_step_moves_its_rows_by_index(mixtral_step, capsys):
+    """Dispatch and combine are row gathers by index (``moe._dispatch``,
+    ``moe._combine``): the compiled step holds no ``[G, E, C]`` array, whole
+    or a chip's share, and no matrix product under ``moe_dispatch`` or
+    ``moe_combine``; what those scopes move across chips is the tokens
+    gathered to their experts' owners and the partial outputs
+    reduce-scattered back, never more than ``[G, d]`` (the ``[E, C, d]``
+    exchange is gone); and no ``d``-wide row is scattered: each move's
+    backward is the other move. Fails on the parent, where the rows moved
+    through ``gd,gec->ecd`` and ``ecd,gec->gd`` against one-hot tensors."""
+    cfg = CFG_MIXTRAL
+    _, batch, seq, compiled = mixtral_step
+    G, E, d = batch * seq, cfg.n_experts, cfg.d_model
+    C = int(cfg.capacity_factor * G * cfg.top_k / E)
+    scopes = ("moe_dispatch", "moe_combine")
+    for _, (name, shape, opcode, _, line), _ in hlo_copies._Module(
+            compiled.as_text()).walk(fusions=True):
+        arrays = hlo_copies._arrays(shape)
+        for _, dims in arrays:
+            assert math.prod(dims) not in (G * E * C, G // 4 * E * C), line[:300]
+        source = hlo_copies._OP_NAME.search(line)
+        if not (source and any(s in source.group(1) for s in scopes)):
+            continue
+        assert opcode not in ("dot", "convolution"), line[:300]
+        assert not (opcode == "fusion" and "convolution" in name), line[:300]
+        if opcode == "scatter":
+            assert all(dims[-1:] != (d,) for _, dims in arrays), line[:300]
+    every = hlo_copies.collectives(compiled)
+    found = [c for c in every if any(s in c["op_name"] for s in scopes)]
+    with capsys.disabled():
+        print("\nMixtral, 1 layer, fsdp 4, collectives under moe_dispatch and "
+              "moe_combine, a launch of 2 steps:")
+        for c in found:
+            if c["bytes"] >= 2 ** 20:  # (indices and gates are 0.1-0.3 MB)
+                print(f"  {c['kind']} {c['arrays']} x{c['runs']}, "
+                      f"{c['runs'] * c['bytes'] / 1e6:.1f} MB  {c['op_name']}")
+    # the tokens' gather: forward, rematted, and for combine's backward
+    # (the reduce-scatters back are merged with small ones by the compiler
+    # and lose their op_name: they are held by size below, with all others)
+    gathers = [c for c in found if c["kind"] == "all-gather"
+               and any(math.prod(dims) == G * d for _, dims in c["arrays"])]
+    assert len(gathers) == 3, gathers
+    for c in found:
+        for _, dims in c["arrays"]:
+            assert math.prod(dims) <= G * d, c
+    for c in every:
+        for _, dims in c["arrays"]:
+            assert math.prod(dims) not in (E * C * d, E * C * d // 4), c
+
+
 def _head_collectives(compiled, dims):
     """The collectives of a compiled step whose result holds the loss's
     head at ``dims``, one per channel: where the TPU compiler makes a

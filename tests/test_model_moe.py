@@ -79,6 +79,110 @@ def test_moe_capacity_overflow_drops_tokens(cfg):
     assert zero_rows >= 14  # ~1 slot served, rest dropped
 
 
+def _one_hot_moe_ffn(cfg, h, layer):
+    """``moe._moe_ffn`` as it was before the rows moved by index: dense
+    ``[G, E, C]`` dispatch and combine tensors and the rows through matrix
+    products against them (the GShard einsum formulation). Kept as the
+    reference the index form is held to; the router is the program's own
+    lines, so the two differ only in how the rows move."""
+    b, s, d = h.shape
+    E, K = cfg.n_experts, cfg.top_k
+    G = b * s
+    C = max(1, int(cfg.capacity_factor * G * K / E))
+    tokens = h.reshape(G, d)
+    logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topk_probs, topk_idx = jax.lax.top_k(probs, K)
+    if cfg.norm_topk_prob:
+        topk_probs = topk_probs / (topk_probs.sum(-1, keepdims=True) + 1e-9)
+    sel_onehot = jax.nn.one_hot(topk_idx, E, dtype=jnp.int32)         # [G, K, E]
+    flat = sel_onehot.transpose(1, 0, 2).reshape(K * G, E)
+    pos_flat = jnp.cumsum(flat, axis=0) - flat
+    pos = pos_flat.reshape(K, G, E).transpose(1, 0, 2)
+    slot = jnp.sum(pos * sel_onehot, axis=-1)                         # [G, K]
+    keep = slot < C
+    gates = topk_probs * keep
+    slot_onehot = jax.nn.one_hot(slot, C, dtype=h.dtype)              # [G, K, C]
+    dispatch = jnp.einsum("gke,gkc->gec",
+                          sel_onehot.astype(h.dtype) * keep[..., None],
+                          slot_onehot)
+    combine = jnp.einsum("gke,gkc,gk->gec", sel_onehot.astype(h.dtype),
+                         slot_onehot, gates.astype(h.dtype))
+    expert_in = jnp.einsum("gd,gec->ecd", tokens, dispatch)           # [E, C, d]
+    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
+                                  layer["e_gate"].astype(h.dtype)))
+    up = jnp.einsum("ecd,edf->ecf", expert_in, layer["e_up"].astype(h.dtype))
+    expert_out = jnp.einsum("ecf,efd->ecd", gate * up,
+                            layer["e_down"].astype(h.dtype))
+    out = jnp.einsum("ecd,gec->gd", expert_out, combine)
+    frac = jnp.mean(sel_onehot[:, 0, :].astype(jnp.float32), axis=0)
+    aux = E * jnp.sum(frac * jnp.mean(probs, axis=0))
+    return out.reshape(b, s, d), aux
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n_experts,top_k,capacity_factor,norm", [
+    (4, 2, 1.25, True),
+    (4, 2, 0.5, True),      # overflow in both choices
+    (4, 1, 1.0, False),     # Switch's: one gate, the softmax's own value
+                            # (renormalised it is 1 and its gradient noise)
+    (16, 8, 1.25, False),   # OLMoE's routing: top-8, the softmax's own gates
+], ids=["top2", "top2-overflow", "top1", "top8-of-16"])
+def test_rows_by_index_agree_with_the_one_hot_products(
+        cfg, dtype, n_experts, top_k, capacity_factor, norm):
+    """Moving the routed rows by index at the same capacity is the same
+    mathematics as the one-hot products: the same rows in the same slots,
+    the same drops, the same gates. ``aux`` and the experts' gradients are
+    the reference's to the bit, and so is the layer's output up to top-2 (a
+    one-hot product's output row has one nonzero term a slot, and both
+    forms sum a token's K terms in float32); the gradients of ``h`` and of
+    the router, where the two forms sum in different orders, agree to the
+    dtype's rounding."""
+    c = dataclasses.replace(cfg, n_layers=1, n_experts=n_experts, top_k=top_k,
+                            capacity_factor=capacity_factor,
+                            norm_topk_prob=norm, compute_dtype=dtype,
+                            param_dtype=dtype)
+    params = moe.init_params(jax.random.key(0), c)
+    layer = jax.tree_util.tree_map(lambda x: x[0], params["layers"])
+    h = jax.random.normal(jax.random.key(3), (2, 64, c.d_model), dtype)
+    weigh = jax.random.normal(jax.random.key(4), h.shape, jnp.float32)
+
+    def run(ffn):
+        def scalar(h, layer):
+            out, aux = ffn(c, h, layer)
+            return (out.astype(jnp.float32) * weigh).sum() + aux, (out, aux)
+
+        (_, (out, aux)), grads = jax.jit(jax.value_and_grad(
+            scalar, argnums=(0, 1), has_aux=True))(h, layer)
+        return out, aux, grads
+
+    want_out, want_aux, (want_h, want_layer) = run(_one_hot_moe_ffn)
+    out, aux, (got_h, got_layer) = run(moe._moe_ffn)
+    if capacity_factor < 1:  # the case does drop assignments
+        assert (np.abs(np.asarray(want_out, np.float32)).max(-1) == 0).any()
+    f32 = lambda x: np.asarray(x, np.float32)
+    eps = float(jnp.finfo(dtype).eps)
+
+    def close(got, want, name):
+        np.testing.assert_allclose(
+            f32(got), f32(want), rtol=0, err_msg=name,
+            atol=4 * eps * np.abs(f32(want)).max())
+
+    if top_k <= 2:  # two terms sum to the same whichever comes first
+        np.testing.assert_array_equal(f32(out), f32(want_out))
+    else:
+        close(out, want_out, "out")
+    assert float(aux) == float(want_aux)
+    for name in ("e_gate", "e_up", "e_down"):
+        np.testing.assert_array_equal(f32(got_layer[name]),
+                                      f32(want_layer[name]), err_msg=name)
+    close(got_h, want_h, "h")
+    close(got_layer["router"], want_layer["router"], "router")
+    # every weight the reference gives a gradient has one here
+    assert set(got_layer) == set(want_layer)
+
+
 def test_moe_aux_loss_prefers_balance(cfg):
     """Aux loss is minimal (=1) under a uniform router and larger under a
     collapsed one."""

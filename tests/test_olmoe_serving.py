@@ -310,7 +310,9 @@ CACHE = jax.ShapeDtypeStruct(
 # them on purpose takes its digest anew (PR 32: the in-place decode step
 # reckons ``generate.kv_read_bound`` from ``pos`` before its layer loop; at
 # this ``max_len`` the bound takes one value and the step lowers as before,
-# ``tests/test_hybrid_serving.py``).
+# ``tests/test_hybrid_serving.py``; PR 41: the two Mixtral programs, whose
+# ``_moe_ffn`` moves its routed rows by index where it multiplied by one-hot
+# tensors, held to the one-hot form by value in ``tests/test_model_moe.py``).
 PROGRAMS = {
     "dense_forward": ("1279e2a80f1d1bb7", lambda: (
         lambda p, t: llama.forward(p, t, DENSE),
@@ -318,10 +320,10 @@ PROGRAMS = {
     "dense_lm_loss": ("58b39139aec97150", lambda: (
         lambda p, t: llama.lm_loss(p, {"tokens": t}, DENSE),
         _shapes(llama.init_params, DENSE), TOKENS)),
-    "mixtral_forward": ("4be82bc60c899b2d", lambda: (
+    "mixtral_forward": ("b325c0cc911d56e5", lambda: (
         lambda p, t: moe.forward(p, t, SPARSE),
         _shapes(moe.init_params, SPARSE), TOKENS)),
-    "mixtral_lm_loss": ("84ac7b8aae592ed7", lambda: (
+    "mixtral_lm_loss": ("a6d6ed438232bb64", lambda: (
         lambda p, t: moe.lm_loss(p, {"tokens": t}, SPARSE),
         _shapes(moe.init_params, SPARSE), TOKENS)),
     "dense_decode_in_place": ("083088822a7edc17", lambda: (
