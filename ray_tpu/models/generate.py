@@ -96,13 +96,7 @@ def cache_names(cfg) -> Tuple[str, ...]:
             + (("ssm", "conv") if cfg.n_recurrent_layers else ()))
 
 
-def embed(params: Params, cfg, tokens: jax.Array) -> jax.Array:
-    """The tokens' embeddings in the compute dtype, times
-    ``embedding_multiplier`` where the config has one."""
-    x = params["embed"].astype(cfg.compute_dtype)[tokens]
-    if cfg.embedding_multiplier != 1.0:
-        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
-    return x
+embed = llama.embed
 
 
 def _qkv(cfg, x, layer, sin, cos, positions):

@@ -79,10 +79,21 @@ class LlamaConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # Trinity's (afmoe's) knobs, neutral by default and read by the training
+    # blocks only: a head size that is not ``d_model // n_heads``; QK-norm a
+    # head (one gain [head_dim] for q and one for k, after the split into
+    # heads, before RoPE) beside OLMoE's whole-projection form above; a
+    # sigmoid gate ``wg`` [d, heads * hd] of the normed input on the
+    # attention's output, before ``wo``; and a norm after each branch
+    # (``attn_post_norm``, ``mlp_post_norm``) beside the one before it
+    attn_head_dim: Optional[int] = None
+    qk_norm_head: bool = False
+    attn_gate: bool = False
+    sandwich_norm: bool = False
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
 
     @property
     def n_attention_layers(self) -> int:
@@ -111,15 +122,23 @@ class LlamaConfig:
 
     def qk_norm_params(self) -> int:
         """One layer's ``q_norm`` and ``k_norm`` weights (0 without them)."""
+        if self.qk_norm_head:
+            return 2 * self.head_dim
         return ((self.n_heads + self.n_kv_heads) * self.head_dim
                 if self.qk_norm else 0)
 
+    def attn_params(self) -> int:
+        """One layer's attention half: the four projections, the gate where
+        there is one, and the norms before, after and on q and k."""
+        d, q = self.d_model, self.n_heads * self.head_dim
+        return ((2 + self.attn_gate) * d * q
+                + 2 * d * self.n_kv_heads * self.head_dim
+                + (1 + self.sandwich_norm) * d + self.qk_norm_params())
+
     def num_params(self) -> int:
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
-        hd = self.head_dim
-        per_layer = (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                     + self.n_heads * hd * d + 3 * d * f + 2 * d
-                     + self.qk_norm_params())
+        per_layer = (self.attn_params() + 3 * d * f
+                     + (1 + self.sandwich_norm) * d)
         head = 0 if self.tie_embeddings else d * v
         return v * d + l * per_layer + d + head
 
@@ -169,9 +188,18 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
         },
         "final_norm": jnp.ones((d,), cfg.param_dtype),
     }
-    if cfg.qk_norm:
+    if cfg.qk_norm_head:
+        params["layers"]["q_norm"] = jnp.ones((L, hd), cfg.param_dtype)
+        params["layers"]["k_norm"] = jnp.ones((L, hd), cfg.param_dtype)
+    elif cfg.qk_norm:
         params["layers"]["q_norm"] = jnp.ones((L, hq * hd), cfg.param_dtype)
         params["layers"]["k_norm"] = jnp.ones((L, hkv * hd), cfg.param_dtype)
+    if cfg.attn_gate:
+        params["layers"]["wg"] = norm_init(
+            jax.random.fold_in(rng, 98), (L, d, hq * hd), d)
+    if cfg.sandwich_norm:
+        for name in ("attn_post_norm", "mlp_post_norm"):
+            params["layers"][name] = jnp.ones((L, d), cfg.param_dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = norm_init(jax.random.fold_in(rng, 99), (d, cfg.vocab_size), d)
     return params
@@ -185,16 +213,38 @@ def project_qk(cfg: LlamaConfig, h: jax.Array, layer: Params,
     by the training blocks and the cached ones (``generate._qkv``)."""
     cdt = cfg.compute_dtype
     y = h @ layer["w" + which].astype(cdt)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not cfg.qk_norm_head:
         y = rmsnorm(y, layer[which + "_norm"].astype(cdt), cfg.norm_eps)
     return y
 
 
+def embed(params: Params, cfg: LlamaConfig, tokens: jax.Array) -> jax.Array:
+    """The tokens' embeddings in the compute dtype, times
+    ``embedding_multiplier`` where the config has one."""
+    x = params["embed"].astype(cfg.compute_dtype)[tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def post_norm(cfg: LlamaConfig, branch: jax.Array, layer: Params, name: str
+              ) -> jax.Array:
+    """A branch on its way to the residual stream: through the RMSNorm
+    ``layer[name]`` where the config norms a branch's output too."""
+    if not cfg.sandwich_norm:
+        return branch
+    return rmsnorm(branch, layer[name].astype(cfg.compute_dtype), cfg.norm_eps)
+
+
 def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
                    sin: jax.Array, cos: jax.Array,
-                   segment_ids: Optional[jax.Array]) -> jax.Array:
+                   segment_ids: Optional[jax.Array], *,
+                   rotate: bool = True,
+                   window: Optional[int] = None) -> jax.Array:
     """Pre-norm attention + residual — shared by every model family
-    (llama's dense blocks, moe's expert blocks)."""
+    (llama's dense blocks, moe's expert blocks). ``rotate`` and ``window``
+    are the layer's kind where a model has more than one (``models/moe.py``:
+    rotated inside a band, or unrotated and full)."""
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.compute_dtype
@@ -203,8 +253,13 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     q = project_qk(cfg, h, layer, "q").reshape(b, s, hq, hd)
     k = project_qk(cfg, h, layer, "k").reshape(b, s, hkv, hd)
     v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    if cfg.qk_norm_head:
+        q = rmsnorm(q, layer["q_norm"].astype(cdt), cfg.norm_eps)
+        k = rmsnorm(k, layer["k_norm"].astype(cdt), cfg.norm_eps)
+    if rotate:
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+    banded = {} if window is None else {"window": window}
     if cfg.attn_impl != "xla" and segment_ids is not None:
         raise NotImplementedError(
             f"segment_ids (packed sequences) require attn_impl='xla'; "
@@ -213,6 +268,10 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     if cfg.attn_impl in ("ring", "ulysses"):
         from ray_tpu.parallel.context import sequence_parallel_attention
 
+        if window is not None:
+            raise NotImplementedError(
+                "a window over a sequence split across chips: the ring "
+                "would have to skip the hops below the band")
         attn = sequence_parallel_attention(q, k, v, impl=cfg.attn_impl,
                                            causal=True)
     elif cfg.attn_impl == "flash":
@@ -222,10 +281,14 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
         # a pipeline stage already runs per device inside its shard_map
         attend = (flash_attention if cfg.pipeline_axis is not None
                   else flash_attention_on_mesh)
-        attn = attend(q, k, v, causal=True)
+        attn = attend(q, k, v, causal=True, **banded)
     else:
-        attn = mha(q, k, v, causal=True, segment_ids=segment_ids)
-    return x + attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
+        attn = mha(q, k, v, causal=True, segment_ids=segment_ids, **banded)
+    attn = attn.reshape(b, s, hq * hd)
+    if cfg.attn_gate:
+        attn = attn * jax.nn.sigmoid(h @ layer["wg"].astype(cdt))
+    return x + post_norm(cfg, attn @ layer["wo"].astype(cdt), layer,
+                         "attn_post_norm")
 
 
 def pre_norm(cfg: LlamaConfig, x: jax.Array, layer: Params, name: str
@@ -258,7 +321,9 @@ def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
             cfg, mm(jax.nn.silu(mm(h, "w_gate")) * mm(h, "w_up"), "w_down"))
     gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
     up = h @ layer["w_up"].astype(cdt)
-    return x + on_residual(cfg, (gate * up) @ layer["w_down"].astype(cdt))
+    return x + on_residual(cfg, post_norm(
+        cfg, (gate * up) @ layer["w_down"].astype(cdt), layer,
+        "mlp_post_norm"))
 
 
 def on_residual(cfg: LlamaConfig, branch: jax.Array) -> jax.Array:
@@ -326,22 +391,27 @@ def _pipelined_layers(layers: Params, x: jax.Array, cfg: LlamaConfig,
         extras=segment_ids)
 
 
+def refuse_served_only(cfg: LlamaConfig) -> None:
+    """What no training block computes yet (the embedding's multiplier they
+    do: ``embed``)."""
+    if (not cfg.use_rope or cfg.attn_scale is not None
+            or cfg.n_recurrent_layers
+            or (cfg.residual_multiplier, cfg.logits_scaling) != (1.0, 1.0)):
+        raise NotImplementedError(
+            "the training blocks compute attention at 1/sqrt(head_dim) with "
+            "no multiplier on a branch or under the logits and no recurrent "
+            "layers; this config is served only (models/generate.py, "
+            "models/hybrid.py, models/sambay.py)")
+
+
 def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
                    segment_ids: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, jax.Array]:
     """tokens [batch, seq] -> (final-norm hidden [batch, seq, d], head [d, V]),
     both in compute dtype — callers project to logits (possibly chunked)."""
     cdt = cfg.compute_dtype
-    if (not cfg.use_rope or cfg.attn_scale is not None
-            or cfg.n_recurrent_layers
-            or (cfg.embedding_multiplier, cfg.residual_multiplier,
-                cfg.logits_scaling) != (1.0, 1.0, 1.0)):
-        raise NotImplementedError(
-            "the training blocks compute rotated attention at 1/sqrt(head_dim) "
-            "with no multipliers and no recurrent layers; this config is "
-            "served only (models/generate.py, models/hybrid.py, "
-            "models/sambay.py)")
-    x = params["embed"].astype(cdt)[tokens]
+    refuse_served_only(cfg)
+    x = embed(params, cfg, tokens)
     sin, cos = rope_angles(tokens.shape[1], cfg.head_dim, cfg.rope_theta, cdt)
 
     if cfg.pipeline_axis is not None:
@@ -387,6 +457,13 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg: LlamaConfig) -> ja
         targets.shape[1])
     return chunked_ce(x, head, targets, batch.get("loss_mask"),
                       cfg.loss_chunk)
+
+
+def loss_and_stats(params: Params, batch: Dict[str, jax.Array],
+                   cfg: LlamaConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``lm_loss`` in the form a train step takes of every family: the loss
+    and what the forward counted beside it, which here is nothing."""
+    return lm_loss(params, batch, cfg), {}
 
 
 def lm_loss_and_grads_1f1b(params: Params, batch: Dict[str, jax.Array],

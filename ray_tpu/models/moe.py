@@ -24,10 +24,28 @@ token takes a slot in its expert's capacity buffer
 overflow tokens drop that expert (standard Switch behavior — the residual
 stream carries them).
 Load-balancing aux loss: ``n_experts * sum_e(fraction_e * prob_e)``.
+
+The patterned form (``MoEConfig.layer_kinds`` set; Trinity's ``afmoe``):
+leading dense layers and then expert layers, each layer's attention one of
+``ATTN_KINDS`` (rotated inside a band, or unrotated and full), a sigmoid
+router whose selection adds a bias the gates do not see, a shared expert
+beside the routed ones, and a layer that may hold a share of its experts:
+``n_experts_held`` of ``n_experts``, the first ones, as one chip of an
+expert-parallel layer does. The router keeps its whole width and its K
+choices, capacity is reckoned from the whole count, the buffers are
+``[held, C, d]``, and an assignment to an expert that lives elsewhere is no
+drop and adds nothing here: what the absent experts would have added is
+left out (on one chip the layer runs without its exchange, and nothing
+stands in for the other chips). ``forward_hidden`` walks the dense segment
+layer by layer and the expert layers period by period (``_walk``), the
+kinds inside a period static; it also counts its routing
+(``ROUTING_COUNTERS``), and a step moves the selection bias by what it
+counted (``buffer_updates``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -42,6 +60,10 @@ from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
 
+#: a patterned config's kinds of attention layer: rotated queries and keys
+#: inside a band of ``sliding_window``, or no rotation and the whole past
+ATTN_KINDS = ("window", "full")
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(llama.LlamaConfig):
@@ -52,28 +74,95 @@ class MoEConfig(llama.LlamaConfig):
     # top-k gates divided by their sum (Mixtral's convention); off, they
     # are the softmax's own values (OLMoE's ``norm_topk_prob: false``)
     norm_topk_prob: bool = True
+    # ---- the patterned form (module docstring); neutral by default --------
+    # one of ATTN_KINDS per layer, the dense layers first; () is the old
+    # stack: every layer rotated and full, all of them expert layers
+    layer_kinds: Tuple[str, ...] = ()
+    sliding_window: Optional[int] = None
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0
+    # experts 0 .. n_experts_held-1 live here (None: all ``n_experts``)
+    n_experts_held: Optional[int] = None
+    # shared experts every token goes through, run as one SwiGLU of width
+    # ``n_shared_experts * d_ff``
+    n_shared_experts: int = 0
+    # "softmax" over the router's logits, or "sigmoid" of each
+    router_score: str = "softmax"
+    # a buffer ``router_bias`` [E] added to the scores for the selection
+    # only; it takes no gradient, and a step moves it toward the experts
+    # that saw fewer rows than their share (``buffer_updates``)
+    router_bias: bool = False
+    route_scale: float = 1.0
+    # the balancing term: "first_choice" (Switch: E * sum_e f_e P_e over
+    # first choices and the whole batch) or "sequence" (E/K * sum_e f_e P_e
+    # a sequence, f over all K choices, P the scores normalised to sum 1)
+    balance: str = "first_choice"
+
+    def __post_init__(self):
+        if self.balance == "first_choice" and self.experts_held != self.n_experts:
+            raise ValueError(
+                "balance 'first_choice' counts first choices over the experts "
+                "held here; with a share of them held use 'sequence'")
+        if not self.layer_kinds:
+            return
+        if (len(self.layer_kinds) != self.n_layers
+                or set(self.layer_kinds) - set(ATTN_KINDS)
+                or not 0 <= self.n_dense_layers < self.n_layers):
+            raise ValueError(
+                f"layer_kinds names {len(self.layer_kinds)} layers of kinds "
+                f"{sorted(set(self.layer_kinds))}; n_layers is "
+                f"{self.n_layers}, {self.n_dense_layers} of them dense, and "
+                f"the kinds are {ATTN_KINDS}")
+        if "window" in self.layer_kinds and not self.sliding_window:
+            raise ValueError("a window layer needs sliding_window")
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts if self.n_experts_held is None \
+            else self.n_experts_held
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    def period(self) -> Tuple[str, ...]:
+        """The shortest run of kinds that the expert layers repeat whole."""
+        kinds = self.layer_kinds[self.n_dense_layers:]
+        n = len(kinds)
+        return next(kinds[:p] for p in range(1, n + 1)
+                    if n % p == 0 and kinds[:p] * (n // p) == kinds)
+
+    def _ffn_params(self, experts: int) -> int:
+        """An expert layer's feed-forward half with ``experts`` routed
+        experts counted: them, the shared one, the router, its bias and the
+        bias's momentum, and the norms round the half."""
+        d, f = self.d_model, self.d_ff
+        return ((experts + self.n_shared_experts) * 3 * d * f
+                + d * self.n_experts + 2 * self.router_bias * self.n_experts
+                + (1 + self.sandwich_norm) * d)
+
+    def _params(self, experts: int) -> int:
+        d, v = self.d_model, self.vocab_size
+        dense = self.n_dense_layers * (
+            self.attn_params() + 3 * d * self.d_ff_dense
+            + (1 + self.sandwich_norm) * d)
+        sparse = self.n_expert_layers * (self.attn_params()
+                                         + self._ffn_params(experts))
+        head = 0 if self.tie_embeddings else d * v
+        return v * d + dense + sparse + d + head
 
     def num_params(self) -> int:
-        d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
-        hd = self.head_dim
-        attn = (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                + self.n_heads * hd * d)
-        moe = self.n_experts * 3 * d * f + d * self.n_experts  # experts+router
-        per_layer = attn + moe + 2 * d + self.qk_norm_params()
-        head = 0 if self.tie_embeddings else d * v
-        return v * d + l * per_layer + d + head
+        """Parameters held here (``n_experts_held`` experts a layer)."""
+        return self._params(self.experts_held)
 
     def active_params(self) -> int:
         """Params touched per token (top-k experts) — the FLOPs-relevant
-        count for MFU estimates."""
-        d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
-        hd = self.head_dim
-        attn = (d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-                + self.n_heads * hd * d)
-        moe = self.top_k * 3 * d * f + d * self.n_experts
-        head = 0 if self.tie_embeddings else d * v
-        return (v * d + l * (attn + moe + 2 * d + self.qk_norm_params())
-                + d + head)
+        count for MFU estimates. With a share of the experts held, the
+        ``top_k * held / n_experts`` a token visits here on average."""
+        d, f = self.d_model, self.d_ff
+        visits = self.top_k * self.experts_held / self.n_experts
+        return int(self._params(0)
+                   + self.n_expert_layers * visits * 3 * d * f)
 
 
 PRESETS: Dict[str, MoEConfig] = {
@@ -89,10 +178,26 @@ PRESETS: Dict[str, MoEConfig] = {
 }
 
 
+#: the selection bias's scale at initialisation: enough to change some
+#: selections of a sigmoid router's scores, which lie in (0, 1)
+ROUTER_BIAS_INIT = 1e-2
+#: the rule that moves it (``buffer_updates``): the largest step of one
+#: expert's bias, and the share of the last steps' movement a step keeps
+ROUTER_BIAS_RATE = 1e-2
+ROUTER_BIAS_MOMENTUM = 0.5
+
+
 def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
-    """Llama init plus stacked expert FFNs [L, E, ...] and routers."""
-    d, f, E, L = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_layers
-    base = llama.init_params(rng, cfg)
+    """Llama init plus stacked expert FFNs [L, E, ...] and routers; ``E``
+    the experts held here, the router ``n_experts`` wide. A patterned
+    config's leading dense layers are a llama stack of their own under
+    ``dense_layers``, its shared expert ``s_gate`` / ``s_up`` / ``s_down``
+    and its selection bias ``router_bias`` (with the momentum of its
+    movement, ``router_bias_m``) lie with the expert layers."""
+    d, f, E, L = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_expert_layers
+    H = cfg.experts_held
+    base = llama.init_params(rng, dataclasses.replace(
+        cfg, n_layers=L, layer_kinds=(), n_dense_layers=0))
     k = jax.random.fold_in(rng, 7)
     k1, k2, k3, k4 = jax.random.split(k, 4)
 
@@ -104,10 +209,60 @@ def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     for name in ("w_gate", "w_up", "w_down"):  # dense FFN -> experts
         del layers[name]
     layers["router"] = norm_init(k1, (L, d, E), d)
-    layers["e_gate"] = norm_init(k2, (L, E, d, f), d)
-    layers["e_up"] = norm_init(k3, (L, E, d, f), d)
-    layers["e_down"] = norm_init(k4, (L, E, f, d), f)
+    layers["e_gate"] = norm_init(k2, (L, H, d, f), d)
+    layers["e_up"] = norm_init(k3, (L, H, d, f), d)
+    layers["e_down"] = norm_init(k4, (L, H, f, d), f)
+    if cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        k5, k6, k7 = jax.random.split(jax.random.fold_in(rng, 8), 3)
+        layers["s_gate"] = norm_init(k5, (L, d, fs), d)
+        layers["s_up"] = norm_init(k6, (L, d, fs), d)
+        layers["s_down"] = norm_init(k7, (L, fs, d), fs)
+    if cfg.router_bias:
+        layers["router_bias"] = ROUTER_BIAS_INIT * jax.random.normal(
+            jax.random.fold_in(rng, 9), (L, E), jnp.float32)
+        layers["router_bias_m"] = jnp.zeros((L, E), jnp.float32)
+    if cfg.n_dense_layers:
+        base["dense_layers"] = llama.init_params(
+            jax.random.fold_in(rng, 10), dataclasses.replace(
+                cfg, n_layers=cfg.n_dense_layers, d_ff=cfg.d_ff_dense,
+                layer_kinds=(), n_dense_layers=0, vocab_size=8))["layers"]
     return base
+
+
+def buffer_updates(cfg: MoEConfig, params: Params, updates: Params,
+                   stats: Dict[str, jax.Array]
+                   ) -> Tuple[Params, Dict[str, jax.Array]]:
+    """The optimizer's ``updates`` with the buffers' own movement in place of
+    what it made of their zero gradient and the decay, and ``stats`` less
+    what that took (``router_load``); both as they came for a config
+    without a selection bias.
+
+    The bias moves by the balancing rule of the models that select by
+    ``score + bias`` (no gradient: the bias is not a weight), in the form
+    Trinity Large's report names, soft-clamped momentum updates: with
+    ``n`` [L, E] the (token, expert) choices each expert of each layer got
+    this step, over all ``n_experts`` (an expert that lives elsewhere is
+    still chosen, and its bias is every chip's), and ``mean`` their mean,
+    ``v = (mean - n) / mean`` is how far under its share an expert is (1
+    for one nobody chose), ``step = RATE * tanh(v)`` the soft-clamped move,
+    centred over the experts so that the biases' mean stays; ``m' =
+    MOMENTUM * m + (1 - MOMENTUM) * step`` and ``bias' = bias + m'``."""
+    if not cfg.router_bias:
+        return updates, stats
+    stats = dict(stats)
+    m = params["layers"]["router_bias_m"]
+    if "router_load" in stats:
+        load = stats.pop("router_load").astype(jnp.float32)           # [L, E]
+        mean = load.mean(-1, keepdims=True)
+        step = ROUTER_BIAS_RATE * jnp.tanh((mean - load) / mean)
+        step = step - step.mean(-1, keepdims=True)
+    else:  # a caller's own loss counted nothing: the bias keeps its course
+        step = jnp.zeros_like(m)
+    m_new = ROUTER_BIAS_MOMENTUM * m + (1 - ROUTER_BIAS_MOMENTUM) * step
+    layers = {**updates["layers"], "router_bias": m_new,
+              "router_bias_m": m_new - m}
+    return {**updates, "layers": layers}, stats
 
 
 def _fill_take(rows: jax.Array, index: jax.Array) -> jax.Array:
@@ -131,12 +286,13 @@ def _row_placement(cfg: MoEConfig, G: int):
         return None
     e_axes = sharding_rules().spec_for(
         "layers/e_gate",
-        (cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff), mesh)[1]
+        (cfg.n_expert_layers, cfg.experts_held, cfg.d_model, cfg.d_ff),
+        mesh)[1]
     e_axes = e_axes if isinstance(e_axes, tuple) else (e_axes,)
     over, among = (tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
                    for axes in (BATCH_AXES, e_axes))
     if (not over + among or G % axes_size(over, mesh)
-            or cfg.n_experts % axes_size(among, mesh)):
+            or cfg.experts_held % axes_size(among, mesh)):
         return None
     return mesh, over, among
 
@@ -254,13 +410,78 @@ def _combine_bwd(place, res, ct):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
-             ) -> Tuple[jax.Array, jax.Array]:
-    """[B, S, d] -> ([B, S, d], aux_loss). Static-shape top-k capacity
-    dispatch: every shape is fixed at trace time, and the routed rows move
-    into and out of the ``[E, C, d]`` capacity buffers by index."""
-    b, s, d = h.shape
+def _route(cfg: MoEConfig, tokens: jax.Array, layer: Params
+           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """tokens [G, d] -> (scores [G, E] float32, the K chosen experts'
+    scores [G, K], their indices [G, K]). The scores are the softmax of the
+    router's logits or the sigmoid of each; the choice is by score, plus the
+    layer's ``router_bias`` where it has one (the bias chooses and is not a
+    weight: the gates are the scores themselves)."""
+    logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)                      # [G, E]
+    if "router_bias" not in layer:
+        return (scores, *jax.lax.top_k(scores, cfg.top_k))
+    _, topk_idx = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(layer["router_bias"]), cfg.top_k)
+    return scores, jnp.take_along_axis(scores, topk_idx, axis=-1), topk_idx
+
+
+def _balance(cfg: MoEConfig, scores: jax.Array, topk_idx: jax.Array,
+             sel_onehot: jax.Array, b: int) -> jax.Array:
+    """The balancing term of ``cfg.balance`` (its coefficient is
+    ``router_aux_coef``, applied by ``lm_loss``)."""
     E, K = cfg.n_experts, cfg.top_k
+    if cfg.balance == "first_choice":
+        # Switch aux loss: balance token fraction vs router probability mass
+        frac = jnp.mean(sel_onehot[:, 0, :].astype(jnp.float32), axis=0)  # top-1
+        return E * jnp.sum(frac * jnp.mean(scores, axis=0))
+    if cfg.balance != "sequence":
+        raise ValueError(f"balance {cfg.balance!r}")
+    # a sequence at a time: the share of its tokens that chose an expert
+    # (over all K choices, over all E experts, held or not) times the mean
+    # of the scores normalised to sum 1
+    chose = jax.nn.one_hot(topk_idx, E, dtype=jnp.float32).sum(1)     # [G, E]
+    frac = chose.reshape(b, -1, E).mean(1)                            # [b, E]
+    mass = (scores / scores.sum(-1, keepdims=True)).reshape(b, -1, E).mean(1)
+    return (E / K) * jnp.mean(jnp.sum(frac * mass, axis=-1))
+
+
+#: what a patterned step counts of its routing beside its loss, int32 each,
+#: over the expert layers (``routing_counters``); a recorder adds them up
+#: over steps, but for ``COUNTER_MAXIMA``, of which it keeps the largest
+ROUTING_COUNTERS = ("moe_assignments",      # (token, expert) pairs routed
+                    "moe_held",             # of them, to an expert held here
+                    "moe_kept",             # of those, inside the capacity
+                    "moe_dropped",          # of those, beyond it
+                    "moe_max_expert_rows")  # the busiest held expert's queue
+COUNTER_MAXIMA = ("moe_max_expert_rows",)
+
+
+def routing_counters(cfg: MoEConfig, load: jax.Array, kept: jax.Array
+                     ) -> Dict[str, jax.Array]:
+    """``ROUTING_COUNTERS`` by name from the expert layers' ``load`` [L, E]
+    (choices each expert got, over all ``n_experts``) and ``kept`` [L]
+    (assignments that took a slot in a held expert's buffer)."""
+    here = load[:, :cfg.experts_held]
+    held, kept = here.sum(dtype=jnp.int32), kept.sum(dtype=jnp.int32)
+    return dict(zip(ROUTING_COUNTERS, (
+        load.sum(dtype=jnp.int32), held, kept, held - kept, here.max())))
+
+
+def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
+             ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """[B, S, d] -> ([B, S, d], aux_loss, routing). Static-shape top-k
+    capacity dispatch: every shape is fixed at trace time, and the routed
+    rows move into and out of the ``[H, C, d]`` capacity buffers by index,
+    ``H`` the experts held here (all ``E`` unless the config says less).
+    ``routing`` is what the routing already computed, for a caller that
+    counts it: the choices ``topk_idx`` [G, K] and which of them took a
+    slot, ``keep``."""
+    b, s, d = h.shape
+    E, K, H = cfg.n_experts, cfg.top_k, cfg.experts_held
     G = b * s
     C = max(1, int(cfg.capacity_factor * G * K / E))
     tokens = h.reshape(G, d)
@@ -269,30 +490,33 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
     # scopes are names only: they group the layer's operations in a
     # device trace and change nothing that is computed
     with jax.named_scope("moe_router"):
-        logits = (tokens @ layer["router"].astype(jnp.float32)).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                       # [G, E]
-        topk_probs, topk_idx = jax.lax.top_k(probs, K)                # [G, K]
+        probs, topk_probs, topk_idx = _route(cfg, tokens, layer)
         if cfg.norm_topk_prob:
             # renormalize the selected gates (Mixtral convention)
             topk_probs = topk_probs / (topk_probs.sum(-1, keepdims=True) + 1e-9)
+        if cfg.route_scale != 1.0:
+            topk_probs = topk_probs * cfg.route_scale
 
         # capacity slots: position of each token within its expert's queue,
-        # counted over the flattened [K, G] selection order
-        sel_onehot = jax.nn.one_hot(topk_idx, E, dtype=jnp.int32)     # [G, K, E]
-        flat = sel_onehot.transpose(1, 0, 2).reshape(K * G, E)        # [K*G, E]
+        # counted over the flattened [K, G] selection order; an expert that
+        # lives elsewhere has no queue here (its one-hot row is zeros)
+        sel_onehot = jax.nn.one_hot(topk_idx, H, dtype=jnp.int32)     # [G, K, H]
+        flat = sel_onehot.transpose(1, 0, 2).reshape(K * G, H)        # [K*G, H]
         pos_flat = jnp.cumsum(flat, axis=0) - flat                    # slot idx
-        pos = pos_flat.reshape(K, G, E).transpose(1, 0, 2)            # [G, K, E]
+        pos = pos_flat.reshape(K, G, H).transpose(1, 0, 2)            # [G, K, H]
         slot = jnp.sum(pos * sel_onehot, axis=-1)                     # [G, K]
         keep = slot < C                                               # overflow
+        if H < E:
+            keep = keep & (topk_idx < H)
         gates = topk_probs * keep                                      # [G, K]
 
     with jax.named_scope("moe_dispatch"):
         # the routing from its two ends: an assignment's slot, a slot's
         # assignment (no two kept assignments share a slot)
-        dest = jnp.where(keep, topk_idx * C + slot, E * C)            # [G, K]
-        src = jnp.full((E * C,), G * K, jnp.int32).at[dest.reshape(-1)].set(
-            jnp.arange(G * K, dtype=jnp.int32), mode="drop").reshape(E, C)
-        expert_in = _dispatch(place, tokens, src, dest)               # [E, C, d]
+        dest = jnp.where(keep, topk_idx * C + slot, H * C)            # [G, K]
+        src = jnp.full((H * C,), G * K, jnp.int32).at[dest.reshape(-1)].set(
+            jnp.arange(G * K, dtype=jnp.int32), mode="drop").reshape(H, C)
+        expert_in = _dispatch(place, tokens, src, dest)               # [H, C, d]
 
     with jax.named_scope("moe_experts"):
         gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in,
@@ -306,11 +530,8 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
         out = _combine(place, expert_out, gates.astype(h.dtype), src, dest)
 
     with jax.named_scope("moe_router"):
-        # Switch aux loss: balance token fraction vs router probability mass
-        frac = jnp.mean(sel_onehot[:, 0, :].astype(jnp.float32), axis=0)  # top-1
-        prob_mean = jnp.mean(probs, axis=0)
-        aux = E * jnp.sum(frac * prob_mean)
-    return out.reshape(b, s, d), aux
+        aux = _balance(cfg, probs, topk_idx, sel_onehot, b)
+    return out.reshape(b, s, d), aux, {"topk_idx": topk_idx, "keep": keep}
 
 
 def ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params
@@ -318,7 +539,7 @@ def ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params
     """Pre-norm MoE FFN + residual; returns (hidden, aux_loss)."""
     h = llama.rmsnorm(x, layer["mlp_norm"].astype(cfg.compute_dtype),
                       cfg.norm_eps)
-    ffn, aux = _moe_ffn(cfg, h, layer)
+    ffn, aux, _ = _moe_ffn(cfg, h, layer)
     return x + ffn, aux
 
 
@@ -426,16 +647,110 @@ def _moe_block(cfg: MoEConfig, x: jax.Array, layer: Params,
     return ffn_half(cfg, x, layer)
 
 
+def _remat(cfg: MoEConfig, fn):
+    """``fn`` as a remat block that keeps what the old stack's keeps."""
+    if not cfg.remat:
+        return fn
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+
+def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
+    """One layer of a patterned config as ``(x, layer) -> (x, aux, load,
+    kept)``, its kind static: the attention half rotated inside the band or
+    unrotated and full, then a dense SwiGLU (no ``aux``, ``load`` or
+    ``kept``: None) or the shared expert beside the routed ones, every
+    branch normed before and after where the config says so. ``load`` [E]
+    is the choices each of the ``n_experts`` got, ``kept`` how many took a
+    slot in a held expert's buffer."""
+    cdt = cfg.compute_dtype
+
+    def run(x, layer, sin, cos, segment_ids):
+        with jax.named_scope("attn_" + kind):
+            x = llama.attention_half(
+                cfg, x, layer, sin, cos, segment_ids,
+                rotate=kind == "window",
+                window=cfg.sliding_window if kind == "window" else None)
+        if dense:
+            with jax.named_scope("mlp"):
+                return llama.ffn_half(cfg, x, layer), None, None, None
+        h = llama.rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
+        ffn, aux, routing = _moe_ffn(cfg, h, layer)
+        if cfg.n_shared_experts:
+            with jax.named_scope("moe_shared"):
+                gate = jax.nn.silu(h @ layer["s_gate"].astype(cdt))
+                ffn = ffn + (gate * (h @ layer["s_up"].astype(cdt))
+                             ) @ layer["s_down"].astype(cdt)
+        with jax.named_scope("moe_router"):
+            load = jnp.zeros((cfg.n_experts,), jnp.int32).at[
+                routing["topk_idx"].reshape(-1)].add(1)
+            kept = routing["keep"].sum(dtype=jnp.int32)
+        return (x + llama.post_norm(cfg, ffn, layer, "mlp_post_norm"), aux,
+                load, kept)
+
+    return run
+
+
+def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
+          segment_ids) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """A patterned config's layers over ``x``: the leading dense layers one
+    after another (their kinds need repeat nothing), then a ``lax.scan``
+    over the repeats of the expert layers' shortest repeating pattern, the
+    layers of one period written out inside it with their kinds static
+    (``models/hybrid._walk`` is the served precedent). Every layer is its
+    own remat block. Returns (x, the expert layers' summed aux, their
+    ``load`` [L, E] and ``kept`` [L])."""
+    for i, kind in enumerate(cfg.layer_kinds[:cfg.n_dense_layers]):
+        layer = jax.tree.map(lambda a, i=i: a[i], params["dense_layers"])
+        run = _patterned_layer(cfg, kind, dense=True)
+        x = _remat(cfg, lambda x, layer, run=run: run(
+            x, layer, sin, cos, segment_ids)[0])(x, layer)
+
+    period = cfg.period()
+    runs = [_remat(cfg, lambda x, layer, run=_patterned_layer(
+        cfg, kind, dense=False): run(x, layer, sin, cos, segment_ids))
+        for kind in period]
+
+    def body(carry, layers):
+        x, aux = carry
+        counts = []
+        for j, run in enumerate(runs):
+            x, a, *count = run(x, jax.tree.map(lambda a, j=j: a[j], layers))
+            aux = aux + a
+            counts.append(count)
+        load, kept = zip(*counts)
+        return (x, aux), (jnp.stack(load), jnp.stack(kept))
+
+    by_period = jax.tree.map(
+        lambda a: a.reshape(-1, len(period), *a.shape[1:]), params["layers"])
+    (x, aux), (load, kept) = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32)), by_period)
+    return x, aux, load.reshape(-1, cfg.n_experts), kept.reshape(-1)
+
+
+def _patterned_scope(cfg: MoEConfig, name: str):
+    """A ``jax.named_scope`` in the patterned form and nothing in the old
+    stack, whose lowered text stays what it was."""
+    return jax.named_scope(name) if cfg.layer_kinds \
+        else contextlib.nullcontext()
+
+
 def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
-                   segment_ids=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """-> (hidden, head, total_aux_loss)."""
+                   segment_ids=None
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array, Dict[str, Any]]:
+    """-> (hidden, head, total_aux_loss, stats). ``stats`` is what the
+    patterned form counts of its routing: ``ROUTING_COUNTERS`` by name and
+    the layers' ``router_load`` [L, E], which moves the selection bias
+    (``buffer_updates``); {} for the old stack, which counts nothing."""
     if cfg.pipeline_axis is not None:
         raise NotImplementedError(
             "pipeline parallelism for the MoE family is not implemented "
             "(use dp/fsdp/tp/ep); silently ignoring pipeline_axis would "
             "train an unpipelined model under pipeline shardings")
     cdt = cfg.compute_dtype
-    x = params["embed"].astype(cdt)[tokens]
+    if cfg.layer_kinds:
+        llama.refuse_served_only(cfg)
+    x = llama.embed(params, cfg, tokens)
     sin, cos = llama.rope_angles(tokens.shape[1], cfg.head_dim,
                                  cfg.rope_theta, cdt)
 
@@ -444,37 +759,51 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
         x, a = _moe_block(cfg, x, layer, sin, cos, segment_ids)
         return (x, aux + a), None
 
-    if cfg.remat:
-        body = jax.checkpoint(
-            body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               params["layers"])
-    x = llama.rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(cdt)
-    return x, head, aux / cfg.n_layers
+    stats = {}
+    if cfg.layer_kinds:
+        x, aux, load, kept = _walk(params, x, cfg, sin, cos, segment_ids)
+        stats = {**routing_counters(cfg, load, kept), "router_load": load}
+    else:
+        if cfg.remat:
+            body = jax.checkpoint(
+                body,
+                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                   params["layers"])
+    with _patterned_scope(cfg, "loss_head"):
+        x = llama.rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(cdt)
+    return x, head, aux / cfg.n_expert_layers, stats
 
 
 def forward(params: Params, tokens: jax.Array, cfg: MoEConfig,
             segment_ids=None) -> jax.Array:
-    x, head, _ = forward_hidden(params, tokens, cfg, segment_ids)
+    x, head, _, _ = forward_hidden(params, tokens, cfg, segment_ids)
     return (x @ head).astype(jnp.float32)
+
+
+def loss_and_stats(params: Params, batch: Dict[str, jax.Array], cfg: MoEConfig
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Next-token CE + router aux loss (llama's chunked CE reused, its
+    loop's head gathered once before it under a mesh), and what the forward
+    counted (``forward_hidden``'s ``stats``)."""
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    x, head, aux, stats = forward_hidden(params, inputs, cfg,
+                                         batch.get("segment_ids"))
+    head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
+                                    targets.shape[1])
+    with _patterned_scope(cfg, "loss_head"):
+        ce = llama.chunked_ce(x, head, targets, batch.get("loss_mask"),
+                              cfg.loss_chunk)
+    return ce + cfg.router_aux_coef * aux, stats
 
 
 def lm_loss(params: Params, batch: Dict[str, jax.Array],
             cfg: MoEConfig) -> jax.Array:
-    """Next-token CE + router aux loss (llama's chunked CE reused, its
-    loop's head gathered once before it under a mesh)."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, head, aux = forward_hidden(params, inputs, cfg,
-                                  batch.get("segment_ids"))
-    head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
-                                    targets.shape[1])
-    ce = llama.chunked_ce(x, head, targets, batch.get("loss_mask"),
-                          cfg.loss_chunk)
-    return ce + cfg.router_aux_coef * aux
+    """``loss_and_stats``'s loss alone."""
+    return loss_and_stats(params, batch, cfg)[0]
 
 
 def sharding_rules(pipeline: bool = False) -> ShardingRules:
@@ -497,9 +826,13 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
     return ShardingRules([
         (r"embed$", P("tp", "fsdp")),
         (r"lm_head$", P("fsdp", "tp")),
-        (r"layers/w[qkv]$", P(None, "fsdp", "tp")),
+        (r"layers/w[qkvg]$", P(None, "fsdp", "tp")),
         (r"layers/wo$", P(None, "tp", "fsdp")),
+        # a patterned config's dense layers and shared expert, as llama's
+        (r"layers/[ws]_(gate|up)$", P(None, "fsdp", "tp")),
+        (r"layers/[ws]_down$", P(None, "tp", "fsdp")),
         (r"layers/router$", P(None, "fsdp", None)),
+        (r"layers/router_bias(_m)?$", P(None)),
         (r"layers/e_(gate|up)$", [P(None, whole, None, "tp"),
                                   P(None, "ep", "fsdp", "tp")]),
         (r"layers/e_down$", [P(None, whole, "tp", None),

@@ -39,6 +39,17 @@ again. A pair wholly below the diagonal and inside both true lengths skips
 the mask arithmetic; only the blocks the diagonal or a padded edge crosses
 build the iota compare. Rows no key reaches give ``o`` = 0, ``lse`` = NEG_INF.
 
+The band. With ``window`` w (causal only) a query at position p sees the keys
+in (p - w, p]. A pair wholly below the band is skipped like one above the
+diagonal, from the other side: the walked index is clamped to the first (in
+dkv: last) live block too, and the band's lower edge builds the mask where it
+crosses a block. ``window=None`` is the causal kernel as it was.
+
+Names. A call is named ``flash_<kind>_bh<bh>_q<sq>_k<sk>_d<d>_c<causal>_w<w>``
+(w 0: no band), the true lengths before padding: a device trace shows a
+``pallas_call`` under its name, and a call's result does not say what of
+the square it skipped.
+
 Precision. Products take their operands in the inputs' dtype and accumulate
 in float32, forward and backward alike (``p`` and ``ds`` are cast as the
 forward casts ``p``); the softmax statistics are float32. float32 inputs
@@ -142,13 +153,29 @@ def _live(i, j, block_q: int, block_k: int, q_offset):
     return j * block_k <= i * block_q + block_q - 1 + q_offset
 
 
+def _in_band(i, j, block_q: int, block_k: int, q_offset, window: int):
+    """Does any key of k block ``j`` lie in the band of a row of q block
+    ``i``: the block's first row is less than ``window`` after the block's
+    last key."""
+    return i * block_q + q_offset - (j * block_k + block_k - 1) < window
+
+
+def _check_window(causal: bool, window: Optional[int]) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window {window!r} needs causal attention and at "
+                         f"least one key a query")
+
+
 def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
-         kind: str, blocks: Optional[Tuple[int, int]] = None) -> Plan:
+         kind: str, blocks: Optional[Tuple[int, int]] = None,
+         window: Optional[int] = None) -> Plan:
     """The tiling of one kernel (``kind`` of ``KINDS``) at one shape; pure.
     Explicit ``blocks`` (block_q, block_k) are kept, shrunk to a short
-    sequence."""
+    sequence. ``window`` moves no block's size: it only takes the pairs
+    below the band out of ``live_steps``."""
     if kind not in KINDS:
         raise ValueError(f"kind {kind!r} is not one of {KINDS}")
+    _check_window(causal, window)
     if blocks is not None:
         bq, bk = _pick_block(blocks[0], seq_q), _pick_block(blocks[1], seq_k)
     else:
@@ -159,8 +186,9 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
             tq, tk = (tq // 2, tk) if tq >= tk else (tq, tk // 2)
         bq, bk = _fit_block(tq, seq_q), _fit_block(tk, seq_k)
     nq, nk = -(-seq_q // bq), -(-seq_k // bk)
-    live = sum(_live(i, j, bq, bk, 0) for i in range(nq) for j in range(nk)) \
-        if causal else nq * nk
+    live = sum(_live(i, j, bq, bk, 0)
+               and (window is None or _in_band(i, j, bq, bk, 0, window))
+               for i in range(nq) for j in range(nk)) if causal else nq * nk
     need = _vmem_bytes(kind, bq, bk, head_dim, itemsize)
     return Plan(kind, bq, bk, nq * nk, live, need,
                 max(_VMEM_DEFAULT_LIMIT_BYTES, need))
@@ -185,30 +213,35 @@ def noting_plans(into: List[Dict[str, Any]]) -> Iterator[None]:
 
 
 def _planned(kind: str, q, k, causal: bool,
-             blocks: Optional[Tuple[int, int]]) -> Plan:
-    """The plan of the kernel about to be built on q, k [bh, s, d], noted."""
-    sq, d = q.shape[1], q.shape[2]
+             blocks: Optional[Tuple[int, int]],
+             window: Optional[int]) -> Tuple[Plan, str]:
+    """The plan of the kernel about to be built on q, k [bh, s, d], noted,
+    and the call's name (module docstring)."""
+    bh, sq, d = q.shape
     sk = k.shape[1]
-    p = plan(sq, sk, d, q.dtype.itemsize, causal, kind, blocks)
+    p = plan(sq, sk, d, q.dtype.itemsize, causal, kind, blocks, window)
     into = getattr(_noting, "into", None)
     if into is not None:
         note = {**p._asdict(), "seq_q": sq, "seq_k": sk, "head_dim": d,
-                "itemsize": q.dtype.itemsize, "causal": causal}
+                "itemsize": q.dtype.itemsize, "causal": causal,
+                "window": window}
         if note not in into:
             into.append(note)
-    return p
+    return p, (f"flash_{kind}_bh{bh}_q{sq}_k{sk}_d{d}_c{int(causal)}"
+               f"_w{window or 0}")
 
 
 # ------------------------------------------------------- what a step skips
 
-def _on_live_steps(step, qi, kk, qoff, *, causal, block_q, block_k, q_len,
-                   kv_len, q_axis):
+def _on_live_steps(step, qi, kk, qoff, *, causal, window, block_q, block_k,
+                   q_len, kv_len, q_axis):
     """Run ``step(mask_of)`` for the (q block, k block) pair, at most once:
-    not at all if the causal mask leaves the pair nothing; with ``mask_of``
-    None if no element of it is masked (every key at or before every row,
-    no padded row or key in it); else with the function that builds the
-    mask of a tile: keys inside the true length and, causal, at or before
-    their row. ``q_axis`` is the axis rows lie on (1 in dkv's transposed
+    not at all if the causal mask or the band leaves the pair nothing; with
+    ``mask_of`` None if no element of it is masked (every key at or before
+    every row and inside every row's band, no padded row or key in it);
+    else with the function that builds the mask of a tile: keys inside the
+    true length and, causal, at or before their row and less than ``window``
+    before it. ``q_axis`` is the axis rows lie on (1 in dkv's transposed
     tile)."""
     q_lo, k_lo = qi * block_q + qoff, kk * block_k
     clear = ((qi + 1) * block_q <= q_len) & (k_lo + block_k <= kv_len)
@@ -216,6 +249,9 @@ def _on_live_steps(step, qi, kk, qoff, *, causal, block_q, block_k, q_len,
     if causal:
         live = _live(qi, kk, block_q, block_k, qoff)
         clear = clear & (k_lo + block_k - 1 <= q_lo)
+    if window is not None:
+        live = live & _in_band(qi, kk, block_q, block_k, qoff, window)
+        clear = clear & (q_lo + block_q - 1 - k_lo < window)
 
     def mask_of(shape):
         kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
@@ -223,6 +259,8 @@ def _on_live_steps(step, qi, kk, qoff, *, causal, block_q, block_k, q_len,
         if causal:
             qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
             mask = mask & (qpos >= kpos)
+            if window is not None:
+                mask = mask & (qpos - kpos < window)
         return mask
 
     pl.when(live & clear)(lambda: step(None))
@@ -236,9 +274,20 @@ def _last_live_k(i, qoff, block_q, block_k, nk):
     return jnp.minimum(last_row // block_k, nk - 1)
 
 
+def _first_live_k(i, qoff, block_q, block_k, window):
+    """The first k block inside the band of q block ``i``'s first row."""
+    return jnp.maximum(i * block_q + qoff - window + 1, 0) // block_k
+
+
 def _first_live_q(j, qoff, block_q, block_k, nq):
     """The first q block that sees k block ``j`` (the last if none)."""
     return jnp.minimum(jnp.maximum(j * block_k - qoff, 0) // block_q, nq - 1)
+
+
+def _last_live_q(j, qoff, block_q, block_k, nq, window):
+    """The last q block whose band still holds a key of k block ``j``."""
+    last_row = jnp.maximum(j * block_k + block_k - 1 + window - 1 - qoff, 0)
+    return jnp.minimum(last_row // block_q, nq - 1)
 
 
 _NT = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]
@@ -299,13 +348,17 @@ def _compiler_params(p: Plan):
         vmem_limit_bytes=p.vmem_limit_bytes)
 
 
-def _walks_k(p: Plan, causal: bool, nk: int):
+def _walks_k(p: Plan, causal: bool, nk: int, window: Optional[int]):
     """Block specs of a (bh, nq, nk) grid: the q-side block of the step,
-    and the k-side block, which past the last live one stays where it is."""
+    and the k-side block, which past the last live one (and, with a band,
+    before the first) stays where it is."""
     def k_index(b, i, j, qoff):
         if causal:
-            j = jnp.minimum(j, _last_live_k(i, qoff[0], p.block_q, p.block_k,
-                                            nk))
+            last = _last_live_k(i, qoff[0], p.block_q, p.block_k, nk)
+            j = jnp.minimum(j, last)
+            if window is not None:
+                j = jnp.maximum(j, jnp.minimum(last, _first_live_k(
+                    i, qoff[0], p.block_q, p.block_k, window)))
         return b, j, 0
 
     q_index = lambda b, i, j, qoff: (b, i, 0)
@@ -313,20 +366,20 @@ def _walks_k(p: Plan, causal: bool, nk: int):
             lambda cols: pl.BlockSpec((1, p.block_k, cols), k_index))
 
 
-def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret
-                    ) -> Tuple[jax.Array, jax.Array]:
+def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
+                    window=None) -> Tuple[jax.Array, jax.Array]:
     """q,k,v: [bh, s, d]; returns (o [bh, sq, d], lse [bh, sq]). Pads to
     block multiples; padded keys are masked, padded rows cut off."""
     bh, sq, d = q.shape
     sk = k.shape[1]
-    p = _planned("fwd", q, k, causal, blocks)
+    p, name = _planned("fwd", q, k, causal, blocks, window)
     q, k, v = _pad_seq(q, p.block_q), _pad_seq(k, p.block_k), \
         _pad_seq(v, p.block_k)
     nq, nk = q.shape[1] // p.block_q, k.shape[1] // p.block_k
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=p.block_q,
-        block_k=p.block_k, q_len=sq, kv_len=sk)
-    qspec, kspec = _walks_k(p, causal, nk)
+        _fwd_kernel, scale=scale, causal=causal, window=window,
+        block_q=p.block_q, block_k=p.block_k, q_len=sq, kv_len=sk)
+    qspec, kspec = _walks_k(p, causal, nk, window)
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -345,7 +398,7 @@ def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret
         ],
         compiler_params=_compiler_params(p),
         interpret=interpret,
-        name="flash_fwd",
+        name=name,
     )(q_offset, q, k, v)
     return o[:, :sq], lse[:, :sq, 0]
 
@@ -415,13 +468,14 @@ def _dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
-                    interpret):
+                    interpret, window=None):
     """q,k,v,o,do: [bh, s, d]; lse: [bh, sq] -> (dq, dk, dv). Each kernel
     pads to its own blocks: a padded row has ``lse`` = NEG_INF."""
     bh, sq, d = q.shape
     sk = k.shape[1]
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    common = dict(scale=scale, causal=causal, q_len=sq, kv_len=sk)
+    common = dict(scale=scale, causal=causal, window=window, q_len=sq,
+                  kv_len=sk)
 
     def padded(p):
         rows = _round_up(sq, p.block_q) - sq
@@ -430,10 +484,10 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                 jnp.pad(lse, ((0, 0), (0, rows)), constant_values=NEG_INF),
                 jnp.pad(delta, ((0, 0), (0, rows))))
 
-    p = _planned("dq", q, k, causal, blocks)
+    p, name = _planned("dq", q, k, causal, blocks, window)
     qp, kp, vp, dop, lsep, deltap = padded(p)
     nq, nk = qp.shape[1] // p.block_q, kp.shape[1] // p.block_k
-    qspec, kspec = _walks_k(p, causal, nk)
+    qspec, kspec = _walks_k(p, causal, nk, window)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=p.block_q, block_k=p.block_k,
                           **common),
@@ -447,19 +501,23 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
         out_shape=[jax.ShapeDtypeStruct(qp.shape, q.dtype)],
         compiler_params=_compiler_params(p),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=name,
     )(q_offset, qp, kp, vp, dop, lsep[..., None], deltap[..., None])[0]
 
     # dk/dv: grid walks k blocks outer, q blocks inner; before the first
-    # live q block the q-side index stays on it
-    p = _planned("dkv", q, k, causal, blocks)
+    # live q block (and, with a band, after the last) the q-side index
+    # stays on it
+    p, name = _planned("dkv", q, k, causal, blocks, window)
     qp, kp, vp, dop, lsep, deltap = padded(p)
     nq, nk = qp.shape[1] // p.block_q, kp.shape[1] // p.block_k
 
     def q_index(b, j, i, qoff):
         if causal:
-            i = jnp.maximum(i, _first_live_q(j, qoff[0], p.block_q, p.block_k,
-                                             nq))
+            first = _first_live_q(j, qoff[0], p.block_q, p.block_k, nq)
+            i = jnp.maximum(i, first)
+            if window is not None:
+                i = jnp.minimum(i, jnp.maximum(first, _last_live_q(
+                    j, qoff[0], p.block_q, p.block_k, nq, window)))
         return i
 
     kspec = pl.BlockSpec((1, p.block_k, d), lambda b, j, i, qoff: (b, j, 0))
@@ -481,7 +539,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                    jax.ShapeDtypeStruct(kp.shape, v.dtype)],
         compiler_params=_compiler_params(p),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=name,
     )(q_offset, qp, kp, vp, dop, lsep[:, None], deltap[:, None])
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 
@@ -532,23 +590,26 @@ def _qoff(q_offset):
     return jnp.asarray(q_offset, jnp.int32).reshape(1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, scale, causal, blocks, interpret, q_offset):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, scale, causal, blocks, interpret, q_offset, window):
     return _flash_core_fwd(q, k, v, scale, causal, blocks, interpret,
-                           q_offset)[0]
+                           q_offset, window)[0]
 
 
-def _flash_core_fwd(q, k, v, scale, causal, blocks, interpret, q_offset):
+def _flash_core_fwd(q, k, v, scale, causal, blocks, interpret, q_offset,
+                    window):
     o, lse = _flash_fwd_bhsd(q, k, v, _qoff(q_offset), scale=scale,
                              causal=causal, blocks=blocks,
-                             interpret=interpret)
+                             interpret=interpret, window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_core_bwd(scale, causal, blocks, interpret, q_offset, res, do):
+def _flash_core_bwd(scale, causal, blocks, interpret, q_offset, window, res,
+                    do):
     q, k, v, o, lse = res
     return _flash_bwd_bhsd(q, k, v, o, lse, do, _qoff(q_offset), scale=scale,
-                           causal=causal, blocks=blocks, interpret=interpret)
+                           causal=causal, blocks=blocks, interpret=interpret,
+                           window=window)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -558,6 +619,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     scale: Optional[float] = None,
                     q_offset: int = 0,
+                    window: Optional[int] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -566,15 +628,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Drop-in for ``ray_tpu.ops.attention.mha`` (minus segment_ids/bias — the
     XLA path handles those). ``q_offset``: absolute position of q[0] relative
     to k[0], for decode and ring steps; static here (see
-    ``flash_attention_with_lse`` for a traced offset). ``block_q`` /
-    ``block_k``: None lets ``plan`` choose per kernel.
+    ``flash_attention_with_lse`` for a traced offset). ``window``: as
+    ``mha``'s, a query at ``i`` sees key ``j`` iff ``0 <= i - j < window``
+    (causal only). ``block_q`` / ``block_k``: None lets ``plan`` choose per
+    kernel.
     """
+    _check_window(causal, window)
     b, _, _, d = q.shape
     scale = scale if scale is not None else d ** -0.5
     if interpret is None:
         interpret = _needs_interpret()
     o = _flash_core(*_prep(q, k, v), scale, causal,
-                    _explicit(block_q, block_k), interpret, q_offset)
+                    _explicit(block_q, block_k), interpret, q_offset, window)
     return _from_bhsd(o, b)
 
 

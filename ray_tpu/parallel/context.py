@@ -184,6 +184,7 @@ def ulysses_attention(q, k, v, axis_name: str, causal: bool = True,
 
 def flash_attention_on_mesh(q, k, v, *, causal: bool = True,
                             scale: Optional[float] = None,
+                            window: Optional[int] = None,
                             mesh: Optional[Mesh] = None):
     """The flash kernel under the ambient mesh. The TPU compiler does not
     partition a Mosaic kernel ("cannot be automatically partitioned"), so
@@ -192,13 +193,15 @@ def flash_attention_on_mesh(q, k, v, *, causal: bool = True,
     that tp does not divide are repeated up to the q heads first."""
     mesh = mesh or current_mesh()
     if mesh is None or mesh.size == 1:
-        return flash_attention(q, k, v, causal=causal, scale=scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window)
     if k.shape[2] % mesh.shape["tp"]:
         rep = q.shape[2] // k.shape[2]
         k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     spec = P(("dp", "fsdp"), None, "tp", None)
     return jax.shard_map(
-        functools.partial(flash_attention, causal=causal, scale=scale),
+        functools.partial(flash_attention, causal=causal, scale=scale,
+                          window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

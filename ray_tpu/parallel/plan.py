@@ -137,7 +137,8 @@ class Plan:
             return None
         c = self.cfg
         return placement(self._rules().spec_for(
-            "layers/e_gate", (c.n_layers, c.n_experts, c.d_model, c.d_ff),
+            "layers/e_gate",
+            (c.n_expert_layers, c.experts_held, c.d_model, c.d_ff),
             self.mesh))
 
     # ---- batch placement ----------------------------------------------------

@@ -49,6 +49,35 @@ def model_family(cfg):
     return moe if isinstance(cfg, moe.MoEConfig) else llama
 
 
+def _value_and_grad(cfg, loss_fn: Optional[Callable]) -> Callable:
+    """``(params, batch) -> (loss, grads, stats)`` of the family's
+    ``loss_and_stats``, or of ``loss_fn`` (a loss alone: no stats).
+    ``stats`` is what the family's forward counts beside its loss, by name
+    (a patterned sparse model's routing); most count nothing."""
+    if loss_fn is None:
+        loss_and_stats = model_family(cfg).loss_and_stats
+    else:
+        def loss_and_stats(params, batch, cfg):
+            return loss_fn(params, batch, cfg), {}
+
+    def grad_fn(params, batch):
+        (loss, stats), grads = jax.value_and_grad(
+            lambda p: loss_and_stats(p, batch, cfg), has_aux=True)(params)
+        return loss, grads, stats
+    return grad_fn
+
+
+def _apply_updates(cfg, params, updates, stats):
+    """``params`` moved by the optimizer's ``updates``, and ``stats`` as the
+    step's metrics carry them. A family with buffers (leaves the step reads
+    and no gradient moves: ``buffer_updates``) puts their own movement in
+    the optimizer's place and takes what it read out of ``stats``."""
+    move = getattr(model_family(cfg), "buffer_updates", None)
+    if move is not None:
+        updates, stats = move(cfg, params, updates, stats)
+    return optax.apply_updates(params, updates), stats
+
+
 def init_sharded_state(rng: jax.Array, cfg: llama.LlamaConfig, mesh: Mesh,
                        optimizer: optax.GradientTransformation,
                        rules: Optional[ShardingRules] = None):
@@ -97,20 +126,16 @@ def make_train_step(cfg: llama.LlamaConfig,
             raise NotImplementedError("1f1b schedule: dense llama only")
 
         def grad_fn(params, batch):
-            return llama.lm_loss_and_grads_1f1b(params, batch, cfg)
+            return (*llama.lm_loss_and_grads_1f1b(params, batch, cfg), {})
     else:
-        loss_fn = loss_fn or model_family(cfg).lm_loss
-
-        def grad_fn(params, batch):
-            return jax.value_and_grad(
-                lambda p: loss_fn(p, batch, cfg))(params)
+        grad_fn = _value_and_grad(cfg, loss_fn)
 
     def step(params, opt_state, batch):
-        loss, grads = grad_fn(params, batch)
+        loss, grads, stats = grad_fn(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, stats = _apply_updates(cfg, params, updates, stats)
         gnorm = optax.global_norm(grads)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
+        return params, opt_state, {"loss": loss, "grad_norm": gnorm, **stats}
 
     if plan is None and mesh is not None:
         plan = compile_plan(cfg, mesh)
@@ -232,16 +257,16 @@ def make_multi_step(cfg: llama.LlamaConfig,
                                   "is unsupported; use gpipe or single-step "
                                   "(StepDriver degrades automatically)")
     custom_loss = loss_fn is not None
-    loss_fn = loss_fn or model_family(cfg).lm_loss
+    grad_fn = _value_and_grad(cfg, loss_fn)
 
     def body(carry, batch):
         params, opt_state = carry
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(p, batch, cfg))(params)
+        loss, grads, stats = grad_fn(params, batch)
         updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, stats = _apply_updates(cfg, params, updates, stats)
         return (params, opt_state), {"loss": loss,
-                                     "grad_norm": optax.global_norm(grads)}
+                                     "grad_norm": optax.global_norm(grads),
+                                     **stats}
 
     def steps(params, opt_state, batches):
         (params, opt_state), metrics = jax.lax.scan(

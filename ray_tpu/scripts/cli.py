@@ -1203,7 +1203,18 @@ def cmd_train(args: argparse.Namespace) -> int:
             print(f"  flash {fp['kind']} s{fp['seq_q']}x{fp['seq_k']} "
                   f"d{fp['head_dim']}: tile {fp['block_q']}x"
                   f"{fp['block_k']}, {fp['live_steps']} of "
-                  f"{fp['grid_steps']} grid steps live")
+                  f"{fp['grid_steps']} grid steps live"
+                  + (f", window {fp['window']}" if fp.get("window") else ""))
+        routing = summ.get("routing") or {}
+        if routing.get("moe_assignments"):
+            held = routing.get("moe_held", 0)
+            print(f"  routing: {routing['moe_assignments']} assignments, "
+                  f"{held} to experts held here "
+                  f"({100 * held / routing['moe_assignments']:.2f}%), "
+                  f"{routing.get('moe_kept', 0)} kept, "
+                  f"{routing.get('moe_dropped', 0)} dropped beyond capacity, "
+                  f"busiest expert's queue "
+                  f"{routing.get('moe_max_expert_rows', 0)} rows")
         if summ.get("expert_placement"):
             print(f"  experts placed by {summ['expert_placement']}")
         coll = summ.get("collectives") or {}

@@ -118,6 +118,9 @@ class StepDriver:
             self.recorder.flash_plans if self.recorder is not None else [])
         if self.recorder is not None and plan is not None:
             self.recorder.expert_placement = plan.expert_placement()
+        if self.recorder is not None:
+            self.recorder.counter_maxima = getattr(
+                ts.model_family(cfg), "COUNTER_MAXIMA", ())
         self._fpt_cache: Dict[int, float] = {}
         self._single = ts.make_train_step(cfg, optimizer, loss_fn, mesh,
                                           plan=plan)

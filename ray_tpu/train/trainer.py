@@ -182,6 +182,8 @@ class JaxTrainer:
                     [w.next_result.remote() for w in active])
             except Exception as e:  # actor died (worker process crash)
                 return f"worker died: {e!r}"
+            if active[0] is group.workers[0]:
+                self._note_launches(round_results[0].get("launches"))
             errors = [r for r in round_results if r["type"] == "error"]
             if errors:
                 return errors[0].get("message", "unknown") + "\n" + \
@@ -203,6 +205,18 @@ class JaxTrainer:
                       if r["type"] == "report"]
         return None
 
+
+    @staticmethod
+    def _note_launches(totals: Optional[Dict[str, Any]]) -> None:
+        """``train_launches`` on the lifecycle record when rank 0 says its
+        loop is done: the extent of its ``StepDriver``'s launches and what
+        their recorder counted (launches, steps, a sparse model's routing
+        counters), kept here because the worker and its recorder are gone
+        by the time anyone asks."""
+        if totals:
+            totals = dict(totals)
+            lifecycle.record("train_launches", totals.pop("t0"),
+                             totals.pop("t1"), **totals)
 
     def _note_first_launch(self, first: Optional[Dict[str, float]]) -> None:
         """``trainer_start`` and its child ``first_launch`` on the lifecycle

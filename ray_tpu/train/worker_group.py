@@ -89,7 +89,21 @@ class TrainWorker:
         if first is not None:
             self._first_launch_told = True
             item = {**item, "first_launch": first}
+        if item["type"] == "done":
+            item = {**item, "launches": self._launch_totals()}
         return item
+
+    @staticmethod
+    def _launch_totals() -> Optional[Dict[str, Any]]:
+        """What the ``StepDriver``'s recorder counted over the loop's
+        launches (``TrainRecorder.launch_totals``), told on the reply that
+        says the loop is done: the trainer's ``train_launches`` span."""
+        recorders = sys.modules.get("ray_tpu.util.train_recorder")
+        for rec in (recorders.live_recorders() if recorders else ()):
+            totals = rec.launch_totals()
+            if totals is not None:
+                return totals
+        return None
 
     @staticmethod
     def _first_launch() -> Optional[Dict[str, float]]:

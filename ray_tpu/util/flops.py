@@ -52,10 +52,21 @@ def _flops_params(cfg) -> int:
     return active() if callable(active) else cfg.num_params()
 
 
+def _attended_keys(cfg, seq: int) -> float:
+    """The keys a query sees, mean over a ``seq``-token causal sequence and
+    summed over the layers: half the square for a full layer, less the
+    triangle below the band for a layer of a patterned config
+    (``models/moe.py``) whose kind is ``window``."""
+    kinds = getattr(cfg, "layer_kinds", ()) or ("full",) * cfg.n_layers
+    w = min(getattr(cfg, "sliding_window", None) or seq, seq)
+    return sum(w - w * w / (2.0 * seq) if kind == "window" else seq / 2.0
+               for kind in kinds)
+
+
 def train_flops_per_token(cfg, seq: int) -> float:
     """Fwd+bwd FLOPs per trained token: 6N + causal attention term."""
     n = _flops_params(cfg)
-    attn = 6 * cfg.n_layers * seq * cfg.n_heads * cfg.head_dim
+    attn = 12 * _attended_keys(cfg, seq) * cfg.n_heads * cfg.head_dim
     return 6.0 * n + attn
 
 

@@ -164,7 +164,8 @@ def waterfall(*, session: Optional[str] = None) -> List[str]:
             + "".join(f" {k}={v:.3f}" if isinstance(v, float) else f" {k}={v}"
                       for k, v in s.items()
                       if k not in ("name", "t0", "t1", "parent", "session",
-                                   "worker_id", "node_id"))
+                                   "worker_id", "node_id")
+                      and not isinstance(v, (list, dict)))
             for s in found]
 
 

@@ -30,7 +30,11 @@ What one LAUNCH record holds — a partition of the launch's wall
                    dispatch, step-profiler convention)
 
 plus K, tokens, the [K, B, S] batch shape, analytic FLOPs from
-``util/flops.py``, and the LAUNCH-GAP: launch N's dispatch start minus
+``util/flops.py``, the step's own COUNTERS where its metrics carry any (every
+integer among them, whatever the model's family calls it: read by the
+watcher from the buffers it has just waited for, with no fence of their
+own, summed over the launch's K steps, or the largest where the family names
+it in ``counter_maxima``), and the LAUNCH-GAP: launch N's dispatch start minus
 launch N−1's device-done while a stacked batch was already available —
 the dispatch-starvation analogue of the engine recorder's decode
 tick-gap. When the loader was genuinely dry (the batch became ready
@@ -154,6 +158,10 @@ class TrainRecorder(RecorderCore):
         # ``{kind: {count, runs, bytes}}`` read off the executable that runs
         # (``util/hlo_copies.collective_inventory``; None until it compiled)
         self.expert_placement: Optional[str] = None
+        # the step's counters (integers among its metrics) of which a launch
+        # and a window keep the largest where they add up the others; the
+        # driver says which, from the model's family (``COUNTER_MAXIMA``)
+        self.counter_maxima: Tuple[str, ...] = ()
         self.collectives: Optional[Dict[str, Dict[str, int]]] = None
         # done-hook plumbing: the step path enqueues, one watcher thread
         # blocks on output buffers FIFO (launch order), so finalize order
@@ -261,9 +269,11 @@ class TrainRecorder(RecorderCore):
                 rec["phases"]["host_tax"] += max(0.0, host_tax_s)
             self._overhead_s += time.perf_counter() - t_in
 
-    def finalize_launch(self, seq: int, t_done: float) -> None:
+    def finalize_launch(self, seq: int, t_done: float,
+                        counters: Optional[Dict[str, int]] = None) -> None:
         """Device-done: close the record — compute ``device_compute``
-        (done minus dispatch-return) and the launch wall. Fired by the
+        (done minus dispatch-return) and the launch wall, and keep the
+        launch's ``counters`` where its metrics carried any. Fired by the
         watcher thread; synthetic tests call it directly."""
         if not self.enabled:
             return
@@ -273,6 +283,8 @@ class TrainRecorder(RecorderCore):
             if rec is None:
                 self._overhead_s += time.perf_counter() - t_in
                 return
+            if counters:
+                rec["counters"] = counters
             rec["t_done"] = t_done
             rec["phases"]["device_compute"] = \
                 max(0.0, t_done - rec["t_dispatch_end"])
@@ -312,11 +324,57 @@ class TrainRecorder(RecorderCore):
             if item is None:
                 return
             seq, outputs = item
+            counters = None
             try:
                 self._block_on(outputs)
+                counters = self._read_counters(outputs)
             except Exception:  # noqa: BLE001 — a deleted/odd buffer still
                 pass           # gets a done stamp (device_compute ~ 0)
-            self.finalize_launch(seq, time.time())
+            self.finalize_launch(seq, time.time(), counters)
+
+    def _read_counters(self, outputs: Any) -> Optional[Dict[str, int]]:
+        """The step's counters off a launch's metrics, which are ready:
+        the ``[K]`` arrays of integers among them, read where the loss is
+        waited for. None for metrics that carry none."""
+        if not isinstance(outputs, dict):
+            return None
+        import numpy as np
+
+        out = {}
+        for name, value in outputs.items():
+            value = np.asarray(value)
+            if np.issubdtype(value.dtype, np.integer):
+                out[name] = int(value.max() if name in self.counter_maxima
+                                else value.sum())
+        return out or None
+
+    def _fold_counters(self, recs: List[Dict[str, Any]]) -> Dict[str, int]:
+        """Launch records' counters over a window: {} where none has any."""
+        out: Dict[str, int] = {}
+        for r in recs:
+            for name, v in (r.get("counters") or {}).items():
+                out[name] = (max(out.get(name, 0), v)
+                             if name in self.counter_maxima
+                             else out.get(name, 0) + v)
+        return out
+
+    def launch_totals(self) -> Optional[Dict[str, Any]]:
+        """The finished launches as one entry: their number, the extent
+        from the first one's start to the last one's end on the wall clock,
+        and their counters, folded and launch by launch in order
+        (``per_launch``, for a reader that wants some of them: a window
+        without its warm-up). What the trainer's process keeps of a run
+        once the worker is gone (``JaxTrainer`` records it as the
+        ``train_launches`` span); None before any launch finished."""
+        with self._lock:
+            recs = [r for r in self._launches if "t_done" in r]
+        if not recs:
+            return None
+        return {"launches": len(recs), "steps": sum(r["k"] for r in recs),
+                "t0": min(r["t"] for r in recs),
+                "t1": max(r["t_done"] for r in recs),
+                "per_launch": [dict(r.get("counters") or {}) for r in recs],
+                **self._fold_counters(recs)}
 
     @staticmethod
     def _block_on(outputs: Any) -> None:
@@ -396,7 +454,8 @@ class TrainRecorder(RecorderCore):
             "flash_plans": [dict(p) for p in self.flash_plans],
             "expert_placement": self.expert_placement,
             "collectives": {k: dict(v) for k, v in
-                            (self.collectives or {}).items()}}
+                            (self.collectives or {}).items()},
+            "routing": self._fold_counters(recs)}
         if not recs:
             return out
         phase_totals = {p: 0.0 for p in LAUNCH_PHASES}
@@ -520,6 +579,8 @@ class TrainRecorder(RecorderCore):
             out["wall_ms"] = round(r["wall_s"] * 1e3, 3)
         if "gap_s" in r:
             out["gap_ms"] = round(r["gap_s"] * 1e3, 3)
+        if "counters" in r:
+            out["counters"] = dict(r["counters"])
         return out
 
     # -- off-step drain (template in recorder_core; hooks below) -----------

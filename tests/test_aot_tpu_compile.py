@@ -101,6 +101,36 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, b, s, h, hkv, d):
                              for kind in ("dkv", "dq", "fwd")], calls
 
 
+@pytest.mark.parametrize("window", [4096, None])
+def test_banded_flash_compiles_for_v5e_at_trinitys_shape(topo, window):
+    """The Trinity cell's attention (48 heads over 8 of 128, s 8192), banded
+    and full: Mosaic takes the band's clamped index maps and masks, and each
+    call carries the name ``benchmark/kernels/flash_band.py`` costs it by."""
+    from benchmark.kernels import flash_band
+
+    b, s, h, hkv, d = 1, 8192, 48, 8, 128
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16, sharding=one)
+
+    def loss(q, k, v):
+        return flash.flash_attention(q, k, v, causal=True, window=window
+                                     ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    calls = [flash_band.call_shape(line.strip())
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert sorted(calls) == [(kind, b * h, s, s, d, True, window or 0, 2)
+                             for kind in ("dkv", "dq", "fwd")], calls
+    for kind in flash.KINDS:
+        p = flash.plan(s, s, d, 2, True, kind, window=window)
+        assert p.vmem_bytes <= p.vmem_limit_bytes <= 64 << 20, p
+        assert (p.block_q, p.block_k, p.live_steps) == (
+            1024, 1024, 30 if window else 36), p
+
+
 def test_flash_plan_at_the_train_cells_shape():
     """What ``plan`` chooses where the benchmark trains (bh 32, s 4096,
     d 128, bf16, causal): tiles of at least 512 a side, at most 0.65 of the

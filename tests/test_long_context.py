@@ -175,6 +175,88 @@ class TestFlashTilings:
             assert worst < 2e-2
 
 
+# (seq_q, seq_k, block_q, block_k, q_offset, window); blocks None = planned
+BANDS = [
+    (96, 96, 32, 32, 0, 40),          # the band's edge crosses blocks
+    (96, 96, 32, 64, 0, 17),          # k the wider block, a narrow band
+    (96, 96, 64, 32, 0, 33),          # q the wider: k blocks below the band
+    (77, 77, 32, 32, 0, 20),          # no multiple of the block
+    (96, 96, 32, 32, 0, 1),           # a query sees itself alone
+    (96, 96, 32, 32, 0, 32),          # the band one block wide exactly
+    (96, 96, 32, 32, 0, 200),         # wider than the sequence: causal
+    (64, 160, 32, 32, 96, 48),        # an offset: the band starts mid-keys
+    (300, 300, None, None, 0, 100),   # planned: one block of 384
+]
+
+
+class TestFlashBand:
+    @pytest.mark.parametrize("sq,sk,bq,bk,off,window", BANDS)
+    def test_forward_and_gradients_match_mha_window(self, sq, sk, bq, bk,
+                                                    off, window):
+        """The banded kernels, forward and all three gradients, against
+        ``mha(window=)`` at the tolerances of the causal tilings: pairs
+        wholly below the band are skipped (the walked index clamped to the
+        first live block in fwd and dq, the last in dkv) and the band's
+        lower edge is masked where it crosses a block."""
+        b, hq, hkv, d = 2, 4, 2, 16
+        key = jax.random.key(13)
+        rnd = lambda i, s, h: jax.random.normal(
+            jax.random.fold_in(key, i), (b, s, h, d), jnp.float32)
+        q, do = rnd(1, sq, hq), rnd(4, sq, hq)
+        k, v = rnd(2, sk, hkv), rnd(3, sk, hkv)
+        o_ref, vjp = jax.vjp(lambda q, k, v: mha(
+            q, k, v, causal=True, q_offset=off, window=window), q, k, v)
+        o, vjp_f = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, q_offset=off, window=window, block_q=bq,
+            block_k=bk), q, k, v)
+        assert jnp.abs(o - o_ref).max() < 1e-5
+        for got, want in zip(vjp_f(do), vjp(do)):
+            assert got.shape == want.shape
+            # with one key a row dq is exactly 0: the floor keeps the
+            # kernel's 1e-6 of rounding from being divided by it
+            assert jnp.abs(got - want).max() < 1e-4 * (
+                jnp.abs(want).max() + 0.1)
+
+    def test_a_forgotten_band_is_seen(self):
+        """The counter-case: the causal kernel on the same inputs lies far
+        outside the tolerance the banded one is held to."""
+        q, k, v = _qkv()
+        want = mha(q, k, v, causal=True, window=17)
+        full = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
+        assert jnp.abs(full - want).max() > 0.1
+
+    def test_a_window_needs_causal_attention_and_a_key(self):
+        q, k, v = _qkv()
+        with pytest.raises(ValueError, match="window"):
+            flash_attention(q, k, v, causal=False, window=8)
+        with pytest.raises(ValueError, match="window"):
+            flash.plan(96, 96, 16, 4, True, "fwd", window=0)
+
+    @pytest.mark.parametrize("seq,blocks,window,live", [
+        # the cell's shape in the planned 1024 tiles: q block i sees k
+        # blocks max(0, i - 4) .. i, because 1024 (i - j) - 1023 < 4096
+        # holds up to i - j = 4: 1 + 2 + 3 + 4 + 5 * 4 of the causal 36
+        (8192, (1024, 1024), 4096, 30),
+        # 512 (i - j) - 511 < 1024 up to i - j = 2: 1 + 2 + 3 * 6
+        (4096, (512, 512), 1024, 21),
+        (4096, (512, 512), 1000, 21),   # a ragged band, the same blocks
+        (4096, (512, 512), 513, 15),    # i - j <= 1: 1 + 2 * 7
+        (4096, (512, 512), 1, 8),       # the diagonal blocks alone
+        (4096, (512, 512), 1 << 20, 36),  # wider than the sequence: causal
+        # nq 8, nk 4: q block i holds rows 512 i .. 512 i + 511, k block j
+        # keys 1024 j .. 1024 j + 1023; live iff 1024 j <= 512 i + 511 and
+        # 512 i - 1024 j - 1023 < 1024. i = 0, 1: j 0; 2, 3: j 0, 1 (i = 4
+        # and j = 0 give 2048 - 1023 = 1025); 4, 5: j 1, 2; 6, 7: j 2, 3
+        (4096, (512, 1024), 1024, 14),
+    ])
+    def test_plan_counts_the_band(self, seq, blocks, window, live):
+        for kind in flash.KINDS:
+            p = flash.plan(seq, seq, 128, 2, True, kind, blocks, window)
+            assert p.live_steps == live, kind
+            assert p[:4] == flash.plan(seq, seq, 128, 2, True, kind,
+                                       blocks)[:4]
+
+
 class TestFlashPlans:
     def test_plan_counts_live_steps(self):
         p = flash.plan(4096, 4096, 128, 2, True, "fwd", (512, 512))

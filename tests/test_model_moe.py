@@ -44,7 +44,7 @@ def test_moe_dispatch_identity_with_ample_capacity(cfg):
 
     h = jax.random.normal(jax.random.key(3), (2, 8, c.d_model),
                           c.compute_dtype)
-    out, _ = moe._moe_ffn(c, h, layer)
+    out, _, _ = moe._moe_ffn(c, h, layer)
 
     tokens = h.reshape(-1, c.d_model)
     logits = tokens @ layer["router"].astype(jnp.float32)
@@ -73,7 +73,7 @@ def test_moe_capacity_overflow_drops_tokens(cfg):
 
     h = jax.random.normal(jax.random.key(3), (1, 16, c.d_model),
                           c.compute_dtype)
-    out, _ = moe._moe_ffn(c, h, layer)
+    out, _, _ = moe._moe_ffn(c, h, layer)
     flat = np.asarray(out.reshape(16, -1), np.float32)
     zero_rows = (np.abs(flat).max(axis=1) < 1e-6).sum()
     assert zero_rows >= 14  # ~1 slot served, rest dropped
@@ -150,7 +150,7 @@ def test_rows_by_index_agree_with_the_one_hot_products(
 
     def run(ffn):
         def scalar(h, layer):
-            out, aux = ffn(c, h, layer)
+            out, aux, *_ = ffn(c, h, layer)
             return (out.astype(jnp.float32) * weigh).sum() + aux, (out, aux)
 
         (_, (out, aux)), grads = jax.jit(jax.value_and_grad(
@@ -194,13 +194,13 @@ def test_moe_aux_loss_prefers_balance(cfg):
 
     uniform = dict(layer)
     uniform["router"] = jnp.zeros_like(layer["router"])
-    _, aux_uniform = moe._moe_ffn(c, h, uniform)
+    _, aux_uniform, _ = moe._moe_ffn(c, h, uniform)
 
     collapsed = dict(layer)
     r = np.zeros_like(np.asarray(layer["router"], np.float32))
     r[:, 0] = 100.0
     collapsed["router"] = jnp.asarray(r, layer["router"].dtype)
-    _, aux_collapsed = moe._moe_ffn(c, h, collapsed)
+    _, aux_collapsed, _ = moe._moe_ffn(c, h, collapsed)
 
     assert float(aux_collapsed) > float(aux_uniform)
     assert abs(float(aux_uniform) - 1.0) < 0.2

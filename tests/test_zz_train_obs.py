@@ -290,7 +290,7 @@ def test_window_summary_carves_launches():
         # synthetic recorder traced no flash kernel and compiled no step)
         assert rec.window_summary(0.0, 999.0) == {
             "window_launches": 0, "flash_plans": [],
-            "expert_placement": None, "collectives": {}}
+            "expert_placement": None, "collectives": {}, "routing": {}}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
     finally:
@@ -462,7 +462,13 @@ def test_api_train_and_cli_json(rt_cluster):
                                data_wait_s=0.05, h2d_s=0.01,
                                dispatch_s=0.1, k=4, tokens=256,
                                batch_shape=(4, 2, 17), flops=5e7)
-        rec.finalize_launch(s1, time.time())
+        rec.finalize_launch(s1, time.time(), {
+            "moe_assignments": 4096, "moe_held": 128, "moe_kept": 120,
+            "moe_dropped": 8, "moe_max_expert_rows": 40})
+        rec.flash_plans.append({
+            "kind": "fwd", "seq_q": 8192, "seq_k": 8192, "head_dim": 128,
+            "block_q": 1024, "block_k": 1024, "live_steps": 30,
+            "grid_steps": 64, "window": 4096})
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
@@ -503,6 +509,10 @@ def test_api_train_and_cli_json(rt_cluster):
         assert "launch gap" in text
         assert "experts placed by expert" in text
         assert "all-gather 2 (6 runs, 3.00 GB)" in text
+        assert ("routing: 4096 assignments, 128 to experts held here "
+                "(3.12%), 120 kept, 8 dropped beyond capacity, busiest "
+                "expert's queue 40 rows") in text
+        assert "30 of 64 grid steps live, window 4096" in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
