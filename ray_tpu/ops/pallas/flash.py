@@ -24,20 +24,46 @@ products are a tenth of that (32,768 steps a call at s 4096: 18.8 / 14.0 /
 slower for all three). ``block_q`` / ``block_k`` left at None are chosen per
 kernel by ``plan`` from what the call can see: ``_TARGET_BLOCK`` rows a side,
 halved until the tile's working set (operands twice for the pipeline, the
-float32 scratch, the [block_q, block_k] float32 temporaries) is inside
-``_VMEM_BUDGET_BYTES``, so a wider head or float32 operands shrink it; a
-sequence shorter than the tile is one block of its own (padded) length. The
-call raises Mosaic's scoped VMEM limit to what the plan counts. An explicit
-``block_q=`` / ``block_k=`` wins for all three kernels (on the chip such a
-block is a multiple of 128 rows or the whole padded sequence).
+float32 scratch, the [block_q, block_k] float32 temporaries of the clear
+body, a sub-tile's mask) is inside ``_VMEM_BUDGET_BYTES``, so a wide head
+with float32 operands shrinks it; a sequence shorter than the tile is one
+block of its own (padded) length. The call raises Mosaic's scoped VMEM limit
+to what the plan counts. An explicit ``block_q=`` / ``block_k=`` wins for
+all three kernels (on the chip such a block is a multiple of 128 rows or
+the whole padded sequence).
 
 What is skipped. With the causal mask, a (q block, k block) pair above the
 diagonal — by the RUNTIME offset — does nothing: no products, no ``exp``, and
 no fetch, because the block index of the walked operand is clamped to the
 last (in dkv: first) live block and the pipeline does not fetch an index
 again. A pair wholly below the diagonal and inside both true lengths skips
-the mask arithmetic; only the blocks the diagonal or a padded edge crosses
-build the iota compare. Rows no key reaches give ``o`` = 0, ``lse`` = NEG_INF.
+the mask arithmetic. A pair that an edge crosses (the diagonal, the band's
+lower edge, a true length) is cut into ``_SUB_BLOCK`` sub-tiles and each is
+judged as the pair was, by the same runtime offset: one with no live
+element does nothing, one with no masked element runs the clear body on its
+slices of the refs, and only one the edge goes through builds a mask (the
+iotas' difference held against two scalars; the terms for padded keys and
+rows only where the call has any). The sub-tile follows the tile: a side it
+does not divide, or a shorter one, is not cut, so small explicit blocks are
+masked whole as before. Rows no key reaches give ``o`` = 0, ``lse`` =
+NEG_INF.
+
+How the sub-tile was chosen (PR 44's chip runs, each call alone, bh 48,
+s 8192, d 128, bf16; a full causal call walks 8 crossed pairs of 36, one
+with ``window`` 4096 walks 12 of 30). Cutting costs ~0.7 us for each
+sub-tile entered (its four or five products and the elementwise passes
+between them run one after another, where the whole tile's overlap), so
+smaller is not better: dkv takes 10.47 / 10.02 ms (full / banded) uncut,
+9.99 / 9.00 at 512 x 512 (three of four sub-tiles worked, two masked),
+10.79 / 9.87 at 256 x 256 (ten of sixteen, four masked), 12.90 / 13.06 at
+128 x 128; dq 9.11 / 8.58, 8.77 / 7.82, 9.29 / 8.38, 11.98 / 12.49. The
+forward, whose masked tile costs 1.5 clear ones and not 2, loses at every
+size (7.48 / 6.71 uncut, 7.95 / 7.26 at 512, 9.60 / 9.74 at 256: each
+sub-tile rescales its rows' accumulator), so its sub-tile is its tile. An
+unrolled double loop over the sub-tiles times the same as the loop over
+their keys (within 0.7%) and takes 1.5 s to trace and lower where this
+takes 0.33 (the parent 0.22). The cheaper mask is worth 0.14 / 0.18 /
+0.36 ms (fwd / dq / dkv) of a banded call and nothing of a full one.
 
 The band. With ``window`` w (causal only) a query at position p sees the keys
 in (p - w, p]. A pair wholly below the band is skipped like one above the
@@ -80,10 +106,16 @@ _TARGET_BLOCK = 1024
 # 64), and Mosaic's scoped default, which a call raises only if it must
 _VMEM_BUDGET_BYTES = 40 << 20
 _VMEM_DEFAULT_LIMIT_BYTES = 16 << 20
-# [block_q, block_k] float32 temporaries a kernel's body holds at once
-# (scores, weights, their casts, the mask's two iotas; the backward adds dp,
-# ds), as if none shared a buffer: Mosaic fits the planned tile into half
-_SCORE_TEMPS = {"fwd": 5, "dq": 7, "dkv": 7}
+# rows, keys a side of the sub-tile a pair that an edge crosses is worked in,
+# the fastest of 128 ... 1024 for each of the three (module docstring); a
+# tile they do not divide is its own sub-tile, as the forward's is
+_SUB_BLOCK = {"fwd": (1024, 1024), "dq": (512, 512), "dkv": (512, 512)}
+# [block_q, block_k] float32 temporaries a kernel's clear body holds at once
+# (scores, weights, their casts; the backward adds dp, ds), as if none shared
+# a buffer: Mosaic fits the planned tile into half. The masked body works on
+# a sub-tile, where it holds the mask's two iotas beside them
+_SCORE_TEMPS = {"fwd": 3, "dq": 5, "dkv": 5}
+_MASK_TEMPS = 2
 
 
 def _needs_interpret() -> bool:
@@ -95,14 +127,18 @@ def _needs_interpret() -> bool:
 # ---------------------------------------------------------------- the plan
 
 class Plan(NamedTuple):
-    """One kernel's tiling at one shape. ``grid_steps`` and ``live_steps``
-    count (q block, k block) pairs of one head: all of them, and those that
-    do work at ``q_offset`` 0."""
+    """One kernel's tiling at one shape. ``grid_steps``, ``live_steps`` and
+    ``edge_steps`` count (q block, k block) pairs of one head: all of them,
+    those that do work at ``q_offset`` 0, and of those the ones an edge (the
+    diagonal, the band's lower edge, a true length) crosses, which are
+    worked ``sub_block`` (rows, keys) at a time."""
     kind: str
     block_q: int
     block_k: int
     grid_steps: int
     live_steps: int
+    edge_steps: int
+    sub_block: Tuple[int, int]
     vmem_bytes: int
     vmem_limit_bytes: int
 
@@ -127,11 +163,20 @@ def _fit_block(target: int, seq: int) -> int:
     return _round_up(-(-seq // n), _LANES)
 
 
+def _sub_block(kind: str, block_q: int, block_k: int) -> Tuple[int, int]:
+    """The sub-tile of a (block_q, block_k) tile: it follows the tile, a
+    side that the sub-tile's does not divide (a shorter one) is not cut."""
+    sub_q, sub_k = _SUB_BLOCK[kind]
+    return (block_q if block_q % sub_q else sub_q,
+            block_k if block_k % sub_k else sub_k)
+
+
 def _vmem_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
                 itemsize: int) -> int:
     """What one grid step holds: each operand and result block twice (the
     pipeline's two buffers), a [rows, 1] float32 block padded to 128 lanes,
-    the float32 scratch, and the score-sized temporaries."""
+    the float32 scratch, the score-sized temporaries of the clear body and
+    the masked body's, a sub-tile's."""
     d = _round_up(head_dim, _LANES)
     q_blk, k_blk = block_q * d * itemsize, block_k * d * itemsize
     q_col = block_q * _LANES * 4
@@ -144,20 +189,42 @@ def _vmem_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
     else:                # q k v do lse delta (rows) -> dk dv | dk dv
         blocks = 2 * (2 * q_blk + 4 * k_blk + 2 * 8 * block_q * 4)
         scratch = 2 * block_k * d * 4
-    return blocks + scratch + _SCORE_TEMPS[kind] * block_q * block_k * 4
+    sub_q, sub_k = _sub_block(kind, block_q, block_k)
+    return (blocks + scratch + _SCORE_TEMPS[kind] * block_q * block_k * 4
+            + _MASK_TEMPS * sub_q * sub_k * 4)
 
 
-def _live(i, j, block_q: int, block_k: int, q_offset):
-    """Does q block ``i`` see any key of k block ``j`` under the causal
-    mask: the block's last row is at or after the block's first key."""
-    return j * block_k <= i * block_q + block_q - 1 + q_offset
+def _live(q_lo, rows: int, k_lo, keys: int):
+    """Does any of the ``rows`` rows from position ``q_lo`` see any of the
+    ``keys`` keys from ``k_lo`` under the causal mask: the last row is at
+    or after the first key."""
+    return k_lo <= q_lo + rows - 1
 
 
-def _in_band(i, j, block_q: int, block_k: int, q_offset, window: int):
-    """Does any key of k block ``j`` lie in the band of a row of q block
-    ``i``: the block's first row is less than ``window`` after the block's
-    last key."""
-    return i * block_q + q_offset - (j * block_k + block_k - 1) < window
+def _in_band(q_lo, rows: int, k_lo, keys: int, window: int):
+    """Does any of the keys lie in the band of any of the rows: the first
+    row is less than ``window`` after the last key."""
+    return q_lo - (k_lo + keys - 1) < window
+
+
+def _live_and_clear(row0, rows: int, key0, keys: int, q_offset, *, causal,
+                    window, q_len: int, kv_len: int):
+    """Of the rectangle of ``rows`` rows from index ``row0`` (position
+    ``row0 + q_offset``) and ``keys`` keys from ``key0``: does it hold a
+    pair the mask leaves alive, and does it hold no other (every key at or
+    before every row and inside every row's band, no padded row or key).
+    The one rule for a (q block, k block) pair and for a sub-tile of it,
+    on Python integers (``plan``) and on the kernel's scalars alike."""
+    q_lo = row0 + q_offset
+    live = (row0 < q_len) & (key0 < kv_len)
+    clear = (row0 + rows <= q_len) & (key0 + keys <= kv_len)
+    if causal:
+        live = live & _live(q_lo, rows, key0, keys)
+        clear = clear & (key0 + keys - 1 <= q_lo)
+    if window is not None:
+        live = live & _in_band(q_lo, rows, key0, keys, window)
+        clear = clear & (q_lo + rows - 1 - key0 < window)
+    return live, clear
 
 
 def _check_window(causal: bool, window: Optional[int]) -> None:
@@ -186,11 +253,13 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
             tq, tk = (tq // 2, tk) if tq >= tk else (tq, tk // 2)
         bq, bk = _fit_block(tq, seq_q), _fit_block(tk, seq_k)
     nq, nk = -(-seq_q // bq), -(-seq_k // bk)
-    live = sum(_live(i, j, bq, bk, 0)
-               and (window is None or _in_band(i, j, bq, bk, 0, window))
-               for i in range(nq) for j in range(nk)) if causal else nq * nk
+    pairs = [_live_and_clear(i * bq, bq, j * bk, bk, 0, causal=causal,
+                             window=window, q_len=seq_q, kv_len=seq_k)
+             for i in range(nq) for j in range(nk)]
     need = _vmem_bytes(kind, bq, bk, head_dim, itemsize)
-    return Plan(kind, bq, bk, nq * nk, live, need,
+    return Plan(kind, bq, bk, nq * nk, sum(live for live, _ in pairs),
+                sum(live and not clear for live, clear in pairs),
+                _sub_block(kind, bq, bk), need,
                 max(_VMEM_DEFAULT_LIMIT_BYTES, need))
 
 
@@ -234,37 +303,65 @@ def _planned(kind: str, q, k, causal: bool,
 # ------------------------------------------------------- what a step skips
 
 def _on_live_steps(step, qi, kk, qoff, *, causal, window, block_q, block_k,
-                   q_len, kv_len, q_axis):
-    """Run ``step(mask_of)`` for the (q block, k block) pair, at most once:
-    not at all if the causal mask or the band leaves the pair nothing; with
-    ``mask_of`` None if no element of it is masked (every key at or before
-    every row and inside every row's band, no padded row or key in it);
-    else with the function that builds the mask of a tile: keys inside the
-    true length and, causal, at or before their row and less than ``window``
-    before it. ``q_axis`` is the axis rows lie on (1 in dkv's transposed
-    tile)."""
-    q_lo, k_lo = qi * block_q + qoff, kk * block_k
-    clear = ((qi + 1) * block_q <= q_len) & (k_lo + block_k <= kv_len)
-    live = True
-    if causal:
-        live = _live(qi, kk, block_q, block_k, qoff)
-        clear = clear & (k_lo + block_k - 1 <= q_lo)
-    if window is not None:
-        live = live & _in_band(qi, kk, block_q, block_k, qoff, window)
-        clear = clear & (q_lo + block_q - 1 - k_lo < window)
+                   sub_block, q_len, kv_len, q_axis):
+    """Run ``step(rows, keys, mask_of)`` over the (q block, k block) pair,
+    ``rows`` and ``keys`` slices of the tile. Not at all if the mask leaves
+    the pair nothing; once over the whole tile with ``mask_of`` None if no
+    element of it is masked (``_live_and_clear``). A pair an edge crosses
+    is cut into ``sub_block`` sub-tiles and each is judged by the same rule
+    from the same runtime offset: nothing, the clear body, or, where the
+    edge goes through it, the body with the function that builds the mask
+    of that sub-tile: keys inside the true length and, causal, at or before
+    their row and less than ``window`` before it. The sub-tiles' rows are
+    static slices (in dkv they index lanes), their keys a loop's: two
+    bodies a row of sub-tiles are traced and lowered, not two a sub-tile.
+    A tile that is its own sub-tile is masked whole. ``q_axis`` is the axis
+    rows lie on (1 in dkv's transposed tile)."""
+    sub_q, sub_k = sub_block
 
-    def mask_of(shape):
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-        mask = kpos < kv_len
-        if causal:
-            qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
-            mask = mask & (qpos >= kpos)
-            if window is not None:
-                mask = mask & (qpos - kpos < window)
-        return mask
+    def run(row0, rows, key0, keys, cut):
+        q0, k0 = qi * block_q + row0, kk * block_k + key0
+        live, clear = _live_and_clear(q0, rows, k0, keys, qoff, causal=causal,
+                                      window=window, q_len=q_len,
+                                      kv_len=kv_len)
+        at = pl.ds(row0, rows), pl.ds(key0, keys)
 
-    pl.when(live & clear)(lambda: step(None))
-    pl.when(live & jnp.logical_not(clear))(lambda: step(mask_of))
+        def mask_of(shape, lse=None):
+            # element (i, j) is row q0 + qoff + i against key k0 + j: the
+            # iotas' difference is held against scalars, and a term that no
+            # tile of this call can need (no padded key, no padded row) is
+            # not built
+            key = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+            mask = None
+            if causal:
+                ahead = jax.lax.broadcasted_iota(
+                    jnp.int32, shape, q_axis) - key
+                lead = q0 + qoff - k0
+                mask = ahead >= -lead
+                if window is not None:
+                    mask = mask & (ahead < window - lead)
+            if mask is None or kv_len % block_k:
+                inside = key < kv_len - k0
+                mask = inside if mask is None else mask & inside
+            if lse is not None and q_len % block_q:
+                # a padded row has lse = NEG_INF (one no key reaches too,
+                # but the terms above leave such a row nothing already)
+                mask = mask & (lse > NEG_INF / 2)
+            return mask
+
+        pl.when(live & clear)(lambda: step(*at, None))
+
+        @pl.when(live & jnp.logical_not(clear))
+        def _crossed():
+            if not cut:
+                step(*at, mask_of)
+                return
+            for r in range(0, rows, sub_q):
+                pl.loop(0, keys // sub_k)(lambda c: run(
+                    r, sub_q, pl.multiple_of(c * sub_k, sub_k), sub_k,
+                    cut=False))
+
+    run(0, block_q, 0, block_k, cut=(sub_q, sub_k) != (block_q, block_k))
 
 
 def _last_live_k(i, qoff, block_q, block_k, nk):
@@ -313,11 +410,11 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref,  # inputs
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def step(mask_of):
-        s = _dot(q_ref[0], k_ref[0], _NT) * scale      # [bq, bk]
+    def step(rows, keys, mask_of):
+        s = _dot(q_ref[0, rows], k_ref[0, keys], _NT) * scale  # [rows, keys]
         if mask_of:
             s = jnp.where(mask_of(s.shape), s, NEG_INF)
-        m_prev = m_scr[...]                            # [bq, 1]
+        m_prev = m_scr[rows]                           # [rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # A row no key has reached yet keeps m at NEG_INF, where
         # exp(s - m) = exp(0) would count its masked keys: exponentiate
@@ -325,11 +422,11 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref,  # inputs
         m_exp = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new) if mask_of \
             else m_new
         alpha = jnp.exp(m_prev - m_exp)
-        p = jnp.exp(s - m_exp)                         # [bq, bk] fp32
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * alpha + _dot(
-            p.astype(v_ref.dtype), v_ref[0], _NN)
+        p = jnp.exp(s - m_exp)                         # [rows, keys] fp32
+        l_scr[rows] = l_scr[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[rows] = m_new
+        acc_scr[rows] = acc_scr[rows] * alpha + _dot(
+            p.astype(v_ref.dtype), v_ref[0, keys], _NN)
 
     _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=0, **tile)
 
@@ -378,7 +475,8 @@ def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
     nq, nk = q.shape[1] // p.block_q, k.shape[1] // p.block_k
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=p.block_q, block_k=p.block_k, q_len=sq, kv_len=sk)
+        block_q=p.block_q, block_k=p.block_k, sub_block=p.sub_block, q_len=sq,
+        kv_len=sk)
     qspec, kspec = _walks_k(p, causal, nk, window)
     o, lse = pl.pallas_call(
         kernel,
@@ -414,17 +512,16 @@ def _dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def step(mask_of):
-        k = k_ref[0]
-        s = _dot(q_ref[0], k, _NT) * scale             # [bq, bk]
-        lse = lse_ref[0]                               # [bq, 1]
+    def step(rows, keys, mask_of):
+        k = k_ref[0, keys]
+        s = _dot(q_ref[0, rows], k, _NT) * scale       # [rows, keys]
+        lse = lse_ref[0, rows]                         # [rows, 1]
         p = jnp.exp(s - lse)
         if mask_of:
-            # a row no key reaches (or a padded one) has lse = NEG_INF
-            p = jnp.where(mask_of(s.shape) & (lse > NEG_INF / 2), p, 0.0)
-        dp = _dot(do_ref[0], v_ref[0], _NT)
-        ds = p * (dp - delta_ref[0])
-        dq_scr[...] += _dot(ds.astype(k.dtype), k, _NN)
+            p = jnp.where(mask_of(s.shape, lse), p, 0.0)
+        dp = _dot(do_ref[0, rows], v_ref[0, keys], _NT)
+        ds = p * (dp - delta_ref[0, rows])
+        dq_scr[rows] += _dot(ds.astype(k.dtype), k, _NN)
 
     _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=0, **tile)
 
@@ -447,17 +544,17 @@ def _dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def step(mask_of):
-        q, do = q_ref[0], do_ref[0]
-        s = _dot(k_ref[0], q, _NT) * scale             # [bk, bq]
-        lse = lse_ref[0]                               # [1, bq]
+    def step(rows, keys, mask_of):
+        q, do = q_ref[0, rows], do_ref[0, rows]
+        s = _dot(k_ref[0, keys], q, _NT) * scale       # [keys, rows]
+        lse = lse_ref[0, :, rows]                      # [1, rows]
         p = jnp.exp(s - lse)
         if mask_of:
-            p = jnp.where(mask_of(s.shape) & (lse > NEG_INF / 2), p, 0.0)
-        dv_scr[...] += _dot(p.astype(do.dtype), do, _NN)
-        dp = _dot(v_ref[0], do, _NT)
-        ds = p * (dp - delta_ref[0])
-        dk_scr[...] += _dot(ds.astype(q.dtype), q, _NN)
+            p = jnp.where(mask_of(s.shape, lse), p, 0.0)
+        dv_scr[keys] += _dot(p.astype(do.dtype), do, _NN)
+        dp = _dot(v_ref[0, keys], do, _NT)
+        ds = p * (dp - delta_ref[0, :, rows])
+        dk_scr[keys] += _dot(ds.astype(q.dtype), q, _NN)
 
     _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=1, **tile)
 
@@ -490,7 +587,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
     qspec, kspec = _walks_k(p, causal, nk, window)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_q=p.block_q, block_k=p.block_k,
-                          **common),
+                          sub_block=p.sub_block, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, nk),
@@ -527,7 +624,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                            lambda b, j, i, qoff: (b, 0, q_index(b, j, i, qoff)))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=p.block_q, block_k=p.block_k,
-                          **common),
+                          sub_block=p.sub_block, **common),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nk, nq),

@@ -1204,6 +1204,9 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"d{fp['head_dim']}: tile {fp['block_q']}x"
                   f"{fp['block_k']}, {fp['live_steps']} of "
                   f"{fp['grid_steps']} grid steps live"
+                  + (f", {fp['edge_steps']} of them crossed by an edge, "
+                     f"sub-tile {fp['sub_block'][0]}x{fp['sub_block'][1]}"
+                     if fp.get("edge_steps") else "")
                   + (f", window {fp['window']}" if fp.get("window") else ""))
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):

@@ -158,8 +158,8 @@ class TestFlashTilings:
         by one block) and has to fail that bound."""
         if diagonal_skipped:
             monkeypatch.setattr(
-                flash, "_live", lambda i, j, bq, bk, off:
-                (j + 1) * bk <= i * bq + off)
+                flash, "_live", lambda q_lo, rows, k_lo, keys:
+                k_lo + keys <= q_lo)
         q, k, v = _qkv(s=160, d=32, dtype=jnp.bfloat16)
         f32 = lambda x: x.astype(jnp.float32)
         want = jax.grad(lambda *a: (mha(*a, causal=True) ** 2).sum(),
@@ -232,43 +232,192 @@ class TestFlashBand:
         with pytest.raises(ValueError, match="window"):
             flash.plan(96, 96, 16, 4, True, "fwd", window=0)
 
-    @pytest.mark.parametrize("seq,blocks,window,live", [
+    @pytest.mark.parametrize("seq,blocks,window,live,edge", [
         # the cell's shape in the planned 1024 tiles: q block i sees k
         # blocks max(0, i - 4) .. i, because 1024 (i - j) - 1023 < 4096
-        # holds up to i - j = 4: 1 + 2 + 3 + 4 + 5 * 4 of the causal 36
-        (8192, (1024, 1024), 4096, 30),
-        # 512 (i - j) - 511 < 1024 up to i - j = 2: 1 + 2 + 3 * 6
-        (4096, (512, 512), 1024, 21),
-        (4096, (512, 512), 1000, 21),   # a ragged band, the same blocks
-        (4096, (512, 512), 513, 15),    # i - j <= 1: 1 + 2 * 7
-        (4096, (512, 512), 1, 8),       # the diagonal blocks alone
-        (4096, (512, 512), 1 << 20, 36),  # wider than the sequence: causal
+        # holds up to i - j = 4: 1 + 2 + 3 + 4 + 5 * 4 of the causal 36.
+        # An edge crosses the 8 on the diagonal and the 4 with i - j = 4
+        # (row 1024 i sees key 1024 j + 1 and no earlier one)
+        (8192, (1024, 1024), 4096, 30, 12),
+        # 512 (i - j) - 511 < 1024 up to i - j = 2: 1 + 2 + 3 * 6; the
+        # diagonal's 8 and the 6 with i - j = 2
+        (4096, (512, 512), 1024, 21, 14),
+        # a ragged band, the same blocks: its edge now leaves the pairs
+        # with i - j = 1 too (row 512 i + 511 misses key 512 j below 24)
+        (4096, (512, 512), 1000, 21, 21),
+        (4096, (512, 512), 513, 15, 15),  # i - j <= 1: 1 + 2 * 7, all cut
+        (4096, (512, 512), 1, 8, 8),    # the diagonal blocks alone
+        (4096, (512, 512), 1 << 20, 36, 8),  # wider than the sequence: causal
         # nq 8, nk 4: q block i holds rows 512 i .. 512 i + 511, k block j
         # keys 1024 j .. 1024 j + 1023; live iff 1024 j <= 512 i + 511 and
         # 512 i - 1024 j - 1023 < 1024. i = 0, 1: j 0; 2, 3: j 0, 1 (i = 4
-        # and j = 0 give 2048 - 1023 = 1025); 4, 5: j 1, 2; 6, 7: j 2, 3
-        (4096, (512, 1024), 1024, 14),
+        # and j = 0 give 2048 - 1023 = 1025); 4, 5: j 1, 2; 6, 7: j 2, 3.
+        # Clear are only those whose 1024 keys all lie at or before row
+        # 512 i and after row 512 i + 511 - 1024: none, the band is as wide
+        # as the block
+        (4096, (512, 1024), 1024, 14, 14),
     ])
-    def test_plan_counts_the_band(self, seq, blocks, window, live):
+    def test_plan_counts_the_band(self, seq, blocks, window, live, edge):
         for kind in flash.KINDS:
             p = flash.plan(seq, seq, 128, 2, True, kind, blocks, window)
-            assert p.live_steps == live, kind
+            assert (p.live_steps, p.edge_steps) == (live, edge), kind
             assert p[:4] == flash.plan(seq, seq, 128, 2, True, kind,
                                        blocks)[:4]
+
+
+# (seq_q, seq_k, block_q, block_k, sub_q, sub_k, q_offset, window, causal):
+# the sub-tile made small, so that a tile of 128 is cut as the chip's 1024 is
+SUB_TILES = [
+    (128, 128, 128, 128, 32, 32, 0, None, True),    # the diagonal alone
+    (256, 256, 128, 128, 32, 32, 0, 144, True),     # the band's edge alone
+    #                            (pair (1, 0): keys 0..127 all before row 128)
+    (128, 128, 128, 128, 32, 32, 0, 40, True),      # both edges in one tile
+    (128, 128, 128, 128, 32, 32, 0, 1, True),       # a query sees itself
+    (256, 256, 128, 128, 32, 32, 37, None, True),   # an offset off the grid
+    (256, 256, 128, 128, 32, 32, -45, None, True),  # ... and rows unreached
+    (192, 256, 64, 128, 32, 32, 83, 70, True),      # a band slid off the grid
+    (150, 215, 128, 128, 32, 32, 65, None, True),   # padded edges inside
+    (150, 215, 128, 128, 32, 32, 0, None, False),   # ... and no other mask
+    (128, 128, 128, 128, 32, 32, -1000, None, True),  # a wholly masked chunk
+    (256, 256, 128, 128, 32, 64, 0, 100, True),     # a sub-tile not square
+    (256, 256, 128, 128, 64, 32, 19, 100, True),
+    (200, 200, 96, 96, 32, 32, 0, 50, True),        # a row of sub-tiles each
+    (96, 96, 32, 64, 32, 64, 0, 17, True),          # the sub-tile is the tile
+    (160, 160, 80, 80, 32, 32, 0, None, True),      # ... as 32 divides no 80
+]
+
+
+def _flash_all(q, k, v, do, off, window, causal, blocks):
+    """(o, lse, dq, dk, dv) of the three kernels on [b, s, h, d] with a
+    TRACED offset and a band: what ``flash_attention_with_lse`` and
+    ``flash_vjp_chunk`` run, which take no ``window``."""
+    b = q.shape[0]
+    kw = dict(scale=q.shape[-1] ** -0.5, causal=causal, blocks=blocks,
+              interpret=True, window=window)
+
+    @jax.jit
+    def run(q, k, v, do, off):
+        qb, kb, vb = flash._prep(q, k, v)
+        o, lse = flash._flash_fwd_bhsd(qb, kb, vb, flash._qoff(off), **kw)
+        g = flash._flash_bwd_bhsd(qb, kb, vb, o, lse, flash._to_bhsd(do),
+                                  flash._qoff(off), **kw)
+        return (flash._from_bhsd(o, b), lse.reshape(b, -1, q.shape[1]),
+                *(flash._from_bhsd(x, b) for x in g))
+
+    return run(q, k, v, do, jnp.int32(off))
+
+
+def _mha_all(q, k, v, do, off, window, causal):
+    """The same five from ``mha``, and which rows see a key: a row that sees
+    none is 0 / NEG_INF in the kernels and uniform in ``mha``."""
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    i, j = jnp.arange(sq)[:, None] + off, jnp.arange(sk)[None, :]
+    alive = jnp.ones((sq, sk), bool)
+    if causal:
+        alive = i >= j
+        if window is not None:
+            alive = alive & (i - j < window)
+    seen = alive.any(axis=1)                              # [sq]
+
+    def ref(q, k, v):
+        o = mha(q, k, v, causal=causal, q_offset=off, window=window)
+        return jnp.where(seen[None, :, None, None], o, 0.0)
+
+    o, vjp = jax.vjp(ref, q, k, v)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    lse = jax.nn.logsumexp(jnp.where(alive, logits, -jnp.inf), axis=-1)
+    return (o, lse, *vjp(do)), seen
+
+
+def _sub_tile_inputs(sq, sk):
+    key = jax.random.key(17)
+    rnd = lambda i, s: jax.random.normal(
+        jax.random.fold_in(key, i), (1, s, 2, 16), jnp.float32)
+    return rnd(1, sq), rnd(2, sk), rnd(3, sk), rnd(4, sq)
+
+
+class TestFlashSubTiles:
+    @pytest.mark.parametrize("sq,sk,bq,bk,sub_q,sub_k,off,window,causal",
+                             SUB_TILES)
+    def test_a_crossed_tile_worked_in_sub_tiles_matches_mha(
+            self, monkeypatch, sq, sk, bq, bk, sub_q, sub_k, off, window,
+            causal):
+        """Forward, ``lse`` and the three gradients where a pair an edge
+        crosses is cut into sub-tiles, each skipped, run clear or masked by
+        the runtime offset; at the tolerances of the whole-tile tests."""
+        monkeypatch.setattr(flash, "_SUB_BLOCK",
+                            {kind: (sub_q, sub_k) for kind in flash.KINDS})
+        for kind in flash.KINDS:
+            p = flash.plan(sq, sk, 16, 4, causal, kind, (bq, bk), window)
+            assert p.sub_block == (sub_q if bq % sub_q == 0 else bq,
+                                   sub_k if bk % sub_k == 0 else bk)
+        q, k, v, do = _sub_tile_inputs(sq, sk)
+        want, seen = _mha_all(q, k, v, do, off, window, causal)
+        got = _flash_all(q, k, v, do, off, window, causal, (bq, bk))
+        assert jnp.abs(got[0] - want[0]).max() < 1e-5
+        assert jnp.abs(jnp.where(seen, got[1] - want[1], 0.0)).max() < 1e-5
+        assert bool((jnp.where(seen, NEG_INF, got[1]) <= NEG_INF / 2).all())
+        for g, w in zip(got[2:], want[2:]):
+            assert g.shape == w.shape
+            assert jnp.abs(g - w).max() < 1e-4 * (jnp.abs(w).max() + 0.1)
+
+    @pytest.mark.parametrize("fault", [
+        None, "a_crossed_sub_tile_run_clear", "a_dead_sub_tile_run_clear"])
+    def test_a_sub_tile_judged_wrongly_is_seen(self, monkeypatch, fault):
+        """The counter-cases: a sub-tile the diagonal goes through run
+        without its mask, and one above the diagonal run at all, put keys
+        after a row into that row's ``lse`` and output, far outside what the
+        test above holds them to."""
+        judge = flash._live_and_clear
+
+        def misjudged(row0, rows, key0, keys, q_offset, **kw):
+            live, clear = judge(row0, rows, key0, keys, q_offset, **kw)
+            if rows == 128:        # the pair itself: judged as it is
+                return live, clear
+            if fault == "a_crossed_sub_tile_run_clear":
+                return live, live
+            return True, clear | jnp.logical_not(live)
+
+        monkeypatch.setattr(flash, "_SUB_BLOCK",
+                            {kind: (32, 32) for kind in flash.KINDS})
+        if fault:
+            monkeypatch.setattr(flash, "_live_and_clear", misjudged)
+        q, k, v, do = _sub_tile_inputs(128, 128)
+        want, _ = _mha_all(q, k, v, do, 0, None, True)
+        got = _flash_all(q, k, v, do, 0, None, True, (128, 128))
+        worst = [float(jnp.abs(g - w).max()) for g, w in zip(got, want)]
+        if fault:
+            assert min(worst) > 0.05, worst
+        else:
+            assert max(worst) < 1e-4, worst
 
 
 class TestFlashPlans:
     def test_plan_counts_live_steps(self):
         p = flash.plan(4096, 4096, 128, 2, True, "fwd", (512, 512))
-        assert (p.grid_steps, p.live_steps) == (64, 36)
+        assert (p.grid_steps, p.live_steps, p.edge_steps) == (64, 36, 8)
         p = flash.plan(4096, 4096, 128, 2, False, "dkv", (512, 512))
-        assert (p.grid_steps, p.live_steps) == (64, 64)
+        assert (p.grid_steps, p.live_steps, p.edge_steps) == (64, 64, 0)
+        # the train cells' shapes as planned: the diagonal's pairs, and
+        # (test_plan_counts_the_band) the band's lower edge beside them
+        for seq, live, edge in ((4096, 10, 4), (8192, 36, 8)):
+            p = flash.plan(seq, seq, 128, 2, True, "dkv")
+            assert (p.block_q, p.block_k, p.live_steps, p.edge_steps) == (
+                1024, 1024, live, edge)
+            assert p.sub_block == flash._SUB_BLOCK["dkv"]
+        # a padded edge crosses the pairs of the last row and column of a
+        # mask-less call; a tile that is no multiple of the sub-tile, or a
+        # shorter one, is its own sub-tile
+        p = flash.plan(1100, 1100, 128, 2, False, "dq")
+        assert (p.block_q, p.grid_steps, p.edge_steps) == (640, 4, 3)
+        assert p.sub_block == (640, 640)
+        assert flash.plan(77, 300, 64, 2, True, "dq").sub_block == (80, 384)
         # a short sequence shrinks the block, planned or explicit
         assert flash.plan(77, 300, 64, 2, True, "dq")[1:3] == (80, 384)
         assert flash.plan(77, 300, 64, 2, True, "dq", (128, 128))[1:3] \
             == (80, 128)
         # wider operands shrink the planned tile, never under the budget
-        wide = flash.plan(8192, 8192, 256, 4, True, "dq")
+        wide = flash.plan(8192, 8192, 512, 4, True, "dq")
         assert wide.block_q * wide.block_k < 1024 * 1024
         assert wide.vmem_bytes <= flash._VMEM_BUDGET_BYTES
 
@@ -294,7 +443,8 @@ class TestFlashPlans:
             assert rec.window_summary(0.0, 1e18)["flash_plans"] == plans
             for p in plans:
                 assert (p["seq_q"], p["seq_k"], p["causal"]) == (16, 16, True)
-                assert p["live_steps"] <= p["grid_steps"]
+                assert p["edge_steps"] <= p["live_steps"] <= p["grid_steps"]
+                assert p["sub_block"] == (16, 16)   # the tile: s 16
         finally:
             rec.close()
 

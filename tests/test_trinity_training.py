@@ -271,10 +271,15 @@ _SHAPE = dict(vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
 
 # the digests of commit 05a80f6 (PR 41, this PR's parent), taken there with
 # the function above: Mistral's and Mixtral's blocks (grouped-query, chunked
-# loss, top-2 of 4 experts) with the flash kernels and without
-PARENT = {("dense", "flash"): "8219d1c99c7ee37b",
+# loss, top-2 of 4 experts) with the flash kernels and without. The two
+# with the kernels are PR 44's, taken the same way: the jaxpr holds the
+# kernels' bodies, which that PR changed (a crossed tile worked in sub-tiles,
+# the refs read by slices, the mask from the iotas' difference; they were
+# 8219d1c99c7ee37b and 9c79849e144cc7ad),
+# and the two without, the same step round them, stayed as they were
+PARENT = {("dense", "flash"): "5aebecaaabf919da",
           ("dense", "xla"): "5eaa6356b79991ed",
-          ("moe", "flash"): "9c79849e144cc7ad",
+          ("moe", "flash"): "587e036cdcc80068",
           ("moe", "xla"): "13aeed2041cd205e"}
 
 
@@ -448,6 +453,12 @@ def test_the_recorder_carries_the_routing_and_the_window(family):
         # window layers and full ones plan their kernels apart
         plans = {(p["kind"], p["window"]) for p in summ["flash_plans"]}
         assert plans == {(k, w) for k in ("fwd", "dq", "dkv") for w in (8, None)}
+        # one tile a call at this length, crossed by the diagonal (and the
+        # band), and too short to be cut: the tile is its own sub-tile
+        for p in summ["flash_plans"]:
+            assert (p["grid_steps"], p["live_steps"], p["edge_steps"]) == (
+                1, 1, 1), p
+            assert p["sub_block"] == (p["block_q"], p["block_k"]), p
     finally:
         rec.close()
 

@@ -468,6 +468,7 @@ def test_api_train_and_cli_json(rt_cluster):
         rec.flash_plans.append({
             "kind": "fwd", "seq_q": 8192, "seq_k": 8192, "head_dim": 128,
             "block_q": 1024, "block_k": 1024, "live_steps": 30,
+            "edge_steps": 12, "sub_block": (512, 512),
             "grid_steps": 64, "window": 4096})
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
@@ -512,7 +513,8 @@ def test_api_train_and_cli_json(rt_cluster):
         assert ("routing: 4096 assignments, 128 to experts held here "
                 "(3.12%), 120 kept, 8 dropped beyond capacity, busiest "
                 "expert's queue 40 rows") in text
-        assert "30 of 64 grid steps live, window 4096" in text
+        assert ("30 of 64 grid steps live, 12 of them crossed by an edge, "
+                "sub-tile 512x512, window 4096") in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
