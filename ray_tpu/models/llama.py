@@ -6,7 +6,8 @@ Design choices (vs. a torch port):
     regardless of depth (fast compiles, friendly to pipeline partitioning).
   - bf16 compute / fp32 params + fp32 softmax+loss accumulation.
   - ``jax.checkpoint`` (remat) around the scanned block body with a
-    dots-saveable policy: trades HBM for recompute, the standard TPU recipe.
+    dots-saveable policy: trades HBM for recompute, the standard TPU recipe
+    (``remat_block``: the flash forward's two results are kept as well).
   - Sharding is declarative: ``sharding_rules()`` returns rules mapping the
     param tree onto a (dp, fsdp, tp) mesh; batch rides (dp, fsdp), matrices
     shard (fsdp, tp). XLA inserts the collectives.
@@ -404,6 +405,24 @@ def refuse_served_only(cfg: LlamaConfig) -> None:
             "models/hybrid.py, models/sambay.py)")
 
 
+def remat_block(cfg: LlamaConfig, fn):
+    """``fn``, a layer, as a remat block where the config asks for one. It
+    keeps the results of its matrix products and, where a flash forward ran
+    inside it, that kernel's output and log-sum-exp, which its backward
+    kernels read: a ``pallas_call`` is no product, so the dots policy alone
+    runs the forward kernel a second time in the backward. A block without
+    the kernel (``attn_impl="xla"``) carries no such name and keeps what
+    the dots policy keeps."""
+    if not cfg.remat:
+        return fn
+    from ray_tpu.ops.pallas.flash import RESIDUAL_NAMES
+
+    policies = jax.checkpoint_policies
+    return jax.checkpoint(fn, policy=policies.save_from_both_policies(
+        policies.dots_with_no_batch_dims_saveable,
+        policies.save_only_these_names(*RESIDUAL_NAMES)))
+
+
 def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
                    segment_ids: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, jax.Array]:
@@ -418,11 +437,7 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         x = _pipelined_layers(params["layers"], x, cfg, segment_ids)
     else:
         body = lambda x, layer: (_block(cfg, x, layer, sin, cos, segment_ids), None)
-        if cfg.remat:
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        x, _ = jax.lax.scan(body, x, params["layers"])
+        x, _ = jax.lax.scan(remat_block(cfg, body), x, params["layers"])
 
     x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"]).astype(cdt)

@@ -647,14 +647,6 @@ def _moe_block(cfg: MoEConfig, x: jax.Array, layer: Params,
     return ffn_half(cfg, x, layer)
 
 
-def _remat(cfg: MoEConfig, fn):
-    """``fn`` as a remat block that keeps what the old stack's keeps."""
-    if not cfg.remat:
-        return fn
-    return jax.checkpoint(
-        fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-
-
 def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
     """One layer of a patterned config as ``(x, layer) -> (x, aux, load,
     kept)``, its kind static: the attention half rotated inside the band or
@@ -703,11 +695,11 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
     for i, kind in enumerate(cfg.layer_kinds[:cfg.n_dense_layers]):
         layer = jax.tree.map(lambda a, i=i: a[i], params["dense_layers"])
         run = _patterned_layer(cfg, kind, dense=True)
-        x = _remat(cfg, lambda x, layer, run=run: run(
+        x = llama.remat_block(cfg, lambda x, layer, run=run: run(
             x, layer, sin, cos, segment_ids)[0])(x, layer)
 
     period = cfg.period()
-    runs = [_remat(cfg, lambda x, layer, run=_patterned_layer(
+    runs = [llama.remat_block(cfg, lambda x, layer, run=_patterned_layer(
         cfg, kind, dense=False): run(x, layer, sin, cos, segment_ids))
         for kind in period]
 
@@ -764,11 +756,8 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
         x, aux, load, kept = _walk(params, x, cfg, sin, cos, segment_ids)
         stats = {**routing_counters(cfg, load, kept), "router_load": load}
     else:
-        if cfg.remat:
-            body = jax.checkpoint(
-                body,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+        (x, aux), _ = jax.lax.scan(llama.remat_block(cfg, body),
+                                   (x, jnp.zeros((), jnp.float32)),
                                    params["layers"])
     with _patterned_scope(cfg, "loss_head"):
         x = llama.rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)

@@ -91,12 +91,19 @@ from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import NEG_INF
 
 KINDS = ("fwd", "dq", "dkv")
+# what ``_flash_core_fwd`` calls its output and log-sum-exp
+# (``jax.ad_checkpoint.checkpoint_name``), the two results the backward
+# kernels read: a ``jax.checkpoint`` whose policy keeps these names does not
+# run the forward kernel again in its backward; to any other they are the
+# identity
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
 
 _LANES = 128
 # rows a side of the tile a kernel takes where the sequence and the budget
@@ -698,6 +705,7 @@ def _flash_core_fwd(q, k, v, scale, causal, blocks, interpret, q_offset,
     o, lse = _flash_fwd_bhsd(q, k, v, _qoff(q_offset), scale=scale,
                              causal=causal, blocks=blocks,
                              interpret=interpret, window=window)
+    o, lse = map(checkpoint_name, (o, lse), RESIDUAL_NAMES)
     return o, (q, k, v, o, lse)
 
 

@@ -724,6 +724,62 @@ def test_the_loss_gathers_its_head_once_a_step(step, request, capsys):
                 assert c["runs"] % (chunks * k), c
 
 
+# Trinity-Large-Preview at its published widths as its cell trains it
+# (benchmark/configs/trinity-large-preview.json: 8 of 256 experts and an
+# eighth of the vocabulary held here), cut to one banded and one full layer
+CFG_TRINITY = moe.MoEConfig(
+    vocab_size=25024, d_model=3072, n_layers=2, n_heads=48, n_kv_heads=8,
+    attn_head_dim=128, d_ff=3072, max_seq_len=8192, rope_theta=1e4,
+    tie_embeddings=False, param_dtype=jnp.bfloat16, attn_impl="flash",
+    loss_chunk=256, qk_norm_head=True, attn_gate=True, sandwich_norm=True,
+    embedding_multiplier=math.sqrt(3072), layer_kinds=("window", "full"),
+    sliding_window=4096, n_experts=256, n_experts_held=8, top_k=4,
+    n_shared_experts=1, router_score="sigmoid", router_bias=True,
+    route_scale=2.448, balance="sequence", router_aux_coef=5e-5)
+
+
+@pytest.fixture(scope="module")
+def trinity_step(topo):
+    """``CFG_TRINITY``, b1 x s8192, K=2 on one described chip: (K, batch,
+    seq, compiled)."""
+    k, batch, seq = 2, 1, 8192
+    mesh = make_mesh(MeshConfig(), topo.devices[:1])
+    return k, batch, seq, _compile_fused_step(moe, CFG_TRINITY, mesh, k,
+                                              batch, seq)[2]
+
+
+# step: (its fixture, kinds of attention layer, and the temporaries and peak
+# in bytes of the same compile at commit 7246729, whose remat blocks kept the
+# products' results alone)
+NO_SECOND_FORWARD = {
+    "1b-fsdp2-tp2": ("flash_step", 1, 449518080, 657651712),
+    "mixtral-fsdp4": ("mixtral_step", 1, 2467869184, 4975883776),
+    "trinity-window-full": ("trinity_step", 2, 3664176128, 8189170688)}
+
+
+@pytest.mark.parametrize("step", sorted(NO_SECOND_FORWARD))
+def test_the_backward_runs_no_second_forward(step, request, capsys):
+    """A layer's remat block keeps the flash forward's output and
+    log-sum-exp (``llama.remat_block``), so the compiled train step holds as
+    many ``flash_fwd`` calls as ``flash_dq`` calls, through the dense scan
+    under ``shard_map`` on four chips, Mixtral's scan and the patterned
+    walk's banded and full layers alike. Fails on the parent, whose backward
+    ran the forward kernel again for them (2 : 1). What the kept arrays
+    cost is printed beside the parent's."""
+    fixture, layer_kinds, temp, peak = NO_SECOND_FORWARD[step]
+    compiled = request.getfixturevalue(fixture)[-1]
+    calls = [re.search(r"flash_(fwd|dq|dkv)", line).group(1)
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert sorted(calls) == sorted(flash.KINDS * layer_kinds), calls
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\n{step}: temporaries {mem.temp_size_in_bytes / 2**20:.1f} "
+              f"MiB (parent {temp / 2**20:.1f}), peak "
+              f"{mem.peak_memory_in_bytes / 2**20:.1f} MiB (parent "
+              f"{peak / 2**20:.1f})")
+
+
 def test_libtpu_accepts_the_perf_flags():
     """libtpu aborts the process on a flag it does not know, and every
     worker passes ``TPU_PERF_FLAGS``. Its flags are parsed when the library

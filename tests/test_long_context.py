@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, moe
 from ray_tpu.ops.attention import NEG_INF, mha
 from ray_tpu.ops.pallas import flash
 from ray_tpu.ops.pallas.flash import (
@@ -447,6 +447,121 @@ class TestFlashPlans:
                 assert p["sub_block"] == (16, 16)   # the tile: s 16
         finally:
             rec.close()
+
+
+def _primitives(jaxpr, into=None):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold, in
+    order: (primitive, a ``pallas_call``'s or a ``name``'s name, results)."""
+    into = [] if into is None else into
+    for eqn in jaxpr.eqns:
+        into.append((eqn.primitive.name,
+                     eqn.params["name"] if eqn.primitive.name
+                     in ("pallas_call", "name") else None,
+                     tuple(str(v.aval) for v in eqn.outvars)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, into)
+    return into
+
+
+def _flash_calls(primitives):
+    return sorted(name.split("_")[1] for kind, name, _ in primitives
+                  if kind == "pallas_call")
+
+
+def _dots_alone(cfg, fn):
+    """``llama.remat_block`` as it was: the policy that keeps no kernel's
+    result."""
+    if not cfg.remat:
+        return fn
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+
+_TINY = dict(vocab_size=96, d_model=32, n_heads=4, n_kv_heads=2, d_ff=48,
+             max_seq_len=32, attn_impl="flash", loss_chunk=8)
+REMAT_STACKS = {
+    "dense": lambda: llama.LlamaConfig(**_TINY, n_layers=2),
+    "mixtral": lambda: moe.MoEConfig(**_TINY, n_layers=2, n_experts=4,
+                                     top_k=2, router_aux_coef=0.02),
+    # a leading dense layer, then a period of a banded and a full layer,
+    # gated under sandwich norms: Trinity's block (tests/test_trinity_training)
+    "patterned": lambda: moe.MoEConfig(
+        **_TINY, n_layers=3, attn_head_dim=16, d_ff_dense=64,
+        layer_kinds=("window", "window", "full"), n_dense_layers=1,
+        sliding_window=8, qk_norm_head=True, attn_gate=True,
+        sandwich_norm=True, n_experts=8, n_experts_held=4, top_k=2,
+        n_shared_experts=1, router_score="sigmoid", router_bias=True,
+        route_scale=2.448, balance="sequence", router_aux_coef=0.05),
+}
+
+
+class TestRematKeepsTheForwardsResults:
+    """``flash._flash_core_fwd`` names its output and log-sum-exp and the
+    training stacks' one remat policy (``llama.remat_block``) keeps those
+    names: the backward of a layer holds one forward kernel where the dots
+    policy alone held two, and computes what it computed."""
+
+    @pytest.mark.parametrize("stack", sorted(REMAT_STACKS))
+    def test_loss_and_gradients_are_the_dots_policys_to_the_bit(
+            self, monkeypatch, stack):
+        cfg = REMAT_STACKS[stack]()
+        fam = ts.model_family(cfg)
+        params = fam.init_params(jax.random.key(5), cfg)
+        batch = {"tokens": jax.random.randint(jax.random.key(6), (2, 33),
+                                              0, cfg.vocab_size)}
+
+        def run():  # (a fresh function: nothing cached across the patch)
+            f = jax.value_and_grad(
+                lambda p: fam.loss_and_stats(p, batch, cfg)[0])
+            return (_primitives(jax.make_jaxpr(f)(params).jaxpr),
+                    jax.jit(f)(params))
+
+        kept, (loss, grads) = run()
+        monkeypatch.setattr(llama, "remat_block", _dots_alone)
+        again, (old_loss, old_grads) = run()
+        # a layer kind's body stands once in the jaxpr: one forward a
+        # backward pair now, two before (the patterned walk has three bodies)
+        bodies = 3 if stack == "patterned" else 1
+        assert _flash_calls(kept) == sorted(
+            ["fwd", "dq", "dkv"] * bodies), _flash_calls(kept)
+        assert _flash_calls(again) == sorted(
+            ["fwd", "fwd", "dq", "dkv"] * bodies), _flash_calls(again)
+        assert float(loss) == float(old_loss) and np.isfinite(float(loss))
+        for (path, new), old in zip(
+                jax.tree_util.tree_leaves_with_path(grads),
+                jax.tree.leaves(old_grads)):
+            assert np.array_equal(np.asarray(new), np.asarray(old)), path
+        assert any(float(jnp.abs(g).max()) > 0 for g in jax.tree.leaves(grads))
+
+    @pytest.mark.parametrize("wrap", ["bare", "checkpoint", "grad",
+                                      "checkpoint_grad"])
+    def test_to_any_other_caller_the_names_are_the_identity(
+            self, monkeypatch, wrap):
+        """Outside a checkpoint, and under one that names no policy (the
+        pipeline's stage, ``vit.py``), a call traces to what it traced to
+        before but for the ``name`` equations of the two; such a checkpoint
+        keeps nothing, so its backward still runs the forward again."""
+        q, k, v = _qkv(s=32, hq=4, hkv=2, d=16)
+
+        def attend(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=8)
+
+        if "checkpoint" in wrap:
+            attend = jax.checkpoint(attend)
+        fn = attend if "grad" not in wrap else jax.grad(
+            lambda *a: (attend(*a) ** 2).sum(), argnums=(0, 1, 2))
+
+        named = _primitives(jax.make_jaxpr(fn)(q, k, v).jaxpr)
+        monkeypatch.setattr(flash, "checkpoint_name", lambda x, name: x)
+        jax.clear_caches()  # (a custom_vjp's traced forward is kept)
+        plain = _primitives(jax.make_jaxpr(fn)(q, k, v).jaxpr)
+        assert {e[1] for e in named if e[0] == "name"} == set(
+            flash.RESIDUAL_NAMES)
+        assert [e for e in named if e[0] != "name"] == plain
+        assert _flash_calls(plain) == {
+            "bare": ["fwd"], "checkpoint": ["fwd"],
+            "grad": ["dkv", "dq", "fwd"],
+            "checkpoint_grad": ["dkv", "dq", "fwd", "fwd"]}[wrap]
 
 
 @pytest.fixture(scope="module")
