@@ -13,8 +13,7 @@ Two forwards share one block arithmetic (``_qkv``, ``_after_attention``):
 
 - ``_forward_with_cache``: [B, S] tokens at ONE position for all rows,
   the cache as the layer scan's ``xs``/``ys``, each layer writing its S
-  new positions with ``dynamic_update_slice``. Prefill, ``generate``,
-  speculation.
+  new positions with ``dynamic_update_slice``. Prefill and ``generate``.
 - ``decode_step_in_place``: one token per row at PER-ROW positions, the
   whole cache in the layer scan's carry, written only at the new
   positions (one indexed update a layer) and read where it lies, and of
@@ -54,7 +53,6 @@ from ray_tpu.models import llama
 from ray_tpu.ops.attention import mha
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_angles
-from ray_tpu.util import step_profiler
 
 Params = Dict[str, Any]
 
@@ -431,23 +429,13 @@ def generate(params: Params, prompt: jax.Array, cfg,
         key = jax.random.key(0)
     run = _compiled_generate(cfg, b, s, total, max_new_tokens,
                              float(temperature), top_k)
-    if not step_profiler.is_enabled():
-        return run(params, prompt, key)
-    from ray_tpu.util import flops as F
-
-    return step_profiler.profiled_call(
-        "generate", run, (params, prompt, key),
-        key=("generate", cfg, b, s, total, max_new_tokens,
-             float(temperature), top_k),
-        tokens=b * max_new_tokens,
-        flops=F.generate_flops(cfg, b, s, max_new_tokens),
-        meta={"batch": b, "prompt_len": s})
+    return run(params, prompt, key)
 
 
 def _sample_token(last_logits, temperature: float, top_k: Optional[int],
                   key):
     """Greedy (temperature<=0) or temperature/top-k categorical sampling —
-    the ONE sampling rule shared by the fused and streaming decode paths."""
+    the ONE sampling rule, which the engine's per-row form follows."""
     if temperature <= 0:
         return jnp.argmax(last_logits, axis=-1)
     scaled = last_logits / temperature
@@ -486,221 +474,3 @@ def _compiled_generate(cfg, b: int, s: int, total: int, max_new_tokens: int,
         return toks.swapaxes(0, 1)  # [B, T]
 
     return run
-
-
-def generate_speculative(params: Params, draft_params: Params,
-                         prompt: jax.Array, cfg, draft_cfg,
-                         *, max_new_tokens: int, speculate_k: int = 4,
-                         max_len: Optional[int] = None,
-                         return_stats: bool = False) -> jax.Array:
-    """Greedy speculative decoding: a small DRAFT model proposes
-    ``speculate_k`` tokens per round; the TARGET verifies them in ONE
-    forward (k+1 positions batched onto the MXU) and emits the longest
-    matching prefix plus its own correction token. Output is EXACTLY the
-    target's greedy continuation — the draft only changes how many
-    target launches it takes (1 per ~(accepted+1) tokens instead of 1
-    per token), which is the lever when decode is launch- or
-    HBM-bound. (Leviathan et al. 2023; no reference counterpart — Ray
-    ships no model code.)
-
-    Batch semantics: acceptance is LOCKSTEP (min over rows). Each row's
-    emitted tokens are still its own target-greedy tokens — a row that
-    would have accepted more simply emits them over later rounds — so
-    exactness holds for any batch size; speedup is highest at B=1 (the
-    latency case).
-
-    The whole loop is one jit: a ``lax.while_loop`` over rounds, a
-    ``lax.scan`` for the draft's proposals inside. Stale cache entries
-    past a rejection are overwritten before they can be attended (each
-    round's k+1-wide write starts exactly at the first stale position).
-
-    ``return_stats=True`` additionally returns
-    ``{"rounds", "accept_per_round"}`` — the measured acceptance profile
-    (tokens emitted per target launch minus the free correction token).
-    Speedup claims are only honest next to this number: a draft the
-    target never agrees with still "works" but pays k draft launches per
-    emitted token.
-    """
-    if cfg.n_recurrent_layers or draft_cfg.n_recurrent_layers:
-        raise NotImplementedError(
-            "speculative decoding on a model with recurrent layers: a "
-            "rejected draft is undone by writing over its keys and values, "
-            "and a recurrent state that has taken the rejected tokens in "
-            "cannot be taken back (it needs a snapshot per round)")
-    b, s = prompt.shape
-    total = max_len or (s + max_new_tokens + speculate_k + 1)
-    if total < s + max_new_tokens + speculate_k + 1:
-        raise ValueError(f"max_len {total} < prompt {s} + new "
-                         f"{max_new_tokens} + k {speculate_k} + 1")
-    run = _compiled_speculative(cfg, draft_cfg, b, s, total,
-                                max_new_tokens, speculate_k)
-    if not step_profiler.is_enabled():
-        out, rounds = run(params, draft_params, prompt)
-    else:
-        from ray_tpu.util import flops as F
-
-        # Analytic work: target prefill+decode plus the draft's proposals
-        # (the draft runs ~1 forward per emitted token too — acceptance
-        # only changes how many TARGET launches that took).
-        out, rounds = step_profiler.profiled_call(
-            "speculative", run, (params, draft_params, prompt),
-            key=("speculative", cfg, draft_cfg, b, s, total, max_new_tokens,
-                 speculate_k),
-            tokens=b * max_new_tokens,
-            flops=(F.generate_flops(cfg, b, s, max_new_tokens)
-                   + F.generate_flops(draft_cfg, b, s, max_new_tokens)),
-            meta={"batch": b, "prompt_len": s, "speculate_k": speculate_k})
-    if not return_stats:
-        return out
-    n_rounds = int(rounds)
-    stats = {"rounds": n_rounds,
-             "accept_per_round": round(
-                 max(0.0, max_new_tokens / max(1, n_rounds) - 1.0), 3)}
-    return out, stats
-
-
-@functools.lru_cache(maxsize=64)
-def _compiled_speculative(cfg, draft_cfg, b: int, s: int, total: int,
-                          max_new_tokens: int, k: int):
-    @jax.jit
-    def run(params, draft_params, prompt):
-        # prefill BOTH models; invariant from here on: caches hold KV for
-        # positions < pos, and cur is the (already decided) token AT pos
-        tcache = init_cache(cfg, b, total)
-        tlogits, tcache = _forward_with_cache(params, prompt, cfg,
-                                              tcache, 0)
-        dcache = init_cache(draft_cfg, b, total)
-        _, dcache = _forward_with_cache(draft_params, prompt, draft_cfg,
-                                        dcache, 0)
-        cur = jnp.argmax(tlogits[:, -1, :], axis=-1)  # token at pos=s
-        out = jnp.zeros((b, max_new_tokens + k + 1), jnp.int32)
-        # out[0] is cur (the first generated token)
-        out = out.at[:, 0].set(cur.astype(jnp.int32))
-
-        rounds = jnp.int32(0)
-
-        def cond(st):
-            return st[0] < max_new_tokens
-
-        def drafts_pad(d):
-            return jnp.concatenate(
-                [d, jnp.zeros((b, 1), d.dtype)], axis=1)
-
-        def body(st):
-            n, pos, cur, tcache, dcache, out, r = st
-
-            # draft proposes k tokens autoregressively
-            def dstep(carry, i):
-                dcache, tok = carry
-                logits, dcache = _forward_with_cache(
-                    draft_params, tok[:, None], draft_cfg, dcache, pos + i)
-                nxt = jnp.argmax(logits[:, -1, :], axis=-1)
-                return (dcache, nxt), nxt
-
-            (dcache, _), drafts = jax.lax.scan(
-                dstep, (dcache, cur), jnp.arange(k))
-            drafts = drafts.swapaxes(0, 1)  # [B, k]
-
-            # target verifies cur + all k drafts in ONE forward
-            block = jnp.concatenate([cur[:, None], drafts], axis=1)
-            logits, tcache = _forward_with_cache(
-                params, block, cfg, tcache, pos, last_only=False)
-            t = jnp.argmax(logits, axis=-1)  # [B, k+1]; t[:, j] follows
-            #                                   block position pos+j
-
-            # longest accepted prefix, lockstep across the batch
-            match = drafts == t[:, :k]                      # [B, k]
-            a = jnp.min(jnp.argmin(
-                jnp.concatenate([match, jnp.zeros((b, 1), bool)], 1), 1))
-            # emitted block: draft tokens below a, target tokens from a on
-            # (position a IS the correction; beyond is scratch that the
-            # next round overwrites)
-            emit = jnp.where(jnp.arange(k + 1)[None, :] < a, drafts_pad(
-                drafts), t).astype(jnp.int32)
-            out = jax.lax.dynamic_update_slice(out, emit, (0, n + 1))
-            cur = jax.lax.dynamic_index_in_dim(emit, a, axis=1,
-                                               keepdims=False)
-            return (n + a + 1, pos + a + 1, cur, tcache, dcache, out,
-                    r + 1)
-
-        n, _, _, _, _, out, rounds = jax.lax.while_loop(
-            cond, body, (jnp.int32(0), jnp.int32(s), cur, tcache,
-                         dcache, out, rounds))
-        return out[:, :max_new_tokens], rounds
-
-    return run
-
-
-@functools.lru_cache(maxsize=64)
-def _compiled_prefill(cfg, b: int, s: int, total: int):
-    @jax.jit
-    def run(params, prompt):
-        cache = init_cache(cfg, b, total)
-        logits, cache = _forward_with_cache(params, prompt, cfg, cache, 0)
-        return logits[:, -1, :], cache
-
-    return run
-
-
-@functools.lru_cache(maxsize=64)
-def _compiled_decode_step(cfg, b: int, total: int):
-    @jax.jit
-    def run(params, cache, tok, pos):
-        logits, cache = _forward_with_cache(
-            params, tok[:, None], cfg, cache, pos)
-        return logits[:, -1, :], cache
-
-    return run
-
-
-def generate_stream(params: Params, prompt: jax.Array, cfg,
-                    *, max_new_tokens: int, temperature: float = 0.0,
-                    top_k: Optional[int] = None,
-                    key: Optional[jax.Array] = None,
-                    max_len: Optional[int] = None):
-    """Yield tokens [B] one at a time — the serve token-streaming path.
-
-    Same math as ``generate`` but the decode loop runs in Python around a
-    cached jitted single-step, so each token is observable as soon as it's
-    sampled (a single fused scan can't stream). ``pos`` is a traced scalar:
-    one compiled step serves every position.
-    """
-    b, s = prompt.shape
-    total = max_len or (s + max_new_tokens)
-    if total < s + max_new_tokens:
-        raise ValueError(f"max_len {total} < prompt {s} + new {max_new_tokens}")
-    if temperature > 0 and key is None:
-        key = jax.random.key(0)
-
-    profiled = step_profiler.is_enabled()
-    if profiled:
-        from ray_tpu.util import flops as F
-
-    prefill = _compiled_prefill(cfg, b, s, total)
-    if profiled:
-        # per-launch records: the streamed path is the one that pays launch
-        # overhead PER TOKEN, which is exactly what the profiler's
-        # dispatch/sync split is built to expose
-        last, cache = step_profiler.profiled_call(
-            "prefill", prefill, (params, prompt),
-            key=("prefill", cfg, b, s, total), tokens=b * s,
-            flops=F.prefill_flops(cfg, b, s), meta={"batch": b})
-    else:
-        last, cache = prefill(params, prompt)
-    step = _compiled_decode_step(cfg, b, total)
-    for i in range(max_new_tokens):
-        if temperature <= 0:
-            sub = None
-        else:
-            key, sub = jax.random.split(key)
-        tok = _sample_token(last, temperature, top_k, sub)
-        yield tok
-        if i + 1 < max_new_tokens:
-            if profiled:
-                last, cache = step_profiler.profiled_call(
-                    "decode", step,
-                    (params, cache, tok, jnp.int32(s + i)),
-                    key=("decode", cfg, b, total), tokens=b,
-                    flops=b * F.decode_flops_per_token(cfg, s + i))
-            else:
-                last, cache = step(params, cache, tok, jnp.int32(s + i))

@@ -147,12 +147,6 @@ class LlamaConfig:
 PRESETS: Dict[str, LlamaConfig] = {
     "debug": LlamaConfig(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
                          n_kv_heads=2, d_ff=128, max_seq_len=128),
-    # genuinely-smaller draft for speculative decoding against "debug"
-    # (same vocab, ~1/8 the compute) — the CPU bench path must never
-    # alias draft == target and call the result a speedup
-    "debug_draft": LlamaConfig(vocab_size=256, d_model=32, n_layers=1,
-                               n_heads=2, n_kv_heads=1, d_ff=64,
-                               max_seq_len=128),
     "160m": LlamaConfig(vocab_size=32000, d_model=768, n_layers=12, n_heads=12,
                         n_kv_heads=12, d_ff=2048, max_seq_len=2048),
     "410m": LlamaConfig(vocab_size=32000, d_model=1024, n_layers=24, n_heads=16,

@@ -116,19 +116,6 @@ def test_generation_behind_serve(rt_cluster):
         serve._forget_controller_for_tests()
 
 
-def test_generate_stream_matches_generate(fp32_cfg):
-    cfg = fp32_cfg
-    params = llama.init_params(jax.random.key(0), cfg)
-    prompt = jax.random.randint(jax.random.key(1), (2, 6), 0,
-                                cfg.vocab_size)
-    batch_toks = np.asarray(generate.generate(params, prompt, cfg,
-                                              max_new_tokens=8))
-    streamed = [np.asarray(t) for t in generate.generate_stream(
-        params, prompt, cfg, max_new_tokens=8)]
-    assert len(streamed) == 8
-    np.testing.assert_array_equal(np.stack(streamed, axis=1), batch_toks)
-
-
 def test_token_streaming_behind_serve(rt_cluster):
     """LLM token streaming end-to-end: a serve deployment yields tokens
     incrementally through the streaming-response path."""
@@ -143,9 +130,10 @@ def test_token_streaming_behind_serve(rt_cluster):
 
         def __call__(self, prompt_ids):
             prompt = jnp.asarray([prompt_ids], jnp.int32)
-            for tok in generate.generate_stream(self.params, prompt,
-                                                self.cfg, max_new_tokens=5):
-                yield int(np.asarray(tok)[0])
+            toks = generate.generate(self.params, prompt, self.cfg,
+                                     max_new_tokens=5)
+            for tok in np.asarray(toks)[0]:
+                yield int(tok)
 
     handle = serve.run(StreamLM.bind(), name="slm", route_prefix=None)
     try:
@@ -196,47 +184,3 @@ def test_batched_generation_with_serve_batch(rt_cluster):
     finally:
         serve.shutdown()
         serve._forget_controller_for_tests()
-
-
-def test_speculative_decode_exactly_matches_greedy(fp32_cfg):
-    """Greedy speculative decoding is EXACT: for any draft model, the
-    output equals the target's own greedy decode — with the same model
-    as draft (every proposal accepted) and with an independently
-    initialized draft (frequent rejections exercise the correction +
-    stale-cache-overwrite path). Several k values cover the lockstep
-    batch-acceptance edges."""
-    cfg = fp32_cfg
-    params = llama.init_params(jax.random.key(0), cfg)
-    draft = llama.init_params(jax.random.key(123), cfg)
-    prompt = jax.random.randint(jax.random.key(1), (2, 9), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-    ref = np.asarray(generate.generate(params, prompt, cfg,
-                                       max_new_tokens=17))
-    for k in (1, 3, 6):
-        same = np.asarray(generate.generate_speculative(
-            params, params, prompt, cfg, cfg, max_new_tokens=17,
-            speculate_k=k))
-        np.testing.assert_array_equal(same, ref)
-        indep = np.asarray(generate.generate_speculative(
-            params, draft, prompt, cfg, cfg, max_new_tokens=17,
-            speculate_k=k))
-        np.testing.assert_array_equal(indep, ref)
-
-
-def test_speculative_decode_smaller_draft_config(fp32_cfg):
-    """The realistic shape: the draft is a SMALLER model (fewer layers/
-    heads) with its own config — still exact vs the target's greedy."""
-    import dataclasses as _dc
-
-    cfg = fp32_cfg
-    draft_cfg = _dc.replace(cfg, n_layers=1)
-    params = llama.init_params(jax.random.key(0), cfg)
-    draft = llama.init_params(jax.random.key(7), draft_cfg)
-    prompt = jax.random.randint(jax.random.key(2), (1, 6), 0,
-                                cfg.vocab_size, dtype=jnp.int32)
-    ref = np.asarray(generate.generate(params, prompt, cfg,
-                                       max_new_tokens=12))
-    got = np.asarray(generate.generate_speculative(
-        params, draft, prompt, cfg, draft_cfg, max_new_tokens=12,
-        speculate_k=4))
-    np.testing.assert_array_equal(got, ref)

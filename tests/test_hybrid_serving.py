@@ -334,16 +334,6 @@ def test_the_prefix_cache_refuses_a_recurrent_model(served):
         serving._compiled_cached_prefill(cfg, 16, 8, SLOTS, MAX_LEN)
 
 
-def test_speculative_decoding_refuses_a_recurrent_model(served):
-    cfg, params = served
-    dense = llama.PRESETS["debug"]
-    draft = llama.init_params(jax.random.key(0), dense)
-    for target, other in ((cfg, dense), (dense, cfg)):
-        with pytest.raises(NotImplementedError, match="cannot be taken back"):
-            G.generate_speculative(params, draft, _tokens(5, 1), target, other,
-                                   max_new_tokens=4)
-
-
 def test_load_params_refuses_a_tree_of_another_shape(served):
     cfg, params = served
     eng = ContinuousEngine(params, cfg, max_slots=SLOTS, max_len=MAX_LEN,

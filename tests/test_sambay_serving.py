@@ -513,13 +513,6 @@ def test_the_prefix_cache_refuses_the_model(served):
         serving._compiled_cached_prefill(cfg, 8, 4, SLOTS, MAX_LEN)
 
 
-def test_speculative_decoding_refuses_the_model(served):
-    cfg, params = served
-    with pytest.raises(NotImplementedError, match="recurrent state"):
-        G.generate_speculative(params, params, _tokens(4, 1), cfg, cfg,
-                               max_new_tokens=4)
-
-
 def test_load_params_refuses_a_tree_of_another_shape(served):
     cfg, params = served
     b = ContinuousBatcher(params, cfg, max_slots=SLOTS, max_len=MAX_LEN)
