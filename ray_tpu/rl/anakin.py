@@ -11,9 +11,7 @@ the whole iteration compiles into a single XLA program:
 
 Zero host↔device transfers inside the iteration; the host only sees the
 final metrics pytree. On a TPU mesh the same program shards over chips
-(the batch axis is embarrassingly parallel); on CPU it still wins by
-amortizing dispatch — the A/B bench (``bench_fused_vs_host``) measures
-env-steps/s against the host-loop ``EnvRunner.sample`` path.
+(the batch axis is embarrassingly parallel).
 
 The fused step is compiled EXACTLY ONCE per (config, shapes):
 ``AnakinRunner.compile_count()`` exposes the jit cache size so tests can
@@ -23,7 +21,6 @@ assert the single-launch property instead of trusting the docstring.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -184,113 +181,3 @@ class AnakinRunner:
         out["env_steps_total"] = self.env_steps_total
         out["iterations"] = self.iterations
         return out
-
-    def block(self) -> None:
-        """Device-sync the carry (bench timing boundary)."""
-        jax.block_until_ready(self._carry)
-
-
-# ---------------------------------------------------------------------------
-# A/B bench: fused Anakin vs the host-loop EnvRunner path
-# ---------------------------------------------------------------------------
-
-
-def bench_fused_vs_host(*, num_envs: int = 64, rollout_len: int = 32,
-                        iters: int = 20, warmup: int = 3,
-                        seed: int = 0) -> Dict[str, Any]:
-    """env-steps/s of the fused Anakin iteration vs the host-loop
-    ``EnvRunner`` path running the SAME work at the SAME (B, T) shape.
-
-    Both legs execute one full PPO iteration per fragment — rollout,
-    GAE, ``num_epochs`` full-batch updates with the identical loss and
-    optimizer. The fused leg runs it all as ONE launch; the host leg is
-    the existing architecture: numpy env stepped under per-step jitted
-    inference (one dispatch + device→host readback per env step, numpy
-    GAE), then the batch shipped host→device for a separately-launched
-    update. The delta is therefore exactly the per-step ping-pong and
-    launch overhead Anakin removes, not a difference in algorithm work.
-
-    Methodology (stamped into the result): ``warmup`` untimed iterations
-    first (XLA compiles + CPU dispatch-jitter dry runs), then ``iters``
-    timed; the fused leg blocks on its carry before and after timing so
-    async dispatch cannot hide work.
-    """
-    cfg = AnakinConfig(num_envs=num_envs, rollout_len=rollout_len,
-                       seed=seed)
-    runner = AnakinRunner(cfg)
-    runner.train(warmup)
-    runner.block()
-    t0 = time.perf_counter()
-    runner.train(iters)
-    runner.block()
-    fused_s = time.perf_counter() - t0
-    fused_steps = iters * cfg.env_steps_per_iter
-
-    # host loop: the plain EnvRunner class (no actor hop — this measures
-    # the per-step host↔device architecture, not RPC overhead), plus the
-    # same PPO update jitted as its own launch (batch crosses the host
-    # boundary, as the existing Algorithm.training_step path does)
-    from ray_tpu.rl.env_runner import EnvRunner
-
-    host_cls = getattr(EnvRunner, "_cls", EnvRunner)
-    host = host_cls("CartPole-v1", num_envs, rollout_len, seed=seed)
-    host_params = jax.tree_util.tree_map(
-        jnp.asarray, models.init_policy(jax.random.key(seed), host.spec,
-                                        hidden=cfg.hidden))
-    loss_fn = make_ppo_loss(host.spec, cfg.clip_param, cfg.vf_coeff,
-                            cfg.entropy_coeff)
-    opt = optax.chain(optax.clip_by_global_norm(cfg.grad_clip),
-                      optax.adam(cfg.lr))
-    opt_state = opt.init(host_params)
-
-    @jax.jit
-    def host_update(params, opt_state, batch):
-        def body(c, _):
-            params, opt_state = c
-            (_, _), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, batch, None)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            return (params, opt_state), None
-
-        (params, opt_state), _ = jax.lax.scan(
-            body, (params, opt_state), None, length=cfg.num_epochs)
-        return params, opt_state
-
-    def host_iter(params, opt_state):
-        frag = host.sample(params)
-        batch = {k: jnp.asarray(frag[k])
-                 for k in ("obs", "actions", "logp", "advantages",
-                           "value_targets")}
-        params, opt_state = host_update(params, opt_state, batch)
-        return params, opt_state
-
-    for _ in range(warmup):
-        host_params, opt_state = host_iter(host_params, opt_state)
-    jax.block_until_ready(host_params)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        host_params, opt_state = host_iter(host_params, opt_state)
-    jax.block_until_ready(host_params)
-    host_s = time.perf_counter() - t0
-    host_steps = iters * num_envs * rollout_len
-
-    fused_sps = fused_steps / max(fused_s, 1e-9)
-    host_sps = host_steps / max(host_s, 1e-9)
-    return {
-        "num_envs": num_envs, "rollout_len": rollout_len,
-        "iters": iters, "warmup": warmup,
-        "fused_env_steps_per_s": round(fused_sps, 1),
-        "host_env_steps_per_s": round(host_sps, 1),
-        "fused_vs_host_ratio": round(fused_sps / max(host_sps, 1e-9), 2),
-        "fused_compile_count": runner.compile_count(),
-        "methodology": (
-            "equal work both legs (rollout + GAE + {e}-epoch PPO update "
-            "at B={b}, T={t}): {w} warmup iters (compiles + CPU "
-            "dispatch-jitter dry runs) then {n} timed; fused leg is one "
-            "launch per iter, block_until_ready-bounded; host leg is "
-            "EnvRunner.sample (per-step jitted inference + numpy env + "
-            "numpy GAE) + a separately-launched jitted update".format(
-                e=cfg.num_epochs, w=warmup, n=iters, b=num_envs,
-                t=rollout_len)),
-    }

@@ -35,8 +35,7 @@ from ray_tpu.serve.grpc_proxy import grpc_request
 from ray_tpu.serve.obs import get_serve_request_id
 from ray_tpu.serve.api import detailed_status, proxy_ports
 from ray_tpu.serve.proxy import ServeRequest
-from ray_tpu.serve.llm import (continuous_llm_app, poisson_load,
-                               static_llm_app)
+from ray_tpu.serve.llm import continuous_llm_app
 
 __all__ = [
     "ASGIResponse", "ASGIResponseStart",
@@ -49,7 +48,7 @@ __all__ = [
     "ingress",
     "get_deployment_handle", "get_multiplexed_model_id", "grpc_request",
     "get_serve_request_id",
-    "http_port", "multiplexed", "poisson_load", "proxy_ports", "run",
-    "shutdown", "start", "start_grpc", "static_llm_app",
+    "http_port", "multiplexed", "proxy_ports", "run",
+    "shutdown", "start", "start_grpc",
     "status",
 ]

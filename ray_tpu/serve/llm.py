@@ -1,6 +1,5 @@
 """Continuous-batching LLM serving: the deployment that makes
-``models/serving.ContinuousBatcher`` live, its static-batch control, and
-the Poisson-arrival load driver the bench/envelope/smoke legs share.
+``models/serving.ContinuousBatcher`` live.
 
 No reference counterpart — Ray pairs with external engines (vLLM) for
 this; here the engine is in-repo (``models/serving.py``) and the serve
@@ -13,12 +12,6 @@ layer's job is admission, streaming and telemetry:
     the proxy's ``_stream_response`` TTFT/inter-token path. Slot
     occupancy lands on the PR 8 ``rt_serve_batch_occupancy`` series
     (``fn="cb:<name>"``) plus the ``rt_serve_cb_slots_active`` gauge.
-  - ``StaticLLM`` is the honest control: the SAME model behind
-    ``@serve.batch`` — requests wait for batch formation, decode in
-    lockstep, and respond only when the whole fused ``generate`` returns.
-  - ``poisson_load`` drives open-loop Poisson arrivals against either and
-    reports throughput + latency percentiles (the ``decode_cb_*`` bench
-    keys and the chaos_smoke serve-load leg both use it).
 """
 
 from __future__ import annotations
@@ -26,16 +19,11 @@ from __future__ import annotations
 import asyncio
 import json
 import os
-import random
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
-from ray_tpu.serve.batching import batch as _serve_batch
-
-__all__ = ["ContinuousLLM", "StaticLLM", "cb_vs_static_load",
-           "continuous_llm_app", "static_llm_app", "poisson_load",
-           "http_token_request"]
+__all__ = ["ContinuousLLM", "continuous_llm_app"]
 
 
 def _parse_request(request: Any) -> Dict[str, Any]:
@@ -226,62 +214,6 @@ class ContinuousLLM:
         return stream()
 
 
-class StaticLLM:
-    """The ``@serve.batch`` control: same model, batch-boundary batching.
-
-    Shapes are static (prompt padded to ``prompt_pad``, always
-    ``max_new`` decode steps) so ONE compiled program serves every
-    flush; requests pay batch-formation wait plus the full fused
-    ``generate`` of the slowest batch — exactly the head-of-line
-    economics continuous batching removes. Note right-padding feeds pad
-    garbage into the shared forward, so per-request token exactness is
-    NOT claimed here (it is for ``ContinuousLLM``) — this class is the
-    throughput/latency control, not a correctness reference.
-    """
-
-    def __init__(self, preset: str = "debug", *, max_batch: int = 8,
-                 prompt_pad: int = 16, max_new: int = 16,
-                 batch_wait_timeout_s: float = 0.02, seed: int = 0):
-        import jax
-
-        from ray_tpu.models import llama
-
-        self.preset = preset
-        self.cfg = llama.PRESETS[preset]
-        self.params = llama.init_params(jax.random.key(seed), self.cfg)
-        self.prompt_pad = prompt_pad
-        self.max_new = max_new
-        self.max_batch = max_batch
-        # a PER-INSTANCE batched function: the decorator stores batch
-        # config on the wrapper it returns, so decorating a method would
-        # share one config across every instance in the process (a
-        # second deployment's max_batch would clobber the first's)
-        self._gen_batch = _serve_batch(
-            max_batch_size=max_batch,
-            batch_wait_timeout_s=batch_wait_timeout_s)(self._generate_batch)
-
-    async def __call__(self, request: Any) -> List[int]:
-        body = _parse_request(request)
-        n_new = min(int(body.get("max_new_tokens", 16)), self.max_new)
-        toks = await self._gen_batch(
-            (list(body["tokens"])[: self.prompt_pad], n_new))
-        return toks[:n_new]
-
-    async def _generate_batch(self, items: List[Any]) -> List[List[int]]:
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.models import generate as G
-
-        toks = np.zeros((self.max_batch, self.prompt_pad), dtype=np.int32)
-        for i, (prompt, _) in enumerate(items):
-            toks[i, : len(prompt)] = prompt
-        out = G.generate(self.params, jnp.asarray(toks), self.cfg,
-                         max_new_tokens=self.max_new)
-        arr = np.asarray(out)
-        return [arr[i].tolist() for i in range(len(items))]
-
-
 def continuous_llm_app(preset: str = "debug", *, max_slots: int = 8,
                        max_len: int = 256, decode_stride: int = 8,
                        name: str = "CB",
@@ -307,223 +239,3 @@ def continuous_llm_app(preset: str = "debug", *, max_slots: int = 8,
     return dep.bind(preset, max_slots=max_slots, max_len=max_len,
                     decode_stride=decode_stride, seed=seed, name=name,
                     kv_cache_bytes=kv_cache_bytes, sampling=sampling)
-
-
-def static_llm_app(preset: str = "debug", *, max_batch: int = 8,
-                   prompt_pad: int = 16, max_new: int = 16,
-                   batch_wait_timeout_s: float = 0.02, name: str = "Static",
-                   max_ongoing_requests: int = 64, seed: int = 0,
-                   ray_actor_options: Optional[Dict] = None):
-    """The static ``@serve.batch`` control Application."""
-    from ray_tpu import serve
-
-    dep = serve.deployment(StaticLLM).options(
-        name=name, max_ongoing_requests=max_ongoing_requests,
-        ray_actor_options=ray_actor_options)
-    return dep.bind(preset, max_batch=max_batch, prompt_pad=prompt_pad,
-                    max_new=max_new,
-                    batch_wait_timeout_s=batch_wait_timeout_s, seed=seed)
-
-
-# ---------------------------------------------------------------------------
-# Poisson-arrival load driver
-# ---------------------------------------------------------------------------
-
-
-def cb_vs_static_load(*, preset: str = "debug", slots: int = 8,
-                      max_len: int = 384, decode_stride: int = 16,
-                      prompt_len: int = 8, short_tokens: int = 2,
-                      long_tokens: int = 256, long_frac: float = 0.05,
-                      rps: float = 15.0, duration_s: float = 15.0,
-                      num_proxies: int = 2, timeout_s: float = 240.0,
-                      seed: int = 42,
-                      route_base: str = "cbvs",
-                      ray_actor_options: Optional[Dict] = None
-                      ) -> Dict[str, Dict[str, Any]]:
-    """THE continuous-vs-static comparison leg, shared by ``bench.py``
-    (``decode_cb_*``), ``rt scale-envelope`` (``serve_under_load``) and
-    ``scripts/chaos_smoke.sh``: open-loop Poisson arrivals round-robined
-    over the proxy fleet at EQUAL offered load and a heterogeneous
-    short/long decode-length mix, against (a) the live continuous-
-    batching app and (b) the ``@serve.batch`` control provisioned at
-    ``max_new=long_tokens`` (a batch-boundary system decodes its longest
-    admissible request every flush — the waste slot admission avoids).
-    One implementation so the three surfaces cannot drift apart on
-    methodology; callers own their parameter sizing and assertions.
-
-    Requires an initialized ray_tpu; deploys/tears down its own apps
-    (``<route_base>-cb`` / ``<route_base>-static``), one after the other,
-    each replica with ``ray_actor_options`` (``{"num_tpus": 1}`` puts it on
-    the chip; a replica granted none runs on the CPU). Returns
-    {"continuous": poisson_result, "static": poisson_result}.
-    """
-    import itertools
-
-    from ray_tpu import serve
-
-    prompt = list(range(1, prompt_len + 1))
-    results: Dict[str, Dict[str, Any]] = {}
-    for leg, app, route in (
-        ("continuous",
-         continuous_llm_app(preset, max_slots=slots, max_len=max_len,
-                            decode_stride=decode_stride, name="CB",
-                            max_ongoing_requests=4 * slots,
-                            ray_actor_options=ray_actor_options),
-         f"/{route_base}-cb"),
-        ("static",
-         static_llm_app(preset, max_batch=slots, prompt_pad=prompt_len,
-                        max_new=long_tokens, name="Static",
-                        max_ongoing_requests=4 * slots,
-                        ray_actor_options=ray_actor_options),
-         f"/{route_base}-static"),
-    ):
-        name = f"{route_base}-{leg}"
-        serve.run(app, name=name, route_prefix=route,
-                  http_options=serve.HTTPOptions(port=0,
-                                                 num_proxies=num_proxies))
-        ports = serve.proxy_ports()
-        fires = {}
-        for p in ports:
-            for n in (short_tokens, long_tokens):
-                fires[(p, n)] = http_token_request(
-                    f"http://127.0.0.1:{p}{route}/", prompt, n,
-                    timeout_s=timeout_s)
-                fires[(p, n)]()  # warmup: replica spawn + XLA compiles
-        rr = itertools.cycle(ports)
-        # deterministic length SCHEDULE, consumed by fire order: the two
-        # legs see the same short/long multiset and near-identical
-        # ordering (worker-thread scheduling and client sheds can still
-        # skew tail placement — per-arrival determinism would need index
-        # plumbing through poisson_load)
-        mix_rng = random.Random(7)
-        schedule = [long_tokens if mix_rng.random() < long_frac
-                    else short_tokens
-                    for _ in range(int(rps * duration_s * 4) + 64)]
-        counter = itertools.count()
-        lock = threading.Lock()
-
-        def fire():
-            with lock:
-                i = next(counter)
-                port = next(rr)
-            n = schedule[min(i, len(schedule) - 1)]
-            return fires[(port, n)]()
-
-        results[leg] = poisson_load(fire, rps=rps, duration_s=duration_s,
-                                    seed=seed)
-        serve.delete(name)
-    return results
-
-
-def http_token_request(url: str, prompt: List[int],
-                       max_new_tokens: int,
-                       timeout_s: float = 120.0) -> Callable[[], int]:
-    """A request closure for :func:`poisson_load`: POSTs the prompt and
-    reads the FULL response (streamed chunks or one JSON list); returns
-    the number of generated tokens observed."""
-    import urllib.request
-
-    body = json.dumps({"tokens": prompt,
-                       "max_new_tokens": max_new_tokens}).encode()
-
-    def fire() -> int:
-        req = urllib.request.Request(
-            url, data=body, headers={"Content-Type": "application/json"})
-        with urllib.request.urlopen(req, timeout=timeout_s) as r:
-            payload = r.read()
-        text = payload.decode().strip()
-        if not text:
-            return 0
-        if text.startswith("["):
-            return len(json.loads(text))
-        return len(text.splitlines())
-
-    return fire
-
-
-def poisson_load(request_fn: Callable[[], int], *, rps: float,
-                 duration_s: float, seed: int = 0,
-                 max_inflight: int = 64) -> Dict[str, Any]:
-    """Open-loop Poisson arrivals: fire ``request_fn`` at exponentially
-    spaced instants for ``duration_s`` and report wall latencies.
-
-    Open-loop matters: a closed loop (fire-when-done) lets a slow server
-    hide its queueing by slowing the client down — here late requests
-    keep arriving on schedule (up to ``max_inflight``), so p99 reflects
-    what an independent client population would see.
-
-    ``request_fn`` returns the token count, or ``(token_count,
-    ttft_seconds)`` — the KV-cache bench's streamed closures report
-    time-to-first-token, surfaced as ``ttft_p50_ms``/``ttft_p99_ms``.
-    """
-    from concurrent.futures import ThreadPoolExecutor
-
-    rng = random.Random(seed)
-    t = 0.0
-    arrivals: List[float] = []
-    while t < duration_s:
-        t += rng.expovariate(rps)
-        if t < duration_s:
-            arrivals.append(t)
-    lat: List[float] = []
-    ttfts: List[float] = []
-    toks = [0]
-    failed = [0]
-    shed = [0]
-    lock = threading.Lock()
-    sem = threading.Semaphore(max_inflight)
-
-    def one() -> None:
-        t0 = time.perf_counter()
-        try:
-            n = request_fn()
-        except Exception:  # noqa: BLE001 — failure is a data point
-            with lock:
-                failed[0] += 1
-            return
-        finally:
-            sem.release()
-        dt = time.perf_counter() - t0
-        ttft = None
-        if isinstance(n, tuple):
-            n, ttft = n
-        with lock:
-            lat.append(dt)
-            toks[0] += n
-            if ttft is not None:
-                ttfts.append(ttft)
-
-    t_start = time.perf_counter()
-    with ThreadPoolExecutor(max_workers=max_inflight + 4) as pool:
-        for at in arrivals:
-            delay = t_start + at - time.perf_counter()
-            if delay > 0:
-                time.sleep(delay)
-            if not sem.acquire(blocking=False):
-                # the client budget is full: count the shed arrival
-                # instead of silently converting open-loop to closed
-                shed[0] += 1
-                continue
-            pool.submit(one)
-    wall = time.perf_counter() - t_start
-    lat.sort()
-    ttfts.sort()
-
-    def pct(vals: List[float], q: float) -> float:
-        if not vals:
-            return 0.0
-        return vals[min(len(vals) - 1, int(q * (len(vals) - 1) + 0.5))]
-
-    out = {"offered": len(arrivals),
-           "offered_rps": round(len(arrivals) / duration_s, 2),
-           "completed": len(lat), "failed": failed[0], "shed": shed[0],
-           "wall_s": round(wall, 3),
-           "rps": round(len(lat) / wall, 2),
-           "tok_s": round(toks[0] / wall, 1),
-           "tokens": toks[0],
-           "p50_ms": round(pct(lat, 0.50) * 1e3, 1),
-           "p99_ms": round(pct(lat, 0.99) * 1e3, 1)}
-    if ttfts:
-        out["ttft_p50_ms"] = round(pct(ttfts, 0.50) * 1e3, 1)
-        out["ttft_p99_ms"] = round(pct(ttfts, 0.99) * 1e3, 1)
-    return out

@@ -516,11 +516,6 @@ def test_chaos_smoke_script():
     proc = subprocess.run(
         ["bash", os.path.join(root, "scripts", "chaos_smoke.sh")],
         capture_output=True, text=True, timeout=900,
-        env=dict(os.environ, JAX_PLATFORMS="cpu", RT_SMOKE_FLOOD="1500",
-                 # shrunk serve-load leg: engine warmup compiles + two
-                 # Poisson legs fit the budget on a loaded CI box
-                 # the offered rate must stay ABOVE the static control's
-                 # saturation point or the degradation assert gets noisy
-                 RT_SMOKE_SERVE_RPS="14", RT_SMOKE_SERVE_SECS="10"))
+        env=dict(os.environ, JAX_PLATFORMS="cpu", RT_SMOKE_FLOOD="1500"))
     assert proc.returncode == 0, \
         f"stdout:\n{proc.stdout}\nstderr:\n{proc.stderr}"
