@@ -9,7 +9,6 @@ State API), ``dashboard/modules/metrics`` (Prometheus). Routes:
   GET /api/actors               actor table
   GET /api/placement_groups     placement groups
   GET /api/tasks                recent task events
-  GET /api/steps                step-profiler records (profile payloads)
   GET /api/objects              object directory
   GET /api/errors               failure plane (categorized FailureEvents)
   GET /api/memory               memory plane (store usage + owner ledgers)
@@ -54,10 +53,6 @@ class DashboardActor:
                            self._gcs_list("list_placement_groups"))
         app.router.add_get("/api/tasks", self._gcs_list(
             "list_tasks", {"profile": "exclude"}))
-        # step-profiler records (util/step_profiler.py): the per-step
-        # device-time / MFU page reads the same store, profile rows only
-        app.router.add_get("/api/steps", self._gcs_list(
-            "list_tasks", {"profile": "only"}))
         app.router.add_get("/api/objects", self._gcs_list("list_objects"))
         # the failure plane: categorized FailureEvents (death-cause
         # taxonomy, core/failure.py) straight off the GCS store

@@ -82,18 +82,14 @@ header button {
   height: 100%; border-radius: 4px; background: var(--series-1);
   transition: width .4s;
 }
-/* step-breakdown stacked bar: compile/dispatch/device-sync share of one
-   step's wall time; 2px surface gaps separate the fills. The tasks tab
-   reuses the track for the per-phase task breakdown. */
+/* stacked bar of the tasks tab's per-phase breakdown; 2px surface gaps
+   separate the fills. */
 .bk-track {
   display: flex; gap: 2px; width: 140px; height: 8px;
   border-radius: 4px; overflow: hidden;
   background: color-mix(in srgb, var(--border) 60%, var(--surface-2));
 }
 .bk-seg { height: 100%; border-radius: 2px; }
-.bk-compile { background: var(--series-2); }
-.bk-dispatch { background: var(--series-3); }
-.bk-sync { background: var(--series-1); }
 /* task phase colors: wait-ish phases warm, work-ish phases cool */
 .ph-queue_wait { background: var(--warning); }
 .ph-worker_acquire { background: var(--serious); }
@@ -211,7 +207,6 @@ const TABS = [
    url: "/api/placement_groups"},
   {id: "tasks", label: "Tasks", url: "/api/tasks?limit=200"},
   {id: "errors", label: "Errors", url: "/api/errors?limit=200"},
-  {id: "steps", label: "Steps", url: "/api/steps?limit=200"},
   {id: "timeline", label: "Timeline", url: "/api/tasks?limit=500"},
   {id: "objects", label: "Objects", url: "/api/objects?limit=200"},
   {id: "memory", label: "Memory", url: "/api/memory?limit=100"},
@@ -337,41 +332,8 @@ const COLS = {
     ["Count", r => `<td>${esc(r.count ?? 1)}</td>`],
     ["Message", r => `<td>${esc(r.message || "")}</td>`],
   ],
-  steps: [
-    ["Kind", r => `<td>${esc(prof(r).kind || "")}</td>`],
-    ["Name", r => `<td>${esc(prof(r).name || "")}</td>`],
-    ["Step", r => `<td>${esc(prof(r).step ?? "")}</td>`],
-    ["Wall ms", r => `<td>${ms(prof(r).wall_s)}</td>`],
-    ["Compile ms", r => `<td>${ms(prof(r).compile_s)}</td>`],
-    ["Dispatch ms", r => `<td>${ms(prof(r).dispatch_s)}</td>`],
-    ["Sync ms", r => `<td>${ms(prof(r).execute_s)}</td>`],
-    ["Tok/s", r => `<td>${prof(r).tokens_per_s
-      ? prof(r).tokens_per_s.toFixed(1) : ""}</td>`],
-    ["MFU", r => `<td>${prof(r).mfu
-      ? (100 * prof(r).mfu).toFixed(2) + "%" : ""}</td>`],
-    ["Breakdown", r => `<td>${breakdownBar(prof(r))}</td>`],
-  ],
 };
-function prof(r) { return r.profile || {}; }
 function ms(v) { return v == null ? "" : (1000 * v).toFixed(2); }
-function breakdownBar(p) {
-  const wall = p.wall_s || 0;
-  if (!wall) return "";
-  const seg = (cls, v, label) => {
-    const pct = Math.max(0, Math.min(100, 100 * (v || 0) / wall));
-    return pct < 0.5 ? "" :
-      `<div class="bk-seg ${cls}" style="width:${pct.toFixed(1)}%"` +
-      ` title="${esc(label)} ${ms(v)}ms"></div>`;
-  };
-  return `<div class="bk-track">` +
-    seg("bk-compile", p.compile_s, "compile") +
-    seg("bk-dispatch", p.dispatch_s, "dispatch") +
-    seg("bk-sync", p.execute_s, "device sync") + `</div>`;
-}
-const STEP_LEGEND = `<div class="legend">` +
-  `<span><span class="chip bk-compile"></span>compile</span>` +
-  `<span><span class="chip bk-dispatch"></span>dispatch</span>` +
-  `<span><span class="chip bk-sync"></span>device sync</span></div>`;
 
 // task-lifecycle phase drill-down (traced tasks; util/tracing.PHASE_ORDER)
 const PHASE_ORDER = ["submit", "queue_wait", "spillback", "worker_acquire",
@@ -875,15 +837,11 @@ function renderTable() {
   if (active === "errors") rows = rows.slice().reverse();  // newest first
   const cols = COLS[active];
   if (!rows.length) {
-    el.innerHTML = active === "steps"
-      ? `<div class="empty">no step records yet — enable the step ` +
-        `profiler (RT_STEP_PROFILER=1 or rt profile) and drain()</div>`
-      : `<div class="empty">no ${esc(active)} yet</div>`;
+    el.innerHTML = `<div class="empty">no ${esc(active)} yet</div>`;
     return;
   }
-  el.innerHTML = (active === "steps" ? STEP_LEGEND
-    : active === "tasks" && rows.some(r => r.phases) ? PHASE_LEGEND
-    : "") + `<table><tr>` +
+  el.innerHTML = (active === "tasks" && rows.some(r => r.phases)
+    ? PHASE_LEGEND : "") + `<table><tr>` +
     cols.map(c => `<th>${esc(c[0])}</th>`).join("") + `</tr>` +
     rows.map(r => {
       const id = active === "actors" ? r.actor_id : null;

@@ -27,7 +27,6 @@ from ray_tpu.models import llama
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 from ray_tpu.parallel.plan import Plan, compile_plan, compile_step, placement_plan
 from ray_tpu.parallel.sharding import ShardingRules
-from ray_tpu.util import step_profiler
 
 
 def default_optimizer(lr: float = 3e-4, weight_decay: float = 0.1,
@@ -142,7 +141,7 @@ def make_train_step(cfg: llama.LlamaConfig,
     jstep = compile_step(step, plan,
                          **_plan_shardings(plan, optimizer, custom_loss,
                                            stacked=False))
-    return _instrumented(jstep, cfg, mesh, plan=plan)
+    return _with_mesh(jstep, mesh, plan)
 
 
 def _plan_shardings(plan: Optional[Plan], optimizer, custom_loss: bool,
@@ -173,9 +172,8 @@ def supports_multi_step(cfg) -> bool:
 def _batch_tokens(batch, stacked: bool = False) -> Tuple[int, int]:
     """(trained tokens, seq len) of one step's batch. Token batches are
     [B, S+1] ([K, B, S+1] stacked): S positions train per row. Custom
-    loss_fn batches without a usable token-shaped leaf yield (0, 1) — the
-    profiler then records times without tokens/MFU instead of crashing
-    the training loop it instruments."""
+    loss_fn batches without a usable token-shaped leaf yield (0, 1): the
+    driver's recorder then counts launches without tokens."""
     need = 3 if stacked else 2
     leaf = batch.get("tokens") if isinstance(batch, dict) else None
     if leaf is None or getattr(leaf, "ndim", 0) < need:
@@ -191,18 +189,9 @@ def _batch_tokens(batch, stacked: bool = False) -> Tuple[int, int]:
     return b * max(1, s1 - 1), max(1, s1 - 1)
 
 
-_PROGRAM_IDS = __import__("itertools").count()
-
-
-def _instrumented(jstep, cfg, mesh, stacked: bool = False,
-                  steps_per_launch: int = 1, plan: Optional[Plan] = None):
+def _with_mesh(jstep, mesh, plan: Optional[Plan] = None):
     """The (params, opt_state, batch) entry point every trainer calls:
-    ambient-mesh plumbing plus the step profiler's per-step record (wall /
-    compile / dispatch / device-sync split, analytic MFU). Disabled
-    profiling costs one predicate per step. The profiler key is a fresh
-    counter value per built step — NOT id(jstep), which CPython reuses
-    after GC and would book a new program's compile as dispatch."""
-    program_id = next(_PROGRAM_IDS)
+    ``jstep`` under the ambient mesh."""
 
     def call(params, opt_state, batch):
         if mesh is None:
@@ -212,23 +201,11 @@ def _instrumented(jstep, cfg, mesh, stacked: bool = False,
         with mesh_scope(mesh):
             return jstep(params, opt_state, batch)
 
-    def run(params, opt_state, batch):
-        if not step_profiler.is_enabled():
-            return call(params, opt_state, batch)
-        from ray_tpu.util import flops as F
-
-        tokens, seq = _batch_tokens(batch, stacked)
-        return step_profiler.profiled_call(
-            "train", call, (params, opt_state, batch),
-            key=("train", program_id), tokens=tokens,
-            steps=steps_per_launch,
-            flops=tokens * F.train_flops_per_token(cfg, seq))
-
     # the compiled program and plan ride along so drivers can assert
     # single-launch fusion via the jit cache and reuse the placement plan
-    run._jit = jstep
-    run._plan = plan
-    return run
+    call._jit = jstep
+    call._plan = plan
+    return call
 
 
 def make_multi_step(cfg: llama.LlamaConfig,
@@ -247,8 +224,7 @@ def make_multi_step(cfg: llama.LlamaConfig,
     steps back to back on-device, so per-launch host/runtime overhead
     (dispatch, XLA launch latency) is paid once per K
     steps instead of per step — the standard trick for host-bound training
-    loops (and the instrument that separates per-launch overhead from true
-    device time in bench.py's sweep: scan-per-step vs single-step marginal).
+    loops.
     Works under any mesh: the scanned body is the same sharded step GSPMD
     already compiles.
     """
@@ -278,8 +254,7 @@ def make_multi_step(cfg: llama.LlamaConfig,
     jsteps = compile_step(steps, plan,
                           **_plan_shardings(plan, optimizer, custom_loss,
                                             stacked=True))
-    return _instrumented(jsteps, cfg, mesh, stacked=True,
-                         steps_per_launch=n_steps, plan=plan)
+    return _with_mesh(jsteps, mesh, plan)
 
 
 def shard_batch(batch: Dict[str, jax.Array], mesh: Mesh,

@@ -523,9 +523,8 @@ def _run_rlhf(args: argparse.Namespace) -> int:
 
 def cmd_trace(args: argparse.Namespace) -> int:
     """rt trace <task_id|trace_id|span_id>: print the span tree with the
-    per-phase latency tables and the named critical path (the cluster-side
-    twin of `rt profile` — reads the GCS task-event store directly, no
-    driver attach)."""
+    per-phase latency tables and the named critical path (reads the GCS
+    task-event store directly, no driver attach)."""
     from ray_tpu.util.tracing import format_trace
 
     gcs = _resolve_gcs(args.address)
@@ -1273,15 +1272,9 @@ def cmd_export_grafana(args: argparse.Namespace) -> int:
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else list(argv)
-    if argv[:1] == ["profile"]:
-        # passthrough: one parser (scripts/profile.py), one source of
-        # truth — `rt profile --help` shows its full flag set
-        from ray_tpu.scripts import profile as _profile
-
-        return _profile.main(argv[1:])
     if argv[:1] == ["lint"]:
-        # passthrough like profile: analysis/runner.py owns the flag set
-        # (`rt lint [--json] [--baseline-update] [paths...]`)
+        # passthrough: one parser (analysis/runner.py), one source of
+        # truth (`rt lint [--json] [--baseline-update] [paths...]`)
         from ray_tpu.analysis import runner as _lint
 
         return _lint.main(argv[1:])
@@ -1333,15 +1326,8 @@ def main(argv=None) -> int:
     p_list.add_argument("--limit", type=int, default=200)
     p_list.set_defaults(fn=cmd_list)
 
-    # `rt profile` is routed in main() before parsing (scripts/profile.py
+    # `rt lint` is routed in main() before parsing (analysis/runner.py
     # owns the flag set); this stub only makes it show up in `rt --help`
-    sub.add_parser(
-        "profile", add_help=False,
-        help="step profiler: per-step wall/compile/sync breakdown + MFU "
-             "over a model preset (util/step_profiler.py)")
-
-    # `rt lint` is routed in main() before parsing too (analysis/runner.py
-    # owns the flag set); stub for `rt --help` discoverability
     sub.add_parser(
         "lint", add_help=False,
         help="concurrency/runtime-invariant static analysis with a "

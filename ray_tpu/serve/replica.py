@@ -28,7 +28,7 @@ import ray_tpu
 from ray_tpu.cluster import stream as rt_stream
 from ray_tpu.serve import obs
 from ray_tpu.serve.multiplex import loaded_model_ids
-from ray_tpu.util import metrics, step_profiler
+from ray_tpu.util import metrics
 
 REJECTED = "__rt_serve_rejected__"
 
@@ -328,16 +328,6 @@ class ReplicaActor:
                             phases={"queue_wait": queue_wait_s,
                                     "execute": execute_s},
                             state="FAILED" if failed else "FINISHED")
-            if step_profiler.is_enabled():
-                # serve is a profiler hot path too: per-request wall time
-                # (the user callable's execution — a returned stream's
-                # drain is accounted by the generate/decode records it
-                # produces, not here)
-                step_profiler.record(
-                    "serve", name=self._deployment, t_start=t_epoch,
-                    wall_s=time.perf_counter() - t0,
-                    meta={"method": method_name,
-                          "replica_id": self._replica_id})
             self._total_served += 1
             models = loaded_model_ids(self._instance)
             kv = None

@@ -5,13 +5,6 @@ execution spans as a Chrome ``chrome://tracing`` / Perfetto JSON file. Spans
 come from the per-state transition times the raylets report to the GCS task
 store (PENDING -> RUNNING -> FINISHED/FAILED).
 
-Step-profiler records (``util/step_profiler.py``) live in the same store
-and export as their own lanes in the same file: each step is a ``step``
-category span on a ``step:<kind>`` track, with ``compile`` and ``sync``
-sub-spans marking the first-call compile time and the post-dispatch
-host-sync stall — so the train/decode breakdown lines up against the task
-lanes in one Perfetto view.
-
 Traced tasks additionally carry a per-phase breakdown (``util/tracing.py``
 ``PHASE_ORDER``): each phase becomes its own span on a ``<task>:phases``
 track, laid out consecutively from the task's enqueue time — queue-wait,
@@ -50,10 +43,6 @@ def timeline(filename: Optional[str] = None) -> List[Dict[str, Any]]:
                        "serve": "include"}))
     trace: List[Dict[str, Any]] = []
     for ev in events:
-        prof = ev.get("profile")
-        if prof:
-            trace.extend(_step_lanes(ev, prof))
-            continue
         etick = ev.get("engine_tick")
         if etick:
             trace.extend(_engine_tick_lanes(ev, etick))
@@ -431,35 +420,4 @@ def _phase_lanes(ev: Dict[str, Any]) -> List[Dict[str, Any]]:
                     "ts": t, "dur": dur, "pid": pid, "tid": tid,
                     "args": args})
         t += dur
-    return out
-
-
-def _step_lanes(ev: Dict[str, Any], prof: Dict[str, Any]
-                ) -> List[Dict[str, Any]]:
-    """One step record -> its Perfetto lanes: the full step span plus
-    compile (front of the span) and sync (tail: the post-dispatch device
-    stall) sub-spans where nonzero."""
-    pid = ev.get("node_id") or "node"
-    tid = f"step:{prof.get('kind', 'step')}"
-    ts = prof["t_start"] * 1e6
-    wall = max(0.0, prof.get("wall_s", 0.0)) * 1e6
-    out = [{
-        "name": ev.get("name") or prof.get("kind", "step"),
-        "cat": "step", "ph": "X", "ts": ts, "dur": wall,
-        "pid": pid, "tid": tid,
-        "args": {"step": prof.get("step"), "tokens": prof.get("tokens"),
-                 "tokens_per_s": prof.get("tokens_per_s"),
-                 "mfu": prof.get("mfu"),
-                 "launches": prof.get("launches")},
-    }]
-    compile_s = prof.get("compile_s") or 0.0
-    if compile_s > 0:
-        out.append({"name": "compile", "cat": "compile", "ph": "X",
-                    "ts": ts, "dur": compile_s * 1e6,
-                    "pid": pid, "tid": tid})
-    sync_s = prof.get("execute_s") or 0.0
-    if sync_s > 0:
-        out.append({"name": "sync", "cat": "sync", "ph": "X",
-                    "ts": ts + wall - sync_s * 1e6, "dur": sync_s * 1e6,
-                    "pid": pid, "tid": tid})
     return out

@@ -96,22 +96,6 @@ def live_recorders() -> List["TrainRecorder"]:
     return _REGISTRY.live()
 
 
-def _profiler_launch_join() -> Optional[Dict[str, int]]:
-    """The step-profiler's registered launch source: launch/step counts
-    from THIS instrumentation point, so ``rt profile``'s st/ln column
-    and ``rt train stats`` can never drift apart. Returns None when no
-    fused launch has been recorded (the profiler falls back to its own
-    records)."""
-    launches = steps = 0
-    for r in live_recorders():
-        with r._lock:
-            launches += r._launches_total
-            steps += r._steps_total
-    if launches == 0:
-        return None
-    return {"launches": launches, "steps": steps}
-
-
 class TrainRecorder(RecorderCore):
     """Bounded flight recorder for one ``StepDriver``.
 
@@ -171,12 +155,6 @@ class TrainRecorder(RecorderCore):
         # drain-side watermarks (drain thread only)
         self._metrics_wm = 0
         self._event_wm = 0
-        try:
-            from ray_tpu.util import step_profiler as SP
-
-            SP.register_launch_source("train", _profiler_launch_join)
-        except Exception:  # noqa: BLE001 — profiler plane optional
-            pass
 
     # -- step path (driver thread) -----------------------------------------
 

@@ -342,23 +342,6 @@ def test_hbm_stats_graceful_on_cpu():
     publish_hbm_gauges(stats)  # must not raise whichever backend
 
 
-def test_step_profiler_hbm_column_cpu_safe():
-    from ray_tpu.util import step_profiler as sp
-
-    sp.reset()
-    sp.enable()
-    try:
-        sp.record("train", name="t", wall_s=0.01, tokens=10)
-        rec = sp.records("train")[-1]
-        assert isinstance(rec.hbm_peak_bytes, int)
-        assert rec.hbm_peak_bytes >= 0
-        assert "hbm_peak_bytes" in rec.to_dict()
-        assert "peak_hbm_bytes" in sp.summary("train")
-    finally:
-        sp.disable()
-        sp.reset()
-
-
 def test_ledger_deref_is_lock_free():
     """A weakref finalizer can fire via the cyclic GC on a thread that is
     ALREADY inside one of the ledger's locked regions (any allocation under

@@ -28,6 +28,6 @@ def test_scanner_sees_known_series():
     cm = _load_checker()
     regs = cm.registered_metrics()
     for name in ("rt_task_queue_wait_seconds", "rt_object_store_bytes",
-                 "rt_oom_kills_total", "rt_step_time_seconds",
+                 "rt_oom_kills_total", "rt_train_launch_gap_seconds",
                  "rt_hbm_used_bytes", "rt_nodes"):
         assert name in regs, f"scanner lost {name}"

@@ -93,33 +93,6 @@ def test_launch_phase_sums_and_overhead(driver_run):
     assert all(r["tokens"] == 2 * 2 * 16 and r["flops"] > 0 for r in recs)
 
 
-def test_profiler_launch_counts_join_recorder(driver_run):
-    """``rt profile``'s train row reads launch/step counts from the
-    recorder's registered source — one instrumentation point, so the two
-    surfaces cannot drift."""
-    from ray_tpu.util import step_profiler as SP
-
-    _driver, rec, _ = driver_run
-    with SP._lock:
-        assert "train" in SP._launch_sources
-    joined = TR._profiler_launch_join()
-    assert joined is not None
-    assert joined["launches"] >= 4 and joined["steps"] >= 8
-    # the profiler's own record count disagrees (it never saw these
-    # launches) — summary(kind) must prefer the recorder's join
-    SP.reset()
-    try:
-        SP.record("train", wall_s=0.01, launches=1, steps=1)
-        s = SP.summary("train")
-        assert s["launch_source"] == "recorder"
-        assert s["launches"] == joined["launches"]
-        assert s["steps"] == joined["steps"]
-        assert s["mean_steps_per_launch"] == pytest.approx(
-            joined["steps"] / joined["launches"])
-    finally:
-        SP.reset()
-
-
 # ---------------------------------------------------------------------------
 # launch-gap + waterfall math (synthetic records — no driver, no jax
 # dispatch; n_devices/peak pinned so the MFU arithmetic is exact)
