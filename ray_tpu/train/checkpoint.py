@@ -26,6 +26,11 @@ from typing import Any, Dict, List, Optional
 
 import cloudpickle
 
+# One orbax save at a time in a process: two that overlap (each writer
+# thread with a checkpointer of its own) break each other's temporary
+# directories or wait for each other without end.
+_SAVE_LOCK = threading.Lock()
+
 
 class Checkpoint:
     """A handle to a checkpoint directory."""
@@ -61,9 +66,10 @@ class Checkpoint:
         import orbax.checkpoint as ocp
 
         path = os.path.join(self.path, name)
-        shutil.rmtree(path, ignore_errors=True)
-        with ocp.StandardCheckpointer() as ckptr:
-            ckptr.save(path, tree)
+        with _SAVE_LOCK:
+            shutil.rmtree(path, ignore_errors=True)
+            with ocp.StandardCheckpointer() as ckptr:
+                ckptr.save(path, tree)
 
     def save_pytree(self, tree: Any, name: str = "state", *,
                     blocking: Optional[bool] = None) -> None:
