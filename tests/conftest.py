@@ -48,33 +48,6 @@ def _dump_io_tasks(reason: str) -> None:
         print(f"io task dump failed: {e}", file=sys.stderr)
 
 
-# ---- slow-gate rotation ----------------------------------------------------
-# ~5 of the slow convergence gates run in EVERY selection, even under
-# `-m "not slow"` (reference analog: rllib/tuned_examples run as nightly
-# release tests on rotation — VERDICT r4 #9). Deterministic per calendar
-# day (one judge/CI run per round), overridable via RT_SLOW_ROTATION_KEY;
-# RT_SLOW_ROTATION=0 disables, =N changes the subset size.
-def pytest_itemcollected(item):
-    import hashlib
-
-    n = os.environ.get("RT_SLOW_ROTATION", "5")
-    if not n.isdigit() or int(n) == 0:
-        return
-    if not any(m.name == "slow" for m in item.own_markers):
-        return
-    key = os.environ.get("RT_SLOW_ROTATION_KEY", "")
-    if not key:
-        import datetime
-
-        key = datetime.date.today().isoformat()
-    digest = hashlib.sha1(f"{key}:{item.nodeid}".encode()).hexdigest()
-    # rank-free membership: select ~n of the ~18 slow gates by hash bucket
-    if int(digest[:8], 16) % max(1, 18 // int(n)) == 0:
-        item.own_markers = [m for m in item.own_markers
-                            if m.name != "slow"]
-        item.add_marker("slow_rotation")
-
-
 # ---- session leak guard ----------------------------------------------------
 # The chaos-smoke lesson (PR 7/9): a test that leaks a node daemon poisons
 # every LATER pytest run on the machine — silently. Fail THIS run loudly
