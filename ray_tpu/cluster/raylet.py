@@ -1131,6 +1131,14 @@ class Raylet:
     def _release_worker(self, entry: _WorkerEntry) -> None:
         entry.busy = False
         entry.current_task = None
+        if entry.client is not None and entry.client._closed:
+            # A worker whose connection is down takes no task again, and a
+            # killed one closes its sockets a moment before ``poll()`` can
+            # collect it: pooled in that moment, it is handed the owner's
+            # retries one after another and fails each at once, so a task
+            # with three retries dies of one crash. The reap loop collects it.
+            entry.row.kill()
+            return
         if entry.row.poll() is None and not entry.is_actor_worker:
             entry.idle_since = time.monotonic()
             self._idle.setdefault(entry.key, []).append(entry)

@@ -159,6 +159,32 @@ def test_the_idle_reaper_keeps_its_row_until_poll_collects_it(
     assert entries[1].worker_id in raylet._workers  # within the soft limit
 
 
+def test_a_worker_whose_connection_is_down_is_not_pooled_again(
+        node, monkeypatch):
+    """A killed worker closes its sockets a moment before ``poll()`` can
+    collect it. Pooled in that moment it was handed the owner's retries one
+    after another and failed each at once: a task with three retries died of
+    one crash (one run in six of ``scripts/chaos_smoke.sh``'s first leg)."""
+    cluster, raylet = node
+
+    class Client:
+        _closed = True
+
+    proc = StandIn(heeds_term=False, gone_after_s=0.8)  # dying, not yet gone
+    entry = _spawn(cluster, raylet, proc, monkeypatch)
+    entry.client = Client()
+
+    async def release():
+        raylet._release_worker(entry)
+
+    cluster.io.run(release())
+    assert entry not in raylet._idle.get(entry.key, [])
+    assert entry.row.t_kill is not None
+    dropped_alive, held_while_dying = _watch(raylet, entry, 5.0)
+    assert not dropped_alive and held_while_dying
+    assert entry.worker_id not in raylet._workers
+
+
 def test_the_prestart_timeout_keeps_its_row_until_poll_collects_it(
         node, monkeypatch):
     cluster, raylet = node
