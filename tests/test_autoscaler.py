@@ -11,8 +11,6 @@ import subprocess
 import sys
 import time
 
-import pytest
-
 import ray_tpu
 from ray_tpu._private import config as config_mod
 
@@ -120,7 +118,6 @@ def test_min_workers_respected():
     assert a.update()["terminated"] == 0
 
 
-@pytest.mark.slow
 def test_autoscaler_e2e_local_provider(tmp_path, monkeypatch):
     """Real flow: CLI head with 1 CPU, autoscaler + LocalNodeProvider; a
     burst of 2-CPU tasks forces a real worker daemon to launch, tasks run,

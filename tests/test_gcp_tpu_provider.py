@@ -128,7 +128,6 @@ def test_no_relaunch_while_slice_is_booting():
     assert len(fake.nodes) == 1
 
 
-@pytest.mark.slow
 def test_autoscaler_scales_fake_tpu_slice_for_slice_group():
     """The full TPU gang flow: a pending slice_group() placement group (2
     hosts x 4 chips, STRICT_SPREAD) drives the autoscaler to provision ONE

@@ -268,7 +268,6 @@ def test_mcts_finds_winning_move():
     assert int(np.argmax(visits)) == 2, visits
 
 
-@pytest.mark.slow
 def test_alphazero_beats_random():
     cfg = rl.AlphaZeroConfig()
     cfg.num_simulations = 24

@@ -8,7 +8,6 @@ import sys
 import pytest
 
 
-@pytest.mark.slow
 def test_native_asan_ubsan_clean():
     if shutil.which("g++") is None:
         pytest.skip("no toolchain")

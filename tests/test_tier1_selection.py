@@ -17,8 +17,8 @@ _FILES = [
     "tests/test_rl_extras.py",
     "tests/test_multi_agent.py",
     "tests/test_tuned_examples.py",
-    "tests/test_autoscaler.py",
-    "tests/test_sanitize_native.py",
+    "tests/test_rl_connectors_ope.py",
+    "tests/test_serve.py",
 ]
 
 # a plugin that makes ``datetime.date.today()`` the day ``RT_TEST_TODAY`` names

@@ -278,8 +278,8 @@ def test_report_drainer_never_blocks_step_loop():
     # (0.36 s), and the 0.1 s is for the hand-off
     losses = [jnp.float32(i) * 2 for i in range(3)]
     t0 = time.perf_counter()
-    for i, ck in enumerate(slow):
-        session.report({"step": i, "loss": losses[i]}, ck)
+    for i, (ck, loss) in enumerate(zip(slow, losses)):
+        session.report({"step": i, "loss": loss}, ck)
     handoff_s = time.perf_counter() - t0
     assert handoff_s < 0.1, f"report blocked the loop: {handoff_s:.3f}s"
     session.finish()
