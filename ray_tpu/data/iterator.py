@@ -129,11 +129,13 @@ class JaxBatchIterator:
     the pipeline keeps up.
 
     ``stack`` advertises the K-stacking factor (``iter_jax_batches(stack=K)``
-    yields [k, B, ...] leaves, k == K except a ragged tail) — the
-    StepDriver keys its fused-vs-single dispatch off it.
+    yields [k, B, ...] leaves, k == K except a ragged tail; None: per-step
+    [B, ...] batches) — the StepDriver keys its fused-vs-single dispatch
+    off it.
     """
 
-    def __init__(self, inner: Iterator[Dict[str, Any]], stack: int = 1):
+    def __init__(self, inner: Iterator[Dict[str, Any]],
+                 stack: Optional[int] = None):
         self._inner = inner
         self.stack = stack
         self.ingest_s = 0.0
@@ -236,13 +238,16 @@ class DataIterator:
     def iter_jax_batches(self, *, batch_size: int = 256,
                          drop_last: bool = True, dtype=None,
                          prefetch_batches: int = 2,
-                         stack: int = 1) -> "JaxBatchIterator":
+                         stack: Optional[int] = None) -> "JaxBatchIterator":
         """Batches as jnp device arrays — the TPU feed path (host numpy →
         device put; drop_last defaults True to keep shapes static for jit).
 
         ``stack=K`` groups K consecutive batches into one [K, B, ...] tree
         (host-side ``np.stack``, then one device put) — the fused-K launch
-        feed. A ragged tail yields [k < K, B, ...]; the StepDriver
+        feed, for every K >= 1: a launch of one step is a group of one,
+        [1, B, ...], so a loop written for K reads the same at 1. Without
+        ``stack`` the batches come a step at a time, [B, ...].
+        A ragged tail yields [k < K, B, ...]; the StepDriver
         single-steps it. The device conversion itself runs ``prefetch_batches``
         ahead on a bounded lookahead thread, so at steady state the
         consumer's ``next()`` returns an already-materialized device batch
@@ -259,6 +264,9 @@ class DataIterator:
 
         import jax.numpy as jnp
 
+        if stack is not None and stack < 1:
+            raise ValueError(f"stack must be >= 1 or None, got {stack}")
+
         def host_gen():
             pend = []
             for batch in self.iter_batches(batch_size=batch_size,
@@ -267,7 +275,7 @@ class DataIterator:
                 batch = {k: (np.asarray(v) if dtype is None
                              else np.asarray(v).astype(dtype))
                          for k, v in batch.items()}
-                if stack <= 1:
+                if stack is None:
                     yield batch
                     continue
                 if pend and any(

@@ -65,6 +65,7 @@ def init_cache(cfg: llama.LlamaConfig, batch: int, max_len: int) -> Dict:
     ``kv_shape(sliding_window)``] for window layers' rings, and for a model with
     recurrent layers their ``ssm`` state and ``conv`` tail as well (its
     module's ``init_state``): ``cache_names``'s buffers."""
+    llama.refuse_trained_only(cfg)
     shape = (cfg.n_attention_layers, batch, *cfg.kv_shape(max_len))
     cache = {"k": jnp.zeros(shape, cfg.compute_dtype),
              "v": jnp.zeros(shape, cfg.compute_dtype)}

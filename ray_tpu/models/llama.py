@@ -399,22 +399,47 @@ def refuse_served_only(cfg: LlamaConfig) -> None:
             "models/hybrid.py, models/sambay.py)")
 
 
+#: kinds of a patterned config's layers (``models/moe.py``) that the
+#: training blocks compute and no served block does
+TRAINED_ONLY_KINDS = ("kda", "mla")
+
+
+def refuse_trained_only(cfg: LlamaConfig) -> None:
+    """What no served block computes yet: called where a cache is made
+    (``generate.init_cache``), which every serving constructor does."""
+    kinds = sorted(set(getattr(cfg, "layer_kinds", ()))
+                   & set(TRAINED_ONLY_KINDS))
+    if kinds:
+        raise NotImplementedError(
+            f"layers of kind {kinds} are trained only (models/moe.py's "
+            f"patterned walk): no served block keeps a latent slot cache "
+            f"('mla') or a matrix state a slot with its update ('kda')")
+
+
 def remat_block(cfg: LlamaConfig, fn):
     """``fn``, a layer, as a remat block where the config asks for one. It
     keeps the results of its matrix products and, where a flash forward ran
     inside it, that kernel's output and log-sum-exp, which its backward
     kernels read: a ``pallas_call`` is no product, so the dots policy alone
-    runs the forward kernel a second time in the backward. A block without
-    the kernel (``attn_impl="xla"``) carries no such name and keeps what
-    the dots policy keeps."""
+    runs the forward kernel a second time in the backward. Likewise where a
+    chunked delta rule ran inside it (``ops/kda.py``): the states at the
+    chunks' starts and the recurrence's output, which are a scan's results
+    and no product's, so that the backward does not walk the chunks a second
+    time forward. A block without either (``attn_impl="xla"``, no ``kda``
+    layer) carries no such name and keeps what the dots policy keeps, which
+    in a ``kda`` layer is its projections' results too (q, k, v and the two
+    low-rank gates, ~55 KB a token): recomputing them would cost a third
+    more of the layer's products and the memory is there."""
     if not cfg.remat:
         return fn
-    from ray_tpu.ops.pallas.flash import RESIDUAL_NAMES
+    from ray_tpu.ops import kda
+    from ray_tpu.ops.pallas import flash
 
     policies = jax.checkpoint_policies
     return jax.checkpoint(fn, policy=policies.save_from_both_policies(
         policies.dots_with_no_batch_dims_saveable,
-        policies.save_only_these_names(*RESIDUAL_NAMES)))
+        policies.save_only_these_names(*flash.RESIDUAL_NAMES,
+                                       *kda.RESIDUAL_NAMES)))
 
 
 def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,

@@ -1,0 +1,200 @@
+"""Two mixers of the patterned walk (``models/moe.py``) beside llama's
+attention half, each a pre-norm branch on the residual stream that is
+trained and not served: a delta-rule linear attention whose state decays a
+channel (``"kda"``, Kimi Delta Attention) and latent attention in its
+uncompressed form, unrotated (``"mla"``).
+
+A ``kda`` layer, ``h`` the normed input, H heads of width ``dk = dv``:
+
+- ``q, k, v = silu(conv4(h @ wq)), silu(conv4(h @ wk)), silu(conv4(h @ wv))``,
+  each its own depthwise causal convolution (``ops/ssm.causal_conv``, no
+  bias); a head's ``q`` and ``k`` L2-normalised over their width, ``q``
+  times ``dk ** -0.5``;
+- log-decay a channel ``g = -exp(A_log)[head] * softplus((h @ f_down) @
+  f_up + dt_bias)`` and step ``beta = sigmoid(h @ wb)``, float32;
+- the recurrence (``ops/kda.py``, chunked);
+- ``o = rms(o, o_norm) * sigmoid((h @ g_down) @ g_up + g_bias)`` over each
+  head's ``dv``, then ``wo``.
+
+An ``mla`` layer, H heads: ``q = h @ wq`` [H, nope + rope]; ``[c, k_pe] = h
+@ wkv_a`` [rank], [rope]; ``c = rms(c, kv_norm)``; ``[k_nope, v] = c @ wkv_b``
+[H, nope + dv]; a head's key is ``[k_nope ; k_pe]``, ``k_pe`` one for all
+heads; nothing is rotated (the ``rope`` columns are plain columns); causal
+softmax at ``(nope + rope) ** -0.5`` with values ``dv`` wide (the flash
+kernels' two widths, ``ops/pallas/flash.py``); ``wo``. Nothing is absorbed
+and nothing cached: this is the form a training step runs.
+
+Scopes are names only. The walk opens ``attn_kda`` / ``attn_mla`` round a
+layer's mixer half; inside ``attn_kda`` lie ``kda_conv``, ``kda_gates`` and
+``kda_scan`` (the recurrence, forward and backward, and nothing else),
+inside ``attn_mla`` ``mla_latent`` (the down- and up-projections and the
+latent's norm).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import post_norm
+from ray_tpu.ops import kda
+from ray_tpu.ops.attention import mha
+from ray_tpu.ops.norms import rmsnorm
+from ray_tpu.ops.ssm import causal_conv
+
+Params = Dict[str, Any]
+F32 = jnp.float32
+
+#: added under the square root of the L2 norm of a head's q and k
+L2_EPS = 1e-6
+#: ``A_log`` is drawn as the log of uniform(A_RANGE) and ``dt_bias`` as the
+#: inverse softplus of exp(uniform(log DT_RANGE)): Mamba's initialisation,
+#: which spreads the initial decays over (0.85, 0.99999) a token
+A_RANGE = (1.0, 16.0)
+DT_RANGE = (1e-3, 1e-1)
+
+
+def _normal(key, shape, fan_in, dtype):
+    return (jax.random.normal(key, shape, F32) / math.sqrt(fan_in)).astype(dtype)
+
+
+# ---------------------------------------------------------------------- kda
+
+def kda_params(cfg) -> int:
+    """One layer's ``kda`` leaves (the norm before the branch left out)."""
+    d, h, w, t = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv_taps
+    return (4 * d * h * w + 3 * t * h * w          # q k v o and their taps
+            + 2 * (d * w + w * h * w) + h * w      # the two low-rank pairs, g_bias
+            + d * h + h + h * w + w)               # wb, A_log, dt_bias, o_norm
+
+
+def init_kda(rng: jax.Array, cfg, n: int) -> Params:
+    """``n`` layers' ``kda`` leaves, stacked. ``A_log`` and ``dt_bias`` are
+    float32 whatever the parameters' dtype: eight bits of mantissa would
+    move a decay near 1 by more than it forgets."""
+    d, h, w, t = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv_taps
+    ch, dt = h * w, cfg.param_dtype
+    ks = jax.random.split(rng, 14)
+    step = jnp.exp(jax.random.uniform(
+        ks[12], (n, ch), F32, math.log(DT_RANGE[0]), math.log(DT_RANGE[1])))
+    return {
+        "wq": _normal(ks[0], (n, d, ch), d, dt),
+        "wk": _normal(ks[1], (n, d, ch), d, dt),
+        "wv": _normal(ks[2], (n, d, ch), d, dt),
+        "conv_q": _normal(ks[3], (n, t, ch), t, dt),
+        "conv_k": _normal(ks[4], (n, t, ch), t, dt),
+        "conv_v": _normal(ks[5], (n, t, ch), t, dt),
+        "f_down": _normal(ks[6], (n, d, w), d, dt),
+        "f_up": _normal(ks[7], (n, w, ch), w, dt),
+        "wb": _normal(ks[8], (n, d, h), d, dt),
+        "g_down": _normal(ks[9], (n, d, w), d, dt),
+        "g_up": _normal(ks[10], (n, w, ch), w, dt),
+        "g_bias": jnp.zeros((n, ch), dt),
+        "A_log": jnp.log(jax.random.uniform(ks[11], (n, h), F32, *A_RANGE)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "o_norm": jnp.ones((n, w), dt),
+        "wo": _normal(ks[13], (n, ch, d), ch, dt),
+    }
+
+
+def kda_half(cfg, x: jax.Array, layer: Params) -> jax.Array:
+    """Pre-norm KDA + residual, [b, s, d] -> [b, s, d]; the caller opens
+    ``attn_kda`` round it (module docstring)."""
+    b, s, _ = x.shape
+    h, w, cdt = cfg.kda_heads, cfg.kda_head_dim, cfg.compute_dtype
+    hx = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
+
+    with jax.named_scope("kda_conv"):
+        tail = jnp.zeros((b, cfg.kda_conv_taps - 1, h * w), cdt)
+
+        def mixed(which):
+            y, _ = causal_conv(hx @ layer["w" + which].astype(cdt), tail,
+                               layer["conv_" + which].astype(cdt), 0.0)
+            return jax.nn.silu(y).reshape(b, s, h, w)
+
+        def unit(y):  # a head's L2 norm, summed in float32
+            y32 = y.astype(F32)
+            return (y32 * jax.lax.rsqrt(
+                jnp.sum(y32 * y32, -1, keepdims=True) + L2_EPS)).astype(cdt)
+
+        q, k, v = unit(mixed("q")) * jnp.asarray(w ** -0.5, cdt), \
+            unit(mixed("k")), mixed("v")
+
+    with jax.named_scope("kda_gates"):
+        a = ((hx @ layer["f_down"].astype(cdt))
+             @ layer["f_up"].astype(cdt)).astype(F32) \
+            + layer["dt_bias"].astype(F32)
+        g = -jnp.exp(layer["A_log"].astype(F32))[:, None] \
+            * jax.nn.softplus(a).reshape(b, s, h, w)
+        beta = jax.nn.sigmoid((hx @ layer["wb"].astype(cdt)).astype(F32))
+
+    with jax.named_scope("kda_scan"):
+        o = kda.kda_chunked(q, k, v, g, beta)
+
+    gate = jax.nn.sigmoid(
+        (hx @ layer["g_down"].astype(cdt)) @ layer["g_up"].astype(cdt)
+        + layer["g_bias"].astype(cdt)).reshape(b, s, h, w)
+    o = rmsnorm(o, layer["o_norm"].astype(cdt), cfg.norm_eps) * gate
+    return x + post_norm(
+        cfg, o.reshape(b, s, h * w) @ layer["wo"].astype(cdt), layer,
+        "attn_post_norm")
+
+
+# ---------------------------------------------------------------------- mla
+
+def mla_params(cfg) -> int:
+    """One layer's ``mla`` leaves (the norm before the branch left out)."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return (d * h * (nope + rope) + d * (r + rope) + r
+            + r * h * (nope + dv) + h * dv * d)
+
+
+def init_mla(rng: jax.Array, cfg, n: int) -> Params:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = cfg.param_dtype
+    ks = jax.random.split(rng, 4)
+    return {
+        "wq": _normal(ks[0], (n, d, h * (nope + rope)), d, dt),
+        "wkv_a": _normal(ks[1], (n, d, r + rope), d, dt),
+        "kv_norm": jnp.ones((n, r), dt),
+        "wkv_b": _normal(ks[2], (n, r, h * (nope + dv)), r, dt),
+        "wo": _normal(ks[3], (n, h * dv, d), h * dv, dt),
+    }
+
+
+def mla_half(cfg, x: jax.Array, layer: Params, segment_ids) -> jax.Array:
+    """Pre-norm latent attention + residual, [b, s, d] -> [b, s, d]."""
+    b, s, _ = x.shape
+    h, r, cdt = cfg.n_heads, cfg.kv_lora_rank, cfg.compute_dtype
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    hx = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
+
+    with jax.named_scope("mla_latent"):
+        q = (hx @ layer["wq"].astype(cdt)).reshape(b, s, h, nope + rope)
+        down = hx @ layer["wkv_a"].astype(cdt)
+        c = rmsnorm(down[..., :r], layer["kv_norm"].astype(cdt), cfg.norm_eps)
+        up = (c @ layer["wkv_b"].astype(cdt)).reshape(b, s, h, nope + dv)
+        k = jnp.concatenate([up[..., :nope], jnp.broadcast_to(
+            down[:, :, None, r:], (b, s, h, rope))], axis=-1)
+        v = up[..., nope:]
+
+    if cfg.attn_impl == "flash":
+        if segment_ids is not None:
+            raise NotImplementedError(
+                "segment_ids (packed sequences) require attn_impl='xla'")
+        from ray_tpu.parallel.context import flash_attention_on_mesh
+
+        attn = flash_attention_on_mesh(q, k, v, causal=True)
+    elif cfg.attn_impl == "xla":
+        attn = mha(q, k, v, causal=True, segment_ids=segment_ids)
+    else:
+        raise NotImplementedError(
+            f"latent attention under attn_impl={cfg.attn_impl!r}: a sequence "
+            f"split across chips has no ring at two head widths")
+    return x + post_norm(cfg, attn.reshape(b, s, h * dv)
+                         @ layer["wo"].astype(cdt), layer, "attn_post_norm")

@@ -25,9 +25,12 @@ overflow tokens drop that expert (standard Switch behavior — the residual
 stream carries them).
 Load-balancing aux loss: ``n_experts * sum_e(fraction_e * prob_e)``.
 
-The patterned form (``MoEConfig.layer_kinds`` set; Trinity's ``afmoe``):
-leading dense layers and then expert layers, each layer's attention one of
-``ATTN_KINDS`` (rotated inside a band, or unrotated and full), a sigmoid
+The patterned form (``MoEConfig.layer_kinds`` set; Trinity's ``afmoe``,
+Kimi Linear): leading dense layers and then expert layers, each layer's
+mixer one of the four ``ATTN_KINDS`` (attention rotated inside a band
+(``"window"``) or unrotated and full (``"full"``), both
+``llama.attention_half``; a delta-rule linear attention (``"kda"``) or
+unrotated latent attention (``"mla"``), both ``models/mixers.py``), a sigmoid
 router whose selection adds a bias the gates do not see, a shared expert
 beside the routed ones, and a layer that may hold a share of its experts:
 ``n_experts_held`` of ``n_experts``, the first ones, as one chip of an
@@ -38,7 +41,9 @@ drop and adds nothing here: what the absent experts would have added is
 left out (on one chip the layer runs without its exchange, and nothing
 stands in for the other chips). ``forward_hidden`` walks the dense segment
 layer by layer and the expert layers period by period (``_walk``), the
-kinds inside a period static; it also counts its routing
+kinds inside a period static and the mixers' leaves stacked by kind, since a
+``kda`` layer and an ``mla`` layer hold different ones (``_pick``); it also
+counts its routing
 (``ROUTING_COUNTERS``), and a step moves the selection bias by what it
 counted (``buffer_updates``).
 """
@@ -54,15 +59,23 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mixers
 from ray_tpu.parallel.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
 
-#: a patterned config's kinds of attention layer: rotated queries and keys
-#: inside a band of ``sliding_window``, or no rotation and the whole past
-ATTN_KINDS = ("window", "full")
+#: a patterned config's kinds of mixer: attention with rotated queries and
+#: keys inside a band of ``sliding_window``, or with no rotation and the
+#: whole past; a delta-rule linear attention with a decay a channel; latent
+#: attention, unrotated, uncompressed, over the whole past
+ATTN_KINDS = ("window", "full", "kda", "mla")
+#: where a kind's mixer keeps its leaves in a segment's tree: beside the
+#: layer's other leaves (``""``: the two kinds of ``llama.attention_half``
+#: hold alike ones, ``_ATTN_LEAVES``), or in a sub-tree of its own; either
+#: way stacked over the segment's layers of that stack alone
+_STACK = {"window": "", "full": "", "kda": "kda", "mla": "mla"}
+_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm", "wg")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +110,16 @@ class MoEConfig(llama.LlamaConfig):
     # first choices and the whole batch) or "sequence" (E/K * sum_e f_e P_e
     # a sequence, f over all K choices, P the scores normalised to sum 1)
     balance: str = "first_choice"
+    # a ``kda`` layer's heads, their width (keys and values alike; the two
+    # low-rank gates pass through it too) and its convolutions' taps
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_taps: int = 4
+    # an ``mla`` layer's latent and its three head widths (``n_heads`` heads)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         if self.balance == "first_choice" and self.experts_held != self.n_experts:
@@ -115,6 +138,14 @@ class MoEConfig(llama.LlamaConfig):
                 f"the kinds are {ATTN_KINDS}")
         if "window" in self.layer_kinds and not self.sliding_window:
             raise ValueError("a window layer needs sliding_window")
+        if "kda" in self.layer_kinds and not (
+                self.kda_heads and self.kda_head_dim):
+            raise ValueError("a kda layer needs kda_heads and kda_head_dim")
+        if "mla" in self.layer_kinds and not (
+                self.kv_lora_rank and self.qk_nope_head_dim
+                and self.v_head_dim):
+            raise ValueError("an mla layer needs kv_lora_rank, "
+                             "qk_nope_head_dim and v_head_dim")
 
     @property
     def experts_held(self) -> int:
@@ -141,15 +172,22 @@ class MoEConfig(llama.LlamaConfig):
                 + d * self.n_experts + 2 * self.router_bias * self.n_experts
                 + (1 + self.sandwich_norm) * d)
 
+    def mixer_params(self, kind: str) -> int:
+        """One layer's mixer of ``kind`` with the norms round its branch."""
+        if _STACK[kind] == "":
+            return self.attn_params()
+        own = (mixers.kda_params if kind == "kda" else mixers.mla_params)(self)
+        return own + (1 + self.sandwich_norm) * self.d_model
+
     def _params(self, experts: int) -> int:
         d, v = self.d_model, self.vocab_size
-        dense = self.n_dense_layers * (
-            self.attn_params() + 3 * d * self.d_ff_dense
-            + (1 + self.sandwich_norm) * d)
-        sparse = self.n_expert_layers * (self.attn_params()
-                                         + self._ffn_params(experts))
+        kinds = self.layer_kinds or ("full",) * self.n_layers
+        mixing = sum(map(self.mixer_params, kinds))
+        dense = self.n_dense_layers * (3 * d * self.d_ff_dense
+                                       + (1 + self.sandwich_norm) * d)
+        sparse = self.n_expert_layers * self._ffn_params(experts)
         head = 0 if self.tie_embeddings else d * v
-        return v * d + dense + sparse + d + head
+        return v * d + mixing + dense + sparse + d + head
 
     def num_params(self) -> int:
         """Parameters held here (``n_experts_held`` experts a layer)."""
@@ -187,13 +225,37 @@ ROUTER_BIAS_RATE = 1e-2
 ROUTER_BIAS_MOMENTUM = 0.5
 
 
+def _segment_mixers(rng: jax.Array, cfg: MoEConfig, layers: Params,
+                    kinds: Tuple[str, ...]) -> None:
+    """Make ``layers``, a llama stack over a segment's ``len(kinds)``
+    layers, hold each mixer's leaves by its stack (``_STACK``): the
+    attention leaves over the segment's ``window`` and ``full`` layers alone
+    (gone where it has none), and a sub-tree for each other kind, stacked
+    over the layers of that kind."""
+    n_attn = sum(_STACK[k] == "" for k in kinds)
+    if n_attn < len(kinds):
+        for name in set(_ATTN_LEAVES) & set(layers):
+            if n_attn:
+                layers[name] = layers[name][:n_attn]
+            else:
+                del layers[name]
+    for i, (kind, init) in enumerate((("kda", mixers.init_kda),
+                                      ("mla", mixers.init_mla))):
+        n = kinds.count(kind)
+        if n:
+            layers[kind] = init(jax.random.fold_in(rng, 20 + i), cfg, n)
+
+
 def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     """Llama init plus stacked expert FFNs [L, E, ...] and routers; ``E``
     the experts held here, the router ``n_experts`` wide. A patterned
     config's leading dense layers are a llama stack of their own under
     ``dense_layers``, its shared expert ``s_gate`` / ``s_up`` / ``s_down``
     and its selection bias ``router_bias`` (with the momentum of its
-    movement, ``router_bias_m``) lie with the expert layers."""
+    movement, ``router_bias_m``) lie with the expert layers, and in either
+    segment a ``kda`` or ``mla`` layer's mixer lies in a sub-tree of its
+    kind's name, stacked over the segment's layers of that kind
+    (``_segment_mixers``)."""
     d, f, E, L = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_expert_layers
     H = cfg.experts_held
     base = llama.init_params(rng, dataclasses.replace(
@@ -222,11 +284,17 @@ def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
         layers["router_bias"] = ROUTER_BIAS_INIT * jax.random.normal(
             jax.random.fold_in(rng, 9), (L, E), jnp.float32)
         layers["router_bias_m"] = jnp.zeros((L, E), jnp.float32)
+    if cfg.layer_kinds:
+        _segment_mixers(jax.random.fold_in(rng, 11), cfg, layers,
+                        cfg.layer_kinds[cfg.n_dense_layers:])
     if cfg.n_dense_layers:
         base["dense_layers"] = llama.init_params(
             jax.random.fold_in(rng, 10), dataclasses.replace(
                 cfg, n_layers=cfg.n_dense_layers, d_ff=cfg.d_ff_dense,
                 layer_kinds=(), n_dense_layers=0, vocab_size=8))["layers"]
+        _segment_mixers(jax.random.fold_in(rng, 12), cfg,
+                        base["dense_layers"],
+                        cfg.layer_kinds[:cfg.n_dense_layers])
     return base
 
 
@@ -647,10 +715,46 @@ def _moe_block(cfg: MoEConfig, x: jax.Array, layer: Params,
     return ffn_half(cfg, x, layer)
 
 
+def _pick(tree: Params, kinds: Tuple[str, ...], j: int) -> Params:
+    """Layer ``j`` of a segment whose layers are of ``kinds``, as one flat
+    dict: the leaves every layer has at ``j``, and its mixer's from its
+    kind's stack (``_STACK``) at the number of earlier layers that share
+    that stack. (Leaf by leaf in the tree's own order: a segment of
+    ``window`` and ``full`` layers alone traces as it did when the walk
+    indexed one tree by position.)"""
+    stack = _STACK[kinds[j]]
+    at = sum(_STACK[k] == stack for k in kinds[:j])
+    layer = {name: a[at if name in _ATTN_LEAVES else j]
+             for name, a in sorted(tree.items())
+             if not isinstance(a, dict)
+             and not (stack and name in _ATTN_LEAVES)}
+    if stack:
+        layer.update(jax.tree.map(lambda a: a[at], tree[stack]))
+    return layer
+
+
+def _mixer_half(cfg: MoEConfig, kind: str, x, layer, sin, cos, segment_ids):
+    """The layer's mixer of ``kind``, a pre-norm branch on ``x``, under the
+    scope ``attn_<kind>``."""
+    if kind == "kda" and segment_ids is not None:
+        raise NotImplementedError(
+            "segment_ids (packed sequences) through a kda layer: the "
+            "recurrence would have to reset its state at a boundary")
+    with jax.named_scope("attn_" + kind):
+        if kind == "kda":
+            return mixers.kda_half(cfg, x, layer)
+        if kind == "mla":
+            return mixers.mla_half(cfg, x, layer, segment_ids)
+        return llama.attention_half(
+            cfg, x, layer, sin, cos, segment_ids, rotate=kind == "window",
+            window=cfg.sliding_window if kind == "window" else None)
+
+
 def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
     """One layer of a patterned config as ``(x, layer) -> (x, aux, load,
-    kept)``, its kind static: the attention half rotated inside the band or
-    unrotated and full, then a dense SwiGLU (no ``aux``, ``load`` or
+    kept)``, its kind static: the mixer of its kind (``_mixer_half``: the
+    attention half rotated inside the band or unrotated and full, the delta
+    rule, latent attention), then a dense SwiGLU (no ``aux``, ``load`` or
     ``kept``: None) or the shared expert beside the routed ones, every
     branch normed before and after where the config says so. ``load`` [E]
     is the choices each of the ``n_experts`` got, ``kept`` how many took a
@@ -658,11 +762,7 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
     cdt = cfg.compute_dtype
 
     def run(x, layer, sin, cos, segment_ids):
-        with jax.named_scope("attn_" + kind):
-            x = llama.attention_half(
-                cfg, x, layer, sin, cos, segment_ids,
-                rotate=kind == "window",
-                window=cfg.sliding_window if kind == "window" else None)
+        x = _mixer_half(cfg, kind, x, layer, sin, cos, segment_ids)
         if dense:
             with jax.named_scope("mlp"):
                 return llama.ffn_half(cfg, x, layer), None, None, None
@@ -689,11 +789,13 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
     after another (their kinds need repeat nothing), then a ``lax.scan``
     over the repeats of the expert layers' shortest repeating pattern, the
     layers of one period written out inside it with their kinds static
-    (``models/hybrid._walk`` is the served precedent). Every layer is its
-    own remat block. Returns (x, the expert layers' summed aux, their
+    (``models/hybrid._walk`` is the served precedent) and each taking its
+    leaves from its kind's stack (``_pick``). Every layer is its own remat
+    block. Returns (x, the expert layers' summed aux, their
     ``load`` [L, E] and ``kept`` [L])."""
-    for i, kind in enumerate(cfg.layer_kinds[:cfg.n_dense_layers]):
-        layer = jax.tree.map(lambda a, i=i: a[i], params["dense_layers"])
+    leading = cfg.layer_kinds[:cfg.n_dense_layers]
+    for i, kind in enumerate(leading):
+        layer = _pick(params["dense_layers"], leading, i)
         run = _patterned_layer(cfg, kind, dense=True)
         x = llama.remat_block(cfg, lambda x, layer, run=run: run(
             x, layer, sin, cos, segment_ids)[0])(x, layer)
@@ -707,14 +809,17 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
         x, aux = carry
         counts = []
         for j, run in enumerate(runs):
-            x, a, *count = run(x, jax.tree.map(lambda a, j=j: a[j], layers))
+            x, a, *count = run(x, _pick(layers, period, j))
             aux = aux + a
             counts.append(count)
         load, kept = zip(*counts)
         return (x, aux), (jnp.stack(load), jnp.stack(kept))
 
+    # every stack [repeats, its layers a period, ...]: a stack holds as many
+    # layers as the periods have of its kinds
+    repeats = cfg.n_expert_layers // len(period)
     by_period = jax.tree.map(
-        lambda a: a.reshape(-1, len(period), *a.shape[1:]), params["layers"])
+        lambda a: a.reshape(repeats, -1, *a.shape[1:]), params["layers"])
     (x, aux), (load, kept) = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), by_period)
     return x, aux, load.reshape(-1, cfg.n_experts), kept.reshape(-1)
@@ -817,6 +922,16 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
         (r"lm_head$", P("fsdp", "tp")),
         (r"layers/w[qkvg]$", P(None, "fsdp", "tp")),
         (r"layers/wo$", P(None, "tp", "fsdp")),
+        # the mixers that lie in a sub-tree of their kind's name: the
+        # matrices into the heads like wq, out of them like wo, the narrow
+        # ones (a latent, a low rank, a scalar a head) whole on that side
+        (r"layers/(kda|mla)/w[qkv]$", P(None, "fsdp", "tp")),
+        (r"layers/(kda|mla)/wo$", P(None, "tp", "fsdp")),
+        (r"layers/(kda/(wb|[fg]_down)|mla/wkv_a)$", P(None, "fsdp", None)),
+        (r"layers/(kda/[fg]_up|mla/wkv_b|kda/conv_[qkv])$",
+         P(None, None, "tp")),
+        (r"layers/kda/(dt|g)_bias$", P(None, "tp")),
+        (r"layers/kda/A_log$", P(None)),
         # a patterned config's dense layers and shared expert, as llama's
         (r"layers/[ws]_(gate|up)$", P(None, "fsdp", "tp")),
         (r"layers/[ws]_down$", P(None, "tp", "fsdp")),

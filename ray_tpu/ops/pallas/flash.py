@@ -74,7 +74,21 @@ crosses a block. ``window=None`` is the causal kernel as it was.
 Names. A call is named ``flash_<kind>_bh<bh>_q<sq>_k<sk>_d<d>_c<causal>_w<w>``
 (w 0: no band), the true lengths before padding: a device trace shows a
 ``pallas_call`` under its name, and a call's result does not say what of
-the square it skipped.
+the square it skipped. Where the values are not as wide as the queries and
+keys the width reads ``_d<d>v<dv>_`` (next paragraph); a call of one width
+keeps the name above to the letter.
+
+Two widths. ``v`` (and so ``o``, ``do``, ``dv``) may have a head width
+``dv`` of its own, as latent attention's uncompressed form has (queries and
+keys 192 = 128 + 64, values 128): ``QK^T`` and the products that give ``dq``
+and ``dk`` contract or produce ``d`` columns, ``PV``, ``dP = dO V^T`` and
+``dV`` ``dv``. Nothing is padded to the wider of the two: every block's last
+dimension is the array's own, which Mosaic takes whole whatever its size. A
+width that is no multiple of the 128 lanes (192) lies in VMEM as two lane
+tiles, the second half empty, and a product that contracts it runs the MXU's
+128-deep pass twice, the second half idle: ``QK^T`` at 192 costs what it
+would at 256, and so ``plan`` counts it (``_vmem_bytes`` rounds each width
+up to whole lanes).
 
 Precision. Products take their operands in the inputs' dtype and accumulate
 in float32, forward and backward alike (``p`` and ``ds`` are cast as the
@@ -179,23 +193,27 @@ def _sub_block(kind: str, block_q: int, block_k: int) -> Tuple[int, int]:
 
 
 def _vmem_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
-                itemsize: int) -> int:
+                itemsize: int, value_dim: Optional[int] = None) -> int:
     """What one grid step holds: each operand and result block twice (the
     pipeline's two buffers), a [rows, 1] float32 block padded to 128 lanes,
     the float32 scratch, the score-sized temporaries of the clear body and
-    the masked body's, a sub-tile's."""
+    the masked body's, a sub-tile's. ``value_dim``: the width of ``v``,
+    ``o``, ``do`` and ``dv`` where it is not ``head_dim``."""
     d = _round_up(head_dim, _LANES)
+    e = _round_up(value_dim or head_dim, _LANES)
     q_blk, k_blk = block_q * d * itemsize, block_k * d * itemsize
+    o_blk, v_blk = block_q * e * itemsize, block_k * e * itemsize
     q_col = block_q * _LANES * 4
     if kind == "fwd":    # q k v -> o lse | m l acc
-        blocks = 2 * (2 * q_blk + 2 * k_blk + q_col)
-        scratch = 2 * q_col + block_q * d * 4
+        blocks = 2 * (q_blk + o_blk + k_blk + v_blk + q_col)
+        scratch = 2 * q_col + block_q * e * 4
     elif kind == "dq":   # q k v do lse delta -> dq | dq
-        blocks = 2 * (3 * q_blk + 2 * k_blk + 2 * q_col)
+        blocks = 2 * (2 * q_blk + o_blk + k_blk + v_blk + 2 * q_col)
         scratch = block_q * d * 4
     else:                # q k v do lse delta (rows) -> dk dv | dk dv
-        blocks = 2 * (2 * q_blk + 4 * k_blk + 2 * 8 * block_q * 4)
-        scratch = 2 * block_k * d * 4
+        blocks = 2 * (q_blk + o_blk + 2 * k_blk + 2 * v_blk
+                      + 2 * 8 * block_q * 4)
+        scratch = block_k * (d + e) * 4
     sub_q, sub_k = _sub_block(kind, block_q, block_k)
     return (blocks + scratch + _SCORE_TEMPS[kind] * block_q * block_k * 4
             + _MASK_TEMPS * sub_q * sub_k * 4)
@@ -242,11 +260,14 @@ def _check_window(causal: bool, window: Optional[int]) -> None:
 
 def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
          kind: str, blocks: Optional[Tuple[int, int]] = None,
-         window: Optional[int] = None) -> Plan:
+         window: Optional[int] = None,
+         value_dim: Optional[int] = None) -> Plan:
     """The tiling of one kernel (``kind`` of ``KINDS``) at one shape; pure.
     Explicit ``blocks`` (block_q, block_k) are kept, shrunk to a short
     sequence. ``window`` moves no block's size: it only takes the pairs
-    below the band out of ``live_steps``."""
+    below the band out of ``live_steps``. ``value_dim``: the values' head
+    width where it is not ``head_dim`` (None or equal: the plan of one
+    width, as it was)."""
     if kind not in KINDS:
         raise ValueError(f"kind {kind!r} is not one of {KINDS}")
     _check_window(causal, window)
@@ -255,7 +276,7 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
     else:
         tq = tk = _TARGET_BLOCK
         # halve the longer side until the tile fits
-        while (_vmem_bytes(kind, tq, tk, head_dim, itemsize)
+        while (_vmem_bytes(kind, tq, tk, head_dim, itemsize, value_dim)
                > _VMEM_BUDGET_BYTES and max(tq, tk) > _LANES):
             tq, tk = (tq // 2, tk) if tq >= tk else (tq, tk // 2)
         bq, bk = _fit_block(tq, seq_q), _fit_block(tk, seq_k)
@@ -263,7 +284,7 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
     pairs = [_live_and_clear(i * bq, bq, j * bk, bk, 0, causal=causal,
                              window=window, q_len=seq_q, kv_len=seq_k)
              for i in range(nq) for j in range(nk)]
-    need = _vmem_bytes(kind, bq, bk, head_dim, itemsize)
+    need = _vmem_bytes(kind, bq, bk, head_dim, itemsize, value_dim)
     return Plan(kind, bq, bk, nq * nk, sum(live for live, _ in pairs),
                 sum(live and not clear for live, clear in pairs),
                 _sub_block(kind, bq, bk), need,
@@ -277,7 +298,8 @@ _noting = threading.local()
 def noting_plans(into: List[Dict[str, Any]]) -> Iterator[None]:
     """Within the scope, each distinct plan a flash kernel is traced with
     in this thread is appended to ``into`` (the plan's fields and the shape
-    it was chosen for). A plan is static per traced shape, so the scope
+    it was chosen for; ``value_dim`` too where the values have a width of
+    their own). A plan is static per traced shape, so the scope
     belongs round the call that traces: ``StepDriver`` puts it round its
     launches and hands the list to its recorder."""
     was = getattr(_noting, "into", None)
@@ -288,22 +310,25 @@ def noting_plans(into: List[Dict[str, Any]]) -> Iterator[None]:
         _noting.into = was
 
 
-def _planned(kind: str, q, k, causal: bool,
+def _planned(kind: str, q, k, v, causal: bool,
              blocks: Optional[Tuple[int, int]],
              window: Optional[int]) -> Tuple[Plan, str]:
-    """The plan of the kernel about to be built on q, k [bh, s, d], noted,
-    and the call's name (module docstring)."""
+    """The plan of the kernel about to be built on q, k [bh, s, d] and v
+    [bh, s, dv], noted, and the call's name (module docstring)."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
-    p = plan(sq, sk, d, q.dtype.itemsize, causal, kind, blocks, window)
+    sk, dv = k.shape[1], v.shape[2]
+    two = dv != d
+    p = plan(sq, sk, d, q.dtype.itemsize, causal, kind, blocks, window,
+             dv if two else None)
     into = getattr(_noting, "into", None)
     if into is not None:
         note = {**p._asdict(), "seq_q": sq, "seq_k": sk, "head_dim": d,
                 "itemsize": q.dtype.itemsize, "causal": causal,
-                "window": window}
+                "window": window, **({"value_dim": dv} if two else {})}
         if note not in into:
             into.append(note)
-    return p, (f"flash_{kind}_bh{bh}_q{sq}_k{sk}_d{d}_c{int(causal)}"
+    width = f"d{d}v{dv}" if two else f"d{d}"
+    return p, (f"flash_{kind}_bh{bh}_q{sq}_k{sk}_{width}_c{int(causal)}"
                f"_w{window or 0}")
 
 
@@ -472,11 +497,12 @@ def _walks_k(p: Plan, causal: bool, nk: int, window: Optional[int]):
 
 def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
                     window=None) -> Tuple[jax.Array, jax.Array]:
-    """q,k,v: [bh, s, d]; returns (o [bh, sq, d], lse [bh, sq]). Pads to
-    block multiples; padded keys are masked, padded rows cut off."""
+    """q,k: [bh, s, d], v: [bh, s, dv]; returns (o [bh, sq, dv], lse
+    [bh, sq]). Pads to block multiples; padded keys are masked, padded rows
+    cut off."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
-    p, name = _planned("fwd", q, k, causal, blocks, window)
+    sk, dv = k.shape[1], v.shape[2]
+    p, name = _planned("fwd", q, k, v, causal, blocks, window)
     q, k, v = _pad_seq(q, p.block_q), _pad_seq(k, p.block_k), \
         _pad_seq(v, p.block_k)
     nq, nk = q.shape[1] // p.block_q, k.shape[1] // p.block_k
@@ -490,15 +516,15 @@ def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, nk),
-            in_specs=[qspec(d), kspec(d), kspec(d)],
-            out_specs=[qspec(d), qspec(1)],
+            in_specs=[qspec(d), kspec(d), kspec(dv)],
+            out_specs=[qspec(dv), qspec(1)],
             scratch_shapes=[
                 pltpu.VMEM((p.block_q, 1), jnp.float32),
                 pltpu.VMEM((p.block_q, 1), jnp.float32),
-                pltpu.VMEM((p.block_q, d), jnp.float32),
+                pltpu.VMEM((p.block_q, dv), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, q.shape[1], dv), q.dtype),
             jax.ShapeDtypeStruct((bh, q.shape[1], 1), jnp.float32),
         ],
         compiler_params=_compiler_params(p),
@@ -573,10 +599,11 @@ def _dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                     interpret, window=None):
-    """q,k,v,o,do: [bh, s, d]; lse: [bh, sq] -> (dq, dk, dv). Each kernel
-    pads to its own blocks: a padded row has ``lse`` = NEG_INF."""
+    """q,k: [bh, s, d]; v,o,do: [bh, s, dv]; lse: [bh, sq] -> (dq, dk,
+    dv). Each kernel pads to its own blocks: a padded row has ``lse`` =
+    NEG_INF."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
     common = dict(scale=scale, causal=causal, window=window, q_len=sq,
                   kv_len=sk)
@@ -588,7 +615,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                 jnp.pad(lse, ((0, 0), (0, rows)), constant_values=NEG_INF),
                 jnp.pad(delta, ((0, 0), (0, rows))))
 
-    p, name = _planned("dq", q, k, causal, blocks, window)
+    p, name = _planned("dq", q, k, v, causal, blocks, window)
     qp, kp, vp, dop, lsep, deltap = padded(p)
     nq, nk = qp.shape[1] // p.block_q, kp.shape[1] // p.block_k
     qspec, kspec = _walks_k(p, causal, nk, window)
@@ -598,7 +625,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, nk),
-            in_specs=[qspec(d), kspec(d), kspec(d), qspec(d), qspec(1),
+            in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), qspec(1),
                       qspec(1)],
             out_specs=[qspec(d)],
             scratch_shapes=[pltpu.VMEM((p.block_q, d), jnp.float32)]),
@@ -611,7 +638,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
     # dk/dv: grid walks k blocks outer, q blocks inner; before the first
     # live q block (and, with a band, after the last) the q-side index
     # stays on it
-    p, name = _planned("dkv", q, k, causal, blocks, window)
+    p, name = _planned("dkv", q, k, v, causal, blocks, window)
     qp, kp, vp, dop, lsep, deltap = padded(p)
     nq, nk = qp.shape[1] // p.block_q, kp.shape[1] // p.block_k
 
@@ -624,9 +651,15 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                     j, qoff[0], p.block_q, p.block_k, nq, window)))
         return i
 
-    kspec = pl.BlockSpec((1, p.block_k, d), lambda b, j, i, qoff: (b, j, 0))
-    qspec = pl.BlockSpec((1, p.block_q, d),
-                         lambda b, j, i, qoff: (b, q_index(b, j, i, qoff), 0))
+    def kspec(cols):
+        return pl.BlockSpec((1, p.block_k, cols),
+                            lambda b, j, i, qoff: (b, j, 0))
+
+    def qspec(cols):
+        return pl.BlockSpec(
+            (1, p.block_q, cols),
+            lambda b, j, i, qoff: (b, q_index(b, j, i, qoff), 0))
+
     rowspec = pl.BlockSpec((1, 1, p.block_q),
                            lambda b, j, i, qoff: (b, 0, q_index(b, j, i, qoff)))
     dk, dv = pl.pallas_call(
@@ -635,12 +668,13 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nk, nq),
-            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-            out_specs=[kspec, kspec],
+            in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), rowspec,
+                      rowspec],
+            out_specs=[kspec(d), kspec(dv)],
             scratch_shapes=[pltpu.VMEM((p.block_k, d), jnp.float32),
-                            pltpu.VMEM((p.block_k, d), jnp.float32)]),
+                            pltpu.VMEM((p.block_k, dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                   jax.ShapeDtypeStruct(kp.shape, v.dtype)],
+                   jax.ShapeDtypeStruct(vp.shape, v.dtype)],
         compiler_params=_compiler_params(p),
         interpret=interpret,
         name=name,
@@ -728,7 +762,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Differentiable flash attention over [batch, seq, heads, head_dim].
+    """Differentiable flash attention over [batch, seq, heads, head_dim];
+    ``v`` may have a head width of its own, which is the output's.
 
     Drop-in for ``ray_tpu.ops.attention.mha`` (minus segment_ids/bias — the
     XLA path handles those). ``q_offset``: absolute position of q[0] relative
@@ -761,8 +796,8 @@ def flash_vjp_chunk(q, k, v, o, do, lse, *,
     chunk, returns this chunk's additive contribution (dq_partial, dk, dv).
     Summing dq_partial over chunks (and routing dk/dv home around the ring)
     yields exact gradients, because p = exp(s - lse_global) is the true
-    softmax weight. q,k,v,o,do: [b,s,h,d]; lse: [b,h,s]; q_offset may be
-    traced.
+    softmax weight. q,k: [b,s,h,d]; v,o,do: [b,s,h,dv]; lse: [b,h,s];
+    q_offset may be traced.
     """
     b, sq, hq, d = q.shape
     hkv, sk = k.shape[2], k.shape[1]
@@ -777,7 +812,7 @@ def flash_vjp_chunk(q, k, v, o, do, lse, *,
     if hq != hkv:
         rep = hq // hkv
         dk = dk.reshape(b, sk, hkv, rep, d).sum(axis=3)
-        dv = dv.reshape(b, sk, hkv, rep, d).sum(axis=3)
+        dv = dv.reshape(b, sk, hkv, rep, v.shape[-1]).sum(axis=3)
     return dq, dk, dv
 
 

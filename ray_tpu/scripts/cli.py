@@ -1207,6 +1207,14 @@ def cmd_train(args: argparse.Namespace) -> int:
                      f"sub-tile {fp['sub_block'][0]}x{fp['sub_block'][1]}"
                      if fp.get("edge_steps") else "")
                   + (f", window {fp['window']}" if fp.get("window") else ""))
+        kp = summ.get("kda_plan") or {}
+        if kp:
+            print(f"  kda: {kp['chunks']} chunks of {kp['chunk']} in "
+                  f"{kp['segments']} segment(s), sub-block {kp['sub_block']}, "
+                  f"{kp['heads']} heads {kp['d_k']}x{kp['d_v']}, states at "
+                  f"the chunks' starts "
+                  f"{kp['boundary_state_bytes'] / 2**20:.0f} MiB a layer "
+                  f"({kp['impl']})")
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):
             held = routing.get("moe_held", 0)

@@ -9,7 +9,10 @@ One driver owns the whole step path:
 
 - **K-fused launches**: ``steps_per_launch`` batches stack into one
   [K, B, ...] tree and ONE compiled program executes K optimizer steps
-  back-to-back on-device (host dispatch paid once per K).
+  back-to-back on-device (host dispatch paid once per K). K = 1 is a
+  launch like any other: a group of one, the same program shape, stamped
+  by the recorder (a sequence long enough that a step is a second needs
+  no amortizing, and is still watched).
 - **Graceful degrade**: the 1f1b pipeline schedule (no scan support) and
   ragged tails (fewer than K batches left) fall back to the single-step
   program — loss/param-exact either way, machine-asserted in
@@ -22,7 +25,7 @@ One driver owns the whole step path:
   publishes ``rt_train_steps_per_launch`` / ``rt_train_host_overhead_ratio``
   so "is the orchestration touching the gradient path?" is a metric, not
   a bench archaeology project.
-- **Flight recorder**: every fused-K launch stamps its phase walls
+- **Flight recorder**: every fused launch (K >= 1) stamps its phase walls
   {data_wait, h2d, dispatch, device_compute, host_tax, compile} plus
   K/tokens/shape/analytic-FLOPs into :class:`~ray_tpu.util.train_recorder.
   TrainRecorder` (``self.recorder``) — device-done lands via an async
@@ -38,7 +41,6 @@ The K knob comes from ``FastPathConfig.steps_per_launch``
 from __future__ import annotations
 
 import contextlib
-import functools
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -75,8 +77,9 @@ class StepDriver:
     ``batches`` may yield per-step host batches (dict leaves shaped
     [B, ...] — the driver stacks K of them) or pre-stacked [k, B, ...]
     trees from ``iter_jax_batches(stack=K)`` (the iterator advertises via
-    its ``stack`` attribute). Anything with ``k == steps_per_launch`` runs
-    fused; smaller tails run step-by-step through the single-step program.
+    its ``stack`` attribute), K = 1 included: a group of one is [1, B, ...].
+    Anything with ``k == steps_per_launch`` runs fused; smaller tails run
+    step-by-step through the single-step program.
     """
 
     def __init__(self, cfg: Any, optimizer: Any, *,
@@ -90,7 +93,7 @@ class StepDriver:
 
             steps_per_launch = get_fast_path().steps_per_launch
         self.requested_steps_per_launch = steps_per_launch
-        self.fused = steps_per_launch > 1 and ts.supports_multi_step(cfg)
+        self.fused = ts.supports_multi_step(cfg)
         self.steps_per_launch = steps_per_launch if self.fused else 1
         if mesh is not None and plan is None:
             from ray_tpu.parallel.plan import compile_plan
@@ -102,20 +105,29 @@ class StepDriver:
         self._ts = ts
         # the training flight recorder (PR 20): per-launch phase records,
         # launch-gap accounting and the MFU-gap waterfall — only the fused
-        # path stamps it, so single-step drivers carry a dormant recorder
+        # path stamps it, so a driver degraded to the single-step program
+        # (the 1f1b schedule) carries a dormant recorder
         try:
             from ray_tpu.util.train_recorder import TrainRecorder
 
             self.recorder: Optional[Any] = TrainRecorder()
         except Exception:  # noqa: BLE001 — observability must not block
             self.recorder = None
-        # the flash kernels' tilings, noted as a launch traces them: the
-        # recorder's own list, so a program compiled later shows too
+        # the flash kernels' tilings and the chunked delta rule's plan,
+        # noted as a launch traces them: the recorder's own list and dict,
+        # so a program compiled later shows too
+        from ray_tpu.ops import kda
         from ray_tpu.ops.pallas import flash
 
-        self._noting_flash_plans = functools.partial(
-            flash.noting_plans,
-            self.recorder.flash_plans if self.recorder is not None else [])
+        rec = self.recorder
+
+        @contextlib.contextmanager
+        def noting_plans():
+            with flash.noting_plans(rec.flash_plans if rec is not None else []), \
+                    kda.noting_plan(rec.kda_plan if rec is not None else {}):
+                yield
+
+        self._noting_plans = noting_plans
         if self.recorder is not None and plan is not None:
             self.recorder.expert_placement = plan.expert_placement()
         if self.recorder is not None:
@@ -246,8 +258,8 @@ class StepDriver:
         the drainer's job, not the loop's). ``stacked`` overrides the
         pre-stacked autodetection (``batches.stack``) for wrappers that
         lose the attribute."""
-        prestacked = (getattr(batches, "stack", 1) > 1 if stacked is None
-                      else stacked)
+        prestacked = (getattr(batches, "stack", None) is not None
+                      if stacked is None else stacked)
         K = self.steps_per_launch
         adv = getattr(batches, "stack", None)
         if prestacked and self.fused and adv is not None and adv != K:
@@ -273,7 +285,7 @@ class StepDriver:
                 batch = None
             if rec is not None and batch is not None:
                 rec_data_s += time.perf_counter() - t0
-            if batch is not None and not prestacked and K > 1:
+            if batch is not None and not prestacked and self.fused:
                 pend.append(batch)
                 if len(pend) < K:
                     self.host_s += time.perf_counter() - t0
@@ -310,7 +322,7 @@ class StepDriver:
                 unread = (_abstract((params, opt_state, placed))
                           if rec is not None and n_exec == 0 else None)
                 t1 = time.perf_counter()
-                with self._noting_flash_plans():
+                with self._noting_plans():
                     params, opt_state, metrics = self._multi(
                         params, opt_state, placed)
                 dispatch_s = time.perf_counter() - t1
@@ -385,7 +397,7 @@ class StepDriver:
         placed = self._place(batch, stacked=False)
         self.host_s += time.perf_counter() - t0
         t1 = time.perf_counter()
-        with self._noting_flash_plans():
+        with self._noting_plans():
             params, opt_state, metrics = self._single(params, opt_state,
                                                       placed)
         self.step_s += time.perf_counter() - t1

@@ -52,22 +52,36 @@ def _flops_params(cfg) -> int:
     return active() if callable(active) else cfg.num_params()
 
 
-def _attended_keys(cfg, seq: int) -> float:
-    """The keys a query sees, mean over a ``seq``-token causal sequence and
-    summed over the layers: half the square for a full layer, less the
-    triangle below the band for a layer of a patterned config
-    (``models/moe.py``) whose kind is ``window``."""
+def _attention_madds(cfg, seq: int) -> float:
+    """Multiply-adds of the mixers' own products (not their projections)
+    for one token of a ``seq``-token causal sequence, forward, summed over
+    the layers, each by its kind (``models/moe.py``'s patterned configs name
+    them; every other config's layers are ``full``): the keys a query sees
+    (half the square; less the triangle below the band for ``window``)
+    times the widths of the score and value products, which latent
+    attention (``mla``) has apart; for ``kda`` the chunked form's, five
+    products of chunk x width and three of width x width a head."""
     kinds = getattr(cfg, "layer_kinds", ()) or ("full",) * cfg.n_layers
     w = min(getattr(cfg, "sliding_window", None) or seq, seq)
-    return sum(w - w * w / (2.0 * seq) if kind == "window" else seq / 2.0
-               for kind in kinds)
+
+    def layer(kind: str) -> float:
+        if kind == "kda":
+            from ray_tpu.ops.kda import CHUNK
+
+            return cfg.kda_heads * cfg.kda_head_dim * (
+                5 * CHUNK + 3 * cfg.kda_head_dim)
+        if kind == "mla":
+            return cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                                  + cfg.v_head_dim) * seq / 2.0
+        keys = w - w * w / (2.0 * seq) if kind == "window" else seq / 2.0
+        return 2 * keys * cfg.n_heads * cfg.head_dim
+
+    return sum(map(layer, kinds))
 
 
 def train_flops_per_token(cfg, seq: int) -> float:
-    """Fwd+bwd FLOPs per trained token: 6N + causal attention term."""
-    n = _flops_params(cfg)
-    attn = 12 * _attended_keys(cfg, seq) * cfg.n_heads * cfg.head_dim
-    return 6.0 * n + attn
+    """Fwd+bwd FLOPs per trained token: 6N + the mixers' own products."""
+    return 6.0 * _flops_params(cfg) + 6 * _attention_madds(cfg, seq)
 
 
 def train_step_flops(cfg, batch: int, seq: int) -> float:

@@ -135,6 +135,11 @@ class TrainRecorder(RecorderCore):
         # this list, static per compiled shape like the engine recorder's
         # ``decode_programs``; an ``xla`` step leaves it empty
         self.flash_plans: List[Dict[str, Any]] = []
+        # what the step's chunked delta rule does at its shape
+        # (``ops/kda.noting_plan``: chunk, sub_block, chunks, segments,
+        # heads, d_k, d_v, boundary_state_bytes, impl), static like the list
+        # above; a step without a ``kda`` layer leaves it empty
+        self.kda_plan: Dict[str, Any] = {}
         # what the driver's plan and compiled step say of themselves, static
         # like the list above: how the plan placed a sparse model's expert
         # matrices (``moe.expert_placement``: "expert" or "model_dim"; None
@@ -430,6 +435,7 @@ class TrainRecorder(RecorderCore):
         out: Dict[str, Any] = {
             "window_launches": len(recs),
             "flash_plans": [dict(p) for p in self.flash_plans],
+            "kda_plan": dict(self.kda_plan),
             "expert_placement": self.expert_placement,
             "collectives": {k: dict(v) for k, v in
                             (self.collectives or {}).items()},

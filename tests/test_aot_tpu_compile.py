@@ -780,6 +780,47 @@ def test_the_backward_runs_no_second_forward(step, request, capsys):
               f"{peak / 2**20:.1f})")
 
 
+# Kimi-Linear-48B-A3B-Instruct at its published widths as its cell trains it
+# (benchmark/configs/kimi-linear-48b-a3b-instruct.json: 8 of 256 experts and
+# an eighth of the vocabulary held here), the cell's whole depth: the leading
+# dense KDA layer and the period KDA, KDA, KDA, MLA
+CFG_KIMI = moe.MoEConfig(
+    vocab_size=20480, d_model=2304, n_layers=5, n_heads=32, n_kv_heads=32,
+    attn_head_dim=72, d_ff=1024, d_ff_dense=9216, max_seq_len=16384,
+    tie_embeddings=False, param_dtype=jnp.bfloat16, attn_impl="flash",
+    loss_chunk=256, layer_kinds=("kda",) * 4 + ("mla",), n_dense_layers=1,
+    n_experts=256, n_experts_held=8, top_k=8, n_shared_experts=1,
+    router_score="sigmoid", router_bias=True, route_scale=2.446,
+    balance="sequence", router_aux_coef=0.0, kda_heads=32, kda_head_dim=128,
+    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+    v_head_dim=128)
+
+
+def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys):
+    """b1 x s16384, K=1, all five layers on one described chip: Mosaic takes
+    the flash kernels at 192 / 128 (192 is no multiple of the lanes), the
+    step fits the chip's 15.75 GiB with room (what the chunked delta rule
+    keeps for its backward is a state a segment, and a segment's temporaries
+    are live at once, not the sequence's), and the backward runs no second
+    flash forward."""
+    mesh = make_mesh(MeshConfig(), topo.devices[:1])
+    compiled = _compile_fused_step(moe, CFG_KIMI, mesh, 1, 1, 16384)[2]
+    text = compiled.as_text()
+    calls = [re.search(r"flash_(fwd|dq|dkv)_bh32_q16384_k16384_d192v128_c1_w0",
+                       line) for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert all(calls) and sorted(m.group(1) for m in calls) \
+        == sorted(flash.KINDS), calls
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nkimi-linear b1 x s16384: temporaries "
+              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
+              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, peak "
+              f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
+    assert mem.peak_memory_in_bytes < 13.0 * 2**30   # 12.36 (PR 48)
+    assert mem.argument_size_in_bytes > 3.3 * 2**30   # 602M x 6 bytes
+
+
 def test_libtpu_accepts_the_perf_flags():
     """libtpu aborts the process on a flag it does not know, and every
     worker passes ``TPU_PERF_FLAGS``. Its flags are parsed when the library

@@ -85,6 +85,61 @@ def test_fused_driver_parity_ragged_tail_and_single_launch():
     assert 0.0 <= rep["host_overhead_ratio"] <= 1.0
 
 
+def test_a_launch_of_one_step_is_a_launch():
+    """K=1 is no special case (PR 48): a group of one, [1, B, S+1], runs
+    the fused program and the recorder stamps it; per-step batches are
+    stacked by the driver like any K; both give the single-step program's
+    losses and parameters."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    from ray_tpu.parallel import train_step as ts
+    from ray_tpu.train.driver import StepDriver
+
+    N = 3
+    cfg = llama.PRESETS["debug"]
+    opt = ts.default_optimizer(total_steps=100)
+    toks = np.asarray(jax.random.randint(
+        jax.random.key(5), (N, 2, 33), 0, cfg.vocab_size, dtype=jnp.int32))
+
+    def fresh():
+        params = llama.init_params(jax.random.key(0), cfg)
+        return params, jax.jit(opt.init)(params)
+
+    p1, s1 = fresh()
+    step = ts.make_train_step(cfg, opt)
+    want = []
+    for i in range(N):
+        p1, s1, m = step(p1, s1, {"tokens": toks[i]})
+        want.append(float(m["loss"]))
+
+    for stacked in (True, False):
+        p2, s2 = fresh()
+        driver = StepDriver(cfg, opt, steps_per_launch=1)
+        assert driver.fused and driver.steps_per_launch == 1
+        feed = ({"tokens": toks[i][None] if stacked else toks[i]}
+                for i in range(N))
+        seen = []
+        p2, s2, _ = driver.run(p2, s2, feed, stacked=stacked,
+                               on_launch=lambda m: seen.append(m["loss"]))
+        assert driver.launches == N and driver.steps == N
+        assert all(np.shape(x) == (1,) for x in seen)  # a [k] leaf, k = 1
+        np.testing.assert_allclose(np.concatenate(seen), want, rtol=2e-4)
+        for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)
+        rec = driver.recorder
+        deadline = time.time() + 10.0
+        while time.time() < deadline and rec.summary().get("in_flight"):
+            time.sleep(0.01)
+        recs = rec.launches()
+        assert len(recs) == N and all(r["k"] == 1 and "t_done" in r
+                                      for r in recs)
+        assert driver.compile_count() == 1  # one program, every launch
+        rec.close()
+
+
 def test_driver_refuses_oversized_stacked_groups():
     """A feed stacking MORE batches per group than the driver fuses would
     silently single-step everything — the driver refuses instead."""
@@ -424,6 +479,13 @@ def test_iter_jax_batches_stack_prefetch_compute_limited(rt_cluster):
 
     toks = np.arange(33 * 4 * 33, dtype=np.int32).reshape(33 * 4, 33)
     ds = rt_data.from_numpy(toks)
+    # a group of one is a group; without ``stack``, a step at a time
+    one = ds.iter_jax_batches(batch_size=4, stack=1)
+    assert one.stack == 1 and tuple(next(one)["data"].shape) == (1, 4, 33)
+    plain = ds.iter_jax_batches(batch_size=4)
+    assert plain.stack is None and tuple(next(plain)["data"].shape) == (4, 33)
+    with pytest.raises(ValueError, match="stack"):
+        ds.iter_jax_batches(batch_size=4, stack=0)
     it = ds.iter_jax_batches(batch_size=4, stack=4)
     assert it.stack == 4
     shapes = []

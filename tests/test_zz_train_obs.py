@@ -262,7 +262,7 @@ def test_window_summary_carves_launches():
         # what it moves across chips (static per compiled shape; this
         # synthetic recorder traced no flash kernel and compiled no step)
         assert rec.window_summary(0.0, 999.0) == {
-            "window_launches": 0, "flash_plans": [],
+            "window_launches": 0, "flash_plans": [], "kda_plan": {},
             "expert_placement": None, "collectives": {}, "routing": {}}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
@@ -443,6 +443,9 @@ def test_api_train_and_cli_json(rt_cluster):
             "block_q": 1024, "block_k": 1024, "live_steps": 30,
             "edge_steps": 12, "sub_block": (512, 512),
             "grid_steps": 64, "window": 4096})
+        rec.kda_plan.update(
+            chunk=64, sub_block=16, chunks=256, segments=4, heads=32,
+            d_k=128, d_v=128, boundary_state_bytes=536_870_912, impl="xla")
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
@@ -488,6 +491,9 @@ def test_api_train_and_cli_json(rt_cluster):
                 "expert's queue 40 rows") in text
         assert ("30 of 64 grid steps live, 12 of them crossed by an edge, "
                 "sub-tile 512x512, window 4096") in text
+        assert ("kda: 256 chunks of 64 in 4 segment(s), sub-block 16, 32 "
+                "heads 128x128, states at the chunks' starts 512 MiB a "
+                "layer (xla)") in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
