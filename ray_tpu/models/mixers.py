@@ -12,7 +12,10 @@ A ``kda`` layer, ``h`` the normed input, H heads of width ``dk = dv``:
   times ``dk ** -0.5``;
 - log-decay a channel ``g = -exp(A_log)[head] * softplus((h @ f_down) @
   f_up + dt_bias)`` and step ``beta = sigmoid(h @ wb)``, float32;
-- the recurrence (``ops/kda.py``, chunked);
+- the recurrence (``ops/kda.py``, chunked; a chunk's two decayed products
+  the Pallas kernel pair of ``ops/pallas/kda_grams.py`` unless ``attn_impl``
+  is ``"xla"``, the head is no whole lanes or a mesh of several chips is
+  ambient);
 - ``o = rms(o, o_norm) * sigmoid((h @ g_down) @ g_up + g_bias)`` over each
   head's ``dv``, then ``wo``.
 
@@ -132,7 +135,13 @@ def kda_half(cfg, x: jax.Array, layer: Params) -> jax.Array:
         beta = jax.nn.sigmoid((hx @ layer["wb"].astype(cdt)).astype(F32))
 
     with jax.named_scope("kda_scan"):
-        o = kda.kda_chunked(q, k, v, g, beta)
+        from ray_tpu.parallel.context import current_mesh
+
+        # the compiler does not partition a Mosaic call: under a mesh of
+        # several chips the recurrence stays the XLA form GSPMD splits
+        mesh = current_mesh()
+        o = kda.kda_chunked(q, k, v, g, beta, impl=(
+            "xla" if mesh is not None and mesh.size > 1 else cfg.attn_impl))
 
     gate = jax.nn.sigmoid(
         (hx @ layer["g_down"].astype(cdt)) @ layer["g_up"].astype(cdt)

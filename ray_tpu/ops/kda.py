@@ -69,12 +69,23 @@ to C(63, 31) ~ 1e18 over 64 rows where keys repeat and nothing decays, and by
 at most C(15, 7) = 6435 over 16, which float32 carries to 4e-4 in that worst
 case and the cast of ``T`` to the products' dtype (2e-3) covers.
 
-Plain XLA: ``impl`` in the plan a step notes says so (``noting_plan``). Its
-operations carry scopes of their own, names only, for whoever reads a device
-trace by hand (they lie under the model's ``kda_scan``): ``kda_grams`` (the
-two decayed products), ``kda_inverse``, ``kda_reweigh`` (``W``, ``U`` and
-the reweighted ``Q`` and ``K``), ``kda_walk`` (``N``, ``B`` and the walk),
-``kda_out``.
+One kernel, the rest plain XLA: ``impl`` in the plan a step notes says which
+(``noting_plan``). The two decayed products of a chunk, ``K K^T`` and ``Q
+K^T``, are one Pallas call forward and one backward
+(``ops/pallas/kda_grams.py``, ``impl`` ``"pallas_grams"``: a pair's decay
+inside a diagonal block formed once for both and once for their four
+backward sums) where the shape takes it: the chunk ``CHUNK`` in sub-blocks of
+``SUB_BLOCK`` and ``d_k`` whole lanes of 128. Everywhere else (a chunk
+shrunk to a short sequence, a narrow head, ``impl="xla"`` asked for) they
+are ``_decayed_gram`` twice, ``impl`` ``"xla"``. The kernel's module is
+imported where a step is traced with it and nowhere else. The cumulative
+sum, the inverse, ``W``, ``U``, the walk and the outputs are XLA in both.
+The operations carry scopes of their own, names only, for whoever reads a
+device trace by hand (they lie under the model's ``kda_scan``):
+``kda_grams`` (the two decayed products: the kernel's calls,
+``kda_grams_fwd_..`` and ``kda_grams_bwd_..``, or XLA's fusions),
+``kda_inverse``, ``kda_reweigh`` (``W``, ``U`` and the reweighted ``Q`` and
+``K``), ``kda_walk`` (``N``, ``B`` and the walk), ``kda_out``.
 """
 
 from __future__ import annotations
@@ -112,11 +123,15 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # ---------------------------------------------------------------- the plan
 
 def plan(seq: int, heads: int, d_k: int, d_v: int, batch: int = 1,
-         chunk: int = CHUNK, sub_block: int = SUB_BLOCK) -> Dict[str, Any]:
+         chunk: int = CHUNK, sub_block: int = SUB_BLOCK,
+         impl: str = "pallas") -> Dict[str, Any]:
     """What ``kda_chunked`` does at one shape; pure. ``chunk`` is shrunk to
     a short sequence (rounded up to whole sub-blocks); a sub-block that
     does not divide the chunk is the chunk. ``boundary_state_bytes``: the
-    float32 states the forward keeps for the backward, one a segment."""
+    float32 states the forward keeps for the backward, one a segment.
+    ``impl``: ``"pallas_grams"`` where the two decayed products are the
+    kernel's (module docstring: the shape takes it and ``impl`` did not ask
+    for ``"xla"``), else ``"xla"``."""
     sub = min(sub_block, chunk)
     c = min(chunk, -(-max(seq, 1) // sub) * sub)
     if c % sub:
@@ -127,7 +142,8 @@ def plan(seq: int, heads: int, d_k: int, d_v: int, batch: int = 1,
             "segments": segments, "heads": heads,
             "d_k": d_k, "d_v": d_v,
             "boundary_state_bytes": segments * batch * heads * d_k * d_v * 4,
-            "impl": "xla"}
+            "impl": ("pallas_grams" if impl != "xla" and c == CHUNK
+                     and sub == SUB_BLOCK and d_k % 128 == 0 else "xla")}
 
 
 _noting = threading.local()
@@ -276,18 +292,26 @@ def _unit_lower_inverse_bwd(sub, X, dX):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _insides(q, k, v, g, beta, sub: int):
+def _insides(q, k, v, g, beta, sub: int, impl: str):
     """What a chunk's step reads that does not depend on the state. q, k
     [b, h, n, C, dk], v [.., dv], g [.., dk] float32, beta [b, h, n, C]
     float32 -> (W [.., C, dk], U [.., C, dv] float32, Aqk [.., C, C], Qg,
     Kend [.., C, dk], gend [.., dk] float32); the operands of the step's
-    products in the inputs' dtype."""
+    products in the inputs' dtype. ``impl``: the plan's."""
     cdt = q.dtype
     G = jnp.cumsum(g, axis=-2)
     qf, kf = q.astype(F32), k.astype(F32)
     with jax.named_scope("kda_grams"):
-        A = beta[..., None] * _decayed_gram(kf, kf, G, sub, True, cdt)
-        Aqk = _decayed_gram(qf, kf, G, sub, False, cdt).astype(cdt)
+        if impl == "pallas_grams":
+            # here and not at the module's top: a process that traces no
+            # step with the kernel never loads it
+            from ray_tpu.ops.pallas import kda_grams
+
+            Akk, Aqk = kda_grams.decayed_grams(q, k, G, sub)
+        else:
+            Akk = _decayed_gram(kf, kf, G, sub, True, cdt)
+            Aqk = _decayed_gram(qf, kf, G, sub, False, cdt)
+        A, Aqk = beta[..., None] * Akk, Aqk.astype(cdt)
     with jax.named_scope("kda_inverse"):
         T = (_unit_lower_inverse(A, sub) * beta[..., None, :]).astype(cdt)
     with jax.named_scope("kda_reweigh"):
@@ -355,11 +379,11 @@ def _walk_states_bwd(res, cts):
 _walk_states.defvjp(_walk_states_fwd, _walk_states_bwd)
 
 
-def _segment(S, q, k, v, g, beta, sub: int):
+def _segment(S, q, k, v, g, beta, sub: int, impl: str):
     """A segment's chunks from the state ``S`` it starts with: (outputs
     [b, h, n, C, dv] in ``v``'s type, the state it ends with)."""
     cdt = q.dtype
-    W, U, Aqk, Qg, Kend, gend = _insides(q, k, v, g, beta, sub)
+    W, U, Aqk, Qg, Kend, gend = _insides(q, k, v, g, beta, sub, impl)
     with jax.named_scope("kda_walk"):
         N = jnp.einsum("...cd,...ce->...de", Kend, W,
                        preferred_element_type=F32).astype(cdt)
@@ -395,22 +419,22 @@ def _from_segments(a):
     return a.reshape(*a.shape[:2], -1, *a.shape[4:])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _chunks(q, k, v, g, beta, sub, segments):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _chunks(q, k, v, g, beta, sub, segments, impl):
     """The whole recurrence from a zero state on inputs already cut into
     chunks ([b, h, n, C, ...], ``_insides``' arguments): the outputs
     [b, h, n, C, dv] in ``v``'s type. ``segments`` divides ``n``: a segment
     is worked forward, and (backward) rebuilt from the state it started with
     and its cotangents pulled back, before the next segment is touched, so
     that what is live at once is a segment's and not the sequence's."""
-    return _chunks_fwd(q, k, v, g, beta, sub, segments)[0]
+    return _chunks_fwd(q, k, v, g, beta, sub, segments, impl)[0]
 
 
-def _chunks_fwd(q, k, v, g, beta, sub, segments):
+def _chunks_fwd(q, k, v, g, beta, sub, segments, impl):
     b, h = q.shape[:2]
 
     def segment(S, xs):
-        O, S_out = _segment(S, *xs, sub)
+        O, S_out = _segment(S, *xs, sub, impl)
         return S_out, (O, S)
 
     _, (O, starts) = jax.lax.scan(
@@ -421,12 +445,12 @@ def _chunks_fwd(q, k, v, g, beta, sub, segments):
     return O, (q, k, v, g, beta, starts)
 
 
-def _chunks_bwd(sub, segments, res, dO):
+def _chunks_bwd(sub, segments, impl, res, dO):
     *inputs, starts = res
 
     def segment(dS, xs):
         *mine, S, dO_s = xs
-        _, pull = jax.vjp(lambda S, *a: _segment(S, *a, sub), S, *mine)
+        _, pull = jax.vjp(lambda S, *a: _segment(S, *a, sub, impl), S, *mine)
         dS, *grads = pull((dO_s, dS))
         return dS, tuple(grads)
 
@@ -444,7 +468,7 @@ _chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, *, chunk: int = CHUNK,
-                sub_block: int = SUB_BLOCK) -> jax.Array:
+                sub_block: int = SUB_BLOCK, impl: str = "pallas") -> jax.Array:
     """The recurrence over a sequence from a zero state, in chunks.
 
     ``q``, ``k`` [b, s, h, dk] (as the model hands them on: normalised, the
@@ -454,10 +478,11 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
     Any ``s >= 1``: the sequence is padded at its END to whole chunks with
     ``g = 0`` and ``beta = 0``, under which a token neither decays the state
-    nor adds to it."""
+    nor adds to it. ``impl="xla"`` keeps the kernel out (``plan``); any
+    other asks for it where the shape takes it."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
-    p = plan(s, h, dk, dv, b, chunk, sub_block)
+    p = plan(s, h, dk, dv, b, chunk, sub_block, impl)
     into = getattr(_noting, "into", None)
     if into is not None:
         into.update(p)
@@ -471,7 +496,8 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         return a.reshape(b, h, n, C, *a.shape[3:])
 
     o = _chunks(chunks(q), chunks(k), chunks(v), chunks(g.astype(F32)),
-                chunks(beta.astype(F32)), p["sub_block"], p["segments"])
+                chunks(beta.astype(F32)), p["sub_block"], p["segments"],
+                p["impl"])
     return jnp.moveaxis(o.reshape(b, h, n * C, dv), 1, 2)[:, :s]
 
 

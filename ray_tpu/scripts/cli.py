@@ -1213,8 +1213,10 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"{kp['segments']} segment(s), sub-block {kp['sub_block']}, "
                   f"{kp['heads']} heads {kp['d_k']}x{kp['d_v']}, states at "
                   f"the chunks' starts "
-                  f"{kp['boundary_state_bytes'] / 2**20:.0f} MiB a layer "
-                  f"({kp['impl']})")
+                  f"{kp['boundary_state_bytes'] / 2**20:.0f} MiB a layer, "
+                  f"decayed products: "
+                  + ("a Pallas kernel pair" if kp["impl"] == "pallas_grams"
+                     else "XLA") + f" ({kp['impl']})")
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):
             held = routing.get("moe_held", 0)

@@ -131,6 +131,29 @@ def test_banded_flash_compiles_for_v5e_at_trinitys_shape(topo, window):
             1024, 1024, 30 if window else 36), p
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_the_kda_gram_kernels_compile_for_v5e_at_the_cells_shape(topo, dtype):
+    """A segment of the Kimi Linear cell (32 heads x 8 chunks of 64 x 128):
+    Mosaic takes the kernel pair of ``ops/pallas/kda_grams.py`` (the sums
+    along the lanes, a cotangent's column spread over them, the product with
+    its left operand transposed), each call under the name a trace shows."""
+    from ray_tpu.ops.pallas import kda_grams
+
+    one = SingleDeviceSharding(topo.devices[0])
+    qk = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), dtype, sharding=one)
+    G = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), jnp.float32, sharding=one)
+
+    def loss(q, k, G):
+        return sum((a * a).sum() for a in kda_grams.decayed_grams(q, k, G, 16))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, G).compile()
+    calls = [re.search(r"kda_grams_(fwd|bwd)_bh32_n8_c64_k128", line).group(1)
+             for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert sorted(calls) == ["bwd", "fwd"], calls
+
+
 def test_flash_plan_at_the_train_cells_shape():
     """What ``plan`` chooses where the benchmark trains (bh 32, s 4096,
     d 128, bf16, causal): tiles of at least 512 a side, at most 0.65 of the
@@ -806,11 +829,19 @@ def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys)
     mesh = make_mesh(MeshConfig(), topo.devices[:1])
     compiled = _compile_fused_step(moe, CFG_KIMI, mesh, 1, 1, 16384)[2]
     text = compiled.as_text()
+    customs = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
     calls = [re.search(r"flash_(fwd|dq|dkv)_bh32_q16384_k16384_d192v128_c1_w0",
-                       line) for line in text.splitlines()
-             if "tpu_custom_call" in line and " custom-call(" in line]
+                       line) for line in customs if "kda_grams" not in line]
     assert all(calls) and sorted(m.group(1) for m in calls) \
         == sorted(flash.KINDS), calls
+    # the four KDA layers' decayed products are the kernel's, a segment of 8
+    # chunks a call: forward, and backward once more forward (the segment
+    # rebuilt from the state it started with) and the one backward call
+    grams = [re.search(r"kda_grams_(fwd|bwd)_bh32_n8_c64_k128", line)
+             for line in customs if "kda_grams" in line]
+    assert all(grams) and sorted(m.group(1) for m in grams) \
+        == ["bwd"] * 4 + ["fwd"] * 8, grams
     mem = compiled.memory_analysis()
     with capsys.disabled():
         print(f"\nkimi-linear b1 x s16384: temporaries "

@@ -6,10 +6,15 @@ with ``kda`` and ``mla`` layers, a leading dense layer, 4 of 16 experts held)
 against the benchmark's plain reference
 (``benchmark/families/moonshot_kimi_linear.py``, which imports nothing of
 ``ray_tpu``) on seeded weights; the chip's share against the uncut layer;
-the step under a mesh; the plan a step notes; and who refuses the two kinds."""
+the step under a mesh; the plan a step notes; and who refuses the two kinds.
+The Pallas kernel for a chunk's two decayed products
+(``ops/pallas/kda_grams.py``, interpreted) against the form it replaces at
+128-wide heads, and when a step takes it."""
 
 import dataclasses
+import functools
 import os
+import subprocess
 import sys
 import time
 
@@ -78,6 +83,23 @@ def _kda_inputs(seed, b, s, h, dk, dv, decay):
     return (q, k, v, g, beta), jax.random.normal(ks[5], (b, s, h, dv))
 
 
+@functools.lru_cache(maxsize=None)
+def _both_forms(chunk, sub):
+    """The two forms and their gradients, jitted once a (chunk, sub-block):
+    the three decays of a shape share a compile (the interpreted kernel's
+    is most of a case's time)."""
+    def chunked(*a):
+        return kda.kda_chunked(*a, chunk=chunk, sub_block=sub)
+
+    def recurrent(*a):
+        return kda.kda_recurrent(*a)[0]
+
+    def grad(fn):
+        return jax.jit(jax.grad(lambda w, *a: (fn(*a) * w).sum(), range(1, 6)))
+
+    return jax.jit(chunked), grad(chunked), jax.jit(recurrent), grad(recurrent)
+
+
 # decays near 1 (alpha = exp(-1e-3)), middling, and near 0 (alpha down to
 # exp(-30 e^2..): G of minus thousands inside a chunk, the exp(-G) case);
 # the limits follow float32's own loss in a cumulative sum of that size
@@ -86,18 +108,19 @@ def _kda_inputs(seed, b, s, h, dk, dv, decay):
     (16, 4, 37, 16, 24),      # a sequence of no whole number of chunks
     (32, 16, 64, 32, 16),
     (64, 16, 100, 16, 16),    # the shipped chunk and sub-block
-    (16, 8, 16 * 32, 8, 8)])  # four segments of 8 chunks
+    (16, 8, 16 * 32, 8, 8),   # four segments of 8 chunks
+    (64, 16, 100, 128, 16)])  # a shape the kernel takes (interpreted here)
 def test_the_chunked_form_is_the_recurrence(decay, tol, chunk, sub, seq, dk, dv):
     args, w = _kda_inputs(1, 2, seq, 3, dk, dv, decay)
-    assert kda.plan(seq, 3, dk, dv, 2, chunk, sub)["segments"] == 1 + 3 * (seq > 500)
+    p = kda.plan(seq, 3, dk, dv, 2, chunk, sub)
+    assert p["segments"] == 1 + 3 * (seq > 500)
+    assert p["impl"] == ("pallas_grams" if dk == 128 else "xla")
+    chunked, chunked_grad, recurrent, recurrent_grad = _both_forms(chunk, sub)
     with jax.default_matmul_precision("highest"):
-        got = kda.kda_chunked(*args, chunk=chunk, sub_block=sub)
-        want, _ = kda.kda_recurrent(*args)
-        grads = jax.grad(lambda *a: (kda.kda_chunked(
-            *a, chunk=chunk, sub_block=sub) * w).sum(), range(5))(*args)
-        wants = jax.grad(lambda *a: (kda.kda_recurrent(*a)[0] * w).sum(),
-                         range(5))(*args)
-    assert float(jnp.abs(want).max()) > 0.1
+        got, want = chunked(*args), recurrent(*args)
+        grads, wants = chunked_grad(w, *args), recurrent_grad(w, *args)
+    # (the query is scaled by dk ** -0.5: a wide head's outputs are smaller)
+    assert float(jnp.abs(want).max()) > (0.1 if dk < 128 else 0.04)
     assert float(jnp.abs(got - want).max()) < tol
     for name, a, b in zip("q k v g beta".split(), grads, wants):
         assert bool(jnp.isfinite(a).all()), name
@@ -140,12 +163,179 @@ def test_the_inverse_where_keys_repeat_and_nothing_decays():
     assert float(jnp.abs(mine - want).max()) < 1e-3 * float(jnp.abs(want).max())
 
 
+# ---- (a') the kernel for a chunk's two decayed products -----------------------------
+
+def _gram_inputs(seed, decay, dtype=jnp.float32, shape=(1, 2, 3, 64, 128)):
+    """q, k and the cumulative log-decay of ``shape`` [b, h, n, C, dk], as
+    ``_kda_inputs`` draws them."""
+    b, h, n, C, dk = shape
+    (q, k, _, g, _), _ = _kda_inputs(seed, b, n * C, h, dk, 8, decay)
+    cut = lambda a: jnp.moveaxis(a, 2, 1).reshape(shape)
+    return cut(q).astype(dtype), cut(k).astype(dtype), jnp.cumsum(cut(g), -2)
+
+
+def _grams_xla(q, k, G):
+    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+    return (kda._decayed_gram(kf, kf, G, 16, True, q.dtype),
+            kda._decayed_gram(qf, kf, G, 16, False, q.dtype))
+
+
+def _grams_kernel(q, k, G):
+    from ray_tpu.ops.pallas import kda_grams
+
+    return kda_grams.decayed_grams(q, k, G, 16)
+
+
+def _weighed(fn):
+    """(both matrices, the gradients of their sums weighed by ``ws``), each
+    jitted once: the tests below share a shape, so a compile."""
+    def loss(ws, *a):
+        return sum((o * w).sum() for o, w in zip(fn(*a), ws))
+
+    return jax.jit(fn), jax.jit(jax.grad(loss, (1, 2, 3)))
+
+
+_KERNEL, _XLA = _weighed(_grams_kernel), _weighed(_grams_xla)
+_GRAM_SHAPE = (1, 2, 3, 64, 128)
+
+
+@pytest.mark.parametrize("decay,tol", [(1e-3, 2e-6), (0.3, 2e-6), (30.0, 2e-6)])
+def test_the_kernel_is_the_form_it_replaces(decay, tol):
+    """Both matrices and every cotangent (``q``, ``k``, ``G``) against
+    ``_decayed_gram`` twice and ``jax.grad`` through it, float32: what
+    differs is the order of the sums."""
+    q, k, G = _gram_inputs(3, decay, shape=_GRAM_SHAPE)
+    ws = [jax.random.normal(jax.random.key(i), (*_GRAM_SHAPE[:4], 64))
+          for i in (1, 2)]
+    with jax.default_matmul_precision("highest"):
+        got, want = _KERNEL[0](q, k, G), _XLA[0](q, k, G)
+        grads, wants = _KERNEL[1](ws, q, k, G), _XLA[1](ws, q, k, G)
+    for name, a, b in zip(("A_kk", "A_qk"), got, want):
+        assert float(jnp.abs(b).max()) > 0.01, name
+        assert float(jnp.abs(a - b).max()) < tol, name
+        # what lies on or above the diagonal (above, for A_qk) is a zero
+        assert not bool(jnp.triu(a, 0 if name == "A_kk" else 1).any()), name
+    for name, a, b in zip("q k G".split(), grads, wants):
+        assert float(jnp.abs(b).max()) > 0.01, name
+        assert float(jnp.abs(a - b).max()) < tol * max(
+            float(jnp.abs(b).max()), 1.0), name
+
+
+def test_the_kernel_takes_its_operands_in_the_inputs_dtype():
+    """bf16 tiles: the products between sub-blocks take bf16 operands as the
+    XLA form's do, so the two agree to the sums' order, and the cotangents
+    come back in the inputs' dtypes (their values at the cell's shape are
+    the chip check's, ``tests/benchmark/kimi_chip_check.py``)."""
+    q, k, G = _gram_inputs(4, 0.3, jnp.bfloat16, (1, 1, 2, 64, 128))
+    for a, b in zip(_grams_kernel(q, k, G), _grams_xla(q, k, G)):
+        assert a.dtype == jnp.float32
+        assert float(jnp.abs(a - b).max()) < 1e-6
+    grads = jax.eval_shape(jax.grad(
+        lambda *a: sum(o.sum() for o in _grams_kernel(*a)), (0, 1, 2)), q, k, G)
+    assert [a.dtype for a in grads] == [jnp.bfloat16, jnp.bfloat16, jnp.float32]
+
+
+def test_a_fast_channel_through_the_kernel_is_a_true_zero():
+    """Every channel forgets by e^-200 a token, ``G`` down to -12,800 inside
+    a chunk: every pair but a token's own underflows to a true zero, its own
+    is ``q . k``, and no cotangent is anything but finite."""
+    q, k, _ = _gram_inputs(5, 1.0, shape=_GRAM_SHAPE)
+    G = jnp.cumsum(jnp.full(q.shape, -200.0), axis=-2)
+    akk, aqk = _KERNEL[0](q, k, G)
+    assert not bool(akk.any())
+    own = jnp.einsum("...cd,...cd->...c", q, k)
+    assert float(jnp.abs(aqk - own[..., None] * jnp.eye(64)).max()) < 1e-7
+    assert not bool((aqk * (1 - jnp.eye(64))).any())
+    grads = _KERNEL[1]([jnp.ones(akk.shape)] * 2, q, k, G)
+    assert all(bool(jnp.isfinite(a).all()) for a in grads)
+    assert float(jnp.abs(grads[0] - k).max()) < 1e-7    # d(q . k) / dq
+
+
+@pytest.mark.parametrize("args,kwargs,impl", [
+    ((16384, 32, 128, 128), {}, "pallas_grams"),        # the cell's shape
+    ((100, 3, 256, 16), {"batch": 2}, "pallas_grams"),  # two lane tiles a head
+    ((37, 3, 128, 128), {}, "xla"),                     # a shrunk chunk (48)
+    ((16384, 32, 64, 64), {}, "xla"),                   # half a lane tile
+    ((16384, 32, 192, 128), {}, "xla"),
+    ((16384, 32, 128, 128), {"chunk": 32}, "xla"),
+    ((16384, 32, 128, 128), {"sub_block": 8}, "xla"),
+    ((16384, 32, 128, 128), {"impl": "xla"}, "xla"),    # asked for
+    ((16384, 32, 128, 128), {"impl": "flash"}, "pallas_grams")])
+def test_when_the_plan_takes_the_kernel(args, kwargs, impl):
+    assert kda.plan(*args, **kwargs)["impl"] == impl
+    # and what a call is traced with is the plan's
+    noted = {}
+    b, (s, h, dk, dv) = kwargs.get("batch", 1), args
+    shapes = [jax.ShapeDtypeStruct((b, s, h, w), jnp.bfloat16)
+              for w in (dk, dk, dv)] + [
+        jax.ShapeDtypeStruct((b, s, h, dk), jnp.float32),
+        jax.ShapeDtypeStruct((b, s, h), jnp.float32)]
+    kwargs = {k: v for k, v in kwargs.items() if k != "batch"}
+    with kda.noting_plan(noted):
+        jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, **kwargs))(*shapes)
+    assert noted["impl"] == impl
+    assert ("kda_grams_fwd" in str(jaxpr)) == (impl == "pallas_grams")
+
+
+def test_a_mesh_of_several_chips_keeps_the_xla_form(family):
+    """Mosaic's calls are not partitioned: where the ambient mesh has more
+    than one device a ``kda`` layer is the XLA form GSPMD splits, at any
+    width; alone on its chip it takes the kernel."""
+    from ray_tpu.parallel.context import mesh_scope
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    cfg = dataclasses.replace(_cfg(family), kda_heads=1, kda_head_dim=128)
+    layer = jax.eval_shape(lambda r: jax.tree.map(
+        lambda a: a[0], mixers.init_kda(r, cfg, 1)), jax.random.key(0))
+    layer["attn_norm"] = jax.ShapeDtypeStruct((cfg.d_model,), jnp.float32)
+    x = jax.ShapeDtypeStruct((1, 64, cfg.d_model), jnp.float32)
+    for mesh, impl in ((None, "pallas_grams"),
+                       (make_mesh(MeshConfig(dp=2), jax.devices()[:2]), "xla")):
+        noted = {}
+        with mesh_scope(mesh), kda.noting_plan(noted):
+            jax.eval_shape(lambda x, l: mixers.kda_half(cfg, x, l), x, layer)
+        assert noted["impl"] == impl, mesh
+
+
+def test_who_has_no_kda_layer_never_loads_the_kernel():
+    """A process that imports ``ray_tpu.ops.kda`` and traces a step with no
+    ``kda`` layer (Mistral's, flash kernels and all) has not loaded the
+    kernel's module; tracing a step that takes it does."""
+    code = """if True:
+        import sys
+        import jax, jax.numpy as jnp
+        import ray_tpu.ops.kda as kda
+        from ray_tpu.models import llama
+        from ray_tpu.parallel import train_step as ts
+        import dataclasses
+        cfg = dataclasses.replace(llama.PRESETS["debug"], attn_impl="flash")
+        opt = ts.default_optimizer(total_steps=100)
+        params = jax.eval_shape(lambda r: llama.init_params(r, cfg), jax.random.key(0))
+        step = ts.make_multi_step(cfg, opt, 2)
+        jaxpr = jax.make_jaxpr(step._jit)(
+            params, jax.eval_shape(opt.init, params),
+            {"tokens": jax.ShapeDtypeStruct((2, 1, 65), jnp.int32)})
+        assert "flash_fwd" in str(jaxpr)
+        assert "ray_tpu.ops.pallas.kda_grams" not in sys.modules, "loaded"
+        a = jax.ShapeDtypeStruct((1, 64, 1, 128), jnp.float32)
+        jax.eval_shape(kda.kda_chunked, a, a, a, a,
+                       jax.ShapeDtypeStruct((1, 64, 1), jnp.float32))
+        assert "ray_tpu.ops.pallas.kda_grams" in sys.modules
+        print("ok")
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    done = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0 and done.stdout.strip().endswith("ok"), \
+        done.stderr[-2000:]
+
+
 def test_the_plan_of_the_cells_shape():
     p = kda.plan(16384, 32, 128, 128)
     assert p == {"chunk": 64, "sub_block": 16, "chunks": 256, "segments": 32,
                  "heads": 32, "d_k": 128, "d_v": 128,
                  "boundary_state_bytes": 32 * 32 * 128 * 128 * 4,
-                 "impl": "xla"}
+                 "impl": "pallas_grams"}
     assert p["boundary_state_bytes"] == 67_108_864   # a state a segment
     short = kda.plan(37, 3, 16, 24, batch=2, chunk=16, sub_block=4)
     assert (short["chunk"], short["chunks"], short["segments"]) == (16, 3, 1)
@@ -399,15 +589,20 @@ def test_the_programs_counts_are_by_kind(family):
         6.0 * cfg.active_params() + 6.0 * madds)
 
 
-def test_the_recorder_carries_the_kda_plan(family):
+@pytest.mark.parametrize("width,seq,depth,impl", [
+    (16, SEQ, DEPTH, "xla"),
+    (128, 64, 2, "pallas_grams")])   # a chunk of 64 and a head of whole lanes
+def test_the_recorder_carries_the_kda_plan(family, width, seq, depth, impl):
     from ray_tpu.train.driver import StepDriver
 
-    cfg = _cfg(family)
+    cfg = dataclasses.replace(_cfg(family, depth=depth), max_seq_len=seq,
+                              kda_head_dim=width)
     opt = ts.default_optimizer(total_steps=100)
     params = family.init_params(jax.random.key(3), cfg)
     # one step a launch, as the cell runs: two launches
     driver = StepDriver(cfg, opt, steps_per_launch=1)
-    batches = [{"tokens": np.asarray(TOKENS)} for _ in range(2)]
+    tokens = jax.random.randint(jax.random.key(1), (2, seq + 1), 0, 96)
+    batches = [{"tokens": np.asarray(tokens)} for _ in range(2)]
     driver.run(params, jax.jit(opt.init)(params), batches)
     rec = driver.recorder
     assert driver.launches == 2
@@ -416,10 +611,13 @@ def test_the_recorder_carries_the_kda_plan(family):
         while time.time() < deadline and rec.summary()["in_flight"]:
             time.sleep(0.01)
         summ = rec.summary()
-        assert summ["kda_plan"] == kda.plan(SEQ, 4, 16, 16, batch=2)
+        assert summ["kda_plan"] == kda.plan(seq, 4, width, width, batch=2)
+        assert summ["kda_plan"]["impl"] == impl
         assert rec.window_summary(0.0, 1e18)["kda_plan"] == summ["kda_plan"]
-        assert {p.get("value_dim") for p in summ["flash_plans"]} == {16}
-        assert summ["routing"]["moe_assignments"] == 2 * 4 * 2 * SEQ * cfg.top_k
+        assert {p.get("value_dim") for p in summ["flash_plans"]} == (
+            {16} if depth == DEPTH else set())    # two layers hold no ``mla``
+        assert summ["routing"]["moe_assignments"] == (
+            2 * (depth - 1) * 2 * seq * cfg.top_k)
     finally:
         rec.close()
 

@@ -445,7 +445,8 @@ def test_api_train_and_cli_json(rt_cluster):
             "grid_steps": 64, "window": 4096})
         rec.kda_plan.update(
             chunk=64, sub_block=16, chunks=256, segments=4, heads=32,
-            d_k=128, d_v=128, boundary_state_bytes=536_870_912, impl="xla")
+            d_k=128, d_v=128, boundary_state_bytes=536_870_912,
+            impl="pallas_grams")
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
@@ -493,7 +494,8 @@ def test_api_train_and_cli_json(rt_cluster):
                 "sub-tile 512x512, window 4096") in text
         assert ("kda: 256 chunks of 64 in 4 segment(s), sub-block 16, 32 "
                 "heads 128x128, states at the chunks' starts 512 MiB a "
-                "layer (xla)") in text
+                "layer, decayed products: a Pallas kernel pair "
+                "(pallas_grams)") in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
