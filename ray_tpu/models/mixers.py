@@ -7,17 +7,29 @@ uncompressed form, unrotated (``"mla"``).
 A ``kda`` layer, ``h`` the normed input, H heads of width ``dk = dv``:
 
 - ``q, k, v = silu(conv4(h @ wq)), silu(conv4(h @ wk)), silu(conv4(h @ wv))``,
-  each its own depthwise causal convolution (``ops/ssm.causal_conv``, no
-  bias); a head's ``q`` and ``k`` L2-normalised over their width, ``q``
-  times ``dk ** -0.5``;
+  each its own depthwise causal convolution (no bias); a head's ``q`` and
+  ``k`` L2-normalised over their width, ``q`` times ``dk ** -0.5``;
 - log-decay a channel ``g = -exp(A_log)[head] * softplus((h @ f_down) @
   f_up + dt_bias)`` and step ``beta = sigmoid(h @ wb)``, float32;
 - the recurrence (``ops/kda.py``, chunked; a chunk's two decayed products
-  the Pallas kernel pair of ``ops/pallas/kda_grams.py`` unless ``attn_impl``
-  is ``"xla"``, the head is no whole lanes or a mesh of several chips is
-  ambient);
+  the Pallas kernel pair of ``ops/pallas/kda_grams.py`` where the plan's
+  ``impl`` says so);
 - ``o = rms(o, o_norm) * sigmoid((h @ g_down) @ g_up + g_bias)`` over each
   head's ``dv``, then ``wo``.
+
+Which form the elementwise chains round the recurrence take is the plan's
+``mix`` (``ops/kda.plan``, noted beside ``impl``). ``"pallas"``: the four
+chains that follow a product (q's, k's and v's convolution, SiLU and norm,
+the decay, the output's norm and gate) are the kernels of
+``ops/pallas/kda_mix.py``, each one pass over its stream forward and one
+backward, a tile float32 from its load to its store and rounded once: where
+``attn_impl`` is not ``"xla"``, the head is whole lanes of 128, the taps are
+at most ``kda.MAX_CONV_TAPS`` and no mesh of several chips is ambient (the
+compiler does not partition a Mosaic call). ``"xla"``, everywhere else:
+``ops/ssm.causal_conv``, ``jax.nn.silu`` and ``ops/norms.rmsnorm`` as
+written below, which round to the compute dtype at every step and sum a
+norm in float32; it is the kernels' oracle in the tests and the form GSPMD
+partitions.
 
 An ``mla`` layer, H heads: ``q = h @ wq`` [H, nope + rope]; ``[c, k_pe] = h
 @ wkv_a`` [rank], [rope]; ``c = rms(c, kv_norm)``; ``[k_nope, v] = c @ wkv_b``
@@ -36,6 +48,7 @@ latent's norm).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict
 
@@ -106,50 +119,80 @@ def init_kda(rng: jax.Array, cfg, n: int) -> Params:
 def kda_half(cfg, x: jax.Array, layer: Params) -> jax.Array:
     """Pre-norm KDA + residual, [b, s, d] -> [b, s, d]; the caller opens
     ``attn_kda`` round it (module docstring)."""
+    from ray_tpu.parallel.context import current_mesh
+
     b, s, _ = x.shape
     h, w, cdt = cfg.kda_heads, cfg.kda_head_dim, cfg.compute_dtype
+    taps = cfg.kda_conv_taps
+    # the compiler does not partition a Mosaic call: under a mesh of
+    # several chips the layer stays the XLA form GSPMD splits
+    mesh = current_mesh()
+    impl = "xla" if mesh is not None and mesh.size > 1 else cfg.attn_impl
+    fused = kda.plan(s, h, w, w, b, impl=impl, conv_taps=taps)["mix"] == "pallas"
+    if fused:
+        # here and not at the module's top: a process that traces no step
+        # with the kernels never loads them
+        from ray_tpu.ops.pallas import kda_mix
+
+        # the kernels hand the recurrence its streams heads first, as it
+        # works: this view and ``kda_chunked``'s own turn cancel, and none
+        # is transposed through HBM
+        heads_second = functools.partial(jnp.moveaxis, source=1, destination=2)
     hx = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
 
     with jax.named_scope("kda_conv"):
-        tail = jnp.zeros((b, cfg.kda_conv_taps - 1, h * w), cdt)
+        if fused:
+            def mixed(which, unit, scale=1.0):
+                return heads_second(kda_mix.conv_silu_unit(
+                    hx @ layer["w" + which].astype(cdt),
+                    layer["conv_" + which].astype(cdt), w, unit, scale,
+                    L2_EPS))
 
-        def mixed(which):
-            y, _ = causal_conv(hx @ layer["w" + which].astype(cdt), tail,
-                               layer["conv_" + which].astype(cdt), 0.0)
-            return jax.nn.silu(y).reshape(b, s, h, w)
+            q, k, v = mixed("q", True, w ** -0.5), mixed("k", True), \
+                mixed("v", False)
+        else:
+            tail = jnp.zeros((b, taps - 1, h * w), cdt)
 
-        def unit(y):  # a head's L2 norm, summed in float32
-            y32 = y.astype(F32)
-            return (y32 * jax.lax.rsqrt(
-                jnp.sum(y32 * y32, -1, keepdims=True) + L2_EPS)).astype(cdt)
+            def mixed(which):
+                y, _ = causal_conv(hx @ layer["w" + which].astype(cdt), tail,
+                                   layer["conv_" + which].astype(cdt), 0.0)
+                return jax.nn.silu(y).reshape(b, s, h, w)
 
-        q, k, v = unit(mixed("q")) * jnp.asarray(w ** -0.5, cdt), \
-            unit(mixed("k")), mixed("v")
+            def unit(y):  # a head's L2 norm, summed in float32
+                y32 = y.astype(F32)
+                return (y32 * jax.lax.rsqrt(
+                    jnp.sum(y32 * y32, -1, keepdims=True) + L2_EPS)).astype(cdt)
+
+            q, k, v = unit(mixed("q")) * jnp.asarray(w ** -0.5, cdt), \
+                unit(mixed("k")), mixed("v")
 
     with jax.named_scope("kda_gates"):
-        a = ((hx @ layer["f_down"].astype(cdt))
-             @ layer["f_up"].astype(cdt)).astype(F32) \
-            + layer["dt_bias"].astype(F32)
-        g = -jnp.exp(layer["A_log"].astype(F32))[:, None] \
-            * jax.nn.softplus(a).reshape(b, s, h, w)
+        a = (hx @ layer["f_down"].astype(cdt)) @ layer["f_up"].astype(cdt)
+        if fused:
+            g = heads_second(kda_mix.decay(
+                a, layer["dt_bias"].astype(F32),
+                jnp.repeat(-jnp.exp(layer["A_log"].astype(F32)), w), w))
+        else:
+            a = a.astype(F32) + layer["dt_bias"].astype(F32)
+            g = -jnp.exp(layer["A_log"].astype(F32))[:, None] \
+                * jax.nn.softplus(a).reshape(b, s, h, w)
         beta = jax.nn.sigmoid((hx @ layer["wb"].astype(cdt)).astype(F32))
 
     with jax.named_scope("kda_scan"):
-        from ray_tpu.parallel.context import current_mesh
+        o = kda.kda_chunked(q, k, v, g, beta, impl=impl, conv_taps=taps)
 
-        # the compiler does not partition a Mosaic call: under a mesh of
-        # several chips the recurrence stays the XLA form GSPMD splits
-        mesh = current_mesh()
-        o = kda.kda_chunked(q, k, v, g, beta, impl=(
-            "xla" if mesh is not None and mesh.size > 1 else cfg.attn_impl))
-
-    gate = jax.nn.sigmoid(
-        (hx @ layer["g_down"].astype(cdt)) @ layer["g_up"].astype(cdt)
-        + layer["g_bias"].astype(cdt)).reshape(b, s, h, w)
-    o = rmsnorm(o, layer["o_norm"].astype(cdt), cfg.norm_eps) * gate
-    return x + post_norm(
-        cfg, o.reshape(b, s, h * w) @ layer["wo"].astype(cdt), layer,
-        "attn_post_norm")
+    gate = (hx @ layer["g_down"].astype(cdt)) @ layer["g_up"].astype(cdt)
+    if fused:
+        o = kda_mix.norm_gate(
+            jnp.moveaxis(o, 2, 1), layer["o_norm"].astype(cdt), gate,
+            layer["g_bias"].astype(cdt), cfg.norm_eps)
+    else:
+        gate = jax.nn.sigmoid(
+            gate + layer["g_bias"].astype(cdt)).reshape(b, s, h, w)
+        o = (rmsnorm(o, layer["o_norm"].astype(cdt), cfg.norm_eps)
+             * gate).reshape(b, s, h * w)
+    return x + post_norm(cfg, o @ layer["wo"].astype(cdt), layer,
+                         "attn_post_norm")
 
 
 # ---------------------------------------------------------------------- mla

@@ -117,6 +117,11 @@ SEGMENT = 8
 #: the walk over chunks again on its way to the backward
 RESIDUAL_NAMES = ("kda_o", "kda_states")
 
+#: the most taps a layer's short convolutions may have for
+#: ``ops/pallas/kda_mix.py`` to run them: of the rows before a block its
+#: kernels read the eight nearest
+MAX_CONV_TAPS = 9
+
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -124,14 +129,19 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def plan(seq: int, heads: int, d_k: int, d_v: int, batch: int = 1,
          chunk: int = CHUNK, sub_block: int = SUB_BLOCK,
-         impl: str = "pallas") -> Dict[str, Any]:
+         impl: str = "pallas", conv_taps: int = 4) -> Dict[str, Any]:
     """What ``kda_chunked`` does at one shape; pure. ``chunk`` is shrunk to
     a short sequence (rounded up to whole sub-blocks); a sub-block that
     does not divide the chunk is the chunk. ``boundary_state_bytes``: the
     float32 states the forward keeps for the backward, one a segment.
     ``impl``: ``"pallas_grams"`` where the two decayed products are the
     kernel's (module docstring: the shape takes it and ``impl`` did not ask
-    for ``"xla"``), else ``"xla"``."""
+    for ``"xla"``), else ``"xla"``. ``mix``: ``"pallas"`` where the layer
+    round the recurrence (``models/mixers.kda_half``) runs its elementwise
+    chains, ``conv_taps`` taps each, as the kernels of
+    ``ops/pallas/kda_mix.py``: both widths whole lanes of 128, the taps one
+    halo block (``MAX_CONV_TAPS``) and ``impl`` not ``"xla"``; else
+    ``"xla"``."""
     sub = min(sub_block, chunk)
     c = min(chunk, -(-max(seq, 1) // sub) * sub)
     if c % sub:
@@ -143,7 +153,10 @@ def plan(seq: int, heads: int, d_k: int, d_v: int, batch: int = 1,
             "d_k": d_k, "d_v": d_v,
             "boundary_state_bytes": segments * batch * heads * d_k * d_v * 4,
             "impl": ("pallas_grams" if impl != "xla" and c == CHUNK
-                     and sub == SUB_BLOCK and d_k % 128 == 0 else "xla")}
+                     and sub == SUB_BLOCK and d_k % 128 == 0 else "xla"),
+            "mix": ("pallas" if impl != "xla" and d_k % 128 == 0
+                    and d_v % 128 == 0 and conv_taps <= MAX_CONV_TAPS
+                    else "xla")}
 
 
 _noting = threading.local()
@@ -468,7 +481,8 @@ _chunks.defvjp(_chunks_fwd, _chunks_bwd)
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, *, chunk: int = CHUNK,
-                sub_block: int = SUB_BLOCK, impl: str = "pallas") -> jax.Array:
+                sub_block: int = SUB_BLOCK, impl: str = "pallas",
+                conv_taps: int = 4) -> jax.Array:
     """The recurrence over a sequence from a zero state, in chunks.
 
     ``q``, ``k`` [b, s, h, dk] (as the model hands them on: normalised, the
@@ -479,10 +493,11 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     Any ``s >= 1``: the sequence is padded at its END to whole chunks with
     ``g = 0`` and ``beta = 0``, under which a token neither decays the state
     nor adds to it. ``impl="xla"`` keeps the kernel out (``plan``); any
-    other asks for it where the shape takes it."""
+    other asks for it where the shape takes it. ``conv_taps`` is the
+    caller's, for the plan that is noted alone (``mix``)."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
-    p = plan(s, h, dk, dv, b, chunk, sub_block, impl)
+    p = plan(s, h, dk, dv, b, chunk, sub_block, impl, conv_taps)
     into = getattr(_noting, "into", None)
     if into is not None:
         into.update(p)

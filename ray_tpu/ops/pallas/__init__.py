@@ -8,8 +8,10 @@ The package imports the flash kernels alone. Every other kernel's module is
 imported by the code that calls it, where it calls it: ``kda_grams`` (the two
 decayed Gram matrices of a chunk of the chunked delta rule, forward and
 backward) by ``ops/kda.py:_insides`` in the branch that a plan with ``impl``
-``"pallas_grams"`` takes, so that a process whose steps hold no ``kda`` layer
-never loads or compiles it (``tests/test_kimi_linear_training.py``).
+``"pallas_grams"`` takes, and ``kda_mix`` (a ``kda`` layer's elementwise
+chains round its recurrence) by ``models/mixers.kda_half`` where the plan's
+``mix`` is ``"pallas"``, so that a process whose steps hold no ``kda`` layer
+never loads or compiles either (``tests/test_kimi_linear_training.py``).
 """
 
 from ray_tpu.ops.pallas.flash import flash_attention, flash_attention_with_lse  # noqa: F401

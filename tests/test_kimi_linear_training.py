@@ -9,11 +9,16 @@ against the benchmark's plain reference
 the step under a mesh; the plan a step notes; and who refuses the two kinds.
 The Pallas kernel for a chunk's two decayed products
 (``ops/pallas/kda_grams.py``, interpreted) against the form it replaces at
-128-wide heads, and when a step takes it."""
+128-wide heads, and when a step takes it; the kernels for a layer's
+elementwise chains outside the recurrence (``ops/pallas/kda_mix.py``,
+interpreted) against the XLA form ``mixers.kda_half`` keeps, and when a
+layer takes them."""
 
 import dataclasses
 import functools
+import hashlib
 import os
+import re
 import subprocess
 import sys
 import time
@@ -26,7 +31,9 @@ import pytest
 from ray_tpu.models import generate, llama, mixers, moe
 from ray_tpu.ops import kda
 from ray_tpu.ops.attention import mha
+from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import flash
+from ray_tpu.ops.ssm import causal_conv
 from ray_tpu.parallel import train_step as ts
 from ray_tpu.util import flops
 
@@ -251,18 +258,25 @@ def test_a_fast_channel_through_the_kernel_is_a_true_zero():
     assert float(jnp.abs(grads[0] - k).max()) < 1e-7    # d(q . k) / dq
 
 
-@pytest.mark.parametrize("args,kwargs,impl", [
-    ((16384, 32, 128, 128), {}, "pallas_grams"),        # the cell's shape
-    ((100, 3, 256, 16), {"batch": 2}, "pallas_grams"),  # two lane tiles a head
-    ((37, 3, 128, 128), {}, "xla"),                     # a shrunk chunk (48)
-    ((16384, 32, 64, 64), {}, "xla"),                   # half a lane tile
-    ((16384, 32, 192, 128), {}, "xla"),
-    ((16384, 32, 128, 128), {"chunk": 32}, "xla"),
-    ((16384, 32, 128, 128), {"sub_block": 8}, "xla"),
-    ((16384, 32, 128, 128), {"impl": "xla"}, "xla"),    # asked for
-    ((16384, 32, 128, 128), {"impl": "flash"}, "pallas_grams")])
-def test_when_the_plan_takes_the_kernel(args, kwargs, impl):
+@pytest.mark.parametrize("args,kwargs,impl,mix", [
+    ((16384, 32, 128, 128), {}, "pallas_grams", "pallas"),   # the cell's shape
+    # two lane tiles a head; the values' 16 are no whole lanes
+    ((100, 3, 256, 16), {"batch": 2}, "pallas_grams", "xla"),
+    ((100, 3, 256, 128), {"batch": 2}, "pallas_grams", "pallas"),
+    # a shrunk chunk (48); the chains outside take any length
+    ((37, 3, 128, 128), {}, "xla", "pallas"),
+    ((16384, 32, 64, 64), {}, "xla", "xla"),                 # half a lane tile
+    ((16384, 32, 192, 128), {}, "xla", "xla"),
+    ((16384, 32, 128, 128), {"chunk": 32}, "xla", "pallas"),
+    ((16384, 32, 128, 128), {"sub_block": 8}, "xla", "pallas"),
+    ((16384, 32, 128, 128), {"impl": "xla"}, "xla", "xla"),  # asked for
+    ((16384, 32, 128, 128), {"impl": "flash"}, "pallas_grams", "pallas"),
+    # the rows before a block come in as one block of eight
+    ((16384, 32, 128, 128), {"conv_taps": 9}, "pallas_grams", "pallas"),
+    ((16384, 32, 128, 128), {"conv_taps": 10}, "pallas_grams", "xla")])
+def test_when_the_plan_takes_the_kernel(args, kwargs, impl, mix):
     assert kda.plan(*args, **kwargs)["impl"] == impl
+    assert kda.plan(*args, **kwargs)["mix"] == mix
     # and what a call is traced with is the plan's
     noted = {}
     b, (s, h, dk, dv) = kwargs.get("batch", 1), args
@@ -273,34 +287,45 @@ def test_when_the_plan_takes_the_kernel(args, kwargs, impl):
     kwargs = {k: v for k, v in kwargs.items() if k != "batch"}
     with kda.noting_plan(noted):
         jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, **kwargs))(*shapes)
-    assert noted["impl"] == impl
+    assert (noted["impl"], noted["mix"]) == (impl, mix)
     assert ("kda_grams_fwd" in str(jaxpr)) == (impl == "pallas_grams")
 
 
-def test_a_mesh_of_several_chips_keeps_the_xla_form(family):
+def _a_layer(cfg):
+    """One ``kda`` layer's leaves and its input at 64 tokens, as shapes."""
+    layer = jax.eval_shape(lambda r: jax.tree.map(
+        lambda a: a[0], mixers.init_kda(r, cfg, 1)), jax.random.key(0))
+    layer["attn_norm"] = jax.ShapeDtypeStruct((cfg.d_model,), jnp.float32)
+    return jax.ShapeDtypeStruct((2, 64, cfg.d_model), cfg.compute_dtype), layer
+
+
+@pytest.mark.parametrize("devices,impl,mix", [(1, "pallas_grams", "pallas"),
+                                              (2, "xla", "xla")])
+def test_a_mesh_of_several_chips_keeps_the_xla_form(family, devices, impl, mix):
     """Mosaic's calls are not partitioned: where the ambient mesh has more
     than one device a ``kda`` layer is the XLA form GSPMD splits, at any
-    width; alone on its chip it takes the kernel."""
+    width, the recurrence and the chains round it; alone on its chip it
+    takes the kernels."""
     from ray_tpu.parallel.context import mesh_scope
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 
     cfg = dataclasses.replace(_cfg(family), kda_heads=1, kda_head_dim=128)
-    layer = jax.eval_shape(lambda r: jax.tree.map(
-        lambda a: a[0], mixers.init_kda(r, cfg, 1)), jax.random.key(0))
-    layer["attn_norm"] = jax.ShapeDtypeStruct((cfg.d_model,), jnp.float32)
-    x = jax.ShapeDtypeStruct((1, 64, cfg.d_model), jnp.float32)
-    for mesh, impl in ((None, "pallas_grams"),
-                       (make_mesh(MeshConfig(dp=2), jax.devices()[:2]), "xla")):
-        noted = {}
-        with mesh_scope(mesh), kda.noting_plan(noted):
-            jax.eval_shape(lambda x, l: mixers.kda_half(cfg, x, l), x, layer)
-        assert noted["impl"] == impl, mesh
+    mesh = None if devices == 1 else make_mesh(MeshConfig(dp=devices),
+                                               jax.devices()[:devices])
+    noted = {}
+    with mesh_scope(mesh), kda.noting_plan(noted):
+        jaxpr = str(jax.make_jaxpr(
+            lambda x, l: mixers.kda_half(cfg, x, l))(*_a_layer(cfg)))
+    assert (noted["impl"], noted["mix"]) == (impl, mix)
+    for call in ("kda_mix_conv_unit_fwd", "kda_mix_conv_fwd",
+                 "kda_mix_decay_fwd", "kda_mix_norm_gate_fwd"):
+        assert (call in jaxpr) == (mix == "pallas"), call
 
 
-def test_who_has_no_kda_layer_never_loads_the_kernel():
-    """A process that imports ``ray_tpu.ops.kda`` and traces a step with no
-    ``kda`` layer (Mistral's, flash kernels and all) has not loaded the
-    kernel's module; tracing a step that takes it does."""
+@functools.lru_cache(maxsize=None)
+def _who_loads_what():
+    """What a fresh process has loaded of ``ray_tpu.ops.pallas`` after it
+    traced Mistral's step, then the recurrence, then a ``kda`` layer."""
     code = """if True:
         import sys
         import jax, jax.numpy as jnp
@@ -316,18 +341,43 @@ def test_who_has_no_kda_layer_never_loads_the_kernel():
             params, jax.eval_shape(opt.init, params),
             {"tokens": jax.ShapeDtypeStruct((2, 1, 65), jnp.int32)})
         assert "flash_fwd" in str(jaxpr)
-        assert "ray_tpu.ops.pallas.kda_grams" not in sys.modules, "loaded"
+        loaded = lambda: sorted(m.rsplit(".", 1)[1] for m in sys.modules
+                                if m.startswith("ray_tpu.ops.pallas.kda_"))
+        print("mistral", *loaded())
         a = jax.ShapeDtypeStruct((1, 64, 1, 128), jnp.float32)
         jax.eval_shape(kda.kda_chunked, a, a, a, a,
                        jax.ShapeDtypeStruct((1, 64, 1), jnp.float32))
-        assert "ray_tpu.ops.pallas.kda_grams" in sys.modules
-        print("ok")
+        print("recurrence", *loaded())
+        from ray_tpu.models import mixers, moe
+        cfg = moe.MoEConfig(d_model=32, kda_heads=1, kda_head_dim=128,
+                            attn_impl="flash")
+        layer = jax.eval_shape(lambda r: jax.tree.map(
+            lambda a: a[0], mixers.init_kda(r, cfg, 1)), jax.random.key(0))
+        layer["attn_norm"] = jax.ShapeDtypeStruct((32,), jnp.float32)
+        jax.eval_shape(lambda x, l: mixers.kda_half(cfg, x, l),
+                       jax.ShapeDtypeStruct((1, 64, 32), cfg.compute_dtype), layer)
+        print("layer", *loaded())
     """
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     done = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
                           capture_output=True, text=True, timeout=300)
-    assert done.returncode == 0 and done.stdout.strip().endswith("ok"), \
-        done.stderr[-2000:]
+    assert done.returncode == 0, done.stderr[-2000:]
+    return {line.split()[0]: line.split()[1:]
+            for line in done.stdout.splitlines()}
+
+
+@pytest.mark.parametrize("module,first_after", [("kda_grams", "recurrence"),
+                                                ("kda_mix", "layer")])
+def test_who_has_no_kda_layer_never_loads_the_kernel(module, first_after):
+    """A process that imports ``ray_tpu.ops.kda`` and traces a step with no
+    ``kda`` layer (Mistral's, flash kernels and all) has not loaded either
+    kernel module; tracing the recurrence loads its own, and a layer that
+    takes the kernels round it theirs."""
+    loaded = _who_loads_what()
+    stages = ["mistral", "recurrence", "layer"]
+    for stage in stages:
+        assert (module in loaded[stage]) == (
+            stages.index(stage) >= stages.index(first_after)), (stage, loaded)
 
 
 def test_the_plan_of_the_cells_shape():
@@ -335,11 +385,285 @@ def test_the_plan_of_the_cells_shape():
     assert p == {"chunk": 64, "sub_block": 16, "chunks": 256, "segments": 32,
                  "heads": 32, "d_k": 128, "d_v": 128,
                  "boundary_state_bytes": 32 * 32 * 128 * 128 * 4,
-                 "impl": "pallas_grams"}
+                 "impl": "pallas_grams", "mix": "pallas"}
     assert p["boundary_state_bytes"] == 67_108_864   # a state a segment
     short = kda.plan(37, 3, 16, 24, batch=2, chunk=16, sub_block=4)
     assert (short["chunk"], short["chunks"], short["segments"]) == (16, 3, 1)
     assert kda.plan(5, 1, 8, 8)["chunk"] == 16   # shrunk to whole sub-blocks
+
+
+# ---- (a'') the kernels for a layer's chains outside the recurrence ----------------------
+#
+# 300 tokens are a row block of 256 and one of 44: the second block's first
+# rows read the first's last, the first's last rows (backward) the second's
+# cotangent, and the second is no whole block.
+
+_MIX_SHAPE = (2, 300, 2, 128)   # batch, tokens, heads, a head's width
+_MIX_TAPS = 4
+
+
+def _conv_xla(p, taps, w, unit, scale):
+    """``mixers.kda_half``'s XLA form of a projection's chain."""
+    b, s, ch = p.shape
+    y, _ = causal_conv(p, jnp.zeros((b, taps.shape[0] - 1, ch), p.dtype), taps,
+                       0.0)
+    y = jax.nn.silu(y).reshape(b, s, ch // w, w)
+    if unit:
+        y32 = y.astype(jnp.float32)
+        y = (y32 * jax.lax.rsqrt(jnp.sum(y32 * y32, -1, keepdims=True)
+                                 + mixers.L2_EPS)).astype(p.dtype)
+    return (y * jnp.asarray(scale, p.dtype)).reshape(b, s, ch)
+
+
+def _conv_kernel(p, taps, w, unit, scale):
+    from ray_tpu.ops.pallas import kda_mix
+
+    y = kda_mix.conv_silu_unit(p, taps, w, unit, scale, mixers.L2_EPS)
+    return jnp.moveaxis(y, 1, 2).reshape(p.shape)      # it is heads first
+
+
+def _gate_xla(o, weight, gate, bias, eps):
+    b, s, ch = o.shape
+    w = weight.shape[0]
+    return (rmsnorm(o.reshape(b, s, ch // w, w), weight, eps)
+            * jax.nn.sigmoid(gate + bias).reshape(b, s, ch // w, w)
+            ).reshape(b, s, ch)
+
+
+def _gate_kernel(o, weight, *a):
+    from ray_tpu.ops.pallas import kda_mix
+
+    b, s, _ = o.shape
+    heads_first = jnp.moveaxis(o.reshape(b, s, -1, weight.shape[0]), 2, 1)
+    return kda_mix.norm_gate(heads_first, weight, *a)
+
+
+def _decay_xla(lin, bias, a_log, w):
+    """``kda_half``'s XLA form of the log-decay a channel."""
+    b, s, ch = lin.shape
+    a = lin.astype(jnp.float32) + bias
+    return (-jnp.exp(a_log)[:, None] * jax.nn.softplus(a).reshape(
+        b, s, ch // w, w)).reshape(b, s, ch)
+
+
+def _decay_kernel(lin, bias, a_log, w):
+    from ray_tpu.ops.pallas import kda_mix
+
+    g = kda_mix.decay(lin, bias, jnp.repeat(-jnp.exp(a_log), w), w)
+    return jnp.moveaxis(g, 1, 2).reshape(lin.shape)    # it is heads first
+
+
+@functools.lru_cache(maxsize=None)
+def _pulled(fn, *static):
+    """``fn``'s value and its cotangents' pull-back of one ``dy``, jitted
+    once a (function, static arguments)."""
+    def run(dy, *a):
+        y, pull = jax.vjp(lambda *a: fn(*a, *static), *a)
+        return (y, *pull(dy))
+
+    return jax.jit(run)
+
+
+def _mix_inputs(dtype, shape=_MIX_SHAPE):
+    b, s, h, w = shape
+    ks = jax.random.split(jax.random.key(7), 7)
+    draw = lambda key, *dims: jax.random.normal(key, dims).astype(dtype)
+    return dict(
+        p=draw(ks[0], b, s, h * w), dy=draw(ks[1], b, s, h * w),
+        taps=(jax.random.normal(ks[2], (_MIX_TAPS, h * w)) / 2).astype(dtype),
+        weight=(1 + 0.3 * jax.random.normal(ks[3], (w,))).astype(dtype),
+        gate=draw(ks[4], b, s, h * w), bias=draw(ks[5], h * w))
+
+
+def _close(got, want, tol, names):
+    for name, a, b in zip(names, got, want):
+        assert (a.dtype, a.shape) == (b.dtype, b.shape), name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.abs(b).max()) > 0.01, name
+        assert float(jnp.abs(a - b).max()) < tol * float(jnp.abs(b).max()), name
+
+
+# bf16: the XLA form rounds at every step where the kernel rounds once
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("unit,scale", [(True, 128 ** -0.5), (False, 1.0)])
+def test_the_convolutions_kernel_is_the_form_it_replaces(dtype, tol, unit,
+                                                         scale):
+    """The convolution, SiLU and a head's L2 norm, and the cotangents of the
+    projection's result and of the taps, against ``causal_conv``,
+    ``jax.nn.silu`` and the norm as ``kda_half`` writes them and ``jax.grad``
+    through them."""
+    i = _mix_inputs(dtype)
+    got = _pulled(_conv_kernel, 128, unit, scale)(i["dy"], i["p"], i["taps"])
+    want = _pulled(_conv_xla, 128, unit, scale)(i["dy"], i["p"], i["taps"])
+    _close(got, want, tol, ("y", "dp", "dtaps"))
+
+
+def test_q_and_k_are_one_traced_call(monkeypatch):
+    """A head's scale is an operand, no part of a call's key: q (``dk **
+    -0.5``) and k (1) trace the kernel's body once between them, v (no
+    norm) once more."""
+    from ray_tpu.ops.pallas import kda_mix
+
+    traced = []
+    body = kda_mix._conv_fwd_kernel
+    monkeypatch.setattr(kda_mix, "_conv_fwd_kernel", lambda *a, **k: (
+        traced.append(k["unit"]), body(*a, **k))[1])
+    i = _mix_inputs(jnp.float32, (1, 96, 1, 128))   # no other test's shape
+    q, k, v = (_conv_kernel(i["p"], i["taps"], 128, unit, scale)
+               for unit, scale in ((True, 128 ** -0.5), (True, 1.0),
+                                   (False, 1.0)))
+    assert traced == [True, False]
+    assert float(jnp.abs(q - k * 128 ** -0.5).max()) < 1e-7
+    assert float(jnp.abs(k - v).max()) > 0.1
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 3e-2)])
+def test_the_output_norms_kernel_is_the_form_it_replaces(dtype, tol):
+    """``rmsnorm`` over a head's width times the gate's sigmoid, and the
+    cotangents of the output, the norm's weight, the gate and its bias."""
+    i = _mix_inputs(dtype)
+    args = (i["dy"], i["p"], i["weight"], i["gate"], i["bias"])
+    _close(_pulled(_gate_kernel, 1e-5)(*args), _pulled(_gate_xla, 1e-5)(*args),
+           tol, ("y", "do", "dweight", "dgate", "dbias"))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-6),
+                                       (jnp.bfloat16, 1e-2)])
+def test_the_decays_kernel_is_the_form_it_replaces(dtype, tol):
+    """``-exp(A_log) * softplus(a + dt_bias)``, float32 whatever the
+    product's dtype (both forms widen it first; the product's cotangent is
+    rounded to its dtype once by both), and the cotangents of the product,
+    the bias and ``A_log``."""
+    i = _mix_inputs(dtype)
+    ks = jax.random.split(jax.random.key(8), 2)
+    args = (i["dy"].astype(jnp.float32), 3 * i["p"],
+            jax.random.normal(ks[0], (i["p"].shape[-1],)),
+            jax.random.normal(ks[1], (_MIX_SHAPE[2],)))
+    _close(_pulled(_decay_kernel, 128)(*args), _pulled(_decay_xla, 128)(*args),
+           tol, ("g", "dlin", "dbias", "dA_log"))
+
+
+def test_a_blocks_first_rows_read_the_block_before_and_a_sequences_none():
+    """One token of the first block's last row: the second block's first
+    ``K - 1`` rows hold its taps' SiLU (zeros if the rows before a block were
+    not brought in), and backward one cotangent on the second block's first
+    row reaches the first block's last three. A sequence's own first rows
+    see zeros before them, the batch's second sequence too, whose block
+    before would be its own."""
+    from ray_tpu.ops.pallas import kda_mix
+
+    b, s, h, w = _MIX_SHAPE
+    rows = kda_mix._tiles(s, h, w)[0]
+    assert 0 < s - rows < rows                       # two blocks, one short
+    i = _mix_inputs(jnp.float32)
+    at = jnp.zeros((b, s, h * w)).at[:, rows - 1].set(1.0)
+    run = _pulled(_conv_kernel, w, False, 1.0)
+    y = run(at, at * i["p"], i["taps"])[0]
+    hit = i["p"][:, rows - 1]
+    for ahead in range(_MIX_TAPS):
+        want = jax.nn.silu(i["taps"][_MIX_TAPS - 1 - ahead] * hit)
+        assert float(jnp.abs(want).max()) > 0.1
+        assert float(jnp.abs(y[:, rows - 1 + ahead] - want).max()) < 1e-6
+    assert not bool(y[:, rows + _MIX_TAPS - 1:].any())
+    # no token at all: silu'(0) = 1/2 on every row
+    dp = run(jnp.roll(at, 1, axis=1), 0 * at, i["taps"])[1]
+    for back in range(1, _MIX_TAPS):
+        want = 0.5 * i["taps"][_MIX_TAPS - 1 - back]
+        assert float(jnp.abs(dp[:, rows - back] - want).max()) < 1e-6, back
+    y = _conv_kernel(i["p"], i["taps"], w, False, 1.0)
+    for t in range(_MIX_TAPS - 1):
+        want = jax.nn.silu(sum(i["taps"][_MIX_TAPS - 1 - j] * i["p"][:, t - j]
+                               for j in range(t + 1)))
+        assert float(jnp.abs(y[:, t] - want).max()) < 1e-6, t
+
+
+@pytest.mark.parametrize("shape", [(1, 37, 10, 128),   # two slabs of lanes
+                                   (1, 7, 1, 256)])    # under one row block
+def test_the_kernels_at_other_shapes(shape):
+    i = _mix_inputs(jnp.float32, shape)
+    w = shape[-1]
+    _close(_pulled(_conv_kernel, w, True, 0.5)(i["dy"], i["p"], i["taps"]),
+           _pulled(_conv_xla, w, True, 0.5)(i["dy"], i["p"], i["taps"]),
+           2e-6, ("y", "dp", "dtaps"))
+    args = (i["dy"], i["p"], i["weight"], i["gate"], i["bias"])
+    _close(_pulled(_gate_kernel, 1e-5)(*args), _pulled(_gate_xla, 1e-5)(*args),
+           2e-6, ("y", "do", "dweight", "dgate", "dbias"))
+
+
+def test_more_taps_than_the_kernel_reads_rows_back_are_refused():
+    """``kda_half`` never asks (the plan's ``mix`` is ``"xla"`` there); a
+    caller of its own is told."""
+    from ray_tpu.ops.pallas import kda_mix
+
+    with pytest.raises(ValueError, match="10 taps"):
+        kda_mix.conv_silu_unit(jnp.zeros((1, 32, 128)), jnp.zeros((10, 128)),
+                               128, False)
+
+
+def _kda_half_digest(cfg, mesh=None):
+    """The jaxpr of a ``kda`` layer and its gradient, addresses taken out."""
+    from ray_tpu.parallel.context import mesh_scope
+
+    with mesh_scope(mesh):
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda x, l: mixers.kda_half(cfg, x, l).astype(jnp.float32).sum(),
+            (0, 1)))(*_a_layer(cfg)))
+    text = re.sub(r"\s+", " ", re.sub(r" at 0x[0-9a-f]+", "", text))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("change,devices,parent", [
+    ({"attn_impl": "xla"}, 1, "f9f42ce4c3d976c4"),
+    ({"kda_heads": 4, "kda_head_dim": 16}, 1, "68d9d4ab5ff54e5c"),
+    ({}, 2, "f9f42ce4c3d976c4"),
+    ({"kda_conv_taps": 10}, 1, "c639d1f9653399b1")])
+def test_where_the_kernels_are_not_taken_the_layer_is_the_parents(
+        family, change, devices, parent):
+    """With ``attn_impl="xla"``, a head of no whole lanes, a mesh of several
+    chips or more taps than a halo block holds, ``kda_half`` and its
+    gradient trace to the jaxpr of commit f1edc9a (PR 51's parent, where the
+    four digests were taken with the function above at bf16, 2 heads x 128
+    but where a case says otherwise); with none of them they do not."""
+    from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    cfg = dataclasses.replace(_cfg(family), compute_dtype=jnp.bfloat16,
+                              kda_heads=2, kda_head_dim=128)
+    mesh = None if devices == 1 else make_mesh(MeshConfig(dp=devices),
+                                               jax.devices()[:devices])
+    assert _kda_half_digest(dataclasses.replace(cfg, **change), mesh) == parent
+    if not change and devices == 1:
+        return
+    assert _kda_half_digest(cfg) not in (parent, "1a89903f0d0501d9")
+
+
+def test_a_layer_with_the_kernels_is_the_layer_without(family):
+    """``kda_half`` at the tiny family's sizes but a head of 128: the layer
+    and every gradient with the kernels round the recurrence against
+    ``attn_impl="xla"``, float32."""
+    cfg = dataclasses.replace(_cfg(family), kda_heads=2, kda_head_dim=128)
+    ks = jax.random.split(jax.random.key(5), 4)
+    layer = jax.tree.map(lambda a: a[0], mixers.init_kda(ks[0], cfg, 1))
+    layer["g_bias"] = 0.5 * jax.random.normal(ks[1], layer["g_bias"].shape)
+    layer["attn_norm"] = 1 + 0.1 * jax.random.normal(ks[2], (cfg.d_model,))
+    x = jax.random.normal(ks[3], (2, SEQ, cfg.d_model))
+
+    def both(cfg):
+        noted = {}
+        with kda.noting_plan(noted):
+            out = jax.jit(jax.value_and_grad(lambda x, l: (mixers.kda_half(
+                cfg, x, l) ** 2).sum(), (0, 1)))(x, layer)
+        return noted["mix"], out
+
+    (mix, (loss, (dx, dl))) = both(cfg)
+    (xla, (want, (wx, wl))) = both(dataclasses.replace(cfg, attn_impl="xla"))
+    assert (mix, xla) == ("pallas", "xla")
+    np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
+    leaves = {"x": (dx, wx), **{k: (dl[k], wl[k]) for k in wl}}
+    for name, (a, b) in leaves.items():
+        assert float(jnp.abs(a - b).max()) < 2e-5 * max(
+            float(jnp.abs(b).max()), 1e-3), name
 
 
 # ---- (b) the flash kernels at two widths -----------------------------------------
@@ -589,10 +913,12 @@ def test_the_programs_counts_are_by_kind(family):
         6.0 * cfg.active_params() + 6.0 * madds)
 
 
-@pytest.mark.parametrize("width,seq,depth,impl", [
-    (16, SEQ, DEPTH, "xla"),
-    (128, 64, 2, "pallas_grams")])   # a chunk of 64 and a head of whole lanes
-def test_the_recorder_carries_the_kda_plan(family, width, seq, depth, impl):
+@pytest.mark.parametrize("width,seq,depth,impl,mix", [
+    (16, SEQ, DEPTH, "xla", "xla"),
+    # a chunk of 64 and a head of whole lanes
+    (128, 64, 2, "pallas_grams", "pallas")])
+def test_the_recorder_carries_the_kda_plan(family, width, seq, depth, impl,
+                                           mix):
     from ray_tpu.train.driver import StepDriver
 
     cfg = dataclasses.replace(_cfg(family, depth=depth), max_seq_len=seq,
@@ -612,7 +938,7 @@ def test_the_recorder_carries_the_kda_plan(family, width, seq, depth, impl):
             time.sleep(0.01)
         summ = rec.summary()
         assert summ["kda_plan"] == kda.plan(seq, 4, width, width, batch=2)
-        assert summ["kda_plan"]["impl"] == impl
+        assert (summ["kda_plan"]["impl"], summ["kda_plan"]["mix"]) == (impl, mix)
         assert rec.window_summary(0.0, 1e18)["kda_plan"] == summ["kda_plan"]
         assert {p.get("value_dim") for p in summ["flash_plans"]} == (
             {16} if depth == DEPTH else set())    # two layers hold no ``mla``
