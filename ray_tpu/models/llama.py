@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import mha
 from ray_tpu.ops.norms import layernorm, rmsnorm
@@ -91,6 +92,23 @@ class LlamaConfig:
     qk_norm_head: bool = False
     attn_gate: bool = False
     sandwich_norm: bool = False
+    # EvaByte's knobs, neutral by default and read by the training blocks
+    # only. ``attn_kind`` "eva": every layer's mixer is EVA attention
+    # (``ops/eva.py``: exact inside ``eva_window`` positions, one learned
+    # summary an ``eva_chunk`` of every earlier window, one softmax over
+    # both; ``eva_phi`` / ``eva_mu`` [heads, head_dim] a layer), else "full".
+    # ``norm_unit_offset``: every RMSNorm scales by ``1 + w`` (w from
+    # zeros). ``residual_f32``: the residual stream is float32, the branches'
+    # products stay in the compute dtype. ``n_pred_heads`` n > 1: the head is
+    # [d, n * V], columns ``i V .. (i + 1) V`` predicting the token at
+    # ``t + 1 + i``, and the loss the mean over the n of each one's mean
+    # cross-entropy over the positions whose target lies inside the row
+    attn_kind: str = "full"
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    norm_unit_offset: bool = False
+    residual_f32: bool = False
+    n_pred_heads: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -134,13 +152,14 @@ class LlamaConfig:
         d, q = self.d_model, self.n_heads * self.head_dim
         return ((2 + self.attn_gate) * d * q
                 + 2 * d * self.n_kv_heads * self.head_dim
-                + (1 + self.sandwich_norm) * d + self.qk_norm_params())
+                + (1 + self.sandwich_norm) * d + self.qk_norm_params()
+                + (2 * q if self.attn_kind == "eva" else 0))
 
     def num_params(self) -> int:
         d, f, v, l = self.d_model, self.d_ff, self.vocab_size, self.n_layers
         per_layer = (self.attn_params() + 3 * d * f
                      + (1 + self.sandwich_norm) * d)
-        head = 0 if self.tie_embeddings else d * v
+        head = 0 if self.tie_embeddings else d * v * self.n_pred_heads
         return v * d + l * per_layer + d + head
 
 
@@ -168,21 +187,31 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
         return (jax.random.normal(key, shape, jnp.float32)
                 * (1.0 / math.sqrt(fan_in))).astype(cfg.param_dtype)
 
+    # a norm's weight: ones, or zeros where the norm adds the unit itself
+    unit = jnp.zeros if cfg.norm_unit_offset else jnp.ones
     params: Params = {
         "embed": norm_init(keys[0], (cfg.vocab_size, d), d),
         "layers": {
-            "attn_norm": jnp.ones((L, d), cfg.param_dtype),
+            "attn_norm": unit((L, d), cfg.param_dtype),
             "wq": norm_init(keys[1], (L, d, hq * hd), d),
             "wk": norm_init(keys[2], (L, d, hkv * hd), d),
             "wv": norm_init(keys[3], (L, d, hkv * hd), d),
             "wo": norm_init(keys[4], (L, hq * hd, d), hq * hd),
-            "mlp_norm": jnp.ones((L, d), cfg.param_dtype),
+            "mlp_norm": unit((L, d), cfg.param_dtype),
             "w_gate": norm_init(keys[5], (L, d, f), d),
             "w_up": norm_init(keys[6], (L, d, f), d),
             "w_down": norm_init(keys[7], (L, f, d), f),
         },
-        "final_norm": jnp.ones((d,), cfg.param_dtype),
+        "final_norm": unit((d,), cfg.param_dtype),
     }
+    if cfg.attn_kind == "eva":
+        from ray_tpu.ops import eva
+
+        for n, name in enumerate(("eva_phi", "eva_mu")):
+            params["layers"][name] = (eva.INIT_STD * jnp.clip(
+                jax.random.normal(jax.random.fold_in(rng, 96 + n),
+                                  (L, hq, hd), jnp.float32), -1.0, 1.0)
+            ).astype(cfg.param_dtype)
     if cfg.qk_norm_head:
         params["layers"]["q_norm"] = jnp.ones((L, hd), cfg.param_dtype)
         params["layers"]["k_norm"] = jnp.ones((L, hd), cfg.param_dtype)
@@ -196,7 +225,9 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> Params:
         for name in ("attn_post_norm", "mlp_post_norm"):
             params["layers"][name] = jnp.ones((L, d), cfg.param_dtype)
     if not cfg.tie_embeddings:
-        params["lm_head"] = norm_init(jax.random.fold_in(rng, 99), (d, cfg.vocab_size), d)
+        params["lm_head"] = norm_init(
+            jax.random.fold_in(rng, 99),
+            (d, cfg.vocab_size * cfg.n_pred_heads), d)
     return params
 
 
@@ -293,6 +324,8 @@ def pre_norm(cfg: LlamaConfig, x: jax.Array, layer: Params, name: str
     (``models/sambay.py``), a LayerNorm with both, in the compute dtype
     whatever ``x`` comes in."""
     cdt = cfg.compute_dtype
+    if cfg.norm_unit_offset:
+        return unit_offset_norm(cfg, x, layer[name])
     if name + "_b" in layer:  # (of a residual stream kept in float32 too)
         return layernorm(x, layer[name].astype(x.dtype),
                          layer[name + "_b"].astype(x.dtype),
@@ -300,10 +333,32 @@ def pre_norm(cfg: LlamaConfig, x: jax.Array, layer: Params, name: str
     return rmsnorm(x, layer[name].astype(cdt), cfg.norm_eps)
 
 
+#: what ``ffn_half`` calls its two products where the stream is float32
+#: (``residual_f32``): the names ``remat_block`` keeps them by
+FFN_RESIDUAL_NAMES = ("ffn_gate", "ffn_up")
+
+
+def unit_offset_norm(cfg: LlamaConfig, x: jax.Array, w: jax.Array
+                     ) -> jax.Array:
+    """``x * rsqrt(mean x^2 + eps) * (1 + w)`` in ``x``'s dtype (the unit is
+    added in it: a bfloat16 ``1 + w`` would lose a small ``w``), handed on in
+    the compute dtype."""
+    return rmsnorm(x, 1.0 + w.astype(x.dtype), cfg.norm_eps).astype(
+        cfg.compute_dtype)
+
+
 def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
     """Pre-norm SwiGLU MLP + residual — shared by train and decode paths."""
     cdt = cfg.compute_dtype
     h = pre_norm(cfg, x, layer, "mlp_norm")
+    if cfg.residual_f32:
+        # a trained block whose stream alone is float32: the products'
+        # results stay in the compute dtype (at 16k tokens a float32 gate
+        # and up are 1.4 GB a layer), the sum is taken in the stream's
+        gate, up = (checkpoint_name(h @ layer[w].astype(cdt), name)
+                    for w, name in zip(("w_gate", "w_up"), FFN_RESIDUAL_NAMES))
+        gate = jax.nn.silu(gate)
+        return x + ((gate * up) @ layer["w_down"].astype(cdt)).astype(x.dtype)
     if x.dtype != cdt:
         # a residual stream kept wider than the compute dtype
         # (``models/sambay.py``): every product is summed to the stream's
@@ -329,11 +384,51 @@ def on_residual(cfg: LlamaConfig, branch: jax.Array) -> jax.Array:
     return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
 
 
+def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
+             sin: jax.Array, cos: jax.Array,
+             segment_ids: Optional[jax.Array]) -> jax.Array:
+    """Pre-norm EVA attention + residual (``ops/eva.py``), under the scope
+    ``attn_eva``: the norm, the three projections, RoPE on q and k at their
+    absolute positions, the chunk summaries (``eva_summaries``) and the
+    attention over the window's keys and the earlier windows' summaries
+    (``eva_attend``), ``wo``. The kernels run where ``attn_impl`` is
+    ``"flash"`` and no mesh of several chips is ambient (a Mosaic call is
+    not partitioned); the dense masked form everywhere else."""
+    from ray_tpu.ops import eva
+    from ray_tpu.parallel.context import current_mesh
+
+    if segment_ids is not None:
+        raise NotImplementedError(
+            "segment_ids (packed sequences) under attn_kind='eva': a "
+            "window's summaries would cross document boundaries")
+    if cfg.n_kv_heads != cfg.n_heads:
+        raise NotImplementedError("attn_kind='eva' summarises a head's own "
+                                  "keys: n_kv_heads must equal n_heads")
+    b, s, _ = x.shape
+    hq, hd, cdt = cfg.n_heads, cfg.head_dim, cfg.compute_dtype
+    mesh = current_mesh()
+    kernels = cfg.attn_impl == "flash" and (mesh is None or mesh.size == 1)
+    with jax.named_scope("attn_eva"):
+        h = pre_norm(cfg, x, layer, "attn_norm")
+        q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
+                       sin, cos)
+        k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hq, hd),
+                       sin, cos)
+        v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hq, hd)
+        attn = eva.eva_attention(
+            q, k, v, layer["eva_phi"].astype(cdt), layer["eva_mu"].astype(cdt),
+            window=cfg.eva_window, chunk=cfg.eva_chunk,
+            impl="pallas" if kernels else "xla")
+        out = attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
+        return x + out.astype(x.dtype)
+
+
 def _block(cfg: LlamaConfig, x: jax.Array, layer: Params,
            sin: jax.Array, cos: jax.Array,
            segment_ids: Optional[jax.Array]) -> jax.Array:
     """One decoder block: pre-norm attn + pre-norm SwiGLU MLP."""
-    x = attention_half(cfg, x, layer, sin, cos, segment_ids)
+    mixer = eva_half if cfg.attn_kind == "eva" else attention_half
+    x = mixer(cfg, x, layer, sin, cos, segment_ids)
     return ffn_half(cfg, x, layer)
 
 
@@ -409,6 +504,12 @@ def refuse_trained_only(cfg: LlamaConfig) -> None:
     (``generate.init_cache``), which every serving constructor does."""
     kinds = sorted(set(getattr(cfg, "layer_kinds", ()))
                    & set(TRAINED_ONLY_KINDS))
+    if getattr(cfg, "attn_kind", "full") == "eva":
+        raise NotImplementedError(
+            "layers of kind ['eva'] are trained only (models/llama.py's "
+            "dense training block): no served block keeps a window buffer "
+            "that empties every eva_window positions beside a store of "
+            "chunk summaries that is read a window late")
     if kinds:
         raise NotImplementedError(
             f"layers of kind {kinds} are trained only (models/moe.py's "
@@ -429,13 +530,26 @@ def remat_block(cfg: LlamaConfig, fn):
     layer) carries no such name and keeps what the dots policy keeps, which
     in a ``kda`` layer is its projections' results too (q, k, v and the two
     low-rank gates, ~55 KB a token): recomputing them would cost a third
-    more of the layer's products and the memory is there."""
+    more of the layer's products and the memory is there. An ``eva`` block
+    keeps by name alone (below)."""
     if not cfg.remat:
         return fn
     from ray_tpu.ops import kda
     from ray_tpu.ops.pallas import flash
 
     policies = jax.checkpoint_policies
+    if cfg.attn_kind == "eva":
+        from ray_tpu.ops import eva
+
+        # at 16k tokens and EvaByte's widths the dots policy's block does
+        # not fit beside four layers' state (15.42 GiB of 15.75 by the
+        # described-chip compile): this block keeps by name what its
+        # backward reads and no product recomputes, q, k, v as the kernels
+        # take them, the summaries, the kernels' o and lse, the
+        # feed-forward's gate and up (14.89 GiB), and rebuilds the norms,
+        # SiLU and the residual's float32 copies
+        return jax.checkpoint(fn, policy=policies.save_only_these_names(
+            *flash.RESIDUAL_NAMES, *eva.RESIDUAL_NAMES, *FFN_RESIDUAL_NAMES))
     return jax.checkpoint(fn, policy=policies.save_from_both_policies(
         policies.dots_with_no_batch_dims_saveable,
         policies.save_only_these_names(*flash.RESIDUAL_NAMES,
@@ -450,6 +564,8 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     cdt = cfg.compute_dtype
     refuse_served_only(cfg)
     x = embed(params, cfg, tokens)
+    if cfg.residual_f32:
+        x = x.astype(jnp.float32)
     sin, cos = rope_angles(tokens.shape[1], cfg.head_dim, cfg.rope_theta, cdt)
 
     if cfg.pipeline_axis is not None:
@@ -458,7 +574,10 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         body = lambda x, layer: (_block(cfg, x, layer, sin, cos, segment_ids), None)
         x, _ = jax.lax.scan(remat_block(cfg, body), x, params["layers"])
 
-    x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
+    if cfg.norm_unit_offset:
+        x = unit_offset_norm(cfg, x, params["final_norm"])
+    else:
+        x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"]).astype(cdt)
     return x, head
 
@@ -489,6 +608,9 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg: LlamaConfig) -> ja
     head = head_for_loss_loop(
         head, sharding_rules(cfg.pipeline_axis is not None), cfg,
         targets.shape[1])
+    if cfg.n_pred_heads > 1:
+        return multi_head_ce(x, head, targets, batch.get("loss_mask"),
+                             cfg.loss_chunk, cfg.n_pred_heads)
     return chunked_ce(x, head, targets, batch.get("loss_mask"),
                       cfg.loss_chunk)
 
@@ -648,6 +770,52 @@ def chunked_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1)
 
 
+def multi_head_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
+                  mask: Optional[jax.Array], chunk: int, n: int) -> jax.Array:
+    """Cross entropy of ``n`` prediction heads from final hiddens x
+    [B, S, d]: ``head`` [d, n * V], its columns ``i V .. (i + 1) V`` the
+    logits for the token ``1 + i`` positions on; ``targets`` [B, S] the next
+    tokens (head 0's). Head ``i``'s target at position ``t`` is
+    ``targets[t + i]``, which lies inside the row for ``S - i`` positions;
+    ``mask`` [B, S] (None: all ones) is read at the target's place. The mean
+    over the heads of each one's mean over its counted positions; float32
+    logits, summed from the compute dtype's operands. In ``chunk``-position
+    slices under a fully rematted loop where ``chunked_ce`` would loop."""
+    B, S = targets.shape
+    V = head.shape[1] // n
+    reach = jnp.arange(S)[:, None] + jnp.arange(n)[None, :]      # [S, n]
+    live = jnp.ones((B, S), jnp.float32) if mask is None \
+        else mask.astype(jnp.float32)
+    past = ((0, 0), (0, n - 1))
+    ts = jnp.pad(targets, past)[:, reach]                        # [B, S, n]
+    ms = jnp.pad(live, past)[:, reach]
+
+    def nll(xc, tc, mc):
+        logits = jnp.matmul(xc, head, preferred_element_type=jnp.float32)
+        logp = jax.nn.log_softmax(logits.reshape(*xc.shape[:-1], n, V), -1)
+        took = jnp.take_along_axis(logp, tc[..., None], axis=-1)[..., 0]
+        lead = tuple(range(took.ndim - 1))
+        return -(took * mc).sum(lead), mc.sum(lead)              # [n], [n]
+
+    n_chunks = _loss_chunks(S, chunk)
+    if n_chunks:
+        def cut(a):
+            return a.reshape(B, n_chunks, chunk, *a.shape[2:]).swapaxes(0, 1)
+
+        def chunk_nll(carry, sl):
+            total, count = nll(*sl)
+            return (carry[0] + total, carry[1] + count), None
+
+        body = jax.checkpoint(
+            chunk_nll, policy=jax.checkpoint_policies.nothing_saveable)
+        zeros = jnp.zeros((n,), jnp.float32)
+        (total, count), _ = jax.lax.scan(body, (zeros, zeros),
+                                         (cut(x), cut(ts), cut(ms)))
+    else:
+        total, count = nll(x, ts, ms)
+    return (total / jnp.maximum(count, 1)).mean()
+
+
 def sharding_rules(pipeline: bool = False) -> ShardingRules:
     """Param partitioning over the (pp, dp, fsdp, tp) mesh (scaling-book
     layout).
@@ -681,6 +849,7 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
         (r"layers/w_(gate|up)$", P(None, "fsdp", "tp")),
         (r"layers/w_down$", P(None, "tp", "fsdp")),
         (r"layers/.*norm", P(None)),
+        (r"layers/eva_(phi|mu)$", P(None, "tp", None)),
         (r"norm", P()),
     ])
 

@@ -1217,6 +1217,16 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"decayed products: "
                   + ("a Pallas kernel pair" if kp["impl"] == "pallas_grams"
                      else "XLA") + f" ({kp['impl']})")
+        ep = summ.get("eva_plan") or {}
+        if ep:
+            print(f"  eva: {ep['windows']} window(s) of {ep['window']}, "
+                  f"{ep['chunks']} chunks of {ep['chunk']} a row, a query "
+                  f"sees at most {ep['summaries_seen']} summaries, "
+                  f"{ep['heads']} heads of {ep['head_dim']}; score tiles "
+                  f"({ep['block']} rows x {ep['block']} keys or "
+                  f"{ep['summary_block']} summaries) visited / needed "
+                  f"{ep['tiles_visited']} / {ep['tiles_needed']} a head "
+                  f"({ep['impl']})")
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):
             held = routing.get("moe_held", 0)

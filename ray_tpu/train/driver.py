@@ -113,10 +113,10 @@ class StepDriver:
             self.recorder: Optional[Any] = TrainRecorder()
         except Exception:  # noqa: BLE001 — observability must not block
             self.recorder = None
-        # the flash kernels' tilings and the chunked delta rule's plan,
-        # noted as a launch traces them: the recorder's own list and dict,
-        # so a program compiled later shows too
-        from ray_tpu.ops import kda
+        # the flash kernels' tilings, the chunked delta rule's plan and EVA
+        # attention's, noted as a launch traces them: the recorder's own
+        # list and dicts, so a program compiled later shows too
+        from ray_tpu.ops import eva, kda
         from ray_tpu.ops.pallas import flash
 
         rec = self.recorder
@@ -124,7 +124,8 @@ class StepDriver:
         @contextlib.contextmanager
         def noting_plans():
             with flash.noting_plans(rec.flash_plans if rec is not None else []), \
-                    kda.noting_plan(rec.kda_plan if rec is not None else {}):
+                    kda.noting_plan(rec.kda_plan if rec is not None else {}), \
+                    eva.noting_plan(rec.eva_plan if rec is not None else {}):
                 yield
 
         self._noting_plans = noting_plans

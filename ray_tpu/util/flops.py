@@ -60,11 +60,20 @@ def _attention_madds(cfg, seq: int) -> float:
     (half the square; less the triangle below the band for ``window``)
     times the widths of the score and value products, which latent
     attention (``mla``) has apart; for ``kda`` the chunked form's, five
-    products of chunk x width and three of width x width a head."""
-    kinds = getattr(cfg, "layer_kinds", ()) or ("full",) * cfg.n_layers
+    products of chunk x width and three of width x width a head; for
+    ``eva`` (a dense config's ``attn_kind``) the pairs a query sees, its
+    window's causal half and one summary a chunk of every earlier window,
+    and the pooling that makes a summary (a key and a value a position)."""
+    kinds = getattr(cfg, "layer_kinds", ()) or (
+        getattr(cfg, "attn_kind", "full"),) * cfg.n_layers
     w = min(getattr(cfg, "sliding_window", None) or seq, seq)
 
     def layer(kind: str) -> float:
+        if kind == "eva":
+            from ray_tpu.ops.eva import visible_pairs
+
+            pairs = sum(visible_pairs(seq, cfg.eva_window, cfg.eva_chunk))
+            return cfg.n_heads * cfg.head_dim * (2.0 * pairs / seq + 2)
         if kind == "kda":
             from ray_tpu.ops.kda import CHUNK
 

@@ -140,6 +140,11 @@ class TrainRecorder(RecorderCore):
         # heads, d_k, d_v, boundary_state_bytes, impl), static like the list
         # above; a step without a ``kda`` layer leaves it empty
         self.kda_plan: Dict[str, Any] = {}
+        # what the step's EVA attention does at its shape
+        # (``ops/eva.noting_plan``: windows, chunks, summaries_seen, block,
+        # tiles_visited, tiles_needed, impl), static likewise; a step
+        # without an ``eva`` mixer leaves it empty
+        self.eva_plan: Dict[str, Any] = {}
         # what the driver's plan and compiled step say of themselves, static
         # like the list above: how the plan placed a sparse model's expert
         # matrices (``moe.expert_placement``: "expert" or "model_dim"; None
@@ -346,9 +351,10 @@ class TrainRecorder(RecorderCore):
         from the first one's start to the last one's end on the wall clock,
         and their counters, folded and launch by launch in order
         (``per_launch``, for a reader that wants some of them: a window
-        without its warm-up). What the trainer's process keeps of a run
-        once the worker is gone (``JaxTrainer`` records it as the
-        ``train_launches`` span); None before any launch finished."""
+        without its warm-up), and the step's ``eva_plan`` where it has one.
+        What the trainer's process keeps of a run once the worker is gone
+        (``JaxTrainer`` records it as the ``train_launches`` span); None
+        before any launch finished."""
         with self._lock:
             recs = [r for r in self._launches if "t_done" in r]
         if not recs:
@@ -357,6 +363,7 @@ class TrainRecorder(RecorderCore):
                 "t0": min(r["t"] for r in recs),
                 "t1": max(r["t_done"] for r in recs),
                 "per_launch": [dict(r.get("counters") or {}) for r in recs],
+                **({"eva_plan": dict(self.eva_plan)} if self.eva_plan else {}),
                 **self._fold_counters(recs)}
 
     @staticmethod
@@ -436,6 +443,7 @@ class TrainRecorder(RecorderCore):
             "window_launches": len(recs),
             "flash_plans": [dict(p) for p in self.flash_plans],
             "kda_plan": dict(self.kda_plan),
+            "eva_plan": dict(self.eva_plan),
             "expert_placement": self.expert_placement,
             "collectives": {k: dict(v) for k, v in
                             (self.collectives or {}).items()},

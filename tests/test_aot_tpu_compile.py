@@ -877,6 +877,46 @@ def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys)
     assert mem.argument_size_in_bytes > 3.3 * 2**30   # 602M x 6 bytes
 
 
+# EvaByte at its published widths as its cell trains it
+# (benchmark/configs/evabyte.json): the first four of 32 layers, the whole
+# vocabulary of 320, eight prediction heads
+CFG_EVABYTE = llama.LlamaConfig(
+    vocab_size=320, d_model=4096, n_layers=4, n_heads=32, n_kv_heads=32,
+    d_ff=11008, max_seq_len=16384, rope_theta=100000.0,
+    param_dtype=jnp.bfloat16, attn_impl="flash", loss_chunk=256,
+    attn_kind="eva", eva_window=2048, eva_chunk=16, norm_unit_offset=True,
+    residual_f32=True, n_pred_heads=8)
+
+
+def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys):
+    """b1 x s16384, K=1, four layers on one described chip: Mosaic takes
+    EVA attention's four kernels with their scalar-prefetched lists of
+    visits (eight windows of two 1,024-row blocks, summary blocks of 128),
+    each carries the name ``benchmark/kernels/eva_attn.py`` costs it by, the
+    backward runs no second forward kernel (the remat block keeps ``o`` and
+    ``lse`` under ``flash.RESIDUAL_NAMES``), and the step fits the chip's
+    15.75 GiB with the 0.6 GiB ISSUE 52 asked for to spare."""
+    from benchmark.kernels import eva_attn as cost
+
+    mesh = make_mesh(MeshConfig(), topo.devices[:1])
+    compiled = _compile_fused_step(llama, CFG_EVABYTE, mesh, 1, 1, 16384)[2]
+    customs = [line.strip() for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    shapes = [cost.call_shape(line) for line in customs]
+    assert all(shapes), customs
+    assert sorted(shapes) == sorted(
+        [(kind, 32, 16384, 128, 2048, 16, 2)
+         for kind in ("fwd", "dq", "dkv", "dsum")]), shapes
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nevabyte b1 x s16384, 4 layers: temporaries "
+              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
+              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, peak "
+              f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
+    assert mem.peak_memory_in_bytes < 15.15 * 2**30   # 14.89 at PR 52
+    assert mem.argument_size_in_bytes > 4.5 * 2**30   # 821M x 6 bytes
+
+
 def test_libtpu_accepts_the_perf_flags():
     """libtpu aborts the process on a flag it does not know, and every
     worker passes ``TPU_PERF_FLAGS``. Its flags are parsed when the library

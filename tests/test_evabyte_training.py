@@ -1,0 +1,456 @@
+"""EvaByte's block through the training path (``models/llama.py`` with
+``attn_kind="eva"``, ``ops/eva.py``, ``ops/pallas/eva_attn.py``) at a small
+size on the CPU, seeded: hidden 64, 4 heads of 16, window 32, chunk 4, 8
+prediction heads over 320 ids, sequences of 128 (four whole windows) and 112
+(the last window part full). Held to the benchmark's plain reference
+(``benchmark/families/multibyte_eva.py``, which imports nothing of
+``ray_tpu``): the logits of all eight heads, the loss, every parameter's
+gradient; and to what the mechanism says of itself: the summaries against a
+loop, window 0 against plain causal attention, a moved key against the exact
+part alone, every query's visible set and the kernels' visits against
+brute-force lists.
+
+Tolerances. The program is traced in float32 here (operands, sums and
+parameters), so the two differ by the order of their float32 sums alone: the
+kernels' running softmax against one softmax a row, the loss in chunks
+against one mean, 1/sqrt(d) applied to the scores against to q. Logits of
+magnitude ~1 agree to 2e-5 (a few hundred float32 roundings of 6e-8 through
+two layers), the loss to 2e-6 of itself, a gradient to 2e-5 of its largest
+entry. One bf16 case holds the cell's own dtypes to 2e-2: bf16's 3 decimal
+digits through two layers.
+"""
+
+import dataclasses
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmark.lib import spec  # noqa: E402
+from ray_tpu.models import generate, llama  # noqa: E402
+from ray_tpu.ops import eva  # noqa: E402
+from ray_tpu.ops.attention import mha  # noqa: E402
+from ray_tpu.ops.pallas import eva_attn  # noqa: E402
+from ray_tpu.parallel import train_step as ts  # noqa: E402
+from ray_tpu.util import flops  # noqa: E402
+
+WINDOW, CHUNK, HEADS, WIDTH, VOCAB, PRED = 32, 4, 4, 16, 320, 8
+HF = {"attention_class": "eva", "chunk_size": CHUNK, "window_size": WINDOW,
+      "fp32_skip_add": True, "norm_add_unit_offset": True, "hidden_size": 64,
+      "intermediate_size": 128, "num_attention_heads": HEADS,
+      "num_key_value_heads": HEADS, "num_hidden_layers": 2,
+      "num_pred_heads": PRED, "rms_norm_eps": 1e-5, "rope_theta": 100000,
+      "tie_word_embeddings": False, "vocab_size": VOCAB}
+CFG_FILE = {"config": HF, "assumed": {}}
+SEQS = (128, 112)
+IMPLS = ("flash", "xla")
+
+
+@pytest.fixture(scope="module")
+def family():
+    return spec.load_family("multibyte_eva")
+
+
+def _cfg(family, impl="flash", seq=128, dtype=jnp.float32, loss_chunk=32):
+    cfg = family.program_config(CFG_FILE, 2, max_seq_len=seq, attn_impl=impl,
+                                loss_chunk=loss_chunk)
+    return dataclasses.replace(cfg, param_dtype=dtype, compute_dtype=dtype)
+
+
+def _params(family):
+    """Seeded weights with the norms' offsets, ``phi`` and ``mu`` moved off
+    their small starts, so that none of them is a bystander."""
+    cfg = _cfg(family)
+    params = family.init_params(jax.random.key(7), cfg)
+    keys = iter(jax.random.split(jax.random.key(8), 8))
+    layers = params["layers"]
+    for name in ("attn_norm", "mlp_norm"):
+        layers[name] = 0.1 * jax.random.normal(next(keys), layers[name].shape)
+    params["final_norm"] = 0.1 * jax.random.normal(next(keys), (64,))
+    for name in ("eva_phi", "eva_mu"):
+        layers[name] = 0.5 * jax.random.normal(next(keys), layers[name].shape)
+    return params
+
+
+def _tokens(seq):
+    return jax.random.randint(jax.random.key(seq), (2, seq + 1), 0, VOCAB)
+
+
+@pytest.fixture(scope="module")
+def both(family):
+    """{(impl, seq): the program's (loss, grads)} and {seq: the
+    reference's}, each computed once."""
+    params = _params(family)
+    program, reference = {}, {}
+    for seq in SEQS:
+        batch = {"tokens": _tokens(seq)}
+        for impl in IMPLS:
+            cfg = _cfg(family, impl, seq, loss_chunk=32 if seq == 128 else 0)
+            program[impl, seq] = jax.jit(jax.value_and_grad(
+                lambda p: llama.lm_loss(p, batch, cfg)))(params)
+        reference[seq] = family.loss_and_grads(params, batch["tokens"], CFG_FILE)
+    return params, program, reference
+
+
+# ---- program against reference ---------------------------------------------------
+
+@pytest.mark.parametrize("seq", SEQS)
+@pytest.mark.parametrize("impl", IMPLS)
+def test_logits_of_all_eight_heads_agree_with_the_reference(family, impl, seq):
+    params, tokens = _params(family), _tokens(seq)[:, :-1]
+    got = llama.forward(params, tokens, _cfg(family, impl, seq))
+    want = family.logits(params, tokens, CFG_FILE)
+    assert got.shape == want.shape == (2, seq, PRED * VOCAB)
+    assert float(jnp.abs(want).max()) > 1.0
+    # per head, so that no head hides behind another's scale
+    per_head = jnp.abs(got - want).reshape(2, seq, PRED, VOCAB).max((0, 1, 3))
+    assert float(per_head.max()) < 2e-5, per_head
+
+
+@pytest.mark.parametrize("seq", SEQS)
+@pytest.mark.parametrize("impl", IMPLS)
+def test_the_loss_agrees_with_the_reference(both, impl, seq):
+    _, program, reference = both
+    got, want = float(program[impl, seq][0]), float(reference[seq][0])
+    assert 5.0 < want < 7.5      # about log 320 of random weights
+    assert abs(got - want) < 2e-6 * want, (got, want)
+
+
+LEAVES = ["embed", "final_norm", "lm_head"] + ["layers/" + n for n in (
+    "attn_norm", "eva_mu", "eva_phi", "mlp_norm", "w_down", "w_gate", "w_up",
+    "wk", "wo", "wq", "wv")]
+
+
+@pytest.mark.parametrize("leaf", LEAVES)
+def test_every_gradient_agrees_with_the_reference(both, leaf):
+    """Both implementations and both sequence lengths a leaf: the kernels'
+    backward (``dq``, ``dkv``, ``dsum``) and the summaries' autodiff against
+    ``jax.grad`` through the reference's dense softmax."""
+    params, program, reference = both
+
+    def at(tree):
+        for part in leaf.split("/"):
+            tree = tree[part]
+        return tree
+
+    assert at(params).size   # the tree has no leaf this list leaves out
+    assert sorted(LEAVES) == sorted(
+        "/".join(str(k.key) for k in path)
+        for path, _ in jax.tree_util.tree_leaves_with_path(params))
+    for seq in SEQS:
+        want = at(reference[seq][1])
+        scale = float(jnp.abs(want).max())
+        assert scale > 0, (leaf, seq)
+        for impl in IMPLS:
+            worst = float(jnp.abs(at(program[impl, seq][1]) - want).max())
+            assert worst < 2e-5 * scale, (leaf, impl, seq, worst, scale)
+
+
+def test_the_cells_own_dtypes_hold_the_loss(family, both):
+    """bf16 parameters and operands, the float32 stream, as the cell runs."""
+    params, _, reference = both
+    cfg = _cfg(family, "flash", 128, dtype=jnp.bfloat16)
+    low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    got = float(llama.lm_loss(low, {"tokens": _tokens(128)}, cfg))
+    assert abs(got - float(reference[128][0])) < 2e-2 * got
+
+
+def test_one_precision_down_is_another_loss(family, both):
+    """The reference through ``float8_e5m2`` (the loss limit's control) lies
+    further from itself than the program does by orders."""
+    params, _, reference = both
+    want = float(reference[128][0])
+    low = float(family.loss(params, _tokens(128), CFG_FILE,
+                            round_to=jnp.float8_e5m2)["loss"])
+    assert abs(low - want) > 1e-4 * want   # fifty times the limit above
+
+
+# ---- the mechanism by itself ---------------------------------------------------
+
+def _qkv(seq, seed=0, heads=HEADS, width=WIDTH, batch=2):
+    keys = jax.random.split(jax.random.key(seed), 5)
+    q, k, v = (jax.random.normal(key, (batch, seq, heads, width))
+               for key in keys[:3])
+    phi, mu = (0.5 * jax.random.normal(key, (heads, width)) for key in keys[3:])
+    return q, k, v, phi, mu
+
+
+@pytest.mark.parametrize("chunk", [4, 8, 16])
+def test_the_summaries_against_a_loop(chunk):
+    _, k, v, phi, mu = _qkv(64, seed=chunk)
+    heads_first = lambda a: a.transpose(0, 2, 1, 3).reshape(2 * HEADS, 64, WIDTH)
+    ks, vs = eva.summaries(heads_first(k), heads_first(v), jnp.tile(phi, (2, 1)),
+                           jnp.tile(mu, (2, 1)), chunk)
+    assert ks.shape == vs.shape == (2 * HEADS, 64 // chunk, WIDTH)
+    k, v, phi, mu = map(np.asarray, (k, v, phi, mu))
+    for b in range(2):
+        for a in range(HEADS):
+            for j in range(64 // chunk):
+                at = slice(chunk * j, chunk * (j + 1))
+                logit = k[b, at, a] @ phi[a]
+                alpha = np.exp(logit - logit.max())
+                alpha /= alpha.sum()
+                np.testing.assert_allclose(
+                    ks[b * HEADS + a, j], mu[a] + alpha @ k[b, at, a], atol=2e-6)
+                np.testing.assert_allclose(
+                    vs[b * HEADS + a, j], alpha @ v[b, at, a], atol=2e-6)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_a_query_in_window_0_sees_plain_causal_attention(impl):
+    """No summary is visible there, whatever ``phi`` and ``mu`` are."""
+    q, k, v, phi, mu = _qkv(128, seed=1)
+    out = eva.eva_attention(q, k, v, phi, mu, window=WINDOW, chunk=CHUNK,
+                            impl=impl)
+    plain = mha(q[:, :WINDOW], k[:, :WINDOW], v[:, :WINDOW], causal=True)
+    np.testing.assert_allclose(out[:, :WINDOW], plain, atol=2e-6)
+    other = eva.eva_attention(q, k, v, 3 * phi, mu + 1, window=WINDOW,
+                              chunk=CHUNK, impl=impl)
+    np.testing.assert_array_equal(out[:, :WINDOW], other[:, :WINDOW])
+    assert float(jnp.abs(out[:, WINDOW:] - other[:, WINDOW:]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_a_moved_key_reaches_its_own_window_through_the_exact_part_alone(impl):
+    """Key 37 (window 1, chunk 9) moved: queries before it and in window 0
+    are unmoved; the queries of window 1 at or after it see the moved key
+    itself and NOT the moved summary (their outputs are those of the moved
+    key with the old summaries); later windows see it through its chunk's
+    summary alone (the moved summary with the old keys)."""
+    q, k, v, phi, mu = _qkv(128, seed=2)
+    moved = k.at[:, 37].add(1.0)
+    run = lambda k_: eva.eva_attention(q, k_, v, phi, mu, window=WINDOW,
+                                       chunk=CHUNK, impl=impl)
+    before, after = run(k), run(moved)
+    np.testing.assert_array_equal(before[:, :37], after[:, :37])
+    assert float(jnp.abs(after[:, 37:64] - before[:, 37:64]).min(1).max()) > 1e-4
+
+    def dense(keys_from, summaries_from):
+        first = lambda a: a.transpose(0, 2, 1, 3).reshape(2 * HEADS, 128, WIDTH)
+        ks, vs = eva.summaries(first(summaries_from), first(v),
+                               jnp.tile(phi, (2, 1)), jnp.tile(mu, (2, 1)), CHUNK)
+        o = eva._attend_dense(first(q), first(keys_from), first(v), ks, vs,
+                              window=WINDOW, chunk=CHUNK, scale=WIDTH ** -0.5)
+        return o.reshape(2, HEADS, 128, WIDTH).transpose(0, 2, 1, 3)
+
+    np.testing.assert_allclose(after[:, 32:64], dense(moved, k)[:, 32:64],
+                               atol=2e-6)
+    np.testing.assert_allclose(after[:, 64:], dense(k, moved)[:, 64:],
+                               atol=2e-6)
+    assert float(jnp.abs(after[:, 64:] - before[:, 64:]).max()) > 1e-5
+
+
+def _seen_by_hand(seq, window, chunk):
+    """{t: (keys, summaries)} from the rule as it is worded."""
+    return {t: ({m for m in range(seq) if m // window == t // window and m <= t},
+                {j for j in range(seq // chunk)
+                 if (chunk * j) // window < t // window})
+            for t in range(seq)}
+
+
+@pytest.mark.parametrize("seq,window,chunk", [
+    (128, 32, 4), (112, 32, 4), (96, 32, 8), (64, 64, 16), (48, 16, 16)])
+def test_the_visible_set_of_every_query(seq, window, chunk):
+    mask = np.asarray(eva.visible(seq, window, chunk))
+    assert mask.shape == (seq, seq + seq // chunk)
+    for t, (keys, pooled) in _seen_by_hand(seq, window, chunk).items():
+        assert set(np.flatnonzero(mask[t, :seq])) == keys, t
+        assert set(np.flatnonzero(mask[t, seq:])) == pooled, t
+    # the cost files count the same pairs
+    fam = spec.load_family("multibyte_eva")
+    kernel = spec.load_kernels()["eva_attn"]
+    pairs = (int(mask[:, :seq].sum()), int(mask[:, seq:].sum()))
+    assert fam.visible_pairs(seq, window, chunk) == pairs
+    assert kernel.visible_pairs(seq, window, chunk) == pairs
+
+
+@pytest.mark.parametrize("windows,blocks_per_window", [(1, 1), (4, 1), (3, 2),
+                                                       (8, 2), (2, 4)])
+def test_the_kernels_visit_the_tiles_that_hold_a_visible_pair_and_no_other(
+        windows, blocks_per_window):
+    """``eva_attn.visits`` against a brute-force list at a block of 8 rows
+    and a chunk of 4, in all three orders; a q block's (a key block's)
+    visits lie together, the first and last flagged; the diagonal tiles and
+    no others are the masked ones."""
+    block, chunk = 8, 4
+    window = block * blocks_per_window
+    seq, per = windows * window, window // chunk
+    mask = np.asarray(eva.visible(seq, window, chunk))
+    local = {(i, j) for i in range(seq // block) for j in range(seq // block)
+             if mask[i * block:(i + 1) * block, j * block:(j + 1) * block].any()}
+    pooled = {(i, j) for i in range(seq // block) for j in range(windows)
+              if mask[i * block:(i + 1) * block,
+                      seq + j * per:seq + (j + 1) * per].any()}
+    need = eva_attn.tiles_needed(windows, blocks_per_window)
+    assert (need["local"], need["summary"]) == (len(local), len(pooled))
+    for kind in ("fwd", "dq"):
+        v = eva_attn.visits(windows, blocks_per_window, kind)
+        is_sum = v["kind"] == eva_attn.SUMMARY
+        assert set(zip(v["q"][~is_sum], v["l"][~is_sum])) == local
+        assert set(zip(v["q"][is_sum], v["s"][is_sum])) == pooled
+        assert len(v["q"]) == len(local) + len(pooled)
+        diagonal = v["kind"] == eva_attn.DIAGONAL
+        assert (v["q"][diagonal] == v["l"][diagonal]).all()
+        assert (v["l"][v["kind"] == eva_attn.CLEAR]
+                < v["q"][v["kind"] == eva_attn.CLEAR]).all()
+        assert list(v["q"]) == sorted(v["q"])
+        edges = np.flatnonzero(np.diff(v["q"])) + 1
+        assert list(np.flatnonzero(v["first"])) == [0, *edges]
+        assert list(np.flatnonzero(v["last"])) == [*(edges - 1), len(v["q"]) - 1]
+    v = eva_attn.visits(windows, blocks_per_window, "dkv")
+    assert set(zip(v["q"], v["k"])) == local and len(v["q"]) == len(local)
+    assert list(v["k"]) == sorted(v["k"])
+    v = eva_attn.visits(windows, blocks_per_window, "dsum")
+    assert set(zip(v["q"], v["k"])) == pooled and len(v["q"]) == len(pooled)
+    assert (v["kind"] == eva_attn.CLEAR).all()
+    p = eva.plan(seq, HEADS, WIDTH, window, chunk)
+    assert p["windows"] == windows and p["chunks"] == seq // chunk
+    assert p["summaries_seen"] == (windows - 1) * per
+
+
+@pytest.mark.parametrize("blocks_per_window", [2, 4])
+def test_a_window_of_several_blocks_is_the_dense_form(monkeypatch,
+                                                      blocks_per_window):
+    """The published window is two blocks of 1,024: here a window of 32 in
+    blocks of 16 and of 8, values and all five gradients."""
+    monkeypatch.setattr(eva_attn, "_TARGET_BLOCK", WINDOW // blocks_per_window)
+    q, k, v, phi, mu = _qkv(112, seed=3)
+    weigh = jax.random.normal(jax.random.key(9), q.shape)
+
+    def run(impl):
+        return jax.value_and_grad(lambda *a: jnp.sum(weigh * eva.eva_attention(
+            *a, window=WINDOW, chunk=CHUNK, impl=impl)), argnums=range(5))(
+                q, k, v, phi, mu)
+
+    (got, got_grads), (want, want_grads) = run("pallas"), run("xla")
+    assert abs(float(got - want)) < 1e-4
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+# ---- what is refused ----------------------------------------------------------------
+
+@pytest.mark.parametrize("seq", [126, 130])
+def test_a_sequence_of_no_whole_number_of_chunks_is_refused_by_name(family, seq):
+    with pytest.raises(ValueError, match="whole number of chunks of 4 "
+                                         r"\(eva_chunk\)"):
+        llama.forward(_params(family), _tokens(seq)[:, :-1], _cfg(family))
+    with pytest.raises(spec.SpecError, match="whole chunks of 4"):
+        family.logits(_params(family), _tokens(seq)[:, :-1], CFG_FILE)
+
+
+def test_the_serving_constructors_refuse_the_kind_by_name(family):
+    cfg = _cfg(family)
+    with pytest.raises(NotImplementedError, match=r"\['eva'\] are trained only"):
+        generate.init_cache(cfg, 1, 64)
+    with pytest.raises(NotImplementedError, match=r"\['eva'\]"):
+        llama.refuse_trained_only(cfg)
+    llama.refuse_trained_only(llama.PRESETS["debug"])   # and no one else
+    with pytest.raises(NotImplementedError, match="attn_kind='eva'"):
+        llama.lm_loss(_params(family), {
+            "tokens": _tokens(128),
+            "segment_ids": jnp.zeros((2, 128), jnp.int32)}, cfg)
+    with pytest.raises(NotImplementedError, match="n_kv_heads must equal"):
+        llama.forward(_params(family), _tokens(128)[:, :-1],
+                      dataclasses.replace(cfg, n_kv_heads=2))
+
+
+# ---- what the program counts -----------------------------------------------------
+
+def test_the_programs_counts(family):
+    cfg = _cfg(family)
+    params = family.init_params(jax.random.key(0), cfg)
+    assert cfg.num_params() == sum(a.size for a in jax.tree.leaves(params))
+    assert params["lm_head"].shape == (64, PRED * VOCAB)
+    assert params["layers"]["eva_phi"].shape == (2, HEADS, WIDTH)
+    # phi and mu: init_std, cut off at one deviation; norms from zero
+    for name in ("eva_phi", "eva_mu"):
+        leaf = np.asarray(params["layers"][name])
+        assert 0 < np.abs(leaf).max() <= eva.INIT_STD + 1e-9
+    assert not np.asarray(params["final_norm"]).any()
+    # a token's mixer products at s 128: the windows' causal halves and 8
+    # summaries a window before, a score and a value product each, and the
+    # pooling's two sums a position
+    local, pooled = 4 * 32 * 33 // 2, 8 * 32 * (0 + 1 + 2 + 3)
+    assert flops._attention_madds(cfg, 128) == pytest.approx(
+        2 * HEADS * WIDTH * (2 * (local + pooled) / 128 + 2))
+    assert flops._attention_madds(cfg, 128) == pytest.approx(
+        family.attention_flops_per_token(HF, 2, 128))
+    assert flops._attention_madds(llama.PRESETS["debug"], 128) == 2 * (
+        2 * 64 * 4 * 16)
+    rules = llama.sharding_rules()
+    assert tuple(rules.spec_for("layers/eva_phi")) == (None, "tp", None)
+
+
+@pytest.mark.parametrize("impl,visited", [("flash", 10), ("xla", 32)])
+def test_the_recorder_carries_the_eva_plan(family, impl, visited):
+    """``StepDriver`` notes the plan as its launch traces it; the summary,
+    a window's summary and the totals the trainer keeps all carry it."""
+    from ray_tpu.train.driver import StepDriver
+
+    cfg = _cfg(family, impl)
+    opt = ts.default_optimizer(total_steps=100)
+    params = family.init_params(jax.random.key(3), cfg)
+    driver = StepDriver(cfg, opt, steps_per_launch=1)
+    batches = [{"tokens": np.asarray(_tokens(128))} for _ in range(2)]
+    driver.run(params, jax.jit(opt.init)(params), batches)
+    rec = driver.recorder
+    try:
+        deadline = time.time() + 30
+        while time.time() < deadline and rec.summary()["in_flight"]:
+            time.sleep(0.01)
+        plan = rec.summary()["eva_plan"]
+        assert plan == eva.plan(128, HEADS, WIDTH, WINDOW, CHUNK, batch=2,
+                                impl="pallas" if impl == "flash" else "xla")
+        assert (plan["windows"], plan["chunks"], plan["summaries_seen"]) \
+            == (4, 32, 24)
+        assert (plan["tiles_visited"], plan["tiles_needed"]) == (visited, 10)
+        assert rec.window_summary(0.0, 1e18)["eva_plan"] == plan
+        assert rec.launch_totals()["eva_plan"] == plan
+        assert rec.summary()["kda_plan"] == {}
+    finally:
+        rec.close()
+
+
+def test_who_has_no_eva_mixer_never_loads_the_kernels():
+    """A process that traces Mistral's step, flash kernels and all, has not
+    loaded ``ops/pallas/eva_attn.py``; tracing an EVA layer loads it."""
+    code = """if True:
+        import sys
+        import jax, jax.numpy as jnp
+        from ray_tpu.models import llama
+        from ray_tpu.parallel import train_step as ts
+        cfg = llama.LlamaConfig(vocab_size=64, d_model=32, n_layers=1,
+                                n_heads=2, n_kv_heads=2, d_ff=64,
+                                attn_impl="flash")
+        opt = ts.default_optimizer(total_steps=10)
+        step = ts.make_multi_step(cfg, opt, 1)
+        params = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
+        batch = {"tokens": jax.ShapeDtypeStruct((1, 1, 65), jnp.int32)}
+        text = str(jax.make_jaxpr(step._jit)(
+            params, jax.eval_shape(opt.init, params), batch))
+        assert "flash_fwd" in text
+        print("mistral", "ray_tpu.ops.pallas.eva_attn" in sys.modules)
+        import dataclasses
+        cfg = dataclasses.replace(cfg, attn_kind="eva", eva_window=32,
+                                  eva_chunk=4)
+        step = ts.make_multi_step(cfg, opt, 1)
+        params = jax.eval_shape(lambda: llama.init_params(jax.random.key(0), cfg))
+        text = str(jax.make_jaxpr(step._jit)(
+            params, jax.eval_shape(opt.init, params), batch))
+        assert "eva_attn_fwd_bh2_s64_d16_w32_c4" in text
+        print("evabyte", "ray_tpu.ops.pallas.eva_attn" in sys.modules)
+    """
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    done = subprocess.run([sys.executable, "-c", code], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert done.stdout.split() == ["mistral", "False", "evabyte", "True"]

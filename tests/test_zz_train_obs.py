@@ -263,6 +263,7 @@ def test_window_summary_carves_launches():
         # synthetic recorder traced no flash kernel and compiled no step)
         assert rec.window_summary(0.0, 999.0) == {
             "window_launches": 0, "flash_plans": [], "kda_plan": {},
+            "eva_plan": {},
             "expert_placement": None, "collectives": {}, "routing": {}}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
@@ -447,6 +448,11 @@ def test_api_train_and_cli_json(rt_cluster):
             chunk=64, sub_block=16, chunks=256, segments=4, heads=32,
             d_k=128, d_v=128, boundary_state_bytes=536_870_912,
             impl="pallas_grams")
+        rec.eva_plan.update(
+            impl="pallas", batch=1, heads=32, head_dim=128, seq=16384,
+            window=2048, chunk=16, windows=8, chunks=1024,
+            summaries_seen=896, block=1024, summary_block=128,
+            tiles_needed=80, tiles_visited=80)
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
@@ -496,6 +502,10 @@ def test_api_train_and_cli_json(rt_cluster):
                 "heads 128x128, states at the chunks' starts 512 MiB a "
                 "layer, decayed products: a Pallas kernel pair "
                 "(pallas_grams)") in text
+        assert ("eva: 8 window(s) of 2048, 1024 chunks of 16 a row, a query "
+                "sees at most 896 summaries, 32 heads of 128; score tiles "
+                "(1024 rows x 1024 keys or 128 summaries) visited / needed "
+                "80 / 80 a head (pallas)") in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
