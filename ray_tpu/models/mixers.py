@@ -11,9 +11,9 @@ A ``kda`` layer, ``h`` the normed input, H heads of width ``dk = dv``:
   ``k`` L2-normalised over their width, ``q`` times ``dk ** -0.5``;
 - log-decay a channel ``g = -exp(A_log)[head] * softplus((h @ f_down) @
   f_up + dt_bias)`` and step ``beta = sigmoid(h @ wb)``, float32;
-- the recurrence (``ops/kda.py``, chunked; a chunk's two decayed products
-  the Pallas kernel pair of ``ops/pallas/kda_grams.py`` where the plan's
-  ``impl`` says so);
+- the recurrence (``ops/kda.py``, chunked; everything of a chunk that does
+  not read the state the Pallas kernel pair of ``ops/pallas/kda_insides.py``
+  where the plan's ``impl`` says so);
 - ``o = rms(o, o_norm) * sigmoid((h @ g_down) @ g_up + g_bias)`` over each
   head's ``dv``, then ``wo``.
 

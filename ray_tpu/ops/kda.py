@@ -62,30 +62,36 @@ float32; a product takes its operands in the inputs' dtype and accumulates
 in float32, as the rest of the program does (``W``, ``T`` and the state are
 cast on their way into a product; what is added to the state is float32).
 The inverse is formed block by block: a 16-row diagonal block by the finite
-Neumann product ``(I - D)(I + D^2)(I + D^4)(I + D^8)`` in float32, the blocks
-merged by block forward substitution at ``highest``. Not the whole chunk by
-that product: the powers of ``A`` grow binomially before they cancel, by up
-to C(63, 31) ~ 1e18 over 64 rows where keys repeat and nothing decays, and by
-at most C(15, 7) = 6435 over 16, which float32 carries to 4e-4 in that worst
-case and the cast of ``T`` to the products' dtype (2e-3) covers.
+Neumann product ``(I - D)(I + D^2)(I + D^4)(I + D^8)`` in float32 (XLA's
+form) or by forward substitution a column at a time in float32 (the
+kernel's, exact), the blocks merged by block forward substitution at
+``highest``. Not the whole chunk by that product: the powers of ``A`` grow
+binomially before they cancel, by up to C(63, 31) ~ 1e18 over 64 rows where
+keys repeat and nothing decays, and by at most C(15, 7) = 6435 over 16, which
+float32 carries to 4e-4 in that worst case and the cast of ``T`` to the
+products' dtype (2e-3) covers.
 
-One kernel, the rest plain XLA: ``impl`` in the plan a step notes says which
-(``noting_plan``). The two decayed products of a chunk, ``K K^T`` and ``Q
-K^T``, are one Pallas call forward and one backward
-(``ops/pallas/kda_grams.py``, ``impl`` ``"pallas_grams"``: a pair's decay
-inside a diagonal block formed once for both and once for their four
-backward sums) where the shape takes it: the chunk ``CHUNK`` in sub-blocks of
-``SUB_BLOCK`` and ``d_k`` whole lanes of 128. Everywhere else (a chunk
-shrunk to a short sequence, a narrow head, ``impl="xla"`` asked for) they
-are ``_decayed_gram`` twice, ``impl`` ``"xla"``. The kernel's module is
-imported where a step is traced with it and nowhere else. The cumulative
-sum, the inverse, ``W``, ``U``, the walk and the outputs are XLA in both.
-The operations carry scopes of their own, names only, for whoever reads a
-device trace by hand (they lie under the model's ``kda_scan``):
-``kda_grams`` (the two decayed products: the kernel's calls,
-``kda_grams_fwd_..`` and ``kda_grams_bwd_..``, or XLA's fusions),
-``kda_inverse``, ``kda_reweigh`` (``W``, ``U`` and the reweighted ``Q`` and
-``K``), ``kda_walk`` (``N``, ``B`` and the walk), ``kda_out``.
+What is a kernel's and what is plain XLA: ``impl`` in the plan a step notes
+says which (``noting_plan``). ``"pallas_insides"``: everything of a chunk
+that does not read the state (``_insides``: the running sum of the gates,
+the two decayed products, the inverse, ``W``, ``U`` and the reweighted ``Q``
+and ``K``) is one Pallas call forward and one backward
+(``ops/pallas/kda_insides.py``; the running sum never reaches HBM, and the
+forward hands the backward the inverse it formed, 4 MiB a segment), where
+the shape takes it: the chunk ``CHUNK`` in sub-blocks of ``SUB_BLOCK`` and
+both ``d_k`` and ``d_v`` whole lanes of 128. Everywhere else (a chunk shrunk
+to a short sequence, a head of no whole lanes, ``impl="xla"`` asked for)
+``impl`` is ``"xla"`` and ``_insides`` is written out below, the products
+``_decayed_gram`` twice. The kernel's module is imported where a step is
+traced with it and nowhere else. The walk over the chunks and a chunk's
+outputs (``N``, ``B``, ``_walk_states``, ``kda_out``), which read the state,
+and the streams' turn to segments (``_by_segment``) are XLA in both. The
+operations carry scopes of their own, names only, for whoever reads a device
+trace by hand (they lie under the model's ``kda_scan``): ``kda_insides`` (the
+pair's calls, ``kda_insides_fwd_..`` and ``kda_insides_bwd_..``) or, in XLA's
+form, ``kda_grams`` (the two decayed products), ``kda_inverse`` and
+``kda_reweigh`` (``W``, ``U`` and the reweighted ``Q`` and ``K``); then
+``kda_walk`` (``N``, ``B`` and the walk) and ``kda_out``.
 """
 
 from __future__ import annotations
@@ -134,28 +140,29 @@ def plan(seq: int, heads: int, d_k: int, d_v: int, batch: int = 1,
     a short sequence (rounded up to whole sub-blocks); a sub-block that
     does not divide the chunk is the chunk. ``boundary_state_bytes``: the
     float32 states the forward keeps for the backward, one a segment.
-    ``impl``: ``"pallas_grams"`` where the two decayed products are the
-    kernel's (module docstring: the shape takes it and ``impl`` did not ask
-    for ``"xla"``), else ``"xla"``. ``mix``: ``"pallas"`` where the layer
-    round the recurrence (``models/mixers.kda_half``) runs its elementwise
-    chains, ``conv_taps`` taps each, as the kernels of
-    ``ops/pallas/kda_mix.py``: both widths whole lanes of 128, the taps one
-    halo block (``MAX_CONV_TAPS``) and ``impl`` not ``"xla"``; else
-    ``"xla"``."""
+    ``impl``: ``"pallas_insides"`` where everything of a chunk that does not
+    read the state is a kernel pair's (module docstring: what the shape
+    takes, and ``impl`` did not ask for ``"xla"``), else ``"xla"``. ``mix``:
+    ``"pallas"`` where the layer round the recurrence
+    (``models/mixers.kda_half``) runs its elementwise chains, ``conv_taps``
+    taps each, as the kernels of ``ops/pallas/kda_mix.py``: both widths whole
+    lanes of 128, the taps one halo block (``MAX_CONV_TAPS``) and ``impl``
+    not ``"xla"``; else ``"xla"``."""
     sub = min(sub_block, chunk)
     c = min(chunk, -(-max(seq, 1) // sub) * sub)
     if c % sub:
         sub = c
     chunks = -(-seq // c)
     segments = chunks // SEGMENT if chunks % SEGMENT == 0 else 1
+    # either kind of kernel: not kept out, and both widths whole lanes
+    kernels = impl != "xla" and d_k % 128 == 0 and d_v % 128 == 0
     return {"chunk": c, "sub_block": sub, "chunks": chunks,
             "segments": segments, "heads": heads,
             "d_k": d_k, "d_v": d_v,
             "boundary_state_bytes": segments * batch * heads * d_k * d_v * 4,
-            "impl": ("pallas_grams" if impl != "xla" and c == CHUNK
-                     and sub == SUB_BLOCK and d_k % 128 == 0 else "xla"),
-            "mix": ("pallas" if impl != "xla" and d_k % 128 == 0
-                    and d_v % 128 == 0 and conv_taps <= MAX_CONV_TAPS
+            "impl": ("pallas_insides" if kernels and c == CHUNK
+                     and sub == SUB_BLOCK else "xla"),
+            "mix": ("pallas" if kernels and conv_taps <= MAX_CONV_TAPS
                     else "xla")}
 
 
@@ -310,20 +317,20 @@ def _insides(q, k, v, g, beta, sub: int, impl: str):
     [b, h, n, C, dk], v [.., dv], g [.., dk] float32, beta [b, h, n, C]
     float32 -> (W [.., C, dk], U [.., C, dv] float32, Aqk [.., C, C], Qg,
     Kend [.., C, dk], gend [.., dk] float32); the operands of the step's
-    products in the inputs' dtype. ``impl``: the plan's."""
+    products in the inputs' dtype. ``impl``: the plan's (module docstring)."""
     cdt = q.dtype
+    if impl == "pallas_insides":
+        # here and not at the module's top: a process that traces no step
+        # with the kernel never loads it
+        from ray_tpu.ops.pallas import kda_insides
+
+        with jax.named_scope("kda_insides"):
+            return kda_insides.insides(q, k, v, g, beta, sub)
     G = jnp.cumsum(g, axis=-2)
     qf, kf = q.astype(F32), k.astype(F32)
     with jax.named_scope("kda_grams"):
-        if impl == "pallas_grams":
-            # here and not at the module's top: a process that traces no
-            # step with the kernel never loads it
-            from ray_tpu.ops.pallas import kda_grams
-
-            Akk, Aqk = kda_grams.decayed_grams(q, k, G, sub)
-        else:
-            Akk = _decayed_gram(kf, kf, G, sub, True, cdt)
-            Aqk = _decayed_gram(qf, kf, G, sub, False, cdt)
+        Akk = _decayed_gram(kf, kf, G, sub, True, cdt)
+        Aqk = _decayed_gram(qf, kf, G, sub, False, cdt)
         A, Aqk = beta[..., None] * Akk, Aqk.astype(cdt)
     with jax.named_scope("kda_inverse"):
         T = (_unit_lower_inverse(A, sub) * beta[..., None, :]).astype(cdt)

@@ -403,7 +403,7 @@ def _call(sp, batch: int, kernel, what: str, in_specs, out_specs, out_shape,
         interpret=interpret, name=f"kda_mix_{what}_{sp['tag']}")
 
 
-# Jitted, as ``kda_grams``' calls are: a step holds each several times (q, k
+# Jitted, as ``kda_insides``' calls are: a step holds each several times (q, k
 # and v, a layer's forward and its recomputation, every ``kda`` layer) and
 # traces and lowers a body once.
 

@@ -1214,8 +1214,8 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"{kp['heads']} heads {kp['d_k']}x{kp['d_v']}, states at "
                   f"the chunks' starts "
                   f"{kp['boundary_state_bytes'] / 2**20:.0f} MiB a layer, "
-                  f"decayed products: "
-                  + ("a Pallas kernel pair" if kp["impl"] == "pallas_grams"
+                  f"a chunk's insides: "
+                  + ("a Pallas kernel pair" if kp["impl"] == "pallas_insides"
                      else "XLA") + f" ({kp['impl']})")
         ep = summ.get("eva_plan") or {}
         if ep:

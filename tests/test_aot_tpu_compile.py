@@ -15,6 +15,7 @@ import dataclasses
 import math
 import os
 import re
+import shutil
 import subprocess
 import sys
 
@@ -131,27 +132,101 @@ def test_banded_flash_compiles_for_v5e_at_trinitys_shape(topo, window):
             1024, 1024, 30 if window else 36), p
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_the_kda_gram_kernels_compile_for_v5e_at_the_cells_shape(topo, dtype):
-    """A segment of the Kimi Linear cell (32 heads x 8 chunks of 64 x 128):
-    Mosaic takes the kernel pair of ``ops/pallas/kda_grams.py`` (the sums
-    along the lanes, a cotangent's column spread over them, the product with
-    its left operand transposed), each call under the name a trace shows."""
-    from ray_tpu.ops.pallas import kda_grams
+_KDA_INSIDES = """if True:
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.pallas import flash, kda_insides
+    flash._needs_interpret = lambda: False
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    qkv = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), jnp.bfloat16, sharding=one)
+    g = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), jnp.float32, sharding=one)
+    beta = jax.ShapeDtypeStruct((1, 32, 8, 64), jnp.float32, sharding=one)
+    loss = lambda *a: sum((o.astype(jnp.float32) ** 2).sum()
+                          for o in kda_insides.insides(*a, 16))
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qkv, qkv, qkv, g, beta).compile()
+"""
+
+
+@pytest.fixture(scope="module")
+def kda_insides_schedule(topo, tmp_path_factory):
+    """{"fwd" | "bwd": the bundles the compiler schedules for the call}: a
+    segment of the Kimi Linear cell (32 heads x 8 chunks of 64 x 128, bf16)
+    through ``ops/pallas/kda_insides.py``, compiled for a described v5e in a
+    process of its own that starts with ``--xla_jf_dump_to`` (libtpu reads
+    the flag once, writes ``*<call>*schedule-analysis_final_bundles.txt``
+    among much else, and aborts when it is done)."""
+    dump = tmp_path_factory.mktemp("kda_insides_schedule")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump}",
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    done = subprocess.run([sys.executable, "-c", _KDA_INSIDES], env=env,
+                          capture_output=True, text=True, timeout=600)
+    found = {}
+    for name in os.listdir(dump):
+        m = re.search(r"kda_insides_(fwd|bwd)_bh32_n8_c64_k128_v128.*"
+                      r"schedule-analysis_final_bundles", name)
+        if m:
+            with open(os.path.join(dump, name)) as f:
+                found[m.group(1)] = int(re.search(
+                    r"total scheduled bundles:\s+(\d+)", f.read()).group(1))
+    shutil.rmtree(dump, ignore_errors=True)
+    assert sorted(found) == ["bwd", "fwd"], done.stderr[-2000:]
+    return found
+
+
+@pytest.mark.parametrize("way,most", [("fwd", 800), ("bwd", 1300)])
+def test_the_kda_insides_schedule_is_what_the_order_of_writing_buys(
+        kda_insides_schedule, way, most):
+    """Mosaic takes both calls at the cell's shape and dtype, and the
+    schedule holds what ``kda_insides``' order of writing was chosen for
+    (its docstring; PERF.md section 6, PR 53): bundles a chunk and head, the
+    whole call's over the chunks a trip of its loop. Forward 765 with the
+    next chunk's products written between this chunk's sums, two chunks a
+    trip (1,113 with one chunk a trip and nothing between). Backward 1,235
+    with the inverse handed over and four chunks a trip (1,780 with the
+    inverse rebuilt; what writing the next chunk's products between the sums
+    buys there shows on the chip, 10.5 -> 8.6 ms a call, and not in this
+    count). A libtpu that schedules the same text worse, or an edit that
+    undoes the order, fails here before any chip is asked."""
+    from ray_tpu.ops.pallas import kda_insides
+
+    together = kda_insides._together(8, {"fwd": kda_insides._TOGETHER,
+                                         "bwd": kda_insides._TOGETHER_BACK}[way])
+    assert kda_insides_schedule[way] / together <= most
+
+
+def test_the_kda_insides_kernels_compile_for_v5e_in_float32(topo):
+    """The same segment with float32 inputs (the products between sub-blocks
+    and ``W``, ``U`` then take float32 operands): Mosaic takes the pair that
+    holds everything of a chunk but the state (the running sum as three
+    bfloat16 products, the substitution by columns, the merge's products at
+    ``highest`` on operands a lane slice cuts, the inverse handed from the
+    forward to the backward), each call under the name a trace shows, which
+    ``benchmark/kernels/kda_scan.py`` does not take for the whole
+    recurrence."""
+    from ray_tpu.ops.pallas import kda_insides
 
     one = SingleDeviceSharding(topo.devices[0])
-    qk = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), dtype, sharding=one)
-    G = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), jnp.float32, sharding=one)
+    qkv = jax.ShapeDtypeStruct((1, 32, 8, 64, 128), jnp.float32, sharding=one)
+    beta = jax.ShapeDtypeStruct((1, 32, 8, 64), jnp.float32, sharding=one)
 
-    def loss(q, k, G):
-        return sum((a * a).sum() for a in kda_grams.decayed_grams(q, k, G, 16))
+    def loss(*a):
+        return sum((o.astype(jnp.float32) ** 2).sum()
+                   for o in kda_insides.insides(*a, 16))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        qk, qk, G).compile()
-    calls = [re.search(r"kda_grams_(fwd|bwd)_bh32_n8_c64_k128", line).group(1)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qkv, qkv, qkv, qkv, beta).compile()
+    calls = [re.search(r"kda_insides_(fwd|bwd)_bh32_n8_c64_k128_v128",
+                       line).group(1)
              for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert sorted(calls) == ["bwd", "fwd"], calls
+    from benchmark.kernels import kda_scan
+    assert not kda_scan._CALL.match("kda_insides_fwd_bh32_n8_c64_k128_v128")
 
 
 def test_flash_plan_at_the_train_cells_shape():
@@ -835,13 +910,15 @@ def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys)
                        line) for line in customs if "kda_" not in line]
     assert all(calls) and sorted(m.group(1) for m in calls) \
         == sorted(flash.KINDS), calls
-    # the four KDA layers' decayed products are the kernel's, a segment of 8
-    # chunks a call: forward, and backward once more forward (the segment
-    # rebuilt from the state it started with) and the one backward call
-    grams = [re.search(r"kda_grams_(fwd|bwd)_bh32_n8_c64_k128", line)
-             for line in customs if "kda_grams" in line]
-    assert all(grams) and sorted(m.group(1) for m in grams) \
-        == ["bwd"] * 4 + ["fwd"] * 8, grams
+    # everything of the four KDA layers' chunks that does not read the state
+    # is the kernel pair's, a segment of 8 chunks a call: forward, and
+    # backward once more forward (the segment rebuilt from the state it
+    # started with) and the one backward call
+    insides = [re.search(r"kda_insides_(fwd|bwd)_bh32_n8_c64_k128_v128", line)
+               for line in customs if "kda_insides" in line]
+    assert all(insides) and sorted(m.group(1) for m in insides) \
+        == ["bwd"] * 4 + ["fwd"] * 8, insides
+    assert not any("kda_grams" in line for line in customs)
     # and their chains round the recurrence the kernels of
     # ``ops/pallas/kda_mix.py``: a layer's q and k (``conv_unit``), v
     # (``conv``), decay (``decay``) and output (``norm_gate``), each forward, once more forward

@@ -7,9 +7,10 @@ against the benchmark's plain reference
 (``benchmark/families/moonshot_kimi_linear.py``, which imports nothing of
 ``ray_tpu``) on seeded weights; the chip's share against the uncut layer;
 the step under a mesh; the plan a step notes; and who refuses the two kinds.
-The Pallas kernel for a chunk's two decayed products
-(``ops/pallas/kda_grams.py``, interpreted) against the form it replaces at
-128-wide heads, and when a step takes it; the kernels for a layer's
+The Pallas kernel pair for everything of a chunk that does not read the
+state (``ops/pallas/kda_insides.py``, interpreted) against ``_insides``' XLA
+form at 128-wide heads, its routines one by one, and when a step takes it;
+the kernels for a layer's
 elementwise chains outside the recurrence (``ops/pallas/kda_mix.py``,
 interpreted) against the XLA form ``mixers.kda_half`` keeps, and when a
 layer takes them."""
@@ -116,12 +117,12 @@ def _both_forms(chunk, sub):
     (32, 16, 64, 32, 16),
     (64, 16, 100, 16, 16),    # the shipped chunk and sub-block
     (16, 8, 16 * 32, 8, 8),   # four segments of 8 chunks
-    (64, 16, 100, 128, 16)])  # a shape the kernel takes (interpreted here)
+    (64, 16, 100, 128, 16)])  # a wide key, a value of no whole lanes
 def test_the_chunked_form_is_the_recurrence(decay, tol, chunk, sub, seq, dk, dv):
     args, w = _kda_inputs(1, 2, seq, 3, dk, dv, decay)
     p = kda.plan(seq, 3, dk, dv, 2, chunk, sub)
     assert p["segments"] == 1 + 3 * (seq > 500)
-    assert p["impl"] == ("pallas_grams" if dk == 128 else "xla")
+    assert p["impl"] == "xla"   # (the kernel's shapes: section (a') below)
     chunked, chunked_grad, recurrent, recurrent_grad = _both_forms(chunk, sub)
     with jax.default_matmul_precision("highest"):
         got, want = chunked(*args), recurrent(*args)
@@ -135,10 +136,28 @@ def test_the_chunked_form_is_the_recurrence(decay, tol, chunk, sub, seq, dk, dv)
         assert float(jnp.abs(a - b).max()) < 3 * tol * max(scale, 1.0), name
 
 
-def test_a_fast_channel_underflows_to_a_true_zero_and_never_overflows():
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_a_fast_channel_underflows_to_a_true_zero_and_never_overflows(form):
     """Every channel forgets by e^-60 a token: ``exp(-G)`` over a chunk
     would be e^3840. The state carries nothing, so a token's output is its
-    own ``beta (q . k) v``."""
+    own ``beta (q . k) v``; through the kernel for a chunk's insides
+    (``_INSIDES``' shape), ``T`` is ``Diag(beta)`` and ``A_qk`` a token's own
+    ``q . k``."""
+    if form == "kernel":
+        q, k, v, _, beta = _insides_inputs(2, 1.0, shape=_ONE_CHUNK)
+        g = jnp.full(q.shape, -60.0)
+        W, U, Aqk, Qg, Kend, gend = _INSIDES["kernel"][0](q, k, v, g, beta)
+        assert float(jnp.abs(U - beta[..., None] * v).max()) < 1e-6
+        own = jnp.einsum("...cd,...cd->...c", q, k)
+        assert float(jnp.abs(Aqk - own[..., None] * jnp.eye(64)).max()) < 1e-7
+        assert float(jnp.abs(Kend[..., -1, :] - k[..., -1, :]).max()) == 0
+        # e^-120, two rows from the chunk's end, is below float32's least
+        assert not bool(gend.any()) and not bool(Kend[..., :-2, :].any())
+        grads = _INSIDES["kernel"][1](
+            [jnp.ones_like(a) for a in (W, U, Aqk, Qg, Kend, gend)],
+            q, k, v, g, beta)
+        assert all(bool(jnp.isfinite(a).all()) for a in grads)
+        return
     (q, k, v, _, beta), _ = _kda_inputs(2, 1, 70, 2, 16, 16, 1.0)
     g = jnp.full(q.shape, -60.0)
     got = kda.kda_chunked(q, k, v, g, beta)
@@ -149,11 +168,28 @@ def test_a_fast_channel_underflows_to_a_true_zero_and_never_overflows():
     assert bool(jnp.isfinite(grads).all())
 
 
-def test_the_inverse_where_keys_repeat_and_nothing_decays():
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_inverse_where_keys_repeat_and_nothing_decays(form):
     """The worst case of the Neumann product inside a 16-row block: every
     earlier key is this one, beta 1, no decay, so ``A`` is all ones below the
     diagonal and its powers reach C(15, 7) before they cancel. The inverse
-    is the bidiagonal [1, -1]; a tenth less, it is still one to 1e-4."""
+    is the bidiagonal [1, -1]; a tenth less, it is still one to 1e-4.
+    Through the kernel for a chunk's insides (which substitutes where the
+    XLA form multiplies powers) ``U = T V`` reads the same inverse: a
+    token's value less the one before's, and ``(I + 0.9 L) U = 0.9 V``."""
+    if form == "kernel":
+        q, k, v, _, _ = _insides_inputs(6, 1.0, shape=_ONE_CHUNK)
+        k = jnp.broadcast_to(k[..., :1, :], k.shape)   # unit keys, all one
+        g = jnp.zeros(q.shape)
+        U = _INSIDES["kernel"][0](q, k, v, g, jnp.ones(q.shape[:-1]))[1]
+        want = v - jnp.pad(v, [(0, 0)] * 3 + [(1, 0), (0, 0)])[..., :-1, :]
+        assert float(jnp.abs(U - want).max()) < 1e-5
+        U = _INSIDES["kernel"][0](q, k, v, g, jnp.full(q.shape[:-1], 0.9))[1]
+        back = U + 0.9 * jnp.einsum(
+            "ij,...jv->...iv", jnp.tril(jnp.ones((64, 64)), -1), U,
+            precision="highest")
+        assert float(jnp.abs(back - 0.9 * v).max()) < 2e-4
+        return
     ones = jnp.tril(jnp.ones((2, 64, 64)), -1)
     got = kda._unit_lower_inverse(ones, 16)
     assert float(jnp.abs(got[0] - (jnp.eye(64) - jnp.eye(64, k=-1))).max()) < 1e-5
@@ -170,99 +206,166 @@ def test_the_inverse_where_keys_repeat_and_nothing_decays():
     assert float(jnp.abs(mine - want).max()) < 1e-3 * float(jnp.abs(want).max())
 
 
-# ---- (a') the kernel for a chunk's two decayed products -----------------------------
+# ---- (a') the kernel pair for everything of a chunk that reads no state ------------
 
-def _gram_inputs(seed, decay, dtype=jnp.float32, shape=(1, 2, 3, 64, 128)):
-    """q, k and the cumulative log-decay of ``shape`` [b, h, n, C, dk], as
-    ``_kda_inputs`` draws them."""
+_INSIDES_SHAPE = (1, 2, 2, 64, 128)   # batch, heads, chunks, a chunk, a head
+# (an interpreted kernel's compile is most of a case and grows with the
+# chunks a trip of its loop works: two for the float32 comparison, whose
+# inverses lie side by side in one tile; one for every other case)
+_ONE_CHUNK = (1, 2, 1, 64, 128)
+
+
+def _insides_inputs(seed, decay, dtype=jnp.float32, shape=_INSIDES_SHAPE):
+    """``kda._insides``' five arguments [b, h, n, C, ..], as ``_kda_inputs``
+    draws them, ``v`` as wide as ``q``."""
     b, h, n, C, dk = shape
-    (q, k, _, g, _), _ = _kda_inputs(seed, b, n * C, h, dk, 8, decay)
-    cut = lambda a: jnp.moveaxis(a, 2, 1).reshape(shape)
-    return cut(q).astype(dtype), cut(k).astype(dtype), jnp.cumsum(cut(g), -2)
+    args, _ = _kda_inputs(seed, b, n * C, h, dk, dk, decay)
+    q, k, v, g, beta = (jnp.moveaxis(a, 2, 1).reshape(b, h, n, C, *a.shape[3:])
+                        for a in args)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
-def _grams_xla(q, k, G):
-    qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
-    return (kda._decayed_gram(kf, kf, G, 16, True, q.dtype),
-            kda._decayed_gram(qf, kf, G, 16, False, q.dtype))
+def _insides_form(impl):
+    """(the six results, the five cotangents of their sums weighed by ``ws``)
+    of ``kda._insides`` in one form, each jitted once: the tests share a
+    shape, so a compile (the interpreted kernels' is most of a case)."""
+    fn = lambda *a: kda._insides(*a, 16, impl)
 
-
-def _grams_kernel(q, k, G):
-    from ray_tpu.ops.pallas import kda_grams
-
-    return kda_grams.decayed_grams(q, k, G, 16)
-
-
-def _weighed(fn):
-    """(both matrices, the gradients of their sums weighed by ``ws``), each
-    jitted once: the tests below share a shape, so a compile."""
     def loss(ws, *a):
-        return sum((o * w).sum() for o, w in zip(fn(*a), ws))
+        return sum((o.astype(jnp.float32) * w).sum() for o, w in zip(fn(*a), ws))
 
-    return jax.jit(fn), jax.jit(jax.grad(loss, (1, 2, 3)))
-
-
-_KERNEL, _XLA = _weighed(_grams_kernel), _weighed(_grams_xla)
-_GRAM_SHAPE = (1, 2, 3, 64, 128)
+    return jax.jit(fn), jax.jit(jax.grad(loss, range(1, 6)))
 
 
-@pytest.mark.parametrize("decay,tol", [(1e-3, 2e-6), (0.3, 2e-6), (30.0, 2e-6)])
-def test_the_kernel_is_the_form_it_replaces(decay, tol):
-    """Both matrices and every cotangent (``q``, ``k``, ``G``) against
-    ``_decayed_gram`` twice and ``jax.grad`` through it, float32: what
-    differs is the order of the sums."""
-    q, k, G = _gram_inputs(3, decay, shape=_GRAM_SHAPE)
-    ws = [jax.random.normal(jax.random.key(i), (*_GRAM_SHAPE[:4], 64))
-          for i in (1, 2)]
-    with jax.default_matmul_precision("highest"):
-        got, want = _KERNEL[0](q, k, G), _XLA[0](q, k, G)
-        grads, wants = _KERNEL[1](ws, q, k, G), _XLA[1](ws, q, k, G)
-    for name, a, b in zip(("A_kk", "A_qk"), got, want):
-        assert float(jnp.abs(b).max()) > 0.01, name
-        assert float(jnp.abs(a - b).max()) < tol, name
-        # what lies on or above the diagonal (above, for A_qk) is a zero
-        assert not bool(jnp.triu(a, 0 if name == "A_kk" else 1).any()), name
-    for name, a, b in zip("q k G".split(), grads, wants):
-        assert float(jnp.abs(b).max()) > 0.01, name
-        assert float(jnp.abs(a - b).max()) < tol * max(
-            float(jnp.abs(b).max()), 1.0), name
+_INSIDES = {"kernel": _insides_form("pallas_insides"),
+            "xla": _insides_form("xla")}
+_INSIDES_NAMES = "W U A_qk Qg Kend gend".split()
 
 
-def test_the_kernel_takes_its_operands_in_the_inputs_dtype():
-    """bf16 tiles: the products between sub-blocks take bf16 operands as the
-    XLA form's do, so the two agree to the sums' order, and the cotangents
-    come back in the inputs' dtypes (their values at the cell's shape are
-    the chip check's, ``tests/benchmark/kimi_chip_check.py``)."""
-    q, k, G = _gram_inputs(4, 0.3, jnp.bfloat16, (1, 1, 2, 64, 128))
-    for a, b in zip(_grams_kernel(q, k, G), _grams_xla(q, k, G)):
-        assert a.dtype == jnp.float32
-        assert float(jnp.abs(a - b).max()) < 1e-6
-    grads = jax.eval_shape(jax.grad(
-        lambda *a: sum(o.sum() for o in _grams_kernel(*a)), (0, 1, 2)), q, k, G)
-    assert [a.dtype for a in grads] == [jnp.bfloat16, jnp.bfloat16, jnp.float32]
+# float32: what differs is the order of the sums, and of the running sum of
+# the gates first (the kernel's is a product with a triangle of ones), whose
+# rounding at G of minus thousands an exponent's difference carries: the
+# limits are ``test_the_chunked_form_is_the_recurrence``'s but the last: at
+# decay 30 ``G`` reaches -145,000, whose last place is 0.016, and either
+# form's ``Kend`` stands 1e-3 of its size from a float64 one. bfloat16: the
+# kernel rounds ``beta k`` where the XLA form rounds ``k`` and multiplies by
+# beta after the product, and keeps float32 cotangents where JAX rounds them
+# to the operands' dtype: one limit at every decay. (A case a dtype and the
+# decays inside it: an interpreted kernel's compile is most of a case, 27 s
+# at two chunks and 16 s at one, and cases that tier-1's workers take apart
+# would each pay it.)
+@pytest.mark.parametrize("dtype,shape,tols", [
+    (jnp.float32, _INSIDES_SHAPE, {1e-3: 5e-6, 0.3: 5e-5, 30.0: 4e-3}),
+    (jnp.bfloat16, _ONE_CHUNK, {1e-3: 2e-2, 0.3: 2e-2, 30.0: 2e-2})])
+def test_the_insides_kernel_is_the_form_it_replaces(dtype, shape, tols):
+    """All six results and, through ``jax.grad``, all five cotangents against
+    ``_insides``' XLA form, at the three decays of
+    ``test_the_chunked_form_is_the_recurrence``."""
+    for decay, tol in tols.items():
+        args = _insides_inputs(3, decay, dtype, shape)
+        with jax.default_matmul_precision("highest"):
+            want = _INSIDES["xla"][0](*args)
+            ws = [jax.random.normal(jax.random.key(i), o.shape)
+                  for i, o in enumerate(want)]
+            got = _INSIDES["kernel"][0](*args)
+            grads = _INSIDES["kernel"][1](ws, *args)
+            wants = _INSIDES["xla"][1](ws, *args)
+        for name, a, b in zip(_INSIDES_NAMES, got, want):
+            assert (a.dtype, a.shape) == (b.dtype, b.shape), name
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            assert bool(jnp.isfinite(a).all()), (name, decay)
+            assert float(jnp.abs(a - b).max()) <= tol * max(
+                float(jnp.abs(b).max()), 1e-30), (name, decay)
+        assert not bool(jnp.triu(got[2], 1).any())  # A_qk above the diagonal
+        for name, a, b in zip("q k v g beta".split(), grads, wants):
+            assert (a.dtype, a.shape) == (b.dtype, b.shape), name
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            assert float(jnp.abs(b).max()) > 0.01, (name, decay)
+            assert float(jnp.abs(a - b).max()) < 3 * tol * max(
+                float(jnp.abs(b).max()), 1.0), (name, decay)
 
 
-def test_a_fast_channel_through_the_kernel_is_a_true_zero():
-    """Every channel forgets by e^-200 a token, ``G`` down to -12,800 inside
-    a chunk: every pair but a token's own underflows to a true zero, its own
-    is ``q . k``, and no cotangent is anything but finite."""
-    q, k, _ = _gram_inputs(5, 1.0, shape=_GRAM_SHAPE)
-    G = jnp.cumsum(jnp.full(q.shape, -200.0), axis=-2)
-    akk, aqk = _KERNEL[0](q, k, G)
-    assert not bool(akk.any())
-    own = jnp.einsum("...cd,...cd->...c", q, k)
-    assert float(jnp.abs(aqk - own[..., None] * jnp.eye(64)).max()) < 1e-7
-    assert not bool((aqk * (1 - jnp.eye(64))).any())
-    grads = _KERNEL[1]([jnp.ones(akk.shape)] * 2, q, k, G)
-    assert all(bool(jnp.isfinite(a).all()) for a in grads)
-    assert float(jnp.abs(grads[0] - k).max()) < 1e-7    # d(q . k) / dq
+def _routines():
+    from ray_tpu.ops.pallas import kda_insides
+
+    return kda_insides
+
+
+@pytest.mark.parametrize("sub,sums", [(8, 5), (16, 17)])
+def test_every_pair_of_a_block_rides_in_one_sum(sub, sums):
+    """``_packed``: every (vreg of eight rows, column) pair of a diagonal
+    block with a row at or below the diagonal goes through exactly one sum
+    along the lanes, alone or in the dead rows of a partner whose live rows
+    fill them: 17 sums a 16-row block, where a vreg a column takes 24."""
+    pairs = _routines()._packed(sub)
+    assert len(pairs) == sums
+    seen = []
+    for ro, i, a, partner in pairs:
+        assert a == max(i - ro, 0) and i < ro + 8
+        seen.append((ro, i))
+        if partner is not None:
+            assert a and max(partner[1] - partner[0], 0) == 8 - a
+            seen.append(partner)
+    assert sorted(seen) == [(ro, i) for ro in range(0, sub, 8)
+                            for i in range(ro + 8)]
+
+
+@pytest.mark.parametrize("reverse", [False, True])
+def test_the_running_sum_is_float32s_own(reverse):
+    """``_running_sum``: three bfloat16 pieces of the gates against a triangle
+    of ones carry float32's 24 bits. At decay 30 (``G`` to minus tens of
+    thousands) it stands from a float64 sum within two of float32's last
+    places, as ``jnp.cumsum`` does."""
+    g = _insides_inputs(7, 30.0, shape=_ONE_CHUNK)[3][0, 0, 0]   # [64, 128]
+    g64 = np.asarray(g, np.float64)
+    want = np.cumsum(g64[::-1], 0)[::-1] if reverse else np.cumsum(g64, 0)
+    assert np.abs(want).max() > 1e4
+    got = np.asarray(_routines()._running_sum(g, reverse), np.float64)
+    assert (np.abs(got - want) <= 2 ** -22 * np.abs(want)).all()
+
+
+@pytest.mark.parametrize("block", ["drawn", "ones"])
+def test_a_blocks_inverse_by_columns_is_the_inverse(block):
+    """``_block_inverse`` from the columns as the sums leave them (eight
+    rows of a column spread over the lanes; rows not below the column hold
+    anything) against numpy's inverse, on a drawn block and on the Neumann
+    product's worst, all ones: the substitution is exact there."""
+    rng = np.random.default_rng(0)
+    D = 0.3 * rng.normal(size=(16, 16)) if block == "drawn" else np.ones((16, 16))
+    D = np.tril(D, -1).astype(np.float32)
+    held = D + np.triu(rng.normal(size=D.shape)).astype(np.float32)
+    cols = {(ro, i): jnp.broadcast_to(held[ro:ro + 8, i:i + 1], (8, 64))
+            for ro in (0, 8) for i in range(ro + 8)}
+    got = np.asarray(_routines()._block_inverse(cols, 16, 64))
+    want = np.linalg.inv(np.eye(16) + D.astype(np.float64))
+    assert np.abs(got[:, :16] - want).max() < 2e-6 * np.abs(want).max()
+    assert not got[:, 16:].any()
+
+
+def test_a_trip_of_a_kernels_loop_is_whole_tiles_of_the_inverses():
+    """Whatever the chunks a segment: a grid step's chunks divide them, the
+    inverses lie one or two a tile, and a trip of either loop is whole
+    tiles that divide the step; the cell's segment of eight is two chunks a
+    trip forward and four backward."""
+    ki = _routines()
+    for n in range(1, 41):
+        chunks = ki._steps_chunks(n)
+        side = ki._side(chunks)
+        assert n % chunks == 0 and chunks <= 8 and chunks % side == 0
+        for most in (ki._TOGETHER, ki._TOGETHER_BACK):
+            together = ki._together(chunks, most)
+            assert chunks % together == 0 and together % side == 0
+            assert together <= max(most, side)
+    assert [ki._steps_chunks(8), ki._side(8), ki._together(8, ki._TOGETHER),
+            ki._together(8, ki._TOGETHER_BACK)] == [8, 2, 2, 4]
 
 
 @pytest.mark.parametrize("args,kwargs,impl,mix", [
-    ((16384, 32, 128, 128), {}, "pallas_grams", "pallas"),   # the cell's shape
-    # two lane tiles a head; the values' 16 are no whole lanes
-    ((100, 3, 256, 16), {"batch": 2}, "pallas_grams", "xla"),
-    ((100, 3, 256, 128), {"batch": 2}, "pallas_grams", "pallas"),
+    ((16384, 32, 128, 128), {}, "pallas_insides", "pallas"),  # the cell's shape
+    # two lane tiles a head; the values' 16 (and 64) are no whole lanes
+    ((100, 3, 256, 16), {"batch": 2}, "xla", "xla"),
+    ((16384, 32, 128, 64), {}, "xla", "xla"),
+    ((100, 3, 256, 128), {"batch": 2}, "pallas_insides", "pallas"),
     # a shrunk chunk (48); the chains outside take any length
     ((37, 3, 128, 128), {}, "xla", "pallas"),
     ((16384, 32, 64, 64), {}, "xla", "xla"),                 # half a lane tile
@@ -270,10 +373,10 @@ def test_a_fast_channel_through_the_kernel_is_a_true_zero():
     ((16384, 32, 128, 128), {"chunk": 32}, "xla", "pallas"),
     ((16384, 32, 128, 128), {"sub_block": 8}, "xla", "pallas"),
     ((16384, 32, 128, 128), {"impl": "xla"}, "xla", "xla"),  # asked for
-    ((16384, 32, 128, 128), {"impl": "flash"}, "pallas_grams", "pallas"),
+    ((16384, 32, 128, 128), {"impl": "flash"}, "pallas_insides", "pallas"),
     # the rows before a block come in as one block of eight
-    ((16384, 32, 128, 128), {"conv_taps": 9}, "pallas_grams", "pallas"),
-    ((16384, 32, 128, 128), {"conv_taps": 10}, "pallas_grams", "xla")])
+    ((16384, 32, 128, 128), {"conv_taps": 9}, "pallas_insides", "pallas"),
+    ((16384, 32, 128, 128), {"conv_taps": 10}, "pallas_insides", "xla")])
 def test_when_the_plan_takes_the_kernel(args, kwargs, impl, mix):
     assert kda.plan(*args, **kwargs)["impl"] == impl
     assert kda.plan(*args, **kwargs)["mix"] == mix
@@ -288,7 +391,7 @@ def test_when_the_plan_takes_the_kernel(args, kwargs, impl, mix):
     with kda.noting_plan(noted):
         jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, **kwargs))(*shapes)
     assert (noted["impl"], noted["mix"]) == (impl, mix)
-    assert ("kda_grams_fwd" in str(jaxpr)) == (impl == "pallas_grams")
+    assert ("kda_insides_fwd" in str(jaxpr)) == (impl == "pallas_insides")
 
 
 def _a_layer(cfg):
@@ -299,7 +402,7 @@ def _a_layer(cfg):
     return jax.ShapeDtypeStruct((2, 64, cfg.d_model), cfg.compute_dtype), layer
 
 
-@pytest.mark.parametrize("devices,impl,mix", [(1, "pallas_grams", "pallas"),
+@pytest.mark.parametrize("devices,impl,mix", [(1, "pallas_insides", "pallas"),
                                               (2, "xla", "xla")])
 def test_a_mesh_of_several_chips_keeps_the_xla_form(family, devices, impl, mix):
     """Mosaic's calls are not partitioned: where the ambient mesh has more
@@ -366,7 +469,7 @@ def _who_loads_what():
             for line in done.stdout.splitlines()}
 
 
-@pytest.mark.parametrize("module,first_after", [("kda_grams", "recurrence"),
+@pytest.mark.parametrize("module,first_after", [("kda_insides", "recurrence"),
                                                 ("kda_mix", "layer")])
 def test_who_has_no_kda_layer_never_loads_the_kernel(module, first_after):
     """A process that imports ``ray_tpu.ops.kda`` and traces a step with no
@@ -385,7 +488,7 @@ def test_the_plan_of_the_cells_shape():
     assert p == {"chunk": 64, "sub_block": 16, "chunks": 256, "segments": 32,
                  "heads": 32, "d_k": 128, "d_v": 128,
                  "boundary_state_bytes": 32 * 32 * 128 * 128 * 4,
-                 "impl": "pallas_grams", "mix": "pallas"}
+                 "impl": "pallas_insides", "mix": "pallas"}
     assert p["boundary_state_bytes"] == 67_108_864   # a state a segment
     short = kda.plan(37, 3, 16, 24, batch=2, chunk=16, sub_block=4)
     assert (short["chunk"], short["chunks"], short["segments"]) == (16, 3, 1)
@@ -618,16 +721,25 @@ def _kda_half_digest(cfg, mesh=None):
     ({"attn_impl": "xla"}, 1, "f9f42ce4c3d976c4"),
     ({"kda_heads": 4, "kda_head_dim": 16}, 1, "68d9d4ab5ff54e5c"),
     ({}, 2, "f9f42ce4c3d976c4"),
-    ({"kda_conv_taps": 10}, 1, "c639d1f9653399b1")])
+    ({"kda_conv_taps": 10}, 1, "4539a7f0266813dc")])
 def test_where_the_kernels_are_not_taken_the_layer_is_the_parents(
-        family, change, devices, parent):
+        family, change, devices, parent, monkeypatch):
     """With ``attn_impl="xla"``, a head of no whole lanes, a mesh of several
     chips or more taps than a halo block holds, ``kda_half`` and its
     gradient trace to the jaxpr of commit f1edc9a (PR 51's parent, where the
     four digests were taken with the function above at bf16, 2 heads x 128
-    but where a case says otherwise); with none of them they do not."""
+    but where a case says otherwise); with none of them they do not. The
+    last case is about the chains round the recurrence alone: a plan's
+    ``impl`` is held to ``"xla"`` on both sides, because the kernel inside
+    the recurrence at this shape was another at f1edc9a (PR 50's pair for
+    the two decayed products; as the plan stood there the digest was
+    c639d1f9653399b1)."""
     from ray_tpu.parallel.mesh import MeshConfig, make_mesh
 
+    if "kda_conv_taps" in change:
+        whole = kda.plan
+        monkeypatch.setattr(kda, "plan", lambda *a, **kw: {
+            **whole(*a, **kw), "impl": "xla"})
     cfg = dataclasses.replace(_cfg(family), compute_dtype=jnp.bfloat16,
                               kda_heads=2, kda_head_dim=128)
     mesh = None if devices == 1 else make_mesh(MeshConfig(dp=devices),
@@ -916,7 +1028,7 @@ def test_the_programs_counts_are_by_kind(family):
 @pytest.mark.parametrize("width,seq,depth,impl,mix", [
     (16, SEQ, DEPTH, "xla", "xla"),
     # a chunk of 64 and a head of whole lanes
-    (128, 64, 2, "pallas_grams", "pallas")])
+    (128, 64, 2, "pallas_insides", "pallas")])
 def test_the_recorder_carries_the_kda_plan(family, width, seq, depth, impl,
                                            mix):
     from ray_tpu.train.driver import StepDriver

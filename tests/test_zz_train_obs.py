@@ -447,7 +447,7 @@ def test_api_train_and_cli_json(rt_cluster):
         rec.kda_plan.update(
             chunk=64, sub_block=16, chunks=256, segments=4, heads=32,
             d_k=128, d_v=128, boundary_state_bytes=536_870_912,
-            impl="pallas_grams")
+            impl="pallas_insides")
         rec.eva_plan.update(
             impl="pallas", batch=1, heads=32, head_dim=128, seq=16384,
             window=2048, chunk=16, windows=8, chunks=1024,
@@ -500,8 +500,8 @@ def test_api_train_and_cli_json(rt_cluster):
                 "sub-tile 512x512, window 4096") in text
         assert ("kda: 256 chunks of 64 in 4 segment(s), sub-block 16, 32 "
                 "heads 128x128, states at the chunks' starts 512 MiB a "
-                "layer, decayed products: a Pallas kernel pair "
-                "(pallas_grams)") in text
+                "layer, a chunk's insides: a Pallas kernel pair "
+                "(pallas_insides)") in text
         assert ("eva: 8 window(s) of 2048, 1024 chunks of 16 a row, a query "
                 "sees at most 896 summaries, 32 heads of 128; score tiles "
                 "(1024 rows x 1024 keys or 128 summaries) visited / needed "
