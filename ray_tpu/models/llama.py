@@ -423,13 +423,28 @@ def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
         return x + out.astype(x.dtype)
 
 
+def _rope_tables(cfg: LlamaConfig, seq: int) -> Tuple[jax.Array, jax.Array]:
+    """The rotary tables of ``seq`` positions, under the scope of the mixer
+    that reads them (a layer loop rebuilds them where it stands)."""
+    with jax.named_scope("attn_eva" if cfg.attn_kind == "eva"
+                         else "attn_full"):
+        return rope_angles(seq, cfg.head_dim, cfg.rope_theta,
+                           cfg.compute_dtype)
+
+
 def _block(cfg: LlamaConfig, x: jax.Array, layer: Params,
            sin: jax.Array, cos: jax.Array,
            segment_ids: Optional[jax.Array]) -> jax.Array:
-    """One decoder block: pre-norm attn + pre-norm SwiGLU MLP."""
-    mixer = eva_half if cfg.attn_kind == "eva" else attention_half
-    x = mixer(cfg, x, layer, sin, cos, segment_ids)
-    return ffn_half(cfg, x, layer)
+    """One decoder block as a train step runs it: pre-norm attn + pre-norm
+    SwiGLU MLP, each half under its scope of a device trace
+    (``parallel/train_step.STEP_SCOPES``; ``eva_half`` opens its own)."""
+    if cfg.attn_kind == "eva":
+        x = eva_half(cfg, x, layer, sin, cos, segment_ids)
+    else:
+        with jax.named_scope("attn_full"):
+            x = attention_half(cfg, x, layer, sin, cos, segment_ids)
+    with jax.named_scope("mlp"):
+        return ffn_half(cfg, x, layer)
 
 
 def _stage_scan(cfg: LlamaConfig, stage_layers: Params, h: jax.Array,
@@ -438,8 +453,7 @@ def _stage_scan(cfg: LlamaConfig, stage_layers: Params, h: jax.Array,
     stage body shared by the GPipe and 1F1B schedules. RoPE tables are
     recomputed inside (cheap, XLA-hoisted) so the shard_map body closes
     over no tracers."""
-    sin, cos = rope_angles(h.shape[1], cfg.head_dim, cfg.rope_theta,
-                           cfg.compute_dtype)
+    sin, cos = _rope_tables(cfg, h.shape[1])
     body = lambda hh, layer: (_block(cfg, hh, layer, sin, cos, seg), None)
     h, _ = jax.lax.scan(body, h, stage_layers)
     return h
@@ -563,10 +577,11 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
     both in compute dtype — callers project to logits (possibly chunked)."""
     cdt = cfg.compute_dtype
     refuse_served_only(cfg)
-    x = embed(params, cfg, tokens)
-    if cfg.residual_f32:
-        x = x.astype(jnp.float32)
-    sin, cos = rope_angles(tokens.shape[1], cfg.head_dim, cfg.rope_theta, cdt)
+    with jax.named_scope("embed"):
+        x = embed(params, cfg, tokens)
+        if cfg.residual_f32:
+            x = x.astype(jnp.float32)
+    sin, cos = _rope_tables(cfg, tokens.shape[1])
 
     if cfg.pipeline_axis is not None:
         x = _pipelined_layers(params["layers"], x, cfg, segment_ids)
@@ -574,11 +589,13 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,
         body = lambda x, layer: (_block(cfg, x, layer, sin, cos, segment_ids), None)
         x, _ = jax.lax.scan(remat_block(cfg, body), x, params["layers"])
 
-    if cfg.norm_unit_offset:
-        x = unit_offset_norm(cfg, x, params["final_norm"])
-    else:
-        x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"]).astype(cdt)
+    with jax.named_scope("loss_head"):
+        if cfg.norm_unit_offset:
+            x = unit_offset_norm(cfg, x, params["final_norm"])
+        else:
+            x = rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(cdt)
     return x, head
 
 
@@ -602,17 +619,24 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg: LlamaConfig) -> ja
     (``head_for_loss_loop``): no chunk, forward or recomputed, moves the
     head, and its gradient crosses the chips once after the loop.
     """
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    inputs, targets = inputs_and_targets(batch["tokens"])
     x, head = forward_hidden(params, inputs, cfg, batch.get("segment_ids"))
-    head = head_for_loss_loop(
-        head, sharding_rules(cfg.pipeline_axis is not None), cfg,
-        targets.shape[1])
-    if cfg.n_pred_heads > 1:
-        return multi_head_ce(x, head, targets, batch.get("loss_mask"),
-                             cfg.loss_chunk, cfg.n_pred_heads)
-    return chunked_ce(x, head, targets, batch.get("loss_mask"),
-                      cfg.loss_chunk)
+    with jax.named_scope("loss_head"):
+        head = head_for_loss_loop(
+            head, sharding_rules(cfg.pipeline_axis is not None), cfg,
+            targets.shape[1])
+        if cfg.n_pred_heads > 1:
+            return multi_head_ce(x, head, targets, batch.get("loss_mask"),
+                                 cfg.loss_chunk, cfg.n_pred_heads)
+        return chunked_ce(x, head, targets, batch.get("loss_mask"),
+                          cfg.loss_chunk)
+
+
+def inputs_and_targets(tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """A batch's rows [B, S+1] as the S positions that are read and the S
+    that are predicted, cut under the scope ``embed`` (the batch's way in)."""
+    with jax.named_scope("embed"):
+        return tokens[:, :-1], tokens[:, 1:]
 
 
 def loss_and_stats(params: Params, batch: Dict[str, jax.Array],
@@ -647,13 +671,13 @@ def lm_loss_and_grads_1f1b(params: Params, batch: Dict[str, jax.Array],
         raise ValueError("1f1b needs an ambient mesh "
                          "(parallel.context.mesh_scope)")
     cdt = cfg.compute_dtype
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    inputs, targets = inputs_and_targets(batch["tokens"])
     segs = batch.get("segment_ids")
     mask = batch.get("loss_mask")
 
     def embed_fn(embed_w):
-        return embed_w.astype(cdt)[inputs]
+        with jax.named_scope("embed"):
+            return embed_w.astype(cdt)[inputs]
 
     x, embed_vjp = jax.vjp(embed_fn, params["embed"])
 
@@ -661,9 +685,11 @@ def lm_loss_and_grads_1f1b(params: Params, batch: Dict[str, jax.Array],
         return _stage_scan(cfg, stage_layers, h, seg)
 
     def head_loss_fn(head_bundle, y, tgt, msk):
-        y = rmsnorm(y, head_bundle["final_norm"].astype(cdt), cfg.norm_eps)
-        head = head_bundle["lm_head"].astype(cdt)
-        return chunked_ce(y, head, tgt, msk, cfg.loss_chunk)
+        with jax.named_scope("loss_head"):
+            y = rmsnorm(y, head_bundle["final_norm"].astype(cdt),
+                        cfg.norm_eps)
+            head = head_bundle["lm_head"].astype(cdt)
+            return chunked_ce(y, head, tgt, msk, cfg.loss_chunk)
 
     head_bundle = {"final_norm": params["final_norm"],
                    "lm_head": params["lm_head"]}

@@ -50,7 +50,6 @@ counted (``buffer_updates``).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import math
@@ -552,12 +551,12 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
     E, K, H = cfg.n_experts, cfg.top_k, cfg.experts_held
     G = b * s
     C = max(1, int(cfg.capacity_factor * G * K / E))
-    tokens = h.reshape(G, d)
     place = _row_placement(cfg, G)
 
     # scopes are names only: they group the layer's operations in a
     # device trace and change nothing that is computed
     with jax.named_scope("moe_router"):
+        tokens = h.reshape(G, d)
         probs, topk_probs, topk_idx = _route(cfg, tokens, layer)
         if cfg.norm_topk_prob:
             # renormalize the selected gates (Mixtral convention)
@@ -604,11 +603,15 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
 
 def ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params
              ) -> Tuple[jax.Array, jax.Array]:
-    """Pre-norm MoE FFN + residual; returns (hidden, aux_loss)."""
-    h = llama.rmsnorm(x, layer["mlp_norm"].astype(cfg.compute_dtype),
-                      cfg.norm_eps)
+    """Pre-norm MoE FFN + residual; returns (hidden, aux_loss). The norm
+    lies under the scope of its first reader (``moe_router``), the residual
+    sum under ``moe_combine``, whose result it takes."""
+    with jax.named_scope("moe_router"):
+        h = llama.rmsnorm(x, layer["mlp_norm"].astype(cfg.compute_dtype),
+                          cfg.norm_eps)
     ffn, aux, _ = _moe_ffn(cfg, h, layer)
-    return x + ffn, aux
+    with jax.named_scope("moe_combine"):
+        return x + ffn, aux
 
 
 # What one served MoE layer says of its routing, an int32 vector in this
@@ -710,8 +713,10 @@ def served_ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params,
 def _moe_block(cfg: MoEConfig, x: jax.Array, layer: Params,
                sin: jax.Array, cos: jax.Array,
                segment_ids) -> Tuple[jax.Array, jax.Array]:
-    """Shared llama attention half + MoE FFN; returns (hidden, aux_loss)."""
-    x = llama.attention_half(cfg, x, layer, sin, cos, segment_ids)
+    """Shared llama attention half, under the scope ``attn_full``, + MoE
+    FFN; returns (hidden, aux_loss)."""
+    with jax.named_scope("attn_full"):
+        x = llama.attention_half(cfg, x, layer, sin, cos, segment_ids)
     return ffn_half(cfg, x, layer)
 
 
@@ -766,7 +771,8 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
         if dense:
             with jax.named_scope("mlp"):
                 return llama.ffn_half(cfg, x, layer), None, None, None
-        h = llama.rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
+        with jax.named_scope("moe_router"):
+            h = llama.rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
         ffn, aux, routing = _moe_ffn(cfg, h, layer)
         if cfg.n_shared_experts:
             with jax.named_scope("moe_shared"):
@@ -777,8 +783,9 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
             load = jnp.zeros((cfg.n_experts,), jnp.int32).at[
                 routing["topk_idx"].reshape(-1)].add(1)
             kept = routing["keep"].sum(dtype=jnp.int32)
-        return (x + llama.post_norm(cfg, ffn, layer, "mlp_post_norm"), aux,
-                load, kept)
+        with jax.named_scope("moe_combine"):
+            x = x + llama.post_norm(cfg, ffn, layer, "mlp_post_norm")
+        return x, aux, load, kept
 
     return run
 
@@ -813,7 +820,8 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
             aux = aux + a
             counts.append(count)
         load, kept = zip(*counts)
-        return (x, aux), (jnp.stack(load), jnp.stack(kept))
+        with jax.named_scope("moe_router"):
+            return (x, aux), (jnp.stack(load), jnp.stack(kept))
 
     # every stack [repeats, its layers a period, ...]: a stack holds as many
     # layers as the periods have of its kinds
@@ -823,13 +831,6 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
     (x, aux), (load, kept) = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), by_period)
     return x, aux, load.reshape(-1, cfg.n_experts), kept.reshape(-1)
-
-
-def _patterned_scope(cfg: MoEConfig, name: str):
-    """A ``jax.named_scope`` in the patterned form and nothing in the old
-    stack, whose lowered text stays what it was."""
-    return jax.named_scope(name) if cfg.layer_kinds \
-        else contextlib.nullcontext()
 
 
 def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
@@ -847,9 +848,13 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
     cdt = cfg.compute_dtype
     if cfg.layer_kinds:
         llama.refuse_served_only(cfg)
-    x = llama.embed(params, cfg, tokens)
-    sin, cos = llama.rope_angles(tokens.shape[1], cfg.head_dim,
-                                 cfg.rope_theta, cdt)
+    with jax.named_scope("embed"):
+        x = llama.embed(params, cfg, tokens)
+    # the rotary tables under the scope of the layers that read them: a
+    # patterned config rotates inside its window layers alone
+    with jax.named_scope("attn_window" if cfg.layer_kinds else "attn_full"):
+        sin, cos = llama.rope_angles(tokens.shape[1], cfg.head_dim,
+                                     cfg.rope_theta, cdt)
 
     def body(carry, layer):
         x, aux = carry
@@ -859,12 +864,13 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
     stats = {}
     if cfg.layer_kinds:
         x, aux, load, kept = _walk(params, x, cfg, sin, cos, segment_ids)
-        stats = {**routing_counters(cfg, load, kept), "router_load": load}
+        with jax.named_scope("moe_router"):
+            stats = {**routing_counters(cfg, load, kept), "router_load": load}
     else:
         (x, aux), _ = jax.lax.scan(llama.remat_block(cfg, body),
                                    (x, jnp.zeros((), jnp.float32)),
                                    params["layers"])
-    with _patterned_scope(cfg, "loss_head"):
+    with jax.named_scope("loss_head"):
         x = llama.rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"]).astype(cdt)
@@ -882,13 +888,12 @@ def loss_and_stats(params: Params, batch: Dict[str, jax.Array], cfg: MoEConfig
     """Next-token CE + router aux loss (llama's chunked CE reused, its
     loop's head gathered once before it under a mesh), and what the forward
     counted (``forward_hidden``'s ``stats``)."""
-    tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    inputs, targets = llama.inputs_and_targets(batch["tokens"])
     x, head, aux, stats = forward_hidden(params, inputs, cfg,
                                          batch.get("segment_ids"))
-    head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
-                                    targets.shape[1])
-    with _patterned_scope(cfg, "loss_head"):
+    with jax.named_scope("loss_head"):
+        head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
+                                        targets.shape[1])
         ce = llama.chunked_ce(x, head, targets, batch.get("loss_mask"),
                               cfg.loss_chunk)
     return ce + cfg.router_aux_coef * aux, stats

@@ -29,6 +29,19 @@ from ray_tpu.parallel.plan import Plan, compile_plan, compile_step, placement_pl
 from ray_tpu.parallel.sharding import ShardingRules
 
 
+#: the outermost ``jax.named_scope`` of every device operation a train step
+#: writes, whichever stack it trains (``llama._block``, ``moe._moe_block``,
+#: ``moe._patterned_layer``): the batch's way in, a layer's mixer by its
+#: kind, its dense or routed feed-forward, the loss, the update. A device
+#: trace is read by these names (``benchmark/lib/trace.py:scope_of`` keeps
+#: an operation's outermost one, so no scope stands round a scan of layers:
+#: it would swallow every name inside). What carries none is the scans'
+#: own stacking and slicing of their per-layer operands and results.
+STEP_SCOPES = ("embed", "attn_full", "attn_window", "attn_kda", "attn_mla",
+               "attn_eva", "mlp", "moe_router", "moe_dispatch", "moe_experts",
+               "moe_combine", "moe_shared", "loss_head", "optimizer")
+
+
 def default_optimizer(lr: float = 3e-4, weight_decay: float = 0.1,
                       warmup_steps: int = 100, total_steps: int = 10000,
                       grad_clip: float = 1.0) -> optax.GradientTransformation:
@@ -66,15 +79,22 @@ def _value_and_grad(cfg, loss_fn: Optional[Callable]) -> Callable:
     return grad_fn
 
 
-def _apply_updates(cfg, params, updates, stats):
-    """``params`` moved by the optimizer's ``updates``, and ``stats`` as the
-    step's metrics carry them. A family with buffers (leaves the step reads
-    and no gradient moves: ``buffer_updates``) puts their own movement in
-    the optimizer's place and takes what it read out of ``stats``."""
-    move = getattr(model_family(cfg), "buffer_updates", None)
-    if move is not None:
-        updates, stats = move(cfg, params, updates, stats)
-    return optax.apply_updates(params, updates), stats
+def _update(cfg, optimizer, params, opt_state, grads, stats):
+    """What a step does once it has its gradients, under the scope
+    ``optimizer`` of a device trace: ``(params, opt_state, metrics)`` after
+    the optimizer's update (the clip by their global norm, AdamW), with the
+    gradients' norm and ``stats`` among the metrics. A family with buffers
+    (leaves the step reads and no gradient moves: ``buffer_updates``) puts
+    their own movement in the optimizer's place and takes what it read out
+    of ``stats``."""
+    with jax.named_scope("optimizer"):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        move = getattr(model_family(cfg), "buffer_updates", None)
+        if move is not None:
+            updates, stats = move(cfg, params, updates, stats)
+        params = optax.apply_updates(params, updates)
+        gnorm = optax.global_norm(grads)
+    return params, opt_state, {"grad_norm": gnorm, **stats}
 
 
 def init_sharded_state(rng: jax.Array, cfg: llama.LlamaConfig, mesh: Mesh,
@@ -131,10 +151,9 @@ def make_train_step(cfg: llama.LlamaConfig,
 
     def step(params, opt_state, batch):
         loss, grads, stats = grad_fn(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params, stats = _apply_updates(cfg, params, updates, stats)
-        gnorm = optax.global_norm(grads)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm, **stats}
+        params, opt_state, metrics = _update(cfg, optimizer, params,
+                                             opt_state, grads, stats)
+        return params, opt_state, {"loss": loss, **metrics}
 
     if plan is None and mesh is not None:
         plan = compile_plan(cfg, mesh)
@@ -238,11 +257,9 @@ def make_multi_step(cfg: llama.LlamaConfig,
     def body(carry, batch):
         params, opt_state = carry
         loss, grads, stats = grad_fn(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params, stats = _apply_updates(cfg, params, updates, stats)
-        return (params, opt_state), {"loss": loss,
-                                     "grad_norm": optax.global_norm(grads),
-                                     **stats}
+        params, opt_state, metrics = _update(cfg, optimizer, params,
+                                             opt_state, grads, stats)
+        return (params, opt_state), {"loss": loss, **metrics}
 
     def steps(params, opt_state, batches):
         (params, opt_state), metrics = jax.lax.scan(

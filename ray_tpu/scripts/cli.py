@@ -1237,6 +1237,12 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"{routing.get('moe_dropped', 0)} dropped beyond capacity, "
                   f"busiest expert's queue "
                   f"{routing.get('moe_max_expert_rows', 0)} rows")
+        mem = summ.get("step_memory") or {}
+        if mem:
+            print(f"  memory: the compiled step peaks at "
+                  f"{mem['peak_bytes'] / 2**30:.2f} GiB a device "
+                  f"(temporaries {mem['temp_bytes'] / 2**30:.2f}, "
+                  f"arguments {mem['argument_bytes'] / 2**30:.2f})")
         if summ.get("expert_placement"):
             print(f"  experts placed by {summ['expert_placement']}")
         coll = summ.get("collectives") or {}

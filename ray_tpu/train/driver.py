@@ -229,23 +229,24 @@ class StepDriver:
             self._fpt_cache[seq] = fpt
         return tokens * fpt
 
-    def _read_collectives(self, abstract: Tuple[Any, Any, Any]
-                          ) -> Dict[str, Dict[str, int]]:
-        """The fused program's collectives by kind, read off the executable
-        the launch just compiled: lowering the same shapes and placements
-        again is answered from ``jit``'s own caches, with no second
-        compile, and the walk of its text happens once, on the host, while
-        the device runs the launch."""
+    def _read_compiled(self, abstract: Tuple[Any, Any, Any]
+                       ) -> Tuple[Dict[str, Dict[str, int]], Dict[str, int]]:
+        """The fused program's collectives by kind and what it needs of a
+        device's memory, read off the executable the launch just compiled:
+        lowering the same shapes and placements again is answered from
+        ``jit``'s own caches, with no second compile, and the walk of its
+        text happens once, on the host, while the device runs the launch."""
         from ray_tpu.parallel.context import mesh_scope
         from ray_tpu.util import hlo_copies
 
         try:
             with (mesh_scope(self._mesh) if self._mesh is not None
                   else contextlib.nullcontext()):
-                return hlo_copies.collective_inventory(
-                    self._multi._jit.lower(*abstract).compile())
+                compiled = self._multi._jit.lower(*abstract).compile()
+            return (hlo_copies.collective_inventory(compiled),
+                    hlo_copies.step_memory(compiled))
         except Exception:  # noqa: BLE001 — observability must not block
-            return {}
+            return {}, {}
 
     # ---- the loop -----------------------------------------------------------
     def run(self, params: Any, opt_state: Any, batches: Iterable[Any],
@@ -341,7 +342,8 @@ class StepDriver:
                     # (step-profiler convention, so the two can't drift)
                     compiled = self.compile_count() > n_exec
                     if unread is not None:
-                        rec.collectives = self._read_collectives(unread)
+                        rec.collectives, rec.step_memory = \
+                            self._read_compiled(unread)
                     seq = rec.record_launch(
                         t_start=rec_t0, data_wait_s=rec_data_s,
                         h2d_s=h2d_s,

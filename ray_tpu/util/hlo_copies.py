@@ -418,3 +418,24 @@ def collective_inventory(compiled: Any) -> Dict[str, Dict[str, int]]:
         k["runs"] += c["runs"]
         k["bytes"] += c["runs"] * c["bytes"]
     return kinds
+
+
+#: ``step_memory``'s keys and the compiler's names for them
+_MEMORY = {"peak_bytes": "peak_memory_in_bytes",
+           "temp_bytes": "temp_size_in_bytes",
+           "argument_bytes": "argument_size_in_bytes",
+           "output_bytes": "output_size_in_bytes",
+           "alias_bytes": "alias_size_in_bytes"}
+
+
+def step_memory(compiled: Any) -> Dict[str, int]:
+    """What a compiled program needs of one device's memory by the
+    compiler's own account (``memory_analysis()``): its peak, and the
+    temporaries, arguments, results and the results that alias an argument
+    (a donated state) which the peak is made of. Live arrays
+    (``memory_stats``) show the state between launches; the temporaries of
+    a launch show only here. {} where the backend gives no account."""
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return {}
+    return {key: int(getattr(mem, name)) for key, name in _MEMORY.items()}

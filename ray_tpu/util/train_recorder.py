@@ -157,6 +157,11 @@ class TrainRecorder(RecorderCore):
         # driver says which, from the model's family (``COUNTER_MAXIMA``)
         self.counter_maxima: Tuple[str, ...] = ()
         self.collectives: Optional[Dict[str, Dict[str, int]]] = None
+        # what that executable needs of one device's memory by the
+        # compiler's account (``util/hlo_copies.step_memory``: peak_bytes,
+        # temp_bytes, argument_bytes, output_bytes, alias_bytes), read at
+        # the same launch; {} until then and where the backend gives none
+        self.step_memory: Dict[str, int] = {}
         # done-hook plumbing: the step path enqueues, one watcher thread
         # blocks on output buffers FIFO (launch order), so finalize order
         # is monotone and _prev_done_t never runs backwards
@@ -351,7 +356,8 @@ class TrainRecorder(RecorderCore):
         from the first one's start to the last one's end on the wall clock,
         and their counters, folded and launch by launch in order
         (``per_launch``, for a reader that wants some of them: a window
-        without its warm-up), and the step's ``eva_plan`` where it has one.
+        without its warm-up), and the step's ``eva_plan`` and
+        ``step_memory`` where it has them.
         What the trainer's process keeps of a run once the worker is gone
         (``JaxTrainer`` records it as the ``train_launches`` span); None
         before any launch finished."""
@@ -364,6 +370,8 @@ class TrainRecorder(RecorderCore):
                 "t1": max(r["t_done"] for r in recs),
                 "per_launch": [dict(r.get("counters") or {}) for r in recs],
                 **({"eva_plan": dict(self.eva_plan)} if self.eva_plan else {}),
+                **({"step_memory": dict(self.step_memory)}
+                   if self.step_memory else {}),
                 **self._fold_counters(recs)}
 
     @staticmethod
@@ -447,6 +455,7 @@ class TrainRecorder(RecorderCore):
             "expert_placement": self.expert_placement,
             "collectives": {k: dict(v) for k, v in
                             (self.collectives or {}).items()},
+            "step_memory": dict(self.step_memory),
             "routing": self._fold_counters(recs)}
         if not recs:
             return out

@@ -894,15 +894,24 @@ CFG_KIMI = moe.MoEConfig(
     v_head_dim=128)
 
 
-def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys):
+@pytest.fixture(scope="module")
+def kimi_step(topo):
+    """``CFG_KIMI``, b1 x s16384, K=1 on one described chip: (K, batch, seq,
+    compiled)."""
+    mesh = make_mesh(MeshConfig(), topo.devices[:1])
+    return 1, 1, 16384, _compile_fused_step(moe, CFG_KIMI, mesh, 1, 1,
+                                            16384)[2]
+
+
+def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(kimi_step,
+                                                                   capsys):
     """b1 x s16384, K=1, all five layers on one described chip: Mosaic takes
     the flash kernels at 192 / 128 (192 is no multiple of the lanes), the
     step fits the chip's 15.75 GiB with room (what the chunked delta rule
     keeps for its backward is a state a segment, and a segment's temporaries
     are live at once, not the sequence's), and the backward runs no second
     flash forward."""
-    mesh = make_mesh(MeshConfig(), topo.devices[:1])
-    compiled = _compile_fused_step(moe, CFG_KIMI, mesh, 1, 1, 16384)[2]
+    compiled = kimi_step[-1]
     text = compiled.as_text()
     customs = [line for line in text.splitlines()
                if "tpu_custom_call" in line and " custom-call(" in line]
@@ -965,7 +974,17 @@ CFG_EVABYTE = llama.LlamaConfig(
     residual_f32=True, n_pred_heads=8)
 
 
-def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys):
+@pytest.fixture(scope="module")
+def evabyte_step(topo):
+    """``CFG_EVABYTE``, b1 x s16384, K=1 on one described chip: (K, batch,
+    seq, compiled)."""
+    mesh = make_mesh(MeshConfig(), topo.devices[:1])
+    return 1, 1, 16384, _compile_fused_step(llama, CFG_EVABYTE, mesh, 1, 1,
+                                            16384)[2]
+
+
+def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(evabyte_step,
+                                                               capsys):
     """b1 x s16384, K=1, four layers on one described chip: Mosaic takes
     EVA attention's four kernels with their scalar-prefetched lists of
     visits (eight windows of two 1,024-row blocks, summary blocks of 128),
@@ -975,8 +994,7 @@ def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys):
     15.75 GiB with the 0.6 GiB ISSUE 52 asked for to spare."""
     from benchmark.kernels import eva_attn as cost
 
-    mesh = make_mesh(MeshConfig(), topo.devices[:1])
-    compiled = _compile_fused_step(llama, CFG_EVABYTE, mesh, 1, 1, 16384)[2]
+    compiled = evabyte_step[-1]
     customs = [line.strip() for line in compiled.as_text().splitlines()
                if "tpu_custom_call" in line and " custom-call(" in line]
     shapes = [cost.call_shape(line) for line in customs]
@@ -992,6 +1010,116 @@ def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(topo, capsys):
               f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
     assert mem.peak_memory_in_bytes < 15.15 * 2**30   # 14.89 at PR 52
     assert mem.argument_size_in_bytes > 4.5 * 2**30   # 821M x 6 bytes
+
+
+# ---- every operation of a compiled train step under a scope of the program's ---
+
+# step: (its fixture, the scopes its operations must be found under, and the
+# most instructions that may carry none: the scans' and the walk's own
+# stacking and slicing, and fusions or collectives the compiler rooted in an
+# instruction of its own)
+STEP_NAMES = {
+    "1b-fsdp2-tp2": ("flash_step", {"embed", "attn_full", "mlp", "loss_head",
+                                    "optimizer"}, 55, 22),
+    "mixtral-fsdp4": ("mixtral_step", {
+        "embed", "attn_full", "moe_router", "moe_dispatch", "moe_experts",
+        "moe_combine", "loss_head", "optimizer"}, 8, 55),
+    "trinity-window-full": ("trinity_step", {
+        "embed", "attn_window", "attn_full", "moe_router", "moe_dispatch",
+        "moe_experts", "moe_combine", "moe_shared", "loss_head",
+        "optimizer"}, 45, 75),
+    "kimi-kda-mla": ("kimi_step", {
+        "embed", "attn_kda", "attn_mla", "mlp", "moe_router", "moe_dispatch",
+        "moe_experts", "moe_combine", "moe_shared", "loss_head",
+        "optimizer"}, 140, 155),
+    "evabyte": ("evabyte_step", {"embed", "attn_eva", "mlp", "loss_head",
+                                 "optimizer"}, 45, 10)}
+_TIMED = {"fusion", "convolution", "custom-call", "all-gather", "all-reduce",
+          "reduce-scatter", "all-to-all", "collective-permute"}
+# a scan's stacking of its per-layer results and slicing of its operands (and
+# the patterned walk's picking of a layer out of its period's stack): JAX
+# writes them, directly under the loop's body, and no scope can stand there
+_STACKING = re.compile(r"(/(body|closed_call)/(dynamic_update_slice"
+                       r"|dynamic_slice|squeeze|slice|broadcast_in_dim)"
+                       r"|/while)$")
+_OP_NAME = re.compile(r'op_name="(jit\([^"]*)"')
+
+
+def _op_name(line):
+    """An instruction's ``op_name`` where it is a path of the program's (the
+    compiler's own carry none, or a bare word: ``reduce_window_sum``)."""
+    found = _OP_NAME.search(line)
+    return found.group(1) if found else ""
+
+
+def _scopes_of_a_step(compiled):
+    """(instructions by scope, those of the scans' stacking, those the
+    compiler rooted in an instruction of its own, the strays) of a compiled
+    step's fusions, products, kernels and collectives outside fused
+    computations, each stray and exception as (name, shape, op_name)."""
+    from benchmark.lib.trace import scope_of
+
+    module = hlo_copies._Module(compiled.as_text())
+    named, stacking, rootless, strays = {}, [], [], []
+    seen = set()
+    for _, (name, shape, opcode, _, line), _ in module.walk():
+        if opcode.replace("-start", "") not in _TIMED or name in seen:
+            continue
+        seen.add(name)
+        if opcode == "custom-call" and "tpu_custom_call" not in line:
+            continue  # AllocateBuffer, ConcatBitcast: the compiler's buffers
+        op_name = _op_name(line)
+        row = (name, shape.split("{")[0][:48], op_name)
+        scope = scope_of(op_name)
+        if not op_name:
+            # the instruction is the compiler's: a fused computation under
+            # it is judged by the named operations it holds
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            body = module.computations.get(called.group(1), []) if called else []
+            inside = {scope_of(_op_name(inst[4])) for inst in body
+                      if not _STACKING.search(_op_name(inst[4]))}
+            rootless.append(row + (sorted(inside - {"other"}),))
+        elif _STACKING.search(op_name):
+            stacking.append(row)
+        elif scope == "other":
+            strays.append(row)
+        else:
+            named[scope] = named.get(scope, 0) + 1
+    return named, stacking, rootless, strays
+
+
+@pytest.mark.parametrize("step", sorted(STEP_NAMES))
+def test_a_train_step_names_all_of_itself(step, request, capsys):
+    """Every fusion, product, kernel and collective of the compiled step
+    carries an ``op_name`` whose outermost scope, as the benchmark's
+    reduction reads it (``benchmark/lib/trace.py:scope_of``), is one of
+    ``train_step.STEP_SCOPES``: the old dense stack, the old MoE stack, the
+    patterned walk and the EVA block alike, with the embedding, the loss and
+    the optimizer's update. Fails on the parent, whose old stacks named
+    nothing (``other`` was 89-91% of Mistral's step). What cannot be named
+    is printed with its shape and held to a count: the scans' own stacking
+    and slicing, and instructions the compiler rooted in one of its own (a
+    ``bitcast`` after the last named operation, an expanded ``cumsum``, an
+    async collective), whose fused computations hold named operations only."""
+    fixture, scopes, most_stacking, most_rootless = STEP_NAMES[step]
+    named, stacking, rootless, strays = _scopes_of_a_step(
+        request.getfixturevalue(fixture)[-1])
+    with capsys.disabled():
+        print(f"\n{step}: {sum(named.values())} instructions under "
+              + ", ".join(f"{k} {v}" for k, v in sorted(named.items()))
+              + f"; {len(stacking)} of the scans' stacking, "
+              f"{len(rootless)} rooted by the compiler")
+        print("  stacking: " + "; ".join(
+            f"{name} {shape} {op_name.rsplit('/', 1)[-1]}"
+            for name, shape, op_name in stacking))
+        print("  rooted by the compiler: " + "; ".join(
+            f"{name} {shape} holds {','.join(inside) or '-'}"
+            for name, shape, _, inside in rootless))
+    assert not strays, strays
+    assert set(named) == scopes <= set(ts.STEP_SCOPES), set(named) ^ scopes
+    assert all(set(inside) <= set(ts.STEP_SCOPES)
+               for *_, inside in rootless), rootless
+    assert len(stacking) <= most_stacking and len(rootless) <= most_rootless
 
 
 def test_libtpu_accepts_the_perf_flags():

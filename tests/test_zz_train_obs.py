@@ -37,6 +37,16 @@ def driver_run():
     opt_state = jax.jit(opt.init)(params)
     driver = StepDriver(cfg, opt, steps_per_launch=K)
     assert driver.fused and driver.recorder is not None
+    # how often the run reads the compiled step (its collectives and its
+    # memory): once, at the launch that compiled it
+    driver.compiled_reads = []
+    read = driver._read_compiled
+
+    def counted(abstract):
+        driver.compiled_reads.append(read(abstract))
+        return driver.compiled_reads[-1]
+
+    driver._read_compiled = counted
     rng = np.random.default_rng(3)
 
     def batches(n):
@@ -241,6 +251,34 @@ def test_waterfall_uncovered_residual():
         rec.close()
 
 
+def test_the_compiled_steps_memory_rides_with_the_launches(driver_run):
+    """The launch that compiled the fused program reads what the compiler
+    says it needs of a device's memory off the same executable as its
+    collectives (``util/hlo_copies.step_memory``), once: the later launches
+    lower nothing. The recorder hands it on wherever ``eva_plan`` rides:
+    ``summary()``, ``window_summary()``, ``launch_totals()`` and from there
+    the trainer's ``train_launches`` span, where a reader that has neither
+    the worker nor JAX finds it."""
+    from ray_tpu.train.trainer import JaxTrainer
+    from ray_tpu.util import lifecycle
+
+    driver, rec, _ = driver_run
+    assert len(driver.compiled_reads) == 1 and driver.launches == 4
+    assert driver.compile_count() == 1
+    mem = rec.summary()["step_memory"]
+    assert sorted(mem) == ["alias_bytes", "argument_bytes", "output_bytes",
+                           "peak_bytes", "temp_bytes"]
+    assert mem["peak_bytes"] >= mem["temp_bytes"] > 0, mem
+    # the state is donated: what comes back lies where it came from
+    assert 0 < mem["alias_bytes"] <= mem["argument_bytes"], mem
+    assert driver.compiled_reads[0][1] == mem == rec.step_memory
+    assert rec.window_summary(0.0, 1e18)["step_memory"] == mem
+    assert rec.launch_totals()["step_memory"] == mem
+    JaxTrainer._note_launches(rec.launch_totals())
+    span = lifecycle.last("train_launches")
+    assert span["step_memory"] == mem and span["launches"] == 4
+
+
 def test_window_summary_carves_launches():
     rec = _synthetic("win")
     try:
@@ -263,8 +301,8 @@ def test_window_summary_carves_launches():
         # synthetic recorder traced no flash kernel and compiled no step)
         assert rec.window_summary(0.0, 999.0) == {
             "window_launches": 0, "flash_plans": [], "kda_plan": {},
-            "eva_plan": {},
-            "expert_placement": None, "collectives": {}, "routing": {}}
+            "eva_plan": {}, "expert_placement": None, "collectives": {},
+            "step_memory": {}, "routing": {}}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
     finally:
@@ -456,6 +494,10 @@ def test_api_train_and_cli_json(rt_cluster):
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
+        rec.step_memory = {
+            "peak_bytes": 15_794_000_000, "temp_bytes": 9_830_000_000,
+            "argument_bytes": 5_110_000_000, "output_bytes": 5_110_000_000,
+            "alias_bytes": 5_110_000_000}
         counts = rec.drain_now()
         assert counts["kv"] == 1, counts  # the @train/ snapshot landed
         assert counts["events"] >= 1, counts  # the timeline lane shipped
@@ -493,6 +535,8 @@ def test_api_train_and_cli_json(rt_cluster):
         assert "launch gap" in text
         assert "experts placed by expert" in text
         assert "all-gather 2 (6 runs, 3.00 GB)" in text
+        assert ("memory: the compiled step peaks at 14.71 GiB a device "
+                "(temporaries 9.15, arguments 4.76)") in text
         assert ("routing: 4096 assignments, 128 to experts held here "
                 "(3.12%), 120 kept, 8 dropped beyond capacity, busiest "
                 "expert's queue 40 rows") in text
