@@ -388,12 +388,16 @@ def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
              sin: jax.Array, cos: jax.Array,
              segment_ids: Optional[jax.Array]) -> jax.Array:
     """Pre-norm EVA attention + residual (``ops/eva.py``), under the scope
-    ``attn_eva``: the norm, the three projections, RoPE on q and k at their
-    absolute positions, the chunk summaries (``eva_summaries``) and the
-    attention over the window's keys and the earlier windows' summaries
-    (``eva_attend``), ``wo``. The kernels run where ``attn_impl`` is
-    ``"flash"`` and no mesh of several chips is ambient (a Mosaic call is
-    not partitioned); the dense masked form everywhere else."""
+    ``attn_eva``: the norm, the three projections, and from their results to
+    the mixer's output ``eva.eva_attention``: RoPE on q and k at their
+    absolute positions, the chunk summaries and the attention over the
+    window's keys and the earlier windows' summaries; then ``wo``. The
+    kernels run where ``attn_impl`` is ``"flash"`` and no mesh of several
+    chips is ambient (a Mosaic call is not partitioned): one call that
+    rotates, turns heads first and pools (``eva_mix``) and four of attention
+    (``eva_attend``), whose ``o`` XLA turns back for ``wo``. Everywhere else
+    ``apply_rope``, transposes, the summaries under autodiff and the dense
+    masked form."""
     from ray_tpu.ops import eva
     from ray_tpu.parallel.context import current_mesh
 
@@ -410,15 +414,12 @@ def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     kernels = cfg.attn_impl == "flash" and (mesh is None or mesh.size == 1)
     with jax.named_scope("attn_eva"):
         h = pre_norm(cfg, x, layer, "attn_norm")
-        q = apply_rope((h @ layer["wq"].astype(cdt)).reshape(b, s, hq, hd),
-                       sin, cos)
-        k = apply_rope((h @ layer["wk"].astype(cdt)).reshape(b, s, hq, hd),
-                       sin, cos)
-        v = (h @ layer["wv"].astype(cdt)).reshape(b, s, hq, hd)
+        q, k, v = ((h @ layer[w].astype(cdt)).reshape(b, s, hq, hd)
+                   for w in ("wq", "wk", "wv"))
         attn = eva.eva_attention(
-            q, k, v, layer["eva_phi"].astype(cdt), layer["eva_mu"].astype(cdt),
-            window=cfg.eva_window, chunk=cfg.eva_chunk,
-            impl="pallas" if kernels else "xla")
+            q, k, v, sin, cos, layer["eva_phi"].astype(cdt),
+            layer["eva_mu"].astype(cdt), window=cfg.eva_window,
+            chunk=cfg.eva_chunk, impl="pallas" if kernels else "xla")
         out = attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
         return x + out.astype(x.dtype)
 
@@ -559,9 +560,13 @@ def remat_block(cfg: LlamaConfig, fn):
         # not fit beside four layers' state (15.42 GiB of 15.75 by the
         # described-chip compile): this block keeps by name what its
         # backward reads and no product recomputes, q, k, v as the kernels
-        # take them, the summaries, the kernels' o and lse, the
-        # feed-forward's gate and up (14.89 GiB), and rebuilds the norms,
-        # SiLU and the residual's float32 copies
+        # take them and the summaries (the five results of
+        # ``ops/pallas/eva_mix.py``'s forward call, which its backward call
+        # needs k and v of and no projection's raw result: the backward
+        # runs neither that call nor wq, wk, wv's products again), the
+        # kernels' o and lse, the feed-forward's gate and up (15.01 GiB),
+        # and rebuilds the norms, SiLU, the product with wo and the
+        # residual's float32 copies
         return jax.checkpoint(fn, policy=policies.save_only_these_names(
             *flash.RESIDUAL_NAMES, *eva.RESIDUAL_NAMES, *FFN_RESIDUAL_NAMES))
     return jax.checkpoint(fn, policy=policies.save_from_both_policies(

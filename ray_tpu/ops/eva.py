@@ -10,24 +10,37 @@ head ``a`` and a chunk ``j`` (positions ``c j .. c j + c - 1``):
     k~_j    = mu_a + sum_m alpha_m k_m
     v~_j    = sum_m alpha_m v_m
 
-(``summaries``; ``phi_a``, ``mu_a`` [head_dim] are learned). A query at
-position t, ``W(t) = t // w``, sees the keys ``{k_m : W(m) = W(t), m <= t}``
-and the summaries ``{k~_j : (c j) // w < W(t)}``: the chunks of its own
-window are NOT summarised for it. One softmax over both sets at
-``1/sqrt(head_dim)``; the output is ``sum p_m v_m + sum p_j v~_j``
-(``visible`` is that rule as a mask, ``eva_attention`` the whole).
+(``summaries``; ``phi_a``, ``mu_a`` [head_dim] are learned; ``k`` is the
+rotated key). A query at position t, ``W(t) = t // w``, sees the keys
+``{k_m : W(m) = W(t), m <= t}`` and the summaries ``{k~_j : (c j) // w <
+W(t)}``: the chunks of its own window are NOT summarised for it. One softmax
+over both sets at ``1/sqrt(head_dim)``; the output is ``sum p_m v_m + sum
+p_j v~_j`` (``visible`` is that rule as a mask, ``eva_attention`` the whole,
+from the projections' results to the mixer's output).
 
-The summaries are an elementwise pass and a ``c``-wide reduction: plain XLA
-under plain autodiff, float32 inside, rounded to the inputs' dtype. The
-attention is ``ops/pallas/eva_attn.py``'s kernels (``impl="pallas"``), or a
-dense masked softmax over ``[s, s + s / c]`` scores (``impl="xla"``: the CPU
-tests' yardstick, and what a mesh of several chips runs, because a Mosaic
-call is not partitioned). Scopes: ``eva_summaries`` and ``eva_attend``, which
-``models/llama.eva_half`` puts inside ``attn_eva``.
+Two paths that share no logic, chosen by ``impl``:
+
+``"pallas"``  (one chip, ``attn_impl="flash"``) two kernel families.
+    ``ops/pallas/eva_mix.py``: RoPE on q and k, the turn heads first (padded
+    to whole windows) and the chunk summaries as one call forward and one
+    backward, each stream once through HBM, float32 inside. Then
+    ``ops/pallas/eva_attn.py``'s four attention kernels.
+``"xla"``     (the CPU tests' yardstick, and what a mesh of several chips
+    runs, because a Mosaic call is not partitioned) ``ops/rope.apply_rope``,
+    a transpose a stream, ``summaries`` under plain autodiff (float32
+    inside, rounded to the inputs' dtype) and a dense masked softmax over
+    ``[s, s + s / c]`` scores.
+
+Scopes: ``eva_mix`` (the first family) or ``eva_summaries`` (XLA's), and
+``eva_attend``, which ``models/llama.eva_half`` puts inside ``attn_eva``.
 
 What a remat block can keep of this (``RESIDUAL_NAMES``): q, k and v as the
-kernels read them (rotated, heads first) and the summaries; the kernels'
-``o`` and ``lse`` carry ``flash.RESIDUAL_NAMES``.
+attention reads them (rotated, heads first) and the summaries: the five
+results of ``eva_mix``'s forward call, which names them itself and whose
+backward reads of them the rotated k and v alone (the rotation's pull-back
+needs the tables, the pooling's the rotated k, v and ``phi``), so that a
+block that saves by these names runs no projection a second time. The
+attention kernels' ``o`` and ``lse`` carry ``flash.RESIDUAL_NAMES``.
 """
 
 from __future__ import annotations
@@ -41,6 +54,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.ops.rope import apply_rope
 
 RESIDUAL_NAMES = ("eva_q", "eva_k", "eva_v", "eva_ks", "eva_vs")
 #: the release's ``init_std``: ``phi`` and ``mu`` start as a normal of this
@@ -61,7 +75,10 @@ def plan(seq: int, heads: int, head_dim: int, window: int, chunk: int,
     one head, at the kernels' block sizes, that hold a visible pair (the
     summaries' part and the windows' causal halves); ``tiles_visited``: those
     the implementation computes, the same for the kernels, every tile of the
-    ``[s, s + s / chunk]`` rectangle for ``impl="xla"``."""
+    ``[s, s + s / chunk]`` rectangle for ``impl="xla"``; ``mix``: what makes
+    the attention's operands of the projections' results, ``"pallas"``
+    (``ops/pallas/eva_mix.py``'s call pair) with the kernels, else
+    ``"xla"``."""
     from ray_tpu.ops.pallas import eva_attn
 
     if seq % chunk:
@@ -77,7 +94,8 @@ def plan(seq: int, heads: int, head_dim: int, window: int, chunk: int,
     needed = need["summary"] + need["local"]
     visited = needed if impl == "pallas" else (windows * bpw) * (
         windows * bpw + windows)
-    return {"impl": impl, "batch": batch, "heads": heads, "head_dim": head_dim,
+    return {"impl": impl, "mix": impl, "batch": batch, "heads": heads,
+            "head_dim": head_dim,
             "seq": seq, "window": window, "chunk": chunk, "windows": windows,
             "chunks": seq // chunk, "summaries_seen": (windows - 1) * per_window,
             "block": block, "summary_block": per_window,
@@ -114,9 +132,16 @@ def noting_plan(into: Dict[str, Any]) -> Iterator[None]:
 # ---------------------------------------------------------------- the parts
 
 def summaries(k: jax.Array, v: jax.Array, phi: jax.Array, mu: jax.Array,
-              chunk: int):
+              chunk: int, made=None):
     """k, v [n, s, d] a head a row of n (``phi``, ``mu`` [n, d], the head's
-    own) -> (k~, v~) [n, s // chunk, d] in the inputs' dtype."""
+    own) -> (k~, v~) [n, s // chunk, d] in the inputs' dtype. ``made``: the
+    pair where a kernel has formed it from the same operands already
+    (``eva_mix``'s forward call); it is handed on as it is, so that both
+    paths' summaries enter the attention here and nowhere else (what cuts
+    their cotangent here cuts it in both:
+    ``tests/benchmark/evabyte_chip_check.py``'s planted fault)."""
+    if made is not None:
+        return made
     n, s, d = k.shape
     kc = k.reshape(n, s // chunk, chunk, d).astype(F32)
     vc = v.reshape(n, s // chunk, chunk, d).astype(F32)
@@ -149,23 +174,38 @@ def _attend_dense(q, k, v, ks, vs, *, window, chunk, scale):
                       preferred_element_type=F32).astype(q.dtype)
 
 
-def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array, phi: jax.Array,
-                  mu: jax.Array, *, window: int, chunk: int,
-                  impl: str = "pallas") -> jax.Array:
-    """q, k, v [b, s, h, d] (rotated), ``phi``, ``mu`` [h, d] -> [b, s, h,
-    d]. ``s`` must be a whole number of chunks; a last window that is part
-    full is padded with keys no real query sees and rows that are cut off."""
+def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array, sin: jax.Array,
+                  cos: jax.Array, phi: jax.Array, mu: jax.Array, *,
+                  window: int, chunk: int, impl: str = "pallas") -> jax.Array:
+    """q, k, v [b, s, h, d], the projections' results (not rotated);
+    ``sin``, ``cos`` ``ops/rope.rope_angles``' tables; ``phi``, ``mu`` [h,
+    d] -> [b, s, h, d]. ``s`` must be a whole number of chunks; a last
+    window that is part full is padded with keys no real query sees and
+    rows that are cut off."""
     b, s, h, d = q.shape
     p = plan(s, h, d, window, chunk, batch=b, impl=impl)
     into = getattr(_noting, "into", None)
     if into is not None:
         into.update(p)
+    if impl == "pallas":
+        from ray_tpu.ops.pallas import eva_attn, eva_mix
+
+        with jax.named_scope("eva_mix"):
+            q, k, v, ks, vs = eva_mix.mix(
+                *(a.reshape(b, s, h * d) for a in (q, k, v)), sin, cos, phi,
+                mu, window, chunk)
+        ks, vs = summaries(k, v, phi, mu, chunk, (ks, vs))
+        with jax.named_scope("eva_attend"):
+            o = eva_attn.eva_attend(q, k, v, ks, vs, window=window,
+                                    chunk=chunk, scale=d ** -0.5)
+        return o[:, :s].reshape(b, h, s, d).transpose(0, 2, 1, 3)
     pad = p["windows"] * window - s
 
     def heads_first(a):  # [b, s, h, d] -> [b * h, padded s, d]
         a = a.transpose(0, 2, 1, 3).reshape(b * h, s, d)
         return jnp.pad(a, ((0, 0), (0, pad), (0, 0))) if pad else a
 
+    q, k = apply_rope(q, sin, cos), apply_rope(k, sin, cos)
     q, k, v = (checkpoint_name(heads_first(a), name)
                for a, name in zip((q, k, v), RESIDUAL_NAMES))
     with jax.named_scope("eva_summaries"):
@@ -173,12 +213,6 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array, phi: jax.Array,
                            chunk)
         ks, vs = map(checkpoint_name, (ks, vs), RESIDUAL_NAMES[3:])
     with jax.named_scope("eva_attend"):
-        if impl == "pallas":
-            from ray_tpu.ops.pallas import eva_attn
-
-            o = eva_attn.eva_attend(q, k, v, ks, vs, window=window,
-                                    chunk=chunk, scale=d ** -0.5)
-        else:
-            o = _attend_dense(q, k, v, ks, vs, window=window, chunk=chunk,
-                              scale=d ** -0.5)
+        o = _attend_dense(q, k, v, ks, vs, window=window, chunk=chunk,
+                          scale=d ** -0.5)
     return o[:, :s].reshape(b, h, s, d).transpose(0, 2, 1, 3)

@@ -150,25 +150,40 @@ _KDA_INSIDES = """if True:
 """
 
 
-@pytest.fixture(scope="module")
-def kda_insides_schedule(topo, tmp_path_factory):
-    """{"fwd" | "bwd": the bundles the compiler schedules for the call}: a
-    segment of the Kimi Linear cell (32 heads x 8 chunks of 64 x 128, bf16)
-    through ``ops/pallas/kda_insides.py``, compiled for a described v5e in a
-    process of its own that starts with ``--xla_jf_dump_to`` (libtpu reads
-    the flag once, writes ``*<call>*schedule-analysis_final_bundles.txt``
-    among much else, and aborts when it is done)."""
-    dump = tmp_path_factory.mktemp("kda_insides_schedule")
+_EVA_MIX = """if True:
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.pallas import eva_mix, flash
+    flash._needs_interpret = lambda: False
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    x = jax.ShapeDtypeStruct((1, 16384, 4096), jnp.bfloat16, sharding=one)
+    table = jax.ShapeDtypeStruct((16384, 64), jnp.bfloat16, sharding=one)
+    phi = jax.ShapeDtypeStruct((32, 128), jnp.bfloat16, sharding=one)
+    loss = lambda *a: sum((o.astype(jnp.float32) ** 2).sum()
+                          for o in eva_mix.mix(*a, 2048, 16))
+    jax.jit(jax.grad(loss, argnums=(0, 1, 2, 5, 6))).lower(
+        x, x, x, table, table, phi, phi).compile()
+"""
+
+
+def _scheduled_bundles(dump, script, calls):
+    """{"fwd" | "bwd": the bundles the compiler schedules for the call
+    ``calls`` (a pattern with one group, the way) names}: ``script``
+    compiled for a described v5e in a process of its own that starts with
+    ``--xla_jf_dump_to`` (libtpu reads the flag once, writes
+    ``*<call>*schedule-analysis_final_bundles.txt`` among much else, and
+    aborts when it is done)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
                LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={dump}",
                PYTHONPATH=os.path.dirname(os.path.dirname(
                    os.path.abspath(__file__))))
-    done = subprocess.run([sys.executable, "-c", _KDA_INSIDES], env=env,
+    done = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=600)
     found = {}
     for name in os.listdir(dump):
-        m = re.search(r"kda_insides_(fwd|bwd)_bh32_n8_c64_k128_v128.*"
-                      r"schedule-analysis_final_bundles", name)
+        m = re.search(calls + r".*schedule-analysis_final_bundles", name)
         if m:
             with open(os.path.join(dump, name)) as f:
                 found[m.group(1)] = int(re.search(
@@ -176,6 +191,43 @@ def kda_insides_schedule(topo, tmp_path_factory):
     shutil.rmtree(dump, ignore_errors=True)
     assert sorted(found) == ["bwd", "fwd"], done.stderr[-2000:]
     return found
+
+
+@pytest.fixture(scope="module")
+def kda_insides_schedule(topo, tmp_path_factory):
+    """A segment of the Kimi Linear cell (32 heads x 8 chunks of 64 x 128,
+    bf16) through ``ops/pallas/kda_insides.py``."""
+    return _scheduled_bundles(
+        tmp_path_factory.mktemp("kda_insides_schedule"), _KDA_INSIDES,
+        r"kda_insides_(fwd|bwd)_bh32_n8_c64_k128_v128")
+
+
+@pytest.fixture(scope="module")
+def eva_mix_schedule(topo, tmp_path_factory):
+    """An EVA layer of the EvaByte cell (b1 x s16384, 32 heads of 128, chunk
+    16, bf16) through ``ops/pallas/eva_mix.py``."""
+    return _scheduled_bundles(
+        tmp_path_factory.mktemp("eva_mix_schedule"), _EVA_MIX,
+        r"eva_mix_(fwd|bwd)_s16384_h32_d128_c16")
+
+
+def test_the_eva_mix_pair_compiles_and_keeps_pace_with_hbm(eva_mix_schedule,
+                                                           capsys):
+    """Mosaic takes both calls at the cell's shape and dtype (a head's
+    lanes at an offset the loop computes, the chunks as [16, 16, 128], the
+    logits as an NT product), and the schedule leaves them bound by HBM: a
+    grid step moves 256 rows of three streams in and out, 12.6 MB forward
+    and 17 MB backward, ~15,000 and ~20,000 cycles at the chip's ~810 GB/s;
+    its instructions are the call's bundles less a prologue, 32 trips of
+    the head loop's body. Forward 772 and backward 1,204 (PR 55; 1,454
+    backward with a chunk a trip of a Python loop, whose sums the scheduler
+    left one behind the other, 3.2 ms a call on the chip): the chip took
+    1.29 and 1.70 ms a call, ~630 GB/s."""
+    with capsys.disabled():
+        print(f"\neva_mix at b1 x s16384 x 32 x 128: bundles a grid step's "
+              f"prologue and one head, forward {eva_mix_schedule['fwd']}, "
+              f"backward {eva_mix_schedule['bwd']}")
+    assert eva_mix_schedule["fwd"] <= 900 and eva_mix_schedule["bwd"] <= 1300
 
 
 @pytest.mark.parametrize("way,most", [("fwd", 800), ("bwd", 1300)])
@@ -988,20 +1040,40 @@ def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(evabyte_step,
     """b1 x s16384, K=1, four layers on one described chip: Mosaic takes
     EVA attention's four kernels with their scalar-prefetched lists of
     visits (eight windows of two 1,024-row blocks, summary blocks of 128),
-    each carries the name ``benchmark/kernels/eva_attn.py`` costs it by, the
-    backward runs no second forward kernel (the remat block keeps ``o`` and
-    ``lse`` under ``flash.RESIDUAL_NAMES``), and the step fits the chip's
-    15.75 GiB with the 0.6 GiB ISSUE 52 asked for to spare."""
+    each carries the name ``benchmark/kernels/eva_attn.py`` costs it by, and
+    beside them the pair that makes their operands of the projections'
+    results (``ops/pallas/eva_mix.py``, PR 55): ONE forward and ONE backward
+    call in the step. The backward runs no second forward kernel of either
+    family (the remat block keeps ``o`` and ``lse`` under
+    ``flash.RESIDUAL_NAMES`` and the pair's five results under
+    ``eva.RESIDUAL_NAMES``) and so none of ``wq``, ``wk``, ``wv``'s products
+    a second time. The step fits the chip's 15.75 GiB with the 0.6 GiB
+    ISSUE 52 asked for to spare."""
     from benchmark.kernels import eva_attn as cost
 
     compiled = evabyte_step[-1]
-    customs = [line.strip() for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    customs = [line.strip() for line in text.splitlines()
                if "tpu_custom_call" in line and " custom-call(" in line]
-    shapes = [cost.call_shape(line) for line in customs]
-    assert all(shapes), customs
+    mixes = [m.group(1) for m in (re.search(
+        r"eva_mix_(fwd|bwd)_s16384_h32_d128_c16/pallas_call", line)
+        for line in customs) if m]
+    assert sorted(mixes) == ["bwd", "fwd"], customs
+    shapes = [cost.call_shape(line) for line in customs
+              if "eva_mix_" not in line]
+    assert all(shapes) and len(shapes) + len(mixes) == len(customs), customs
     assert sorted(shapes) == sorted(
         [(kind, 32, 16384, 128, 2048, 16, 2)
          for kind in ("fwd", "dq", "dkv", "dsum")]), shapes
+    # what the backward computes a second time under ``attn_eva``: the
+    # product with ``wo`` (the block keeps ``o`` and rebuilds the stream
+    # after the mixer from it, as at PR 52) and none of ``wq``, ``wk``,
+    # ``wv``'s, whose results only the pair's forward call read
+    again = {re.sub(r"\.clone\.\d+$", "", inst[0])
+             for _, inst, _ in hlo_copies._Module(text).walk(fusions=True)
+             if inst[2] == "convolution"
+             and "rematted_computation/attn_eva" in inst[4]}
+    assert len(again) == 1, again
     mem = compiled.memory_analysis()
     with capsys.disabled():
         print(f"\nevabyte b1 x s16384, 4 layers: temporaries "

@@ -38,7 +38,8 @@ from benchmark.lib import spec  # noqa: E402
 from ray_tpu.models import generate, llama  # noqa: E402
 from ray_tpu.ops import eva  # noqa: E402
 from ray_tpu.ops.attention import mha  # noqa: E402
-from ray_tpu.ops.pallas import eva_attn  # noqa: E402
+from ray_tpu.ops.pallas import eva_attn, eva_mix  # noqa: E402
+from ray_tpu.ops.rope import apply_rope, rope_angles  # noqa: E402
 from ray_tpu.parallel import train_step as ts  # noqa: E402
 from ray_tpu.util import flops  # noqa: E402
 
@@ -175,12 +176,19 @@ def test_one_precision_down_is_another_loss(family, both):
 
 # ---- the mechanism by itself ---------------------------------------------------
 
-def _qkv(seq, seed=0, heads=HEADS, width=WIDTH, batch=2):
+def _qkv(seq, seed=0, heads=HEADS, width=WIDTH, batch=2, dtype=jnp.float32):
     keys = jax.random.split(jax.random.key(seed), 5)
-    q, k, v = (jax.random.normal(key, (batch, seq, heads, width))
+    q, k, v = (jax.random.normal(key, (batch, seq, heads, width)).astype(dtype)
                for key in keys[:3])
-    phi, mu = (0.5 * jax.random.normal(key, (heads, width)) for key in keys[3:])
+    phi, mu = (0.5 * jax.random.normal(key, (heads, width)).astype(dtype)
+               for key in keys[3:])
     return q, k, v, phi, mu
+
+
+def _tables(seq, dtype=jnp.float32):
+    """(sin, cos) as ``llama._rope_tables`` makes them, and longer than the
+    sequence: a reader takes its first ``seq`` rows."""
+    return rope_angles(seq + 8, WIDTH, 100000.0, dtype)
 
 
 @pytest.mark.parametrize("chunk", [4, 8, 16])
@@ -208,12 +216,14 @@ def test_the_summaries_against_a_loop(chunk):
 def test_a_query_in_window_0_sees_plain_causal_attention(impl):
     """No summary is visible there, whatever ``phi`` and ``mu`` are."""
     q, k, v, phi, mu = _qkv(128, seed=1)
-    out = eva.eva_attention(q, k, v, phi, mu, window=WINDOW, chunk=CHUNK,
-                            impl=impl)
-    plain = mha(q[:, :WINDOW], k[:, :WINDOW], v[:, :WINDOW], causal=True)
+    sin, cos = _tables(128)
+    out = eva.eva_attention(q, k, v, sin, cos, phi, mu, window=WINDOW,
+                            chunk=CHUNK, impl=impl)
+    plain = mha(apply_rope(q, sin, cos)[:, :WINDOW],
+                apply_rope(k, sin, cos)[:, :WINDOW], v[:, :WINDOW], causal=True)
     np.testing.assert_allclose(out[:, :WINDOW], plain, atol=2e-6)
-    other = eva.eva_attention(q, k, v, 3 * phi, mu + 1, window=WINDOW,
-                              chunk=CHUNK, impl=impl)
+    other = eva.eva_attention(q, k, v, sin, cos, 3 * phi, mu + 1,
+                              window=WINDOW, chunk=CHUNK, impl=impl)
     np.testing.assert_array_equal(out[:, :WINDOW], other[:, :WINDOW])
     assert float(jnp.abs(out[:, WINDOW:] - other[:, WINDOW:]).max()) > 1e-3
 
@@ -226,18 +236,20 @@ def test_a_moved_key_reaches_its_own_window_through_the_exact_part_alone(impl):
     key with the old summaries); later windows see it through its chunk's
     summary alone (the moved summary with the old keys)."""
     q, k, v, phi, mu = _qkv(128, seed=2)
+    sin, cos = _tables(128)
     moved = k.at[:, 37].add(1.0)
-    run = lambda k_: eva.eva_attention(q, k_, v, phi, mu, window=WINDOW,
-                                       chunk=CHUNK, impl=impl)
+    run = lambda k_: eva.eva_attention(q, k_, v, sin, cos, phi, mu,
+                                       window=WINDOW, chunk=CHUNK, impl=impl)
     before, after = run(k), run(moved)
     np.testing.assert_array_equal(before[:, :37], after[:, :37])
     assert float(jnp.abs(after[:, 37:64] - before[:, 37:64]).min(1).max()) > 1e-4
 
     def dense(keys_from, summaries_from):
         first = lambda a: a.transpose(0, 2, 1, 3).reshape(2 * HEADS, 128, WIDTH)
-        ks, vs = eva.summaries(first(summaries_from), first(v),
+        turned = lambda a: first(apply_rope(a, sin, cos))
+        ks, vs = eva.summaries(turned(summaries_from), first(v),
                                jnp.tile(phi, (2, 1)), jnp.tile(mu, (2, 1)), CHUNK)
-        o = eva._attend_dense(first(q), first(keys_from), first(v), ks, vs,
+        o = eva._attend_dense(turned(q), turned(keys_from), first(v), ks, vs,
                               window=WINDOW, chunk=CHUNK, scale=WIDTH ** -0.5)
         return o.reshape(2, HEADS, 128, WIDTH).transpose(0, 2, 1, 3)
 
@@ -323,17 +335,136 @@ def test_a_window_of_several_blocks_is_the_dense_form(monkeypatch,
     blocks of 16 and of 8, values and all five gradients."""
     monkeypatch.setattr(eva_attn, "_TARGET_BLOCK", WINDOW // blocks_per_window)
     q, k, v, phi, mu = _qkv(112, seed=3)
+    sin, cos = _tables(112)
     weigh = jax.random.normal(jax.random.key(9), q.shape)
 
     def run(impl):
-        return jax.value_and_grad(lambda *a: jnp.sum(weigh * eva.eva_attention(
-            *a, window=WINDOW, chunk=CHUNK, impl=impl)), argnums=range(5))(
-                q, k, v, phi, mu)
+        return jax.value_and_grad(lambda q, k, v, phi, mu: jnp.sum(
+            weigh * eva.eva_attention(q, k, v, sin, cos, phi, mu, window=WINDOW,
+                                      chunk=CHUNK, impl=impl)),
+            argnums=range(5))(q, k, v, phi, mu)
 
     (got, got_grads), (want, want_grads) = run("pallas"), run("xla")
     assert abs(float(got - want)) < 1e-4
     for g, w in zip(got_grads, want_grads):
         np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+# ---- the passes between the projections and the kernels (``eva_mix``) ------------
+
+def _xla_mix(q, k, v, sin, cos, phi, mu, window, chunk):
+    """What ``eva_mix.mix`` replaces, as ``eva_attention(impl="xla")`` does
+    it: ``apply_rope``, a transpose a stream, the pad to whole windows,
+    ``eva.summaries``."""
+    b, s, _ = q.shape
+    h, d = phi.shape
+    pad = -(-s // window) * window - s
+
+    def heads_first(a):
+        a = a.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+        return jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+
+    q, k = (apply_rope(a.reshape(b, s, h, d), sin, cos) for a in (q, k))
+    q, k, v = heads_first(q), heads_first(k), heads_first(v.reshape(b, s, h, d))
+    return (q, k, v) + eva.summaries(k, v, jnp.tile(phi, (b, 1)),
+                                     jnp.tile(mu, (b, 1)), chunk)
+
+
+# (sequence, rows a block): four whole windows; a last window part full; a
+# window of several row blocks, whole and part full
+MIX_CASES = [(128, 256), (112, 256), (128, 8), (112, 16)]
+
+
+def _mix_operands(seq, dtype):
+    q, k, v, phi, mu = _qkv(seq, seed=seq, dtype=dtype)
+    flat = lambda a: a.reshape(2, seq, HEADS * WIDTH)
+    return (flat(q), flat(k), flat(v), *_tables(seq, dtype), phi, mu)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("seq,rows", MIX_CASES)
+def test_the_pair_makes_the_kernels_operands_as_xla_did(monkeypatch, seq, rows,
+                                                        dtype):
+    """Rotated q, k and v heads first and padded to whole windows, ``ks``,
+    ``vs``: the forward call against ``apply_rope`` + the transposes +
+    ``eva.summaries``. float32: to the order of the sums. bfloat16: to two
+    of bf16's last places (XLA's rotation rounds each of its three
+    operations to bf16, the call's is float32 rounded once; a summary is a
+    sum of sixteen such keys)."""
+    monkeypatch.setattr(eva_mix, "_ROWS", rows)
+    args = _mix_operands(seq, dtype)
+    got = eva_mix.mix(*args, WINDOW, CHUNK)
+    want = _xla_mix(*args, WINDOW, CHUNK)
+    padded = -(-seq // WINDOW) * WINDOW
+    assert [a.shape for a in got] == [(2 * HEADS, padded, WIDTH)] * 3 + [
+        (2 * HEADS, padded // CHUNK, WIDTH)] * 2
+    rtol, atol = (0, 1e-6) if dtype == jnp.float32 else (2 ** -6, 2 ** -8)
+    for name, g, w in zip(eva.RESIDUAL_NAMES, got, want):
+        assert g.dtype == w.dtype == dtype, name
+        w = np.asarray(w, np.float32)
+        np.testing.assert_allclose(np.asarray(g, np.float32), w, err_msg=name,
+                                   rtol=rtol, atol=atol * np.abs(w).max())
+    # the rows past the sequence: keys of zeros, and their chunks' ks is mu
+    assert not np.asarray(got[1][:, seq:], np.float32).any()
+    np.testing.assert_array_equal(
+        np.asarray(got[3][:HEADS, seq // CHUNK:], np.float32),
+        np.broadcast_to(np.asarray(args[-1], np.float32)[:, None],
+                        (HEADS, (padded - seq) // CHUNK, WIDTH)))
+
+
+@pytest.mark.parametrize("leaf", ["dq", "dk", "dv", "dphi", "dmu"])
+@pytest.mark.parametrize("seq,rows", MIX_CASES)
+def test_every_cotangent_of_the_pair_against_autodiff_of_xlas_form(
+        monkeypatch, seq, rows, leaf):
+    """The backward call (the pooling's pull-back recomputed from the kept
+    rotated k and v, the rotation turned back, ``dphi`` and ``dmu`` gathered
+    over the row blocks) against ``jax.grad`` through ``apply_rope`` and
+    ``eva.summaries``, under a random cotangent on all five results."""
+    monkeypatch.setattr(eva_mix, "_ROWS", rows)
+    args = _mix_operands(seq, jnp.float32)
+    at = ["dq", "dk", "dv", None, None, "dphi", "dmu"].index(leaf)
+    keys = jax.random.split(jax.random.key(11), 5)
+
+    def loss(fn, x):
+        outs = fn(*args[:at], x, *args[at + 1:], WINDOW, CHUNK)
+        return sum(jnp.sum(jax.random.normal(key, o.shape) * o)
+                   for key, o in zip(keys, outs))
+
+    got = jax.grad(lambda x: loss(eva_mix.mix, x))(args[at])
+    want = jax.grad(lambda x: loss(_xla_mix, x))(args[at])
+    assert got.shape == want.shape and float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_the_last_windows_summaries_get_no_gradient():
+    """No query sees them (``dsum`` never visits them): the attention's
+    pull-back hands the pair zeros there, and what the pair makes of zeros
+    is no cotangent of the pooling's: ``phi`` and ``mu`` hear of the earlier
+    windows alone."""
+    q, k, v, phi, mu = _qkv(128, seed=5)
+    sin, cos = _tables(128)
+    flat = lambda a: a.reshape(2, 128, HEADS * WIDTH)
+    outs, pull_mix = jax.vjp(lambda phi, mu: eva_mix.mix(
+        flat(q), flat(k), flat(v), sin, cos, phi, mu, WINDOW, CHUNK), phi, mu)
+    o, pull = jax.vjp(lambda *a: eva_attn.eva_attend(
+        *a, window=WINDOW, chunk=CHUNK, scale=WIDTH ** -0.5), *outs)
+    *_, dks, dvs = pull(jax.random.normal(jax.random.key(6), o.shape))
+    last = (128 - WINDOW) // CHUNK
+    assert not np.asarray(dks[:, last:]).any() and not np.asarray(dvs[:, last:]).any()
+    assert np.abs(np.asarray(dks[:, :last])).min(axis=(0, 2)).max() > 0
+    zero = [jnp.zeros_like(a) for a in outs[:3]]
+    whole = pull_mix((*zero, dks, dvs))
+    # the same from the earlier windows' cotangents alone, the last
+    # window's replaced by anything: they are zeros, so nothing changes
+    assert float(jnp.abs(whole[0]).max()) > 1e-3
+    none = pull_mix((*zero, jnp.zeros_like(dks), jnp.zeros_like(dvs)))
+    assert not np.asarray(none[0]).any() and not np.asarray(none[1]).any()
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_the_plan_says_who_made_the_kernels_operands(impl):
+    plan = eva.plan(128, HEADS, WIDTH, WINDOW, CHUNK, impl=impl)
+    assert plan["mix"] == plan["impl"] == impl
 
 
 # ---- what is refused ----------------------------------------------------------------
