@@ -155,7 +155,7 @@ def _after_attention(cfg, x, attn, layer, experts=None, index=None):
             cfg, attn.reshape(b, s, -1) @ layer["wo"].astype(cfg.compute_dtype))
     if "w_gate" in layer:  # dense llama FFN (shared ffn_half)
         with jax.named_scope("mlp"):
-            return llama.ffn_half(cfg, x, layer), None
+            return llama.join(x, llama.ffn_half(cfg, x, layer)), None
     # MoE FFN: drop-free inference routing under its own four scopes
     from ray_tpu.models import moe
 

@@ -271,7 +271,7 @@ def _mamba_out(cfg: HybridConfig, x, y, xs, z, layer: Params):
         y = rmsnorm(y, layer["gate_norm"].astype(cdt), cfg.norm_eps)
         x = x + llama.on_residual(cfg, y @ layer["out_proj"].astype(cdt))
     with jax.named_scope("mlp"):
-        return llama.ffn_half(cfg, x, layer)
+        return llama.join(x, llama.ffn_half(cfg, x, layer))
 
 
 def _mamba_block(cfg: HybridConfig, x, layer: Params, state, tail):

@@ -267,10 +267,11 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
                    segment_ids: Optional[jax.Array], *,
                    rotate: bool = True,
                    window: Optional[int] = None) -> jax.Array:
-    """Pre-norm attention + residual — shared by every model family
-    (llama's dense blocks, moe's expert blocks). ``rotate`` and ``window``
-    are the layer's kind where a model has more than one (``models/moe.py``:
-    rotated inside a band, or unrotated and full)."""
+    """The attention half's branch, pre-norm, which its caller joins to the
+    stream (``join``) — shared by every model family (llama's dense blocks,
+    moe's expert blocks). ``rotate`` and ``window`` are the layer's kind
+    where a model has more than one (``models/moe.py``: rotated inside a
+    band, or unrotated and full)."""
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.compute_dtype
@@ -313,8 +314,24 @@ def attention_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     attn = attn.reshape(b, s, hq * hd)
     if cfg.attn_gate:
         attn = attn * jax.nn.sigmoid(h @ layer["wg"].astype(cdt))
-    return x + post_norm(cfg, attn @ layer["wo"].astype(cdt), layer,
-                         "attn_post_norm")
+    return post_norm(cfg, attn @ layer["wo"].astype(cdt), layer,
+                     "attn_post_norm")
+
+
+def join(x: jax.Array, branch: jax.Array, mix=None) -> jax.Array:
+    """A half layer's ``branch`` joined to the residual stream ``x``: where
+    every training block, and the feed-forward half the served blocks share
+    with them, writes its stream. The plain sum ``x + branch`` [b, s, d]; or,
+    where the stream is a hyper-connection's rows [n, b, s, d] and ``mix``
+    what ``ops/hyper.mix_in`` read of them for this half, its mix-out
+    ``H_res x + H_post branch``. The halves themselves (``attention_half``,
+    ``ffn_half``, ``eva_half``, ``mixers.kda_half``, ``mixers.mla_half``,
+    ``moe._moe_ffn``) return their branch and add nothing."""
+    if mix is None:
+        return x + branch
+    from ray_tpu.ops import hyper
+
+    return hyper.mix_out(x, branch, mix)
 
 
 def pre_norm(cfg: LlamaConfig, x: jax.Array, layer: Params, name: str
@@ -348,7 +365,8 @@ def unit_offset_norm(cfg: LlamaConfig, x: jax.Array, w: jax.Array
 
 
 def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
-    """Pre-norm SwiGLU MLP + residual — shared by train and decode paths."""
+    """The pre-norm SwiGLU MLP's branch (``join`` adds it to the stream) —
+    shared by train and decode paths."""
     cdt = cfg.compute_dtype
     h = pre_norm(cfg, x, layer, "mlp_norm")
     if cfg.residual_f32:
@@ -358,7 +376,7 @@ def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
         gate, up = (checkpoint_name(h @ layer[w].astype(cdt), name)
                     for w, name in zip(("w_gate", "w_up"), FFN_RESIDUAL_NAMES))
         gate = jax.nn.silu(gate)
-        return x + ((gate * up) @ layer["w_down"].astype(cdt)).astype(x.dtype)
+        return ((gate * up) @ layer["w_down"].astype(cdt)).astype(x.dtype)
     if x.dtype != cdt:
         # a residual stream kept wider than the compute dtype
         # (``models/sambay.py``): every product is summed to the stream's
@@ -367,11 +385,11 @@ def ffn_half(cfg: LlamaConfig, x: jax.Array, layer: Params) -> jax.Array:
             return jnp.matmul(a.astype(cdt), layer[w].astype(cdt),
                               preferred_element_type=x.dtype)
 
-        return x + on_residual(
+        return on_residual(
             cfg, mm(jax.nn.silu(mm(h, "w_gate")) * mm(h, "w_up"), "w_down"))
     gate = jax.nn.silu(h @ layer["w_gate"].astype(cdt))
     up = h @ layer["w_up"].astype(cdt)
-    return x + on_residual(cfg, post_norm(
+    return on_residual(cfg, post_norm(
         cfg, (gate * up) @ layer["w_down"].astype(cdt), layer,
         "mlp_post_norm"))
 
@@ -387,10 +405,10 @@ def on_residual(cfg: LlamaConfig, branch: jax.Array) -> jax.Array:
 def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
              sin: jax.Array, cos: jax.Array,
              segment_ids: Optional[jax.Array]) -> jax.Array:
-    """Pre-norm EVA attention + residual (``ops/eva.py``), under the scope
-    ``attn_eva``: the norm, the three projections, and from their results to
-    the mixer's output ``eva.eva_attention``: RoPE on q and k at their
-    absolute positions, the chunk summaries and the attention over the
+    """Pre-norm EVA attention's branch (``ops/eva.py``), in the stream's
+    dtype, under the scope ``attn_eva``: the norm, the three projections,
+    and from their results to the mixer's output ``eva.eva_attention``:
+    RoPE on q and k at their absolute positions, the chunk summaries and the attention over the
     window's keys and the earlier windows' summaries; then ``wo``. The
     kernels run where ``attn_impl`` is ``"flash"`` and no mesh of several
     chips is ambient (a Mosaic call is not partitioned): one call that
@@ -421,7 +439,7 @@ def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
             layer["eva_mu"].astype(cdt), window=cfg.eva_window,
             chunk=cfg.eva_chunk, impl="pallas" if kernels else "xla")
         out = attn.reshape(b, s, hq * hd) @ layer["wo"].astype(cdt)
-        return x + out.astype(x.dtype)
+        return out.astype(x.dtype)
 
 
 def _rope_tables(cfg: LlamaConfig, seq: int) -> Tuple[jax.Array, jax.Array]:
@@ -440,12 +458,14 @@ def _block(cfg: LlamaConfig, x: jax.Array, layer: Params,
     SwiGLU MLP, each half under its scope of a device trace
     (``parallel/train_step.STEP_SCOPES``; ``eva_half`` opens its own)."""
     if cfg.attn_kind == "eva":
-        x = eva_half(cfg, x, layer, sin, cos, segment_ids)
+        branch = eva_half(cfg, x, layer, sin, cos, segment_ids)
+        with jax.named_scope("attn_eva"):
+            x = join(x, branch)
     else:
         with jax.named_scope("attn_full"):
-            x = attention_half(cfg, x, layer, sin, cos, segment_ids)
+            x = join(x, attention_half(cfg, x, layer, sin, cos, segment_ids))
     with jax.named_scope("mlp"):
-        return ffn_half(cfg, x, layer)
+        return join(x, ffn_half(cfg, x, layer))
 
 
 def _stage_scan(cfg: LlamaConfig, stage_layers: Params, h: jax.Array,
@@ -525,11 +545,23 @@ def refuse_trained_only(cfg: LlamaConfig) -> None:
             "dense training block): no served block keeps a window buffer "
             "that empties every eva_window positions beside a store of "
             "chunk summaries that is read a window late")
+    why = []
     if kinds:
-        raise NotImplementedError(
+        why.append(
             f"layers of kind {kinds} are trained only (models/moe.py's "
             f"patterned walk): no served block keeps a latent slot cache "
             f"('mla') or a matrix state a slot with its update ('kda')")
+    if getattr(cfg, "hc_mult", 0):
+        why.append(
+            f"a residual stream of hc_mult={cfg.hc_mult} rows under "
+            f"hyper-connections is trained only (models/moe.py's patterned "
+            f"walk, ops/hyper.py): no served block widens its stream")
+    if getattr(cfg, "n_mtp_modules", 0):
+        why.append(
+            "a multi-token-prediction module (n_mtp_modules) is trained "
+            "only (models/moe.py's loss): no engine drafts from one")
+    if why:
+        raise NotImplementedError("; ".join(why))
 
 
 def remat_block(cfg: LlamaConfig, fn):

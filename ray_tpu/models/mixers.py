@@ -1,8 +1,9 @@
 """Two mixers of the patterned walk (``models/moe.py``) beside llama's
-attention half, each a pre-norm branch on the residual stream that is
-trained and not served: a delta-rule linear attention whose state decays a
-channel (``"kda"``, Kimi Delta Attention) and latent attention in its
-uncompressed form, unrotated (``"mla"``).
+attention half, each a pre-norm branch that its caller joins to the residual
+stream (``llama.join``), trained and not served: a delta-rule linear
+attention whose state decays a channel (``"kda"``, Kimi Delta Attention) and
+latent attention in its uncompressed form (``"mla"``), unrotated or with a
+rotary part.
 
 A ``kda`` layer, ``h`` the normed input, H heads of width ``dk = dv``:
 
@@ -31,13 +32,19 @@ written below, which round to the compute dtype at every step and sum a
 norm in float32; it is the kernels' oracle in the tests and the form GSPMD
 partitions.
 
-An ``mla`` layer, H heads: ``q = h @ wq`` [H, nope + rope]; ``[c, k_pe] = h
-@ wkv_a`` [rank], [rope]; ``c = rms(c, kv_norm)``; ``[k_nope, v] = c @ wkv_b``
-[H, nope + dv]; a head's key is ``[k_nope ; k_pe]``, ``k_pe`` one for all
-heads; nothing is rotated (the ``rope`` columns are plain columns); causal
-softmax at ``(nope + rope) ** -0.5`` with values ``dv`` wide (the flash
-kernels' two widths, ``ops/pallas/flash.py``); ``wo``. Nothing is absorbed
-and nothing cached: this is the form a training step runs.
+An ``mla`` layer, H heads: ``q = h @ wq`` [H, nope + rope], or through a
+low rank where the config has ``q_lora_rank``: ``q = rms(h @ wq_a, q_norm)
+@ wq_b``; ``[c, k_pe] = h @ wkv_a`` [rank], [rope]; ``c = rms(c, kv_norm)``;
+``[k_nope, v] = c @ wkv_b`` [H, nope + dv]; a head's key is ``[k_nope ;
+k_pe]``, ``k_pe`` one for all heads. Without ``mla_rope`` nothing is rotated
+(the ``rope`` columns are plain columns); with it ``k_pe`` and each head's
+last ``rope`` query columns are rotated (``ops/rope.apply_rope``,
+interleaved pairs) by tables the walk makes once (``mla_rope_tables``), at
+YaRN's blended frequencies where the config has ``mla_yarn``, whose factor
+also multiplies the softmax's scale. Causal softmax at ``(nope + rope) **
+-0.5`` with values ``dv`` wide (the flash kernels' two widths,
+``ops/pallas/flash.py``); ``wo``. Nothing is absorbed and nothing cached:
+this is the form a training step runs.
 
 Scopes are names only. The walk opens ``attn_kda`` / ``attn_mla`` round a
 layer's mixer half; inside ``attn_kda`` lie ``kda_conv``, ``kda_gates`` and
@@ -59,6 +66,7 @@ from ray_tpu.models.llama import post_norm
 from ray_tpu.ops import kda
 from ray_tpu.ops.attention import mha
 from ray_tpu.ops.norms import rmsnorm
+from ray_tpu.ops.rope import apply_rope, rope_angles
 from ray_tpu.ops.ssm import causal_conv
 
 Params = Dict[str, Any]
@@ -117,8 +125,8 @@ def init_kda(rng: jax.Array, cfg, n: int) -> Params:
 
 
 def kda_half(cfg, x: jax.Array, layer: Params) -> jax.Array:
-    """Pre-norm KDA + residual, [b, s, d] -> [b, s, d]; the caller opens
-    ``attn_kda`` round it (module docstring)."""
+    """Pre-norm KDA's branch, [b, s, d] -> [b, s, d]; the caller opens
+    ``attn_kda`` round it (module docstring) and joins it to the stream."""
     from ray_tpu.parallel.context import current_mesh
 
     b, s, _ = x.shape
@@ -191,8 +199,8 @@ def kda_half(cfg, x: jax.Array, layer: Params) -> jax.Array:
             gate + layer["g_bias"].astype(cdt)).reshape(b, s, h, w)
         o = (rmsnorm(o, layer["o_norm"].astype(cdt), cfg.norm_eps)
              * gate).reshape(b, s, h * w)
-    return x + post_norm(cfg, o @ layer["wo"].astype(cdt), layer,
-                         "attn_post_norm")
+    return post_norm(cfg, o @ layer["wo"].astype(cdt), layer,
+                     "attn_post_norm")
 
 
 # ---------------------------------------------------------------------- mla
@@ -201,17 +209,25 @@ def mla_params(cfg) -> int:
     """One layer's ``mla`` leaves (the norm before the branch left out)."""
     d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
     nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    return (d * h * (nope + rope) + d * (r + rope) + r
-            + r * h * (nope + dv) + h * dv * d)
+    rq = cfg.q_lora_rank
+    wq = d * rq + rq + rq * h * (nope + rope) if rq else d * h * (nope + rope)
+    return (wq + d * (r + rope) + r + r * h * (nope + dv) + h * dv * d)
 
 
 def init_mla(rng: jax.Array, cfg, n: int) -> Params:
     d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
     nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    dt = cfg.param_dtype
+    dt, rq = cfg.param_dtype, cfg.q_lora_rank
     ks = jax.random.split(rng, 4)
+    if rq:
+        wq = {"wq_a": _normal(ks[0], (n, d, rq), d, dt),
+              "q_norm": jnp.ones((n, rq), dt),
+              "wq_b": _normal(jax.random.fold_in(rng, 4),
+                              (n, rq, h * (nope + rope)), rq, dt)}
+    else:
+        wq = {"wq": _normal(ks[0], (n, d, h * (nope + rope)), d, dt)}
     return {
-        "wq": _normal(ks[0], (n, d, h * (nope + rope)), d, dt),
+        **wq,
         "wkv_a": _normal(ks[1], (n, d, r + rope), d, dt),
         "kv_norm": jnp.ones((n, r), dt),
         "wkv_b": _normal(ks[2], (n, r, h * (nope + dv)), r, dt),
@@ -219,34 +235,59 @@ def init_mla(rng: jax.Array, cfg, n: int) -> Params:
     }
 
 
-def mla_half(cfg, x: jax.Array, layer: Params, segment_ids) -> jax.Array:
-    """Pre-norm latent attention + residual, [b, s, d] -> [b, s, d]."""
+def mla_rope_tables(cfg, seq: int):
+    """(sin, cos) [seq, rope // 2] of an ``mla`` layer's rotary part in the
+    compute dtype, or None where the config rotates nothing there."""
+    if not cfg.mla_rope:
+        return None
+    return rope_angles(seq, cfg.qk_rope_head_dim, cfg.rope_theta,
+                       cfg.compute_dtype, yarn=cfg.mla_yarn)
+
+
+def mla_half(cfg, x: jax.Array, layer: Params, segment_ids,
+             tables=None) -> jax.Array:
+    """Pre-norm latent attention's branch, [b, s, d] -> [b, s, d];
+    ``tables``: ``mla_rope_tables``'s."""
     b, s, _ = x.shape
     h, r, cdt = cfg.n_heads, cfg.kv_lora_rank, cfg.compute_dtype
     nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     hx = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
 
     with jax.named_scope("mla_latent"):
-        q = (hx @ layer["wq"].astype(cdt)).reshape(b, s, h, nope + rope)
+        if cfg.q_lora_rank:
+            cq = rmsnorm(hx @ layer["wq_a"].astype(cdt),
+                         layer["q_norm"].astype(cdt), cfg.norm_eps)
+            q = cq @ layer["wq_b"].astype(cdt)
+        else:
+            q = hx @ layer["wq"].astype(cdt)
+        q = q.reshape(b, s, h, nope + rope)
         down = hx @ layer["wkv_a"].astype(cdt)
         c = rmsnorm(down[..., :r], layer["kv_norm"].astype(cdt), cfg.norm_eps)
         up = (c @ layer["wkv_b"].astype(cdt)).reshape(b, s, h, nope + dv)
-        k = jnp.concatenate([up[..., :nope], jnp.broadcast_to(
-            down[:, :, None, r:], (b, s, h, rope))], axis=-1)
+        k_nope, k_pe = up[..., :nope], down[:, :, None, r:]
+        if tables is not None:
+            q = jnp.concatenate(
+                [q[..., :nope], apply_rope(q[..., nope:], *tables)], axis=-1)
+            k_pe = apply_rope(k_pe, *tables)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_pe, (b, s, h, rope))], axis=-1)
         v = up[..., nope:]
 
+    # None, the kernels' own default, where the config has no YaRN record
+    scale = None if cfg.mla_yarn is None else (
+        (nope + rope) ** -0.5 * cfg.mla_yarn.softmax_scale())
     if cfg.attn_impl == "flash":
         if segment_ids is not None:
             raise NotImplementedError(
                 "segment_ids (packed sequences) require attn_impl='xla'")
         from ray_tpu.parallel.context import flash_attention_on_mesh
 
-        attn = flash_attention_on_mesh(q, k, v, causal=True)
+        attn = flash_attention_on_mesh(q, k, v, causal=True, scale=scale)
     elif cfg.attn_impl == "xla":
-        attn = mha(q, k, v, causal=True, segment_ids=segment_ids)
+        attn = mha(q, k, v, causal=True, segment_ids=segment_ids, scale=scale)
     else:
         raise NotImplementedError(
             f"latent attention under attn_impl={cfg.attn_impl!r}: a sequence "
             f"split across chips has no ring at two head widths")
-    return x + post_norm(cfg, attn.reshape(b, s, h * dv)
-                         @ layer["wo"].astype(cdt), layer, "attn_post_norm")
+    return post_norm(cfg, attn.reshape(b, s, h * dv)
+                     @ layer["wo"].astype(cdt), layer, "attn_post_norm")

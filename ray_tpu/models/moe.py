@@ -39,7 +39,14 @@ choices, capacity is reckoned from the whole count, the buffers are
 ``[held, C, d]``, and an assignment to an expert that lives elsewhere is no
 drop and adds nothing here: what the absent experts would have added is
 left out (on one chip the layer runs without its exchange, and nothing
-stands in for the other chips). ``forward_hidden`` walks the dense segment
+stands in for the other chips). Two more things a patterned config may have
+(``ops/hyper.py``, ``_mtp``): a residual stream of ``hc_mult`` rows that
+every half layer reads and writes through a hyper-connection in place of the
+plain sum (``_read``, ``_join``; the halves themselves return their branch
+and add nothing), and a multi-token-prediction module in the loss, one more
+expert layer on a stream started from the trunk's last hidden state and the
+next token's embedding, whose cross entropy of the token after next joins
+the loss at ``mtp_weight``. ``forward_hidden`` walks the dense segment
 layer by layer and the expert layers period by period (``_walk``), the
 kinds inside a period static and the mixers' leaves stacked by kind, since a
 ``kda`` layer and an ``mla`` layer hold different ones (``_pick``); it also
@@ -59,6 +66,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixers
+from ray_tpu.ops import hyper
+from ray_tpu.ops.rope import Yarn
 from ray_tpu.parallel.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
 
@@ -119,6 +128,24 @@ class MoEConfig(llama.LlamaConfig):
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # its query through a low rank (``wq_a``, a norm, ``wq_b``; 0: one
+    # full-rank ``wq``), and its rotary part: the ``qk_rope_head_dim``
+    # columns rotated, under YaRN's frequencies and softmax factor where
+    # the config publishes a ``rope_scaling`` of that type
+    q_lora_rank: int = 0
+    mla_rope: bool = False
+    mla_yarn: Optional[Yarn] = None
+    # hyper-connections (``ops/hyper.py``): the residual stream's rows (0:
+    # one row and the plain sum), Sinkhorn's iterations and the ``eps`` in
+    # its sums, and the clamp on ``H_res``'s logits
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # multi-token-prediction modules in the loss (0 or 1: an expert layer
+    # of the last layer's kind) and the weight of its cross entropy
+    n_mtp_modules: int = 0
+    mtp_weight: float = 0.3
 
     def __post_init__(self):
         if self.balance == "first_choice" and self.experts_held != self.n_experts:
@@ -145,6 +172,12 @@ class MoEConfig(llama.LlamaConfig):
                 and self.v_head_dim):
             raise ValueError("an mla layer needs kv_lora_rank, "
                              "qk_nope_head_dim and v_head_dim")
+        if self.mla_yarn is not None and not self.mla_rope:
+            raise ValueError("mla_yarn scales a rotary part: set mla_rope")
+        if self.n_mtp_modules not in (0, 1):
+            raise ValueError(
+                f"n_mtp_modules {self.n_mtp_modules}: a second module would "
+                f"read the first's stream, and nothing here chains them")
 
     @property
     def experts_held(self) -> int:
@@ -154,6 +187,11 @@ class MoEConfig(llama.LlamaConfig):
     @property
     def n_expert_layers(self) -> int:
         return self.n_layers - self.n_dense_layers
+
+    @property
+    def n_routed_layers(self) -> int:
+        """Expert layers with the prediction module's counted."""
+        return self.n_expert_layers + self.n_mtp_modules
 
     def period(self) -> Tuple[str, ...]:
         """The shortest run of kinds that the expert layers repeat whole."""
@@ -171,6 +209,20 @@ class MoEConfig(llama.LlamaConfig):
                 + d * self.n_experts + 2 * self.router_bias * self.n_experts
                 + (1 + self.sandwich_norm) * d)
 
+    def _hyper_params(self) -> int:
+        """A layer's two hyper-connections' leaves."""
+        return 2 * hyper.params(self.hc_mult, self.d_model) \
+            if self.hc_mult else 0
+
+    def mtp_params(self, experts: int) -> int:
+        """The prediction module: its projection of two normed inputs, its
+        expert layer and its final norm (it shares embedding and head)."""
+        if not self.n_mtp_modules:
+            return 0
+        d = self.d_model
+        return (2 * d * d + 3 * d + self.mixer_params(self.layer_kinds[-1])
+                + self._ffn_params(experts) + self._hyper_params())
+
     def mixer_params(self, kind: str) -> int:
         """One layer's mixer of ``kind`` with the norms round its branch."""
         if _STACK[kind] == "":
@@ -186,7 +238,8 @@ class MoEConfig(llama.LlamaConfig):
                                        + (1 + self.sandwich_norm) * d)
         sparse = self.n_expert_layers * self._ffn_params(experts)
         head = 0 if self.tie_embeddings else d * v
-        return v * d + mixing + dense + sparse + d + head
+        extra = self.n_layers * self._hyper_params() + self.mtp_params(experts)
+        return v * d + mixing + dense + sparse + d + head + extra
 
     def num_params(self) -> int:
         """Parameters held here (``n_experts_held`` experts a layer)."""
@@ -199,7 +252,7 @@ class MoEConfig(llama.LlamaConfig):
         d, f = self.d_model, self.d_ff
         visits = self.top_k * self.experts_held / self.n_experts
         return int(self._params(0)
-                   + self.n_expert_layers * visits * 3 * d * f)
+                   + self.n_routed_layers * visits * 3 * d * f)
 
 
 PRESETS: Dict[str, MoEConfig] = {
@@ -245,6 +298,37 @@ def _segment_mixers(rng: jax.Array, cfg: MoEConfig, layers: Params,
             layers[kind] = init(jax.random.fold_in(rng, 20 + i), cfg, n)
 
 
+def _segment_hyper(rng: jax.Array, cfg: MoEConfig, layers: Params,
+                   n: int) -> None:
+    """Give ``layers``, a segment of ``n`` layers, each half's
+    hyper-connection (``hc_attn_*``, ``hc_mlp_*``: ``hyper.LEAVES``) where
+    the config widens the stream."""
+    if not cfg.hc_mult:
+        return
+    for i, half in enumerate(("attn", "mlp")):
+        made = hyper.init(jax.random.fold_in(rng, i), cfg.hc_mult,
+                          cfg.d_model, n, cfg.param_dtype)
+        layers.update({f"hc_{half}_{name}": a for name, a in made.items()})
+
+
+def _init_mtp(rng: jax.Array, cfg: MoEConfig) -> Params:
+    """The prediction module's leaves: the two norms and the projection of
+    its input, one expert layer of the last layer's kind (a segment of one,
+    as ``init_params`` makes the expert layers) and its final norm."""
+    d, kind = cfg.d_model, cfg.layer_kinds[-1]
+    one = init_params(jax.random.fold_in(rng, 0), dataclasses.replace(
+        cfg, n_layers=1, layer_kinds=(kind,), n_dense_layers=0,
+        n_mtp_modules=0, vocab_size=8))
+    # (a buffer each: a step donates its parameters leaf by leaf)
+    norms = {name: jnp.ones((d,), cfg.param_dtype)
+             for name in ("hnorm", "enorm", "final_norm")}
+    return {**norms,
+            "proj": (jax.random.normal(jax.random.fold_in(rng, 1), (2 * d, d),
+                                       jnp.float32) / math.sqrt(2 * d)
+                     ).astype(cfg.param_dtype),
+            "layers": one["layers"]}
+
+
 def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     """Llama init plus stacked expert FFNs [L, E, ...] and routers; ``E``
     the experts held here, the router ``n_experts`` wide. A patterned
@@ -254,7 +338,10 @@ def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     movement, ``router_bias_m``) lie with the expert layers, and in either
     segment a ``kda`` or ``mla`` layer's mixer lies in a sub-tree of its
     kind's name, stacked over the segment's layers of that kind
-    (``_segment_mixers``)."""
+    (``_segment_mixers``). Where the stream is widened every layer of
+    either segment holds its two hyper-connections' leaves
+    (``_segment_hyper``), and a prediction module lies under ``mtp``
+    (``_init_mtp``)."""
     d, f, E, L = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.n_expert_layers
     H = cfg.experts_held
     base = llama.init_params(rng, dataclasses.replace(
@@ -286,6 +373,7 @@ def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
     if cfg.layer_kinds:
         _segment_mixers(jax.random.fold_in(rng, 11), cfg, layers,
                         cfg.layer_kinds[cfg.n_dense_layers:])
+        _segment_hyper(jax.random.fold_in(rng, 13), cfg, layers, L)
     if cfg.n_dense_layers:
         base["dense_layers"] = llama.init_params(
             jax.random.fold_in(rng, 10), dataclasses.replace(
@@ -294,6 +382,10 @@ def init_params(rng: jax.Array, cfg: MoEConfig) -> Params:
         _segment_mixers(jax.random.fold_in(rng, 12), cfg,
                         base["dense_layers"],
                         cfg.layer_kinds[:cfg.n_dense_layers])
+        _segment_hyper(jax.random.fold_in(rng, 14), cfg,
+                       base["dense_layers"], cfg.n_dense_layers)
+    if cfg.n_mtp_modules:
+        base["mtp"] = _init_mtp(jax.random.fold_in(rng, 15), cfg)
     return base
 
 
@@ -314,22 +406,32 @@ def buffer_updates(cfg: MoEConfig, params: Params, updates: Params,
     ``v = (mean - n) / mean`` is how far under its share an expert is (1
     for one nobody chose), ``step = RATE * tanh(v)`` the soft-clamped move,
     centred over the experts so that the biases' mean stays; ``m' =
-    MOMENTUM * m + (1 - MOMENTUM) * step`` and ``bias' = bias + m'``."""
+    MOMENTUM * m + (1 - MOMENTUM) * step`` and ``bias' = bias + m'``. A
+    prediction module's layer has a bias of its own, moved alike by what
+    that layer counted (``mtp_router_load``)."""
     if not cfg.router_bias:
         return updates, stats
     stats = dict(stats)
-    m = params["layers"]["router_bias_m"]
-    if "router_load" in stats:
-        load = stats.pop("router_load").astype(jnp.float32)           # [L, E]
-        mean = load.mean(-1, keepdims=True)
-        step = ROUTER_BIAS_RATE * jnp.tanh((mean - load) / mean)
-        step = step - step.mean(-1, keepdims=True)
-    else:  # a caller's own loss counted nothing: the bias keeps its course
-        step = jnp.zeros_like(m)
-    m_new = ROUTER_BIAS_MOMENTUM * m + (1 - ROUTER_BIAS_MOMENTUM) * step
-    layers = {**updates["layers"], "router_bias": m_new,
-              "router_bias_m": m_new - m}
-    return {**updates, "layers": layers}, stats
+
+    def moved(layers: Params, upd: Params, counted: str) -> Params:
+        m = layers["router_bias_m"]
+        if counted in stats:
+            load = stats.pop(counted).astype(jnp.float32)             # [L, E]
+            mean = load.mean(-1, keepdims=True)
+            step = ROUTER_BIAS_RATE * jnp.tanh((mean - load) / mean)
+            step = step - step.mean(-1, keepdims=True)
+        else:  # a caller's own loss counted nothing: the bias keeps its course
+            step = jnp.zeros_like(m)
+        m_new = ROUTER_BIAS_MOMENTUM * m + (1 - ROUTER_BIAS_MOMENTUM) * step
+        return {**upd, "router_bias": m_new, "router_bias_m": m_new - m}
+
+    updates = {**updates, "layers": moved(params["layers"], updates["layers"],
+                                          "router_load")}
+    if cfg.n_mtp_modules:
+        updates["mtp"] = {**updates["mtp"], "layers": moved(
+            params["mtp"]["layers"], updates["mtp"]["layers"],
+            "mtp_router_load")}
+    return updates, stats
 
 
 def _fill_take(rows: jax.Array, index: jax.Array) -> jax.Array:
@@ -603,15 +705,15 @@ def _moe_ffn(cfg: MoEConfig, h: jax.Array, layer: Params
 
 def ffn_half(cfg: MoEConfig, x: jax.Array, layer: Params
              ) -> Tuple[jax.Array, jax.Array]:
-    """Pre-norm MoE FFN + residual; returns (hidden, aux_loss). The norm
-    lies under the scope of its first reader (``moe_router``), the residual
-    sum under ``moe_combine``, whose result it takes."""
+    """Pre-norm MoE FFN joined to the stream; returns (hidden, aux_loss).
+    The norm lies under the scope of its first reader (``moe_router``), the
+    residual sum under ``moe_combine``, whose result it takes."""
     with jax.named_scope("moe_router"):
         h = llama.rmsnorm(x, layer["mlp_norm"].astype(cfg.compute_dtype),
                           cfg.norm_eps)
     ffn, aux, _ = _moe_ffn(cfg, h, layer)
     with jax.named_scope("moe_combine"):
-        return x + ffn, aux
+        return llama.join(x, ffn), aux
 
 
 # What one served MoE layer says of its routing, an int32 vector in this
@@ -716,7 +818,8 @@ def _moe_block(cfg: MoEConfig, x: jax.Array, layer: Params,
     """Shared llama attention half, under the scope ``attn_full``, + MoE
     FFN; returns (hidden, aux_loss)."""
     with jax.named_scope("attn_full"):
-        x = llama.attention_half(cfg, x, layer, sin, cos, segment_ids)
+        x = llama.join(x, llama.attention_half(cfg, x, layer, sin, cos,
+                                               segment_ids))
     return ffn_half(cfg, x, layer)
 
 
@@ -738,8 +841,9 @@ def _pick(tree: Params, kinds: Tuple[str, ...], j: int) -> Params:
     return layer
 
 
-def _mixer_half(cfg: MoEConfig, kind: str, x, layer, sin, cos, segment_ids):
-    """The layer's mixer of ``kind``, a pre-norm branch on ``x``, under the
+def _mixer_half(cfg: MoEConfig, kind: str, h, layer, sin, cos, segment_ids,
+                mla_tables=None):
+    """The layer's mixer of ``kind``, a pre-norm branch on ``h``, under the
     scope ``attn_<kind>``."""
     if kind == "kda" and segment_ids is not None:
         raise NotImplementedError(
@@ -747,12 +851,35 @@ def _mixer_half(cfg: MoEConfig, kind: str, x, layer, sin, cos, segment_ids):
             "recurrence would have to reset its state at a boundary")
     with jax.named_scope("attn_" + kind):
         if kind == "kda":
-            return mixers.kda_half(cfg, x, layer)
+            return mixers.kda_half(cfg, h, layer)
         if kind == "mla":
-            return mixers.mla_half(cfg, x, layer, segment_ids)
+            return mixers.mla_half(cfg, h, layer, segment_ids, mla_tables)
         return llama.attention_half(
-            cfg, x, layer, sin, cos, segment_ids, rotate=kind == "window",
+            cfg, h, layer, sin, cos, segment_ids, rotate=kind == "window",
             window=cfg.sliding_window if kind == "window" else None)
+
+
+def _read(cfg: MoEConfig, x: jax.Array, layer: Params, half: str):
+    """What the ``half`` ("attn" or "mlp") of a layer reads of the stream
+    ``x``, and what its ``_join`` needs beside the branch: the stream itself
+    and nothing for the plain sum; the hyper-connection's mix of the rows
+    and its two matrices for the write (``hyper.mix_in``, under
+    ``hyper_mix``) where the stream is widened."""
+    if not cfg.hc_mult:
+        return x, None
+    return hyper.mix_in(
+        x, {name: layer[f"hc_{half}_{name}"] for name in hyper.LEAVES},
+        iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_clamp,
+        norm_eps=cfg.norm_eps)
+
+
+def _join(scope: str, x: jax.Array, branch: jax.Array, mix) -> jax.Array:
+    """``llama.join``: the plain sum under the half's own ``scope``, the
+    hyper-connection's write under its own (``hyper_mix``, outermost)."""
+    if mix is not None:
+        return llama.join(x, branch, mix)
+    with jax.named_scope(scope):
+        return llama.join(x, branch)
 
 
 def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
@@ -761,18 +888,23 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
     attention half rotated inside the band or unrotated and full, the delta
     rule, latent attention), then a dense SwiGLU (no ``aux``, ``load`` or
     ``kept``: None) or the shared expert beside the routed ones, every
-    branch normed before and after where the config says so. ``load`` [E]
+    branch normed before and after where the config says so, read from the
+    stream by ``_read`` and joined to it by ``_join``. ``load`` [E]
     is the choices each of the ``n_experts`` got, ``kept`` how many took a
     slot in a held expert's buffer."""
     cdt = cfg.compute_dtype
 
-    def run(x, layer, sin, cos, segment_ids):
-        x = _mixer_half(cfg, kind, x, layer, sin, cos, segment_ids)
+    def run(x, layer, sin, cos, segment_ids, mla_tables=None):
+        h, mix = _read(cfg, x, layer, "attn")
+        x = _join("attn_" + kind, x, _mixer_half(
+            cfg, kind, h, layer, sin, cos, segment_ids, mla_tables), mix)
+        h, mix = _read(cfg, x, layer, "mlp")
         if dense:
             with jax.named_scope("mlp"):
-                return llama.ffn_half(cfg, x, layer), None, None, None
+                branch = llama.ffn_half(cfg, h, layer)
+            return _join("mlp", x, branch, mix), None, None, None
         with jax.named_scope("moe_router"):
-            h = llama.rmsnorm(x, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
+            h = llama.rmsnorm(h, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
         ffn, aux, routing = _moe_ffn(cfg, h, layer)
         if cfg.n_shared_experts:
             with jax.named_scope("moe_shared"):
@@ -784,14 +916,15 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
                 routing["topk_idx"].reshape(-1)].add(1)
             kept = routing["keep"].sum(dtype=jnp.int32)
         with jax.named_scope("moe_combine"):
-            x = x + llama.post_norm(cfg, ffn, layer, "mlp_post_norm")
-        return x, aux, load, kept
+            ffn = llama.post_norm(cfg, ffn, layer, "mlp_post_norm")
+        return _join("moe_combine", x, ffn, mix), aux, load, kept
 
     return run
 
 
 def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
-          segment_ids) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+          segment_ids, mla_tables=None
+          ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """A patterned config's layers over ``x``: the leading dense layers one
     after another (their kinds need repeat nothing), then a ``lax.scan``
     over the repeats of the expert layers' shortest repeating pattern, the
@@ -805,11 +938,12 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
         layer = _pick(params["dense_layers"], leading, i)
         run = _patterned_layer(cfg, kind, dense=True)
         x = llama.remat_block(cfg, lambda x, layer, run=run: run(
-            x, layer, sin, cos, segment_ids)[0])(x, layer)
+            x, layer, sin, cos, segment_ids, mla_tables)[0])(x, layer)
 
     period = cfg.period()
     runs = [llama.remat_block(cfg, lambda x, layer, run=_patterned_layer(
-        cfg, kind, dense=False): run(x, layer, sin, cos, segment_ids))
+        cfg, kind, dense=False): run(x, layer, sin, cos, segment_ids,
+                                     mla_tables))
         for kind in period]
 
     def body(carry, layers):
@@ -833,13 +967,11 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
     return x, aux, load.reshape(-1, cfg.n_experts), kept.reshape(-1)
 
 
-def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
-                   segment_ids=None
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array, Dict[str, Any]]:
-    """-> (hidden, head, total_aux_loss, stats). ``stats`` is what the
-    patterned form counts of its routing: ``ROUTING_COUNTERS`` by name and
-    the layers' ``router_load`` [L, E], which moves the selection bias
-    (``buffer_updates``); {} for the old stack, which counts nothing."""
+def _trunk(params: Params, tokens: jax.Array, cfg: MoEConfig, segment_ids):
+    """tokens [b, s] -> (the stream after the last layer, before the final
+    norm, its rows summed where it is widened; the expert layers' summed
+    aux; their ``load`` [L, E] and ``kept`` [L], None for the old stack,
+    which counts nothing; an ``mla`` layer's rotary tables)."""
     if cfg.pipeline_axis is not None:
         raise NotImplementedError(
             "pipeline parallelism for the MoE family is not implemented "
@@ -848,6 +980,11 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
     cdt = cfg.compute_dtype
     if cfg.layer_kinds:
         llama.refuse_served_only(cfg)
+    elif cfg.hc_mult or cfg.n_mtp_modules:
+        raise NotImplementedError(
+            "hc_mult and n_mtp_modules are the patterned form's: the old "
+            "stack's layers join their branches by the plain sum and its "
+            "loss has one term (set layer_kinds)")
     with jax.named_scope("embed"):
         x = llama.embed(params, cfg, tokens)
     # the rotary tables under the scope of the layers that read them: a
@@ -861,20 +998,89 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
         x, a = _moe_block(cfg, x, layer, sin, cos, segment_ids)
         return (x, aux + a), None
 
-    stats = {}
-    if cfg.layer_kinds:
-        x, aux, load, kept = _walk(params, x, cfg, sin, cos, segment_ids)
-        with jax.named_scope("moe_router"):
-            stats = {**routing_counters(cfg, load, kept), "router_load": load}
-    else:
+    if not cfg.layer_kinds:
         (x, aux), _ = jax.lax.scan(llama.remat_block(cfg, body),
                                    (x, jnp.zeros((), jnp.float32)),
                                    params["layers"])
+        return x, aux, None, None, None
+    with jax.named_scope("attn_mla"):
+        mla_tables = mixers.mla_rope_tables(cfg, tokens.shape[1])
+    if cfg.hc_mult:
+        x = hyper.widen(x, cfg.hc_mult)
+    x, aux, load, kept = _walk(params, x, cfg, sin, cos, segment_ids,
+                               mla_tables)
+    if cfg.hc_mult:
+        x = hyper.narrow(x)
+    return x, aux, load, kept, mla_tables
+
+
+def _mtp(params: Params, cfg: MoEConfig, x: jax.Array, targets: jax.Array,
+         head: jax.Array, mask, segment_ids, mla_tables):
+    """The prediction module, everything of it under the scope ``mtp``: x
+    [b, s, d] the trunk's stream before the final norm, ``targets`` [b, s]
+    the next tokens. ``h' = [rms(x) ; rms(E[next token])] @ proj``; one
+    expert layer of the last layer's kind on a stream started from ``h'``
+    (``hc_mult`` copies of it where the stream is widened); its own final
+    norm; the shared ``head``; the cross entropy of the token after next,
+    which lies inside the row for all positions but the last (``mask``, the
+    next tokens', is read at the target's place). Returns (that cross
+    entropy, the layer's aux, its ``load`` [1, E] and ``kept`` [1])."""
+    cdt, m = cfg.compute_dtype, params["mtp"]
+    kind = cfg.layer_kinds[-1]
+    with jax.named_scope("mtp"):
+        joined = jnp.concatenate([
+            llama.rmsnorm(x, m["hnorm"].astype(cdt), cfg.norm_eps),
+            llama.rmsnorm(llama.embed(params, cfg, targets),
+                          m["enorm"].astype(cdt), cfg.norm_eps)], axis=-1)
+        x = joined @ m["proj"].astype(cdt)
+        sin, cos = (llama.rope_angles(targets.shape[1], cfg.head_dim,
+                                      cfg.rope_theta, cdt)
+                    if kind == "window" else (None, None))
+        if cfg.hc_mult:
+            x = hyper.widen(x, cfg.hc_mult)
+        run = _patterned_layer(cfg, kind, dense=False)
+        x, aux, load, kept = llama.remat_block(
+            cfg, lambda x, layer: run(x, layer, sin, cos, segment_ids,
+                                      mla_tables))(
+            x, _pick(m["layers"], (kind,), 0))
+        if cfg.hc_mult:
+            x = hyper.narrow(x)
+        x = llama.rmsnorm(x, m["final_norm"].astype(cdt), cfg.norm_eps)
+        after = jnp.roll(targets, -1, axis=1)
+        live = jnp.ones(targets.shape, jnp.float32) if mask is None \
+            else jnp.roll(mask.astype(jnp.float32), -1, axis=1)
+        live = live.at[:, -1].set(0.0)
+        ce = llama.chunked_ce(x, head, after, live, cfg.loss_chunk)
+    return ce, aux, load[None], kept[None]
+
+
+def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
+                   segment_ids=None
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array, Dict[str, Any]]:
+    """-> (hidden, head, total_aux_loss, stats). ``stats`` is what the
+    patterned form counts of its routing: ``ROUTING_COUNTERS`` by name and
+    the layers' ``router_load`` [L, E], which moves the selection bias
+    (``buffer_updates``); {} for the old stack, which counts nothing. A
+    prediction module is the loss's (``loss_and_stats``) and no part of
+    this."""
+    x, aux, load, kept, _ = _trunk(params, tokens, cfg, segment_ids)
+    stats = {}
+    if cfg.layer_kinds:
+        with jax.named_scope("moe_router"):
+            stats = {**routing_counters(cfg, load, kept), "router_load": load}
+    x, head = _final(params, cfg, x)
+    return x, head, aux / cfg.n_expert_layers, stats
+
+
+def _final(params: Params, cfg: MoEConfig, x: jax.Array):
+    """The final norm of the trunk's stream and the head, under
+    ``loss_head``."""
+    cdt = cfg.compute_dtype
     with jax.named_scope("loss_head"):
         x = llama.rmsnorm(x, params["final_norm"].astype(cdt), cfg.norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"]).astype(cdt)
-    return x, head, aux / cfg.n_expert_layers, stats
+    return x, head
 
 
 def forward(params: Params, tokens: jax.Array, cfg: MoEConfig,
@@ -889,6 +1095,8 @@ def loss_and_stats(params: Params, batch: Dict[str, jax.Array], cfg: MoEConfig
     loop's head gathered once before it under a mesh), and what the forward
     counted (``forward_hidden``'s ``stats``)."""
     inputs, targets = llama.inputs_and_targets(batch["tokens"])
+    if cfg.n_mtp_modules:
+        return _loss_with_mtp(params, batch, cfg, inputs, targets)
     x, head, aux, stats = forward_hidden(params, inputs, cfg,
                                          batch.get("segment_ids"))
     with jax.named_scope("loss_head"):
@@ -897,6 +1105,32 @@ def loss_and_stats(params: Params, batch: Dict[str, jax.Array], cfg: MoEConfig
         ce = llama.chunked_ce(x, head, targets, batch.get("loss_mask"),
                               cfg.loss_chunk)
     return ce + cfg.router_aux_coef * aux, stats
+
+
+def _loss_with_mtp(params: Params, batch: Dict[str, jax.Array],
+                   cfg: MoEConfig, inputs: jax.Array, targets: jax.Array):
+    """``loss_and_stats`` of a config with a prediction module: the main
+    head's cross entropy plus ``mtp_weight`` times the module's (``_mtp``),
+    each a mean over its counted positions, plus the balancing term meaned
+    over the trunk's expert layers and the module's. The counters count the
+    module's layer too; its choices move its own bias
+    (``mtp_router_load``)."""
+    segment_ids, mask = batch.get("segment_ids"), batch.get("loss_mask")
+    trunk, aux, load, kept, mla_tables = _trunk(params, inputs, cfg,
+                                                segment_ids)
+    x, head = _final(params, cfg, trunk)
+    with jax.named_scope("loss_head"):
+        head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
+                                        targets.shape[1])
+        ce = llama.chunked_ce(x, head, targets, mask, cfg.loss_chunk)
+    ce_mtp, aux_mtp, load_mtp, kept_mtp = _mtp(
+        params, cfg, trunk, targets, head, mask, segment_ids, mla_tables)
+    with jax.named_scope("moe_router"):
+        stats = {**routing_counters(cfg, jnp.concatenate([load, load_mtp]),
+                                    jnp.concatenate([kept, kept_mtp])),
+                 "router_load": load, "mtp_router_load": load_mtp}
+    aux = (aux + aux_mtp) / cfg.n_routed_layers
+    return ce + cfg.mtp_weight * ce_mtp + cfg.router_aux_coef * aux, stats
 
 
 def lm_loss(params: Params, batch: Dict[str, jax.Array],
@@ -931,6 +1165,13 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
         # matrices into the heads like wq, out of them like wo, the narrow
         # ones (a latent, a low rank, a scalar a head) whole on that side
         (r"layers/(kda|mla)/w[qkv]$", P(None, "fsdp", "tp")),
+        (r"layers/mla/wq_a$", P(None, "fsdp", None)),
+        (r"layers/mla/wq_b$", P(None, None, "tp")),
+        # a hyper-connection's leaves: phi's long side like a matrix's model
+        # dim, the rest (a gain the stream's width, 24 biases, 3 scalars) whole
+        (r"layers/hc_\w+_phi$", P(None, "fsdp", None)),
+        (r"layers/hc_\w+_(g|b|alpha)$", P(None)),
+        (r"mtp/proj$", P("fsdp", "tp")),
         (r"layers/(kda|mla)/wo$", P(None, "tp", "fsdp")),
         (r"layers/(kda/(wb|[fg]_down)|mla/wkv_a)$", P(None, "fsdp", None)),
         (r"layers/(kda/[fg]_up|mla/wkv_b|kda/conv_[qkv])$",

@@ -385,7 +385,7 @@ def _mamba_out(cfg: SambaYConfig, x, y, xs, z, layer: Params):
         mem = y + xs * layer["D"]                        # float32
         x = x + _mm(cfg, mem * jax.nn.silu(z), layer["out_proj"])
     with jax.named_scope("mlp"):
-        return llama.ffn_half(cfg, x, layer), mem
+        return llama.join(x, llama.ffn_half(cfg, x, layer)), mem
 
 
 def _queries(cfg: SambaYConfig, u, layer: Params):
@@ -434,7 +434,7 @@ def _gmu_block(cfg: SambaYConfig, x, mem, layer: Params):
         x = x + _mm(cfg, mem * jax.nn.silu(_mm(cfg, u, layer["w1"])),
                     layer["w2"])
     with jax.named_scope("mlp"):
-        return llama.ffn_half(cfg, x, layer)
+        return llama.join(x, llama.ffn_half(cfg, x, layer))
 
 
 def _depths(cfg: SambaYConfig) -> Dict[str, jax.Array]:
@@ -502,7 +502,7 @@ def forward_with_cache(params: Params, tokens: jax.Array, cfg: SambaYConfig,
                               q_offset=pos, kv_heads_major=True)
             x = _after_attention(cfg, x, out, layer, depth["window"][i])
         with jax.named_scope("mlp"):
-            x = llama.ffn_half(cfg, x, layer)
+            x = llama.join(x, llama.ffn_half(cfg, x, layer))
         return x, (wk.at[i].set(ring_k), wv.at[i].set(ring_v))
 
     def shared(x, bufs, i, kind, last: bool):
@@ -531,7 +531,7 @@ def forward_with_cache(params: Params, tokens: jax.Array, cfg: SambaYConfig,
                           kv_heads_major=True)
             x = _after_attention(cfg, x, out, layer, depth[kind][i])
         with jax.named_scope("mlp"):
-            x = llama.ffn_half(cfg, x, layer)
+            x = llama.join(x, llama.ffn_half(cfg, x, layer))
         return x, (ck, cv)
 
     def gmu(x, bufs, i):
@@ -617,7 +617,7 @@ def decode_step_in_place(params: Params, tok: jax.Array, cfg: SambaYConfig,
                           rows_of(wv, i), q_offset=pos, kv_heads_major=True)
             x = _after_attention(cfg, x, out, layer, depth["window"][i])
         with jax.named_scope("mlp"):
-            x = llama.ffn_half(cfg, x, layer)
+            x = llama.join(x, llama.ffn_half(cfg, x, layer))
         return x, (wk, wv)
 
     def shared(x, bufs, i, kind):
@@ -637,7 +637,7 @@ def decode_step_in_place(params: Params, tok: jax.Array, cfg: SambaYConfig,
             x = _after_attention(cfg, x, out, {**layer, "wo": held(layer["wo"])},
                                  depth[kind][i])
         with jax.named_scope("mlp"):
-            x = llama.ffn_half(cfg, x, layer)
+            x = llama.join(x, llama.ffn_half(cfg, x, layer))
         return x, (ck, cv)
 
     def gmu(x, bufs, i):

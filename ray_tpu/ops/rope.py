@@ -2,13 +2,67 @@
 
 from __future__ import annotations
 
+import math
+from typing import NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
 
 
+class Yarn(NamedTuple):
+    """A published ``rope_scaling`` of type ``yarn`` (arXiv:2309.00071, as
+    DeepSeek-V3's modeling file applies it): the context grew ``factor``
+    times from ``original_max_position``; a pair that turns more than
+    ``beta_fast`` times in the original context keeps its frequency, one
+    that turns less than ``beta_slow`` times has it divided by ``factor``,
+    and the pairs between blend the two along a linear ramp."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _m(factor: float, mscale: float) -> float:
+        return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    def table_scale(self) -> float:
+        """What the sin and cos tables are multiplied by."""
+        return (self._m(self.factor, self.mscale)
+                / self._m(self.factor, self.mscale_all_dim))
+
+    def softmax_scale(self) -> float:
+        """What the attention's ``d ** -0.5`` is multiplied by."""
+        return self._m(self.factor, self.mscale_all_dim) ** 2
+
+    def inv_freq(self, head_dim: int, theta: float) -> jax.Array:
+        """[head_dim // 2] float32: each pair's frequency, the blend."""
+        half = head_dim // 2
+
+        def pair_that_turns(times: float) -> float:
+            return head_dim * math.log(self.original_max_position / (
+                times * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(pair_that_turns(self.beta_fast)), 0)
+        high = min(math.ceil(pair_that_turns(self.beta_slow)), head_dim - 1)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        plain = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                                 / head_dim))
+        return plain / self.factor * ramp + plain * (1.0 - ramp)
+
+
 def rope_angles(seq_len: int, head_dim: int, theta: float = 10000.0,
-                dtype=jnp.float32):
-    """(sin, cos) tables of shape [seq_len, head_dim // 2]."""
+                dtype=jnp.float32, yarn: Optional[Yarn] = None):
+    """(sin, cos) tables of shape [seq_len, head_dim // 2]; under ``yarn``
+    at its blended frequencies and times its ``table_scale``."""
+    if yarn is not None:
+        angles = jnp.outer(jnp.arange(seq_len, dtype=jnp.float32),
+                           yarn.inv_freq(head_dim, theta))
+        scale = yarn.table_scale()
+        return ((jnp.sin(angles) * scale).astype(dtype),
+                (jnp.cos(angles) * scale).astype(dtype))
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     pos = jnp.arange(seq_len, dtype=jnp.float32)
     angles = jnp.outer(pos, inv_freq)

@@ -1227,6 +1227,14 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"{ep['summary_block']} summaries) visited / needed "
                   f"{ep['tiles_visited']} / {ep['tiles_needed']} a head "
                   f"({ep['impl']})")
+        hp = summ.get("hyper_plan") or {}
+        if hp:
+            print(f"  hyper-connections: a stream of {hp['rows']} rows of "
+                  f"{hp['d_model']}, {hp['sinkhorn_iters']} Sinkhorn "
+                  f"iterations a half layer; the least passes over the "
+                  f"stream move {hp['stream_bytes_fwd'] / 1e3:.1f} KB forward"
+                  f" and {hp['stream_bytes_bwd'] / 1e3:.1f} KB backward a "
+                  f"token and half layer ({hp['impl']}; {hp['layout']})")
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):
             held = routing.get("moe_held", 0)

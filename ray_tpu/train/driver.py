@@ -113,10 +113,11 @@ class StepDriver:
             self.recorder: Optional[Any] = TrainRecorder()
         except Exception:  # noqa: BLE001 — observability must not block
             self.recorder = None
-        # the flash kernels' tilings, the chunked delta rule's plan and EVA
-        # attention's, noted as a launch traces them: the recorder's own
-        # list and dicts, so a program compiled later shows too
-        from ray_tpu.ops import eva, kda
+        # the flash kernels' tilings, the chunked delta rule's plan, EVA
+        # attention's and the hyper-connections', noted as a launch traces
+        # them: the recorder's own list and dicts, so a program compiled
+        # later shows too
+        from ray_tpu.ops import eva, hyper, kda
         from ray_tpu.ops.pallas import flash
 
         rec = self.recorder
@@ -125,7 +126,9 @@ class StepDriver:
         def noting_plans():
             with flash.noting_plans(rec.flash_plans if rec is not None else []), \
                     kda.noting_plan(rec.kda_plan if rec is not None else {}), \
-                    eva.noting_plan(rec.eva_plan if rec is not None else {}):
+                    eva.noting_plan(rec.eva_plan if rec is not None else {}), \
+                    hyper.noting_plan(
+                        rec.hyper_plan if rec is not None else {}):
                 yield
 
         self._noting_plans = noting_plans

@@ -63,9 +63,12 @@ def _attention_madds(cfg, seq: int) -> float:
     products of chunk x width and three of width x width a head; for
     ``eva`` (a dense config's ``attn_kind``) the pairs a query sees, its
     window's causal half and one summary a chunk of every earlier window,
-    and the pooling that makes a summary (a key and a value a position)."""
+    and the pooling that makes a summary (a key and a value a position). A
+    prediction module's layer (``n_mtp_modules``) is one more of the last
+    layer's kind."""
     kinds = getattr(cfg, "layer_kinds", ()) or (
         getattr(cfg, "attn_kind", "full"),) * cfg.n_layers
+    kinds = kinds + kinds[-1:] * getattr(cfg, "n_mtp_modules", 0)
     w = min(getattr(cfg, "sliding_window", None) or seq, seq)
 
     def layer(kind: str) -> float:
@@ -89,8 +92,13 @@ def _attention_madds(cfg, seq: int) -> float:
 
 
 def train_flops_per_token(cfg, seq: int) -> float:
-    """Fwd+bwd FLOPs per trained token: 6N + the mixers' own products."""
-    return 6.0 * _flops_params(cfg) + 6 * _attention_madds(cfg, seq)
+    """Fwd+bwd FLOPs per trained token: 6N + the mixers' own products; N
+    holds a prediction module's matrices (its layer, its projection of two
+    inputs) and a low-rank query's two factors, and the head counts once
+    more for every module that projects through it too."""
+    again = getattr(cfg, "n_mtp_modules", 0) * cfg.d_model * cfg.vocab_size
+    return (6.0 * (_flops_params(cfg) + again)
+            + 6 * _attention_madds(cfg, seq))
 
 
 def train_step_flops(cfg, batch: int, seq: int) -> float:

@@ -145,6 +145,12 @@ class TrainRecorder(RecorderCore):
         # tiles_visited, tiles_needed, impl), static likewise; a step
         # without an ``eva`` mixer leaves it empty
         self.eva_plan: Dict[str, Any] = {}
+        # what the step's hyper-connections do at their shape
+        # (``ops/hyper.noting_plan``: rows, d_model, sinkhorn_iters, layout,
+        # stream_bytes_fwd, stream_bytes_bwd a token and half layer by the
+        # least passes, impl), static likewise; a step whose stream is one
+        # row leaves it empty
+        self.hyper_plan: Dict[str, Any] = {}
         # what the driver's plan and compiled step say of themselves, static
         # like the list above: how the plan placed a sparse model's expert
         # matrices (``moe.expert_placement``: "expert" or "model_dim"; None
@@ -356,7 +362,7 @@ class TrainRecorder(RecorderCore):
         from the first one's start to the last one's end on the wall clock,
         and their counters, folded and launch by launch in order
         (``per_launch``, for a reader that wants some of them: a window
-        without its warm-up), and the step's ``eva_plan`` and
+        without its warm-up), and the step's ``eva_plan``, ``hyper_plan`` and
         ``step_memory`` where it has them.
         What the trainer's process keeps of a run once the worker is gone
         (``JaxTrainer`` records it as the ``train_launches`` span); None
@@ -370,6 +376,8 @@ class TrainRecorder(RecorderCore):
                 "t1": max(r["t_done"] for r in recs),
                 "per_launch": [dict(r.get("counters") or {}) for r in recs],
                 **({"eva_plan": dict(self.eva_plan)} if self.eva_plan else {}),
+                **({"hyper_plan": dict(self.hyper_plan)}
+                   if self.hyper_plan else {}),
                 **({"step_memory": dict(self.step_memory)}
                    if self.step_memory else {}),
                 **self._fold_counters(recs)}
@@ -452,6 +460,7 @@ class TrainRecorder(RecorderCore):
             "flash_plans": [dict(p) for p in self.flash_plans],
             "kda_plan": dict(self.kda_plan),
             "eva_plan": dict(self.eva_plan),
+            "hyper_plan": dict(self.hyper_plan),
             "expert_placement": self.expert_placement,
             "collectives": {k: dict(v) for k, v in
                             (self.collectives or {}).items()},

@@ -711,7 +711,8 @@ def _kda_half_digest(cfg, mesh=None):
 
     with mesh_scope(mesh):
         text = str(jax.make_jaxpr(jax.grad(
-            lambda x, l: mixers.kda_half(cfg, x, l).astype(jnp.float32).sum(),
+            lambda x, l: llama.join(x, mixers.kda_half(cfg, x, l)).astype(
+                jnp.float32).sum(),
             (0, 1)))(*_a_layer(cfg)))
     text = re.sub(r"\s+", " ", re.sub(r" at 0x[0-9a-f]+", "", text))
     return hashlib.sha256(text.encode()).hexdigest()[:16]

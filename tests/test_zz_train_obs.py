@@ -301,7 +301,8 @@ def test_window_summary_carves_launches():
         # synthetic recorder traced no flash kernel and compiled no step)
         assert rec.window_summary(0.0, 999.0) == {
             "window_launches": 0, "flash_plans": [], "kda_plan": {},
-            "eva_plan": {}, "expert_placement": None, "collectives": {},
+            "eva_plan": {}, "hyper_plan": {}, "expert_placement": None,
+            "collectives": {},
             "step_memory": {}, "routing": {}}
         # full summary spans both
         assert rec.summary()["window_launches"] == 2
@@ -491,6 +492,8 @@ def test_api_train_and_cli_json(rt_cluster):
             window=2048, chunk=16, windows=8, chunks=1024,
             summaries_seen=896, block=1024, summary_block=128,
             tiles_needed=80, tiles_visited=80)
+        from ray_tpu.ops import hyper
+        rec.hyper_plan.update(hyper.plan(4, 3584, 2, 20))
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
@@ -550,6 +553,10 @@ def test_api_train_and_cli_json(rt_cluster):
                 "sees at most 896 summaries, 32 heads of 128; score tiles "
                 "(1024 rows x 1024 keys or 128 summaries) visited / needed "
                 "80 / 80 a head (pallas)") in text
+        assert ("hyper-connections: a stream of 4 rows of 3584, 20 Sinkhorn "
+                "iterations a half layer; the least passes over the stream "
+                "move 100.4 KB forward and 164.9 KB backward a token and "
+                "half layer (xla;") in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
