@@ -577,11 +577,16 @@ def remat_block(cfg: LlamaConfig, fn):
     layer) carries no such name and keeps what the dots policy keeps, which
     in a ``kda`` layer is its projections' results too (q, k, v and the two
     low-rank gates, ~55 KB a token): recomputing them would cost a third
-    more of the layer's products and the memory is there. An ``eva`` block
-    keeps by name alone (below)."""
+    more of the layer's products and the memory is there. Likewise where
+    the hyper-connections' kernels ran inside it (``ops/hyper.py``,
+    ``impl="pallas"``): ``mix_in``'s ``h``, coefficients and the two small
+    results its backward call reads, so that the backward does not run the
+    norm over the rows, ``phi``'s product and the iterations a second time
+    (``h`` is 58.7 MB a half layer at Xing4's cell: 13.56 GiB compiled for
+    13.32). An ``eva`` block keeps by name alone (below)."""
     if not cfg.remat:
         return fn
-    from ray_tpu.ops import kda
+    from ray_tpu.ops import hyper, kda
     from ray_tpu.ops.pallas import flash
 
     policies = jax.checkpoint_policies
@@ -604,7 +609,8 @@ def remat_block(cfg: LlamaConfig, fn):
     return jax.checkpoint(fn, policy=policies.save_from_both_policies(
         policies.dots_with_no_batch_dims_saveable,
         policies.save_only_these_names(*flash.RESIDUAL_NAMES,
-                                       *kda.RESIDUAL_NAMES)))
+                                       *kda.RESIDUAL_NAMES,
+                                       *hyper.RESIDUAL_NAMES)))
 
 
 def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,

@@ -870,7 +870,8 @@ def _read(cfg: MoEConfig, x: jax.Array, layer: Params, half: str):
     return hyper.mix_in(
         x, {name: layer[f"hc_{half}_{name}"] for name in hyper.LEAVES},
         iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_clamp,
-        norm_eps=cfg.norm_eps)
+        norm_eps=cfg.norm_eps,
+        impl="pallas" if cfg.attn_impl == "flash" else "xla")
 
 
 def _join(scope: str, x: jax.Array, branch: jax.Array, mix) -> jax.Array:

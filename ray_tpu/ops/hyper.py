@@ -33,9 +33,28 @@ X (g * phi)``), so that the normed rows are never written. Everything but
 the rows and that product's operands is float32; a row is rounded to the
 stream's dtype once, as it is written.
 
-The backward is autodiff's through all of it, the iterations included;
-under ``models/llama.remat_block`` the forward of a layer's two mixes runs
-again in its backward. Scopes: everything here lies under ``hyper_mix``,
+Two paths that share no logic, chosen by ``mix_in``'s ``impl`` and by what
+it can observe (``kernels_tile``):
+
+``"pallas"``  (``attn_impl="flash"``, one chip, ``d`` whole lanes of 128 and
+    the tokens whole tiles) ``ops/pallas/hyper_mix.py``: the two mixes as
+    ``jax.custom_vjp``s, each pass forward and backward one Pallas call that
+    holds a tile of tokens' ``n`` rows in VMEM and reads them once, the
+    iterations in VMEM; the coefficients one ``[tokens, 128]`` float32
+    array, a coefficient a lane (``Held``).
+``"xla"``     (the CPU tests' yardstick, and what a mesh of several chips
+    runs, because a Mosaic call is not partitioned) everything below under
+    plain autodiff, the iterations included; under
+    ``models/llama.remat_block`` the forward of a layer's two mixes runs
+    again in its backward. It is the reference the kernels are tested
+    against (``tests/test_hyper_mix.py``).
+
+What a remat block can keep of the kernels' path (``RESIDUAL_NAMES``):
+``mix_in``'s ``h``, its coefficients, and ``phi``'s normed product and the
+norm's ``rsqrt``, which its backward reads: a block that saves by these
+names runs that call, with its norm, product and iterations, once.
+
+Scopes: everything here lies under ``hyper_mix``, forward and backward,
 which its callers keep outermost (``models/moe._patterned_layer``).
 """
 
@@ -44,7 +63,7 @@ from __future__ import annotations
 import contextlib
 import math
 import threading
-from typing import Any, Dict, Iterator, NamedTuple, Tuple
+from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +73,10 @@ F32 = jnp.float32
 
 #: a half layer's leaves, under ``hc_<half>_<name>`` in a layer's tree
 LEAVES = ("g", "phi", "b", "alpha")
+#: what the kernels' ``mix_in`` names of its forward call's results
+#: (``jax.ad_checkpoint.checkpoint_name``): h, the coefficients, and the two
+#: its backward call reads beside the rows
+RESIDUAL_NAMES = ("hc_h", "hc_coef", "hc_z", "hc_inv")
 #: what ``init`` draws, "as trained" (seeded weights stand in for trained
 #: ones, as ``moe.ROUTER_BIAS_INIT`` does for the selection bias; a half
 #: would START from ``alpha`` 0.01 and ``neutral_bias``): ``alpha`` uniform in
@@ -73,6 +96,17 @@ class Mix(NamedTuple):
     (as a row's slab has them, so that a coefficient spreads along ``d``)."""
     post: jax.Array    # [b, s, n]
     res: jax.Array     # [b, s, n, n], [i, j]: row j's share of new row i
+
+
+class Held(NamedTuple):
+    """What the kernels' ``mix_in`` hands ``mix_out``: the rows as its
+    custom_vjp passed them through (``mix_out`` reads THESE, so that the
+    rows' cotangent so far enters ``mix_in``'s backward call and is summed
+    there), the coefficients [tokens, 128] float32 (``H_pre``, ``H_post``,
+    ``H_res`` row by row, a lane each) and the tokens a grid step."""
+    rows: jax.Array    # [n, b s, d]
+    coef: jax.Array
+    tile: int
 
 
 def columns(n: int) -> int:
@@ -144,12 +178,42 @@ def noting_plan(into: Dict[str, Any]) -> Iterator[None]:
         _noting.into = was
 
 
-def plan(n: int, d: int, itemsize: int, iters: int) -> Dict[str, Any]:
+def plan(n: int, d: int, itemsize: int, iters: int,
+         tile: Optional[int] = None) -> Dict[str, Any]:
+    """What ``mix_in`` does at one shape; pure. ``tile``: the tokens a grid
+    step of the kernels' (``kernels_tile``), None for XLA's form.
+    ``stream_bytes_moved_*``: what the four calls' block specs move a token
+    and half layer (``hyper_mix.bytes_moved``), beside the least passes'
+    ``stream_bytes_*``; None where XLA decides what moves."""
     fwd, bwd = stream_bytes(n, d, itemsize)
+    moved = (None, None)
+    if tile:
+        from ray_tpu.ops.pallas import hyper_mix
+
+        moved = hyper_mix.bytes_moved(n, d, itemsize)
     return {"rows": n, "d_model": d, "sinkhorn_iters": iters,
             "layout": "rows first [n, b, s, d]; coefficients "
-                      "[n*n + 2n, b, s] float32, tokens on the lanes",
-            "stream_bytes_fwd": fwd, "stream_bytes_bwd": bwd, "impl": "xla"}
+                      + ("[b s, 128] float32, a coefficient a lane" if tile
+                         else "[n*n + 2n, b, s] float32, tokens on the lanes"),
+            "stream_bytes_fwd": fwd, "stream_bytes_bwd": bwd,
+            "impl": "pallas" if tile else "xla", "tile_tokens": tile,
+            "stream_bytes_moved_fwd": moved[0],
+            "stream_bytes_moved_bwd": moved[1]}
+
+
+def kernels_tile(impl: str, tokens: int, d: int) -> Optional[int]:
+    """The tokens a grid step of ``ops/pallas/hyper_mix.py``'s calls where
+    they run, None where XLA's form does: ``impl`` ``"pallas"`` (the
+    caller's ``attn_impl == "flash"``), no mesh of several chips ambient (a
+    Mosaic call is not partitioned), ``d`` whole lanes and the tokens whole
+    tiles: the rule ``llama.eva_half`` and ``mixers`` choose by."""
+    from ray_tpu.ops.pallas import hyper_mix
+    from ray_tpu.parallel.context import current_mesh
+
+    mesh = current_mesh()
+    if impl != "pallas" or (mesh is not None and mesh.size > 1) or d % 128:
+        return None
+    return hyper_mix.tile_tokens(tokens)
 
 
 # ------------------------------------------------------------ the parts
@@ -166,9 +230,20 @@ def narrow(x: jax.Array) -> jax.Array:
         return jnp.sum(x.astype(F32), axis=0).astype(x.dtype)
 
 
-def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
+def _g_phi(half: Params) -> jax.Array:
+    """``g`` folded into ``phi``, float32 [n d, n^2 + 2n]."""
+    return half["g"].astype(F32)[:, None] * half["phi"].astype(F32)
+
+
+def sinkhorn(m: jax.Array, iters: int, eps: float, made=None) -> jax.Array:
     """m [n, n, ...] positive -> ``iters`` times its columns and then its
-    rows divided by their sums (``+ eps``)."""
+    rows divided by their sums (``+ eps``). ``made``: the result where a
+    kernel has formed it already (``hyper_mix.mix_in``'s call); it is handed
+    on as it is, so that both paths' ``H_res`` enters the mixes here and
+    nowhere else (what cuts its cotangent here cuts it in both:
+    ``tests/benchmark/xing4_chip_check.py``'s planted fault)."""
+    if made is not None:
+        return made
     for _ in range(iters):
         m = m / (m.sum(0, keepdims=True) + eps)
         m = m / (m.sum(1, keepdims=True) + eps)
@@ -182,8 +257,7 @@ def coefficients(x: jax.Array, half: Params, *, iters: int, eps: float,
     [b, s, n], H_res [b, s, n, n]), float32."""
     n, _, _, d = x.shape
     ms = jnp.mean(jnp.square(x.astype(F32)), axis=(0, 3))            # [b, s]
-    w = (half["g"].astype(F32)[:, None] * half["phi"].astype(F32)
-         ).astype(x.dtype).reshape(n, d, -1)
+    w = _g_phi(half).astype(x.dtype).reshape(n, d, -1)
     # (a product's result comes tokens first; the 24 columns are turned
     # onto the sublanes after it)
     raw = sum(jnp.einsum("bsd,dc->bsc", x[i], w[i],
@@ -199,15 +273,42 @@ def coefficients(x: jax.Array, half: Params, *, iters: int, eps: float,
             jnp.moveaxis(res, (0, 1), (-2, -1)))
 
 
+def _mix_in_kernels(x, half, tile, *, iters, eps, clamp, norm_eps):
+    """``mix_in`` through ``ops/pallas/hyper_mix.py``: ``g`` folded into
+    ``phi`` in float32 and turned [n, columns, d] outside the call (its
+    gradient's way back to both is autodiff's)."""
+    from ray_tpu.ops.pallas import hyper_mix
+
+    n, b, s, d = x.shape
+    c = columns(n)
+    h, coef, rows = hyper_mix.mix_in(
+        x.reshape(n, b * s, d),
+        _g_phi(half).reshape(n, d, c).transpose(0, 2, 1), half["b"],
+        half["alpha"], hyper_mix.Rule(iters, eps, tuple(clamp), norm_eps),
+        tile)
+    made = sinkhorn(None, iters, eps, coef)
+    if made is not coef:
+        # something stands in ``sinkhorn``'s place (the planted fault): its
+        # result takes H_res's lanes and no other
+        lane = jnp.arange(coef.shape[-1])
+        coef = jnp.where((lane >= 2 * n) & (lane < c), made, coef)
+    return h.reshape(b, s, d), Held(rows, coef, tile)
+
+
 def mix_in(x: jax.Array, half: Params, *, iters: int, eps: float,
-           clamp: Tuple[float, float], norm_eps: float
-           ) -> Tuple[jax.Array, Mix]:
+           clamp: Tuple[float, float], norm_eps: float, impl: str = "xla"):
     """The stream's rows [n, b, s, d] -> (what the half's branch reads
-    [b, s, d], what ``mix_out`` needs to write its result back)."""
+    [b, s, d], what ``mix_out`` needs to write its result back: a ``Mix``,
+    or a ``Held`` where the kernels run, ``kernels_tile``)."""
+    n, b, s, d = x.shape
+    tile = kernels_tile(impl, b * s, d)
     into = getattr(_noting, "into", None)
     if into is not None:
-        into.update(plan(x.shape[0], x.shape[-1], x.dtype.itemsize, iters))
+        into.update(plan(n, d, x.dtype.itemsize, iters, tile))
     with jax.named_scope("hyper_mix"):
+        if tile:
+            return _mix_in_kernels(x, half, tile, iters=iters, eps=eps,
+                                   clamp=clamp, norm_eps=norm_eps)
         pre, post, res = coefficients(x, half, iters=iters, eps=eps,
                                       clamp=clamp, norm_eps=norm_eps)
         h = sum(pre[..., i, None] * x[i].astype(F32)
@@ -215,10 +316,16 @@ def mix_in(x: jax.Array, half: Params, *, iters: int, eps: float,
         return h.astype(x.dtype), Mix(post, res)
 
 
-def mix_out(x: jax.Array, branch: jax.Array, mix: Mix) -> jax.Array:
+def mix_out(x: jax.Array, branch: jax.Array, mix) -> jax.Array:
     """The rows after the half: ``H_res`` over the rows plus ``H_post`` of
-    the branch [b, s, d]."""
+    the branch [b, s, d]. ``mix``: what ``mix_in`` returned of ``x``."""
     with jax.named_scope("hyper_mix"):
+        if isinstance(mix, Held):
+            from ray_tpu.ops.pallas import hyper_mix
+
+            return hyper_mix.mix_out(
+                mix.rows, branch.reshape(-1, branch.shape[-1]), mix.coef,
+                mix.tile).reshape(x.shape)
         n = x.shape[0]
         rows = [x[j].astype(F32) for j in range(n)]
         y = branch.astype(F32)

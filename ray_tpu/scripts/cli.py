@@ -1234,7 +1234,13 @@ def cmd_train(args: argparse.Namespace) -> int:
                   f"iterations a half layer; the least passes over the "
                   f"stream move {hp['stream_bytes_fwd'] / 1e3:.1f} KB forward"
                   f" and {hp['stream_bytes_bwd'] / 1e3:.1f} KB backward a "
-                  f"token and half layer ({hp['impl']}; {hp['layout']})")
+                  f"token and half layer"
+                  + (f", the four calls' blocks "
+                     f"{hp['stream_bytes_moved_fwd'] / 1e3:.1f} and "
+                     f"{hp['stream_bytes_moved_bwd'] / 1e3:.1f} KB, "
+                     f"{hp['tile_tokens']} tokens a grid step"
+                     if hp.get("tile_tokens") else "")
+                  + f" ({hp['impl']}; {hp['layout']})")
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):
             held = routing.get("moe_held", 0)

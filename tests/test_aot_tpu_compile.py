@@ -11,6 +11,7 @@ here at no chip time. Nothing runs: a compile that passes is not a chip run.
 The file's name sorts first so that tier-1 reaches it inside its time limit.
 """
 
+import collections
 import dataclasses
 import math
 import os
@@ -168,9 +169,34 @@ _EVA_MIX = """if True:
 """
 
 
-def _scheduled_bundles(dump, script, calls):
-    """{"fwd" | "bwd": the bundles the compiler schedules for the call
-    ``calls`` (a pattern with one group, the way) names}: ``script``
+_HYPER_MIX = """if True:
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops import hyper
+    from ray_tpu.ops.pallas import flash
+    flash._needs_interpret = lambda: False
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    n, s, d = 4, 8192, 3584
+    half = {"g": on((n * d,), jnp.bfloat16),
+            "phi": on((n * d, 24), jnp.bfloat16),
+            "b": on((24,), jnp.float32), "alpha": on((3,), jnp.float32)}
+
+    def loss(x, half):
+        h, mix = hyper.mix_in(x, half, iters=20, eps=1e-6, clamp=(-30., 30.),
+                              norm_eps=1e-6, impl="pallas")
+        return (hyper.mix_out(x, h + h, mix).astype(jnp.float32) ** 2).sum()
+
+    jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        on((n, 1, s, d), jnp.bfloat16), half).compile()
+"""
+
+
+def _scheduled_bundles(dump, script, calls, ways=("bwd", "fwd")):
+    """{way: the bundles the compiler schedules for the call ``calls`` (a
+    pattern with one group, the way: ``ways``, sorted) names}: ``script``
     compiled for a described v5e in a process of its own that starts with
     ``--xla_jf_dump_to`` (libtpu reads the flag once, writes
     ``*<call>*schedule-analysis_final_bundles.txt`` among much else, and
@@ -189,7 +215,7 @@ def _scheduled_bundles(dump, script, calls):
                 found[m.group(1)] = int(re.search(
                     r"total scheduled bundles:\s+(\d+)", f.read()).group(1))
     shutil.rmtree(dump, ignore_errors=True)
-    assert sorted(found) == ["bwd", "fwd"], done.stderr[-2000:]
+    assert sorted(found) == list(ways), done.stderr[-2000:]
     return found
 
 
@@ -209,6 +235,40 @@ def eva_mix_schedule(topo, tmp_path_factory):
     return _scheduled_bundles(
         tmp_path_factory.mktemp("eva_mix_schedule"), _EVA_MIX,
         r"eva_mix_(fwd|bwd)_s16384_h32_d128_c16")
+
+
+@pytest.fixture(scope="module")
+def hyper_mix_schedule(topo, tmp_path_factory):
+    """A half layer of the Xing4 cell (a stream of 4 rows, b1 x s8192 x
+    3584, bf16) through both mixes of ``ops/pallas/hyper_mix.py``."""
+    return _scheduled_bundles(
+        tmp_path_factory.mktemp("hyper_mix_schedule"), _HYPER_MIX,
+        r"mhc_(in_fwd|out_fwd|out_bwd|in_bwd)_n4_t8192_d3584",
+        ways=("in_bwd", "in_fwd", "out_bwd", "out_fwd"))
+
+
+def test_the_four_hyper_mix_calls_compile_and_keep_pace_with_hbm(
+        hyper_mix_schedule, capsys):
+    """Mosaic takes the four calls at the cell's shape and dtype (a tile of
+    256 tokens' four rows, the coefficients turned once a tile, ``phi``'s
+    product with ``d`` contracted of both operands, twenty iterations forward
+    and back on four slabs), and the schedule leaves each bound by HBM: a
+    grid step moves 9.2 MB (``mix_in`` forward), 16.5 (``mix_out`` forward),
+    25.7 (``mix_out`` backward) and 23.9 MB (``mix_in`` backward), 16,800 to
+    47,000 cycles at 819 GB/s and 1.5 GHz. A call's bundles as counted here
+    are its text's, each loop's body once: eight trips of 32 tokens a grid
+    step. ``mix_out`` 2,659 forward (2,594 a trip: 20,800 a grid step) and
+    4,452 backward (4,326 a trip: 34,700); ``mix_in`` 5,065 forward (two
+    loops of 377 and 421 a trip and 4,178 between them, the product and the
+    iterations: 10,600) and 6,331 backward (387 and 1,230 a trip, 4,500
+    between: 17,500) (PR 57)."""
+    with capsys.disabled():
+        print("\nhyper_mix at 4 x 8192 x 3584: a call's bundles, "
+              + ", ".join(f"{k} {v}" for k, v in sorted(
+                  hyper_mix_schedule.items())))
+    most = {"out_fwd": 3000, "out_bwd": 5000, "in_fwd": 5700, "in_bwd": 7100}
+    assert all(hyper_mix_schedule[k] <= v for k, v in most.items()), \
+        hyper_mix_schedule
 
 
 def test_the_eva_mix_pair_compiles_and_keeps_pace_with_hbm(eva_mix_schedule,
@@ -1054,18 +1114,28 @@ def test_xing4s_step_compiles_for_one_v5e_at_the_cells_shape(xing4_step,
     compiled = xing4_step[-1]
     customs = [line for line in compiled.as_text().splitlines()
                if "tpu_custom_call" in line and " custom-call(" in line]
-    calls = [re.search(r"flash_(fwd|dq|dkv)_bh32_q8192_k8192_d192v128_c1_w0",
-                       line) for line in customs]
-    # the dense layer, the scanned expert layers' one body, the module's
-    assert all(calls) and sorted(m.group(1) for m in calls) \
-        == sorted(flash.KINDS * 3), calls
+    calls = [re.search(r"flash_(fwd|dq|dkv)_bh32_q8192_k8192_d192v128_c1_w0"
+                       r"|mhc_(in|out)_(fwd|bwd)_n4_t8192_d3584", line)
+             for line in customs]
+    assert all(calls), [c for c, m in zip(customs, calls) if not m]
+    count = collections.Counter(
+        "_".join(filter(None, m.groups())) for m in calls)
+    # the dense layer, the scanned expert layers' one body, the module's:
+    # three layers' worth of the flash kernels and no second forward, and of
+    # the hyper-connections' four calls (PR 57) a half layer one each way,
+    # with the attention half's mix_out a second time in a layer's backward
+    # (its rows are what the feed-forward half's backward reads; mix_in's
+    # call, whose results the block keeps by name, runs once)
+    assert count == {**{kind: 3 for kind in flash.KINDS}, "in_fwd": 6,
+                     "out_fwd": 9, "out_bwd": 6, "in_bwd": 6}, count
     mem = compiled.memory_analysis()
     with capsys.disabled():
         print(f"\nxing4 b1 x s8192, K=2: temporaries "
               f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
               f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, peak "
               f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
-    assert mem.peak_memory_in_bytes < 15.75 * 2**30   # 13.32 at PR 56
+    # 13.32 at PR 56
+    assert mem.peak_memory_in_bytes < 15.75 * 2**30
     assert mem.argument_size_in_bytes > 5.0 * 2**30    # 913.6M x 6 bytes
     # and it names all of itself, as ``test_a_train_step_names_all_of_itself``
     # holds the older steps to (here and not a case of that test's: a case

@@ -390,6 +390,10 @@ def test_a_driver_launch_moves_every_leaf_and_notes_the_hyper_plan(family,
     assert plan == hyper.plan(4, 32, 4, 6)
     assert (plan["stream_bytes_fwd"], plan["stream_bytes_bwd"]) == (
         14 * 32 * 4, 23 * 32 * 4)
+    # the CPU's yardstick: XLA's form (``attn_impl="xla"``, and 32 is no
+    # whole number of lanes), which says so and what it cannot know
+    assert (plan["impl"], plan["tile_tokens"], plan["stream_bytes_moved_fwd"],
+            plan["stream_bytes_moved_bwd"]) == ("xla", None, None, None)
     driver.recorder.close()
 
 

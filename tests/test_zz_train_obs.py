@@ -493,7 +493,7 @@ def test_api_train_and_cli_json(rt_cluster):
             summaries_seen=896, block=1024, summary_block=128,
             tiles_needed=80, tiles_visited=80)
         from ray_tpu.ops import hyper
-        rec.hyper_plan.update(hyper.plan(4, 3584, 2, 20))
+        rec.hyper_plan.update(hyper.plan(4, 3584, 2, 20, 256))
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
@@ -556,7 +556,8 @@ def test_api_train_and_cli_json(rt_cluster):
         assert ("hyper-connections: a stream of 4 rows of 3584, 20 Sinkhorn "
                 "iterations a half layer; the least passes over the stream "
                 "move 100.4 KB forward and 164.9 KB backward a token and "
-                "half layer (xla;") in text
+                "half layer, the four calls' blocks 101.5 and 223.8 KB, 256 "
+                "tokens a grid step (pallas;") in text
         # the postmortem property: the snapshot SURVIVES close() —
         # `rt train stats` works after the driver is gone
         rec.close()
