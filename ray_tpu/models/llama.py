@@ -21,8 +21,9 @@ lives inside the framework.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -653,26 +654,28 @@ def lm_loss(params: Params, batch: Dict[str, jax.Array], cfg: LlamaConfig) -> ja
     """Next-token cross entropy; ``batch`` has tokens [B, S+1] (+opt. mask).
 
     With ``cfg.loss_chunk`` set (and dividing S), the vocab projection +
-    softmax run chunk-by-chunk under a ``lax.scan`` with full remat, so peak
-    HBM holds one [B, chunk, V] fp32 slice instead of [B, S, V] plus its
-    cotangent — the logits, not the activations, are what cap batch size at
-    32k vocab. Extra cost: the head matmul is recomputed in backward (~3% of
-    step FLOPs at 410M scale). Under a mesh that shards the head's model
-    dim the loop closes over a head gathered once before it
-    (``head_for_loss_loop``): no chunk, forward or recomputed, moves the
-    head, and its gradient crosses the chips once after the loop.
+    softmax run chunk-by-chunk under a ``lax.scan`` that forms both
+    gradients while it holds a chunk's logits (``_looped_ce``), so peak HBM
+    holds one [B, chunk, V] fp32 slice and a group's cotangent in the
+    compute dtype instead of [B, S, V] plus its cotangent — the logits, not
+    the activations, are what cap batch size at 32k vocab. Under a mesh
+    that shards the head's model dim the loop closes over a head gathered
+    once before it (``head_for_loss_loop``): no chunk moves the head, and
+    its gradient crosses the chips once after the loop.
     """
     inputs, targets = inputs_and_targets(batch["tokens"])
     x, head = forward_hidden(params, inputs, cfg, batch.get("segment_ids"))
     with jax.named_scope("loss_head"):
-        head = head_for_loss_loop(
-            head, sharding_rules(cfg.pipeline_axis is not None), cfg,
-            targets.shape[1])
+        place = functools.partial(
+            head_for_loss_loop,
+            rules=sharding_rules(cfg.pipeline_axis is not None), cfg=cfg,
+            S=targets.shape[1])
+        head = place(head)
         if cfg.n_pred_heads > 1:
             return multi_head_ce(x, head, targets, batch.get("loss_mask"),
-                                 cfg.loss_chunk, cfg.n_pred_heads)
+                                 cfg.loss_chunk, cfg.n_pred_heads, place)
         return chunked_ce(x, head, targets, batch.get("loss_mask"),
-                          cfg.loss_chunk)
+                          cfg.loss_chunk, place)
 
 
 def inputs_and_targets(tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -763,13 +766,15 @@ def head_for_loss_loop(head: jax.Array, rules: ShardingRules, cfg: Any,
     the family's ``rules`` give it.
 
     The rules store the head with d over ``fsdp``. Left so, the product
-    ``xc @ head`` inside the loop's fully rematted body makes GSPMD gather
-    the head in every chunk, forward and recomputed, and reduce-scatter its
-    gradient chunk by chunk: at ``[4096, 32000]`` over ``fsdp 4`` with 16
-    chunks, 48 collectives of 262 MB a step where 2 do. Constrained here,
-    before the loop, the gather happens once; the constraint's transpose is
-    the same constraint, so the head's cotangent accumulates whole per chip
-    in the backward loop's carry and is summed over the chips once after it.
+    ``xc @ head`` inside the loop's body makes GSPMD gather the head in
+    every chunk and reduce-scatter its gradient chunk by chunk: at
+    ``[4096, 32000]`` over ``fsdp 4`` with 16 chunks, dozens of collectives
+    of 262 MB a step where 2 do. Constrained here, before the loop, the
+    gather happens once, and the head's gradient accumulates whole per chip
+    in the loop's carry and is summed over the chips once after it; the
+    loop repeats this placement on the head inside its body
+    (``chunked_ce``'s ``place``), where the partitioner would otherwise
+    shard the loop's operand as it liked.
 
     What tells is what the step can observe: the ambient mesh and the rule
     that places the head. With no mesh, no loop, every axis on d of size 1
@@ -778,7 +783,7 @@ def head_for_loss_loop(head: jax.Array, rules: ShardingRules, cfg: Any,
     the head comes back as it came and the program is what it was.
 
     Cost: ``d * V / tp`` elements of the compute dtype a chip for the
-    gathered head and as much for its cotangent's carry (262 MB each at
+    gathered head and as much for its gradient's carry (262 MB each at
     4096 x 32000 bf16). A head too large for that (a 256k vocabulary)
     wants a loop over vocabulary shards with the log-sum-exp reduced
     instead, which nothing here builds.
@@ -800,36 +805,166 @@ def head_for_loss_loop(head: jax.Array, rules: ShardingRules, cfg: Any,
         head, NamedSharding(mesh, P(None, v_axes)))
 
 
+# Positions of a sequence whose logits' cotangent ``_looped_ce`` keeps before
+# it multiplies them into the head's gradient as one product. That product
+# contracts over the positions, so against its accumulator [d, V] (read and
+# written in the head's dtype, 4 bytes an element a pass) it does
+# ``positions / 2`` FLOP a byte: the chip's ridge (v5e: 197 TFLOP/s over
+# 819 GB/s, 240 FLOP a byte) wants 1,024 and more, where a chunk of 256
+# alone has the accumulator's traffic pace the product. What it costs is the
+# kept cotangent, ``B * 2048 * V`` of the compute dtype: four chunks' float32
+# logits at ``loss_chunk`` 256. On the chip 1,024, 2,048 and 4,096 lie within
+# 1.5% of one another at every train cell's shape and 2,048 is the best or
+# within 0.5 ms of it in each (``PERF.md`` section 6, PR 60).
+HEAD_GRAD_ROWS = 2048
+
+
+def _chunks_a_group(n_chunks: int, chunk: int) -> int:
+    """How many of the loop's ``n_chunks`` chunks share one product into the
+    head's gradient: the most that cover no more than ``HEAD_GRAD_ROWS``
+    positions of a sequence and divide ``n_chunks``, so that every group is
+    as long as every other (six chunks of 512 go three and three, not four
+    and two)."""
+    most = max(1, min(HEAD_GRAD_ROWS // chunk, n_chunks))
+    return max(g for g in range(1, most + 1) if n_chunks % g == 0)
+
+
+def _ce_loop(x, head, ts, ms, chunk, n, logits_dtype, place, grads):
+    """The chunked cross entropy's loop over x [B, S, d], ``head``
+    [d, n * V], the heads' targets ``ts`` and float32 weights ``ms``
+    [B, S, n]: (the mean over the heads of each one's mean over its counted
+    positions, (dx, dhead) of that mean or None).
+
+    A chunk's logits are summed into ``logits_dtype`` and read as float32;
+    ``log_softmax``, the targets' weighted sum and the weights' sum are the
+    loop-free path's operations. With ``grads``, while the float32 logits
+    are in hand, the chunk also forms the cotangent autodiff would hand the
+    two backward products, ``(softmax - onehot) * m / (n * max(count, 1))``
+    cast to x's dtype, multiplies it into the chunk's place of ``dx``, and
+    keeps it for the rest of its group (``_chunks_a_group``); a group ends
+    with ONE product ``x_group^T @ g_group``, summed in float32 and added
+    to ``dhead``. No product runs twice, and the accumulator is read and
+    written once a group, not once a chunk.
+
+    One scan over the groups, a group's chunks unrolled inside it.
+    ``place`` (``head_for_loss_loop`` by the caller's rules; nothing with no
+    mesh) is repeated on the head INSIDE the loop's body: the partitioner
+    gives a loop's operand a sharding of its own choosing, and with the
+    head's gradient in the carry it chose the stored one, d split, and
+    gathered the head a group and a group's cotangent over the batch a chunk
+    (``fsdp 4`` on the described chips; with a second scan inside,
+    ``fsdp 2 x tp 2`` gathered the head a chunk). Placed in the body, the
+    head is gathered once a step before the loop, and the sum over a split
+    batch leaves the loop as one sum over the chips. On one chip a second
+    scan and the unrolled inside run the train cells within 0.1% of each
+    other (``PERF.md`` section 6, PR 60).
+
+    ``dhead`` is carried in the head's dtype: the float32 ``[d, V]`` this
+    could be is alive together with its cast when the loop ends, and that
+    made the loss the step's peak on the described chip (Mistral 14.90 GiB
+    for 14.71, Trinity 15.20 for 15.06) whatever the group's length. A
+    group's product still sums in float32; a step rounds the carry once a
+    group, ``S / 2048`` times."""
+    B, S, d = x.shape
+    V = head.shape[1] // n
+    n_chunks = S // chunk
+    per = _chunks_a_group(n_chunks, chunk) if grads else n_chunks
+    f32 = jnp.float32
+
+    def cut(a):  # [B, S, ...] -> [groups, chunks a group, B, chunk, ...]
+        return jnp.moveaxis(
+            a.reshape(B, n_chunks // per, per, chunk, *a.shape[2:]), 0, 2)
+
+    # what a position's nll weighs in the result: its head's mean over the
+    # counted positions, the heads' mean
+    weight = 1.0 / (n * jnp.maximum(ms.sum((0, 1)), 1))
+    columns = jnp.arange(V, dtype=ts.dtype)
+
+    def one_chunk(head, carry, sl):
+        xc, tc, mc = sl
+        logits = jnp.matmul(xc, head, preferred_element_type=logits_dtype)
+        logp = jax.nn.log_softmax(
+            logits.astype(f32).reshape(B, chunk, n, V), -1)
+        took = jnp.take_along_axis(logp, tc[..., None], axis=-1)[..., 0]
+        total, count = carry
+        carry = (total - (took * mc).sum((0, 1)), count + mc.sum((0, 1)))
+        if not grads:
+            return carry, None
+        g = (jnp.exp(logp) - (tc[..., None] == columns)) \
+            * (mc * weight)[..., None]
+        g = g.astype(x.dtype).reshape(B, chunk, n * V)
+        dxc = jax.lax.dot_general(g, head, (((2,), (1,)), ((), ())),
+                                  preferred_element_type=x.dtype)
+        return carry, (g, dxc)
+
+    def one_group(carry, sl):
+        *sums, dhead = carry
+        sums, (g, dxg) = jax.lax.scan(
+            functools.partial(one_chunk, place(head)), tuple(sums), sl,
+            unroll=True)
+        dhead = (dhead + jnp.einsum("pbcd,pbcv->dv", sl[0], g,
+                                    preferred_element_type=f32)
+                 ).astype(head.dtype)
+        return (*sums, dhead), dxg
+
+    sums = (jnp.zeros((n,), f32),) * 2
+    slices = (cut(x), cut(ts), cut(ms))
+    if grads:
+        (*sums, dhead), dx = jax.lax.scan(
+            one_group, (*sums, jnp.zeros_like(head)), slices)
+        made = (jnp.moveaxis(dx, 2, 0).reshape(B, S, d), dhead)
+    else:  # one group of every chunk
+        sums, made = jax.lax.scan(
+            lambda carry, sl: one_chunk(place(head), carry, sl), sums,
+            jax.tree.map(lambda a: a[0], slices))
+    total, count = sums
+    return (total / jnp.maximum(count, 1)).mean(), made
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _looped_ce(x, head, ts, ms, chunk, n, logits_dtype, place):
+    """``_ce_loop``'s mean under the rule that forms both gradients in the
+    loop that holds the logits; where nothing is differentiated the loop
+    forms none."""
+    return _ce_loop(x, head, ts, ms, chunk, n, logits_dtype, place, False)[0]
+
+
+def _looped_ce_fwd(x, head, ts, ms, chunk, n, logits_dtype, place):
+    return _ce_loop(x, head, ts, ms, chunk, n, logits_dtype, place, True)
+
+
+def _looped_ce_bwd(chunk, n, logits_dtype, place, kept, ct):
+    """Both gradients were formed for a cotangent of one: the incoming
+    scalar scales them (elementwise, into what reads them). Targets and
+    weights take none."""
+    return (*((a * ct).astype(a.dtype) for a in kept), None, None)
+
+
+_looped_ce.defvjp(_looped_ce_fwd, _looped_ce_bwd)
+
+
+def _as_it_came(head: jax.Array) -> jax.Array:
+    return head
+
+
 def chunked_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
-               mask: Optional[jax.Array], chunk: int) -> jax.Array:
+               mask: Optional[jax.Array], chunk: int,
+               place: Callable[[jax.Array], jax.Array] = _as_it_came
+               ) -> jax.Array:
     """Cross entropy from final hiddens; shared by every model family.
 
-    Knows no mesh: the loop's body multiplies by ``head`` as it is handed
-    in, in every chunk and again in the rematted backward, so a caller
-    under a mesh passes it through ``head_for_loss_loop`` first."""
-    S = targets.shape[1]
-    n_chunks = _loss_chunks(S, chunk)
-    if n_chunks:
-        xs = x.reshape(x.shape[0], n_chunks, chunk, -1).swapaxes(0, 1)
-        ts = targets.reshape(targets.shape[0], n_chunks, chunk).swapaxes(0, 1)
-        ms = (jnp.ones_like(ts, jnp.float32) if mask is None
-              else mask.reshape(mask.shape[0], n_chunks, chunk).swapaxes(0, 1)
-              .astype(jnp.float32))
-
-        def chunk_nll(carry, sl):
-            xc, tc, mc = sl
-            logits = (xc @ head).astype(jnp.float32)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(logp, tc[..., None], axis=-1)[..., 0]
-            s, cnt = carry
-            return (s + (nll * mc).sum(), cnt + mc.sum()), None
-
-        body = jax.checkpoint(
-            chunk_nll, policy=jax.checkpoint_policies.nothing_saveable)
-        (total, count), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (xs, ts, ms))
-        return total / jnp.maximum(count, 1)
+    Knows no mesh: the loop multiplies by ``head`` as it is handed in, in
+    every chunk, so a caller under a mesh passes it through
+    ``head_for_loss_loop`` first, and hands that placement in as ``place``
+    for the loop to repeat on the head inside its body. Where it loops
+    (``_loss_chunks``) it is ``_looped_ce`` of one head, the logits rounded
+    to the compute dtype as the loop-free path below and ``forward`` round
+    them."""
+    if _loss_chunks(targets.shape[1], chunk):
+        live = jnp.ones(targets.shape, jnp.float32) if mask is None \
+            else mask.astype(jnp.float32)
+        return _looped_ce(x, head, targets[..., None], live[..., None],
+                          chunk, 1, jnp.result_type(x, head), place)
 
     logits = (x @ head).astype(jnp.float32)
     logp = jax.nn.log_softmax(logits, axis=-1)
@@ -840,7 +975,9 @@ def chunked_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
 
 
 def multi_head_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
-                  mask: Optional[jax.Array], chunk: int, n: int) -> jax.Array:
+                  mask: Optional[jax.Array], chunk: int, n: int,
+                  place: Callable[[jax.Array], jax.Array] = _as_it_came
+                  ) -> jax.Array:
     """Cross entropy of ``n`` prediction heads from final hiddens x
     [B, S, d]: ``head`` [d, n * V], its columns ``i V .. (i + 1) V`` the
     logits for the token ``1 + i`` positions on; ``targets`` [B, S] the next
@@ -849,7 +986,7 @@ def multi_head_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
     ``mask`` [B, S] (None: all ones) is read at the target's place. The mean
     over the heads of each one's mean over its counted positions; float32
     logits, summed from the compute dtype's operands. In ``chunk``-position
-    slices under a fully rematted loop where ``chunked_ce`` would loop."""
+    slices under ``_looped_ce``'s loop where ``chunked_ce`` would loop."""
     B, S = targets.shape
     V = head.shape[1] // n
     reach = jnp.arange(S)[:, None] + jnp.arange(n)[None, :]      # [S, n]
@@ -858,30 +995,13 @@ def multi_head_ce(x: jax.Array, head: jax.Array, targets: jax.Array,
     past = ((0, 0), (0, n - 1))
     ts = jnp.pad(targets, past)[:, reach]                        # [B, S, n]
     ms = jnp.pad(live, past)[:, reach]
+    if _loss_chunks(S, chunk):
+        return _looped_ce(x, head, ts, ms, chunk, n, jnp.float32, place)
 
-    def nll(xc, tc, mc):
-        logits = jnp.matmul(xc, head, preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(logits.reshape(*xc.shape[:-1], n, V), -1)
-        took = jnp.take_along_axis(logp, tc[..., None], axis=-1)[..., 0]
-        lead = tuple(range(took.ndim - 1))
-        return -(took * mc).sum(lead), mc.sum(lead)              # [n], [n]
-
-    n_chunks = _loss_chunks(S, chunk)
-    if n_chunks:
-        def cut(a):
-            return a.reshape(B, n_chunks, chunk, *a.shape[2:]).swapaxes(0, 1)
-
-        def chunk_nll(carry, sl):
-            total, count = nll(*sl)
-            return (carry[0] + total, carry[1] + count), None
-
-        body = jax.checkpoint(
-            chunk_nll, policy=jax.checkpoint_policies.nothing_saveable)
-        zeros = jnp.zeros((n,), jnp.float32)
-        (total, count), _ = jax.lax.scan(body, (zeros, zeros),
-                                         (cut(x), cut(ts), cut(ms)))
-    else:
-        total, count = nll(x, ts, ms)
+    logits = jnp.matmul(x, head, preferred_element_type=jnp.float32)
+    logp = jax.nn.log_softmax(logits.reshape(B, S, n, V), -1)
+    took = jnp.take_along_axis(logp, ts[..., None], axis=-1)[..., 0]
+    total, count = -(took * ms).sum((0, 1)), ms.sum((0, 1))      # [n], [n]
     return (total / jnp.maximum(count, 1)).mean()
 
 

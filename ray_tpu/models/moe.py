@@ -1051,7 +1051,8 @@ def _mtp(params: Params, cfg: MoEConfig, x: jax.Array, targets: jax.Array,
         live = jnp.ones(targets.shape, jnp.float32) if mask is None \
             else jnp.roll(mask.astype(jnp.float32), -1, axis=1)
         live = live.at[:, -1].set(0.0)
-        ce = llama.chunked_ce(x, head, after, live, cfg.loss_chunk)
+        ce = llama.chunked_ce(x, head, after, live, cfg.loss_chunk,
+                              _head_placement(cfg, targets.shape[1]))
     return ce, aux, load[None], kept[None]
 
 
@@ -1071,6 +1072,14 @@ def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
             stats = {**routing_counters(cfg, load, kept), "router_load": load}
     x, head = _final(params, cfg, x)
     return x, head, aux / cfg.n_expert_layers, stats
+
+
+def _head_placement(cfg: MoEConfig, S: int):
+    """``llama.head_for_loss_loop`` by this family's rules for a loss over
+    ``S`` positions: what places the head before the chunked loss's loop,
+    and again inside it."""
+    return functools.partial(llama.head_for_loss_loop, rules=sharding_rules(),
+                             cfg=cfg, S=S)
 
 
 def _final(params: Params, cfg: MoEConfig, x: jax.Array):
@@ -1101,10 +1110,9 @@ def loss_and_stats(params: Params, batch: Dict[str, jax.Array], cfg: MoEConfig
     x, head, aux, stats = forward_hidden(params, inputs, cfg,
                                          batch.get("segment_ids"))
     with jax.named_scope("loss_head"):
-        head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
-                                        targets.shape[1])
-        ce = llama.chunked_ce(x, head, targets, batch.get("loss_mask"),
-                              cfg.loss_chunk)
+        place = _head_placement(cfg, targets.shape[1])
+        ce = llama.chunked_ce(x, place(head), targets,
+                              batch.get("loss_mask"), cfg.loss_chunk, place)
     return ce + cfg.router_aux_coef * aux, stats
 
 
@@ -1121,9 +1129,9 @@ def _loss_with_mtp(params: Params, batch: Dict[str, jax.Array],
                                                 segment_ids)
     x, head = _final(params, cfg, trunk)
     with jax.named_scope("loss_head"):
-        head = llama.head_for_loss_loop(head, sharding_rules(), cfg,
-                                        targets.shape[1])
-        ce = llama.chunked_ce(x, head, targets, mask, cfg.loss_chunk)
+        place = _head_placement(cfg, targets.shape[1])
+        head = place(head)
+        ce = llama.chunked_ce(x, head, targets, mask, cfg.loss_chunk, place)
     ce_mtp, aux_mtp, load_mtp, kept_mtp = _mtp(
         params, cfg, trunk, targets, head, mask, segment_ids, mla_tables)
     with jax.named_scope("moe_router"):

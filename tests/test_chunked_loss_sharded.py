@@ -1,5 +1,7 @@
 """The chunked loss under a mesh: the loop's head is gathered once before
-the loop (``llama.head_for_loss_loop``), where the rules shard its model dim.
+the loop (``llama.head_for_loss_loop``), where the rules shard its model dim,
+and the same placement stands on the head inside the loop's body (the loop's
+operand is the partitioner's to shard, and it chose d split).
 
 The arithmetic must be the single device's, the head's gradient must come
 home in the head's stored sharding, and the decision must follow what the
@@ -7,6 +9,11 @@ step can observe (the ambient mesh, the rule that places the head): with no
 mesh, a mesh that does not split d, no loop or the pipelined rules, the
 traced program holds no constraint and is what it was. What the compiled
 four-chip step does with the constraint is in ``test_aot_tpu_compile.py``.
+
+The loop is ``llama._looped_ce``'s: both gradients formed forward, the
+head's a product a group of chunks. Groups are two chunks here
+(``HEAD_GRAD_ROWS`` patched), so every case sums two groups' products over
+a batch the mesh splits; the rule alone is in ``test_chunked_loss.py``.
 """
 
 import dataclasses
@@ -30,6 +37,12 @@ SPARSE = dataclasses.replace(moe.PRESETS["moe-debug"], loss_chunk=16,
                              compute_dtype=jnp.float32)
 FAMILY = {"dense": (llama, DENSE), "sparse": (moe, SPARSE)}
 BATCH, SEQ = 8, 64
+
+
+@pytest.fixture(autouse=True)
+def two_chunks_a_group(monkeypatch):
+    monkeypatch.setattr(llama, "HEAD_GRAD_ROWS", 32)
+    assert llama._chunks_a_group(SEQ // 16, 16) == 2
 
 
 def _batch(cfg, masked):
@@ -95,7 +108,8 @@ def test_sharded_loss_and_grads_equal_the_single_device(family, masked, tied):
     p_sh, _ = plan.state_shardings(ts.default_optimizer(total_steps=5))
     sharded = jax.device_put(params, p_sh)
     with mesh_scope(mesh):
-        assert len(_traced_constraints(fam, cfg, mesh)) == 1
+        # before the loop, and on the head inside its body
+        assert len(_traced_constraints(fam, cfg, mesh)) == 2
         # gradients pinned where the step's optimizer state holds them
         loss_4, grads_4 = jax.jit(
             grad_fn, out_shardings=(plan.replicated(), p_sh))(
@@ -107,19 +121,49 @@ def test_sharded_loss_and_grads_equal_the_single_device(family, masked, tied):
         grads_4[leaf].sharding, p_sh[leaf])
 
 
+@pytest.mark.parametrize("axes,v_axis", [({"fsdp": 4}, None),
+                                         ({"fsdp": 2, "tp": 2}, "tp")])
+def test_the_heads_gradient_leaves_the_rule_as_the_head_came(axes, v_axis):
+    """On the way out of ``_looped_ce``, before the constraint's transpose
+    takes it home: the head's gradient in the head's dtype, whole along d on
+    every chip and V where the head had it (the groups' products over a
+    split batch summed into what each chip holds whole), its values and the
+    hidden's the single device's; the hidden's stays on the batch's axis."""
+    k = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(k[0], (BATCH, SEQ, 32)).astype(jnp.bfloat16)
+    head = (0.3 * jax.random.normal(k[1], (32, 64))).astype(jnp.bfloat16)
+    targets = jax.random.randint(k[2], (BATCH, SEQ), 0, 64, jnp.int32)
+    grad = jax.jit(jax.grad(
+        lambda x, h: llama.chunked_ce(x, h, targets, None, 16), (0, 1)))
+    dx_1, dh_1 = grad(x, head)
+    mesh = _mesh(**axes)
+    came = NamedSharding(mesh, P(None, v_axis))
+    with mesh_scope(mesh):
+        dx, dh = grad(jax.device_put(x, NamedSharding(mesh, P("fsdp"))),
+                      jax.device_put(head, came))
+    assert dh.dtype == head.dtype and dx.dtype == x.dtype
+    assert dh.sharding.is_equivalent_to(came, 2), dh.sharding
+    assert dx.sharding.is_equivalent_to(NamedSharding(mesh, P("fsdp")), 3)
+    _assert_close(dh, dh_1, rtol=1e-2)  # bfloat16: a group's rounding
+    _assert_close(dx, dx_1, rtol=1e-2)
+
+
 def test_fsdp_by_tp_keeps_the_vocabulary_on_tp():
-    """``fsdp 2 x tp 2``: the one constraint is whole along d and leaves V on
-    ``tp``; tied, the embedding's rule is read the other way round."""
+    """``fsdp 2 x tp 2``: the constraint, before the loop and in its body,
+    is whole along d and leaves V on ``tp``; tied, the embedding's rule is
+    read the other way round."""
     mesh = _mesh(fsdp=2, tp=2)
     for tied in (False, True):
         cfg = dataclasses.replace(DENSE, tie_embeddings=tied)
-        eqn, = _traced_constraints(llama, cfg, mesh)
-        assert eqn.params["sharding"] == NamedSharding(mesh, P(None, "tp"))
-        assert eqn.invars[0].aval.shape == (cfg.d_model, cfg.vocab_size)
-        assert eqn.invars[0].aval.dtype == cfg.compute_dtype
-    # and the backward holds its transpose, the same constraint once more
+        before, inside = _traced_constraints(llama, cfg, mesh)
+        for eqn in (before, inside):
+            assert eqn.params["sharding"] == NamedSharding(mesh, P(None, "tp"))
+            assert eqn.invars[0].aval.shape == (cfg.d_model, cfg.vocab_size)
+            assert eqn.invars[0].aval.dtype == cfg.compute_dtype
+    # and the backward holds the first one's transpose, the same constraint
+    # once more (the rule's own backward is two scalings)
     both = _traced_constraints(llama, DENSE, mesh, grad=True)
-    assert [e.params["sharding"].spec for e in both] == [P(None, "tp")] * 2
+    assert [e.params["sharding"].spec for e in both] == [P(None, "tp")] * 3
 
 
 @pytest.mark.parametrize("case", ["no-mesh", "fsdp-1", "tp-only", "no-loop",

@@ -1074,21 +1074,29 @@ def test_the_serving_constructors_refuse_both_kinds_by_name(family):
 
 # ---- the walk the benchmark already trains ---------------------------------------------
 
-@pytest.mark.parametrize("attn_impl,parent", [("flash", "94d2651053756d6e"),
-                                              ("xla", "023f9347d58d09bf")])
+@pytest.mark.parametrize("attn_impl,loss_chunk,parent", [
+    ("flash", 8, "fb8b8b5966e4bfe5"), ("xla", 8, "21914aa40d5fb441"),
+    ("flash", 0, "bab56c94956a4485"), ("xla", 0, "b4c98a0ce1659c33")])
 def test_a_walk_of_window_and_full_layers_traces_to_the_parents_jaxpr(
-        attn_impl, parent):
+        attn_impl, loss_chunk, parent):
     """The stacks by kind leave a segment of ``window`` and ``full`` layers
     as it was: the fused step of ``tests/test_trinity_training.py``'s tiny
-    Trinity, traced with that file's ``_digest``, is commit ce6ce1f's (PR
+    Trinity, traced with that file's ``_digest``, was commit ce6ce1f's (PR
     48's parent, where the two digests were taken with the same function),
     equation for equation. (At the cell's own shapes the gradient's jaxpr
-    is the parent's too, and Mistral's and Mixtral's with their flash plans'
-    records: compared once by hand, PERF.md section 6.)"""
+    was the parent's too, and Mistral's and Mixtral's with their flash
+    plans' records: compared once by hand, PERF.md section 6.) Since PR 60
+    the two with the chunked loss hold ``llama._looped_ce``'s rule where
+    the jaxpr held a rematted scan and its transpose (they were
+    94d2651053756d6e and 023f9347d58d09bf); with ``loss_chunk`` 0 the step
+    runs no loop and its two are commit 23fff03's, PR 60's parent: the walk
+    did not move."""
     sys.path.insert(0, os.path.join(REPO, "tests"))
     import test_trinity_training as tt
 
     cfg = tt._cfg(spec.load_family("trinity_afmoe"), attn_impl=attn_impl)
+    assert cfg.loss_chunk == 8
+    cfg = dataclasses.replace(cfg, loss_chunk=loss_chunk)
     assert tt._digest(cfg) == parent
 
 

@@ -258,6 +258,12 @@ def _digest(cfg):
                             jax.random.key(0))
     batch = {"tokens": jax.ShapeDtypeStruct((2, 1, 65), jnp.int32)}
     step = ts.make_multi_step(cfg, opt, 2)
+    # from empty caches: JAX prints a sub-jaxpr that two call sites share
+    # once, hoisted, and which sites share one (``jnp.where`` at a shape an
+    # earlier test used, an entry since evicted) depends on what the process
+    # traced before, so the text would depend on the tests that ran before
+    # on this worker
+    jax.clear_caches()
     text = str(jax.make_jaxpr(step._jit)(
         params, jax.eval_shape(opt.init, params), batch))
     text = re.sub(r" at 0x[0-9a-f]+", "", text)
@@ -274,33 +280,40 @@ def _digest(cfg):
 _SHAPE = dict(vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
               d_ff=96, max_seq_len=64, param_dtype=jnp.bfloat16, loss_chunk=16)
 
-# the digests of commit 05a80f6 (PR 41, this PR's parent), taken there with
-# the function above: Mistral's and Mixtral's blocks (grouped-query, chunked
-# loss, top-2 of 4 experts) with the flash kernels and without. The two
-# with the kernels are PR 44's, taken the same way: the jaxpr holds the
-# kernels' bodies, which that PR changed (a crossed tile worked in sub-tiles,
-# the refs read by slices, the mask from the iotas' difference; they were
-# 8219d1c99c7ee37b and 9c79849e144cc7ad), and PR 45's after that: the remat
-# blocks keep the forward kernel's output and log-sum-exp, so the layer's
-# body holds two ``name`` equations and its backward one ``flash_fwd`` call
-# where it held two (they were 5aebecaaabf919da and 587e036cdcc80068);
-# and the two without, the same step round them, stayed as they were
-PARENT = {("dense", "flash"): "cbc5c26018a3bf58",
-          ("dense", "xla"): "5eaa6356b79991ed",
-          ("moe", "flash"): "ffce3c5afcb4fa55",
-          ("moe", "xla"): "13aeed2041cd205e"}
+# Mistral's and Mixtral's blocks (grouped-query, chunked loss, top-2 of 4
+# experts) with the flash kernels and without, and the same four steps with
+# ``loss_chunk`` 0, which run no loop. The four with the loop are PR 60's:
+# that PR made the chunked loss ``llama._looped_ce``'s rule, a ``custom_vjp``
+# whose forward loop forms both gradients, where the jaxpr held a rematted
+# scan and its transpose (they were cbc5c26018a3bf58, 5eaa6356b79991ed,
+# ffce3c5afcb4fa55 and 13aeed2041cd205e: PR 45's with the kernels, which keep
+# the forward kernel's output and log-sum-exp, and PR 41's parent's without).
+# The four without the loop are commit 23fff03's (PR 60's parent) by the
+# function above: nothing but the loop moved. That PR also made the function
+# start from empty caches: on a worker that had traced other tests first
+# every digest here came out otherwise, and the values are a fresh process's
+PARENT = {("dense", "flash", 16): "a172b282cdc1a4c7",
+          ("dense", "xla", 16): "3e4a4cc6ec53d4bd",
+          ("moe", "flash", 16): "2e699e0aaa931136",
+          ("moe", "xla", 16): "ba3652f6b27da927",
+          ("dense", "flash", 0): "6bab30ef2ec83d19",
+          ("dense", "xla", 0): "b146de5e389c56e4",
+          ("moe", "flash", 0): "b9d79a264182b4b6",
+          ("moe", "xla", 0): "ad6ea5226ec1a578"}
 
 
-@pytest.mark.parametrize("family_name,attn_impl", sorted(PARENT))
-def test_the_old_steps_trace_to_the_parents_jaxpr(family_name, attn_impl):
+@pytest.mark.parametrize("family_name,attn_impl,loss_chunk", sorted(PARENT))
+def test_the_old_steps_trace_to_the_parents_jaxpr(family_name, attn_impl,
+                                                  loss_chunk):
     """With ``window=None`` and the new config fields at their defaults the
     steps of the cells the benchmark already trains are the parent's."""
+    shape = dict(_SHAPE, loss_chunk=loss_chunk)
     if family_name == "dense":
-        cfg = llama.LlamaConfig(**_SHAPE, attn_impl=attn_impl)
+        cfg = llama.LlamaConfig(**shape, attn_impl=attn_impl)
     else:
-        cfg = moe.MoEConfig(**_SHAPE, attn_impl=attn_impl, n_experts=4,
+        cfg = moe.MoEConfig(**shape, attn_impl=attn_impl, n_experts=4,
                             top_k=2, router_aux_coef=0.02)
-    assert _digest(cfg) == PARENT[family_name, attn_impl]
+    assert _digest(cfg) == PARENT[family_name, attn_impl, loss_chunk]
 
 
 def test_the_defaults_count_what_they_counted():
