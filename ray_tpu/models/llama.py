@@ -418,7 +418,7 @@ def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
     ``apply_rope``, transposes, the summaries under autodiff and the dense
     masked form."""
     from ray_tpu.ops import eva
-    from ray_tpu.parallel.context import current_mesh
+    from ray_tpu.parallel.context import single_chip
 
     if segment_ids is not None:
         raise NotImplementedError(
@@ -429,8 +429,7 @@ def eva_half(cfg: LlamaConfig, x: jax.Array, layer: Params,
                                   "keys: n_kv_heads must equal n_heads")
     b, s, _ = x.shape
     hq, hd, cdt = cfg.n_heads, cfg.head_dim, cfg.compute_dtype
-    mesh = current_mesh()
-    kernels = cfg.attn_impl == "flash" and (mesh is None or mesh.size == 1)
+    kernels = cfg.attn_impl == "flash" and single_chip()
     with jax.named_scope("attn_eva"):
         h = pre_norm(cfg, x, layer, "attn_norm")
         q, k, v = ((h @ layer[w].astype(cdt)).reshape(b, s, hq, hd)

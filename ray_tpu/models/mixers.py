@@ -127,15 +127,12 @@ def init_kda(rng: jax.Array, cfg, n: int) -> Params:
 def kda_half(cfg, x: jax.Array, layer: Params) -> jax.Array:
     """Pre-norm KDA's branch, [b, s, d] -> [b, s, d]; the caller opens
     ``attn_kda`` round it (module docstring) and joins it to the stream."""
-    from ray_tpu.parallel.context import current_mesh
+    from ray_tpu.parallel.context import single_chip
 
     b, s, _ = x.shape
     h, w, cdt = cfg.kda_heads, cfg.kda_head_dim, cfg.compute_dtype
     taps = cfg.kda_conv_taps
-    # the compiler does not partition a Mosaic call: under a mesh of
-    # several chips the layer stays the XLA form GSPMD splits
-    mesh = current_mesh()
-    impl = "xla" if mesh is not None and mesh.size > 1 else cfg.attn_impl
+    impl = cfg.attn_impl if single_chip() else "xla"
     fused = kda.plan(s, h, w, w, b, impl=impl, conv_taps=taps)["mix"] == "pallas"
     if fused:
         # here and not at the module's top: a process that traces no step

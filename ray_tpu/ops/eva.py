@@ -45,9 +45,7 @@ attention kernels' ``o`` and ``lse`` carry ``flash.RESIDUAL_NAMES``.
 
 from __future__ import annotations
 
-import contextlib
-import threading
-from typing import Any, Dict, Iterator
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +53,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import NEG_INF
 from ray_tpu.ops.rope import apply_rope
+from ray_tpu.util import plans
 
 RESIDUAL_NAMES = ("eva_q", "eva_k", "eva_v", "eva_ks", "eva_vs")
 #: the release's ``init_std``: ``phi`` and ``mu`` start as a normal of this
@@ -113,22 +112,6 @@ def visible_pairs(seq: int, window: int, chunk: int):
     return local, pooled
 
 
-_noting = threading.local()
-
-
-@contextlib.contextmanager
-def noting_plan(into: Dict[str, Any]) -> Iterator[None]:
-    """Within the scope, the plan ``eva_attention`` is traced with in this
-    thread is written into ``into`` (static per compiled shape, as
-    ``ops/kda.noting_plan``)."""
-    was = getattr(_noting, "into", None)
-    _noting.into = into
-    try:
-        yield
-    finally:
-        _noting.into = was
-
-
 # ---------------------------------------------------------------- the parts
 
 def summaries(k: jax.Array, v: jax.Array, phi: jax.Array, mu: jax.Array,
@@ -184,9 +167,7 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array, sin: jax.Array,
     rows that are cut off."""
     b, s, h, d = q.shape
     p = plan(s, h, d, window, chunk, batch=b, impl=impl)
-    into = getattr(_noting, "into", None)
-    if into is not None:
-        into.update(p)
+    plans.note("eva", p)
     if impl == "pallas":
         from ray_tpu.ops.pallas import eva_attn, eva_mix
 

@@ -60,13 +60,13 @@ which its callers keep outermost (``models/moe._patterned_layer``).
 
 from __future__ import annotations
 
-import contextlib
 import math
-import threading
-from typing import Any, Dict, Iterator, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.util import plans
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -162,22 +162,6 @@ def stream_bytes(n: int, d: int, itemsize: int) -> Tuple[int, int]:
 
 # --------------------------------------------------------------- the plan
 
-_noting = threading.local()
-
-
-@contextlib.contextmanager
-def noting_plan(into: Dict[str, Any]) -> Iterator[None]:
-    """Within the scope, what ``mix_in`` is traced with in this thread is
-    written into ``into`` (static per compiled shape, as
-    ``ops/kda.noting_plan``)."""
-    was = getattr(_noting, "into", None)
-    _noting.into = into
-    try:
-        yield
-    finally:
-        _noting.into = was
-
-
 def plan(n: int, d: int, itemsize: int, iters: int,
          tile: Optional[int] = None) -> Dict[str, Any]:
     """What ``mix_in`` does at one shape; pure. ``tile``: the tokens a grid
@@ -204,14 +188,12 @@ def plan(n: int, d: int, itemsize: int, iters: int,
 def kernels_tile(impl: str, tokens: int, d: int) -> Optional[int]:
     """The tokens a grid step of ``ops/pallas/hyper_mix.py``'s calls where
     they run, None where XLA's form does: ``impl`` ``"pallas"`` (the
-    caller's ``attn_impl == "flash"``), no mesh of several chips ambient (a
-    Mosaic call is not partitioned), ``d`` whole lanes and the tokens whole
-    tiles: the rule ``llama.eva_half`` and ``mixers`` choose by."""
+    caller's ``attn_impl == "flash"``), one chip (``context.single_chip``),
+    ``d`` whole lanes and the tokens whole tiles."""
     from ray_tpu.ops.pallas import hyper_mix
-    from ray_tpu.parallel.context import current_mesh
+    from ray_tpu.parallel.context import single_chip
 
-    mesh = current_mesh()
-    if impl != "pallas" or (mesh is not None and mesh.size > 1) or d % 128:
+    if impl != "pallas" or not single_chip() or d % 128:
         return None
     return hyper_mix.tile_tokens(tokens)
 
@@ -302,9 +284,7 @@ def mix_in(x: jax.Array, half: Params, *, iters: int, eps: float,
     or a ``Held`` where the kernels run, ``kernels_tile``)."""
     n, b, s, d = x.shape
     tile = kernels_tile(impl, b * s, d)
-    into = getattr(_noting, "into", None)
-    if into is not None:
-        into.update(plan(n, d, x.dtype.itemsize, iters, tile))
+    plans.note("hyper", plan(n, d, x.dtype.itemsize, iters, tile))
     with jax.named_scope("hyper_mix"):
         if tile:
             return _mix_in_kernels(x, half, tile, iters=iters, eps=eps,

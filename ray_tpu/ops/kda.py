@@ -72,7 +72,7 @@ float32 carries to 4e-4 in that worst case and the cast of ``T`` to the
 products' dtype (2e-3) covers.
 
 What is a kernel's and what is plain XLA: ``impl`` in the plan a step notes
-says which (``noting_plan``). ``"pallas_insides"``: everything of a chunk
+says which (``util/plans.note``). ``"pallas_insides"``: everything of a chunk
 that does not read the state (``_insides``: the running sum of the gates,
 the two decayed products, the inverse, ``W``, ``U`` and the reweighted ``Q``
 and ``K``) is one Pallas call forward and one backward
@@ -96,14 +96,14 @@ form, ``kda_grams`` (the two decayed products), ``kda_inverse`` and
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
-from typing import Any, Dict, Iterator, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.util import plans
 
 F32 = jnp.float32
 
@@ -164,22 +164,6 @@ def plan(seq: int, heads: int, d_k: int, d_v: int, batch: int = 1,
                      and sub == SUB_BLOCK else "xla"),
             "mix": ("pallas" if kernels and conv_taps <= MAX_CONV_TAPS
                     else "xla")}
-
-
-_noting = threading.local()
-
-
-@contextlib.contextmanager
-def noting_plan(into: Dict[str, Any]) -> Iterator[None]:
-    """Within the scope, the plan ``kda_chunked`` is traced with in this
-    thread is written into ``into`` (static per compiled shape, as
-    ``ops/pallas/flash.noting_plans``)."""
-    was = getattr(_noting, "into", None)
-    _noting.into = into
-    try:
-        yield
-    finally:
-        _noting.into = was
 
 
 # ------------------------------------------------- inside a chunk, no state
@@ -505,9 +489,7 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     p = plan(s, h, dk, dv, b, chunk, sub_block, impl, conv_taps)
-    into = getattr(_noting, "into", None)
-    if into is not None:
-        into.update(p)
+    plans.note("kda", p)
     C, n = p["chunk"], p["chunks"]
     pad = n * C - s
 

@@ -98,10 +98,8 @@ multiply in float32.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
-from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +108,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.util import plans
 
 KINDS = ("fwd", "dq", "dkv")
 # what ``_flash_core_fwd`` calls its output and log-sum-exp
@@ -291,25 +290,6 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
                 max(_VMEM_DEFAULT_LIMIT_BYTES, need))
 
 
-_noting = threading.local()
-
-
-@contextlib.contextmanager
-def noting_plans(into: List[Dict[str, Any]]) -> Iterator[None]:
-    """Within the scope, each distinct plan a flash kernel is traced with
-    in this thread is appended to ``into`` (the plan's fields and the shape
-    it was chosen for; ``value_dim`` too where the values have a width of
-    their own). A plan is static per traced shape, so the scope
-    belongs round the call that traces: ``StepDriver`` puts it round its
-    launches and hands the list to its recorder."""
-    was = getattr(_noting, "into", None)
-    _noting.into = into
-    try:
-        yield
-    finally:
-        _noting.into = was
-
-
 def _planned(kind: str, q, k, v, causal: bool,
              blocks: Optional[Tuple[int, int]],
              window: Optional[int]) -> Tuple[Plan, str]:
@@ -320,13 +300,10 @@ def _planned(kind: str, q, k, v, causal: bool,
     two = dv != d
     p = plan(sq, sk, d, q.dtype.itemsize, causal, kind, blocks, window,
              dv if two else None)
-    into = getattr(_noting, "into", None)
-    if into is not None:
-        note = {**p._asdict(), "seq_q": sq, "seq_k": sk, "head_dim": d,
-                "itemsize": q.dtype.itemsize, "causal": causal,
-                "window": window, **({"value_dim": dv} if two else {})}
-        if note not in into:
-            into.append(note)
+    plans.note("flash", {
+        **p._asdict(), "seq_q": sq, "seq_k": sk, "head_dim": d,
+        "itemsize": q.dtype.itemsize, "causal": causal, "window": window,
+        **({"value_dim": dv} if two else {})})
     width = f"d{d}v{dv}" if two else f"d{d}"
     return p, (f"flash_{kind}_bh{bh}_q{sq}_k{sk}_{width}_c{int(causal)}"
                f"_w{window or 0}")

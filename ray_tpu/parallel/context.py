@@ -53,6 +53,14 @@ def current_mesh() -> Optional[Mesh]:
     return _CURRENT_MESH.get()
 
 
+def single_chip() -> bool:
+    """May a Mosaic call run here: the compiler does not partition one, so
+    under an ambient mesh of several chips a layer takes the XLA form GSPMD
+    splits. The one place the rule lives."""
+    mesh = current_mesh()
+    return mesh is None or mesh.size == 1
+
+
 def _merge(o1, lse1, o2, lse2):
     """Merge two partial-softmax results; lse: [b,h,s], o: [b,s,h,d]."""
     m = jnp.maximum(lse1, lse2)

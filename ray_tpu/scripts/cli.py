@@ -24,6 +24,7 @@ from typing import Dict, List, Optional
 
 from ray_tpu._private.config import get_config
 from ray_tpu.cluster import node_main
+from ray_tpu.util import plans
 
 
 def _list_node_states() -> List[Dict]:
@@ -1198,49 +1199,8 @@ def cmd_train(args: argparse.Namespace) -> int:
                 print(f"  gap attribution (MFU cost): {parts}")
         print(f"  recorder overhead "
               f"{100 * summ.get('overhead_frac', 0):.3f}% of launch wall")
-        for fp in summ.get("flash_plans") or ():
-            print(f"  flash {fp['kind']} s{fp['seq_q']}x{fp['seq_k']} "
-                  f"d{fp['head_dim']}: tile {fp['block_q']}x"
-                  f"{fp['block_k']}, {fp['live_steps']} of "
-                  f"{fp['grid_steps']} grid steps live"
-                  + (f", {fp['edge_steps']} of them crossed by an edge, "
-                     f"sub-tile {fp['sub_block'][0]}x{fp['sub_block'][1]}"
-                     if fp.get("edge_steps") else "")
-                  + (f", window {fp['window']}" if fp.get("window") else ""))
-        kp = summ.get("kda_plan") or {}
-        if kp:
-            print(f"  kda: {kp['chunks']} chunks of {kp['chunk']} in "
-                  f"{kp['segments']} segment(s), sub-block {kp['sub_block']}, "
-                  f"{kp['heads']} heads {kp['d_k']}x{kp['d_v']}, states at "
-                  f"the chunks' starts "
-                  f"{kp['boundary_state_bytes'] / 2**20:.0f} MiB a layer, "
-                  f"a chunk's insides: "
-                  + ("a Pallas kernel pair" if kp["impl"] == "pallas_insides"
-                     else "XLA") + f" ({kp['impl']})")
-        ep = summ.get("eva_plan") or {}
-        if ep:
-            print(f"  eva: {ep['windows']} window(s) of {ep['window']}, "
-                  f"{ep['chunks']} chunks of {ep['chunk']} a row, a query "
-                  f"sees at most {ep['summaries_seen']} summaries, "
-                  f"{ep['heads']} heads of {ep['head_dim']}; score tiles "
-                  f"({ep['block']} rows x {ep['block']} keys or "
-                  f"{ep['summary_block']} summaries) visited / needed "
-                  f"{ep['tiles_visited']} / {ep['tiles_needed']} a head "
-                  f"({ep['impl']})")
-        hp = summ.get("hyper_plan") or {}
-        if hp:
-            print(f"  hyper-connections: a stream of {hp['rows']} rows of "
-                  f"{hp['d_model']}, {hp['sinkhorn_iters']} Sinkhorn "
-                  f"iterations a half layer; the least passes over the "
-                  f"stream move {hp['stream_bytes_fwd'] / 1e3:.1f} KB forward"
-                  f" and {hp['stream_bytes_bwd'] / 1e3:.1f} KB backward a "
-                  f"token and half layer"
-                  + (f", the four calls' blocks "
-                     f"{hp['stream_bytes_moved_fwd'] / 1e3:.1f} and "
-                     f"{hp['stream_bytes_moved_bwd'] / 1e3:.1f} KB, "
-                     f"{hp['tile_tokens']} tokens a grid step"
-                     if hp.get("tile_tokens") else "")
-                  + f" ({hp['impl']}; {hp['layout']})")
+        for sentence in plans.sentences(summ):
+            print(f"  {sentence}")
         routing = summ.get("routing") or {}
         if routing.get("moe_assignments"):
             held = routing.get("moe_held", 0)

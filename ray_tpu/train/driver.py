@@ -45,6 +45,7 @@ import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 from ray_tpu.util import metrics as M
+from ray_tpu.util import plans
 
 _LAUNCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0)
 
@@ -113,25 +114,11 @@ class StepDriver:
             self.recorder: Optional[Any] = TrainRecorder()
         except Exception:  # noqa: BLE001 — observability must not block
             self.recorder = None
-        # the flash kernels' tilings, the chunked delta rule's plan, EVA
-        # attention's and the hyper-connections', noted as a launch traces
-        # them: the recorder's own list and dicts, so a program compiled
-        # later shows too
-        from ray_tpu.ops import eva, hyper, kda
-        from ray_tpu.ops.pallas import flash
-
-        rec = self.recorder
-
-        @contextlib.contextmanager
-        def noting_plans():
-            with flash.noting_plans(rec.flash_plans if rec is not None else []), \
-                    kda.noting_plan(rec.kda_plan if rec is not None else {}), \
-                    eva.noting_plan(rec.eva_plan if rec is not None else {}), \
-                    hyper.noting_plan(
-                        rec.hyper_plan if rec is not None else {}):
-                yield
-
-        self._noting_plans = noting_plans
+        # what the step's kernels note of themselves as a launch traces
+        # them (``util/plans``): into the recorder's own dict, so a program
+        # compiled later shows too
+        self._plans: Dict[str, Any] = (
+            self.recorder.plans if self.recorder is not None else {})
         if self.recorder is not None and plan is not None:
             self.recorder.expert_placement = plan.expert_placement()
         if self.recorder is not None:
@@ -327,7 +314,7 @@ class StepDriver:
                 unread = (_abstract((params, opt_state, placed))
                           if rec is not None and n_exec == 0 else None)
                 t1 = time.perf_counter()
-                with self._noting_plans():
+                with plans.noting(self._plans):
                     params, opt_state, metrics = self._multi(
                         params, opt_state, placed)
                 dispatch_s = time.perf_counter() - t1
@@ -403,7 +390,7 @@ class StepDriver:
         placed = self._place(batch, stacked=False)
         self.host_s += time.perf_counter() - t0
         t1 = time.perf_counter()
-        with self._noting_plans():
+        with plans.noting(self._plans):
             params, opt_state, metrics = self._single(params, opt_state,
                                                       placed)
         self.step_s += time.perf_counter() - t1

@@ -1,22 +1,19 @@
-"""Analytic FLOP accounting for the model zoo — MFU and tokens/s in one place.
+"""Analytic FLOP accounting for the model zoo: a train step's work and MFU.
 
-Every throughput claim in the repo (bench.py's sweep MFU, the step
-profiler's per-step MFU, the decode tokens/s legs) needs the same three
-ingredients: a parameter count, a per-token FLOP estimate, and a peak-FLOPs
-denominator. This module is the single home for those formulas so the
-numbers agree everywhere (Podracer, arXiv:2104.06272, makes the same
-accounting the basis of TPU throughput work).
+The training flight recorder's MFU waterfall (``util/train_recorder.py``,
+fed by ``train/driver.py``) needs three ingredients: a parameter count, a
+per-token FLOP estimate, and a peak-FLOPs denominator. This module is the
+single home for those formulas so the numbers agree everywhere (Podracer,
+arXiv:2104.06272, makes the same accounting the basis of TPU throughput
+work).
 
 Conventions (the standard scaling-book estimates):
   - A matmul touching N parameters costs 2N FLOPs per token forward and
     4N backward, so a train step is ~6N per token plus the attention
     quadratic term (causal halves it): 6*L*S*d per token.
-  - Decode costs 2N per token forward plus attention over the live
-    context: 4*L*d*ctx per token (no causal halving — one query row).
   - MoE counts ACTIVE parameters (top-k experts), not total.
-Embedding/head params are included — at the small-vocab presets they are
-a real fraction of the work; callers wanting the non-embedding convention
-can pass their own ``params`` count.
+Embedding/head params are included: at the small-vocab presets they are
+a real fraction of the work.
 """
 
 from __future__ import annotations
@@ -99,43 +96,6 @@ def train_flops_per_token(cfg, seq: int) -> float:
     again = getattr(cfg, "n_mtp_modules", 0) * cfg.d_model * cfg.vocab_size
     return (6.0 * (_flops_params(cfg) + again)
             + 6 * _attention_madds(cfg, seq))
-
-
-def train_step_flops(cfg, batch: int, seq: int) -> float:
-    """One optimizer step over a [batch, seq] token block."""
-    return batch * seq * train_flops_per_token(cfg, seq)
-
-
-def decode_flops_per_token(cfg, context: int) -> float:
-    """One-token forward with a KV cache holding ``context`` positions."""
-    n = _flops_params(cfg)
-    attn = 4 * cfg.n_layers * cfg.n_heads * cfg.head_dim * context
-    return 2.0 * n + attn
-
-
-def prefill_flops(cfg, batch: int, seq: int) -> float:
-    """Batched prompt forward (causal attention over the prompt)."""
-    n = _flops_params(cfg)
-    attn = 2 * cfg.n_layers * seq * cfg.n_heads * cfg.head_dim
-    return batch * seq * (2.0 * n + attn)
-
-
-def generate_flops(cfg, batch: int, prompt_len: int,
-                   new_tokens: int) -> float:
-    """Prefill + autoregressive decode of ``new_tokens`` tokens. The decode
-    attention term uses the mean live context (prompt + T/2)."""
-    ctx = prompt_len + new_tokens / 2.0
-    return (prefill_flops(cfg, batch, prompt_len)
-            + batch * new_tokens * decode_flops_per_token(cfg, ctx))
-
-
-def vit_step_flops(cfg, batch: int) -> float:
-    """ViT classification train step: 6N per patch token plus the
-    NON-causal attention term (every token attends to every token)."""
-    tokens = cfg.num_patches + 1  # + cls token
-    n = cfg.num_params()
-    attn = 12 * cfg.n_layers * tokens * cfg.n_heads * cfg.head_dim
-    return batch * tokens * (6.0 * n + attn)
 
 
 def mfu(flops: float, seconds: float, n_devices: int = 1,

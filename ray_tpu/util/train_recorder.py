@@ -70,6 +70,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu.util import plans
 from ray_tpu.util.recorder_core import (RecorderCore, RecorderRegistry,
                                         pct as _pct)
 
@@ -130,29 +131,14 @@ class TrainRecorder(RecorderCore):
         self._dry_resets = 0  # rt: guarded-by(_lock)
         self._compiles = 0  # rt: guarded-by(_lock)
         self._peak_total_cached: Optional[float] = None
-        # one entry per distinct tiling the step's flash kernels were traced
-        # with (``ops/pallas/flash.noting_plans``): the driver notes into
-        # this list, static per compiled shape like the engine recorder's
-        # ``decode_programs``; an ``xla`` step leaves it empty
-        self.flash_plans: List[Dict[str, Any]] = []
-        # what the step's chunked delta rule does at its shape
-        # (``ops/kda.noting_plan``: chunk, sub_block, chunks, segments,
-        # heads, d_k, d_v, boundary_state_bytes, impl), static like the list
-        # above; a step without a ``kda`` layer leaves it empty
-        self.kda_plan: Dict[str, Any] = {}
-        # what the step's EVA attention does at its shape
-        # (``ops/eva.noting_plan``: windows, chunks, summaries_seen, block,
-        # tiles_visited, tiles_needed, impl), static likewise; a step
-        # without an ``eva`` mixer leaves it empty
-        self.eva_plan: Dict[str, Any] = {}
-        # what the step's hyper-connections do at their shape
-        # (``ops/hyper.noting_plan``: rows, d_model, sinkhorn_iters, layout,
-        # stream_bytes_fwd, stream_bytes_bwd a token and half layer by the
-        # least passes, impl), static likewise; a step whose stream is one
-        # row leaves it empty
-        self.hyper_plan: Dict[str, Any] = {}
+        # what the step's kernels noted of themselves as the driver traced
+        # them (``util/plans.noting``: ``flash_plans``, a list of distinct
+        # tilings, and a dict a kind under ``<kind>_plan``), static per
+        # compiled shape like the engine recorder's ``decode_programs``; a
+        # step without such a kernel leaves its key out
+        self.plans: Dict[str, Any] = {}
         # what the driver's plan and compiled step say of themselves, static
-        # like the list above: how the plan placed a sparse model's expert
+        # likewise: how the plan placed a sparse model's expert
         # matrices (``moe.expert_placement``: "expert" or "model_dim"; None
         # for a dense model or no mesh), and the fused program's collectives
         # ``{kind: {count, runs, bytes}}`` read off the executable that runs
@@ -375,9 +361,7 @@ class TrainRecorder(RecorderCore):
                 "t0": min(r["t"] for r in recs),
                 "t1": max(r["t_done"] for r in recs),
                 "per_launch": [dict(r.get("counters") or {}) for r in recs],
-                **({"eva_plan": dict(self.eva_plan)} if self.eva_plan else {}),
-                **({"hyper_plan": dict(self.hyper_plan)}
-                   if self.hyper_plan else {}),
+                **plans.for_span(self.plans),
                 **({"step_memory": dict(self.step_memory)}
                    if self.step_memory else {}),
                 **self._fold_counters(recs)}
@@ -457,10 +441,7 @@ class TrainRecorder(RecorderCore):
     def _aggregate(self, recs: List[Dict[str, Any]]) -> Dict[str, Any]:
         out: Dict[str, Any] = {
             "window_launches": len(recs),
-            "flash_plans": [dict(p) for p in self.flash_plans],
-            "kda_plan": dict(self.kda_plan),
-            "eva_plan": dict(self.eva_plan),
-            "hyper_plan": dict(self.hyper_plan),
+            **plans.copied(self.plans),
             "expert_placement": self.expert_placement,
             "collectives": {k: dict(v) for k, v in
                             (self.collectives or {}).items()},

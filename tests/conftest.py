@@ -54,8 +54,15 @@ def _dump_io_tasks(reason: str) -> None:
 # instead: at session start record the already-running node daemons; at
 # session finish, any new daemon still alive (or any non-daemon thread a
 # test left running) flips the exit status and names the culprit. Leaked
-# daemons are then killed so the next run starts clean.
+# daemons are then killed so the next run starts clean. Under xdist a
+# worker's guard sees only what its own worker started (``_started_here``):
+# a worker that runs out of work does not reap the head node of a script
+# another worker is still running (seen, PR 29).
 # RT_LEAK_GUARD=0 disables; RT_LEAK_GUARD_KILL=0 reports without reaping.
+
+# what xdist sets in a worker and every process the worker starts inherits:
+# the run's id and the worker's own
+_XDIST_TAGS = ("PYTEST_XDIST_TESTRUNUID", "PYTEST_XDIST_WORKER")
 
 def _is_node_daemon(pid):
     """cmdline-verified: never trust a bare PID (a stale state file's pid
@@ -67,10 +74,29 @@ def _is_node_daemon(pid):
         return False
 
 
+def _started_here(pid, environ=None):
+    """Did this session's process, or one it started, start ``pid``: its
+    environment carries this xdist worker's tags (a daemon detaches from the
+    process tree, its environment stays). Without xdist there is no other
+    worker to spare and every daemon counts, as it always did; one whose
+    environment cannot be read is nobody's to reap."""
+    mine = {k: (os.environ if environ is None else environ).get(k)
+            for k in _XDIST_TAGS}
+    try:
+        with open(f"/proc/{pid}/environ", "rb") as f:
+            its = dict(kv.split("=", 1) for kv in
+                       f.read().decode(errors="replace").split("\0")
+                       if "=" in kv)
+    except OSError:
+        return False
+    return all(its.get(k) == v for k, v in mine.items())
+
+
 def _node_daemon_pids():
-    """PIDs verifiably running ray_tpu.cluster.node_main: the /proc scan
-    (Linux), cross-checked with the state-dir records — every candidate
-    must pass the cmdline check before it can be reported or reaped."""
+    """PIDs verifiably running ray_tpu.cluster.node_main that this session
+    may answer for (``_started_here``): the /proc scan (Linux),
+    cross-checked with the state-dir records — every candidate must pass
+    the cmdline check before it can be reported or reaped."""
     pids = set()
     try:
         for name in os.listdir("/proc"):
@@ -93,7 +119,7 @@ def _node_daemon_pids():
                 continue
     except Exception:  # noqa: BLE001 — guard must never break collection
         pass
-    return pids
+    return {pid for pid in pids if _started_here(pid)}
 
 
 def _leaked_threads(baseline=()):
@@ -168,28 +194,42 @@ def pytest_sessionfinish(session, exitstatus):
     session.exitstatus = 1
 
 
+#: seconds a test may take, its function fixtures' set-up and teardown
+#: included (a module fixture's set-up is not: it is built before this one)
+TEST_DEADLINE_S = 300
+
+
 @pytest.fixture(autouse=True)
 def _hang_watchdog(request):
-    """A test that wedges past 50s first dumps the io-loop's asyncio task
-    stacks (the only place an await-graph deadlock is visible), then at 300s
-    faulthandler kills the run — a silent CI hang becomes a loud,
-    diagnosable failure."""
-    import threading
+    """A test past its deadline FAILS ALONE: at ``TEST_DEADLINE_S`` (or the
+    seconds an indirect parameter names) a ``SIGALRM`` handler writes every
+    thread's stack to the process's own stderr, dumps the io-loop's asyncio
+    task stacks (the only place an await-graph deadlock is visible) and
+    raises ``pytest.fail`` in the test; the worker and every test it still
+    holds go on. A main thread held inside a C call takes the signal when
+    the call returns: ten seconds on, faulthandler's timer writes the stacks
+    from its own thread, and ends nothing."""
+    import signal
 
-    faulthandler.dump_traceback_later(300, exit=True)
-    done = threading.Event()
+    limit = getattr(request, "param", TEST_DEADLINE_S)
     name = request.node.name
 
-    def soft_dump():
-        if not done.wait(30):
-            faulthandler.dump_traceback(file=sys.stderr)
-            _dump_io_tasks(f"test {name} exceeded 30s")
+    def past_deadline(signum, frame):
+        faulthandler.dump_traceback(file=sys.__stderr__)
+        _dump_io_tasks(f"test {name} exceeded {limit}s")
+        pytest.fail(f"{name} exceeded its deadline of {limit} s "
+                    f"(tests/conftest.py:_hang_watchdog)")
 
-    t = threading.Thread(target=soft_dump, daemon=True)
-    t.start()
-    yield
-    done.set()
-    faulthandler.cancel_dump_traceback_later()
+    faulthandler.dump_traceback_later(limit + 10, exit=False,
+                                      file=sys.__stderr__)
+    was = signal.signal(signal.SIGALRM, past_deadline)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, was)
+        faulthandler.cancel_dump_traceback_later()
 
 
 @pytest.fixture(scope="session", autouse=True)

@@ -11,7 +11,6 @@ here at no chip time. Nothing runs: a compile that passes is not a chip run.
 The file's name sorts first so that tier-1 reaches it inside its time limit.
 """
 
-import collections
 import dataclasses
 import math
 import os
@@ -20,8 +19,6 @@ import shutil
 import subprocess
 import sys
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -29,50 +26,9 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models import generate, hybrid, llama, moe, sambay, serving
 from ray_tpu.ops.pallas import flash
-from ray_tpu.parallel import train_step as ts
-from ray_tpu.parallel.context import mesh_scope
-from ray_tpu.parallel.mesh import MeshConfig, make_mesh
-from ray_tpu.parallel.plan import compile_plan
 from ray_tpu.util import hlo_copies
 
-CFG_1B = llama.PRESETS["1b"]
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no libtpu here
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep the cache out of it
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield desc
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(autouse=True)
-def compiled_kernel(monkeypatch):
-    """The process's backend is the CPU, where ``flash`` picks interpret
-    mode; these tests are about the Mosaic kernel."""
-    monkeypatch.setattr(flash, "_needs_interpret", lambda: False)
-
-
-def _on(sharding, tree):
-    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-        x.shape, x.dtype, sharding=sharding), tree)
-
-
-def _as_sharded(tree, shardings):
-    return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
-        x.shape, x.dtype, sharding=s), tree, shardings)
+from _aot import CFG_1B, _on, compiled_kernel, topo  # noqa: F401 (fixtures)
 
 
 @pytest.mark.parametrize("b,s,h,hkv,d", [
@@ -740,696 +696,6 @@ def test_decode_reads_below_the_bound_on_v5e(topo, family, bucket):
         assert not [line for line in text.splitlines() if " copy(" in line
                     and "%params__layers__" in line
                     and "router" not in line], "a weight stack is copied"
-
-
-def _compile_fused_step(fam, cfg, mesh, k, batch, seq):
-    """The fused-K train step of ``cfg`` compiled for ``mesh``'s described
-    chips: (plan, parameter shardings, compiled). Module fixtures are set up
-    before the function-scoped ``compiled_kernel``, so the Mosaic kernel is
-    asked for here too."""
-    opt = ts.default_optimizer(total_steps=100)
-    plan = compile_plan(cfg, mesh)
-    p_sh, o_sh = plan.state_shardings(opt)
-    p_abs = jax.eval_shape(lambda: fam.init_params(jax.random.key(0), cfg))
-    o_abs = jax.eval_shape(opt.init, p_abs)
-    tokens = {"tokens": jax.ShapeDtypeStruct(
-        (k, batch, seq + 1), jnp.int32,
-        sharding=plan.batch_sharding(3, False, True))}
-    multi = ts.make_multi_step(cfg, opt, k, mesh=mesh, plan=plan)
-    with pytest.MonkeyPatch.context() as mp, mesh_scope(mesh):
-        mp.setattr(flash, "_needs_interpret", lambda: False)
-        return plan, p_sh, multi._jit.lower(
-            _as_sharded(p_abs, p_sh), _as_sharded(o_abs, o_sh),
-            tokens).compile()
-
-
-@pytest.fixture(scope="module")
-def flash_step(topo):
-    """The fused-K step over fsdp x tp on four described chips, "1b" widths,
-    depth cut to two layers for the test's time: (cfg, K, compiled)."""
-    cfg = dataclasses.replace(CFG_1B, param_dtype=jnp.bfloat16,
-                              attn_impl="flash", loss_chunk=256, n_layers=2)
-    mesh = make_mesh(MeshConfig(fsdp=2, tp=2), topo.devices)
-    k = 2
-    return cfg, k, _compile_fused_step(llama, cfg, mesh, k, 2, 2048)[2]
-
-
-def test_sharded_flash_step_compiles_for_four_chips(flash_step):
-    """The TPU compiler does not partition a Mosaic kernel;
-    ``flash_attention_on_mesh`` runs it per shard, and the fused-K step
-    must hold both the kernel and the collectives."""
-    text = flash_step[2].as_text()
-    assert "tpu_custom_call" in text
-    assert "all-gather" in text and "all-reduce" in text
-
-
-# Mixtral-8x7B at its published widths (benchmark/configs/
-# mixtral-8x7b-v0.1.json) as its four-chip cell trains it, one layer deep
-CFG_MIXTRAL = moe.MoEConfig(
-    vocab_size=32000, d_model=4096, n_layers=1, n_heads=32, n_kv_heads=8,
-    d_ff=14336, max_seq_len=4096, rope_theta=1e6, tie_embeddings=False,
-    param_dtype=jnp.bfloat16, attn_impl="flash", loss_chunk=256,
-    n_experts=8, top_k=2, capacity_factor=1.25, router_aux_coef=0.02)
-
-
-@pytest.fixture(scope="module")
-def mixtral_step(topo):
-    """``CFG_MIXTRAL``, b4 x s4096, K=2 over ``fsdp 4``, as the four-chip
-    cell runs it: (K, batch, seq, compiled)."""
-    k, batch, seq = 2, 4, 4096
-    mesh, _ = ts.auto_mesh(4, topo.devices, tp=1)
-    plan, p_sh, compiled = _compile_fused_step(moe, CFG_MIXTRAL, mesh, k,
-                                               batch, seq)
-    assert plan.expert_placement() == "expert"
-    assert "fsdp" in p_sh["layers"]["e_gate"].spec[1]
-    assert p_sh["layers"]["e_gate"].spec[2] is None
-    return k, batch, seq, compiled
-
-
-def test_mixtral_step_keeps_its_experts_rows_on_their_chip(mixtral_step,
-                                                           capsys):
-    """Eight experts split four ways, so a chip owns two whole experts and
-    contracts the whole model dim. No collective of the compiled step is
-    then an ``[E, C, f]`` buffer (under fsdp on the model dim there were
-    five, 1.17 GB each: every chip's partial products summed onto every
-    chip) and none completes a product of ``moe_experts``; what crosses
-    chips in the layer is ``[E, C, d]``."""
-    cfg = CFG_MIXTRAL
-    _, batch, seq, compiled = mixtral_step
-    E = cfg.n_experts
-    C = int(cfg.capacity_factor * batch * seq * cfg.top_k / E)
-    found = hlo_copies.collectives(compiled)
-    assert found
-    for c in found:
-        assert "moe_experts" not in c["op_name"], c
-        for _, dims in c["arrays"]:
-            assert math.prod(dims) != E * C * cfg.d_ff, c
-    with capsys.disabled():
-        print("\nMixtral, 1 layer, fsdp 4, collectives a launch of 2 steps:")
-        for kind, n in hlo_copies.collective_inventory(compiled).items():
-            print(f"  {kind}: {n['count']} ({n['runs']} runs), "
-                  f"{n['bytes'] / 1e9:.2f} GB of results")
-
-
-def test_mixtral_step_moves_its_rows_by_index(mixtral_step, capsys):
-    """Dispatch and combine are row gathers by index (``moe._dispatch``,
-    ``moe._combine``): the compiled step holds no ``[G, E, C]`` array, whole
-    or a chip's share, and no matrix product under ``moe_dispatch`` or
-    ``moe_combine``; what those scopes move across chips is the tokens
-    gathered to their experts' owners and the partial outputs
-    reduce-scattered back, never more than ``[G, d]`` (the ``[E, C, d]``
-    exchange is gone); and no ``d``-wide row is scattered: each move's
-    backward is the other move. Fails on the parent, where the rows moved
-    through ``gd,gec->ecd`` and ``ecd,gec->gd`` against one-hot tensors."""
-    cfg = CFG_MIXTRAL
-    _, batch, seq, compiled = mixtral_step
-    G, E, d = batch * seq, cfg.n_experts, cfg.d_model
-    C = int(cfg.capacity_factor * G * cfg.top_k / E)
-    scopes = ("moe_dispatch", "moe_combine")
-    for _, (name, shape, opcode, _, line), _ in hlo_copies._Module(
-            compiled.as_text()).walk(fusions=True):
-        arrays = hlo_copies._arrays(shape)
-        for _, dims in arrays:
-            assert math.prod(dims) not in (G * E * C, G // 4 * E * C), line[:300]
-        source = hlo_copies._OP_NAME.search(line)
-        if not (source and any(s in source.group(1) for s in scopes)):
-            continue
-        assert opcode not in ("dot", "convolution"), line[:300]
-        assert not (opcode == "fusion" and "convolution" in name), line[:300]
-        if opcode == "scatter":
-            assert all(dims[-1:] != (d,) for _, dims in arrays), line[:300]
-    every = hlo_copies.collectives(compiled)
-    found = [c for c in every if any(s in c["op_name"] for s in scopes)]
-    with capsys.disabled():
-        print("\nMixtral, 1 layer, fsdp 4, collectives under moe_dispatch and "
-              "moe_combine, a launch of 2 steps:")
-        for c in found:
-            if c["bytes"] >= 2 ** 20:  # (indices and gates are 0.1-0.3 MB)
-                print(f"  {c['kind']} {c['arrays']} x{c['runs']}, "
-                      f"{c['runs'] * c['bytes'] / 1e6:.1f} MB  {c['op_name']}")
-    # the tokens' gather: forward, rematted, and for combine's backward
-    # (the reduce-scatters back are merged with small ones by the compiler
-    # and lose their op_name: they are held by size below, with all others)
-    gathers = [c for c in found if c["kind"] == "all-gather"
-               and any(math.prod(dims) == G * d for _, dims in c["arrays"])]
-    assert len(gathers) == 3, gathers
-    for c in found:
-        for _, dims in c["arrays"]:
-            assert math.prod(dims) <= G * d, c
-    for c in every:
-        for _, dims in c["arrays"]:
-            assert math.prod(dims) not in (E * C * d, E * C * d // 4), c
-
-
-def _head_collectives(compiled, dims):
-    """The collectives of a compiled step whose result holds the loss's
-    head at ``dims``, one per channel: where the TPU compiler makes a
-    collective asynchronous, its start, continuation and done fusions each
-    spell the instruction out under the one ``channel_id``, and
-    ``hlo_copies.collectives`` lists all three."""
-    text = compiled.as_text()
-    by_channel = {}
-    for c in hlo_copies.collectives(compiled):
-        if any(d == dims for _, d in c["arrays"]):
-            channel = re.search(
-                rf"%{re.escape(c['name'])} = .*?channel_id=(\d+)", text)
-            by_channel.setdefault(channel.group(1) if channel else c["name"],
-                                  c)
-    return list(by_channel.values())
-
-
-@pytest.mark.parametrize("step", ["mixtral-fsdp4", "1b-fsdp2-tp2"])
-def test_the_loss_gathers_its_head_once_a_step(step, request, capsys):
-    """``chunked_ce``'s loop closes over a head whole along d
-    (``llama.head_for_loss_loop``): the head is gathered once a step before
-    the loop and its gradient summed over the chips once after it, V left
-    on ``tp``. At most 3 collectives a step hold the head and none runs per
-    chunk. Fails on the parent of the change that brought it: there the
-    Mixtral step gathers ``[4096, 32000]`` 16 times forward and 16 times in
-    the rematted backward a step (64 runs a launch of 2, and 32 more of
-    the gradient's ``[1024, 32000]`` reduce-scatter); the ``fsdp 2 x tp 2``
-    one gathers ``[2048, 16000]`` 8 + 8 times a step (32 a launch)."""
-    if step == "mixtral-fsdp4":
-        k, _, seq, compiled = request.getfixturevalue("mixtral_step")
-        cfg, tp = CFG_MIXTRAL, 1
-    else:
-        cfg, k, compiled = request.getfixturevalue("flash_step")
-        seq, tp = 2048, 2
-    chunks = seq // cfg.loss_chunk
-    found = _head_collectives(compiled, (cfg.d_model, cfg.vocab_size // tp))
-    with capsys.disabled():
-        print(f"\n{step}: collectives that hold the head, a launch of {k}:")
-        for c in found:
-            print(f"  {c['kind']} {c['arrays']} x{c['runs']} {c['op_name']}")
-    assert found and {c["kind"] for c in found} >= {"all-gather"}, found
-    assert sum(c["runs"] for c in found) <= 3 * k, found
-    for c in found:
-        assert c["runs"] % (chunks * k), c
-    # nor does anything else as wide as the vocabulary cross chips per chunk
-    # (the parent's gradient, reduce-scattered as [d / fsdp, V], and the
-    # chunk's [b, 256, V] logits' cotangent, gathered for it)
-    # nor once a group: the loop's chunks are unrolled inside a group, so a
-    # collective of theirs would run ``groups * k`` times (the ``fsdp 4``
-    # step did, 32 gathers of a chunk's cotangent over the batch and the
-    # head's once a group, until the head was placed inside the loop's body)
-    for c in hlo_copies.collectives(compiled):
-        for _, dims in c["arrays"]:
-            if len(dims) > 1 and dims[-1] == cfg.vocab_size // tp:
-                assert c["runs"] % (chunks * k) and c["runs"] <= k, c
-
-
-# Trinity-Large-Preview at its published widths as its cell trains it
-# (benchmark/configs/trinity-large-preview.json: 8 of 256 experts and an
-# eighth of the vocabulary held here), cut to one banded and one full layer
-CFG_TRINITY = moe.MoEConfig(
-    vocab_size=25024, d_model=3072, n_layers=2, n_heads=48, n_kv_heads=8,
-    attn_head_dim=128, d_ff=3072, max_seq_len=8192, rope_theta=1e4,
-    tie_embeddings=False, param_dtype=jnp.bfloat16, attn_impl="flash",
-    loss_chunk=256, qk_norm_head=True, attn_gate=True, sandwich_norm=True,
-    embedding_multiplier=math.sqrt(3072), layer_kinds=("window", "full"),
-    sliding_window=4096, n_experts=256, n_experts_held=8, top_k=4,
-    n_shared_experts=1, router_score="sigmoid", router_bias=True,
-    route_scale=2.448, balance="sequence", router_aux_coef=5e-5)
-
-
-@pytest.fixture(scope="module")
-def trinity_step(topo):
-    """``CFG_TRINITY``, b1 x s8192, K=2 on one described chip: (K, batch,
-    seq, compiled)."""
-    k, batch, seq = 2, 1, 8192
-    mesh = make_mesh(MeshConfig(), topo.devices[:1])
-    return k, batch, seq, _compile_fused_step(moe, CFG_TRINITY, mesh, k,
-                                              batch, seq)[2]
-
-
-# step: (its fixture, kinds of attention layer, and the temporaries and peak
-# in bytes of the same compile at commit 7246729, whose remat blocks kept the
-# products' results alone)
-NO_SECOND_FORWARD = {
-    "1b-fsdp2-tp2": ("flash_step", 1, 449518080, 657651712),
-    "mixtral-fsdp4": ("mixtral_step", 1, 2467869184, 4975883776),
-    "trinity-window-full": ("trinity_step", 2, 3664176128, 8189170688)}
-
-
-@pytest.mark.parametrize("step", sorted(NO_SECOND_FORWARD))
-def test_the_backward_runs_no_second_forward(step, request, capsys):
-    """A layer's remat block keeps the flash forward's output and
-    log-sum-exp (``llama.remat_block``), so the compiled train step holds as
-    many ``flash_fwd`` calls as ``flash_dq`` calls, through the dense scan
-    under ``shard_map`` on four chips, Mixtral's scan and the patterned
-    walk's banded and full layers alike. Fails on the parent, whose backward
-    ran the forward kernel again for them (2 : 1). What the kept arrays
-    cost is printed beside the parent's."""
-    fixture, layer_kinds, temp, peak = NO_SECOND_FORWARD[step]
-    compiled = request.getfixturevalue(fixture)[-1]
-    calls = [re.search(r"flash_(fwd|dq|dkv)", line).group(1)
-             for line in compiled.as_text().splitlines()
-             if "tpu_custom_call" in line and " custom-call(" in line]
-    assert sorted(calls) == sorted(flash.KINDS * layer_kinds), calls
-    mem = compiled.memory_analysis()
-    with capsys.disabled():
-        print(f"\n{step}: temporaries {mem.temp_size_in_bytes / 2**20:.1f} "
-              f"MiB (parent {temp / 2**20:.1f}), peak "
-              f"{mem.peak_memory_in_bytes / 2**20:.1f} MiB (parent "
-              f"{peak / 2**20:.1f})")
-
-
-# Kimi-Linear-48B-A3B-Instruct at its published widths as its cell trains it
-# (benchmark/configs/kimi-linear-48b-a3b-instruct.json: 8 of 256 experts and
-# an eighth of the vocabulary held here), the cell's whole depth: the leading
-# dense KDA layer and the period KDA, KDA, KDA, MLA
-CFG_KIMI = moe.MoEConfig(
-    vocab_size=20480, d_model=2304, n_layers=5, n_heads=32, n_kv_heads=32,
-    attn_head_dim=72, d_ff=1024, d_ff_dense=9216, max_seq_len=16384,
-    tie_embeddings=False, param_dtype=jnp.bfloat16, attn_impl="flash",
-    loss_chunk=256, layer_kinds=("kda",) * 4 + ("mla",), n_dense_layers=1,
-    n_experts=256, n_experts_held=8, top_k=8, n_shared_experts=1,
-    router_score="sigmoid", router_bias=True, route_scale=2.446,
-    balance="sequence", router_aux_coef=0.0, kda_heads=32, kda_head_dim=128,
-    kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
-    v_head_dim=128)
-
-
-@pytest.fixture(scope="module")
-def kimi_step(topo):
-    """``CFG_KIMI``, b1 x s16384, K=1 on one described chip: (K, batch, seq,
-    compiled)."""
-    mesh = make_mesh(MeshConfig(), topo.devices[:1])
-    return 1, 1, 16384, _compile_fused_step(moe, CFG_KIMI, mesh, 1, 1,
-                                            16384)[2]
-
-
-def test_kimi_linears_step_compiles_for_one_v5e_at_the_cells_shape(kimi_step,
-                                                                   capsys):
-    """b1 x s16384, K=1, all five layers on one described chip: Mosaic takes
-    the flash kernels at 192 / 128 (192 is no multiple of the lanes), the
-    step fits the chip's 15.75 GiB with room (what the chunked delta rule
-    keeps for its backward is a state a segment, and a segment's temporaries
-    are live at once, not the sequence's), and the backward runs no second
-    flash forward."""
-    compiled = kimi_step[-1]
-    text = compiled.as_text()
-    customs = [line for line in text.splitlines()
-               if "tpu_custom_call" in line and " custom-call(" in line]
-    calls = [re.search(r"flash_(fwd|dq|dkv)_bh32_q16384_k16384_d192v128_c1_w0",
-                       line) for line in customs if "kda_" not in line]
-    assert all(calls) and sorted(m.group(1) for m in calls) \
-        == sorted(flash.KINDS), calls
-    # everything of the four KDA layers' chunks that does not read the state
-    # is the kernel pair's, a segment of 8 chunks a call: forward, and
-    # backward once more forward (the segment rebuilt from the state it
-    # started with) and the one backward call
-    insides = [re.search(r"kda_insides_(fwd|bwd)_bh32_n8_c64_k128_v128", line)
-               for line in customs if "kda_insides" in line]
-    assert all(insides) and sorted(m.group(1) for m in insides) \
-        == ["bwd"] * 4 + ["fwd"] * 8, insides
-    assert not any("kda_grams" in line for line in customs)
-    # and their chains round the recurrence the kernels of
-    # ``ops/pallas/kda_mix.py``: a layer's q and k (``conv_unit``), v
-    # (``conv``), decay (``decay``) and output (``norm_gate``), each forward, once more forward
-    # inside the backward (a remat block keeps the products' results, not
-    # the chains') and once backward
-    mixes = [re.search(r"kda_mix_(\w+)_s16384_h32_w128", line)
-             for line in customs if "kda_mix" in line]
-    assert all(mixes), mixes
-    counts = {name: sum(m.group(1) == name for m in mixes)
-              for name in {m.group(1) for m in mixes}}
-    assert counts == {"conv_unit_fwd": 16, "conv_unit_bwd": 8, "conv_fwd": 8,
-                      "conv_bwd": 4, "decay_fwd": 8, "decay_bwd": 4,
-                      "norm_gate_fwd": 8, "norm_gate_bwd": 4}
-    # no float32 stream is re-laid or spread through HBM outside the
-    # recurrence (the parent's step made twelve such copies, a layer's decay
-    # turned heads first forward, recomputed and backward; a layer alone
-    # with a stand-in for the recurrence, ISSUE 51's reading, also the
-    # [16384, 32] norms' spread over a head's channels)
-    streams = {("f32", dims) for dims in (
-        (16384, 32, 128), (1, 16384, 32, 128), (16384, 4096), (1, 16384, 4096))}
-    spread = [(inst[2], inst[1]) for _, inst, _ in
-              hlo_copies._Module(text).walk()
-              if inst[2] in ("broadcast", "copy") and "kda_scan" not in inst[4]
-              and set(hlo_copies._arrays(inst[1])) & streams]
-    assert not spread, spread
-    mem = compiled.memory_analysis()
-    with capsys.disabled():
-        print(f"\nkimi-linear b1 x s16384: temporaries "
-              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
-              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, peak "
-              f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
-    # 11.52 at PR 60 as at PR 54 (11.80 at PR 53, 12.36 at PR 48): the loss's
-    # rule (``llama._looped_ce``) carries the head's gradient in the head's
-    # dtype, and a group's kept cotangent, 84 MB here, is not where the step
-    # peaks. The cells this file does not compile whole read the same on the
-    # described chip with and without the rule: Mistral's six layers 14.71
-    # GiB, Trinity's 15.06 (a float32 carry: 14.90 and 15.20)
-    assert mem.peak_memory_in_bytes < 13.0 * 2**30
-    assert mem.argument_size_in_bytes > 3.3 * 2**30   # 602M x 6 bytes
-
-
-# Xing4.0-29B-A4B at its published widths as its cell trains it
-# (benchmark/configs/xing4.0-29b-a4b.json: 8 of 64 experts and an eighth of
-# the vocabulary held here), the cell's whole depth and its prediction module
-def _cfg_xing4():
-    from ray_tpu.ops.rope import Yarn
-
-    return moe.MoEConfig(
-        vocab_size=16384, d_model=3584, n_layers=5, n_heads=32, n_kv_heads=32,
-        d_ff=1024, d_ff_dense=9216, max_seq_len=8192, norm_eps=1e-6,
-        tie_embeddings=False, param_dtype=jnp.bfloat16, attn_impl="flash",
-        loss_chunk=256, layer_kinds=("mla",) * 5, n_dense_layers=1,
-        n_experts=64, n_experts_held=8, top_k=4, n_shared_experts=1,
-        router_score="sigmoid", router_bias=True, route_scale=2.0,
-        balance="sequence", router_aux_coef=0.0, kv_lora_rank=512,
-        q_lora_rank=768, qk_nope_head_dim=128, qk_rope_head_dim=64,
-        v_head_dim=128, mla_rope=True,
-        mla_yarn=Yarn(64.0, 4096, 32.0, 1.0, 1.0, 1.0), hc_mult=4,
-        n_mtp_modules=1)
-
-
-@pytest.fixture(scope="module")
-def xing4_step(topo):
-    """The cell's config, b1 x s8192, K=2 on one described chip: (K, batch,
-    seq, compiled)."""
-    mesh = make_mesh(MeshConfig(), topo.devices[:1])
-    return 2, 1, 8192, _compile_fused_step(moe, _cfg_xing4(), mesh, 2, 1,
-                                           8192)[2]
-
-
-def test_xing4s_step_compiles_for_one_v5e_at_the_cells_shape(xing4_step,
-                                                             capsys):
-    """b1 x s8192, K=2, five layers and the prediction module on one
-    described chip, every layer its own remat block over a four-row stream:
-    Mosaic takes the flash kernels at 192 / 128 with 32 heads, six layers'
-    worth and no second forward, and the step fits the chip's 15.75 GiB
-    beside 913.6M parameters' state (8 bytes each: the arguments are 6)."""
-    compiled = xing4_step[-1]
-    customs = [line for line in compiled.as_text().splitlines()
-               if "tpu_custom_call" in line and " custom-call(" in line]
-    calls = [re.search(r"flash_(fwd|dq|dkv)_bh32_q8192_k8192_d192v128_c1_w0"
-                       r"|mhc_(in|out)_(fwd|bwd)_n4_t8192_d3584", line)
-             for line in customs]
-    assert all(calls), [c for c, m in zip(customs, calls) if not m]
-    count = collections.Counter(
-        "_".join(filter(None, m.groups())) for m in calls)
-    # the dense layer, the scanned expert layers' one body, the module's:
-    # three layers' worth of the flash kernels and no second forward, and of
-    # the hyper-connections' four calls (PR 57) a half layer one each way,
-    # with the attention half's mix_out a second time in a layer's backward
-    # (its rows are what the feed-forward half's backward reads; mix_in's
-    # call, whose results the block keeps by name, runs once)
-    assert count == {**{kind: 3 for kind in flash.KINDS}, "in_fwd": 6,
-                     "out_fwd": 9, "out_bwd": 6, "in_bwd": 6}, count
-    mem = compiled.memory_analysis()
-    with capsys.disabled():
-        print(f"\nxing4 b1 x s8192, K=2: temporaries "
-              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
-              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, peak "
-              f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
-    # 13.66 at PR 60: two looped cross entropies, each a group's cotangent
-    # kept (67 MB) and its gradients held to the backward (13.56 at PR 57,
-    # 13.32 at PR 56)
-    assert mem.peak_memory_in_bytes < 15.75 * 2**30
-    assert mem.argument_size_in_bytes > 5.0 * 2**30    # 913.6M x 6 bytes
-    # and it names all of itself, as ``test_a_train_step_names_all_of_itself``
-    # holds the older steps to (here and not a case of that test's: a case
-    # may run on another worker and compile the step, ~110 s, a second time)
-    named, stacking, rootless, strays = _scopes_of_a_step(compiled)
-    assert not strays, strays
-    assert set(named) == {
-        "embed", "hyper_mix", "attn_mla", "mlp", "moe_router", "moe_dispatch",
-        "moe_experts", "moe_combine", "moe_shared", "mtp", "loss_head",
-        "optimizer"} <= set(ts.STEP_SCOPES), named
-    assert all(set(inside) <= set(ts.STEP_SCOPES)
-               for *_, inside in rootless), rootless
-    assert len(stacking) <= 140 and len(rootless) <= 95, (
-        len(stacking), len(rootless))
-    # both of its cross entropies, the main head's and the prediction
-    # module's, are ``llama._looped_ce``'s loop: 32 chunks in 4 groups each
-    for scope in ("loss_head", "mtp"):
-        assert _assert_three_products_a_chunk(
-            compiled, scope, 2, _cfg_xing4(), 8192) == (32, 4)
-
-
-# EvaByte at its published widths as its cell trains it
-# (benchmark/configs/evabyte.json): the first four of 32 layers, the whole
-# vocabulary of 320, eight prediction heads
-CFG_EVABYTE = llama.LlamaConfig(
-    vocab_size=320, d_model=4096, n_layers=4, n_heads=32, n_kv_heads=32,
-    d_ff=11008, max_seq_len=16384, rope_theta=100000.0,
-    param_dtype=jnp.bfloat16, attn_impl="flash", loss_chunk=256,
-    attn_kind="eva", eva_window=2048, eva_chunk=16, norm_unit_offset=True,
-    residual_f32=True, n_pred_heads=8)
-
-
-@pytest.fixture(scope="module")
-def evabyte_step(topo):
-    """``CFG_EVABYTE``, b1 x s16384, K=1 on one described chip: (K, batch,
-    seq, compiled)."""
-    mesh = make_mesh(MeshConfig(), topo.devices[:1])
-    return 1, 1, 16384, _compile_fused_step(llama, CFG_EVABYTE, mesh, 1, 1,
-                                            16384)[2]
-
-
-def test_evabytes_step_compiles_for_one_v5e_at_the_cells_shape(evabyte_step,
-                                                               capsys):
-    """b1 x s16384, K=1, four layers on one described chip: Mosaic takes
-    EVA attention's four kernels with their scalar-prefetched lists of
-    visits (eight windows of two 1,024-row blocks, summary blocks of 128),
-    each carries the name ``benchmark/kernels/eva_attn.py`` costs it by, and
-    beside them the pair that makes their operands of the projections'
-    results (``ops/pallas/eva_mix.py``, PR 55): ONE forward and ONE backward
-    call in the step. The backward runs no second forward kernel of either
-    family (the remat block keeps ``o`` and ``lse`` under
-    ``flash.RESIDUAL_NAMES`` and the pair's five results under
-    ``eva.RESIDUAL_NAMES``) and so none of ``wq``, ``wk``, ``wv``'s products
-    a second time. The step fits the chip's 15.75 GiB with the 0.6 GiB
-    ISSUE 52 asked for to spare."""
-    from benchmark.kernels import eva_attn as cost
-
-    compiled = evabyte_step[-1]
-    text = compiled.as_text()
-    customs = [line.strip() for line in text.splitlines()
-               if "tpu_custom_call" in line and " custom-call(" in line]
-    mixes = [m.group(1) for m in (re.search(
-        r"eva_mix_(fwd|bwd)_s16384_h32_d128_c16/pallas_call", line)
-        for line in customs) if m]
-    assert sorted(mixes) == ["bwd", "fwd"], customs
-    shapes = [cost.call_shape(line) for line in customs
-              if "eva_mix_" not in line]
-    assert all(shapes) and len(shapes) + len(mixes) == len(customs), customs
-    assert sorted(shapes) == sorted(
-        [(kind, 32, 16384, 128, 2048, 16, 2)
-         for kind in ("fwd", "dq", "dkv", "dsum")]), shapes
-    # what the backward computes a second time under ``attn_eva``: the
-    # product with ``wo`` (the block keeps ``o`` and rebuilds the stream
-    # after the mixer from it, as at PR 52) and none of ``wq``, ``wk``,
-    # ``wv``'s, whose results only the pair's forward call read
-    again = {re.sub(r"\.clone\.\d+$", "", inst[0])
-             for _, inst, _ in hlo_copies._Module(text).walk(fusions=True)
-             if inst[2] == "convolution"
-             and "rematted_computation/attn_eva" in inst[4]}
-    assert len(again) == 1, again
-    mem = compiled.memory_analysis()
-    with capsys.disabled():
-        print(f"\nevabyte b1 x s16384, 4 layers: temporaries "
-              f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
-              f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, peak "
-              f"{mem.peak_memory_in_bytes / 2**30:.2f} GiB")
-    # 15.01 at PR 60 as at PR 55 (14.89 at PR 52)
-    assert mem.peak_memory_in_bytes < 15.15 * 2**30
-    assert mem.argument_size_in_bytes > 4.5 * 2**30   # 821M x 6 bytes
-
-
-# ---- the chunked loss's loop: three vocabulary-wide products a chunk ----------
-
-def _loss_loop(compiled, scope, cfg, tp=1):
-    """What the compiled step runs under ``scope`` of ``llama._looped_ce``'s
-    loop, in one launch: (runs of products that read or write
-    an array as wide as the vocabulary a chip holds, runs of instructions
-    that write a ``[d, V]`` array), each as (name, runs) rows. A product is a
-    ``convolution`` inside or outside a fusion; its operands' shapes are its
-    computation's parameters'."""
-    from benchmark.lib.trace import scope_of
-
-    wide = cfg.vocab_size * max(getattr(cfg, "n_pred_heads", 1), 1) // tp
-    module = hlo_copies._Module(compiled.as_text())
-    products, writes = [], []
-    for times, (name, shape, opcode, operands, line), comp in module.walk(
-            fusions=True):
-        if scope_of(_op_name(line)) != scope:
-            continue
-        if opcode == "convolution":
-            shapes = [shape] + [i[1] for i in comp if i[0] in operands]
-            # (n heads' logits may stand as [chunk, n, V]: a run of
-            # dimensions that multiplies to the width)
-            if any(math.prod(dims[i:j]) == wide for text in shapes
-                   for _, dims in hlo_copies._arrays(text)
-                   for i in range(len(dims))
-                   for j in range(i + 1, len(dims) + 1)):
-                products.append((name, times))
-    for times, (name, shape, opcode, _, line), _ in module.walk():
-        if (opcode in ("fusion", "convolution", "copy")
-                and scope_of(_op_name(line)) == scope
-                and any(dims[-2:] == (cfg.d_model, wide)
-                        for _, dims in hlo_copies._arrays(shape))):
-            writes.append((name, times))
-    return products, writes
-
-
-def _assert_three_products_a_chunk(compiled, scope, k, cfg, seq, tp=1):
-    """Under ``scope`` the step's loss runs, a step, two vocabulary-wide
-    products a chunk of ``loss_chunk`` positions (the logits, the hidden's
-    gradient) and one a GROUP of chunks (the head's gradient), and writes a
-    ``[d, V]`` array once a group and not once a chunk. Fails on the
-    parent, whose rematted loop ran four a chunk (the logits twice) and
-    read and wrote the head's whole cotangent in each (Mistral's shape:
-    ``convolution_add_fusion.5``, 64 runs a launch of 4)."""
-    chunks = seq // cfg.loss_chunk
-    groups = chunks // llama._chunks_a_group(chunks, cfg.loss_chunk)
-    products, writes = _loss_loop(compiled, scope, cfg, tp)
-    assert sum(runs for _, runs in products) == k * (2 * chunks + groups), (
-        scope, products)
-    # the groups' sums, and what a step makes of them once: the zero they
-    # start from, the incoming cotangent's scale, a cast
-    assert k * groups <= sum(runs for _, runs in writes) \
-        <= k * (groups + 3), (scope, writes)
-    return chunks, groups
-
-
-# ---- every operation of a compiled train step under a scope of the program's ---
-
-# step: (its fixture, the scopes its operations must be found under, and the
-# most instructions that may carry none: the scans' and the walk's own
-# stacking and slicing, and fusions or collectives the compiler rooted in an
-# instruction of its own; since PR 60 among them the loss's unrolled chunks'
-# writes of their cotangent and of their slice of ``dx`` into the group's
-# stacks, sixteen in a group of eight, which hold ``loss_head`` only)
-STEP_NAMES = {
-    "1b-fsdp2-tp2": ("flash_step", {"embed", "attn_full", "mlp", "loss_head",
-                                    "optimizer"}, 55, 36),
-    "mixtral-fsdp4": ("mixtral_step", {
-        "embed", "attn_full", "moe_router", "moe_dispatch", "moe_experts",
-        "moe_combine", "loss_head", "optimizer"}, 8, 60),
-    "trinity-window-full": ("trinity_step", {
-        "embed", "attn_window", "attn_full", "moe_router", "moe_dispatch",
-        "moe_experts", "moe_combine", "moe_shared", "loss_head",
-        "optimizer"}, 45, 75),
-    "kimi-kda-mla": ("kimi_step", {
-        "embed", "attn_kda", "attn_mla", "mlp", "moe_router", "moe_dispatch",
-        "moe_experts", "moe_combine", "moe_shared", "loss_head",
-        "optimizer"}, 140, 155),
-    "evabyte": ("evabyte_step", {"embed", "attn_eva", "mlp", "loss_head",
-                                 "optimizer"}, 45, 10)}
-# step: (the config whose loss it runs, positions a sequence, ways ``tp``
-# splits the vocabulary); the 1b step's config is its fixture's first
-LOSS_LOOPS = {"1b-fsdp2-tp2": (None, 2048, 2),
-              "mixtral-fsdp4": (CFG_MIXTRAL, 4096, 1),
-              "trinity-window-full": (CFG_TRINITY, 8192, 1),
-              "kimi-kda-mla": (CFG_KIMI, 16384, 1),
-              "evabyte": (CFG_EVABYTE, 16384, 1)}
-_TIMED = {"fusion", "convolution", "custom-call", "all-gather", "all-reduce",
-          "reduce-scatter", "all-to-all", "collective-permute"}
-# a scan's stacking of its per-layer results and slicing of its operands (and
-# the patterned walk's picking of a layer out of its period's stack): JAX
-# writes them, directly under the loop's body, and no scope can stand there
-# (and an index the compiler folded out of such a slice, an ``s32[2]`` that
-# carries the enclosing call's name and nothing after it: the widened
-# stream's step has fourteen, two microseconds each)
-_STACKING = re.compile(r"(/(body|closed_call)/(dynamic_update_slice"
-                       r"|dynamic_slice|squeeze|slice|broadcast_in_dim)"
-                       r"|/while|/closed_call)$")
-_OP_NAME = re.compile(r'op_name="(jit\([^"]*)"')
-
-
-def _op_name(line):
-    """An instruction's ``op_name`` where it is a path of the program's (the
-    compiler's own carry none, or a bare word: ``reduce_window_sum``)."""
-    found = _OP_NAME.search(line)
-    return found.group(1) if found else ""
-
-
-def _scopes_of_a_step(compiled):
-    """(instructions by scope, those of the scans' stacking, those the
-    compiler rooted in an instruction of its own, the strays) of a compiled
-    step's fusions, products, kernels and collectives outside fused
-    computations, each stray and exception as (name, shape, op_name)."""
-    from benchmark.lib.trace import scope_of
-
-    module = hlo_copies._Module(compiled.as_text())
-    named, stacking, rootless, strays = {}, [], [], []
-    seen = set()
-    for _, (name, shape, opcode, _, line), _ in module.walk():
-        if opcode.replace("-start", "") not in _TIMED or name in seen:
-            continue
-        seen.add(name)
-        if opcode == "custom-call" and "tpu_custom_call" not in line:
-            continue  # AllocateBuffer, ConcatBitcast: the compiler's buffers
-        op_name = _op_name(line)
-        row = (name, shape.split("{")[0][:48], op_name)
-        scope = scope_of(op_name)
-        if not op_name:
-            # the instruction is the compiler's: a fused computation under
-            # it is judged by the named operations it holds
-            called = re.search(r"calls=%?([\w.\-]+)", line)
-            body = module.computations.get(called.group(1), []) if called else []
-            inside = {scope_of(_op_name(inst[4])) for inst in body
-                      if not _STACKING.search(_op_name(inst[4]))}
-            rootless.append(row + (sorted(inside - {"other"}),))
-        elif _STACKING.search(op_name):
-            stacking.append(row)
-        elif scope == "other":
-            strays.append(row)
-        else:
-            named[scope] = named.get(scope, 0) + 1
-    return named, stacking, rootless, strays
-
-
-@pytest.mark.parametrize("step", sorted(STEP_NAMES))
-def test_a_train_step_names_all_of_itself(step, request, capsys):
-    """Every fusion, product, kernel and collective of the compiled step
-    carries an ``op_name`` whose outermost scope, as the benchmark's
-    reduction reads it (``benchmark/lib/trace.py:scope_of``), is one of
-    ``train_step.STEP_SCOPES``: the old dense stack, the old MoE stack, the
-    patterned walk and the EVA block alike, with the embedding, the loss and
-    the optimizer's update. Fails on the parent, whose old stacks named
-    nothing (``other`` was 89-91% of Mistral's step). What cannot be named
-    is printed with its shape and held to a count: the scans' own stacking
-    and slicing, and instructions the compiler rooted in one of its own (a
-    ``bitcast`` after the last named operation, an expanded ``cumsum``, an
-    async collective), whose fused computations hold named operations only.
-
-    The loss is ``llama._looped_ce``'s rule, forward and backward under
-    ``loss_head`` with nothing of it astray, and the compiled step holds
-    what the rule says (``_assert_three_products_a_chunk``)."""
-    fixture, scopes, most_stacking, most_rootless = STEP_NAMES[step]
-    made = request.getfixturevalue(fixture)
-    named, stacking, rootless, strays = _scopes_of_a_step(made[-1])
-    with capsys.disabled():
-        print(f"\n{step}: {sum(named.values())} instructions under "
-              + ", ".join(f"{k} {v}" for k, v in sorted(named.items()))
-              + f"; {len(stacking)} of the scans' stacking, "
-              f"{len(rootless)} rooted by the compiler")
-        print("  stacking: " + "; ".join(
-            f"{name} {shape} {op_name.rsplit('/', 1)[-1]}"
-            for name, shape, op_name in stacking))
-        print("  rooted by the compiler: " + "; ".join(
-            f"{name} {shape} holds {','.join(inside) or '-'}"
-            for name, shape, _, inside in rootless))
-    assert not strays, strays
-    assert set(named) == scopes <= set(ts.STEP_SCOPES), set(named) ^ scopes
-    assert all(set(inside) <= set(ts.STEP_SCOPES)
-               for *_, inside in rootless), rootless
-    assert len(stacking) <= most_stacking and len(rootless) <= most_rootless
-    # and of the loss (here, on the compile this case already has: another
-    # test's case may run on another worker and compile the step again)
-    cfg, seq, tp = LOSS_LOOPS[step]
-    cfg, k = (made[0], made[1]) if cfg is None else (cfg, made[0])
-    chunks, groups = _assert_three_products_a_chunk(
-        made[-1], "loss_head", k, cfg, seq, tp)
-    with capsys.disabled():
-        print(f"  loss_head: {chunks} chunks in {groups} group(s) a step, "
-              f"{2 * chunks + groups} vocabulary-wide products")
-
-
 def test_libtpu_accepts_the_perf_flags():
     """libtpu aborts the process on a flag it does not know, and every
     worker passes ``TPU_PERF_FLAGS``. Its flags are parsed when the library

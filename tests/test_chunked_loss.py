@@ -8,7 +8,7 @@ the place of (``_parents_loop`` below, kept here as it was). Groups are made
 small (``HEAD_GRAD_ROWS`` patched) so that shapes a CPU can afford run
 several of them. What the rule does under a mesh is in
 ``test_chunked_loss_sharded.py``, what the chip's compiler makes of it in
-``test_aot_tpu_compile.py``.
+``test_aot_step_*.py`` (``_aot.names_all_of_itself``).
 """
 
 import jax

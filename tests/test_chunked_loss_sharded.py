@@ -8,7 +8,8 @@ home in the head's stored sharding, and the decision must follow what the
 step can observe (the ambient mesh, the rule that places the head): with no
 mesh, a mesh that does not split d, no loop or the pipelined rules, the
 traced program holds no constraint and is what it was. What the compiled
-four-chip step does with the constraint is in ``test_aot_tpu_compile.py``.
+four-chip step does with the constraint is in ``test_aot_step_1b.py`` and
+``test_aot_step_mixtral.py``.
 
 The loop is ``llama._looped_ce``'s: both gradients formed forward, the
 head's a product a group of chunks. Groups are two chunks here

@@ -19,6 +19,7 @@ size, on the CPU. Two tolerances, each with its reason:
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -80,10 +81,25 @@ def _tokens(n, salt, rows=1):
         0, TINY["vocab_size"], (rows, n)), jnp.int32)
 
 
-def _prefill(params, cfg, tokens, max_len=MAX_LEN):
-    return G._forward_with_cache(params, tokens, cfg,
-                                 G.init_cache(cfg, tokens.shape[0], max_len),
-                                 0, last_only=False)
+@functools.lru_cache(maxsize=None)
+def _prefill_of(cfg, max_len, last_only):
+    return jax.jit(lambda params, tokens: G._forward_with_cache(
+        params, tokens, cfg, G.init_cache(cfg, tokens.shape[0], max_len), 0,
+        last_only=last_only))
+
+
+def _prefill(params, cfg, tokens, max_len=MAX_LEN, last_only=False):
+    """A prefill into a fresh tree, jitted once a (config, shape): the
+    layers op by op were 8-14 s a call, and the tests share their lengths."""
+    return _prefill_of(cfg, max_len, last_only)(params, tokens)
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_step(cfg):
+    """``decode_step_on_slots`` jitted once a config, the weights an argument:
+    the tests that replay at one config and as many rows share a compile."""
+    return jax.jit(lambda params, tok, cache, pos: G.decode_step_on_slots(
+        params, tok, cfg, cache, 0, pos)[:2])
 
 
 def _off(got, ref):
@@ -139,16 +155,14 @@ def _replay(params, cfg, prompts, new):
     cache = G.init_cache(cfg, len(prompts), MAX_LEN)
     out = [[] for _ in prompts]
     for row, p in enumerate(prompts):
-        logits, one = G._forward_with_cache(
-            params, p[None, :-new], cfg, G.init_cache(cfg, 1, MAX_LEN), 0)
+        logits, one = _prefill(params, cfg, p[None, :-new], last_only=True)
         cache = serving._write_row(cache, one, row)
         out[row].append(logits[0, -1])
-    step = jax.jit(lambda tok, cache, pos: G.decode_step_on_slots(
-        params, tok, cfg, cache, 0, pos)[:2])
+    step = _decode_step(cfg)
     pos = jnp.asarray([len(p) - new for p in prompts], jnp.int32)
     for t in range(new):
         tok = jnp.asarray([p[len(p) - new + t] for p in prompts], jnp.int32)
-        logits, cache = step(tok, cache, pos + t)
+        logits, cache = step(params, tok, cache, pos + t)
         for row in range(len(prompts)):
             out[row].append(logits[row])
     return [jnp.stack(o) for o in out], cache

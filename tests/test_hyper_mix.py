@@ -17,6 +17,7 @@ from ray_tpu.ops import hyper
 from ray_tpu.ops.pallas import hyper_mix
 from ray_tpu.parallel.context import mesh_scope
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.util import plans
 
 D, B, S = 256, 2, 128          # 256 tokens: two tiles of 128, one of 256
 RULE = dict(iters=20, eps=1e-6, clamp=(-30.0, 30.0), norm_eps=1e-6)
@@ -204,10 +205,11 @@ def test_a_shape_the_kernels_refuse_takes_the_xla_path(case, impl, d, tokens,
     scope = (mesh_scope(make_mesh(MeshConfig(fsdp=devices),
                                   jax.devices()[:devices]))
              if devices else contextlib.nullcontext())
-    plan = {}
-    with scope, hyper.noting_plan(plan):
+    noted = {}
+    with scope, plans.noting(noted):
         h, mix = jax.eval_shape(
             lambda x: hyper.mix_in(x, half, **RULE, impl=impl), x)
+    plan = noted["hyper_plan"]
     assert isinstance(mix, hyper.Held if tile else hyper.Mix), case
     assert plan == hyper.plan(n, d, 2, 20, tile)
     assert (plan["impl"], plan["tile_tokens"]) == (

@@ -297,7 +297,8 @@ def test_both_heads_logits_are_the_references(family, model):
     cfg, params = model
     drop_free = dataclasses.replace(cfg, capacity_factor=64.0)
     with jax.default_matmul_precision("highest"):
-        got = moe.forward(params, TOKENS[:, :-1], drop_free)
+        got = jax.jit(lambda p: moe.forward(p, TOKENS[:, :-1], drop_free))(
+            params)
         want = family.logits(params, TOKENS[:, :-1], CFG_FILE)
     assert float(jnp.abs(want).max()) > 1.0
     assert float(jnp.abs(got - want).max()) < 2e-4
@@ -320,7 +321,7 @@ def test_both_heads_logits_are_the_references(family, model):
         return seen["x"] @ params["lm_head"]
 
     with jax.default_matmul_precision("highest"):
-        got = module(params)
+        got = jax.jit(module)(params)
         want = family.module_logits(params, TOKENS, CFG_FILE)
     assert float(jnp.abs(want).max()) > 1.0
     assert float(jnp.abs(got - want).max()) < 2e-4

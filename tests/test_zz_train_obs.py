@@ -443,6 +443,131 @@ def _get_json(port, path):
         return json.loads(resp.read())
 
 
+def _four_plans():
+    """A plan of each kind that has a sentence, hand-made."""
+    from ray_tpu.ops import hyper
+
+    return {
+        "flash_plans": [{
+            "kind": "fwd", "seq_q": 8192, "seq_k": 8192, "head_dim": 128,
+            "block_q": 1024, "block_k": 1024, "live_steps": 30,
+            "edge_steps": 12, "sub_block": (512, 512),
+            "grid_steps": 64, "window": 4096}],
+        "kda_plan": dict(
+            chunk=64, sub_block=16, chunks=256, segments=4, heads=32,
+            d_k=128, d_v=128, boundary_state_bytes=536_870_912,
+            impl="pallas_insides"),
+        "eva_plan": dict(
+            impl="pallas", batch=1, heads=32, head_dim=128, seq=16384,
+            window=2048, chunk=16, windows=8, chunks=1024,
+            summaries_seen=896, block=1024, summary_block=128,
+            tiles_needed=80, tiles_visited=80),
+        "hyper_plan": hyper.plan(4, 3584, 2, 20, 256)}
+
+
+def test_a_plan_is_noted_through_one_seam():
+    """A kind nobody has heard of, noted inside ``plans.noting``, lands in
+    the recorder's summaries under ``<kind>_plan`` with no edit to driver or
+    recorder; a nested scope hands the outer sink back, another thread sees
+    none, and nothing is noted outside a scope."""
+    import threading
+
+    from ray_tpu.util import plans
+
+    rec = _synthetic("seam")
+    try:
+        plans.note("stub", {"lost": 1})
+        inner, seen = {}, {}
+        with plans.noting(rec.plans):
+            plans.note("stub", {"tile": 8})
+            with plans.noting(inner):
+                plans.note("stub", {"tile": 16})
+            plans.note("stub", {"impl": "xla"})
+            for _ in range(2):
+                plans.note("flash", {"kind": "fwd", "block_q": 128})
+            plans.note("flash", {"kind": "dq", "block_q": 64})
+            t = threading.Thread(target=lambda: (
+                plans.note("stub", {"tile": 32}),
+                seen.update(into=getattr(plans._noting, "into", None))))
+            t.start()
+            t.join()
+        plans.note("stub", {"lost": 2})
+        assert seen == {"into": None}
+        assert inner == {"stub_plan": {"tile": 16}}
+        assert rec.plans == {
+            "stub_plan": {"tile": 8, "impl": "xla"},
+            "flash_plans": [{"kind": "fwd", "block_q": 128},
+                            {"kind": "dq", "block_q": 64}]}
+        for summ in (rec.summary(), rec.window_summary(0.0, 1e18)):
+            assert summ["stub_plan"] == {"tile": 8, "impl": "xla"}
+            assert summ["flash_plans"] == rec.plans["flash_plans"]
+            assert summ["kda_plan"] == summ["eva_plan"] == {}
+            assert summ["hyper_plan"] == {}
+        # a copy: a reader's edit does not reach the recorder
+        rec.summary()["stub_plan"]["tile"] = 0
+        rec.summary()["flash_plans"][0]["kind"] = "?"
+        assert rec.plans["stub_plan"]["tile"] == 8
+        assert rec.plans["flash_plans"][0]["kind"] == "fwd"
+        # the span carries the keys a reader without the worker takes, where
+        # they are not empty, and nothing of flash or kda
+        s1 = rec.record_launch(t_start=1000.0, data_wait_s=0.0, h2d_s=0.0,
+                               dispatch_s=0.05, t_dispatch_end=1000.05,
+                               tokens=64, k=2)
+        rec.finalize_launch(s1, 1000.1)
+        assert not {"flash_plans", "stub_plan", "kda_plan", "eva_plan",
+                    "hyper_plan"} & set(rec.launch_totals())
+        rec.plans.update(_four_plans())
+        totals = rec.launch_totals()
+        assert totals["eva_plan"] == rec.plans["eva_plan"]
+        assert totals["hyper_plan"] == rec.plans["hyper_plan"]
+        assert not {"flash_plans", "kda_plan", "stub_plan"} & set(totals)
+    finally:
+        rec.close()
+
+
+def test_rt_train_stats_prints_each_plan_as_before():
+    """``plans.DESCRIBE``'s four sentences are, to the letter, what
+    ``cli.cmd_train`` printed for the same plans before they moved there
+    (PR 60's commit, lines 1201-1244), both branches of each."""
+    from ray_tpu.util import plans
+
+    four = _four_plans()
+    four["flash_plans"].append({
+        "kind": "dkv", "seq_q": 4096, "seq_k": 4096, "head_dim": 64,
+        "block_q": 512, "block_k": 1024, "live_steps": 16, "edge_steps": 0,
+        "sub_block": (512, 512), "grid_steps": 32, "window": None})
+    assert list(plans.DESCRIBE) == ["flash_plans", "kda_plan", "eva_plan",
+                                    "hyper_plan"]
+    assert [plans.DESCRIBE["flash_plans"](p) for p in four["flash_plans"]] == [
+        "flash fwd s8192x8192 d128: tile 1024x1024, 30 of 64 grid steps "
+        "live, 12 of them crossed by an edge, sub-tile 512x512, window 4096",
+        "flash dkv s4096x4096 d64: tile 512x1024, 16 of 32 grid steps live"]
+    kda_says = (
+        "kda: 256 chunks of 64 in 4 segment(s), sub-block 16, 32 heads "
+        "128x128, states at the chunks' starts 512 MiB a layer, a chunk's "
+        "insides: ")
+    assert plans.DESCRIBE["kda_plan"](four["kda_plan"]) == (
+        kda_says + "a Pallas kernel pair (pallas_insides)")
+    assert plans.DESCRIBE["kda_plan"]({**four["kda_plan"], "impl": "xla"}) \
+        == kda_says + "XLA (xla)"
+    assert plans.DESCRIBE["eva_plan"](four["eva_plan"]) == (
+        "eva: 8 window(s) of 2048, 1024 chunks of 16 a row, a query sees at "
+        "most 896 summaries, 32 heads of 128; score tiles (1024 rows x 1024 "
+        "keys or 128 summaries) visited / needed 80 / 80 a head (pallas)")
+    hyper_says = (
+        "hyper-connections: a stream of 4 rows of 3584, 20 Sinkhorn "
+        "iterations a half layer; the least passes over the stream move "
+        "100.4 KB forward and 164.9 KB backward a token and half layer")
+    assert plans.DESCRIBE["hyper_plan"](four["hyper_plan"]) == (
+        hyper_says + ", the four calls' blocks 101.5 and 223.8 KB, 256 "
+        "tokens a grid step (pallas; rows first [n, b, s, d]; coefficients "
+        "[b s, 128] float32, a coefficient a lane)")
+    from ray_tpu.ops import hyper
+    assert plans.DESCRIBE["hyper_plan"](hyper.plan(4, 3584, 2, 20)) == (
+        hyper_says + " (xla; rows first [n, b, s, d]; coefficients "
+        "[n*n + 2n, b, s] float32, tokens on the lanes)")
+
+
 def test_train_stats_missing_snapshot_is_an_error(rt_cluster):
     """Grading a run that never recorded is a mistake worth failing:
     exactly one stderr line, exit 1, nothing on stdout."""
@@ -478,22 +603,7 @@ def test_api_train_and_cli_json(rt_cluster):
         rec.finalize_launch(s1, time.time(), {
             "moe_assignments": 4096, "moe_held": 128, "moe_kept": 120,
             "moe_dropped": 8, "moe_max_expert_rows": 40})
-        rec.flash_plans.append({
-            "kind": "fwd", "seq_q": 8192, "seq_k": 8192, "head_dim": 128,
-            "block_q": 1024, "block_k": 1024, "live_steps": 30,
-            "edge_steps": 12, "sub_block": (512, 512),
-            "grid_steps": 64, "window": 4096})
-        rec.kda_plan.update(
-            chunk=64, sub_block=16, chunks=256, segments=4, heads=32,
-            d_k=128, d_v=128, boundary_state_bytes=536_870_912,
-            impl="pallas_insides")
-        rec.eva_plan.update(
-            impl="pallas", batch=1, heads=32, head_dim=128, seq=16384,
-            window=2048, chunk=16, windows=8, chunks=1024,
-            summaries_seen=896, block=1024, summary_block=128,
-            tiles_needed=80, tiles_visited=80)
-        from ray_tpu.ops import hyper
-        rec.hyper_plan.update(hyper.plan(4, 3584, 2, 20, 256))
+        rec.plans.update(_four_plans())
         rec.expert_placement = "expert"
         rec.collectives = {"all-gather": {"count": 2, "runs": 6,
                                           "bytes": 3_000_000_000}}
