@@ -35,7 +35,10 @@ from where the kind's last run ended. The outer loop is what keeps the
 cache in a loop's carry for every layer: four attention blocks written out
 one after another made the TPU compiler copy K and V round each (PERF.md,
 PR 31). So is the cache: ``k``/``v`` for the attention layers,
-and for the mamba layers ``ssm`` [L, rows, h, p, n] in float32 and ``conv``
+and for the mamba layers ``ssm`` [L, rows, n, h p] in float32 (state element
+major, channels minor, head ``i``'s channels the lanes ``i p .. (i + 1) p``:
+the layout S6's state has, for ``ops/ssm.py``'s reason: a decode step's
+decay, ``dt x`` and ``y`` are then lane-dense rows) and ``conv``
 [L, rows, d_conv - 1, d_inner + 2 g n], the convolution's tail.
 
 Departures from the published code, all of precision: activations are the
@@ -227,8 +230,8 @@ def init_params(rng: jax.Array, cfg: HybridConfig) -> Params:
 def init_state(cfg: HybridConfig, batch: int) -> Dict[str, jax.Array]:
     """The mamba layers' zeroed part of ``generate.init_cache``'s tree."""
     lm = cfg.n_recurrent_layers
-    return {"ssm": jnp.zeros((lm, batch, cfg.mamba_n_heads, cfg.mamba_d_head,
-                              cfg.mamba_d_state), cfg.state_dtype),
+    return {"ssm": jnp.zeros((lm, batch, cfg.mamba_d_state, cfg.d_inner),
+                             cfg.state_dtype),
             "conv": jnp.zeros((lm, batch, cfg.mamba_d_conv - 1, cfg.conv_dim),
                               cfg.compute_dtype)}
 
@@ -275,7 +278,7 @@ def _mamba_out(cfg: HybridConfig, x, y, xs, z, layer: Params):
 
 
 def _mamba_block(cfg: HybridConfig, x, layer: Params, state, tail):
-    """A mamba block over [B, S, d] from ``state`` [B, h, p, n] and
+    """A mamba block over [B, S, d] from ``state`` [B, n, h p] and
     ``tail``; returns (hidden, the state and the tail after token S - 1)."""
     z, xs, bm, cm, dt, a, tail = _mamba_in(cfg, x, layer, tail)
     with jax.named_scope("ssm_scan"):

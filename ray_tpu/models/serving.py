@@ -9,7 +9,8 @@ paged dynamic memory:
   compiled programs never change as requests come and go. The cache is
   one tree of buffers by layer kind (``generate.init_cache``): ``k`` and
   ``v`` for the layers that attend and, for a model with recurrent layers
-  (``models/hybrid.py``), their state ``ssm`` [L, max_slots, h, p, n] and
+  (``models/hybrid.py``), their state ``ssm`` [L, max_slots, n, h p]
+  (channels minor: ``ops/ssm.py`` says why) and
   convolution tail ``conv`` beside them, and for one with window layers
   (``models/sambay.py``) their rings ``wk``/``wv`` of the last
   ``sliding_window`` positions. Every engine program takes the

@@ -1,20 +1,23 @@
-"""The one-token state-space update of a decode step, in place on the
-STACKED state of every layer and slot, in one Pallas call.
+"""Mamba-2's one-token update of a decode step, in place on the STACKED
+state of every layer and slot: ``ops/pallas/s6_update.py``'s call under this
+recurrence's name, with what a head owns spread over its channels.
 
-``ops.ssm.ssm_update`` written in XLA compiled, for the TPU, to two fusions
-that each read the rows' state (one reduces it against ``C``, the other
-writes it back): 3.0 x the rows' bytes a step where 2.0 is the least
-(PERF.md, PR 31). Here a grid step holds one row's state ``[h, p, n]`` in
-VMEM, computes ``new = state * decay + (dt x) (x) B`` and ``y = new C`` from
-it and writes ``new`` back over what it read: the state crosses HBM once
-each way. The kernel takes the whole ``[L, slots, h, p, n]`` buffer with its
-output aliased to it, and its block index picks ``(layer, slot0 + row)``
-straight out of HBM, as ``grouped_matmul`` picks its expert: nothing slices
-a layer's rows out first, and rows outside the launch are not touched.
+The state is kept ``[L, slots, n, h p]``, channels minor, as S6's is
+(``ops/ssm.py`` says why): a row's ``[128, 4096]`` float32 is 512 whole
+vector registers, the decay, ``dt x`` and ``y`` are lane-dense rows
+``[1, h p]`` and the sum over ``n`` runs down the sublanes as VPU adds. Kept
+``[h, p, n]`` (until PR 62) every head's decay and ``dt x`` was one lane
+broadcast across 128, ``y`` one masked lane column and the sum over ``n``
+eight cross-lane reductions a head: 5,568 XLU pushes a row, and the call
+ran at 72% of what its bytes take at the chip's nominal bandwidth where
+this one runs at 80%, the pace the chip gives any stream that reads and
+writes (a bare Pallas copy of the rows and XLA's own in-place scaling of
+the whole buffer both read 76-78%: PERF.md section 6, PR 62).
 
-What a head needs along the state's ``p`` (sublane) axis, ``dt x`` and ``y``,
-travels as ``[rows, p, h]`` so that a head's column is a lane slice; ``B``
-and ``C`` lie along ``n`` (lanes) as they come.
+A grid step takes ``_ROWS_A_STEP`` rows: one. At 2 MiB a row a step's
+transfers are already long beside its bookkeeping: two and four rows a
+step took the chip 471.9 and 471.3 us a layer's call where one took 470.0
+(PERF.md, PR 62).
 """
 
 from __future__ import annotations
@@ -23,33 +26,18 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.pallas import flash
+from ray_tpu.ops.pallas.s6_update import update_in_place
 
-_VMEM_LIMIT_BYTES = 48 << 20
+_ROWS_A_STEP = 1
 F32 = jnp.float32
-
-
-def _kernel(layer, slot0, st_ref, decay_ref, xd_ref, b_ref, c_ref,
-            out_ref, y_ref, *, heads: int, per_group: int):
-    del layer, slot0  # the block indices read them
-    decay, xd = decay_ref[...], xd_ref[...]          # [1, h], [p, h]
-    for i in range(heads):  # static: a head's column is a lane slice
-        g = i // per_group
-        new = (st_ref[i].astype(F32) * decay[:, i:i + 1]
-               + xd[:, i:i + 1] * b_ref[g:g + 1, :])  # [p, n]
-        out_ref[i] = new.astype(out_ref.dtype)
-        y_ref[:, i:i + 1] = jnp.sum(new * c_ref[g:g + 1, :], axis=-1,
-                                    keepdims=True)
 
 
 def ssm_update_in_place(state: jax.Array, layer, slot0, x: jax.Array,
                         dt: jax.Array, A: jax.Array, B: jax.Array,
                         C: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """``ops.ssm.ssm_update`` for the rows ``slot0 .. slot0 + b`` of layer
-    ``layer`` of ``state`` [L, slots, h, p, n] (float32 as served; computed
+    ``layer`` of ``state`` [L, slots, n, h p] (float32 as served; computed
     in float32 whatever it is kept in), which the caller
     gives up (donated, or a loop's carry): ``x`` [b, h, p], ``dt`` [b, h]
     float32, ``A`` [h], ``B``, ``C`` [b, g, n]. Returns (``y`` [b, h, p] in
@@ -57,33 +45,15 @@ def ssm_update_in_place(state: jax.Array, layer, slot0, x: jax.Array,
     device trace says what the result's shape does not, the rows it steps
     (``benchmark/kernels/ssm_update.py`` and ``util/hlo_copies.py`` read
     it): ``ssm_update_r<rows>_h<h>_p<p>_n<n>``."""
-    _, _, h, p, n = state.shape
-    b, g = x.shape[0], B.shape[1]
-    decay = jnp.exp(dt * A)[:, None, :]                          # [b, 1, h]
-    xd = (x.astype(F32) * dt[..., None]).swapaxes(1, 2)          # [b, p, h]
-    scalars = [jnp.asarray(v, jnp.int32).reshape(1) for v in (layer, slot0)]
+    n = state.shape[2]
+    b, h, p = x.shape
 
-    def row(i, layer, slot0):
-        return layer[0], slot0[0] + i, 0, 0, 0
+    def per_channel(v):  # a head's value on each of its p lanes
+        return jnp.repeat(v.astype(F32), p, axis=-1)
 
-    def mine(i, *_):
-        return i, 0, 0
-
-    per_row = [pl.BlockSpec((None, 1, h), mine), pl.BlockSpec((None, p, h), mine),
-               pl.BlockSpec((None, g, n), mine), pl.BlockSpec((None, g, n), mine)]
-    rows = pl.BlockSpec((None, None, h, p, n), row)
-    state, y = pl.pallas_call(
-        lambda *refs: _kernel(*refs, heads=h, per_group=h // g),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b,), in_specs=[rows] + per_row,
-            out_specs=[rows, pl.BlockSpec((None, p, h), mine)]),
-        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((b, p, h), F32)],
-        input_output_aliases={2: 0},  # the state, after the two scalars
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-        interpret=flash._needs_interpret(),
-        name=f"ssm_update_r{b}_h{h}_p{p}_n{n}",
-    )(*scalars, state, decay, xd, B.astype(F32), C.astype(F32))
-    return y.swapaxes(1, 2).astype(x.dtype), state
+    y, state = update_in_place(
+        state, layer, slot0, x.reshape(b, h * p), per_channel(dt),
+        per_channel(A)[None], B.astype(F32).swapaxes(1, 2),
+        C.astype(F32).swapaxes(1, 2), rows_a_step=_ROWS_A_STEP,
+        name=f"ssm_update_r{b}_h{h}_p{p}_n{n}")
+    return y.reshape(b, h, p), state

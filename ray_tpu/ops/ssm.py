@@ -20,7 +20,14 @@ Both are plain XLA. The scan is what a prefill runs. The one-token update
 here is the recurrence as it is written and what the kernel is tested
 against: the engine's decode step runs ``ops/pallas/ssm_update.py`` instead
 (the chip's finding, PERF.md PR 31: XLA compiled this form to two passes
-over the rows' state). The state is float32 whatever the activations are: a decode step
+over the rows' state). Both take and hand back the state as it is STORED,
+``[b, n, h p]``: state element major, channels minor, head ``i``'s channels
+the lanes ``i p .. (i + 1) p``. It is the layout S6's state has, for the
+reason given at the end of this file: with the channels on the lanes what a
+channel owns (its decay, ``dt x``, ``y``) is a lane-dense row and the sum
+over ``n`` an add down the sublanes, where ``[h, p, n]`` made every head's
+decay a lane broadcast and the sum a cross-lane reduction (PERF.md, PR 62).
+The state is float32 whatever the activations are: a decode step
 adds ``dt x B`` of the order of 1e-3 of the state to a state that decays by
 as little a step, and bf16's eight bits lose it. The decays, their
 cumulative sums and the products that touch the state are float32 too; the
@@ -51,9 +58,14 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
     ``x`` [b, s, h, p]; ``dt`` [b, s, h] float32, already positive; ``A``
     [h] float32, negative; ``B``, ``C`` [b, s, g, n] with ``h % g == 0``
-    (head ``i`` reads group ``i // (h / g)``); ``h0`` [b, h, p, n] float32
-    or None for zeros. Returns (``y`` [b, s, h, p] in ``x``'s type, the
-    state after token ``s - 1`` [b, h, p, n] float32).
+    (head ``i`` reads group ``i // (h / g)``); ``h0`` [b, n, h p] float32,
+    the stored layout, or None for zeros. Returns (``y`` [b, s, h, p] in
+    ``x``'s type, the state after token ``s - 1`` [b, n, h p] float32).
+    Inside, the chunks' states are ``[.., p, n]`` (the products that form
+    and read them contract and keep ``n`` as the MXU takes it: formed
+    ``[.., n, p]`` the scan took 1.8 x the time on the chip, PERF.md PR 62),
+    so ``h0`` is turned once on its way in and the last state once on its
+    way out: a row's 2 MiB a layer, once a prefill.
 
     Any ``s >= 1``: the chunk is ``min(chunk, s)`` and the sequence is
     padded at its END to whole chunks with ``dt = 0``, under which a token
@@ -93,7 +105,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
     # across chunks: the state entering each chunk, and the last one
     state0 = (jnp.zeros((b, g, r, p, n), F32) if h0 is None
-              else h0.astype(F32).reshape(b, g, r, p, n))
+              else h0.astype(F32).reshape(b, n, g, r, p).transpose(
+                  0, 2, 3, 4, 1))
 
     def step(state, per_chunk):
         w, add = per_chunk
@@ -105,24 +118,30 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     y = y + jnp.einsum("bcign,bcgrpn->bcigrp", Cc.astype(F32), entering
                        ) * jnp.exp(acs)[..., None]
     y = y.reshape(b, c * q, h, p)[:, :s].astype(cdt)
-    return y, last.reshape(b, h, p, n)
+    return y, last.transpose(0, 4, 1, 2, 3).reshape(b, n, h * p)
 
 
 def ssm_update(state: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
                B: jax.Array, C: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """The recurrence for one token a row: ``state`` [b, h, p, n] float32,
-    ``x`` [b, h, p], ``dt`` [b, h] float32 and positive, ``A`` [h], ``B``,
-    ``C`` [b, g, n]. Returns (``y`` [b, h, p] in ``x``'s type, the new state).
-    Elementwise and a sum over ``n``: one pass over the state."""
-    b, h, p, n = state.shape
+    """The recurrence for one token a row: ``state`` [b, n, h p] float32 (the
+    stored layout), ``x`` [b, h, p], ``dt`` [b, h] float32 and positive,
+    ``A`` [h], ``B``, ``C`` [b, g, n]. Returns (``y`` [b, h, p] in ``x``'s
+    type, the new state). Elementwise and a sum over ``n``: one pass over
+    the state."""
+    b, h, p = x.shape
     g = B.shape[1]
-    r = h // g
-    st = state.reshape(b, g, r, p, n)
-    decay = jnp.exp(dt * A).reshape(b, g, r, 1, 1)
-    xd = (x.astype(F32) * dt[..., None]).reshape(b, g, r, p, 1)
-    new = st * decay + xd * B.astype(F32)[:, :, None, None, :]
-    y = (new * C.astype(F32)[:, :, None, None, :]).sum(-1)
-    return y.reshape(b, h, p).astype(x.dtype), new.reshape(b, h, p, n)
+
+    def per_channel(v):  # [b, h] -> [b, 1, h p], a head's value on its lanes
+        return jnp.repeat(v, p, axis=-1)[:, None, :]
+
+    def per_group(m):    # [b, g, n] -> [b, n, h p], a group's on its heads'
+        return jnp.repeat(m.astype(F32).swapaxes(1, 2), h // g * p, axis=-1)
+
+    new = (state.astype(F32) * per_channel(jnp.exp(dt * A))
+           + (x.astype(F32) * dt[..., None]).reshape(b, 1, h * p)
+           * per_group(B))
+    y = (new * per_group(C)).sum(1)
+    return y.reshape(b, h, p).astype(x.dtype), new
 
 
 def causal_conv(xs: jax.Array, tail: jax.Array, w: jax.Array, bias: jax.Array

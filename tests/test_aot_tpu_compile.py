@@ -150,6 +150,25 @@ _HYPER_MIX = """if True:
 """
 
 
+_SSM_UPDATE = """if True:
+    import jax, jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from ray_tpu.ops.pallas import flash
+    from ray_tpu.ops.pallas.ssm_update import ssm_update_in_place
+    flash._needs_interpret = lambda: False
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    on = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+    h, p, n, bf16 = 64, 64, 128, jnp.bfloat16
+    jax.jit(ssm_update_in_place, donate_argnums=0).lower(
+        on((4, 64, n, h * p)), on((), jnp.int32), on((), jnp.int32),
+        on((64, h, p), bf16), on((64, h)), on((h,)), on((64, 1, n), bf16),
+        on((64, 1, n), bf16)).compile()
+"""
+
+
 def _scheduled_bundles(dump, script, calls, ways=("bwd", "fwd")):
     """{way: the bundles the compiler schedules for the call ``calls`` (a
     pattern with one group, the way: ``ways``, sorted) names}: ``script``
@@ -201,6 +220,80 @@ def hyper_mix_schedule(topo, tmp_path_factory):
         tmp_path_factory.mktemp("hyper_mix_schedule"), _HYPER_MIX,
         r"mhc_(in_fwd|out_fwd|out_bwd|in_bwd)_n4_t8192_d3584",
         ways=("in_bwd", "in_fwd", "out_bwd", "out_fwd"))
+
+
+@pytest.fixture(scope="module")
+def ssm_update_schedule(topo, tmp_path_factory):
+    """A layer's update of the Granite cell (64 rows of 64 heads x 64 x 128,
+    float32 state) through ``ops/pallas/ssm_update.py``."""
+    return _scheduled_bundles(
+        tmp_path_factory.mktemp("ssm_update_schedule"), _SSM_UPDATE,
+        r"(ssm_update)_r64_h64_p64_n128", ways=("ssm_update",))
+
+
+def test_the_ssm_update_keeps_pace_with_hbm(ssm_update_schedule, capsys):
+    """Mosaic takes the S6 body at Mamba-2's shape (a row's ``[128, 4096]``
+    tile a grid step, ``A`` a row ``[1, 4096]``), and the schedule leaves the
+    call bound by HBM: a grid step moves a row's 2 MiB each way, 7,650
+    cycles at 819 GB/s and 1.5 GHz, and is 1,733 bundles (PR 62: VALU 2,860
+    pushes, XLU 248, 965 spills). Kept ``[h, p, n]`` the step was 3,540
+    bundles with 5,568 XLU pushes, every head's decay and ``dt x`` a lane
+    broadcast and the sum over ``n`` eight cross-lane reductions a head,
+    and the chip took 10,600 cycles a row (``ssm_update_roofline`` 71.7
+    from PR 31 to PR 61). A libtpu that schedules the body worse, or an edit
+    that brings lane work back, fails here before any chip is asked."""
+    with capsys.disabled():
+        print(f"\nssm_update at 64 rows x [128, 4096]: bundles a row "
+              f"{ssm_update_schedule['ssm_update']}")
+    assert ssm_update_schedule["ssm_update"] <= 2000
+
+
+def _mosaic_bodies(lowered):
+    """The Mosaic modules of a lowered program's ``tpu_custom_call``s as
+    text, without their source locations."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for m in re.finditer(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                         lowered.as_text()):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True  # ``stable_mosaic``
+        with ctx:
+            out.append(ir.Module.parse(base64.b64decode(m.group(1)))
+                       .operation.get_asm(enable_debug_info=False))
+    return out
+
+
+@pytest.mark.parametrize("rows,digest", [(64, "6386df922b9c18d0"),
+                                         (1, "cc7d44d8c10e8c75")])
+def test_the_s6_update_lowers_as_the_parents_did(topo, rows, digest):
+    """Phi's call is built by the builder Granite's now shares
+    (``s6_update.update_in_place``: groups of channels, ``A`` of either
+    rank), and at Phi's shape it lowers to the Mosaic module commit 2c5f8c7
+    (the parent of PR 62) lowered, to the character, the whole engine's
+    launch and the lone row's: the digests are that commit's, taken by this
+    function in a checkout of it. (The compiled decode programs of the two
+    commits were equal too, outside their tables of source locations.)"""
+    import hashlib
+
+    from ray_tpu.ops.pallas.s6_update import s6_update_in_place
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    n, c, bf16 = 16, 5120, jnp.bfloat16
+    lowered = jax.jit(s6_update_in_place, donate_argnums=0).lower(
+        on((9, 64, n, c)), on((), jnp.int32), on((), jnp.int32),
+        on((rows, c), bf16), on((rows, c)), on((n, c)), on((rows, n), bf16),
+        on((rows, n), bf16))
+    (body,) = _mosaic_bodies(lowered)
+    assert f"@s6_update_r{rows}_n16_c5120" in body
+    assert hashlib.sha256(body.encode()).hexdigest()[:16] == digest
 
 
 def test_the_four_hyper_mix_calls_compile_and_keep_pace_with_hbm(
@@ -528,8 +621,8 @@ def test_hybrid_decode_steps_the_whole_slot_tree_in_place_on_v5e(topo, bucket):
     _no_weight_stack_is_copied(compiled)
     for line in text.splitlines():  # no layer's rows of the state on their own
         if " copy(" in line or " dynamic-update-slice(" in line:
-            assert f"f32[{bucket},64,64,128]" not in line \
-                and f"f32[1,{bucket},64,64,128]" not in line, line
+            assert f"f32[{bucket},128,4096]" not in line \
+                and f"f32[1,{bucket},128,4096]" not in line, line
 
 
 @pytest.mark.parametrize("length", [128, 384])

@@ -37,6 +37,7 @@ from ray_tpu.models import hybrid, llama, serving  # noqa: E402
 from ray_tpu.models.serving import (ContinuousBatcher, ContinuousEngine,  # noqa: E402
                                     PrefixKVCache)
 from ray_tpu.ops import ssm  # noqa: E402
+from ray_tpu.ops.pallas.ssm_update import ssm_update_in_place  # noqa: E402
 from ray_tpu.util import hlo_copies  # noqa: E402
 
 EXACT, SERVED = 2e-5, 1 / 32
@@ -102,6 +103,13 @@ def _decode_step(cfg):
         params, tok, cfg, cache, 0, pos)[:2])
 
 
+def _stored(states):
+    """The reference's states [L, h, p, n], as the publication has them, in
+    the layout the program stores [L, n, h p] (``ops/ssm.py`` says why)."""
+    layers, h, p, n = states.shape
+    return states.transpose(0, 3, 1, 2).reshape(layers, n, h * p)
+
+
 def _off(got, ref):
     """Largest difference as a share of the reference logits' scale."""
     return float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
@@ -120,7 +128,8 @@ def test_prefill_logits_match_the_reference(family, f32, served, n):
     ref = family.logits(f32[1], tokens, CFG_FILE)
     got, cache = _prefill(f32[1], f32[0], tokens)
     assert _off(got, ref) < EXACT
-    states = family.final_states(f32[1], tokens[0], CFG_FILE)
+    states = _stored(family.final_states(f32[1], tokens[0], CFG_FILE))
+    assert cache["ssm"].shape == (3, 1, 16, 8 * 16)
     assert float(jnp.abs(cache["ssm"][:, 0] - states).max()) \
         < EXACT * float(jnp.abs(states).max())
     # the served types, against the reference on the same bf16-valued weights
@@ -191,7 +200,7 @@ def test_a_bf16_state_fails_the_tolerance(family, f32):
     is off by 1e-6."""
     seq = _tokens(5 + NEW, 11)[0]
     ref = family.logits(f32[1], seq[None], CFG_FILE)[0][4:]
-    states = family.final_states(f32[1], seq, CFG_FILE)
+    states = _stored(family.final_states(f32[1], seq, CFG_FILE))
 
     def state_off(cache):
         return float(jnp.abs(cache["ssm"][:, 0].astype(jnp.float32) - states
@@ -210,8 +219,9 @@ def test_a_bf16_state_fails_the_tolerance(family, f32):
 @pytest.mark.parametrize("s", [1, 7, 8, 9, 12, 27])
 def test_chunked_scan_equals_the_sequential_recurrence(s, groups):
     """``ssd_scan`` against ``ssm_update`` a token at a time, from a state
-    that is not zero: every output and the final state. float32, so the
-    two differ by summation order alone."""
+    that is not zero, both in the stored layout [b, n, h p]: every output
+    and the final state. float32, so the two differ by summation order
+    alone."""
     rng = np.random.default_rng(s)
     b, h, p, n = 2, 4, 8, 16
     x = jnp.asarray(rng.normal(size=(b, s, h, p)), jnp.float32)
@@ -219,8 +229,9 @@ def test_chunked_scan_equals_the_sequential_recurrence(s, groups):
     a = -jnp.asarray(rng.uniform(0.5, 4.0, size=(h,)), jnp.float32)
     bm = jnp.asarray(rng.normal(size=(b, s, groups, n)), jnp.float32)
     cm = jnp.asarray(rng.normal(size=(b, s, groups, n)), jnp.float32)
-    h0 = jnp.asarray(rng.normal(size=(b, h, p, n)), jnp.float32)
+    h0 = jnp.asarray(rng.normal(size=(b, n, h * p)), jnp.float32)
     y, last = ssm.ssd_scan(x, dt, a, bm, cm, chunk=CHUNK, h0=h0)
+    assert last.shape == h0.shape
     state, ys = h0, []
     for t in range(s):
         y_t, state = ssm.ssm_update(state, x[:, t], dt[:, t], a, bm[:, t],
@@ -229,6 +240,44 @@ def test_chunked_scan_equals_the_sequential_recurrence(s, groups):
     assert float(jnp.abs(y - jnp.stack(ys, 1)).max()) < 1e-4
     assert float(jnp.abs(last - state).max()) < 1e-4
     assert ssm.n_chunks(s, CHUNK) == -(-s // min(CHUNK, s))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("slot0,rows", [(0, 4), (0, 1), (2, 1), (1, 2)],
+                         ids=["engine", "row0", "row2", "rows1-2"])
+@pytest.mark.parametrize("groups", [1, 2])
+def test_the_update_kernel_steps_its_rows_where_they_lie(groups, slot0, rows,
+                                                         dtype):
+    """``ssm_update_in_place`` (interpret mode here) against ``ssm_update``
+    on a stacked state [L, slots, n, h p]: a launch of the whole engine
+    (several rows a grid step where the kernel takes them), of one row at
+    slot 0 and at another, and of some rows from a slot that is not 0; one
+    group and two (a group's heads one run of lanes); the rows of the launch
+    stepped, every other row and layer equal bit for bit; and a state kept
+    in bf16 computed in float32 and rounded once, on its way back."""
+    rng = np.random.default_rng(7 * groups + slot0 + rows)
+    layers, slots, h, p, n, layer = 3, 4, 4, 8, 16, 1
+    state = jnp.asarray(rng.normal(size=(layers, slots, n, h * p)), dtype)
+    x = jnp.asarray(rng.normal(size=(rows, h, p)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(1e-3, 0.5, size=(rows, h)), jnp.float32)
+    a = -jnp.asarray(rng.uniform(0.5, 4.0, size=(h,)), jnp.float32)
+    bm = jnp.asarray(rng.normal(size=(rows, groups, n)), jnp.float32)
+    cm = jnp.asarray(rng.normal(size=(rows, groups, n)), jnp.float32)
+    y, new = jax.jit(ssm_update_in_place)(state, layer, slot0, x, dt, a, bm,
+                                          cm)
+    mine = slice(slot0, slot0 + rows)
+    want_y, want = ssm.ssm_update(state[layer, mine], x, dt, a, bm, cm)
+    assert new.shape == state.shape and new.dtype == dtype
+    assert y.shape == (rows, h, p) and y.dtype == x.dtype
+    assert float(jnp.abs(y - want_y).max()) < 1e-5
+    if dtype == jnp.float32:
+        assert float(jnp.abs(new[layer, mine] - want).max()) < 1e-6
+    else:  # float32 inside, one rounding out: the reference's, rounded
+        assert (new[layer, mine] == want.astype(dtype)).all()
+    untouched = np.ones((layers, slots), bool)
+    untouched[layer, mine] = False
+    assert (np.asarray(new)[untouched] == np.asarray(state)[untouched]).all()
 
 
 # ---- (d) prefill(p) + k steps leaves prefill(p + k)'s state ------------------
@@ -398,7 +447,7 @@ def test_every_engine_program_aliases_every_buffer_of_the_tree(f32):
     b = ContinuousBatcher(params, cfg, max_slots=SLOTS, max_len=MAX_LEN)
     assert list(b._cache) == ["k", "v", "ssm", "conv"]
     assert b._cache["ssm"].dtype == jnp.float32
-    assert b._cache["ssm"].shape == (3, SLOTS, 8, 16, 16)
+    assert b._cache["ssm"].shape == (3, SLOTS, 16, 8 * 16)  # [L, slots, n, h p]
     assert b._cache["conv"].shape == (3, SLOTS, 3, 8 * 16 + 2 * 16)
     assert b._cache["k"].shape[0] == 1  # the attention layers alone
     tree = tuple(b._cache.values())
@@ -447,11 +496,12 @@ def test_the_recorder_shows_the_state_and_a_dense_model_none_of_it(served):
             list(iter(eng.submit_stream(_prompt(n, n), 6).get, None))
         rec = eng.stats()["recorder"]
         window = eng._recorder.window_summary(0.0, 1e12)
+        eng_state_bytes = eng._batcher._cache["ssm"].nbytes
     finally:
         eng.shutdown()
     layout = rec["state_layout"]
     assert layout["layers"] == {"attention": 1, "recurrent": 3}
-    # per row: 3 layers of a [8, 16, 16] float32 state and a [3, 160] bf16 tail
+    # per row: 3 layers of a [16, 8 * 16] float32 state and a [3, 160] bf16 tail
     assert layout["state_bytes_per_row"] == 3 * (8 * 16 * 16 * 4 + 3 * 160 * 2) \
         == cfg.state_bytes_per_row()
     assert layout["kv_bytes_per_position"] == 2 * 1 * 2 * 16 * 2
@@ -461,7 +511,8 @@ def test_the_recorder_shows_the_state_and_a_dense_model_none_of_it(served):
         (1, 1), (1, 4), (SLOTS, 1), (SLOTS, 4)]
     for p in progs:
         assert p["cache_donated"] and p["state_donated"]
-        assert p["state_bytes"] == 3 * SLOTS * 8 * 16 * 16 * 4
+        assert p["state_bytes"] == 3 * SLOTS * 16 * (8 * 16) * 4 \
+            == eng_state_bytes
         assert p["state_copy_bytes_per_step"] > 0
     dense = llama.PRESETS["debug"]
     eng = ContinuousEngine(llama.init_params(jax.random.key(0), dense), dense,
@@ -483,11 +534,11 @@ def test_the_state_counter_reads_reads_writes_and_copies():
     rows' bytes on this backend's compile), with the state as the layer
     scan's ``xs``/``ys`` (stacked back: more), and not donated."""
     layers, slots, h, p, n = 3, 4, 4, 8, 16
-    state = jnp.zeros((layers, slots, h, p, n), jnp.float32)
+    state = jnp.zeros((layers, slots, n, h * p), jnp.float32)
     kv = jnp.zeros((1, slots, 16, 2, 8), jnp.float32)
     tree = {"k": kv, "v": kv, "ssm": state,
             "conv": jnp.zeros((layers, slots, 3, 8), jnp.float32)}
-    decay = jnp.full((slots, h, 1, 1), 0.5, jnp.float32)
+    decay = jnp.full((slots, 1, h * p), 0.5, jnp.float32)
 
     def in_place(st):
         def layer(st, i):
@@ -603,9 +654,11 @@ PARENT_PROGRAMS = {
     # taking an order in segments, the cache tree's shapes asked of the
     # config, and the norms, the bounded read and the dispatch behind
     # helpers, a Granite program computes what it computed, in that order
-    "granite-like decode 4": "7c18f399f995a11a",
-    "granite-like decode 1": "91b9e1163771b27f",
-    "granite-like prefill": "ef68303b068763ff",
+    # (taken anew at PR 62, on purpose: the state is stored [L, slots, n, h p],
+    # the scan turns it once each way, and the update is the S6 kernel's body)
+    "granite-like decode 4": "47628b208c13fd95",
+    "granite-like decode 1": "c903874369a78c17",
+    "granite-like prefill": "92bfc2f343dabda4",
 }
 
 
