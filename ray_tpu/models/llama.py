@@ -531,7 +531,7 @@ def refuse_served_only(cfg: LlamaConfig) -> None:
 
 #: kinds of a patterned config's layers (``models/moe.py``) that the
 #: training blocks compute and no served block does
-TRAINED_ONLY_KINDS = ("kda", "mla")
+TRAINED_ONLY_KINDS = ("kda", "mla", "sparse")
 
 
 def refuse_trained_only(cfg: LlamaConfig) -> None:
@@ -550,7 +550,9 @@ def refuse_trained_only(cfg: LlamaConfig) -> None:
         why.append(
             f"layers of kind {kinds} are trained only (models/moe.py's "
             f"patterned walk): no served block keeps a latent slot cache "
-            f"('mla') or a matrix state a slot with its update ('kda')")
+            f"('mla'), a matrix state a slot with its update ('kda'), or a "
+            f"cache of an indexer's keys with a gather of the positions it "
+            f"picks inside the cached read ('sparse')")
     if getattr(cfg, "hc_mult", 0):
         why.append(
             f"a residual stream of hc_mult={cfg.hc_mult} rows under "
@@ -583,10 +585,18 @@ def remat_block(cfg: LlamaConfig, fn):
     results its backward call reads, so that the backward does not run the
     norm over the rows, ``phi``'s product and the iterations a second time
     (``h`` is 58.7 MB a half layer at Xing4's cell: 13.56 GiB compiled for
-    13.32). An ``eva`` block keeps by name alone (below)."""
+    13.32). Likewise where a learned choice ran inside it
+    (``ops/sparse_index.py``): the choice itself, an int8 mask [b, s, s]
+    that the backward kernels read as the forward did (268 MB a layer at
+    16k: rebuilding it from a kept threshold would run the indexer's scores
+    again, and a recomputed score one rounding off would choose another
+    key), and the indexer's three gradients, which its loss's rule forms in
+    its forward pass, so that the backward walks neither the scores, the
+    threshold nor the loss's blocks again. An ``eva`` block keeps by name
+    alone (below)."""
     if not cfg.remat:
         return fn
-    from ray_tpu.ops import hyper, kda
+    from ray_tpu.ops import hyper, kda, sparse_index
     from ray_tpu.ops.pallas import flash
 
     policies = jax.checkpoint_policies
@@ -610,7 +620,8 @@ def remat_block(cfg: LlamaConfig, fn):
         policies.dots_with_no_batch_dims_saveable,
         policies.save_only_these_names(*flash.RESIDUAL_NAMES,
                                        *kda.RESIDUAL_NAMES,
-                                       *hyper.RESIDUAL_NAMES)))
+                                       *hyper.RESIDUAL_NAMES,
+                                       *sparse_index.RESIDUAL_NAMES)))
 
 
 def forward_hidden(params: Params, tokens: jax.Array, cfg: LlamaConfig,

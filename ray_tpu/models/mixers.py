@@ -1,9 +1,11 @@
-"""Two mixers of the patterned walk (``models/moe.py``) beside llama's
+"""Three mixers of the patterned walk (``models/moe.py``) beside llama's
 attention half, each a pre-norm branch that its caller joins to the residual
 stream (``llama.join``), trained and not served: a delta-rule linear
-attention whose state decays a channel (``"kda"``, Kimi Delta Attention) and
+attention whose state decays a channel (``"kda"``, Kimi Delta Attention),
 latent attention in its uncompressed form (``"mla"``), unrotated or with a
-rotary part.
+rotary part, and grouped-query attention over the positions a learned
+indexer picks (``"sparse"``, DeepSeek Sparse Attention as Keye-VL-2.0 sizes
+it).
 
 A ``kda`` layer, ``h`` the normed input, H heads of width ``dk = dv``:
 
@@ -46,11 +48,30 @@ also multiplies the softmax's scale. Causal softmax at ``(nope + rope) **
 ``ops/pallas/flash.py``); ``wo``. Nothing is absorbed and nothing cached:
 this is the form a training step runs.
 
+A ``sparse`` layer, H heads over Hkv key/value heads of ``head_dim``, an
+indexer of J heads of width e over one key a position: ``q, k, v = h @ wq,
+h @ wk, h @ wv``; an RMSNorm over each head's q and k (gains ``q_norm``,
+``k_norm`` [head_dim]); q and k rotated by sections (``ops/rope.py``:
+frequency pair i takes the position stream of its ``rope_sections``
+section); on ``stop_gradient(h)``: ``qI = h @ index_wq`` [J, e], ``kI =
+LayerNorm(h @ index_wk)`` [e], both rotated whole by the same streams (the
+sections scaled to e / 2 pairs), ``w = h @ index_ww`` [J] float32 times ``J
+** -0.5 * e ** -0.5``; the choice of at most ``index_topk`` keys a query,
+ties aside, and the indexer's loss (``ops/sparse_index.py``); causal
+softmax at ``head_dim ** -0.5`` over the chosen keys alone, the flash
+kernels under the choice as a mask (``ops/pallas/flash.py``'s ``select=``)
+or, ``attn_impl`` "xla", the dense form; ``wo``. The trunk takes its
+gradient through the choice held fixed; the indexer's five leaves take
+theirs from its loss alone.
+
 Scopes are names only. The walk opens ``attn_kda`` / ``attn_mla`` round a
 layer's mixer half; inside ``attn_kda`` lie ``kda_conv``, ``kda_gates`` and
 ``kda_scan`` (the recurrence, forward and backward, and nothing else),
 inside ``attn_mla`` ``mla_latent`` (the down- and up-projections and the
-latent's norm).
+latent's norm). ``sparse_half`` opens its own, none inside another:
+``attn_sparse`` (the projections, head norms, rotation, the kernels and
+``wo``), ``index_scores`` (the indexer's projections and its scores for the
+choice), ``index_select`` (the threshold and the mask) and ``index_loss``.
 """
 
 from __future__ import annotations
@@ -63,10 +84,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import post_norm
-from ray_tpu.ops import kda
+from ray_tpu.ops import kda, sparse_index
 from ray_tpu.ops.attention import mha
-from ray_tpu.ops.norms import rmsnorm
-from ray_tpu.ops.rope import apply_rope, rope_angles
+from ray_tpu.ops.norms import layernorm, rmsnorm
+from ray_tpu.ops.rope import (apply_rope, apply_rope_by_position, rope_angles,
+                              rope_angles_by_sections)
 from ray_tpu.ops.ssm import causal_conv
 
 Params = Dict[str, Any]
@@ -288,3 +310,135 @@ def mla_half(cfg, x: jax.Array, layer: Params, segment_ids,
             f"split across chips has no ring at two head widths")
     return post_norm(cfg, attn.reshape(b, s, h * dv)
                      @ layer["wo"].astype(cdt), layer, "attn_post_norm")
+
+
+# ------------------------------------------------------------------- sparse
+
+def sparse_params(cfg) -> int:
+    """One layer's ``sparse`` leaves (the norm before the branch left out):
+    the four projections and the two head norms, the indexer's three
+    matrices and its LayerNorm's gain and bias."""
+    d, q = cfg.d_model, cfg.n_heads * cfg.head_dim
+    kv = cfg.n_kv_heads * cfg.head_dim
+    j, e = cfg.index_heads, cfg.index_head_dim
+    return (2 * d * q + 2 * d * kv + 2 * cfg.head_dim
+            + d * (j * e + e + j) + 2 * e)
+
+
+def init_sparse(rng: jax.Array, cfg, n: int) -> Params:
+    d, hd, dt = cfg.d_model, cfg.head_dim, cfg.param_dtype
+    q, kv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+    j, e = cfg.index_heads, cfg.index_head_dim
+    ks = jax.random.split(rng, 7)
+    return {
+        "wq": _normal(ks[0], (n, d, q), d, dt),
+        "wk": _normal(ks[1], (n, d, kv), d, dt),
+        "wv": _normal(ks[2], (n, d, kv), d, dt),
+        "wo": _normal(ks[3], (n, q, d), q, dt),
+        "q_norm": jnp.ones((n, hd), dt),
+        "k_norm": jnp.ones((n, hd), dt),
+        "index_wq": _normal(ks[4], (n, d, j * e), d, dt),
+        "index_wk": _normal(ks[5], (n, d, e), d, dt),
+        "index_ww": _normal(ks[6], (n, d, j), d, dt),
+        "index_k_norm": jnp.ones((n, e), dt),
+        "index_k_norm_b": jnp.zeros((n, e), dt),
+    }
+
+
+def sparse_rope_tables(cfg, batch: int, seq: int, positions=None):
+    """((sin, cos) [b, s, head_dim / 2] for the main attention's heads,
+    the same for the indexer's), in the compute dtype, from ``positions``
+    [sections, b, s]: one position stream a ``rope_sections`` entry, every
+    stream ``arange(seq)`` where a batch carries none (text-only rows)."""
+    if positions is None:
+        positions = jnp.broadcast_to(
+            jnp.arange(seq), (len(cfg.rope_sections), batch, seq))
+    return tuple(rope_angles_by_sections(
+        positions, width, cfg.rope_theta, cfg.rope_sections, cfg.compute_dtype)
+        for width in (cfg.head_dim, cfg.index_head_dim))
+
+
+def _indexer_inputs(cfg, hx: jax.Array, layer: Params, tables):
+    """The indexer's (queries [b, s, J, e], keys [b, s, e], weights
+    [b, s, J] float32) of a layer's normed input ``hx``, which is held
+    constant; under ``index_scores``. ``tables``: the indexer's rotary ones."""
+    b, s, _ = hx.shape
+    j, e, cdt = cfg.index_heads, cfg.index_head_dim, cfg.compute_dtype
+    with jax.named_scope("index_scores"):
+        hs = jax.lax.stop_gradient(hx)
+        q_idx = apply_rope_by_position(
+            (hs @ layer["index_wq"].astype(cdt)).reshape(b, s, j, e), *tables)
+        k_idx = apply_rope_by_position(layernorm(
+            hs @ layer["index_wk"].astype(cdt),
+            layer["index_k_norm"].astype(cdt),
+            layer["index_k_norm_b"].astype(cdt),
+            cfg.norm_eps)[:, :, None, :], *tables)[:, :, 0, :]
+        w = (hs @ layer["index_ww"].astype(cdt)).astype(F32) \
+            * (j ** -0.5 * e ** -0.5)
+    return q_idx, k_idx, w
+
+
+def sparse_choice(cfg, x: jax.Array, layer: Params, tables):
+    """What a ``sparse`` layer's indexer picks for the stream ``x`` [b, s,
+    d]: (the choice [b, s, s] int8, the thresholds [b, s] float32), by the
+    functions ``sparse_half`` runs, for a reader that wants a layer's choice
+    beside its output (the tests, the chip check)."""
+    hx = rmsnorm(x, layer["attn_norm"].astype(cfg.compute_dtype), cfg.norm_eps)
+    return sparse_index.choose(*_indexer_inputs(cfg, hx, layer, tables[1]),
+                               cfg.index_topk)[:2]
+
+
+def sparse_half(cfg, x: jax.Array, layer: Params, segment_ids, tables):
+    """Pre-norm attention over the indexer's choice, [b, s, d] -> (the
+    branch [b, s, d], the indexer's loss, ``sparse_index.COUNTERS`` as an
+    int32 [3]); ``tables``: ``sparse_rope_tables``'s. Opens its own scopes
+    (module docstring)."""
+    from ray_tpu.parallel.context import single_chip
+
+    if segment_ids is not None:
+        raise NotImplementedError(
+            "segment_ids (packed sequences) through a sparse layer: the "
+            "indexer would have to choose inside a document")
+    b, s, _ = x.shape
+    h, hkv, hd, cdt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
+    topk = cfg.index_topk
+    main, index = tables
+
+    with jax.named_scope("attn_sparse"):
+        hx = rmsnorm(x, layer["attn_norm"].astype(cdt), cfg.norm_eps)
+        q = rmsnorm((hx @ layer["wq"].astype(cdt)).reshape(b, s, h, hd),
+                    layer["q_norm"].astype(cdt), cfg.norm_eps)
+        k = rmsnorm((hx @ layer["wk"].astype(cdt)).reshape(b, s, hkv, hd),
+                    layer["k_norm"].astype(cdt), cfg.norm_eps)
+        v = (hx @ layer["wv"].astype(cdt)).reshape(b, s, hkv, hd)
+        q, k = apply_rope_by_position(q, *main), apply_rope_by_position(k, *main)
+
+    q_idx, k_idx, w = _indexer_inputs(cfg, hx, layer, index)
+    chosen, _, counted = sparse_index.choose(q_idx, k_idx, w, topk)
+
+    with jax.named_scope("attn_sparse"):
+        if cfg.attn_impl == "flash":
+            if not single_chip():
+                raise NotImplementedError(
+                    "a sparse layer's kernels under a mesh of several chips: "
+                    "the choice [b, s, s] would have to follow q's shards")
+            from ray_tpu.ops.pallas.flash import flash_attention_chosen
+
+            attn, lse = flash_attention_chosen(q, k, v, chosen, topk=topk)
+        elif cfg.attn_impl == "xla":
+            attn, lse = sparse_index.dense_attention(q, k, v, chosen,
+                                                     hd ** -0.5)
+        else:
+            raise NotImplementedError(
+                f"a sparse layer under attn_impl={cfg.attn_impl!r}: a "
+                f"sequence split across chips has no ring under a choice")
+
+    with jax.named_scope("index_loss"):
+        loss = sparse_index.index_loss(
+            q_idx, k_idx, w, jax.lax.stop_gradient(q),
+            jax.lax.stop_gradient(k), lse, chosen, hd ** -0.5)
+
+    with jax.named_scope("attn_sparse"):
+        branch = post_norm(cfg, attn.reshape(b, s, h * hd)
+                           @ layer["wo"].astype(cdt), layer, "attn_post_norm")
+    return branch, loss, counted

@@ -27,12 +27,14 @@ Load-balancing aux loss: ``n_experts * sum_e(fraction_e * prob_e)``.
 
 The patterned form (``MoEConfig.layer_kinds`` set; Trinity's ``afmoe``,
 Kimi Linear): leading dense layers and then expert layers, each layer's
-mixer one of the four ``ATTN_KINDS`` (attention rotated inside a band
+mixer one of the five ``ATTN_KINDS`` (attention rotated inside a band
 (``"window"``) or unrotated and full (``"full"``), both
-``llama.attention_half``; a delta-rule linear attention (``"kda"``) or
-unrotated latent attention (``"mla"``), both ``models/mixers.py``), a sigmoid
-router whose selection adds a bias the gates do not see, a shared expert
-beside the routed ones, and a layer that may hold a share of its experts:
+``llama.attention_half``; a delta-rule linear attention (``"kda"``),
+latent attention (``"mla"``) or grouped-query attention over the positions
+a learned indexer picks (``"sparse"``), all ``models/mixers.py``), a router
+that may be a sigmoid whose selection adds a bias the gates do not see, a
+shared expert beside the routed ones where the config has one, and a layer
+that may hold a share of its experts:
 ``n_experts_held`` of ``n_experts``, the first ones, as one chip of an
 expert-parallel layer does. The router keeps its whole width and its K
 choices, capacity is reckoned from the whole count, the buffers are
@@ -52,7 +54,11 @@ kinds inside a period static and the mixers' leaves stacked by kind, since a
 ``kda`` layer and an ``mla`` layer hold different ones (``_pick``); it also
 counts its routing
 (``ROUTING_COUNTERS``), and a step moves the selection bias by what it
-counted (``buffer_updates``).
+counted (``buffer_updates``). A ``sparse`` layer hands back a loss term
+of its own, the indexer's, and three counts (``INDEX_COUNTERS``): the walk
+sums them over the layers and ``loss_and_stats`` adds the term at
+``index_loss_coef`` times its mean over all layers; a config without the
+kind carries neither.
 """
 
 from __future__ import annotations
@@ -66,7 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixers
-from ray_tpu.ops import hyper
+from ray_tpu.ops import hyper, sparse_index
 from ray_tpu.ops.rope import Yarn
 from ray_tpu.parallel.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
@@ -76,13 +82,20 @@ Params = Dict[str, Any]
 #: a patterned config's kinds of mixer: attention with rotated queries and
 #: keys inside a band of ``sliding_window``, or with no rotation and the
 #: whole past; a delta-rule linear attention with a decay a channel; latent
-#: attention, unrotated, uncompressed, over the whole past
-ATTN_KINDS = ("window", "full", "kda", "mla")
+#: attention, uncompressed, over the whole past; grouped-query attention,
+#: rotated by sections, over the keys a learned indexer picks
+ATTN_KINDS = ("window", "full", "kda", "mla", "sparse")
 #: where a kind's mixer keeps its leaves in a segment's tree: beside the
 #: layer's other leaves (``""``: the two kinds of ``llama.attention_half``
 #: hold alike ones, ``_ATTN_LEAVES``), or in a sub-tree of its own; either
 #: way stacked over the segment's layers of that stack alone
-_STACK = {"window": "", "full": "", "kda": "kda", "mla": "mla"}
+_STACK = {"window": "", "full": "", "kda": "kda", "mla": "mla",
+          "sparse": "sparse"}
+#: a kind's own leaves where it has a stack of its own: (their count a
+#: layer, their initialiser), in the order ``_segment_mixers`` seeds them
+_OWN = {"kda": (mixers.kda_params, mixers.init_kda),
+        "mla": (mixers.mla_params, mixers.init_mla),
+        "sparse": (mixers.sparse_params, mixers.init_sparse)}
 _ATTN_LEAVES = ("wq", "wk", "wv", "wo", "q_norm", "k_norm", "wg")
 
 
@@ -146,6 +159,16 @@ class MoEConfig(llama.LlamaConfig):
     # of the last layer's kind) and the weight of its cross entropy
     n_mtp_modules: int = 0
     mtp_weight: float = 0.3
+    # a ``sparse`` layer's indexer (``ops/sparse_index.py``): its query
+    # heads over one key a position, their width, the keys a query keeps
+    # and the weight of the indexer's loss, meaned over all layers, in the
+    # step's; and the frequency pairs a position stream of its rotation by
+    # sections (a published ``mrope_section``; ``head_dim / 2`` in all)
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_loss_coef: float = 1.0
+    rope_sections: Tuple[int, ...] = ()
 
     def __post_init__(self):
         if self.balance == "first_choice" and self.experts_held != self.n_experts:
@@ -172,6 +195,15 @@ class MoEConfig(llama.LlamaConfig):
                 and self.v_head_dim):
             raise ValueError("an mla layer needs kv_lora_rank, "
                              "qk_nope_head_dim and v_head_dim")
+        if "sparse" in self.layer_kinds:
+            if not (self.index_heads and self.index_head_dim
+                    and self.index_topk and self.rope_sections):
+                raise ValueError("a sparse layer needs index_heads, "
+                                 "index_head_dim, index_topk and rope_sections")
+            if self.n_mtp_modules:
+                raise ValueError(
+                    "a prediction module of kind 'sparse': nothing adds its "
+                    "indexer's loss to the step's")
         if self.mla_yarn is not None and not self.mla_rope:
             raise ValueError("mla_yarn scales a rotary part: set mla_rope")
         if self.n_mtp_modules not in (0, 1):
@@ -227,8 +259,7 @@ class MoEConfig(llama.LlamaConfig):
         """One layer's mixer of ``kind`` with the norms round its branch."""
         if _STACK[kind] == "":
             return self.attn_params()
-        own = (mixers.kda_params if kind == "kda" else mixers.mla_params)(self)
-        return own + (1 + self.sandwich_norm) * self.d_model
+        return _OWN[kind][0](self) + (1 + self.sandwich_norm) * self.d_model
 
     def _params(self, experts: int) -> int:
         d, v = self.d_model, self.vocab_size
@@ -291,8 +322,7 @@ def _segment_mixers(rng: jax.Array, cfg: MoEConfig, layers: Params,
                 layers[name] = layers[name][:n_attn]
             else:
                 del layers[name]
-    for i, (kind, init) in enumerate((("kda", mixers.init_kda),
-                                      ("mla", mixers.init_mla))):
+    for i, (kind, (_, init)) in enumerate(_OWN.items()):
         n = kinds.count(kind)
         if n:
             layers[kind] = init(jax.random.fold_in(rng, 20 + i), cfg, n)
@@ -394,8 +424,9 @@ def buffer_updates(cfg: MoEConfig, params: Params, updates: Params,
                    ) -> Tuple[Params, Dict[str, jax.Array]]:
     """The optimizer's ``updates`` with the buffers' own movement in place of
     what it made of their zero gradient and the decay, and ``stats`` less
-    what that took (``router_load``); both as they came for a config
-    without a selection bias.
+    what that took (``router_load``); for a config without a selection
+    bias the updates as they came and ``stats`` less the loads, which move
+    nothing there.
 
     The bias moves by the balancing rule of the models that select by
     ``score + bias`` (no gradient: the bias is not a weight), in the form
@@ -410,7 +441,9 @@ def buffer_updates(cfg: MoEConfig, params: Params, updates: Params,
     prediction module's layer has a bias of its own, moved alike by what
     that layer counted (``mtp_router_load``)."""
     if not cfg.router_bias:
-        return updates, stats
+        # (the loads move nothing here, and a [L, E] array is no metric)
+        return updates, {name: v for name, v in stats.items()
+                         if name not in ("router_load", "mtp_router_load")}
     stats = dict(stats)
 
     def moved(layers: Params, upd: Params, counted: str) -> Params:
@@ -627,6 +660,10 @@ ROUTING_COUNTERS = ("moe_assignments",      # (token, expert) pairs routed
                     "moe_dropped",          # of those, beyond it
                     "moe_max_expert_rows")  # the busiest held expert's queue
 COUNTER_MAXIMA = ("moe_max_expert_rows",)
+#: what a config with a ``sparse`` layer counts of its choices beside them,
+#: int32 each, summed over the layers (``ops/sparse_index.COUNTERS``: the
+#: causal (query, key) pairs, those chosen, the rows ties kept over ``topk``)
+INDEX_COUNTERS = sparse_index.COUNTERS
 
 
 def routing_counters(cfg: MoEConfig, load: jax.Array, kept: jax.Array
@@ -842,9 +879,12 @@ def _pick(tree: Params, kinds: Tuple[str, ...], j: int) -> Params:
 
 
 def _mixer_half(cfg: MoEConfig, kind: str, h, layer, sin, cos, segment_ids,
-                mla_tables=None):
+                mla_tables=None, sparse_tables=None):
     """The layer's mixer of ``kind``, a pre-norm branch on ``h``, under the
-    scope ``attn_<kind>``."""
+    scope ``attn_<kind>``; a ``sparse`` layer's opens its scopes itself and
+    returns its loss and counts beside the branch (``mixers.sparse_half``)."""
+    if kind == "sparse":
+        return mixers.sparse_half(cfg, h, layer, segment_ids, sparse_tables)
     if kind == "kda" and segment_ids is not None:
         raise NotImplementedError(
             "segment_ids (packed sequences) through a kda layer: the "
@@ -887,23 +927,35 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
     """One layer of a patterned config as ``(x, layer) -> (x, aux, load,
     kept)``, its kind static: the mixer of its kind (``_mixer_half``: the
     attention half rotated inside the band or unrotated and full, the delta
-    rule, latent attention), then a dense SwiGLU (no ``aux``, ``load`` or
+    rule, latent attention, attention over a learned choice), then a dense
+    SwiGLU (no ``aux``, ``load`` or
     ``kept``: None) or the shared expert beside the routed ones, every
     branch normed before and after where the config says so, read from the
     stream by ``_read`` and joined to it by ``_join``. ``load`` [E]
     is the choices each of the ``n_experts`` got, ``kept`` how many took a
-    slot in a held expert's buffer."""
+    slot in a held expert's buffer. Where the config has a ``sparse`` layer
+    anywhere every layer's tuple ends with one more, ``(the indexer's loss,
+    its counts [3])``, zeros from a layer of another kind."""
     cdt = cfg.compute_dtype
+    indexed = "sparse" in cfg.layer_kinds
 
-    def run(x, layer, sin, cos, segment_ids, mla_tables=None):
+    def run(x, layer, sin, cos, segment_ids, mla_tables=None,
+            sparse_tables=None):
         h, mix = _read(cfg, x, layer, "attn")
-        x = _join("attn_" + kind, x, _mixer_half(
-            cfg, kind, h, layer, sin, cos, segment_ids, mla_tables), mix)
+        branch = _mixer_half(cfg, kind, h, layer, sin, cos, segment_ids,
+                             mla_tables, sparse_tables)
+        if kind == "sparse":
+            branch, *own = branch
+        else:
+            own = (jnp.zeros((), jnp.float32),
+                   jnp.zeros((len(INDEX_COUNTERS),), jnp.int32))
+        own = (tuple(own),) if indexed else ()
+        x = _join("attn_" + kind, x, branch, mix)
         h, mix = _read(cfg, x, layer, "mlp")
         if dense:
             with jax.named_scope("mlp"):
                 branch = llama.ffn_half(cfg, h, layer)
-            return _join("mlp", x, branch, mix), None, None, None
+            return (_join("mlp", x, branch, mix), None, None, None, *own)
         with jax.named_scope("moe_router"):
             h = llama.rmsnorm(h, layer["mlp_norm"].astype(cdt), cfg.norm_eps)
         ffn, aux, routing = _moe_ffn(cfg, h, layer)
@@ -918,14 +970,13 @@ def _patterned_layer(cfg: MoEConfig, kind: str, dense: bool):
             kept = routing["keep"].sum(dtype=jnp.int32)
         with jax.named_scope("moe_combine"):
             ffn = llama.post_norm(cfg, ffn, layer, "mlp_post_norm")
-        return _join("moe_combine", x, ffn, mix), aux, load, kept
+        return (_join("moe_combine", x, ffn, mix), aux, load, kept, *own)
 
     return run
 
 
 def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
-          segment_ids, mla_tables=None
-          ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+          segment_ids, mla_tables=None, sparse_tables=None):
     """A patterned config's layers over ``x``: the leading dense layers one
     after another (their kinds need repeat nothing), then a ``lax.scan``
     over the repeats of the expert layers' shortest repeating pattern, the
@@ -933,18 +984,25 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
     (``models/hybrid._walk`` is the served precedent) and each taking its
     leaves from its kind's stack (``_pick``). Every layer is its own remat
     block. Returns (x, the expert layers' summed aux, their
-    ``load`` [L, E] and ``kept`` [L])."""
+    ``load`` [L, E] and ``kept`` [L], and of a config with a ``sparse``
+    layer all layers' summed (indexer's loss, counts [3]), else None)."""
+    indexed = "sparse" in cfg.layer_kinds
+    tables = (mla_tables, sparse_tables) if indexed else (mla_tables,)
+    owns = []  # where indexed: (indexer's loss, counts) a dense layer, a scan
     leading = cfg.layer_kinds[:cfg.n_dense_layers]
     for i, kind in enumerate(leading):
         layer = _pick(params["dense_layers"], leading, i)
         run = _patterned_layer(cfg, kind, dense=True)
-        x = llama.remat_block(cfg, lambda x, layer, run=run: run(
-            x, layer, sin, cos, segment_ids, mla_tables)[0])(x, layer)
+        # (of a dense layer's tuple the stream and, where there is one, the
+        # fifth part)
+        x, *own = llama.remat_block(cfg, lambda x, layer, run=run: run(
+            x, layer, sin, cos, segment_ids, *tables)[::4])(x, layer)
+        owns += own
 
     period = cfg.period()
     runs = [llama.remat_block(cfg, lambda x, layer, run=_patterned_layer(
         cfg, kind, dense=False): run(x, layer, sin, cos, segment_ids,
-                                     mla_tables))
+                                     *tables))
         for kind in period]
 
     def body(carry, layers):
@@ -954,25 +1012,34 @@ def _walk(params: Params, x: jax.Array, cfg: MoEConfig, sin, cos,
             x, a, *count = run(x, _pick(layers, period, j))
             aux = aux + a
             counts.append(count)
-        load, kept = zip(*counts)
+        load, kept, *own = zip(*counts)
         with jax.named_scope("moe_router"):
-            return (x, aux), (jnp.stack(load), jnp.stack(kept))
+            stacked = (jnp.stack(load), jnp.stack(kept))
+        if indexed:  # a period's layers' (loss, counts), summed
+            stacked += (jax.tree.map(lambda *a: sum(a), *own[0]),)
+        return (x, aux), stacked
 
     # every stack [repeats, its layers a period, ...]: a stack holds as many
     # layers as the periods have of its kinds
     repeats = cfg.n_expert_layers // len(period)
     by_period = jax.tree.map(
         lambda a: a.reshape(repeats, -1, *a.shape[1:]), params["layers"])
-    (x, aux), (load, kept) = jax.lax.scan(
+    (x, aux), (load, kept, *own) = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), by_period)
-    return x, aux, load.reshape(-1, cfg.n_experts), kept.reshape(-1)
+    owns += [jax.tree.map(lambda a: a.sum(0), o) for o in own]
+    index = jax.tree.map(lambda *a: sum(a), *owns) if owns else None
+    return x, aux, load.reshape(-1, cfg.n_experts), kept.reshape(-1), index
 
 
-def _trunk(params: Params, tokens: jax.Array, cfg: MoEConfig, segment_ids):
+def _trunk(params: Params, tokens: jax.Array, cfg: MoEConfig, segment_ids,
+           positions=None):
     """tokens [b, s] -> (the stream after the last layer, before the final
     norm, its rows summed where it is widened; the expert layers' summed
     aux; their ``load`` [L, E] and ``kept`` [L], None for the old stack,
-    which counts nothing; an ``mla`` layer's rotary tables)."""
+    which counts nothing; an ``mla`` layer's rotary tables; and, of a config
+    with a ``sparse`` layer alone, a sixth: ``_walk``'s summed (indexer's
+    loss, counts)). ``positions`` [sections, b, s]: the position streams of
+    a rotation by sections, where a batch carries them."""
     if cfg.pipeline_axis is not None:
         raise NotImplementedError(
             "pipeline parallelism for the MoE family is not implemented "
@@ -1006,13 +1073,19 @@ def _trunk(params: Params, tokens: jax.Array, cfg: MoEConfig, segment_ids):
         return x, aux, None, None, None
     with jax.named_scope("attn_mla"):
         mla_tables = mixers.mla_rope_tables(cfg, tokens.shape[1])
+    sparse_tables = None
+    if "sparse" in cfg.layer_kinds:
+        with jax.named_scope("attn_sparse"):
+            sparse_tables = mixers.sparse_rope_tables(cfg, *tokens.shape,
+                                                      positions)
     if cfg.hc_mult:
         x = hyper.widen(x, cfg.hc_mult)
-    x, aux, load, kept = _walk(params, x, cfg, sin, cos, segment_ids,
-                               mla_tables)
+    x, aux, load, kept, index = _walk(params, x, cfg, sin, cos, segment_ids,
+                                      mla_tables, sparse_tables)
     if cfg.hc_mult:
         x = hyper.narrow(x)
-    return x, aux, load, kept, mla_tables
+    return (x, aux, load, kept, mla_tables,
+            *(() if index is None else (index,)))
 
 
 def _mtp(params: Params, cfg: MoEConfig, x: jax.Array, targets: jax.Array,
@@ -1057,19 +1130,27 @@ def _mtp(params: Params, cfg: MoEConfig, x: jax.Array, targets: jax.Array,
 
 
 def forward_hidden(params: Params, tokens: jax.Array, cfg: MoEConfig,
-                   segment_ids=None
+                   segment_ids=None, positions=None
                    ) -> Tuple[jax.Array, jax.Array, jax.Array, Dict[str, Any]]:
     """-> (hidden, head, total_aux_loss, stats). ``stats`` is what the
     patterned form counts of its routing: ``ROUTING_COUNTERS`` by name and
     the layers' ``router_load`` [L, E], which moves the selection bias
     (``buffer_updates``); {} for the old stack, which counts nothing. A
     prediction module is the loss's (``loss_and_stats``) and no part of
-    this."""
-    x, aux, load, kept, _ = _trunk(params, tokens, cfg, segment_ids)
+    this. A config with a ``sparse`` layer also has ``index_loss``, the
+    indexers' loss meaned over all layers (float32, which the loss adds at
+    ``index_loss_coef``), and ``INDEX_COUNTERS`` by name."""
+    x, aux, load, kept, _, *index = _trunk(params, tokens, cfg, segment_ids,
+                                           positions)
     stats = {}
     if cfg.layer_kinds:
         with jax.named_scope("moe_router"):
             stats = {**routing_counters(cfg, load, kept), "router_load": load}
+    if index:
+        (loss, counts), = index
+        with jax.named_scope("index_loss"):
+            stats = {**stats, "index_loss": loss / cfg.n_layers,
+                     **dict(zip(INDEX_COUNTERS, counts))}
     x, head = _final(params, cfg, x)
     return x, head, aux / cfg.n_expert_layers, stats
 
@@ -1108,12 +1189,17 @@ def loss_and_stats(params: Params, batch: Dict[str, jax.Array], cfg: MoEConfig
     if cfg.n_mtp_modules:
         return _loss_with_mtp(params, batch, cfg, inputs, targets)
     x, head, aux, stats = forward_hidden(params, inputs, cfg,
-                                         batch.get("segment_ids"))
+                                         batch.get("segment_ids"),
+                                         batch.get("position_ids"))
     with jax.named_scope("loss_head"):
         place = _head_placement(cfg, targets.shape[1])
         ce = llama.chunked_ce(x, place(head), targets,
                               batch.get("loss_mask"), cfg.loss_chunk, place)
-    return ce + cfg.router_aux_coef * aux, stats
+    loss = ce + cfg.router_aux_coef * aux
+    if "index_loss" in stats:
+        with jax.named_scope("index_loss"):
+            loss = loss + cfg.index_loss_coef * stats["index_loss"]
+    return loss, stats
 
 
 def _loss_with_mtp(params: Params, batch: Dict[str, jax.Array],
@@ -1173,7 +1259,9 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
         # the mixers that lie in a sub-tree of their kind's name: the
         # matrices into the heads like wq, out of them like wo, the narrow
         # ones (a latent, a low rank, a scalar a head) whole on that side
-        (r"layers/(kda|mla)/w[qkv]$", P(None, "fsdp", "tp")),
+        (r"layers/(kda|mla|sparse)/w[qkv]$", P(None, "fsdp", "tp")),
+        # an indexer's three matrices: narrow on the heads' side, whole there
+        (r"layers/sparse/index_w[qkw]$", P(None, "fsdp", None)),
         (r"layers/mla/wq_a$", P(None, "fsdp", None)),
         (r"layers/mla/wq_b$", P(None, None, "tp")),
         # a hyper-connection's leaves: phi's long side like a matrix's model
@@ -1181,7 +1269,7 @@ def sharding_rules(pipeline: bool = False) -> ShardingRules:
         (r"layers/hc_\w+_phi$", P(None, "fsdp", None)),
         (r"layers/hc_\w+_(g|b|alpha)$", P(None)),
         (r"mtp/proj$", P("fsdp", "tp")),
-        (r"layers/(kda|mla)/wo$", P(None, "tp", "fsdp")),
+        (r"layers/(kda|mla|sparse)/wo$", P(None, "tp", "fsdp")),
         (r"layers/(kda/(wb|[fg]_down)|mla/wkv_a)$", P(None, "fsdp", None)),
         (r"layers/(kda/[fg]_up|mla/wkv_b|kda/conv_[qkv])$",
          P(None, None, "tp")),

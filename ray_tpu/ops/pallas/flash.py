@@ -71,6 +71,28 @@ diagonal, from the other side: the walked index is clamped to the first (in
 dkv: last) live block too, and the band's lower edge builds the mask where it
 crosses a block. ``window=None`` is the causal kernel as it was.
 
+The choice. ``select=`` [b, s_q, s_k] int8 is a mask that is data and not
+arithmetic on positions: a learned indexer's pick of the keys a query sees
+(``ops/sparse_index.py``), one for all heads. All three kernels take it as
+one more operand, streamed tile by tile beside K and V: the tile of the
+step's (q block, k block), its batch row that of the step's head, fetched by
+the index the walked operand has (a pair the causal test skips fetches no
+tile of it either); dkv, which works on the transposed tile, reads the
+choice turned once, [b, s_k, s_q], by one XLA transpose a call. A tile's
+int8 is widened to 32 bits and compared with 0, and the mask it gives is
+ANDed with the causal test where the diagonal crosses the pair and applied
+alone where it does not: under a choice no pair is clear, every live one
+runs the masked body (``plan`` counts a tile's 2 + 4 bytes an element).
+Nothing of the square is skipped for the choice's sake: a tile no row chose
+from is walked and masked, so the kernels' work is the causal walk's
+whatever the choice keeps (at s 16,384 and 2,048 keys a query, 23% of the
+pairs: what skipping by a tile's emptiness, or gathering, would be worth is
+``benchmark/kernels/flash_select.py``'s roofline). The forward hands back
+its log-sum-exp beside ``o`` (``flash_attention_chosen``), which the
+indexer's loss rebuilds the softmax's weights from. A call under a choice
+ends its name ``_t<topk>``, the most keys a row chose, ties aside; a call
+without one is named, planned and traced as it was.
+
 Names. A call is named ``flash_<kind>_bh<bh>_q<sq>_k<sk>_d<d>_c<causal>_w<w>``
 (w 0: no band), the true lengths before padding: a device trace shows a
 ``pallas_call`` under its name, and a call's result does not say what of
@@ -192,12 +214,15 @@ def _sub_block(kind: str, block_q: int, block_k: int) -> Tuple[int, int]:
 
 
 def _vmem_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
-                itemsize: int, value_dim: Optional[int] = None) -> int:
+                itemsize: int, value_dim: Optional[int] = None,
+                select: bool = False) -> int:
     """What one grid step holds: each operand and result block twice (the
     pipeline's two buffers), a [rows, 1] float32 block padded to 128 lanes,
     the float32 scratch, the score-sized temporaries of the clear body and
     the masked body's, a sub-tile's. ``value_dim``: the width of ``v``,
-    ``o``, ``do`` and ``dv`` where it is not ``head_dim``."""
+    ``o``, ``do`` and ``dv`` where it is not ``head_dim``. ``select``: the
+    call takes a choice (module docstring), an int8 tile twice and its
+    widening to 32 bits, a score-sized temporary more."""
     d = _round_up(head_dim, _LANES)
     e = _round_up(value_dim or head_dim, _LANES)
     q_blk, k_blk = block_q * d * itemsize, block_k * d * itemsize
@@ -214,8 +239,9 @@ def _vmem_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
                       + 2 * 8 * block_q * 4)
         scratch = block_k * (d + e) * 4
     sub_q, sub_k = _sub_block(kind, block_q, block_k)
+    chosen = block_q * block_k * (2 + 4) if select else 0
     return (blocks + scratch + _SCORE_TEMPS[kind] * block_q * block_k * 4
-            + _MASK_TEMPS * sub_q * sub_k * 4)
+            + _MASK_TEMPS * sub_q * sub_k * 4 + chosen)
 
 
 def _live(q_lo, rows: int, k_lo, keys: int):
@@ -260,13 +286,15 @@ def _check_window(causal: bool, window: Optional[int]) -> None:
 def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
          kind: str, blocks: Optional[Tuple[int, int]] = None,
          window: Optional[int] = None,
-         value_dim: Optional[int] = None) -> Plan:
+         value_dim: Optional[int] = None, select: bool = False) -> Plan:
     """The tiling of one kernel (``kind`` of ``KINDS``) at one shape; pure.
     Explicit ``blocks`` (block_q, block_k) are kept, shrunk to a short
     sequence. ``window`` moves no block's size: it only takes the pairs
     below the band out of ``live_steps``. ``value_dim``: the values' head
     width where it is not ``head_dim`` (None or equal: the plan of one
-    width, as it was)."""
+    width, as it was). ``select``: the call takes a choice, whose tile
+    counts in the working set and which leaves no pair clear: every live
+    step is an edge step."""
     if kind not in KINDS:
         raise ValueError(f"kind {kind!r} is not one of {KINDS}")
     _check_window(causal, window)
@@ -275,7 +303,7 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
     else:
         tq = tk = _TARGET_BLOCK
         # halve the longer side until the tile fits
-        while (_vmem_bytes(kind, tq, tk, head_dim, itemsize, value_dim)
+        while (_vmem_bytes(kind, tq, tk, head_dim, itemsize, value_dim, select)
                > _VMEM_BUDGET_BYTES and max(tq, tk) > _LANES):
             tq, tk = (tq // 2, tk) if tq >= tk else (tq, tk // 2)
         bq, bk = _fit_block(tq, seq_q), _fit_block(tk, seq_k)
@@ -283,36 +311,40 @@ def plan(seq_q: int, seq_k: int, head_dim: int, itemsize: int, causal: bool,
     pairs = [_live_and_clear(i * bq, bq, j * bk, bk, 0, causal=causal,
                              window=window, q_len=seq_q, kv_len=seq_k)
              for i in range(nq) for j in range(nk)]
-    need = _vmem_bytes(kind, bq, bk, head_dim, itemsize, value_dim)
+    need = _vmem_bytes(kind, bq, bk, head_dim, itemsize, value_dim, select)
     return Plan(kind, bq, bk, nq * nk, sum(live for live, _ in pairs),
-                sum(live and not clear for live, clear in pairs),
+                sum(live and not (clear and not select)
+                    for live, clear in pairs),
                 _sub_block(kind, bq, bk), need,
                 max(_VMEM_DEFAULT_LIMIT_BYTES, need))
 
 
 def _planned(kind: str, q, k, v, causal: bool,
              blocks: Optional[Tuple[int, int]],
-             window: Optional[int]) -> Tuple[Plan, str]:
+             window: Optional[int],
+             topk: Optional[int] = None) -> Tuple[Plan, str]:
     """The plan of the kernel about to be built on q, k [bh, s, d] and v
-    [bh, s, dv], noted, and the call's name (module docstring)."""
+    [bh, s, dv], noted, and the call's name (module docstring). ``topk``:
+    the call takes a choice of at most that many keys a query."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     two = dv != d
     p = plan(sq, sk, d, q.dtype.itemsize, causal, kind, blocks, window,
-             dv if two else None)
+             dv if two else None, topk is not None)
     plans.note("flash", {
         **p._asdict(), "seq_q": sq, "seq_k": sk, "head_dim": d,
         "itemsize": q.dtype.itemsize, "causal": causal, "window": window,
-        **({"value_dim": dv} if two else {})})
+        **({"value_dim": dv} if two else {}),
+        **({} if topk is None else {"topk": topk})})
     width = f"d{d}v{dv}" if two else f"d{d}"
     return p, (f"flash_{kind}_bh{bh}_q{sq}_k{sk}_{width}_c{int(causal)}"
-               f"_w{window or 0}")
+               f"_w{window or 0}" + ("" if topk is None else f"_t{topk}"))
 
 
 # ------------------------------------------------------- what a step skips
 
 def _on_live_steps(step, qi, kk, qoff, *, causal, window, block_q, block_k,
-                   sub_block, q_len, kv_len, q_axis):
+                   sub_block, q_len, kv_len, q_axis, chosen=None):
     """Run ``step(rows, keys, mask_of)`` over the (q block, k block) pair,
     ``rows`` and ``keys`` slices of the tile. Not at all if the mask leaves
     the pair nothing; once over the whole tile with ``mask_of`` None if no
@@ -325,7 +357,10 @@ def _on_live_steps(step, qi, kk, qoff, *, causal, window, block_q, block_k,
     static slices (in dkv they index lanes), their keys a loop's: two
     bodies a row of sub-tiles are traced and lowered, not two a sub-tile.
     A tile that is its own sub-tile is masked whole. ``q_axis`` is the axis
-    rows lie on (1 in dkv's transposed tile)."""
+    rows lie on (1 in dkv's transposed tile). ``chosen(rows, keys)``, where
+    the call takes a choice: the tile's part of it as a mask, which every
+    body then applies, the clear one alone and the crossed one beside the
+    positions' own."""
     sub_q, sub_k = sub_block
 
     def run(row0, rows, key0, keys, cut):
@@ -358,12 +393,21 @@ def _on_live_steps(step, qi, kk, qoff, *, causal, window, block_q, block_k,
                 mask = mask & (lse > NEG_INF / 2)
             return mask
 
-        pl.when(live & clear)(lambda: step(*at, None))
+        if chosen is None:
+            on_clear, on_crossed = None, mask_of
+        else:
+            def on_clear(shape, lse=None):
+                return chosen(*at)
+
+            def on_crossed(shape, lse=None):
+                return mask_of(shape, lse) & chosen(*at)
+
+        pl.when(live & clear)(lambda: step(*at, on_clear))
 
         @pl.when(live & jnp.logical_not(clear))
         def _crossed():
             if not cut:
-                step(*at, mask_of)
+                step(*at, on_crossed)
                 return
             for r in range(0, rows, sub_q):
                 pl.loop(0, keys // sub_k)(lambda c: run(
@@ -396,6 +440,30 @@ def _last_live_q(j, qoff, block_q, block_k, nq, window):
     return jnp.minimum(last_row // block_q, nq - 1)
 
 
+def _chosen(sel_ref, q_axis: int):
+    """``_on_live_steps``'s ``chosen`` for a kernel whose choice tile is
+    ``sel_ref`` [1, rows, keys] int8 (dkv's transposed, [1, keys, rows]:
+    ``q_axis`` 1); nothing for a kernel without one."""
+    if sel_ref is None:
+        return {}
+
+    def chosen(rows, keys):
+        at = (rows, keys) if q_axis == 0 else (keys, rows)
+        return sel_ref[(0, *at)].astype(jnp.int32) != 0
+
+    return {"chosen": chosen}
+
+
+def _takes_choice(kernel, n_in: int):
+    """``kernel`` with one more input after its ``n_in`` (the scalar
+    prefetch not counted), the choice's tile, handed on as ``sel_ref``."""
+    def with_choice(qoff_ref, *refs, **static):
+        return kernel(qoff_ref, *refs[:n_in], *refs[n_in + 1:],
+                      sel_ref=refs[n_in], **static)
+
+    return with_choice
+
+
 _NT = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]
 _NN = (((1,), (0,)), ((), ()))  # [m, n] x [n, d] -> [m, d]
 
@@ -409,7 +477,7 @@ def _dot(a, b, dims):
 def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref,  # inputs
                 o_ref, lse_ref,                 # outputs
                 m_scr, l_scr, acc_scr,          # scratch
-                *, scale, **tile):
+                *, scale, sel_ref=None, **tile):
     qi, kk = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -437,7 +505,8 @@ def _fwd_kernel(qoff_ref, q_ref, k_ref, v_ref,  # inputs
         acc_scr[rows] = acc_scr[rows] * alpha + _dot(
             p.astype(v_ref.dtype), v_ref[0, keys], _NN)
 
-    _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=0, **tile)
+    _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=0,
+                   **_chosen(sel_ref, 0), **tile)
 
     @pl.when(kk == nk - 1)
     def _finish():
@@ -456,8 +525,8 @@ def _compiler_params(p: Plan):
 
 def _walks_k(p: Plan, causal: bool, nk: int, window: Optional[int]):
     """Block specs of a (bh, nq, nk) grid: the q-side block of the step,
-    and the k-side block, which past the last live one (and, with a band,
-    before the first) stays where it is."""
+    the k-side block, which past the last live one (and, with a band,
+    before the first) stays where it is, and a choice's tile of the two."""
     def k_index(b, i, j, qoff):
         if causal:
             last = _last_live_k(i, qoff[0], p.block_q, p.block_k, nk)
@@ -468,18 +537,40 @@ def _walks_k(p: Plan, causal: bool, nk: int, window: Optional[int]):
         return b, j, 0
 
     q_index = lambda b, i, j, qoff: (b, i, 0)
+
+    def choice_spec(heads):
+        # a choice [b, sq, sk]: the tile of the step's q block and of the k
+        # block the walk is on (an index that stays on a live block fetches
+        # no tile of the choice either), the batch row of the step's head
+        return pl.BlockSpec(
+            (1, p.block_q, p.block_k),
+            lambda b, i, j, qoff: (b // heads, i, k_index(b, i, j, qoff)[1]))
+
     return (lambda cols: pl.BlockSpec((1, p.block_q, cols), q_index),
-            lambda cols: pl.BlockSpec((1, p.block_k, cols), k_index))
+            lambda cols: pl.BlockSpec((1, p.block_k, cols), k_index),
+            choice_spec)
+
+
+def _pad_choice(select, rows: int, cols: int):
+    """``select`` [b, r, c] padded with zeros (nothing chosen) to whole
+    blocks of ``rows`` x ``cols``."""
+    _, r, c = select.shape
+    pad_r, pad_c = (-r) % rows, (-c) % cols
+    if pad_r or pad_c:
+        select = jnp.pad(select, ((0, 0), (0, pad_r), (0, pad_c)))
+    return select
 
 
 def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
-                    window=None) -> Tuple[jax.Array, jax.Array]:
+                    window=None, select=None, topk=None
+                    ) -> Tuple[jax.Array, jax.Array]:
     """q,k: [bh, s, d], v: [bh, s, dv]; returns (o [bh, sq, dv], lse
     [bh, sq]). Pads to block multiples; padded keys are masked, padded rows
-    cut off."""
+    cut off. ``select`` [b, sq, sk] int8: the choice (module docstring), of
+    at most ``topk`` keys a query."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
-    p, name = _planned("fwd", q, k, v, causal, blocks, window)
+    p, name = _planned("fwd", q, k, v, causal, blocks, window, topk)
     q, k, v = _pad_seq(q, p.block_q), _pad_seq(k, p.block_k), \
         _pad_seq(v, p.block_k)
     nq, nk = q.shape[1] // p.block_q, k.shape[1] // p.block_k
@@ -487,13 +578,18 @@ def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
         _fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=p.block_q, block_k=p.block_k, sub_block=p.sub_block, q_len=sq,
         kv_len=sk)
-    qspec, kspec = _walks_k(p, causal, nk, window)
+    qspec, kspec, choice_spec = _walks_k(p, causal, nk, window)
+    choice, operands = [], (q_offset, q, k, v)
+    if select is not None:
+        kernel = _takes_choice(kernel, 3)
+        choice = [choice_spec(bh // select.shape[0])]
+        operands += (_pad_choice(select, p.block_q, p.block_k),)
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, nk),
-            in_specs=[qspec(d), kspec(d), kspec(dv)],
+            in_specs=[qspec(d), kspec(d), kspec(dv), *choice],
             out_specs=[qspec(dv), qspec(1)],
             scratch_shapes=[
                 pltpu.VMEM((p.block_q, 1), jnp.float32),
@@ -507,14 +603,14 @@ def _flash_fwd_bhsd(q, k, v, q_offset, *, scale, causal, blocks, interpret,
         compiler_params=_compiler_params(p),
         interpret=interpret,
         name=name,
-    )(q_offset, q, k, v)
+    )(*operands)
     return o[:, :sq], lse[:, :sq, 0]
 
 
 # ---------------------------------------------------------------- backward
 
 def _dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, scale, **tile):
+               dq_ref, dq_scr, *, scale, sel_ref=None, **tile):
     qi, kk = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -533,7 +629,8 @@ def _dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta_ref[0, rows])
         dq_scr[rows] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=0, **tile)
+    _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=0,
+                   **_chosen(sel_ref, 0), **tile)
 
     @pl.when(kk == nk - 1)
     def _finish():
@@ -541,7 +638,8 @@ def _dq_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, **tile):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, sel_ref=None,
+                **tile):
     """Works on the TRANSPOSED tile [bk, bq]: keys down the sublanes, rows
     along the lanes, so that every product is in the MXU's own form (none
     contracts a leading axis, which would transpose a [bq, bk] tile a step)
@@ -566,7 +664,8 @@ def _dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ds = p * (dp - delta_ref[0, :, rows])
         dk_scr[keys] += _dot(ds.astype(q.dtype), q, _NN)
 
-    _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=1, **tile)
+    _on_live_steps(step, qi, kk, qoff_ref[0], q_axis=1,
+                   **_chosen(sel_ref, 1), **tile)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -575,10 +674,11 @@ def _dkv_kernel(qoff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
-                    interpret, window=None):
+                    interpret, window=None, select=None, topk=None):
     """q,k: [bh, s, d]; v,o,do: [bh, s, dv]; lse: [bh, sq] -> (dq, dk,
     dv). Each kernel pads to its own blocks: a padded row has ``lse`` =
-    NEG_INF."""
+    NEG_INF. ``select``, ``topk``: as the forward's; dkv, which works on
+    the transposed tile, reads the choice turned once, [b, sk, sq]."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
@@ -592,30 +692,38 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
                 jnp.pad(lse, ((0, 0), (0, rows)), constant_values=NEG_INF),
                 jnp.pad(delta, ((0, 0), (0, rows))))
 
-    p, name = _planned("dq", q, k, v, causal, blocks, window)
+    p, name = _planned("dq", q, k, v, causal, blocks, window, topk)
     qp, kp, vp, dop, lsep, deltap = padded(p)
     nq, nk = qp.shape[1] // p.block_q, kp.shape[1] // p.block_k
-    qspec, kspec = _walks_k(p, causal, nk, window)
+    qspec, kspec, choice_spec = _walks_k(p, causal, nk, window)
+    kernel = functools.partial(_dq_kernel, block_q=p.block_q,
+                               block_k=p.block_k, sub_block=p.sub_block,
+                               **common)
+    choice, chosen = [], ()
+    if select is not None:
+        kernel = _takes_choice(kernel, 6)
+        choice = [choice_spec(bh // select.shape[0])]
+        chosen = (_pad_choice(select, p.block_q, p.block_k),)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_q=p.block_q, block_k=p.block_k,
-                          sub_block=p.sub_block, **common),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nq, nk),
             in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), qspec(1),
-                      qspec(1)],
+                      qspec(1), *choice],
             out_specs=[qspec(d)],
             scratch_shapes=[pltpu.VMEM((p.block_q, d), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(qp.shape, q.dtype)],
         compiler_params=_compiler_params(p),
         interpret=interpret,
         name=name,
-    )(q_offset, qp, kp, vp, dop, lsep[..., None], deltap[..., None])[0]
+    )(q_offset, qp, kp, vp, dop, lsep[..., None], deltap[..., None],
+      *chosen)[0]
 
     # dk/dv: grid walks k blocks outer, q blocks inner; before the first
     # live q block (and, with a band, after the last) the q-side index
     # stays on it
-    p, name = _planned("dkv", q, k, v, causal, blocks, window)
+    p, name = _planned("dkv", q, k, v, causal, blocks, window, topk)
     qp, kp, vp, dop, lsep, deltap = padded(p)
     nq, nk = qp.shape[1] // p.block_q, kp.shape[1] // p.block_k
 
@@ -639,14 +747,24 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
 
     rowspec = pl.BlockSpec((1, 1, p.block_q),
                            lambda b, j, i, qoff: (b, 0, q_index(b, j, i, qoff)))
+    kernel = functools.partial(_dkv_kernel, block_q=p.block_q,
+                               block_k=p.block_k, sub_block=p.sub_block,
+                               **common)
+    choice, chosen = [], ()
+    if select is not None:
+        heads = bh // select.shape[0]
+        kernel = _takes_choice(kernel, 6)
+        choice = [pl.BlockSpec(
+            (1, p.block_k, p.block_q),
+            lambda b, j, i, qoff: (b // heads, j, q_index(b, j, i, qoff)))]
+        chosen = (_pad_choice(select.swapaxes(1, 2), p.block_k, p.block_q),)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=p.block_q, block_k=p.block_k,
-                          sub_block=p.sub_block, **common),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, nk, nq),
             in_specs=[qspec(d), kspec(d), kspec(dv), qspec(dv), rowspec,
-                      rowspec],
+                      rowspec, *choice],
             out_specs=[kspec(d), kspec(dv)],
             scratch_shapes=[pltpu.VMEM((p.block_k, d), jnp.float32),
                             pltpu.VMEM((p.block_k, dv), jnp.float32)]),
@@ -655,7 +773,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, q_offset, *, scale, causal, blocks,
         compiler_params=_compiler_params(p),
         interpret=interpret,
         name=name,
-    )(q_offset, qp, kp, vp, dop, lsep[:, None], deltap[:, None])
+    )(q_offset, qp, kp, vp, dop, lsep[:, None], deltap[:, None], *chosen)
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 
 
@@ -731,11 +849,71 @@ def _flash_core_bwd(scale, causal, blocks, interpret, q_offset, window, res,
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_chosen(q, k, v, select, scale, causal, blocks, interpret, topk):
+    """The kernels under a choice: (o, lse). ``lse`` comes out for a reader
+    that wants the softmax's weights again (``ops/sparse_index.py``) and
+    takes no cotangent; nor does the choice."""
+    return _flash_chosen_fwd(q, k, v, select, scale, causal, blocks,
+                             interpret, topk)[0]
+
+
+def _flash_chosen_fwd(q, k, v, select, scale, causal, blocks, interpret,
+                      topk):
+    o, lse = _flash_fwd_bhsd(q, k, v, _qoff(0), scale=scale, causal=causal,
+                             blocks=blocks, interpret=interpret,
+                             select=select, topk=topk)
+    o, lse = map(checkpoint_name, (o, lse), RESIDUAL_NAMES)
+    return (o, lse), (q, k, v, select, o, lse)
+
+
+def _flash_chosen_bwd(scale, causal, blocks, interpret, topk, res, ct):
+    q, k, v, select, o, lse = res
+    do, _ = ct
+    return (*_flash_bwd_bhsd(q, k, v, o, lse, do, _qoff(0), scale=scale,
+                             causal=causal, blocks=blocks,
+                             interpret=interpret, select=select, topk=topk),
+            None)
+
+
+_flash_chosen.defvjp(_flash_chosen_fwd, _flash_chosen_bwd)
+
+
+def flash_attention_chosen(q: jax.Array, k: jax.Array, v: jax.Array,
+                           select: jax.Array, *, topk: int,
+                           causal: bool = True,
+                           scale: Optional[float] = None,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None
+                           ) -> Tuple[jax.Array, jax.Array]:
+    """``flash_attention(..., select=)`` with the log-sum-exp beside the
+    output: (o [b, s, h, dv], lse [b, h, s] float32, no gradient through
+    it). ``select`` [b, s_q, s_k] int8, one choice for all heads: a query
+    sees the keys whose entry is not 0 (and, ``causal``, that lie at or
+    before it); a row that chose nothing gives ``o`` 0, ``lse`` NEG_INF.
+    ``topk`` is the most keys a row chose, ties aside, which the calls
+    carry in their names (``_t<topk>``) and nothing computes from."""
+    b, sq, hq, d = q.shape
+    if select.shape != (b, sq, k.shape[1]) or select.dtype != jnp.int8:
+        raise ValueError(
+            f"select is {select.dtype}{list(select.shape)}; a choice is "
+            f"int8 [batch, s_q, s_k] = {[b, sq, k.shape[1]]}")
+    scale = scale if scale is not None else d ** -0.5
+    if interpret is None:
+        interpret = _needs_interpret()
+    o, lse = _flash_chosen(*_prep(q, k, v), select, scale, causal,
+                           _explicit(block_q, block_k), interpret, int(topk))
+    return _from_bhsd(o, b), jax.lax.stop_gradient(lse).reshape(b, hq, sq)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     scale: Optional[float] = None,
                     q_offset: int = 0,
                     window: Optional[int] = None,
+                    select: Optional[jax.Array] = None,
+                    topk: Optional[int] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
@@ -747,10 +925,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     to k[0], for decode and ring steps; static here (see
     ``flash_attention_with_lse`` for a traced offset). ``window``: as
     ``mha``'s, a query at ``i`` sees key ``j`` iff ``0 <= i - j < window``
-    (causal only). ``block_q`` / ``block_k``: None lets ``plan`` choose per
-    kernel.
+    (causal only). ``select``, ``topk``: a choice of keys a query, as
+    ``flash_attention_chosen`` takes it (no band and no offset beside it).
+    ``block_q`` / ``block_k``: None lets ``plan`` choose per kernel.
     """
     _check_window(causal, window)
+    if select is not None:
+        if window is not None or q_offset or topk is None:
+            raise ValueError("a choice needs topk and takes neither a "
+                             "window nor a q_offset")
+        return flash_attention_chosen(
+            q, k, v, select, topk=topk, causal=causal, scale=scale,
+            block_q=block_q, block_k=block_k, interpret=interpret)[0]
     b, _, _, d = q.shape
     scale = scale if scale is not None else d ** -0.5
     if interpret is None:

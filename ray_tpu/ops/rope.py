@@ -3,10 +3,11 @@
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 class Yarn(NamedTuple):
@@ -83,7 +84,55 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array,
     else:
         s = sin[positions][:, :, None, :]
         c = cos[positions][:, :, None, :]
+    return _rotate(x, s, c)
+
+
+def _rotate(x: jax.Array, s: jax.Array, c: jax.Array) -> jax.Array:
+    """The pairs of ``x`` [b, s, h, d] turned by ``s``, ``c`` (broadcast
+    against [b, s, h, d / 2])."""
     x1 = x[..., ::2]
     x2 = x[..., 1::2]
     rotated = jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return rotated.reshape(x.shape).astype(x.dtype)
+
+
+def section_pairs(sections: Sequence[int], half: int) -> Tuple[int, ...]:
+    """``sections`` (frequency pairs a position stream, as a published
+    ``mrope_section`` gives them for the main head width) for a head of
+    ``half`` pairs: as they are where they add up to ``half``, else scaled
+    to it (16 | 24 | 24 of 64 is 8 | 12 | 12 of 32)."""
+    total = sum(sections)
+    pairs = tuple(n * half // total for n in sections)
+    if sum(pairs) != half:
+        raise ValueError(f"sections {tuple(sections)} do not scale to "
+                         f"{half} pairs")
+    return pairs
+
+
+def rope_angles_by_sections(positions: jax.Array, head_dim: int,
+                            theta: float, sections: Sequence[int],
+                            dtype=jnp.float32):
+    """(sin, cos) [b, s, head_dim // 2] of a rotation by sections:
+    ``positions`` [n, b, s] holds one position stream a section, and
+    frequency pair ``i`` (``theta ** (-2 i / head_dim)``, as
+    ``rope_angles``') takes the stream of the section it lies in, the
+    first ``sections[0]`` pairs the first stream's and so on. With every
+    stream ``arange(s)`` the tables are ``rope_angles``' to the bit."""
+    half = head_dim // 2
+    pairs = section_pairs(sections, half)
+    if len(pairs) != positions.shape[0]:
+        raise ValueError(f"{positions.shape[0]} position streams for "
+                         f"{len(pairs)} sections")
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                                / head_dim))
+    stream = np.repeat(np.arange(len(pairs)), pairs)               # [half]
+    pos = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., stream]
+    angles = pos * inv_freq
+    return jnp.sin(angles).astype(dtype), jnp.cos(angles).astype(dtype)
+
+
+def apply_rope_by_position(x: jax.Array, sin: jax.Array, cos: jax.Array
+                           ) -> jax.Array:
+    """``apply_rope`` with tables a position of the batch: x [b, s, h, d],
+    sin / cos [b, s, d // 2] (``rope_angles_by_sections``')."""
+    return _rotate(x, sin[:, :, None, :], cos[:, :, None, :])

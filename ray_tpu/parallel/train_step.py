@@ -32,15 +32,17 @@ from ray_tpu.parallel.sharding import ShardingRules
 #: the outermost ``jax.named_scope`` of every device operation a train step
 #: writes, whichever stack it trains (``llama._block``, ``moe._moe_block``,
 #: ``moe._patterned_layer``): the batch's way in, a layer's mixer by its
-#: kind, its dense or routed feed-forward, a widened stream's
-#: hyper-connections, a prediction module (everything of it), the loss, the
-#: update. A device
+#: kind (a sparse layer's indexer under three of its own: its scores, its
+#: threshold and choice, its loss), its dense or routed feed-forward, a
+#: widened stream's hyper-connections, a prediction module (everything of
+#: it), the loss, the update. A device
 #: trace is read by these names (``benchmark/lib/trace.py:scope_of`` keeps
 #: an operation's outermost one, so no scope stands round a scan of layers:
 #: it would swallow every name inside). What carries none is the scans'
 #: own stacking and slicing of their per-layer operands and results.
 STEP_SCOPES = ("embed", "attn_full", "attn_window", "attn_kda", "attn_mla",
-               "attn_eva", "hyper_mix", "mlp", "moe_router", "moe_dispatch",
+               "attn_eva", "attn_sparse", "index_scores", "index_select",
+               "index_loss", "hyper_mix", "mlp", "moe_router", "moe_dispatch",
                "moe_experts", "moe_combine", "moe_shared", "mtp", "loss_head",
                "optimizer")
 

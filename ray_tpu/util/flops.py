@@ -60,8 +60,13 @@ def _attention_madds(cfg, seq: int) -> float:
     products of chunk x width and three of width x width a head; for
     ``eva`` (a dense config's ``attn_kind``) the pairs a query sees, its
     window's causal half and one summary a chunk of every earlier window,
-    and the pooling that makes a summary (a key and a value a position). A
-    prediction module's layer (``n_mtp_modules``) is one more of the last
+    and the pooling that makes a summary (a key and a value a position);
+    for ``sparse`` the main attention over the pairs the indexer CHOSE
+    (``min(t + 1, index_topk)`` a query, the model's work whatever a kernel
+    walks), the indexer's scores over the causal pairs, and the second
+    ``Q K^T`` over the chosen pairs that its loss's target takes, which
+    runs forward alone and so counts a third (a multiply-add here is six
+    operations a step). A prediction module's layer (``n_mtp_modules``) is one more of the last
     layer's kind."""
     kinds = getattr(cfg, "layer_kinds", ()) or (
         getattr(cfg, "attn_kind", "full"),) * cfg.n_layers
@@ -82,6 +87,12 @@ def _attention_madds(cfg, seq: int) -> float:
         if kind == "mla":
             return cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
                                   + cfg.v_head_dim) * seq / 2.0
+        if kind == "sparse":
+            from ray_tpu.ops.sparse_index import chosen_pairs
+
+            chosen = chosen_pairs(seq, cfg.index_topk) / seq
+            return (cfg.n_heads * cfg.head_dim * chosen * (2 + 1 / 3.0)
+                    + cfg.index_heads * cfg.index_head_dim * (seq + 1) / 2.0)
         keys = w - w * w / (2.0 * seq) if kind == "window" else seq / 2.0
         return 2 * keys * cfg.n_heads * cfg.head_dim
 

@@ -27,7 +27,7 @@ LISTED = "flash"
 # the keys ``TrainRecorder.launch_totals()``, and through it the trainer's
 # ``train_launches`` span, carries where they are not empty: what a reader
 # without the worker takes (``benchmark/layer_metrics/eva_tile_waste.py``)
-SPAN_KEYS = ("eva_plan", "hyper_plan")
+SPAN_KEYS = ("eva_plan", "hyper_plan", "sparse_plan")
 
 
 def key(kind: str) -> str:
@@ -85,7 +85,9 @@ def _flash(fp: Dict[str, Any]) -> str:
             + (f", {fp['edge_steps']} of them crossed by an edge, "
                f"sub-tile {fp['sub_block'][0]}x{fp['sub_block'][1]}"
                if fp.get("edge_steps") else "")
-            + (f", window {fp['window']}" if fp.get("window") else ""))
+            + (f", window {fp['window']}" if fp.get("window") else "")
+            + (f", under a choice of {fp['topk']} keys a query"
+               if fp.get("topk") else ""))
 
 
 def _kda(kp: Dict[str, Any]) -> str:
@@ -125,9 +127,34 @@ def _hyper(hp: Dict[str, Any]) -> str:
             + f" ({hp['impl']}; {hp['layout']})")
 
 
+def _sparse(sp: Dict[str, Any]) -> str:
+    return (f"sparse attention: an indexer of {sp['index_heads']} heads of "
+            f"{sp['index_head_dim']} keeps {sp['topk']} keys a query of "
+            f"{sp['seq']}: {sp['pairs_chosen']} of {sp['pairs_live']} causal "
+            f"pairs a row of the batch ("
+            f"{100 * sp['pairs_chosen'] / sp['pairs_live']:.1f}%); scores "
+            f"{sp['block_rows']} rows a block in {sp['spans']} span(s), "
+            f"{sp['block_score_bytes'] / 2**20:.0f} MiB a block, the "
+            f"threshold in {sp['threshold_passes']} passes of "
+            f"{sp['counts_a_pass']} counts, the choice "
+            f"{sp['choice_bytes'] / 2**20:.0f} MiB a layer")
+
+
+def _chosen(routing: Dict[str, Any]) -> Iterator[str]:
+    """What a step counted of its indexers' choices (``summary()``'s
+    ``routing``, where the counters of every kind are folded)."""
+    live = routing.get("index_pairs_live")
+    if live:
+        kept = routing.get("index_pairs_chosen", 0)
+        yield (f"sparse attention: {kept} of {live} causal pairs chosen "
+               f"({100 * kept / live:.2f}%), "
+               f"{routing.get('index_rows_over_k', 0)} rows where ties kept "
+               f"more than the indexer's count")
+
+
 DESCRIBE: Dict[str, Callable[[Dict[str, Any]], str]] = {
     key("flash"): _flash, key("kda"): _kda, key("eva"): _eva,
-    key("hyper"): _hyper}
+    key("hyper"): _hyper, key("sparse"): _sparse}
 
 
 def sentences(summary: Dict[str, Any]) -> Iterator[str]:
@@ -137,3 +164,4 @@ def sentences(summary: Dict[str, Any]) -> Iterator[str]:
         noted = summary.get(k) or []
         for plan in noted if isinstance(noted, list) else [noted]:
             yield describe(plan)
+    yield from _chosen(summary.get("routing") or {})

@@ -301,7 +301,8 @@ def test_window_summary_carves_launches():
         # synthetic recorder traced no flash kernel and compiled no step)
         assert rec.window_summary(0.0, 999.0) == {
             "window_launches": 0, "flash_plans": [], "kda_plan": {},
-            "eva_plan": {}, "hyper_plan": {}, "expert_placement": None,
+            "eva_plan": {}, "hyper_plan": {}, "sparse_plan": {},
+            "expert_placement": None,
             "collectives": {},
             "step_memory": {}, "routing": {}}
         # full summary spans both
@@ -537,7 +538,7 @@ def test_rt_train_stats_prints_each_plan_as_before():
         "block_q": 512, "block_k": 1024, "live_steps": 16, "edge_steps": 0,
         "sub_block": (512, 512), "grid_steps": 32, "window": None})
     assert list(plans.DESCRIBE) == ["flash_plans", "kda_plan", "eva_plan",
-                                    "hyper_plan"]
+                                    "hyper_plan", "sparse_plan"]
     assert [plans.DESCRIBE["flash_plans"](p) for p in four["flash_plans"]] == [
         "flash fwd s8192x8192 d128: tile 1024x1024, 30 of 64 grid steps "
         "live, 12 of them crossed by an edge, sub-tile 512x512, window 4096",
