@@ -21,6 +21,7 @@ digits through two layers.
 """
 
 import dataclasses
+import functools
 import os
 import subprocess
 import sys
@@ -66,21 +67,33 @@ def _cfg(family, impl="flash", seq=128, dtype=jnp.float32, loss_chunk=32):
     return dataclasses.replace(cfg, param_dtype=dtype, compute_dtype=dtype)
 
 
+@functools.cache
 def _params(family):
     """Seeded weights with the norms' offsets, ``phi`` and ``mu`` moved off
-    their small starts, so that none of them is a bystander."""
+    their small starts, so that none of them is a bystander. (Made, as
+    everything below that is not itself under test, as one compiled program:
+    op by op each small operation is a program for the CPU backend to build,
+    and they were half of this file's time, PR 64.)"""
     cfg = _cfg(family)
-    params = family.init_params(jax.random.key(7), cfg)
-    keys = iter(jax.random.split(jax.random.key(8), 8))
-    layers = params["layers"]
-    for name in ("attn_norm", "mlp_norm"):
-        layers[name] = 0.1 * jax.random.normal(next(keys), layers[name].shape)
-    params["final_norm"] = 0.1 * jax.random.normal(next(keys), (64,))
-    for name in ("eva_phi", "eva_mu"):
-        layers[name] = 0.5 * jax.random.normal(next(keys), layers[name].shape)
-    return params
+
+    @jax.jit
+    def make():
+        params = family.init_params(jax.random.key(7), cfg)
+        keys = iter(jax.random.split(jax.random.key(8), 8))
+        layers = params["layers"]
+        for name in ("attn_norm", "mlp_norm"):
+            layers[name] = 0.1 * jax.random.normal(next(keys),
+                                                   layers[name].shape)
+        params["final_norm"] = 0.1 * jax.random.normal(next(keys), (64,))
+        for name in ("eva_phi", "eva_mu"):
+            layers[name] = 0.5 * jax.random.normal(next(keys),
+                                                   layers[name].shape)
+        return params
+
+    return make()
 
 
+@functools.cache
 def _tokens(seq):
     return jax.random.randint(jax.random.key(seq), (2, seq + 1), 0, VOCAB)
 
@@ -97,7 +110,8 @@ def both(family):
             cfg = _cfg(family, impl, seq, loss_chunk=32 if seq == 128 else 0)
             program[impl, seq] = jax.jit(jax.value_and_grad(
                 lambda p: llama.lm_loss(p, batch, cfg)))(params)
-        reference[seq] = family.loss_and_grads(params, batch["tokens"], CFG_FILE)
+        reference[seq] = jax.jit(lambda p, t: family.loss_and_grads(
+            p, t, CFG_FILE))(params, batch["tokens"])
     return params, program, reference
 
 
@@ -107,13 +121,16 @@ def both(family):
 @pytest.mark.parametrize("impl", IMPLS)
 def test_logits_of_all_eight_heads_agree_with_the_reference(family, impl, seq):
     params, tokens = _params(family), _tokens(seq)[:, :-1]
-    got = llama.forward(params, tokens, _cfg(family, impl, seq))
-    want = family.logits(params, tokens, CFG_FILE)
+    cfg = _cfg(family, impl, seq)
+    got = np.asarray(jax.jit(lambda p, t: llama.forward(p, t, cfg))(
+        params, tokens))
+    want = np.asarray(jax.jit(lambda p, t: family.logits(p, t, CFG_FILE))(
+        params, tokens))
     assert got.shape == want.shape == (2, seq, PRED * VOCAB)
-    assert float(jnp.abs(want).max()) > 1.0
+    assert np.abs(want).max() > 1.0
     # per head, so that no head hides behind another's scale
-    per_head = jnp.abs(got - want).reshape(2, seq, PRED, VOCAB).max((0, 1, 3))
-    assert float(per_head.max()) < 2e-5, per_head
+    per_head = np.abs(got - want).reshape(2, seq, PRED, VOCAB).max((0, 1, 3))
+    assert per_head.max() < 2e-5, per_head
 
 
 @pytest.mark.parametrize("seq", SEQS)
@@ -147,11 +164,12 @@ def test_every_gradient_agrees_with_the_reference(both, leaf):
         "/".join(str(k.key) for k in path)
         for path, _ in jax.tree_util.tree_leaves_with_path(params))
     for seq in SEQS:
-        want = at(reference[seq][1])
-        scale = float(jnp.abs(want).max())
+        want = np.asarray(at(reference[seq][1]))
+        scale = float(np.abs(want).max())
         assert scale > 0, (leaf, seq)
         for impl in IMPLS:
-            worst = float(jnp.abs(at(program[impl, seq][1]) - want).max())
+            worst = float(np.abs(
+                np.asarray(at(program[impl, seq][1])) - want).max())
             assert worst < 2e-5 * scale, (leaf, impl, seq, worst, scale)
 
 
@@ -159,8 +177,9 @@ def test_the_cells_own_dtypes_hold_the_loss(family, both):
     """bf16 parameters and operands, the float32 stream, as the cell runs."""
     params, _, reference = both
     cfg = _cfg(family, "flash", 128, dtype=jnp.bfloat16)
-    low = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
-    got = float(llama.lm_loss(low, {"tokens": _tokens(128)}, cfg))
+    got = float(jax.jit(lambda p, t: llama.lm_loss(
+        jax.tree.map(lambda a: a.astype(jnp.bfloat16), p), {"tokens": t},
+        cfg))(params, _tokens(128)))
     assert abs(got - float(reference[128][0])) < 2e-2 * got
 
 
@@ -169,13 +188,16 @@ def test_one_precision_down_is_another_loss(family, both):
     further from itself than the program does by orders."""
     params, _, reference = both
     want = float(reference[128][0])
-    low = float(family.loss(params, _tokens(128), CFG_FILE,
-                            round_to=jnp.float8_e5m2)["loss"])
+    low = float(jax.jit(lambda p, t: family.loss(
+        p, t, CFG_FILE, round_to=jnp.float8_e5m2)["loss"])(
+            params, _tokens(128)))
     assert abs(low - want) > 1e-4 * want   # fifty times the limit above
 
 
 # ---- the mechanism by itself ---------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=range(6),
+                   static_argnames=("seed", "dtype"))
 def _qkv(seq, seed=0, heads=HEADS, width=WIDTH, batch=2, dtype=jnp.float32):
     keys = jax.random.split(jax.random.key(seed), 5)
     q, k, v = (jax.random.normal(key, (batch, seq, heads, width)).astype(dtype)
@@ -185,6 +207,7 @@ def _qkv(seq, seed=0, heads=HEADS, width=WIDTH, batch=2, dtype=jnp.float32):
     return q, k, v, phi, mu
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def _tables(seq, dtype=jnp.float32):
     """(sin, cos) as ``llama._rope_tables`` makes them, and longer than the
     sequence: a reader takes its first ``seq`` rows."""
@@ -195,9 +218,11 @@ def _tables(seq, dtype=jnp.float32):
 def test_the_summaries_against_a_loop(chunk):
     _, k, v, phi, mu = _qkv(64, seed=chunk)
     heads_first = lambda a: a.transpose(0, 2, 1, 3).reshape(2 * HEADS, 64, WIDTH)
-    ks, vs = eva.summaries(heads_first(k), heads_first(v), jnp.tile(phi, (2, 1)),
-                           jnp.tile(mu, (2, 1)), chunk)
+    ks, vs = jax.jit(lambda k, v, phi, mu: eva.summaries(
+        heads_first(k), heads_first(v), jnp.tile(phi, (2, 1)),
+        jnp.tile(mu, (2, 1)), chunk))(k, v, phi, mu)
     assert ks.shape == vs.shape == (2 * HEADS, 64 // chunk, WIDTH)
+    ks, vs = np.asarray(ks), np.asarray(vs)
     k, v, phi, mu = map(np.asarray, (k, v, phi, mu))
     for b in range(2):
         for a in range(HEADS):
@@ -217,15 +242,17 @@ def test_a_query_in_window_0_sees_plain_causal_attention(impl):
     """No summary is visible there, whatever ``phi`` and ``mu`` are."""
     q, k, v, phi, mu = _qkv(128, seed=1)
     sin, cos = _tables(128)
-    out = eva.eva_attention(q, k, v, sin, cos, phi, mu, window=WINDOW,
-                            chunk=CHUNK, impl=impl)
-    plain = mha(apply_rope(q, sin, cos)[:, :WINDOW],
-                apply_rope(k, sin, cos)[:, :WINDOW], v[:, :WINDOW], causal=True)
+    run = jax.jit(lambda phi, mu: eva.eva_attention(
+        q, k, v, sin, cos, phi, mu, window=WINDOW, chunk=CHUNK, impl=impl))
+    out = np.asarray(run(phi, mu))
+    plain = jax.jit(lambda q, k, v: mha(
+        apply_rope(q, sin, cos)[:, :WINDOW],
+        apply_rope(k, sin, cos)[:, :WINDOW], v[:, :WINDOW], causal=True))(
+            q, k, v)
     np.testing.assert_allclose(out[:, :WINDOW], plain, atol=2e-6)
-    other = eva.eva_attention(q, k, v, sin, cos, 3 * phi, mu + 1,
-                              window=WINDOW, chunk=CHUNK, impl=impl)
+    other = np.asarray(run(3 * phi, mu + 1))
     np.testing.assert_array_equal(out[:, :WINDOW], other[:, :WINDOW])
-    assert float(jnp.abs(out[:, WINDOW:] - other[:, WINDOW:]).max()) > 1e-3
+    assert np.abs(out[:, WINDOW:] - other[:, WINDOW:]).max() > 1e-3
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
@@ -238,12 +265,13 @@ def test_a_moved_key_reaches_its_own_window_through_the_exact_part_alone(impl):
     q, k, v, phi, mu = _qkv(128, seed=2)
     sin, cos = _tables(128)
     moved = k.at[:, 37].add(1.0)
-    run = lambda k_: eva.eva_attention(q, k_, v, sin, cos, phi, mu,
-                                       window=WINDOW, chunk=CHUNK, impl=impl)
-    before, after = run(k), run(moved)
+    run = jax.jit(lambda k_: eva.eva_attention(
+        q, k_, v, sin, cos, phi, mu, window=WINDOW, chunk=CHUNK, impl=impl))
+    before, after = np.asarray(run(k)), np.asarray(run(moved))
     np.testing.assert_array_equal(before[:, :37], after[:, :37])
-    assert float(jnp.abs(after[:, 37:64] - before[:, 37:64]).min(1).max()) > 1e-4
+    assert np.abs(after[:, 37:64] - before[:, 37:64]).min(1).max() > 1e-4
 
+    @jax.jit
     def dense(keys_from, summaries_from):
         first = lambda a: a.transpose(0, 2, 1, 3).reshape(2 * HEADS, 128, WIDTH)
         turned = lambda a: first(apply_rope(a, sin, cos))
@@ -257,7 +285,7 @@ def test_a_moved_key_reaches_its_own_window_through_the_exact_part_alone(impl):
                                atol=2e-6)
     np.testing.assert_allclose(after[:, 64:], dense(k, moved)[:, 64:],
                                atol=2e-6)
-    assert float(jnp.abs(after[:, 64:] - before[:, 64:]).max()) > 1e-5
+    assert np.abs(after[:, 64:] - before[:, 64:]).max() > 1e-5
 
 
 def _seen_by_hand(seq, window, chunk):
@@ -339,10 +367,10 @@ def test_a_window_of_several_blocks_is_the_dense_form(monkeypatch,
     weigh = jax.random.normal(jax.random.key(9), q.shape)
 
     def run(impl):
-        return jax.value_and_grad(lambda q, k, v, phi, mu: jnp.sum(
+        return jax.jit(jax.value_and_grad(lambda q, k, v, phi, mu: jnp.sum(
             weigh * eva.eva_attention(q, k, v, sin, cos, phi, mu, window=WINDOW,
                                       chunk=CHUNK, impl=impl)),
-            argnums=range(5))(q, k, v, phi, mu)
+            argnums=range(5)))(q, k, v, phi, mu)
 
     (got, got_grads), (want, want_grads) = run("pallas"), run("xla")
     assert abs(float(got - want)) < 1e-4
@@ -377,7 +405,7 @@ MIX_CASES = [(128, 256), (112, 256), (128, 8), (112, 16)]
 
 def _mix_operands(seq, dtype):
     q, k, v, phi, mu = _qkv(seq, seed=seq, dtype=dtype)
-    flat = lambda a: a.reshape(2, seq, HEADS * WIDTH)
+    flat = lambda a: np.asarray(a).reshape(2, seq, HEADS * WIDTH)
     return (flat(q), flat(k), flat(v), *_tables(seq, dtype), phi, mu)
 
 
@@ -393,8 +421,8 @@ def test_the_pair_makes_the_kernels_operands_as_xla_did(monkeypatch, seq, rows,
     sum of sixteen such keys)."""
     monkeypatch.setattr(eva_mix, "_ROWS", rows)
     args = _mix_operands(seq, dtype)
-    got = eva_mix.mix(*args, WINDOW, CHUNK)
-    want = _xla_mix(*args, WINDOW, CHUNK)
+    got = jax.jit(lambda *a: eva_mix.mix(*a, WINDOW, CHUNK))(*args)
+    want = jax.jit(lambda *a: _xla_mix(*a, WINDOW, CHUNK))(*args)
     padded = -(-seq // WINDOW) * WINDOW
     assert [a.shape for a in got] == [(2 * HEADS, padded, WIDTH)] * 3 + [
         (2 * HEADS, padded // CHUNK, WIDTH)] * 2
@@ -423,17 +451,17 @@ def test_every_cotangent_of_the_pair_against_autodiff_of_xlas_form(
     monkeypatch.setattr(eva_mix, "_ROWS", rows)
     args = _mix_operands(seq, jnp.float32)
     at = ["dq", "dk", "dv", None, None, "dphi", "dmu"].index(leaf)
-    keys = jax.random.split(jax.random.key(11), 5)
 
     def loss(fn, x):
+        keys = jax.random.split(jax.random.key(11), 5)
         outs = fn(*args[:at], x, *args[at + 1:], WINDOW, CHUNK)
         return sum(jnp.sum(jax.random.normal(key, o.shape) * o)
                    for key, o in zip(keys, outs))
 
-    got = jax.grad(lambda x: loss(eva_mix.mix, x))(args[at])
-    want = jax.grad(lambda x: loss(_xla_mix, x))(args[at])
-    assert got.shape == want.shape and float(jnp.abs(want).max()) > 0.1
-    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
+    got = jax.jit(jax.grad(lambda x: loss(eva_mix.mix, x)))(args[at])
+    want = np.asarray(jax.jit(jax.grad(lambda x: loss(_xla_mix, x)))(args[at]))
+    assert got.shape == want.shape and np.abs(want).max() > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(np.abs(want).max()))
 
 
 def test_the_last_windows_summaries_get_no_gradient():

@@ -25,9 +25,10 @@ def test_greedy_decode_matches_full_forward(fp32_cfg):
     toks = generate.generate(params, prompt, cfg, max_new_tokens=10)
     assert toks.shape == (2, 10)
     seq = np.asarray(prompt)
+    # (one program a length: op by op a forward is twenty small ones)
+    forward = jax.jit(lambda p, seq: llama.forward(p, seq, cfg)[:, -1, :])
     for t in range(10):
-        logits = llama.forward(params, jnp.asarray(seq), cfg)
-        expect = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        expect = np.asarray(forward(params, seq)).argmax(-1)
         got = np.asarray(toks[:, t])
         assert (expect == got).all(), f"step {t}: {expect} != {got}"
         seq = np.concatenate([seq, got[:, None]], axis=1)
@@ -40,9 +41,9 @@ def test_gqa_decode(fp32_cfg):
     prompt = jax.random.randint(jax.random.key(1), (1, 5), 0, cfg.vocab_size)
     toks = generate.generate(params, prompt, cfg, max_new_tokens=6)
     seq = np.asarray(prompt)
+    forward = jax.jit(lambda p, seq: llama.forward(p, seq, cfg)[:, -1, :])
     for t in range(6):
-        logits = llama.forward(params, jnp.asarray(seq), cfg)
-        expect = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        expect = np.asarray(forward(params, seq)).argmax(-1)
         assert (expect == np.asarray(toks[:, t])).all()
         seq = np.concatenate([seq, np.asarray(toks[:, t])[:, None]], axis=1)
 

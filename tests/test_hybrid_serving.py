@@ -110,6 +110,16 @@ def _stored(states):
     return states.transpose(0, 3, 1, 2).reshape(layers, n, h * p)
 
 
+@functools.lru_cache(maxsize=None)
+def _reference(family, what):
+    """The family's reference ``what`` (``logits``, ``final_states``) of
+    (params, tokens) as one program a shape: op by op every small operation
+    of it was a program for the CPU backend to build, at every new length
+    again."""
+    return jax.jit(lambda params, tokens: getattr(family, what)(
+        params, tokens, CFG_FILE))
+
+
 def _off(got, ref):
     """Largest difference as a share of the reference logits' scale."""
     return float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
@@ -125,15 +135,15 @@ def test_prefill_logits_match_the_reference(family, f32, served, n):
     """A prompt shorter than a chunk, of exactly one, across a boundary, and
     of one token: every position's logits, and the state handed on."""
     tokens = _tokens(n, n)
-    ref = family.logits(f32[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(f32[1], tokens)
     got, cache = _prefill(f32[1], f32[0], tokens)
     assert _off(got, ref) < EXACT
-    states = _stored(family.final_states(f32[1], tokens[0], CFG_FILE))
+    states = _stored(_reference(family, "final_states")(f32[1], tokens[0]))
     assert cache["ssm"].shape == (3, 1, 16, 8 * 16)
     assert float(jnp.abs(cache["ssm"][:, 0] - states).max()) \
         < EXACT * float(jnp.abs(states).max())
     # the served types, against the reference on the same bf16-valued weights
-    ref = family.logits(served[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(served[1], tokens)
     assert _off(_prefill(served[1], served[0], tokens)[0], ref) < SERVED
 
 
@@ -148,7 +158,7 @@ def test_the_exact_tolerance_refuses(family, f32, what, change):
     Llama block, undone, lies outside it (the softmax scale alone moves the
     logits by ~1e-3 of their scale at this size, 50 tolerances)."""
     tokens = _tokens(2 * CHUNK + 3, 5)
-    ref = family.logits(f32[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(f32[1], tokens)
     wrong = dataclasses.replace(f32[0], **change)
     assert _off(_prefill(f32[1], wrong, tokens)[0], ref) > 10 * EXACT, what
 
@@ -188,7 +198,7 @@ def test_prefill_then_decode_on_the_slot_tree(family, f32, served):
     for (cfg, params), tol in ((f32, EXACT), (served, SERVED)):
         got, _ = _replay(params, cfg, seqs, NEW)
         for seq, mine in zip(seqs, got):
-            ref = family.logits(params, seq[None], CFG_FILE)[0]
+            ref = _reference(family, "logits")(params, seq[None])[0]
             assert _off(mine, ref[len(seq) - NEW - 1:]) < tol
 
 
@@ -199,8 +209,8 @@ def test_a_bf16_state_fails_the_tolerance(family, f32):
     state after them is off by 2e-3 of its scale where the float32 state's
     is off by 1e-6."""
     seq = _tokens(5 + NEW, 11)[0]
-    ref = family.logits(f32[1], seq[None], CFG_FILE)[0][4:]
-    states = _stored(family.final_states(f32[1], seq, CFG_FILE))
+    ref = _reference(family, "logits")(f32[1], seq[None])[0][4:]
+    states = _stored(_reference(family, "final_states")(f32[1], seq))
 
     def state_off(cache):
         return float(jnp.abs(cache["ssm"][:, 0].astype(jnp.float32) - states
@@ -353,7 +363,7 @@ def test_a_reused_slot_gives_a_fresh_engines_logits(family, f32):
             params, second[pos + t][None], cfg, cache, 0,
             jnp.asarray([pos + t], jnp.int32))
         out.append(step[0])
-    ref = family.logits(params, second[None], CFG_FILE)[0][pos - 1:]
+    ref = _reference(family, "logits")(params, second[None])[0][pos - 1:]
     assert _off(jnp.stack(out), ref) < EXACT
 
 

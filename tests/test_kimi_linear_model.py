@@ -85,9 +85,10 @@ def both(family):
         (loss, stats), grads = jax.jit(jax.value_and_grad(
             lambda p: moe.loss_and_stats(p, {"tokens": TOKENS}, cfg),
             has_aux=True))(params)
-    ref = family.loss(params, TOKENS, CFG_FILE)
-    ref_grads = jax.grad(
-        lambda p: family.loss(p, TOKENS, CFG_FILE)["loss"])(params)
+    # and the reference's the same way (PR 64: its backward op by op)
+    ref = jax.jit(lambda p: family.loss(p, TOKENS, CFG_FILE))(params)
+    ref_grads = jax.jit(jax.grad(
+        lambda p: family.loss(p, TOKENS, CFG_FILE)["loss"]))(params)
     return dict(cfg=cfg, params=params, loss=loss, stats=stats, grads=grads,
                 ref=ref, ref_grads=ref_grads)
 
@@ -100,11 +101,13 @@ def test_the_loss_agrees_with_the_reference(both):
 
 
 def test_logits_agree_with_the_reference(family, both):
+    cfg = dataclasses.replace(both["cfg"], capacity_factor=1e3)
     with jax.default_matmul_precision("highest"):
-        got = moe.forward(both["params"], TOKENS[:, :-1], dataclasses.replace(
-            both["cfg"], capacity_factor=1e3))
-    want = family.logits(both["params"], TOKENS[:, :-1], CFG_FILE)
-    assert float(jnp.abs(got - want).max()) < 2e-5 * float(jnp.abs(want).max())
+        got = np.asarray(jax.jit(lambda p: moe.forward(
+            p, TOKENS[:, :-1], cfg))(both["params"]))
+    want = np.asarray(jax.jit(lambda p: family.logits(
+        p, TOKENS[:, :-1], CFG_FILE))(both["params"]))
+    assert np.abs(got - want).max() < 2e-5 * float(np.abs(want).max())
 
 
 def test_every_gradient_agrees_with_the_reference(both):

@@ -161,22 +161,31 @@ SHAPES = {
 def _layer(shape, seed=0):
     c = dataclasses.replace(moe.PRESETS["moe-debug"], n_layers=1,
                             compute_dtype=jnp.float32, **SHAPES[shape])
-    p = moe.init_params(jax.random.key(seed), c)
-    return c, jax.tree.map(lambda x: x[0], p["layers"])
+    return c, jax.jit(lambda: jax.tree.map(
+        lambda x: x[0], moe.init_params(jax.random.key(seed), c)["layers"]))()
 
 
 def _served(c, x, layer):
-    """``served_ffn_half`` on one layer's own weights: a stack of one."""
-    experts = {name: layer[name][None] for name in moe.EXPERT_WEIGHTS}
-    return moe.served_ffn_half(c, x, layer, experts, 0)
+    """``served_ffn_half`` on one layer's own weights: a stack of one. (One
+    program, as the engine runs it and as ``_one_hot_form`` below is: op by
+    op each was a hundred small programs for the CPU backend to build.)"""
+    def run(x, layer):
+        experts = {name: layer[name][None] for name in moe.EXPERT_WEIGHTS}
+        return moe.served_ffn_half(c, x, layer, experts, 0)
+
+    return jax.jit(run)(x, layer)
 
 
 def _one_hot_form(c, x, layer):
     """The capacity dispatch with room for every selection: what the served
     path was before, and still what training runs at a smaller capacity."""
     ample = dataclasses.replace(c, capacity_factor=float(c.n_experts))
-    h = llama.rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-    return x + moe._moe_ffn(ample, h, layer)[0]
+
+    def run(x, layer):
+        h = llama.rmsnorm(x, layer["mlp_norm"], c.norm_eps)
+        return x + moe._moe_ffn(ample, h, layer)[0]
+
+    return jax.jit(run)(x, layer)
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))

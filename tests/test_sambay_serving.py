@@ -125,6 +125,16 @@ def _prefill(params, cfg, tokens, last_only=False):
     return _prefill_of(cfg, last_only)(params, tokens)
 
 
+@functools.lru_cache(maxsize=None)
+def _reference(family, what):
+    """The family's reference ``what`` (``logits``, ``final_states``) of
+    (params, tokens) as one program a shape: op by op every small operation
+    of it was a program for the CPU backend to build, at every new length
+    again."""
+    return jax.jit(lambda params, tokens: getattr(family, what)(
+        params, tokens, CFG_FILE))
+
+
 def _off(got, ref):
     """Largest difference as a share of the reference logits' scale."""
     return float(jnp.abs(got - ref).max() / jnp.abs(ref).max())
@@ -159,13 +169,13 @@ def test_prefill_logits_match_the_reference(family, f32, served, n):
     """A prompt inside the window, of exactly one, past three: every position's
     logits, the state handed on, and what the rings hold."""
     tokens = _tokens(n, n)
-    ref = family.logits(f32[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(f32[1], tokens)
     got, cache = _prefill(f32[1], f32[0], tokens)
     assert _off(got, ref) < EXACT
-    states = family.final_states(f32[1], tokens[0], CFG_FILE)
+    states = _reference(family, "final_states")(f32[1], tokens[0])
     assert float(jnp.abs(cache["ssm"][:, 0] - states).max()) \
         < EXACT * float(jnp.abs(states).max())
-    ref = family.logits(served[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(served[1], tokens)
     assert _off(_prefill(served[1], served[0], tokens)[0], ref) < SERVED
 
 
@@ -194,7 +204,7 @@ def test_the_prefill_that_skips_gives_the_logits_of_one_that_does_not(f32, n):
 ])
 def test_the_exact_tolerance_refuses_another_config(family, f32, what, change):
     tokens = _tokens(3 * W + 5, 5)
-    ref = family.logits(f32[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(f32[1], tokens)
     assert _off(_prefill(f32[1], change(f32[0]), tokens)[0], ref) > 10 * EXACT, what
 
 
@@ -369,7 +379,7 @@ def test_prefill_then_decode_on_the_slot_tree(family, f32, served):
     for (cfg, params), tol in ((f32, EXACT), (served, SERVED)):
         got, _ = _replay(params, cfg, seqs, NEW)
         for seq, mine in zip(seqs, got):
-            ref = family.logits(params, seq[None], CFG_FILE)[0]
+            ref = _reference(family, "logits")(params, seq[None])[0]
             assert _off(mine, ref[len(seq) - NEW - 1:]) < tol
 
 
@@ -405,8 +415,8 @@ def test_a_bf16_state_fails_the_tolerance(family, f32, what, change, least):
     and its state after them is off by 1e-3 of its scale where the float32
     state's is off by 1e-6."""
     seq = _tokens(5 + NEW, 11)[0]
-    ref = family.logits(f32[1], seq[None], CFG_FILE)[0][4:]
-    states = family.final_states(f32[1], seq, CFG_FILE)
+    ref = _reference(family, "logits")(f32[1], seq[None])[0][4:]
+    states = _reference(family, "final_states")(f32[1], seq)
 
     def state_off(cache):
         return float(jnp.abs(cache["ssm"][:, 0].astype(jnp.float32) - states
@@ -423,7 +433,7 @@ def test_a_bf16_softmax_fails_the_tolerance(family, f32, monkeypatch):
     """Why the softmaxes are float32: the same float32 program with the
     attention weights computed from bf16 scores leaves the exact tolerance."""
     tokens = _tokens(3 * W + 5, 9)
-    ref = family.logits(f32[1], tokens, CFG_FILE)
+    ref = _reference(family, "logits")(f32[1], tokens)
     real = jax.nn.softmax
     monkeypatch.setattr(jax.nn, "softmax", lambda x, axis=-1: real(
         x.astype(jnp.bfloat16), axis=axis).astype(x.dtype))

@@ -8,6 +8,7 @@ families the benchmark already trains against the parent's jaxprs; the
 routing counters from the step to the recorder's summary."""
 
 import dataclasses
+import functools
 import hashlib
 import os
 import re
@@ -56,11 +57,20 @@ def _cfg(family, attn_impl="flash", cfg_file=CFG_FILE, depth=DEPTH):
                                compute_dtype=jnp.float32)
 
 
+@functools.cache
+def _params_program(family, cfg, seed):
+    def make():
+        params = family.init_params(jax.random.key(seed), cfg)
+        # a bias as large as the scores' spread: it decides selections
+        params["layers"]["router_bias"] = params["layers"]["router_bias"] * 20
+        return params
+
+    return jax.jit(make)
+
+
 def _params(family, cfg, seed=3):
-    params = family.init_params(jax.random.key(seed), cfg)
-    # a bias as large as the scores' spread: it decides selections
-    params["layers"]["router_bias"] = params["layers"]["router_bias"] * 20
-    return params
+    """A fresh tree a call (a step donates its arguments), one program."""
+    return _params_program(family, cfg, seed)()
 
 
 TOKENS = jax.random.randint(jax.random.key(1), (2, SEQ + 1), 0, 96)
@@ -112,8 +122,11 @@ def both(family):
 
     from benchmark.lib import reference as ref
 
-    hidden, _ = family.hidden(params, TOKENS[:, :-1], CFG_FILE, 1.25)
-    want = family.loss(params, TOKENS, CFG_FILE)
+    # the reference's three as one program each (op by op its backward alone
+    # was hundreds of programs for the CPU backend to build, PR 64)
+    ref_logits = jax.jit(lambda p: ref._project(family.hidden(
+        p, TOKENS[:, :-1], CFG_FILE, 1.25)[0], p["lm_head"]))(params)
+    want = jax.jit(lambda p: family.loss(p, TOKENS, CFG_FILE))(params)
 
     def ref_loss(p):
         with jax.default_matmul_precision("highest"):
@@ -121,18 +134,17 @@ def both(family):
 
     return {"cfg": cfg, "params": params, "loss": loss, "grads": grads,
             "stats": {k: int(v) for k, v in stats.items() if not v.ndim},
-            "logits": logits,
-            "ref_logits": ref._project(hidden, params["lm_head"]),
+            "logits": logits, "ref_logits": ref_logits,
             "ref": {k: float(v) for k, v in want.items()},
-            "ref_grads": jax.grad(ref_loss)(params)}
+            "ref_grads": jax.jit(jax.grad(ref_loss))(params)}
 
 
 def test_logits_agree_with_the_reference(both):
     # float32 on both sides: what is left is the order of the sums
-    scale = float(jnp.abs(both["ref_logits"]).max())
+    got, want = np.asarray(both["logits"]), np.asarray(both["ref_logits"])
+    scale = float(np.abs(want).max())
     assert scale > 1.0
-    assert float(jnp.abs(both["logits"] - both["ref_logits"]).max()) \
-        < 1e-4 * scale
+    assert np.abs(got - want).max() < 1e-4 * scale
 
 
 def test_the_loss_agrees_with_the_reference(both):
@@ -150,13 +162,14 @@ def test_every_gradient_agrees_with_the_reference(both):
     assert got.keys() == want.keys() and len(got) == 37
     for path, g in got.items():
         name = jax.tree_util.keystr(path)
-        scale = float(jnp.abs(want[path]).max())
+        g, w = np.asarray(g), np.asarray(want[path])
+        scale = float(np.abs(w).max())
         if "router_bias" in name:
             # the bias chooses and is no weight: nothing flows into it
-            assert scale == 0.0 and float(jnp.abs(g).max()) == 0.0
+            assert scale == 0.0 and not g.any()
             continue
         assert scale > 1e-4, name
-        assert float(jnp.abs(g - want[path]).max()) < 1e-4 * scale, name
+        assert np.abs(g - w).max() < 1e-4 * scale, name
 
 
 def test_the_steps_counters_count_the_routing(both):
@@ -178,15 +191,16 @@ def test_the_bias_decides_selections_and_the_band_is_seen(family, both):
     params = both["params"]
     flat = {**params, "layers": {**params["layers"], "router_bias":
                                  jnp.zeros_like(params["layers"]["router_bias"])}}
-    assert abs(float(family.loss(flat, TOKENS, CFG_FILE)["loss"])
-               - both["ref"]["loss"]) > 1e-3
+    assert abs(float(jax.jit(lambda p: family.loss(
+        p, TOKENS, CFG_FILE)["loss"])(flat)) - both["ref"]["loss"]) > 1e-3
     from benchmark.lib import reference as ref
 
-    hidden, _ = family.hidden(params, TOKENS[:, :-1], CFG_FILE, 1.25,
-                              window=False)
-    unbanded = ref._project(hidden, params["lm_head"])
+    unbanded = jax.jit(lambda p: ref._project(family.hidden(
+        p, TOKENS[:, :-1], CFG_FILE, 1.25, window=False)[0], p["lm_head"]))(
+            params)
     late = slice(TINY["sliding_window"], None)  # rows with a past below the band
-    assert float(jnp.abs(unbanded - both["ref_logits"])[:, late].max()) > 1e-2
+    assert np.abs(np.asarray(unbanded) - np.asarray(both["ref_logits"]))[
+        :, late].max() > 1e-2
 
 
 def test_the_xla_path_computes_the_same_step(family, both):
@@ -357,14 +371,33 @@ def _bias_step(load, m):
     return m, m
 
 
-def test_a_step_moves_the_bias_by_the_load_alone(family):
+@pytest.fixture(scope="module")
+def launched(family):
+    """One ``StepDriver`` for the two tests below that need its programs and
+    are not about the optimizer, (config, optimizer, driver): four steps in
+    two launches through ``run`` first (a launch's trace is what notes the
+    kernels' plans in the recorder), then its single step and its fused
+    launch of two for who calls them bare. The two tests built the fused
+    program once each."""
+    from ray_tpu.train.driver import StepDriver
+
+    cfg = _cfg(family)
+    opt = ts.default_optimizer(lr=1e-2, warmup_steps=1, total_steps=10)
+    params = _params(family, cfg)
+    driver = StepDriver(cfg, opt, steps_per_launch=2)
+    batches = [{"tokens": np.asarray(TOKENS)} for _ in range(4)]
+    driver.run(params, jax.jit(opt.init)(params), batches)
+    yield cfg, opt, driver
+    driver.recorder.close()
+
+
+def test_a_step_moves_the_bias_by_the_load_alone(family, launched):
     """The selection bias takes no gradient and no decay: a step moves it
     by the balancing rule from what the step's routers chose, toward the
     experts under their share, held here or not; the router and every
     weight move by the optimizer."""
-    cfg = _cfg(family)
+    cfg, opt, driver = launched
     params = _params(family, cfg)
-    opt = ts.default_optimizer(lr=1e-2, warmup_steps=1, total_steps=10)
     before = jax.tree.map(np.asarray, params["layers"])
     _, stats = jax.jit(lambda p: moe.loss_and_stats(
         p, {"tokens": TOKENS}, cfg))(params)
@@ -373,8 +406,7 @@ def test_a_step_moves_the_bias_by_the_load_alone(family):
     assert int(stats["moe_held"]) == load[:, :4].sum()
     assert int(stats["moe_max_expert_rows"]) == load[:, :4].max()
 
-    single = ts.make_train_step(cfg, opt)
-    after, _, one = single(_params(family, cfg), opt.init(params),
+    after, _, one = driver._single(_params(family, cfg), opt.init(params),
                            {"tokens": TOKENS})
     assert set(one) == {"loss", "grad_norm", *moe.ROUTING_COUNTERS}
     move, m = _bias_step(load, before["router_bias_m"])
@@ -389,8 +421,7 @@ def test_a_step_moves_the_bias_by_the_load_alone(family):
     assert np.abs(moved.mean(-1)).max() < 1e-8
 
     # the fused launch: the second step starts from the first's momentum
-    fused = ts.make_multi_step(cfg, opt, 2)
-    after2, _, metrics = fused(_params(family, cfg), opt.init(params),
+    after2, _, metrics = driver._multi(_params(family, cfg), opt.init(params),
                                {"tokens": jnp.stack([TOKENS, TOKENS])})
     assert set(metrics) == {"loss", "grad_norm", *moe.ROUTING_COUNTERS}
     assert all(metrics[k].shape == (2,) for k in metrics)
@@ -441,47 +472,37 @@ def test_first_choice_balance_is_refused_with_a_share_held(family, bad):
         dataclasses.replace(_cfg(family), **bad)
 
 
-def test_the_recorder_carries_the_routing_and_the_window(family):
-    from ray_tpu.train.driver import StepDriver
-
-    cfg = _cfg(family)
-    opt = ts.default_optimizer(total_steps=100)
-    params = _params(family, cfg)
-    driver = StepDriver(cfg, opt, steps_per_launch=2)
-    batches = [{"tokens": np.asarray(TOKENS)} for _ in range(4)]
-    driver.run(params, jax.jit(opt.init)(params), batches)
+def test_the_recorder_carries_the_routing_and_the_window(launched):
+    cfg, _, driver = launched
     rec = driver.recorder
-    try:
-        deadline = time.time() + 30  # the watcher closes a record behind it
-        while time.time() < deadline and rec.summary()["in_flight"]:
-            time.sleep(0.01)
-        summ = rec.summary()
-        per_step = cfg.n_expert_layers * 2 * SEQ * cfg.top_k
-        routing = summ["routing"]
-        assert routing["moe_assignments"] == 4 * per_step
-        assert routing["moe_kept"] + routing["moe_dropped"] == routing["moe_held"]
-        assert 0 < routing["moe_max_expert_rows"] <= 2 * SEQ
-        assert rec.window_summary(0.0, 1e18)["routing"] == routing
-        launches = [r for r in rec.launches() if "counters" in r]
-        assert len(launches) == 2
-        assert launches[0]["counters"]["moe_assignments"] == 2 * per_step
-        totals = rec.launch_totals()
-        assert totals["launches"] == 2 and totals["steps"] == 4
-        assert totals["moe_held"] == routing["moe_held"]
-        assert [c["moe_assignments"] for c in totals["per_launch"]] == [
-            2 * per_step] * 2
-        assert totals["t0"] < totals["t1"]
-        # window layers and full ones plan their kernels apart
-        plans = {(p["kind"], p["window"]) for p in summ["flash_plans"]}
-        assert plans == {(k, w) for k in ("fwd", "dq", "dkv") for w in (8, None)}
-        # one tile a call at this length, crossed by the diagonal (and the
-        # band), and too short to be cut: the tile is its own sub-tile
-        for p in summ["flash_plans"]:
-            assert (p["grid_steps"], p["live_steps"], p["edge_steps"]) == (
-                1, 1, 1), p
-            assert p["sub_block"] == (p["block_q"], p["block_k"]), p
-    finally:
-        rec.close()
+    deadline = time.time() + 30  # the watcher closes a record behind it
+    while time.time() < deadline and rec.summary()["in_flight"]:
+        time.sleep(0.01)
+    summ = rec.summary()
+    per_step = cfg.n_expert_layers * 2 * SEQ * cfg.top_k
+    routing = summ["routing"]
+    assert routing["moe_assignments"] == 4 * per_step
+    assert routing["moe_kept"] + routing["moe_dropped"] == routing["moe_held"]
+    assert 0 < routing["moe_max_expert_rows"] <= 2 * SEQ
+    assert rec.window_summary(0.0, 1e18)["routing"] == routing
+    launches = [r for r in rec.launches() if "counters" in r]
+    assert len(launches) == 2
+    assert launches[0]["counters"]["moe_assignments"] == 2 * per_step
+    totals = rec.launch_totals()
+    assert totals["launches"] == 2 and totals["steps"] == 4
+    assert totals["moe_held"] == routing["moe_held"]
+    assert [c["moe_assignments"] for c in totals["per_launch"]] == [
+        2 * per_step] * 2
+    assert totals["t0"] < totals["t1"]
+    # window layers and full ones plan their kernels apart
+    plans = {(p["kind"], p["window"]) for p in summ["flash_plans"]}
+    assert plans == {(k, w) for k in ("fwd", "dq", "dkv") for w in (8, None)}
+    # one tile a call at this length, crossed by the diagonal (and the
+    # band), and too short to be cut: the tile is its own sub-tile
+    for p in summ["flash_plans"]:
+        assert (p["grid_steps"], p["live_steps"], p["edge_steps"]) == (
+            1, 1, 1), p
+        assert p["sub_block"] == (p["block_q"], p["block_k"]), p
 
 
 def test_a_dense_steps_record_has_no_routing():

@@ -134,9 +134,13 @@ def _filled_cache(cfg, salt):
             for name, buf in G.init_cache(cfg, SLOTS, MAX_LEN).items()}
 
 
-def _step(params, cfg, cache, tok, slot0, pos):
-    return jax.jit(lambda c, t, p: G.decode_step_on_slots(
-        params, t, cfg, c, slot0, p)[:2])(cache, tok, pos)
+def _stepper(params, cfg):
+    """(cache, tokens, first slot, positions) -> (logits, cache) as a
+    program of its own, traced at the chunk that stands at its first call;
+    the first slot is an argument, as it is the engine's, so the five lone
+    rows are one program a chunk and not five."""
+    return jax.jit(lambda c, t, s, p: G.decode_step_on_slots(
+        params, t, cfg, c, s, p)[:2])
 
 
 @pytest.mark.parametrize("bucket", ["lone-row", "full"])
@@ -144,22 +148,28 @@ def _step(params, cfg, cache, tok, slot0, pos):
 def test_bounded_step_equals_the_whole_row_step(family, bucket, chunk):
     cfg, params = FAMILIES[family](MAX_LEN)
     cache = _filled_cache(cfg, 3)
-    tok = jnp.asarray([7, 11, 13, 17, 19], jnp.int32)
-    pos = jnp.asarray(POSITIONS, jnp.int32)
+    tok = np.asarray([7, 11, 13, 17, 19], np.int32)
+    pos = np.asarray(POSITIONS, np.int32)
     launches = ([(0, slice(0, SLOTS))] if bucket == "full"
                 else [(i, slice(i, i + 1)) for i in range(SLOTS)])
-    for slot0, rows in launches:
-        chunk(MAX_LEN)  # one branch: every allocated position, as before
-        whole, whole_cache = _step(params, cfg, cache, tok[rows], slot0,
-                                   pos[rows])
-        chunk(EDGE)
-        assert len(G.kv_read_bounds(MAX_LEN)) == 5
-        got, got_cache = _step(params, cfg, cache, tok[rows], slot0, pos[rows])
+
+    def run():
+        step = _stepper(params, cfg)
+        return [step(cache, tok[rows], jnp.int32(slot0), pos[rows])
+                for slot0, rows in launches]
+
+    chunk(MAX_LEN)  # one branch: every allocated position, as before
+    wholes = run()
+    chunk(EDGE)
+    assert len(G.kv_read_bounds(MAX_LEN)) == 5
+    for (_, rows), (whole, whole_cache), (got, got_cache) in zip(
+            launches, wholes, run()):
+        whole, got = np.asarray(whole), np.asarray(got)
         # (a row past its end attends to nothing it wrote: nobody reads it)
-        live = np.asarray(pos[rows]) < MAX_LEN
-        off = np.abs(np.asarray(got - whole))[live]
-        assert off.max(initial=0.0) < 2e-6 * float(jnp.abs(whole).max())
-        assert (jnp.argmax(got, -1) == jnp.argmax(whole, -1))[live].all()
+        live = pos[rows] < MAX_LEN
+        off = np.abs(got - whole)[live]
+        assert off.max(initial=0.0) < 2e-6 * float(np.abs(whole).max())
+        assert (got.argmax(-1) == whole.argmax(-1))[live].all()
         for name in got_cache:  # what a step writes is what it wrote before
             np.testing.assert_allclose(got_cache[name], whole_cache[name],
                                        rtol=0, atol=1e-5)

@@ -1,7 +1,8 @@
 """What keeps one slow test from costing others theirs (PR 61): a compiled
 fixture is built in a set-up and never inside a test's call, a test past its
 deadline fails alone, and the session's leak guard reaps only what its own
-worker started."""
+worker started. And the one seat that decides the test process's compiler
+(PR 64): eight CPU devices, compiled the cheap way."""
 
 import importlib.util
 import os
@@ -28,6 +29,47 @@ def _load(path, name):
 def guard():
     """``tests/conftest.py`` as a plain module (not registered again)."""
     return _load(os.path.join(_ROOT, "tests", "conftest.py"), "_rt_guard")
+
+
+def test_the_test_process_compiles_for_eight_cpu_devices_the_cheap_way(
+        monkeypatch):
+    """``rt_test_platform.py`` is the seat: this process (an xdist worker
+    inherits the re-exec's environment) has the device count and the CPU
+    compiler's two cheap-mode flags in ``XLA_FLAGS`` once each, a process
+    that has the platform and the count but not the flags is sent round
+    again with what it lacks and nothing twice, and a run on the real chip
+    (``RT_TESTS_KEEP_PLATFORM=1``) is left as it came."""
+    import rt_test_platform as seat
+
+    if os.environ.get("RT_TESTS_KEEP_PLATFORM"):
+        pytest.skip("a run on the real chip: the seat left it as it came")
+    flags = os.environ["XLA_FLAGS"].split()
+    for flag in seat._CPU_XLA_FLAGS:
+        assert flags.count(flag) == 1, (flag, flags)
+    assert os.environ["JAX_NUM_CPU_DEVICES"] == "8"
+    import jax
+
+    assert jax.device_count() == 8 and jax.default_backend() == "cpu"
+
+    sent = []
+    monkeypatch.setattr(os, "execve", lambda *call: sent.append(call))
+    seat._reexec_on_cpu()  # this process has all of it: let through
+    assert not sent
+    monkeypatch.setenv("XLA_FLAGS", " ".join(
+        [f for f in flags if f != seat._CPU_XLA_FLAGS[-1]] + ["--mine=1"]))
+    seat._reexec_on_cpu()
+    (_, argv, env), = sent
+    assert argv[:3] == [sys.executable, "-m", "pytest"]
+    assert sorted(env["XLA_FLAGS"].split()) == sorted(flags + ["--mine=1"])
+    assert env["JAX_PLATFORMS"] == "cpu"
+
+    del sent[:]
+    for name in ("XLA_FLAGS", "JAX_PLATFORMS", "JAX_NUM_CPU_DEVICES"):
+        monkeypatch.delenv(name)
+    monkeypatch.setenv("RT_TESTS_KEEP_PLATFORM", "1")
+    before = dict(os.environ)
+    seat._reexec_on_cpu()
+    assert not sent and dict(os.environ) == before
 
 
 def test_no_test_builds_a_module_fixture_inside_its_call():
