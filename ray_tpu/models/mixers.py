@@ -62,7 +62,13 @@ softmax at ``head_dim ** -0.5`` over the chosen keys alone, the flash
 kernels under the choice as a mask (``ops/pallas/flash.py``'s ``select=``)
 or, ``attn_impl`` "xla", the dense form; ``wo``. The trunk takes its
 gradient through the choice held fixed; the indexer's five leaves take
-theirs from its loss alone.
+theirs from its loss alone. The loss rebuilds its target, the attention's
+weights summed over the heads, a block of rows at a time from ``q``, ``k``
+and the kernel's log-sum-exp: under ``attn_impl`` "flash" on one chip, heads
+of whole lanes and whole tiles of rows and keys as one Pallas call a block
+(``ops/pallas/index_target.py``: the logits stay in VMEM), else in XLA
+(``sparse_index.kernel_tile`` is the rule, ``sparse_plan`` says which); the
+indexer's scores, their vjp and the KL are XLA either way.
 
 Scopes are names only. The walk opens ``attn_kda`` / ``attn_mla`` round a
 layer's mixer half; inside ``attn_kda`` lie ``kda_conv``, ``kda_gates`` and
@@ -436,7 +442,8 @@ def sparse_half(cfg, x: jax.Array, layer: Params, segment_ids, tables):
     with jax.named_scope("index_loss"):
         loss = sparse_index.index_loss(
             q_idx, k_idx, w, jax.lax.stop_gradient(q),
-            jax.lax.stop_gradient(k), lse, chosen, hd ** -0.5)
+            jax.lax.stop_gradient(k), lse, chosen, hd ** -0.5,
+            cfg.attn_impl)
 
     with jax.named_scope("attn_sparse"):
         branch = post_norm(cfg, attn.reshape(b, s, h * hd)

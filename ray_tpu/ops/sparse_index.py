@@ -28,15 +28,20 @@ scale ``J ** -0.5 * e ** -0.5`` folded in by the caller):
   them by name (``RESIDUAL_NAMES``) and its backward only scales them: a
   remat block that keeps those names walks the blocks once a step.
 
-Everything here is XLA. Scopes are the caller's (``models/mixers.py``:
-``index_scores``, ``index_select``, ``index_loss``); ``choose`` opens the
-first two itself, round the parts of a block's body.
+Everything here is XLA but the loss's target: where the caller's kernels run
+(``kernel_tile``) a block's ``p`` before its normalisation is one Pallas
+call, ``ops/pallas/index_target.py``, whose [heads, rows, keys] logits stay
+in VMEM; elsewhere the same lines in XLA (``block_target``), which writes
+them to HBM once a span's keys are many. Scopes are
+the caller's (``models/mixers.py``: ``index_scores``, ``index_select``,
+``index_loss``); ``choose`` opens the first two itself, round the parts of a
+block's body.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,10 +73,11 @@ RESIDUAL_NAMES = ("sparse_choice", "index_grad_q", "index_grad_k",
 COUNTERS = ("index_pairs_live", "index_pairs_chosen", "index_rows_over_k")
 
 
-def plan(seq: int, heads: int, head_dim: int, topk: int, batch: int = 1
-         ) -> Dict[str, int]:
+def plan(seq: int, heads: int, head_dim: int, topk: int, batch: int = 1,
+         target_tile: Optional[int] = None) -> Dict[str, Any]:
     """What ``choose`` and ``index_loss`` do at one shape, noted as
-    ``sparse_plan``; pure."""
+    ``sparse_plan``; pure. ``target_tile``: the keys a grid step of the
+    target's kernel (``kernel_tile``), None for XLA's form."""
     rows = _block_rows(seq)
     return {"seq": seq, "index_heads": heads, "index_head_dim": head_dim,
             "topk": topk, "block_rows": rows, "blocks": seq // rows,
@@ -81,7 +87,30 @@ def plan(seq: int, heads: int, head_dim: int, topk: int, batch: int = 1
             "block_score_bytes": 4 * batch * heads * rows * seq,
             "choice_bytes": batch * seq * seq,
             "pairs_live": seq * (seq + 1) // 2,
-            "pairs_chosen": chosen_pairs(seq, topk)}
+            "pairs_chosen": chosen_pairs(seq, topk),
+            **target_plan(target_tile)}
+
+
+def target_plan(tile: Optional[int]) -> Dict[str, Any]:
+    """``plan``'s two keys that ``index_loss`` decides, and notes."""
+    return {"target_impl": "pallas" if tile else "xla", "target_tile": tile}
+
+
+def kernel_tile(impl: str, seq: int, heads: int, kv_heads: int, d: int,
+                itemsize: int) -> Optional[int]:
+    """The keys a grid step of ``ops/pallas/index_target.py``'s call where
+    it builds the loss's target, None where XLA's lines do: ``impl``
+    ``"flash"`` (the caller's ``attn_impl``), one chip
+    (``context.single_chip``), ``d`` whole lanes, the block's rows whole
+    int8 tiles and every span's keys whole tiles (a span sees a whole
+    number of spans' keys, so the tile that divides one divides all)."""
+    from ray_tpu.ops.pallas import index_target
+    from ray_tpu.parallel.context import single_chip
+
+    if impl != "flash" or not single_chip():
+        return None
+    return index_target.tile_keys(_block_rows(seq), _spans(seq)[0][1], heads,
+                                  kv_heads, d, itemsize)
 
 
 def chosen_pairs(seq: int, topk: int) -> int:
@@ -223,12 +252,13 @@ def dense_attention(q, k, v, select, scale: float):
         lse.reshape(b, h, s))
 
 
-def _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale):
+def _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale, impl):
     """(``L_I``, its gradients by ``q_idx``, ``k_idx``, ``w``), one pass
     over the blocks of rows."""
     b, s, h, d = q.shape
     rows = _block_rows(s)
-    hkv = k.shape[2]
+    tile = kernel_tile(impl, s, h, k.shape[2], d, q.dtype.itemsize)
+    plans.note("sparse", target_plan(tile))
     k_heads = jnp.moveaxis(k, 2, 1)                         # [b, hkv, s, d]
     w = w.astype(F32)
     total, grad_k = jnp.zeros((), F32), jnp.zeros(k_idx.shape, F32)
@@ -241,9 +271,10 @@ def _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale):
         end, at = start + n, slice(start, start + n)
         (total, grad_k), (d_q, d_w) = jax.lax.scan(
             functools.partial(_loss_block, k_idx[:, :end], k_heads[:, :, :end],
-                              scale, b * s),
+                              scale, b * s, tile),
             (total, grad_k),
-            (_blocks(q_idx[:, at], rows), _blocks(w[:, at], rows),
+            (jnp.arange(start, end, rows),
+             _blocks(q_idx[:, at], rows), _blocks(w[:, at], rows),
              _blocks(q[:, at], rows),
              jnp.moveaxis(lse[:, :, at].reshape(b, h, n // rows, rows), 2, 0),
              _blocks(select[:, at, :end], rows)))
@@ -254,23 +285,39 @@ def _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale):
                              jnp.concatenate(grad_w, 1))
 
 
-def _loss_block(k_idx, k_heads, scale, n_rows, carry, args):
-    """One block of rows of ``_loss_and_grads`` against the keys its span
-    sees, ``k_idx`` [b, u, e] and ``k_heads`` [b, hkv, u, d]: the carry is
-    (the rows' summed KL so far, ``k_idx``'s gradient [b, s, e] float32)."""
-    total, grad_k = carry
-    q_i, w_i, q_m, lse_m, sel = args
+def block_target(q_m, k_heads, lse_m, sel, scale):
+    """The target before its normalisation, XLA's form: ``q_m`` [b, rows,
+    h, d], ``k_heads`` [b, hkv, u, d], ``lse_m`` [b, h, rows], ``sel``
+    [b, rows, u] bool -> [b, rows, u] float32, the heads' weights under the
+    choice, summed. What ``index_target.index_target`` computes tile by
+    tile, and what it is tested against."""
     b, rows, h, d = q_m.shape
     hkv = k_heads.shape[1]
-    sel = sel != 0
-    scores, back = jax.vjp(block_scores, q_i, k_idx, w_i)
-    # the target: the heads' weights under the choice, summed
     qg = q_m.reshape(b, rows, hkv, h // hkv, d)
     logits = jnp.einsum("brhgd,bhud->bhgru", qg, k_heads,
                         preferred_element_type=F32) * scale
     lse_g = lse_m.reshape(b, hkv, h // hkv, rows)
-    p = jnp.where(sel[:, None, None],
-                  jnp.exp(logits - lse_g[..., None]), 0.0).sum((1, 2))
+    return jnp.where(sel[:, None, None],
+                     jnp.exp(logits - lse_g[..., None]), 0.0).sum((1, 2))
+
+
+def _loss_block(k_idx, k_heads, scale, n_rows, tile, carry, args):
+    """One block of rows of ``_loss_and_grads`` against the keys its span
+    sees, ``k_idx`` [b, u, e] and ``k_heads`` [b, hkv, u, d]: the carry is
+    (the rows' summed KL so far, ``k_idx``'s gradient [b, s, e] float32).
+    ``tile``: the target's kernel runs, that many keys a grid step."""
+    total, grad_k = carry
+    first, q_i, w_i, q_m, lse_m, chosen = args
+    sel = chosen != 0
+    scores, back = jax.vjp(block_scores, q_i, k_idx, w_i)
+    # the target: the heads' weights under the choice, summed
+    if tile:
+        from ray_tpu.ops.pallas.index_target import index_target
+
+        p = index_target(q_m, k_heads, lse_m, chosen, first, scale=scale,
+                         tile=tile)
+    else:
+        p = block_target(q_m, k_heads, lse_m, sel, scale)
     p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
     # the indexer's distribution over the same keys
     masked = jnp.where(sel, scores, -jnp.inf)
@@ -283,22 +330,25 @@ def _loss_block(k_idx, k_heads, scale, n_rows, carry, args):
     return (total + kl.sum(), grad_k), (d_q, d_w)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def index_loss(q_idx, k_idx, w, q, k, lse, select, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def index_loss(q_idx, k_idx, w, q, k, lse, select, scale, impl="xla"):
     """``L_I`` (module docstring): ``q_idx`` [b, s, J, e], ``k_idx``
     [b, s, e], ``w`` [b, s, J] float32 the indexer's; ``q`` [b, s, h, d],
     ``k`` [b, s, hkv, d] the main attention's as its kernel took them,
     ``lse`` [b, h, s] what it returned, ``select`` [b, s, s] the choice,
-    ``scale`` its softmax's. Gradients reach the first three alone."""
-    return _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale)[0]
+    ``scale`` its softmax's, ``impl`` the caller's ``attn_impl``: under
+    ``"flash"`` the target is the kernel's where it runs (``kernel_tile``).
+    Gradients reach the first three alone."""
+    return _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale, impl)[0]
 
 
-def _index_loss_fwd(q_idx, k_idx, w, q, k, lse, select, scale):
-    loss, grads = _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale)
+def _index_loss_fwd(q_idx, k_idx, w, q, k, lse, select, scale, impl):
+    loss, grads = _loss_and_grads(q_idx, k_idx, w, q, k, lse, select, scale,
+                                  impl)
     return loss, tuple(map(checkpoint_name, grads, RESIDUAL_NAMES[1:]))
 
 
-def _index_loss_bwd(scale, grads, ct):
+def _index_loss_bwd(scale, impl, grads, ct):
     g_q, g_k, g_w = grads
     return ((ct * g_q).astype(g_q.dtype), (ct * g_k).astype(g_k.dtype),
             ct * g_w, None, None, None, None)

@@ -137,7 +137,10 @@ def _sparse(sp: Dict[str, Any]) -> str:
             f"{sp['block_score_bytes'] / 2**20:.0f} MiB a block, the "
             f"threshold in {sp['threshold_passes']} passes of "
             f"{sp['counts_a_pass']} counts, the choice "
-            f"{sp['choice_bytes'] / 2**20:.0f} MiB a layer")
+            f"{sp['choice_bytes'] / 2**20:.0f} MiB a layer; the loss's target "
+            + (f"a Pallas call of {sp['target_tile']} keys a grid step"
+               if sp.get("target_tile") else "in XLA")
+            + f" ({sp.get('target_impl', 'xla')})")
 
 
 def _chosen(routing: Dict[str, Any]) -> Iterator[str]:

@@ -1,5 +1,5 @@
-"""The flash kernels under a learned choice at Keye-VL-2.0's cell's shape,
-compiled for one described chip.
+"""The flash kernels under a learned choice and the indexer loss's target
+(PR 65) at Keye-VL-2.0's cell's shape, compiled for one described chip.
 
 One of the files that ask the chip's compiler, without the chip
 (``test_aot_tpu_compile.py``'s docstring says what that shows). The cell's
@@ -10,11 +10,15 @@ as int8 among it) and no test here holds it: tier-1 has under 80 s of its
 kernels' int8 tile, which this compile asks Mosaic about in a few seconds.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops.pallas import flash
+from ray_tpu.ops import sparse_index
+from ray_tpu.ops.pallas import flash, index_target
 
 from _aot import compiled_kernel, topo  # noqa: F401 (fixtures)
 
@@ -49,3 +53,37 @@ def test_the_kernels_under_a_choice_compile_for_v5e_at_keyes_shape(topo):
         assert p.vmem_bytes <= p.vmem_limit_bytes <= 64 << 20, p
         assert (p.block_q, p.block_k) == (1024, 1024), p
         assert p.live_steps == p.edge_steps == 136, p
+
+
+@pytest.mark.parametrize("keys", [2048, 16384])
+def test_the_indexer_losss_target_compiles_for_v5e_at_keyes_shape(topo, keys):
+    """A block of 256 rows of 32 heads over 4 of 128 against the first and
+    the last span's keys: Mosaic takes the queries as [4, 8 * 256, 128], the
+    log-sum-exp as the column beside them, the choice's int8 tile of 256 x
+    512 and a traced first row; the call carries the name the ledger's
+    ``device_ops`` shows (no ``flash_`` in it: ``flash_select_roofline``
+    reads the three attention calls alone), and the planned tile stays
+    inside the budget."""
+    b, rows, h, hkv, d = 1, 256, 32, 4, 128
+    one = SingleDeviceSharding(topo.devices[0])
+    tile = sparse_index.kernel_tile("flash", 16384, h, hkv, d, 2)
+    assert tile == 512 == index_target.tile_keys(rows, keys, h, hkv, d, 2)
+    need = index_target.vmem_bytes(rows, tile, h, hkv, d, 2)
+    assert 16 << 20 < need <= flash._VMEM_BUDGET_BYTES == 40 << 20
+    shapes = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+              for shape, dtype in [((b, rows, h, d), jnp.bfloat16),
+                                   ((b, hkv, keys, d), jnp.bfloat16),
+                                   ((b, h, rows), jnp.float32),
+                                   ((b, rows, keys), jnp.int8),
+                                   ((), jnp.int32)]]
+    compiled = jax.jit(functools.partial(
+        index_target.index_target, scale=d ** -0.5, tile=tile)).lower(
+        *shapes).compile()
+    customs = [line for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(customs) == 1
+    name = f"index_target_bh{b * h}_r{rows}_k{keys}_d{d}_g{h // hkv}"
+    assert name in customs[0] and "flash_" not in name
+    assert f"f32[{b},{rows},{keys}]" in customs[0]
+    # nothing [heads, rows, keys] round the call: the block's p and no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * keys

@@ -223,6 +223,15 @@ def test_the_walk_is_planned_in_whole_blocks_and_spans(seq, blocks, spans,
     assert plan["block_rows"] * plan["blocks"] == seq
     assert plan["threshold_passes"] * sparse_index._BITS == 32
     assert plan["choice_bytes"] == seq * seq
+    # the loss's target: XLA's lines unless a tile is handed in, which is
+    # ``kernel_tile``'s where the caller's kernels run (Keye's heads, bf16)
+    assert (plan["target_impl"], plan["target_tile"]) == ("xla", None)
+    tile = sparse_index.kernel_tile("flash", seq, 32, 4, 128, 2)
+    assert tile == {16384: 512, 4096: 512, 2048: 256, 1024: 512, 100: None}[seq]
+    assert sparse_index.kernel_tile("xla", seq, 32, 4, 128, 2) is None
+    planned = sparse_index.plan(seq, 16, 64, 2048, target_tile=tile)
+    assert planned == {**plan, **sparse_index.target_plan(tile)}
+    assert planned["target_impl"] == ("pallas" if tile else "xla")
     covered = sparse_index._spans(seq)
     assert [a for a, _ in covered] == [i * seq // spans for i in range(spans)]
     assert sum(n for _, n in covered) == seq
@@ -546,6 +555,11 @@ def test_a_driver_launch_moves_every_leaf_and_notes_the_sparse_plan(model):
     plan = summary["sparse_plan"]
     assert plan == sparse_index.plan(SEQ, 4, 8, 16, 2)
     assert (plan["blocks"], plan["spans"], plan["threshold_passes"]) == (4, 2, 16)
+    # heads of 16 are no whole lanes: the loss's target stays XLA's lines
+    # under "flash" too, and the plan says so
+    assert (plan["target_impl"], plan["target_tile"]) == ("xla", None)
+    assert any("the loss's target in XLA (xla)" in s for s in
+               plans.sentences(summary))
     assert plans.for_span(driver.recorder.plans)["sparse_plan"] == plan
     said = list(plans.sentences(summary))
     assert any("an indexer of 4 heads of 8 keeps 16 keys" in s for s in said)

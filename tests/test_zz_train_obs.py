@@ -567,6 +567,22 @@ def test_rt_train_stats_prints_each_plan_as_before():
     assert plans.DESCRIBE["hyper_plan"](hyper.plan(4, 3584, 2, 20)) == (
         hyper_says + " (xla; rows first [n, b, s, d]; coefficients "
         "[n*n + 2n, b, s] float32, tokens on the lanes)")
+    # the fifth, with the two keys PR 65 gave it: which form builds the
+    # indexer loss's target, and the keys a grid step where it is the kernel
+    from ray_tpu.ops import sparse_index
+    sparse_says = (
+        "sparse attention: an indexer of 16 heads of 64 keeps 2048 keys a "
+        "query of 16384: 31458304 of 134225920 causal pairs a row of the "
+        "batch (23.4%); scores 256 rows a block in 8 span(s), 256 MiB a "
+        "block, the threshold in 16 passes of 3 counts, the choice 256 MiB "
+        "a layer; the loss's target ")
+    plan = sparse_index.plan(16384, 16, 64, 2048, target_tile=512)
+    assert (plan["target_impl"], plan["target_tile"]) == ("pallas", 512)
+    assert plans.DESCRIBE["sparse_plan"](plan) == (
+        sparse_says + "a Pallas call of 512 keys a grid step (pallas)")
+    plan = sparse_index.plan(16384, 16, 64, 2048)
+    assert (plan["target_impl"], plan["target_tile"]) == ("xla", None)
+    assert plans.DESCRIBE["sparse_plan"](plan) == sparse_says + "in XLA (xla)"
 
 
 def test_train_stats_missing_snapshot_is_an_error(rt_cluster):
